@@ -4,6 +4,7 @@ ctypes). See shm_store.cc for the object-store arena."""
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -11,7 +12,18 @@ from typing import Optional
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "shm_store.cc")
-_LIB = os.path.join(_DIR, "libshm_store.so")
+
+
+def _lib_path() -> str:
+    """The library is named by the digest of its source, so a build left
+    by another version of the source (a copied tree keeps no useful
+    mtimes) is never loaded in its place."""
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha1(f.read()).hexdigest()[:12]
+    return os.path.join(_DIR, f"libshm_store.{digest}.so")
+
+
+_LIB = _lib_path()
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _build_failed = False
@@ -43,8 +55,7 @@ def load_shm_store() -> Optional[ctypes.CDLL]:
             return _lib
         if _build_failed:
             return None
-        if not os.path.exists(_LIB) or \
-                os.path.getmtime(_LIB) < os.path.getmtime(_SRC):
+        if not os.path.exists(_LIB):
             if not _build():
                 _build_failed = True
                 return None

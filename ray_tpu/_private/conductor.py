@@ -77,6 +77,34 @@ def _chips_needed(resources: Dict[str, float]) -> int:
     return 0
 
 
+# libtpu process bounds for a worker bound to k of a host's chips. Without
+# them a second process on the host cannot initialize the TPU at all: it
+# claims the whole host topology and fails on libtpu's multi-process
+# lockfile (four-chip v5e host, PR 21). Other counts leave libtpu to its
+# defaults.
+_CHIP_BOUNDS = {1: "1,1,1", 2: "1,2,1", 4: "2,2,1", 8: "2,4,1"}
+
+
+def chip_worker_env(chips: Tuple[int, ...]) -> Dict[str, str]:
+    """Environment of a worker process that owns exactly `chips`
+    (reference accelerators/tpu.py:147,161
+    set_current_process_visible_accelerator_ids): the chips it may open,
+    the process bounds that make them its whole topology, and the TPU
+    platform, so a worker that cannot reach its chips fails instead of
+    computing on the CPU. Inside the process the chips are renumbered
+    from 0; TPU_VISIBLE_CHIPS is what tells two workers apart."""
+    env = {
+        "TPU_VISIBLE_CHIPS": ",".join(str(c) for c in chips),
+        "JAX_PLATFORMS": "tpu",
+        "RAY_TPU_WORKER_FULL_SITE": "1",
+    }
+    bounds = _CHIP_BOUNDS.get(len(chips))
+    if bounds:
+        env["TPU_CHIPS_PER_PROCESS_BOUNDS"] = bounds
+        env["TPU_PROCESS_BOUNDS"] = "1,1,1"
+    return env
+
+
 @dataclass
 class WorkerRecord:
     worker_id: str
@@ -508,7 +536,7 @@ class ConductorHandler:
 
         def reap():
             # Free the chips ONLY once the owner is verifiably gone. A
-            # wedged worker (e.g. stuck in a native call) keeps its chips
+            # hung worker (e.g. stuck in a native call) keeps its chips
             # parked — leaked capacity beats a libtpu double-bind. Keep
             # retrying with backoff; most stragglers exit eventually.
             backoff = 1.0
@@ -918,12 +946,8 @@ class ConductorHandler:
             chips = tuple(sorted(pool.free_chips)[:n_chips])
             for c in chips:
                 pool.free_chips.remove(c)
-            w = self._spawn_worker(node=node, env_extra={
-                "TPU_VISIBLE_CHIPS": ",".join(str(c) for c in chips),
-                "RAY_TPU_WORKER_FULL_SITE": "1",
-                # undo the host-side workers' cpu pin: this worker owns chips
-                "JAX_PLATFORMS": "",
-            })
+            w = self._spawn_worker(node=node,
+                                   env_extra=chip_worker_env(chips))
             w.chip_ids = chips
             return True
 
@@ -2880,7 +2904,7 @@ class ConductorHandler:
                 return {"error":
                         f"pipeline mailbox full "
                         f"({self._PIPELINE_MAILBOX_CAP} entries) — "
-                        "receiver stages dead or wedged?"}
+                        "receiver stages dead or stuck?"}
             self._pipeline_mailbox[str(key)] = desc
         self.publish("pipeline", {"kind": "channel_put", "key": key})
         return {"ok": True}

@@ -200,6 +200,13 @@ class LocalObjectStore:
         # uses plasma pins; deferred reuse is the ownership-model analog).
         self._arena_quarantine: List[Tuple[float, int]] = []
 
+    @property
+    def implementation(self) -> str:
+        """Which store large objects go through: the C++ slab arena, or
+        per-object SharedMemory when the library could not be built or
+        loaded (that fallback is silent; this says which one ran)."""
+        return "native-arena" if self._arena is not None else "python-shm"
+
     # ---------- write paths ----------
 
     def put_value(self, object_id: str, value: Any) -> int:

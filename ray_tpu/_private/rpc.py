@@ -86,7 +86,7 @@ class RpcServer:
         # (lease_worker parks on a condition variable until capacity
         # frees) opt out via the handler's _slow_ok_methods set.
         # 5s default: create_actor legitimately takes ~2-3s (process
-        # spawn + imports); the warning is for wedged handlers.
+        # spawn + imports); the warning is for stuck handlers.
         self._warn_slow = warn_slow
         self._warn_handler_s = float(
             os.environ.get("RAY_TPU_RPC_WARN_MS", "5000")) / 1e3

@@ -574,7 +574,7 @@ class Worker:
                 "subscribe_object", ref.id, self.address, timeout=5.0))
         except (ConnectionLost, RemoteError, TimeoutError):
             # TimeoutError too: a GIL-bound owner answering late must not
-            # leave ref.id wedged in _subscribed with no push coming
+            # leave ref.id stranded in _subscribed with no push coming
             with self._state_lock:
                 self._subscribed.discard(ref.id)
             return False

@@ -48,12 +48,18 @@ def main() -> None:
     from . import worker as worker_mod
     from .worker import Worker
 
+    chips = _bound_chips()
+    if chips:
+        # this process will compile for its chips: share the persistent
+        # cache with every other chip owner, before the first program
+        from ray_tpu.util.compile_cache import enable_compile_cache
+
+        enable_compile_cache()
     w = Worker(mode="worker", conductor_address=(host, int(port)),
                session_dir=session_dir, worker_id=worker_id)
     worker_mod.global_worker = w
     # announce the chip binding so a restarted conductor (whose free_chips
     # reinitialized to the full range) re-learns which chips are taken
-    chips = _bound_chips()
     w.conductor.call("register_worker", worker_id, w.address, os.getpid(),
                      os.environ.get("RAY_TPU_NODE_ID"), chips, timeout=30.0)
 

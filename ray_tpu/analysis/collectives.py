@@ -46,6 +46,8 @@ HEAVY_AXES = ("tp", "sp", "ep")
 #: primitive name -> fn(bytes, n, d) -> bytes over DCN
 _COST_MODEL: Dict[str, Callable[[float, int, int], float]] = {
     "psum": lambda b, n, d: 2.0 * b * (d - 1) / d,
+    # what a psum inside shard_map traces as under check_vma
+    "psum_invariant": lambda b, n, d: 2.0 * b * (d - 1) / d,
     "all_gather": lambda b, n, d: float(b) * n * (d - 1) / d,
     "all_gather_invariant": lambda b, n, d: float(b) * n * (d - 1) / d,
     "reduce_scatter": lambda b, n, d: float(b) * (d - 1) / d,
@@ -53,14 +55,11 @@ _COST_MODEL: Dict[str, Callable[[float, int, int], float]] = {
     "ppermute": lambda b, n, d: float(b),
     "pmin": lambda b, n, d: 2.0 * b * (d - 1) / d,
     "pmax": lambda b, n, d: 2.0 * b * (d - 1) / d,
-    # jax 0.4.x traces psum as psum2 under check_rep — same ring cost
-    "psum2": lambda b, n, d: 2.0 * b * (d - 1) / d,
 }
 
-# Named-axis primitives that move no payload (replication/VMA
-# bookkeeping and index queries; pbroadcast is jax 0.4.x's check_rep
-# marker, pvary the newer name): never collected, never costed.
-_NON_COMM = frozenset({"pvary", "pbroadcast", "axis_index"})
+# Named-axis primitives that move no payload (VMA bookkeeping and index
+# queries): never collected, never costed.
+_NON_COMM = frozenset({"pvary", "axis_index"})
 
 
 @dataclass(frozen=True)
@@ -123,10 +122,8 @@ def _axis_names(params: Dict[str, Any]) -> Tuple[str, ...]:
 
 
 def _walk_jaxpr(jaxpr: Any, out: List[CollectiveUse]) -> None:
-    try:
-        from jax.extend.core import ClosedJaxpr, Jaxpr
-    except ImportError:  # jax < 0.4.38
-        from jax.core import ClosedJaxpr, Jaxpr
+    from jax.extend.core import ClosedJaxpr, Jaxpr
+
     def _sub_jaxprs(params):
         subs = []
         for v in params.values():
@@ -173,20 +170,11 @@ def scan_collectives(fn: Callable, *abstract_args: Any,
 
 def abstract_mesh(layout: MeshLayout) -> Any:
     """A `jax.sharding.AbstractMesh` with the layout's axis names/sizes —
-    shard_map programs trace against it with no devices. Returns None on
-    jax versions without AbstractMesh (callers fall back to a real
-    mesh or skip the collective scan)."""
+    shard_map programs trace against it with no devices."""
     import jax
 
-    cls = getattr(jax.sharding, "AbstractMesh", None)
-    if cls is None:
-        return None
-    items = tuple(layout.axis_sizes.items())
-    try:
-        return cls(tuple((name, size) for name, size in items))
-    except TypeError:
-        # newer signature: AbstractMesh(axis_sizes, axis_names)
-        return cls(tuple(s for _, s in items), tuple(n for n, _ in items))
+    return jax.sharding.AbstractMesh(tuple(layout.axis_sizes.values()),
+                                     tuple(layout.axis_sizes.keys()))
 
 
 def _fmt_bytes(n: float) -> str:

@@ -154,8 +154,7 @@ def _trace_pipeline(config: MeshConfig, n_devices: int,
                     num_slices: int, pp: int, data_parallel: int,
                     name: str) -> LayoutTrace:
     """Trace the toy GPipe pipeline (ppermute ring + final-stage psum
-    over 'pp') over an abstract mesh. Empty uses when this jax has no
-    AbstractMesh."""
+    over 'pp') over an abstract mesh."""
     import jax.numpy as jnp
 
     from ..parallel.pipeline import make_pipeline_fn
@@ -167,9 +166,6 @@ def _trace_pipeline(config: MeshConfig, n_devices: int,
     d, batch = 16, data_parallel * m
     # toy tanh-matmul "model": ~6 flops per param per row (fwd+bwd)
     flops = 6.0 * (pp * d * d + pp * d) * batch
-    if mesh is None:  # jax without AbstractMesh: nothing to trace
-        return LayoutTrace(layout=layout, flops_per_step=flops,
-                           tokens_per_step=batch)
     pipe = make_pipeline_fn(
         lambda p, h: jnp.tanh(h @ p[0] + p[1]), mesh, num_microbatches=m)
     params = (_sds((pp, d, d)), _sds((pp, d)))
@@ -191,10 +187,6 @@ def _pipeline_findings(config: MeshConfig, n_devices: int,
                                        where=f"{name}/schedule")
     trace = _trace_pipeline(config, n_devices, num_slices, pp,
                             data_parallel, name)
-    if not trace.uses:  # jax without AbstractMesh: nothing was traced
-        return findings + [Finding(
-            "collective-over-dcn", INFO, f"{name}/collectives",
-            "collective scan skipped: this jax has no AbstractMesh")]
     return findings + check_collectives(trace.layout, trace.uses,
                                         where=f"{name}/collectives")
 

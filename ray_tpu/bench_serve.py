@@ -190,7 +190,7 @@ def run_load(router, prompts: Sequence[Sequence[int]], *,
         th.join(timeout=timeout_s)
     wall = time.perf_counter() - t_start
 
-    # ONE locked snapshot for the whole record: wedged request threads
+    # ONE locked snapshot for the whole record: stuck request threads
     # outlive their join timeout (daemon) and may still be mutating the
     # outcome state while the record is built. hung is derived from the
     # same view — every request thread records exactly one outcome

@@ -773,10 +773,7 @@ class ContinuousBatchingEngine:
         dashboard (all surfaces report THIS dict's numbers)."""
         s: Dict[str, Any] = (self.kv_cache.stats() if self.kv_cache
                              else {"enabled": False})
-        try:
-            programs = _prefill_paged._cache_size()
-        except Exception:  # noqa: BLE001 — older jax without _cache_size
-            programs = -1
+        programs = _prefill_paged._cache_size()
         s.update(
             engine_id=self.engine_id,
             max_batch=self.max_batch,

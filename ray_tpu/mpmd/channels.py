@@ -187,7 +187,7 @@ class ActivationChannel:
                     f"pipeline {self.name!r}: stage {self.dst} waited "
                     f"{timeout}s for {kind} microbatch {mb} of step "
                     f"{step} from stage {self.src} — upstream stage "
-                    "dead or wedged?")
+                    "dead or stuck?")
             with self._cv:
                 self._cv.wait(min(remaining, self._poll))
 

@@ -38,12 +38,11 @@ NOMINAL_PEAK_FLOPS = {
     "gpu": 312e12,  # A100-class bf16, the reference comparison point
 }
 
-_UNKNOWN_TPU_PEAK = 275e12  # assume v4-class so MFU stays conservative
-
 
 def device_peak_flops(device: Any = None) -> float:
     """bf16 peak FLOP/s of one device (jax Device or None for the first
-    local device)."""
+    local device). A TPU whose device_kind is not in the table raises: an
+    assumed peak would put a wrong denominator under every MFU."""
     if device is None:
         import jax
 
@@ -55,7 +54,9 @@ def device_peak_flops(device: Any = None) -> float:
             return peak
     platform = getattr(device, "platform", "") or ""
     if platform == "tpu":
-        return _UNKNOWN_TPU_PEAK
+        raise ValueError(
+            f"no bf16 peak for TPU device_kind {kind!r}: add it to "
+            "observability.flops.PEAK_FLOPS_BF16 with its source")
     return NOMINAL_PEAK_FLOPS.get(platform, NOMINAL_PEAK_FLOPS["cpu"])
 
 

@@ -88,8 +88,6 @@ NOMINAL_LINK_CONSTANTS: Dict[str, LinkConstants] = {
     "gpu": LinkConstants(6.0e11, 1e-6, 2.5e10, 2.5e-5),  # NVLink / IB
 }
 
-_UNKNOWN_TPU_LINKS = LINK_CONSTANTS["TPU v4"]  # conservative, like flops
-
 
 def device_link_constants(device: Any = None) -> LinkConstants:
     """Link constants of one device (jax Device or None for the first
@@ -106,7 +104,9 @@ def device_link_constants(device: Any = None) -> LinkConstants:
             return links
     platform = getattr(device, "platform", "") or ""
     if platform == "tpu":
-        return _UNKNOWN_TPU_LINKS
+        raise ValueError(
+            f"no link constants for TPU device_kind {kind!r}: add it to "
+            "observability.roofline.LINK_CONSTANTS with its source")
     return NOMINAL_LINK_CONSTANTS.get(platform,
                                       NOMINAL_LINK_CONSTANTS["cpu"])
 

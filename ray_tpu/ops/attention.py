@@ -12,12 +12,14 @@ The backward pass is two Pallas kernels (the FlashAttention-2 recipe):
 - dq kernel: grid over q blocks, inner loop over kv blocks;
 - dkv kernel: grid over kv blocks, inner loop over q blocks;
 both recompute P = exp(S - L) from the forward's saved logsumexp L (stored
-lane-broadcast as [B*H, T, 128] f32, the same layout jax's own TPU kernel
-uses) and the precomputed row term D = rowsum(dO * O).
+broadcast over a minor dim of `_LANES` = 8 as [B*H, T, 8] f32) and the
+precomputed row term D = rowsum(dO * O).
 
-`flash_attention` dispatches: Pallas kernel on TPU backends (or
-`interpret=True` when RAY_TPU_PALLAS_INTERPRET=1, which is how CPU CI
-tests the hardware code path), jnp reference otherwise.
+`flash_attention` dispatches: Pallas kernel on TPU backends (or in
+interpret mode, which is how CPU CI tests the hardware code path), jnp
+reference otherwise. The choice per traced shape is recorded in
+`ops.dispatch.kernel_choices()`; under an ambient mesh the kernels run per
+shard over the batch and head axes (`ops.dispatch.per_shard`).
 """
 from __future__ import annotations
 
@@ -28,36 +30,34 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
 
-try:  # pltpu only imports on TPU-capable jaxlib builds
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from . import dispatch
 
-# block shapes tuned on v5e; env overrides for bench sweeps
+# 1024 x 1024 compiles and fits on a v5e at the 32 x 1024 training shape
+# (chip run, PR 21); choosing among block sizes on the chip is ROADMAP S5.
+# Env overrides for bench sweeps.
 DEFAULT_BLOCK_Q = int(os.environ.get("RAY_TPU_FLASH_BLOCK_Q", "1024"))
 DEFAULT_BLOCK_K = int(os.environ.get("RAY_TPU_FLASH_BLOCK_K", "1024"))
-_LANES = 8  # LSE/D are broadcast over a small minor dim (sublane tile);
-#             keeping it at 8 rather than the 128-lane width cuts the HBM
-#             traffic of the side outputs 16x
+_LANES = 8  # LSE/D are broadcast over a small minor dim. It saves nothing
+#             in HBM: XLA tiles these arrays T(8,128), so the 8 is padded
+#             to 128 on the chip (compiled HLO, PR 21; PERF.md section 5)
 _NEG_INF = -1e30
 _LOG2E = 1.4426950408889634  # kernels work in log2 domain: exp2 is the
 _LN2 = 0.6931471805599453    # cheap VPU transcendental; scale*log2(e) is
 #                              folded into q so softmax needs only exp2.
 
 
-def _parallel_grid_params(n_axes: int, interpret: bool):
-    """Mosaic dimension_semantics: every grid axis of these kernels is
-    embarrassingly parallel (no cross-program carries), which lets the
-    compiler software-pipeline block DMA against compute instead of
-    assuming a sequential grid. No-op in interpret mode / without pltpu."""
-    if interpret or pltpu is None:
+def _grid_params(interpret: bool, minor: str = "parallel"):
+    """Mosaic dimension_semantics for a (batch*head, block) grid. An axis
+    is "parallel" when no program carries state to the next, which lets
+    the compiler software-pipeline block DMA against compute; the fused
+    backward accumulates dq across its block axis, which is therefore
+    "arbitrary" (run in order). None in interpret mode."""
+    if interpret:
         return None
-    try:
-        return pltpu.CompilerParams(
-            dimension_semantics=("parallel",) * n_axes)
-    except Exception:  # noqa: BLE001 — older pallas: params shape moved
-        return None
+    return pltpu.CompilerParams(dimension_semantics=("parallel", minor))
 
 
 def mha_reference(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -182,13 +182,16 @@ def _flash_fwd_pallas(q, k, v, causal: bool, sm_scale: float,
             jax.ShapeDtypeStruct((b * h, tq, _LANES), jnp.float32),
         ],
         interpret=interpret,
-        compiler_params=_parallel_grid_params(2, interpret),
+        name="flash_fwd",
+        compiler_params=_grid_params(interpret),
         cost_estimate=pl.CostEstimate(
             flops=4 * b * h * tq * tk * d,
             bytes_accessed=(qf.size + kf.size + vf.size) * qf.dtype.itemsize,
             transcendentals=b * h * tq * tk),
     )(qf, kf, vf)
-    return out.reshape(b, h, tq, d).transpose(0, 2, 1, 3), lse
+    # lse leaves as [B, H, T, LANES] so per_shard can split batch and heads
+    return (out.reshape(b, h, tq, d).transpose(0, 2, 1, 3),
+            lse.reshape(b, h, tq, _LANES))
 
 
 # -------------------------------------------------------------- backward
@@ -331,8 +334,8 @@ def _flash_bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dcor_ref,
     dK/dV AND this block's dQ contributions. The two-pass backward
     recomputes S twice (7 dots per q-kv pair); this computes it once
     (5 dots) and halves the Q/dO HBM traffic. dq is a REVISITED output
-    ([q_len, D] f32, index ignoring ki): TPU pallas grids execute
-    sequentially, so cell (g, ki) accumulates onto what (g, ki-1)
+    ([q_len, D] f32, index ignoring ki): the ki grid axis is "arbitrary"
+    (run in order), so cell (g, ki) accumulates onto what (g, ki-1)
     wrote — the standard TPU revisiting-accumulator pattern.
     Refs: k/v/dk/dv [block_k, D]; q/do [q_len, D]; dq [q_len, D] f32;
     lse/dcor [q_len, LANES]."""
@@ -419,6 +422,7 @@ def _flash_bwd_fused_pallas(q, k, v, o, lse, do, causal: bool,
     tk = k.shape[1]
     block_q = min(block_q, tq)
     block_k = min(block_k, tk)
+    lse = lse.reshape(b * h, tq, _LANES)
     qf = q.transpose(0, 2, 1, 3).reshape(b * h, tq, d)
     kf = k.transpose(0, 2, 1, 3).reshape(b * h, tk, d)
     vf = v.transpose(0, 2, 1, 3).reshape(b * h, tk, d)
@@ -454,7 +458,8 @@ def _flash_bwd_fused_pallas(q, k, v, o, lse, do, causal: bool,
             jax.ShapeDtypeStruct((b * h, tq, d), jnp.float32),
         ],
         interpret=interpret,
-        compiler_params=_parallel_grid_params(2, interpret),
+        name="flash_bwd_fused",
+        compiler_params=_grid_params(interpret, minor="arbitrary"),
         cost_estimate=pl.CostEstimate(
             flops=10 * b * h * tq * tk * d,
             bytes_accessed=(qf.size + kf.size + vf.size + dof.size)
@@ -477,6 +482,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal: bool, sm_scale: float,
     tk = k.shape[1]
     block_q = min(block_q, tq)
     block_k = min(block_k, tk)
+    lse = lse.reshape(b * h, tq, _LANES)
     qf = q.transpose(0, 2, 1, 3).reshape(b * h, tq, d)
     kf = k.transpose(0, 2, 1, 3).reshape(b * h, tk, d)
     vf = v.transpose(0, 2, 1, 3).reshape(b * h, tk, d)
@@ -505,7 +511,8 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal: bool, sm_scale: float,
         out_specs=pl.BlockSpec((None, block_q, d), lambda g, i: (g, i, 0)),
         out_shape=jax.ShapeDtypeStruct((b * h, tq, d), q.dtype),
         interpret=interpret,
-        compiler_params=_parallel_grid_params(2, interpret),
+        name="flash_bwd_dq",
+        compiler_params=_grid_params(interpret),
         cost_estimate=pl.CostEstimate(
             flops=4 * b * h * tq * tk * d,
             bytes_accessed=(qf.size + kf.size + vf.size + dof.size)
@@ -536,7 +543,8 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal: bool, sm_scale: float,
             jax.ShapeDtypeStruct((b * h, tk, d), v.dtype),
         ],
         interpret=interpret,
-        compiler_params=_parallel_grid_params(2, interpret),
+        name="flash_bwd_dkv",
+        compiler_params=_grid_params(interpret),
         cost_estimate=pl.CostEstimate(
             flops=6 * b * h * tq * tk * d,
             bytes_accessed=(qf.size + kf.size + vf.size + dof.size)
@@ -553,32 +561,25 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal: bool, sm_scale: float,
 # ------------------------------------------------------------- dispatch
 
 
-def _interpret_forced() -> bool:
-    return os.environ.get("RAY_TPU_PALLAS_INTERPRET", "0") == "1"
+def _reference_reason(q, k, block_q: int, block_k: int) -> str:
+    """Why this call takes the XLA reference; "" when the kernels run.
 
-
-def _use_pallas() -> bool:
+    Sequence lengths must divide the *effective* block size (after
+    clamping to the sequence length); otherwise the in-kernel pl.ds
+    reads would silently clamp out-of-bounds starts and corrupt the
+    causal indexing."""
     if os.environ.get("RAY_TPU_DISABLE_FLASH") == "1":  # ablation/debug escape hatch
-        return False
-    if _interpret_forced():
-        return True
-    if pltpu is None:
-        return False
-    try:
-        return jax.default_backend() == "tpu"
-    except RuntimeError:  # pragma: no cover
-        return False
-
-
-def _shapes_ok(q, k, block_q: int, block_k: int) -> bool:
-    # Sequence lengths must divide the *effective* block size (after
-    # clamping to the sequence length); otherwise the in-kernel pl.ds
-    # reads would silently clamp out-of-bounds starts and corrupt the
-    # causal indexing.
+        return "RAY_TPU_DISABLE_FLASH=1"
+    if (reason := dispatch.backend_reason()):
+        return reason
     tq, tk = q.shape[1], k.shape[1]
-    return (tq % min(block_q, tq) == 0 and tk % min(block_k, tk) == 0
-            and tq % 128 == 0 and tk % 128 == 0
-            and (q.shape[-1] % 128 == 0 or q.shape[-1] == 64))
+    if tq % min(block_q, tq) or tk % min(block_k, tk) \
+            or tq % 128 or tk % 128:
+        return (f"sequence lengths ({tq}, {tk}) are not multiples of 128 "
+                f"and of the blocks ({block_q}, {block_k})")
+    if q.shape[-1] % 128 and q.shape[-1] != 64:
+        return f"head_dim {q.shape[-1]} is neither 64 nor a multiple of 128"
+    return ""
 
 
 def set_default_blocks(block_q: Optional[int] = None,
@@ -606,16 +607,35 @@ def flash_attention(q, k, v, causal: bool = True,
     return _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k)[0]
 
 
+def _shard_specs(q):
+    """(q/k/v spec, lse spec, shard count) for running the kernels per
+    shard: attention is independent per batch row and per head."""
+    b, _, h, _ = q.shape
+    batch = dispatch.shard_axes(b, dispatch.DATA_AXES) or None
+    head = dispatch.shard_axes(h, (dispatch.HEAD_AXIS,)) or None
+    return (P(batch, None, head, None), P(batch, head, None, None),
+            dispatch.axes_size((batch or ()) + (head or ())))
+
+
 def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k):
     block_q = DEFAULT_BLOCK_Q if block_q is None else block_q
     block_k = DEFAULT_BLOCK_K if block_k is None else block_k
     scale = q.shape[-1] ** -0.5 if sm_scale is None else sm_scale
-    if _use_pallas() and _shapes_ok(q, k, block_q, block_k):
-        out, lse = _flash_fwd_pallas(q, k, v, causal, scale, block_q,
-                                     block_k, interpret=_interpret_forced())
-        return out, (q, k, v, out, lse)
-    out = mha_reference(q, k, v, causal, scale)
-    return out, (q, k, v, None, None)
+    shape = (*q.shape, k.shape[1])
+    reason = _reference_reason(q, k, block_q, block_k)
+    if reason:
+        dispatch.record_choice("flash_attention", shape, "reference", reason)
+        out = mha_reference(q, k, v, causal, scale)
+        return out, (q, k, v, None, None)
+    qkv_spec, lse_spec, n_shards = _shard_specs(q)
+    dispatch.record_choice("flash_attention", shape, "pallas",
+                           shards=n_shards)
+    fwd = functools.partial(
+        _flash_fwd_pallas, causal=causal, sm_scale=scale, block_q=block_q,
+        block_k=block_k, interpret=dispatch.interpret_forced())
+    out, lse = dispatch.per_shard(fwd, (q, k, v), (qkv_spec,) * 3,
+                                  (qkv_spec, lse_spec))
+    return out, (q, k, v, out, lse)
 
 
 def _flash_bwd(causal, sm_scale, block_q, block_k, res, g):
@@ -624,9 +644,15 @@ def _flash_bwd(causal, sm_scale, block_q, block_k, res, g):
     block_k = DEFAULT_BLOCK_K if block_k is None else block_k
     scale = q.shape[-1] ** -0.5 if sm_scale is None else sm_scale
     if lse is not None:
-        return _flash_bwd_pallas(q, k, v, o, lse, g, causal, scale,
-                                 block_q, block_k,
-                                 interpret=_interpret_forced())
+        qkv_spec, lse_spec, _ = _shard_specs(q)
+        bwd = functools.partial(
+            _flash_bwd_pallas, causal=causal, sm_scale=scale,
+            block_q=block_q, block_k=block_k,
+            interpret=dispatch.interpret_forced())
+        return dispatch.per_shard(
+            bwd, (q, k, v, o, lse, g),
+            (qkv_spec, qkv_spec, qkv_spec, qkv_spec, lse_spec, qkv_spec),
+            (qkv_spec,) * 3)
 
     def ref(q_, k_, v_):
         return mha_reference(q_, k_, v_, causal, scale)

@@ -13,7 +13,10 @@ where they are cheap single passes.
 
 New capability vs the reference (no kernels of its own — SURVEY.md §5.7);
 the chunked-XLA fallback (`_ce_reference`) is the correctness oracle, and
-interpret-mode tests drive the kernels on CPU CI.
+interpret-mode tests drive the kernels on CPU CI. The choice per traced
+shape is recorded in `ops.dispatch.kernel_choices()`; under an ambient
+mesh the kernels run per shard over the batch axes
+(`ops.dispatch.per_shard`).
 """
 from __future__ import annotations
 
@@ -24,13 +27,17 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
+
+from . import dispatch
 
 _LANES = 8
 _NEG_INF = -1e30
 _LOG2E = 1.4426950408889634
 _LN2 = 0.6931471805599453
 
-# token rows per program (tuned on v5e; env override for bench sweeps)
+# token rows per program (env override for bench sweeps)
 DEFAULT_BLOCK_N = int(os.environ.get("RAY_TPU_CE_BLOCK_N", "1024"))
 
 
@@ -97,8 +104,6 @@ def _ce_fwd_kernel(x_ref, w_ref, t_ref, loss_ref, lse_ref,
 
 def _ce_fwd_pallas(x, w, targets, vocab_size: int, block_n: int,
                    block_v: int, interpret: bool):
-    from jax.experimental.pallas import tpu as pltpu
-
     n, d = x.shape
     v = w.shape[0]
     block_n = min(block_n, n)
@@ -130,6 +135,7 @@ def _ce_fwd_pallas(x, w, targets, vocab_size: int, block_n: int,
             pltpu.VMEM((block_n, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="fused_ce_fwd",
         cost_estimate=pl.CostEstimate(
             flops=2 * n * v * d,
             bytes_accessed=(x.size * x.dtype.itemsize
@@ -210,8 +216,8 @@ def _ce_dw_kernel(x_ref, w_ref, lse_ref, xg_ref, dw_ref, acc_scr, *,
 
 def _ce_bwd_pallas(x, w, targets, lse, g, vocab_size: int, block_n: int,
                    block_v: int, interpret: bool):
-    from jax.experimental.pallas import tpu as pltpu
-
+    """Returns (dx in x's dtype, dW in f32: the caller may still have to
+    sum it over row shards)."""
     n, d = x.shape
     v = w.shape[0]
     block_n = min(block_n, n)
@@ -233,6 +239,7 @@ def _ce_bwd_pallas(x, w, targets, lse, g, vocab_size: int, block_n: int,
         out_shape=jax.ShapeDtypeStruct((n, d), jnp.float32),
         scratch_shapes=[pltpu.VMEM((block_n, d), jnp.float32)],
         interpret=interpret,
+        name="fused_ce_dx",
         cost_estimate=pl.CostEstimate(
             flops=4 * n * v * d, bytes_accessed=2 * x.size,
             transcendentals=n * v),
@@ -258,32 +265,17 @@ def _ce_bwd_pallas(x, w, targets, lse, g, vocab_size: int, block_n: int,
         out_shape=jax.ShapeDtypeStruct((v, d), jnp.float32),
         scratch_shapes=[pltpu.VMEM((block_v, d), jnp.float32)],
         interpret=interpret,
+        name="fused_ce_dw",
         cost_estimate=pl.CostEstimate(
             flops=4 * n * v * d,
             bytes_accessed=2 * x.size + w.size, transcendentals=n * v),
     )(x, w, lse_b, xg)
     # scatter-add of the one-hot rows: dW[tgt] -= g*x
     dw = dw_unscaled.at[targets].add(-xg.astype(jnp.float32))
-    return dx.astype(x.dtype), dw.astype(w.dtype)
+    return dx.astype(x.dtype), dw
 
 
 # ------------------------------------------------------------- dispatch
-
-
-def _interpret_forced() -> bool:
-    return os.environ.get("RAY_TPU_PALLAS_INTERPRET", "0") == "1"
-
-
-def _use_pallas() -> bool:
-    if os.environ.get("RAY_TPU_DISABLE_FUSED_CE") == "1":  # ablation/debug escape hatch
-        return False
-    if _interpret_forced():
-        return True
-    try:
-        from jax.experimental.pallas import tpu as pltpu  # noqa: F401
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover
-        return False
 
 
 def _pick_block_v(v: int) -> Optional[int]:
@@ -293,14 +285,37 @@ def _pick_block_v(v: int) -> Optional[int]:
     return None
 
 
+def _reference_reason(n: int, d: int, v: int) -> str:
+    """Why these shapes take an XLA loss; "" when the kernels run."""
+    if os.environ.get("RAY_TPU_DISABLE_FUSED_CE") == "1":  # ablation/debug escape hatch
+        return "RAY_TPU_DISABLE_FUSED_CE=1"
+    if (reason := dispatch.backend_reason()):
+        return reason
+    if dispatch.live_axes((dispatch.HEAD_AXIS,)):
+        # each tp shard holds a slice of the vocabulary: a per-shard
+        # kernel would see a partial softmax, a gathered one repeats the
+        # whole loss on every tp shard. The partitioner's vocab-parallel
+        # CE on the caller's chunked XLA loss is the right program there.
+        return f"vocabulary is split over the {dispatch.HEAD_AXIS!r} axis"
+    local = n // dispatch.axes_size(
+        dispatch.shard_axes(n, dispatch.DATA_AXES))
+    if _pick_block_v(v) is None or local % min(DEFAULT_BLOCK_N, local) \
+            or local % 128 or d % 128:
+        return (f"rows {local}, width {d}, vocabulary {v} do not tile "
+                "into the kernel's blocks")
+    return ""
+
+
 def fused_ce_supported(n: int, d: int, v: int) -> bool:
     """True iff the Pallas fused path will actually run for these shapes
     on this backend — callers (models.gpt2) dispatch on this so a shape
     miss falls back to *their* chunked path, never the unchunked
-    full-logit reference."""
-    return (_use_pallas() and _pick_block_v(v) is not None
-            and n % min(DEFAULT_BLOCK_N, n) == 0 and n % 128 == 0
-            and d % 128 == 0)
+    full-logit reference. A "no" is recorded with its reason."""
+    reason = _reference_reason(n, d, v)
+    if reason:
+        dispatch.record_choice("linear_cross_entropy", (n, d, v),
+                               "reference", reason)
+    return not reason
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
@@ -315,14 +330,26 @@ def linear_cross_entropy(x: jax.Array, w: jax.Array, targets: jax.Array,
     return _lce_fwd(x, w, targets, vocab_size)[0]
 
 
+def _row_axes(n: int):
+    """Batch axes the token rows are split over (None: not split)."""
+    return dispatch.shard_axes(n, dispatch.DATA_AXES) or None
+
+
 def _lce_fwd(x, w, targets, vocab_size):
     n, d = x.shape
     v = w.shape[0]
     use = fused_ce_supported(n, d, v)
     if use:
-        loss, lse = _ce_fwd_pallas(x, w, targets, vocab_size,
-                                   DEFAULT_BLOCK_N, _pick_block_v(v),
-                                   _interpret_forced())
+        rows = _row_axes(n)
+        dispatch.record_choice(
+            "linear_cross_entropy", (n, d, v), "pallas",
+            shards=dispatch.axes_size(rows or ()))
+        fwd = functools.partial(
+            _ce_fwd_pallas, vocab_size=vocab_size, block_n=DEFAULT_BLOCK_N,
+            block_v=_pick_block_v(v), interpret=dispatch.interpret_forced())
+        loss, lse = dispatch.per_shard(
+            fwd, (x, w, targets), (P(rows, None), P(None, None), P(rows)),
+            (P(rows), P(rows)))
     else:
         loss, lse = _ce_reference(x, w, targets, vocab_size)
     return loss, (x, w, targets, lse, use)
@@ -331,9 +358,21 @@ def _lce_fwd(x, w, targets, vocab_size):
 def _lce_bwd(vocab_size, res, g):
     x, w, targets, lse, used_pallas = res
     if used_pallas:
-        bv = _pick_block_v(w.shape[0])
-        dx, dw = _ce_bwd_pallas(x, w, targets, lse, g, vocab_size,
-                                DEFAULT_BLOCK_N, bv, _interpret_forced())
+        rows = _row_axes(x.shape[0])
+
+        def bwd(x_, w_, t_, lse_, g_):
+            dx, dw = _ce_bwd_pallas(
+                x_, w_, t_, lse_, g_, vocab_size, DEFAULT_BLOCK_N,
+                _pick_block_v(w.shape[0]), dispatch.interpret_forced())
+            # w is whole on every shard; its gradient sums over the rows
+            if rows:
+                dw = jax.lax.psum(dw, rows)
+            return dx, dw.astype(w_.dtype)
+
+        dx, dw = dispatch.per_shard(
+            bwd, (x, w, targets, lse, g),
+            (P(rows, None), P(None, None), P(rows), P(rows), P(rows)),
+            (P(rows, None), P(None, None)))
         return dx, dw, None
     # XLA fallback: differentiate the reference
     def ref(x_, w_):

@@ -274,13 +274,8 @@ def initialize_jax_distributed(group_key: str, rank: int, world: int,
         # CPU-pinned gangs (tests, host-side data/eval work): the
         # default CPU client has no cross-process collectives ("not
         # implemented on the CPU backend"); gloo is jaxlib's portable
-        # implementation. Best-effort — older jaxlibs without the
-        # option still form the gang for non-collective work.
-        try:
-            jax.config.update("jax_cpu_collectives_implementation",
-                              "gloo")
-        except Exception:  # noqa: BLE001 — option absent on this jax
-            pass
+        # implementation.
+        jax.config.update("jax_cpu_collectives_implementation", "gloo")
     jax.distributed.initialize(coordinator_address=coordinator,
                                num_processes=world,
                                process_id=process_id)
@@ -288,24 +283,10 @@ def initialize_jax_distributed(group_key: str, rank: int, world: int,
 
 
 def is_jax_distributed_initialized() -> bool:
-    """True once jax.distributed.initialize succeeded in this process.
-
-    Version-portable: `jax.distributed.is_initialized` only exists on
-    newer jax; older 0.4.x exposes nothing public, so fall back to the
-    internal global_state's client handle (None until initialize)."""
+    """True once jax.distributed.initialize succeeded in this process."""
     import jax
 
-    fn = getattr(jax.distributed, "is_initialized", None)
-    if fn is not None:
-        return bool(fn())
-    try:
-        from jax._src import distributed as _dist
-
-        state = getattr(_dist, "global_state", None)
-        return state is not None and \
-            getattr(state, "client", None) is not None
-    except ImportError:
-        return False
+    return bool(jax.distributed.is_initialized())
 
 
 def setup_jax_distributed(timeout: float = 120.0) -> Tuple[int, int]:

@@ -110,17 +110,6 @@ def ici_device_mesh(shape: Tuple[int, ...],
         return np.asarray(devices, dtype=object).reshape(shape)
 
 
-try:  # jax >= 0.6 exports it at top level
-    from jax import shard_map as _shard_map_impl
-except ImportError:  # pragma: no cover - jax 0.4/0.5
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-_REPLICATION_CHECK_KW = next(
-    (kw for kw in ("check_vma", "check_rep")
-     if kw in __import__("inspect").signature(_shard_map_impl).parameters),
-    None)
-
-
 def validate_axis_names(mesh: Any, specs: Any, what: str = "spec") -> None:
     """Raise a clear ValueError when a PartitionSpec (or pytree of specs)
     names an axis the mesh does not have — instead of the opaque deep-XLA
@@ -144,18 +133,13 @@ def validate_axis_names(mesh: Any, specs: Any, what: str = "spec") -> None:
                         f"MESH_AXES = {MESH_AXES})")
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None, **kw):
-    """Version-portable `shard_map`: jax renamed the replication-check
-    kwarg (check_rep -> check_vma) and moved the function out of
-    experimental; this front door accepts `check_vma` and forwards to
-    whatever the installed jax calls it. Spec axis names are validated
-    against the mesh up front (clear ValueError, not a deep-XLA error)."""
+def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
+    """`jax.shard_map` with the spec axis names validated against the
+    mesh up front (clear ValueError, not a deep-XLA error)."""
     validate_axis_names(mesh, in_specs, "shard_map in_specs")
     validate_axis_names(mesh, out_specs, "shard_map out_specs")
-    if check_vma is not None and _REPLICATION_CHECK_KW:
-        kw[_REPLICATION_CHECK_KW] = check_vma
-    return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, **kw)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 def named_sharding(mesh: Mesh, *spec) -> NamedSharding:

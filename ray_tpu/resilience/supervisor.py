@@ -78,7 +78,7 @@ class GangSupervisor:
 
     On the first DEAD member: records the failure (cause + host) to the
     conductor's resilience log and kills every surviving member so the
-    driver's blocking ``get`` fails fast instead of waiting out a wedged
+    driver's blocking ``get`` fails fast instead of waiting out a hung
     collective. The kills go through ``kill_actor`` and are therefore
     *expected* deaths — only the original casualty charges the failure
     domain tracker.

@@ -307,6 +307,45 @@ def _call(target: Any, method: str, *args, block: bool = True, **kw):
     return fn(*args, **kw)
 
 
+def own_params(params: Any) -> Any:
+    """`params` is the parameter pytree, or a zero-argument callable that
+    builds it here, in the replica's own process: actor replicas then
+    make their weights from a seed on their own chip, and the driver that
+    creates them neither ships the weights nor touches JAX (a driver
+    that has touched JAX holds every chip its replicas need)."""
+    return params() if callable(params) else params
+
+
+def device_record() -> Dict[str, Any]:
+    """Where this process computes, as JAX reports it. An actor replica
+    created without num_tpus runs on a CPU-pinned worker and nothing
+    else says so: describe() and stats() carry this, the router surfaces
+    it, and chip_smoke.py asserts on it. Chips are renumbered from 0
+    inside a bound process, so `visible_chips` (the conductor's binding)
+    and `pid` are what tell two replicas apart."""
+    import jax
+
+    dev = jax.devices()[0]  # the default device: engines place nothing
+    return {"platform": dev.platform, "device_kind": dev.device_kind,
+            "device_id": int(dev.id),
+            "local_devices": len(jax.local_devices()),
+            "visible_chips": os.environ.get("TPU_VISIBLE_CHIPS"),
+            "pid": os.getpid()}
+
+
+def runtime_record() -> Dict[str, Any]:
+    """What this replica's process has compiled and how much device
+    memory it holds (peak where the backend reports it): set-up cost and
+    head-room a caller budgets with, read through stats()."""
+    import jax
+
+    from ray_tpu.util.compile_cache import compile_cache_counts
+
+    mem = jax.devices()[0].memory_stats() or {}
+    return {"compile_cache": compile_cache_counts(),
+            "peak_bytes_in_use": mem.get("peak_bytes_in_use")}
+
+
 # ------------------------------------------------------------ prefill tier
 
 class PrefillServer:
@@ -345,11 +384,12 @@ class PrefillServer:
 
         from .lora import build_pool
 
-        self.params = params
+        self.params = params = own_params(params)
         self.config = config
         self.server_id = server_id or \
             f"pf-{os.getpid()}-{next(_SERVER_SEQ)}"
         self.machine = local_machine_id()
+        self.device = device_record()
         # scripted fault injection (resilience/chaos.py kill_replica):
         # meaningful on ACTOR replicas — the fire is an os._exit
         self._chaos = serve_monkey_from_spec(chaos, "prefill",
@@ -696,7 +736,7 @@ class PrefillServer:
         """Registration record for a router: identity + host (the
         decode-side placement-affinity input)."""
         return {"server_id": self.server_id, "role": "prefill",
-                "machine": self.machine,
+                "machine": self.machine, "device": self.device,
                 "lora": self.lora_pool is not None,
                 "kvplane": self.kvplane,
                 # the router computes directory digests with OUR block
@@ -782,6 +822,8 @@ class PrefillServer:
             s["held_transfers"] = len(self._held)
         s["role"] = "prefill"
         s["server_id"] = self.server_id
+        s["device"] = self.device
+        s["runtime"] = runtime_record()
         if self.kv_cache is not None:
             s["prefix_cache"] = self.kv_cache.stats()
         if self.lora_pool is not None:
@@ -888,12 +930,13 @@ class DecodeServer:
                                     rank_max=lora_rank_max)
         if self.lora_pool is not None:
             engine_kw.setdefault("lora_pool", self.lora_pool)
-        self.engine = ContinuousBatchingEngine(params, config,
+        self.engine = ContinuousBatchingEngine(own_params(params), config,
                                                max_batch=max_batch,
                                                **engine_kw)
         self.server_id = server_id or \
             f"dec-{os.getpid()}-{next(_SERVER_SEQ)}"
         self.machine = local_machine_id()
+        self.device = device_record()
         self._chaos = serve_monkey_from_spec(chaos, "decode",
                                              chaos_replica)
         self._lock = threading.Lock()
@@ -1124,17 +1167,14 @@ class DecodeServer:
         stay flat on a pure decode replica (0 when it runs alone)."""
         from ray_tpu.models.engine import _prefill_paged
 
-        try:
-            return _prefill_paged._cache_size()
-        except Exception:  # noqa: BLE001 — older jax without _cache_size
-            return -1
+        return _prefill_paged._cache_size()
 
     def describe(self) -> Dict[str, Any]:
         """Registration record for a router: identity, capacity, host
         (the decode-side placement-affinity anchor)."""
         return {"server_id": self.server_id, "role": "decode",
                 "capacity": self.engine.max_batch,
-                "machine": self.machine,
+                "machine": self.machine, "device": self.device,
                 "lora": self.lora_pool is not None}
 
     def publish_adapter(self, tenant: str,
@@ -1174,6 +1214,7 @@ class DecodeServer:
         with self._lock:
             s: Dict[str, Any] = dict(self._stats)
         s.update(role="decode", server_id=self.server_id,
+                 device=self.device, runtime=runtime_record(),
                  capacity=self.engine.max_batch,
                  free_slots=self.engine.free_slots,
                  adopted=self.engine.adopted,
@@ -1211,10 +1252,11 @@ class _TierReplica:
     bookkeeping."""
 
     __slots__ = ("target", "rid", "cap", "inflight", "draining",
-                 "machine", "lora")
+                 "machine", "lora", "device")
 
     def __init__(self, target: Any, rid: str, cap: int,
-                 machine: Optional[str] = None, lora: bool = False):
+                 machine: Optional[str] = None, lora: bool = False,
+                 device: Optional[Dict[str, Any]] = None):
         self.target = target
         self.rid = rid
         self.cap = int(cap)
@@ -1222,11 +1264,13 @@ class _TierReplica:
         self.draining = False
         self.machine = machine
         self.lora = bool(lora)
+        # the replica's own device_record(): where it really computes
+        self.device = device
 
     def snapshot(self) -> Dict[str, Any]:
         return {"rid": self.rid, "target": self.target, "cap": self.cap,
                 "inflight": self.inflight, "draining": self.draining,
-                "machine": self.machine}
+                "machine": self.machine, "device": self.device}
 
 
 # cache-outcome weights for the router's recent hit-rate signal: a full
@@ -1419,7 +1463,7 @@ class DisaggRouter:
             if bs:
                 self._kv_block_size = int(bs)
         return _TierReplica(target, rid, cap, info.get("machine"),
-                            bool(info.get("lora")))
+                            bool(info.get("lora")), info.get("device"))
 
     def _push_retention_hint(self) -> None:
         """Every admissible request can be in flight at once and
@@ -2764,7 +2808,11 @@ class DisaggRouter:
                      1 for r in decode + prefill if r.draining),
                  capacity=sum(r.cap for r in decode if not r.draining),
                  max_queue_depth=self.max_queue_depth,
-                 retry_after_s=self.retry_after_s)
+                 retry_after_s=self.retry_after_s,
+                 replica_devices={
+                     tier: {r.rid: r.device for r in reps}
+                     for tier, reps in (("prefill", prefill),
+                                        ("decode", decode))})
         if s["shm_affinity_total"]:
             s["shm_affinity_hit_rate"] = round(
                 s["shm_affinity_hits"] / s["shm_affinity_total"], 4)

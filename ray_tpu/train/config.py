@@ -13,10 +13,12 @@ from typing import Any, Dict, Optional
 @dataclass
 class ScalingConfig:
     """Reference air/config.py ScalingConfig: num_workers + resources.
-    Here: worker processes for host-side work; chips belong to the mesh."""
+    Here: worker processes for host-side work; chips belong to the mesh.
+    A mode="workers" rank computes on the CPU unless
+    resources_per_worker asks for chips ({"TPU": n}): only a lease with a
+    TPU resource is bound to one (conductor chip workers)."""
 
     num_workers: int = 1
-    use_tpu: bool = True
     resources_per_worker: Dict[str, float] = field(default_factory=dict)
     # mode="workers": rendezvous the gang into one jax.distributed job
     # BEFORE train_fn runs (the reference does process-group setup for

@@ -194,7 +194,7 @@ def report(metrics: Dict[str, Any],
             from .async_checkpoint import expedite_all
 
             expedite_all()
-            # bounded by the broadcast's own deadline: a wedged writer
+            # bounded by the broadcast's own deadline: a stuck writer
             # must not pin the worker in report() past the grace window
             # it was trying to beat (then the gang would die mid-wait
             # with nothing committed AND nothing else attempted)

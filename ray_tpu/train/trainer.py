@@ -193,9 +193,15 @@ class TrainStep:
         # AOT-compiled executable (jit.lower().compile()): built at first
         # execution when a flight-recorder session is active, both to
         # time compilation explicitly and to read XLA's cost_analysis
-        # FLOPs for MFU. Falls back to the plain jit cache on any
-        # backend that rejects the AOT path.
+        # FLOPs for MFU.
         self._compiled = None
+
+    @property
+    def compiled(self):
+        """The AOT-compiled step (`jax.stages.Compiled`: as_text(),
+        cost_analysis()), or None before the first call made under a
+        session whose step telemetry is on."""
+        return self._compiled
 
     def init_state(self, params: Any) -> Dict[str, Any]:
         """Shard params onto the mesh and build optimizer state with
@@ -221,7 +227,7 @@ class TrainStep:
             warnings.warn("shardlint: " + format_report(findings),
                           stacklevel=2)
         params = jax.device_put(params, self._shardings(self.param_specs))
-        with self.mesh:
+        with jax.set_mesh(self.mesh):
             opt_state = jax.jit(
                 self.optimizer.init,
                 in_shardings=(self._shardings(self.param_specs),))(params)
@@ -265,7 +271,7 @@ class TrainStep:
                 self._instrument(timer, state, batch)
                 timer.record("compile", time.perf_counter() - t0)
             t0 = time.perf_counter()
-        with self.mesh:
+        with jax.set_mesh(self.mesh):
             if self._compiled is not None:
                 try:
                     out = self._compiled(state, batch)
@@ -298,24 +304,17 @@ class TrainStep:
         tokens-per-step + the mesh's aggregate peak FLOPs for MFU."""
         from ray_tpu.observability import flops as _flops
 
-        try:
-            with self.mesh:
-                self._compiled = self._jitted.lower(state, batch).compile()
-            per_device = _flops.compiled_flops(self._compiled)
-            if per_device:
-                # cost_analysis reports the PER-DEVICE partitioned
-                # program; the MFU denominator aggregates peak over the
-                # whole mesh, so scale the numerator to match (verified:
-                # an 8-way sharded matmul reports 1/8th the flops)
-                timer.set_flops_per_step(
-                    per_device * int(self.mesh.devices.size))
-        except Exception:  # noqa: BLE001 — backend without AOT support
-            self._compiled = None
-        try:
-            timer.set_peak_flops(
-                _flops.total_peak_flops(self.mesh.devices))
-        except Exception:  # noqa: BLE001 — exotic device objects
-            pass
+        with jax.set_mesh(self.mesh):
+            self._compiled = self._jitted.lower(state, batch).compile()
+        per_device = _flops.compiled_flops(self._compiled)
+        if per_device:
+            # cost_analysis reports the PER-DEVICE partitioned
+            # program; the MFU denominator aggregates peak over the
+            # whole mesh, so scale the numerator to match (verified:
+            # an 8-way sharded matmul reports 1/8th the flops)
+            timer.set_flops_per_step(
+                per_device * int(self.mesh.devices.size))
+        timer.set_peak_flops(_flops.total_peak_flops(self.mesh.devices))
         tokens = _batch_tokens(batch)
         if tokens:
             timer.set_tokens_per_step(tokens)

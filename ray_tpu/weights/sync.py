@@ -200,13 +200,13 @@ class WeightSync:
                                                         version=latest)
                     if applied is not None and \
                             not applied.wait(timeout=60.0):
-                        # swap queued but not applied (wedged or stopped
+                        # swap queued but not applied (stuck or stopped
                         # decode loop): surface through the except path
                         # — status/staleness must keep telling the
                         # truth, not record the version as served
                         raise RuntimeError(
                             f"swap to v{latest} not applied within 60s "
-                            "(decode loop wedged or engine stopped)")
+                            "(decode loop stuck or engine stopped)")
                     # re-point the reshard template at the weights now
                     # being served (same shapes/dtypes/shardings):
                     # keeping the ORIGINAL params alive as the template

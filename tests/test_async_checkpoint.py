@@ -51,7 +51,7 @@ def test_save_restore_roundtrip_numpy(tmp_path):
 
 
 def test_restore_onto_different_mesh_bit_exact(tmp_path):
-    """dp=2,fsdp=4 -> dp=8: the VERDICT done-criterion."""
+    """dp=2,fsdp=4 -> dp=8: the review done-criterion."""
     mesh_a = _mesh([("dp", 2), ("fsdp", 4)])
     state = _sharded_state(mesh_a, SPECS, seed=3)
     ac.async_save(str(tmp_path / "ck"), state).wait()

@@ -26,9 +26,10 @@ def test_peak_flops_table():
         platform = "cpu"
 
     assert flops.device_peak_flops(FakeTpu()) == 197e12
-    # unknown TPU generations stay conservative (v4-class)
+    # an unknown TPU generation is an error, not an assumed peak
     FakeTpu.device_kind = "TPU v9x"
-    assert flops.device_peak_flops(FakeTpu()) == 275e12
+    with pytest.raises(ValueError, match="TPU v9x"):
+        flops.device_peak_flops(FakeTpu())
     # non-TPU backends get the documented nominal constant (nonzero so
     # off-silicon MFU series stay meaningful)
     assert flops.device_peak_flops(FakeCpu()) == \

@@ -56,9 +56,9 @@ def test_device_link_constants_prefix_match():
 
     assert roofline.device_link_constants(Fake()) == \
         roofline.LINK_CONSTANTS["TPU v5 lite"]
-    Fake.device_kind = "TPU v9x"  # unknown TPU: conservative v4-class
-    assert roofline.device_link_constants(Fake()) == \
-        roofline.LINK_CONSTANTS["TPU v4"]
+    Fake.device_kind = "TPU v9x"  # unknown TPU: an error, not a default
+    with pytest.raises(ValueError, match="TPU v9x"):
+        roofline.device_link_constants(Fake())
     Fake.device_kind, Fake.platform = "cpu", "cpu"
     assert roofline.device_link_constants(Fake()) == \
         roofline.NOMINAL_LINK_CONSTANTS["cpu"]
@@ -139,12 +139,12 @@ def test_unmodeled_collective_is_named_not_absorbed():
 
 
 def test_checkrep_psum_trace_stays_modeled():
-    """jax 0.4.x traces psum as `psum2` and inserts zero-payload
-    `pbroadcast` markers under check_rep: the former must be priced
-    like psum, the latter never collected — a plain psum trace must not
-    flag the model's own core primitive as unmodeled."""
+    """Under check_vma a psum inside shard_map traces as
+    `psum_invariant` with zero-payload `pvary` markers: the former must
+    be priced like psum, the latter never collected — a plain psum trace
+    must not flag the model's own core primitive as unmodeled."""
     import jax
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from ray_tpu.analysis.collectives import (abstract_mesh,
@@ -155,17 +155,15 @@ def test_checkrep_psum_trace_stays_modeled():
     layout = MeshLayout({"dp": 8}, {"dp": 2}, name="t",
                         declared_dcn=True)
     mesh = abstract_mesh(layout)
-    if mesh is None:
-        pytest.skip("this jax has no AbstractMesh")
     fn = shard_map(lambda x: x * jax.lax.psum(x, "dp"), mesh=mesh,
                    in_specs=P("dp"), out_specs=P("dp"))
     uses = scan_collectives(fn, jax.ShapeDtypeStruct((64,), "float32"))
     assert uses and all(u.modeled() for u in uses)
-    assert not any(u.primitive in ("pbroadcast", "pvary") for u in uses)
+    assert not any(u.primitive == "pvary" for u in uses)
     findings = check_collectives(layout, uses)
     assert not [f for f in findings
                 if f.rule == "unmodeled-collective"]
-    # psum2 is priced exactly like psum (ring allreduce)
+    # psum_invariant is priced exactly like psum (ring allreduce)
     psum_like = next(u for u in uses if u.primitive.startswith("psum"))
     assert psum_like.dcn_bytes(layout) == pytest.approx(
         2.0 * psum_like.in_bytes * (2 - 1) / 2)

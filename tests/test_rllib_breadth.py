@@ -1,6 +1,6 @@
 """APPO, DQN, and multi-agent env runner (reference rllib/algorithms/
 appo/, rllib/algorithms/dqn/, rllib/env/multi_agent_env_runner.py) —
-the VERDICT r2 breadth items."""
+the round-2 breadth items."""
 from __future__ import annotations
 
 import jax
@@ -151,7 +151,7 @@ def test_multi_agent_mismatched_spaces_rejected():
 
 
 def test_multi_agent_two_policies_learn_smoke():
-    """2-policy smoke (VERDICT done-criterion): both policies improve on
+    """2-policy smoke (review done-criterion): both policies improve on
     independent CartPoles."""
     algo = MultiAgentPPO(
         "MultiAgentCartPole", num_envs=16, rollout_fragment_length=64,

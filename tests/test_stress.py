@@ -64,7 +64,7 @@ def test_deep_nested_task_tree(cluster):
 
 def test_object_churn_stays_flat(cluster):
     """Sustained put/get churn must not grow the store (distributed
-    refcounting done-criterion, VERDICT r2 item 3)."""
+    refcounting done-criterion, round-2 item 3)."""
     from ray_tpu._private.worker import global_worker
 
     payload = np.zeros(200_000, np.uint8)  # 200KB -> shm path
