@@ -44,9 +44,8 @@ import time
 REF_TOKENS_PER_SEC_PER_CHIP = 140_000.0
 
 # Child exit code for a measurement the bench itself declared invalid
-# (implied-MFU over chip peak, unstable timing). The supervisor must fail
-# loudly on this — a CPU-fallback "success" would silently swallow the
-# validity guard.
+# (implied-MFU over chip peak, unstable timing). The supervisor exits
+# non-zero on it, as on any other failed stage.
 INVALID_MEASUREMENT_RC = 3
 
 def _chip_peak(device) -> float:

@@ -21,8 +21,10 @@ child that hosts the gateway and the router is held to the CPU.
 
 It fails (exit code not 0, the reason on the last line, no result) when
 JAX finds no TPU, when a phase raises, fails an assertion or runs out of
-time. On success the last stdout line is one JSON object, also written to
-chiprun_out/chip_smoke.json. Wall times in it are for budgeting chip calls
+time. On success the last stdout line is the result, one JSON object with
+exactly `ok` and `device` (platform, kind, count as JAX reports them). What
+the phases recorded goes to the line before it and to
+chiprun_out/chip_smoke.json. Wall times there are for budgeting chip calls
 and are no metric.
 """
 from __future__ import annotations
@@ -583,6 +585,16 @@ def _run_phase(phase: str, actors: bool = False) -> Dict[str, Any]:
     return record
 
 
+def result_record(device: Dict[str, Any]) -> Dict[str, Any]:
+    """What the last stdout line says once every phase has passed: these
+    keys and no others. `device` is the train child's view, the process
+    that held every chip."""
+    return {"ok": True,
+            "device": {"platform": str(device["platform"]),
+                       "kind": str(device["kind"]),
+                       "count": int(device["count"])}}
+
+
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--phase", choices=sorted(PHASE_TIMEOUT_S),
@@ -612,9 +624,9 @@ def main() -> int:
         return 1
     cache_dir = train.pop("cache_dir")
     serve.pop("cache_dir")
+    result = result_record(device)
     summary = {
-        "ok": True,
-        "device": {k: device[k] for k in ("platform", "kind", "count")},
+        **result,
         "jax": device["jax"],
         "phases": {"train": train, "serve": serve},
         "serve_driver_platform": serve_device["platform"],
@@ -625,7 +637,9 @@ def main() -> int:
     line = json.dumps(summary)
     with open(os.path.join(OUT_DIR, "chip_smoke.json"), "w") as f:
         f.write(line + "\n")
-    print(line, flush=True)
+    print(f"chip_smoke: record {line}", flush=True)
+    # the last line is the result and nothing but the result
+    print(json.dumps(result), flush=True)
     return 0
 
 

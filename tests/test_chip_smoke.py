@@ -6,6 +6,7 @@ compiled step) is the script's own job, through the chip tool."""
 from __future__ import annotations
 
 import dataclasses
+import json
 import os
 import subprocess
 import sys
@@ -35,6 +36,34 @@ def test_refuses_to_run_without_a_tpu():
     last = p.stdout.strip().splitlines()[-1]
     assert last.startswith("chip_smoke FAILED") and "'cpu'" in last
     assert '"ok": true' not in p.stdout
+
+
+def test_last_line_is_the_result_and_nothing_else(monkeypatch, capsys,
+                                                  tmp_path):
+    """With both phases passed, the last stdout line is a JSON object with
+    exactly `ok` and `device` {platform, kind, count}; what the phases
+    recorded is on the line before it and in chip_smoke.json."""
+    facts = {"platform": "tpu", "kind": "TPU v5 lite", "count": 1,
+             "jax": jax.__version__}
+
+    def fake_phase(phase, actors=False):
+        rec = {"ok": True, "device": dict(facts), "cache_dir": "/c",
+               "wall_s": 1.0}
+        return dict(rec, object_store="native-arena") \
+            if phase == "serve" else rec
+
+    monkeypatch.setattr(chip_smoke, "_run_phase", fake_phase)
+    monkeypatch.setattr(chip_smoke, "OUT_DIR", str(tmp_path))
+    monkeypatch.setattr(sys, "argv", ["chip_smoke.py"])
+    assert chip_smoke.main() == 0
+    lines = capsys.readouterr().out.strip().splitlines()
+    assert json.loads(lines[-1]) == {
+        "ok": True,
+        "device": {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}}
+    record = json.loads(lines[-2].split("chip_smoke: record ", 1)[1])
+    assert record == json.loads((tmp_path / "chip_smoke.json").read_text())
+    assert record["claim"] is None and set(record["phases"]) == {
+        "train", "serve"}
 
 
 def test_train_phase_tiny_interpret(tmp_path):
