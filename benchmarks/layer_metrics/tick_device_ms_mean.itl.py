@@ -1,0 +1,6 @@
+"""Mean device duration of the `_tick` program in the traced window."""
+from benchmarks.harness.readers import program_mean_ms
+
+
+def read(obs):
+    return program_mean_ms(obs, "_tick")
