@@ -65,6 +65,41 @@ Surfaces: ``util.state.speculation_stats()``, ``ray_tpu speculate``,
 ``ray_tpu_spec_acceptance_rate``), and spec_accept / spec_reject
 instant markers in the merged timeline's kvcache lane.
 
+The loop keeps a clock of its own. While the per-request flight
+recorder is on (``RAY_TPU_REQTRACE``, observability/requests.py), every
+pass of ``_loop`` that had a live slot or admitted something leaves ONE
+record in the recorder's process-local store
+(``reqtrace.store().loop_records()``; a bounded ring, nothing is pushed
+to the conductor), all from ``time.perf_counter()`` on this thread:
+``engine_id``; ``ts`` (``time.time()`` at the top of the pass); ``live``
+(slots decoding at the top, before admission) and ``max_batch``;
+``pending`` (requests waiting in ``_pending``); ``admit_ms`` (inside
+``_admit``; 0 where nothing was admitted or adopted); ``admissions``,
+one entry per request admitted in the pass (``rid``, ``prompt_tokens``,
+``suffix_tokens``, ``reused_tokens`` and the self times ``lookup_ms``
+(lookup + gather), ``prefill_ms`` (the ``_prefill_paged`` call and the
+read-back of its logits, the commit between them taken out),
+``commit_ms`` with ``commit_dispatches`` (programs the pool commit
+launched: each block's extract and its write or copy-on-write) and
+``commit_blocks``, ``splice_ms``; an adoption has ``prefill_ms`` 0);
+``dispatch_ms`` (from the end of ``_admit`` to the return of the tick
+call: two uploads and the dispatch; the speculative tick counts from
+its own start, its drafting is bookkeeping); ``readback_ms`` (the
+tokens and log-probabilities read back: BLOCKED on the device, so not
+host work); ``emit_ms`` (the walk over the slots: emit, finish, queue
+puts); ``total_ms`` (the whole pass; what the parts leave is
+bookkeeping: swap, cancels, drafting, telemetry push). A request
+carries three stamps of the same clock (``submit()`` returns, ``_admit``
+pops it, ``_emit`` puts its first token) and ``TokenStream`` exposes
+their differences as ``queue_ms`` and ``prefill_ms``, which the router
+hands to the flight recorder as the two parts of
+``decode_first_token``. The same boundaries are
+``util.profiling.annotate`` spans on this thread (``engine.admit``,
+``engine.prefill``, ``engine.pool_commit``, ``engine.splice``,
+``engine.tick_dispatch``, ``engine.tick_readback``, ``engine.emit``),
+so a ``jax.profiler`` session shows them beside the device's programs.
+With the recorder off the loop reads no clock and builds no record.
+
 Per-request token queues make it the natural producer for Serve's
 streaming path; `ContinuousBatchingEngine` is thread-safe for
 concurrent submit/iterate from replica request threads. The streamed
@@ -86,12 +121,22 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ray_tpu.observability import requests as reqtrace
+from ray_tpu.util.profiling import annotate, name_thread
+
 from .generate import _model_fns, merge_lora_params
 from .kvcache import PagedKVCache, resolve_pool_config
 
 _DONE = object()
 _ENGINE_SEQ = itertools.count()
 _SPEC_EVENTS_KEPT = 512
+# the loop's one clock; tests swap it to count the reads
+_now = time.perf_counter
+
+
+def _clock(rec: Optional[dict]) -> float:
+    """A reading for the loop record `rec`; none is taken without one."""
+    return _now() if rec is not None else 0.0
 
 
 def default_speculate_k() -> int:
@@ -188,7 +233,8 @@ def _prefill_paged_lora(params, suffix, config, prefix_k, prefix_v,
 
 
 def _prefill_with_cache(params, config, kv_cache, prompt, empty_prefix,
-                        event_extra=None, adapter=None, namespace=None):
+                        event_extra=None, adapter=None, namespace=None,
+                        parts=None):
     """The prefill-behind-the-prefix-cache sequence shared by the
     colocated engine's `_admit_one` and the disagg `PrefillServer`:
     lookup → gather → `_prefill_paged` on the suffix → commit +
@@ -201,10 +247,16 @@ def _prefill_with_cache(params, config, kv_cache, prompt, empty_prefix,
     `adapter`/`namespace` (multi-tenant LoRA, serve/lora.py): prefill
     under one tenant's adapter slice, with the prefix cache keyed by
     (namespace, prompt) so one tenant's KV can never match
-    another's."""
+    another's.
+
+    `parts`: a dict the engine's loop record wants filled with this
+    admission's self times and commit counts (module docstring); None
+    reads no clock. The profiler spans are there either way."""
     plen = prompt.shape[1]
     prompt_np = prompt[0]
+    rid = (event_extra or {}).get("rid", -1)
     outcome, reused = "miss", 0
+    t0 = _clock(parts)
     if kv_cache is not None:
         match = kv_cache.lookup(prompt_np, max_tokens=plen - 1,
                                 namespace=namespace)
@@ -215,27 +267,44 @@ def _prefill_with_cache(params, config, kv_cache, prompt, empty_prefix,
         prefix_k = prefix_v = empty_prefix
     cached = int(prefix_k.shape[1])
     suffix = prompt[:, cached:]
-    if adapter is not None:
-        last_logits, ck, cv = _prefill_paged_lora(
-            params, suffix, config, prefix_k, prefix_v, adapter)
-    else:
-        last_logits, ck, cv = _prefill_paged(params, suffix, config,
-                                             prefix_k, prefix_v)
-    table: List[Any] = []
-    if kv_cache is not None:
-        kv_cache.note_prefilled(suffix.shape[1])
-        table = kv_cache.commit(prompt_np, ck, cv, match,
-                                namespace=namespace)
-        if match.tokens:
-            event = {"kind": "prefix_hit", "outcome": outcome,
-                     "reused_tokens": reused, "prompt_tokens": plen}
-            if event_extra:
-                event.update(event_extra)
-            kv_cache.record_event(event)
-    live = np.asarray(last_logits[0, :config.vocab_size], np.float32)
+    t1 = t2 = t3 = _clock(parts)
+    # engine.prefill runs to the read-back of the logits, the commit
+    # nested in it: the device works on the prefill while the host
+    # dispatches the commit's programs, so the span's self time is
+    # the prefill's
+    with annotate("engine.prefill", rid=rid, prompt_tokens=plen):
+        if adapter is not None:
+            last_logits, ck, cv = _prefill_paged_lora(
+                params, suffix, config, prefix_k, prefix_v, adapter)
+        else:
+            last_logits, ck, cv = _prefill_paged(params, suffix, config,
+                                                 prefix_k, prefix_v)
+        table: List[Any] = []
+        if kv_cache is not None:
+            kv_cache.note_prefilled(suffix.shape[1])
+            if parts is not None:
+                t2 = _now()
+            with annotate("engine.pool_commit", rid=rid):
+                table = kv_cache.commit(prompt_np, ck, cv, match,
+                                        namespace=namespace)
+            if parts is not None:
+                t3 = _now()
+                parts["commit_dispatches"], parts["commit_blocks"] = \
+                    kv_cache.last_commit
+            if match.tokens:
+                event = {"kind": "prefix_hit", "outcome": outcome,
+                         "reused_tokens": reused, "prompt_tokens": plen}
+                if event_extra:
+                    event.update(event_extra)
+                kv_cache.record_event(event)
+        live = np.asarray(last_logits[0, :config.vocab_size], np.float32)
     first = int(np.argmax(live))
     m = float(live[first])
     score = -float(np.log(np.exp(live - m).sum()))  # m - logsumexp
+    if parts is not None:
+        commit_ms = (t3 - t2) * 1e3
+        parts.update(lookup_ms=(t1 - t0) * 1e3, commit_ms=commit_ms,
+                     prefill_ms=(_now() - t1) * 1e3 - commit_ms)
     return (ck, cv, table, first, score, outcome, int(reused),
             int(suffix.shape[1]))
 
@@ -368,13 +437,22 @@ class _Request:
         self.cancelled = False
         self.cancel_reason: Optional[str] = None
         self.finished = False
+        # the engine's clock (module docstring): submit() or
+        # adopt_prefill() returns, _admit pops the request, _emit puts
+        # its first token; None while the flight recorder is off
+        self.t_submit: Optional[float] = None
+        self.t_admit: Optional[float] = None
+        self.t_first: Optional[float] = None
 
 
 class TokenStream:
     """Iterator over one request's tokens with the prefix-cache outcome
     attached (``cache_outcome``: hit|partial|miss, None until the
     request is admitted — always set before the first token arrives).
-    Serve's streaming replica reads it to label the TTFT histogram."""
+    Serve's streaming replica reads it to label the TTFT histogram.
+    ``queue_ms`` / ``prefill_ms`` split the first token's wait on the
+    engine's own clock, set before the first token arrives too (None
+    with the flight recorder off)."""
 
     def __init__(self, req: _Request, timeout_s: float):
         self._req = req
@@ -396,6 +474,24 @@ class TokenStream:
     @property
     def reused_tokens(self) -> int:
         return self._req.reused_tokens
+
+    @property
+    def queue_ms(self) -> Optional[float]:
+        """From submit()'s return to the pop in `_admit`: the wait for
+        a tick boundary, a free slot and the prefills ahead."""
+        r = self._req
+        if r.t_submit is None or r.t_admit is None:
+            return None
+        return (r.t_admit - r.t_submit) * 1e3
+
+    @property
+    def prefill_ms(self) -> Optional[float]:
+        """From that pop to the first token's `_emit`: this request's
+        own lookup, prefill, pool commit and splice."""
+        r = self._req
+        if r.t_admit is None or r.t_first is None:
+            return None
+        return (r.t_first - r.t_admit) * 1e3
 
     @property
     def scores(self) -> List[float]:
@@ -566,6 +662,8 @@ class ContinuousBatchingEngine:
         req.ctx_has_prompt = True
         req.adapter_id = adapter_id
         req.lora_slot = lora_slot
+        if reqtrace.enabled():
+            req.t_submit = _now()
         self._pending.put(req)
         return req
 
@@ -661,6 +759,8 @@ class ContinuousBatchingEngine:
         req.reused_tokens = int(reused_tokens)
         req.adapter_id = adapter_id
         req.lora_slot = lora_slot
+        if reqtrace.enabled():
+            req.t_submit = _now()
         self._pending_adopt.put(_Adoption(req, plen, ck, cv,
                                           first_token, score))
         return TokenStream(req, timeout_s)
@@ -853,17 +953,18 @@ class ContinuousBatchingEngine:
             pass
 
     # ------------------------------------------------------- admission
-    def _admit(self) -> None:
+    def _admit(self, it: Optional[Dict[str, Any]] = None) -> None:
         # adoptions first (disaggregated decode: splices, no prefill
         # program), then prefill admissions — each against its own
-        # per-phase cap so the counters stay truthful in both modes
+        # per-phase cap so the counters stay truthful in both modes.
+        # `it` is the pass's loop record (None: flight recorder off).
         adopted = 0
         while self._free and adopted < self.max_adoptions_per_tick:
             try:
                 adoption = self._pending_adopt.get_nowait()
             except queue.Empty:
                 break
-            if self._adopt_one(adoption):
+            if self._adopt_one(adoption, it):
                 adopted += 1
         admitted = 0
         while self._free and admitted < self.max_prefills_per_tick:
@@ -871,7 +972,7 @@ class ContinuousBatchingEngine:
                 req = self._pending.get_nowait()
             except queue.Empty:
                 break
-            if self._admit_one(req):
+            if self._admit_one(req, it):
                 admitted += 1
         if adopted:
             self.max_adoptions_admitted_per_tick = max(
@@ -882,19 +983,47 @@ class ContinuousBatchingEngine:
         if adopted or admitted:
             self.publish_kv_telemetry()
 
-    def _adopt_one(self, adoption: _Adoption) -> bool:
+    def _splice(self, ck, cv, slot: int, plen: int,
+                entry: Optional[Dict[str, Any]]) -> None:
+        """Both admission paths' write into the decode slab."""
+        t0 = _clock(entry)
+        with annotate("engine.splice"):
+            self._cache = _splice_slot(self._cache, ck, cv,
+                                       np.int32(slot), self.config, plen)
+        if entry is not None:
+            entry["splice_ms"] = (_now() - t0) * 1e3
+        self.spliced_tokens += plen
+
+    @staticmethod
+    def _admission(it: Optional[Dict[str, Any]], req: _Request,
+                   plen: int) -> Optional[Dict[str, Any]]:
+        """Open `req`'s entry in the pass's record and stamp the pop."""
+        if it is None:
+            return None
+        req.t_admit = _now()
+        entry = {"rid": req.rid, "prompt_tokens": plen,
+                 "suffix_tokens": 0, "reused_tokens": 0,
+                 "lookup_ms": 0.0, "prefill_ms": 0.0, "commit_ms": 0.0,
+                 "commit_dispatches": 0, "commit_blocks": 0,
+                 "splice_ms": 0.0}
+        it["admissions"].append(entry)
+        return entry
+
+    def _adopt_one(self, adoption: _Adoption,
+                   it: Optional[Dict[str, Any]] = None) -> bool:
         req = adoption.req
         if req.cancelled:
             # cancelled before admission: never occupies a slot
             self._count_cancel(req)
             self._finish(req)
             return False
+        plen = adoption.plen
+        entry = self._admission(it, req, plen)
+        if entry is not None:
+            entry["reused_tokens"] = req.reused_tokens
         with self._lock:
             slot = self._free.pop()
-        plen = adoption.plen
-        self._cache = _splice_slot(self._cache, adoption.ck, adoption.cv,
-                                   np.int32(slot), self.config, plen)
-        self.spliced_tokens += plen
+        self._splice(adoption.ck, adoption.cv, slot, plen, entry)
         self.admitted += 1
         self.adopted += 1
         req.slot = slot
@@ -905,15 +1034,17 @@ class ContinuousBatchingEngine:
         self._emit(req, adoption.first_token, adoption.score)
         return True
 
-    def _admit_one(self, req: _Request) -> bool:
+    def _admit_one(self, req: _Request,
+                   it: Optional[Dict[str, Any]] = None) -> bool:
         if req.cancelled:
             # cancelled before admission: never occupies a slot
             self._count_cancel(req)
             self._finish(req)
             return False
+        plen = req.prompt.shape[1]
+        entry = self._admission(it, req, plen)
         with self._lock:
             slot = self._free.pop()
-        plen = req.prompt.shape[1]
         adapter = None
         namespace = None
         if self.lora_pool is not None and req.adapter_id is not None:
@@ -930,16 +1061,17 @@ class ContinuousBatchingEngine:
                                 req.prompt, self._empty_prefix,
                                 event_extra={"rid": req.rid},
                                 adapter=adapter,
-                                namespace=namespace)
+                                namespace=namespace, parts=entry)
+        if entry is not None:
+            entry["suffix_tokens"] = suffix_len
+            entry["reused_tokens"] = reused
         if self.kv_cache is not None:
             req.cache_outcome = outcome
             req.reused_tokens = reused
             req.block_table = table
         self.prefill_calls += 1
         self.prefilled_tokens += suffix_len
-        self._cache = _splice_slot(self._cache, ck, cv, np.int32(slot),
-                                   self.config, plen)
-        self.spliced_tokens += plen
+        self._splice(ck, cv, slot, plen, entry)
         self.admitted += 1
         self.prefill_admitted += 1
         req.slot = slot
@@ -986,6 +1118,8 @@ class ContinuousBatchingEngine:
     def _emit(self, req: _Request, tok: int, score: float = 0.0) -> None:
         req.ctx.append(int(tok))
         req.scores.append(score)
+        if req.t_first is None and req.t_admit is not None:
+            req.t_first = _now()
         req.out.put(tok)
         req.produced += 1
         if (req.eos_token is not None and tok == req.eos_token) \
@@ -1070,31 +1204,52 @@ class ContinuousBatchingEngine:
             out, self._spec_events = self._spec_events, []
         return out
 
-    def _spec_tick(self, drafts: Dict[int, List[int]],
-                   lora_live: bool) -> None:
-        """One widened verify tick: feed [last_token, draft...] per
-        slot, emit the greedy chain's longest agreement. Slots without
-        a draft pad by repeating their last token — their column-0
-        output is bit-identical to the plain tick's, so mixed batches
-        cost one program and zero correctness."""
-        k = self.speculate_k
-        toks = np.repeat(self._tokens[:, None], k + 1, axis=1)
+    def _run_tick(self, toks: np.ndarray, lora_live: bool,
+                  it: Optional[Dict[str, Any]], t0: float):
+        """Upload, dispatch and read back one decode step (`toks` [B]
+        or the speculative [B, k+1]); `t0` is where the pass's
+        dispatch time counts from. Returns the host copies of the
+        chosen tokens and their log-probabilities."""
+        with annotate("engine.tick_dispatch",
+                      live=self.max_batch - len(self._free)):
+            tok_dev = jnp.asarray(toks)
+            pos_dev = jnp.asarray(self._pos)
+            if lora_live:
+                cache, nxt, lp = self.lora_pool.dispatch_tick(
+                    lambda la: _tick_lora(
+                        self.params, self.config, self._cache, tok_dev,
+                        pos_dev, la),
+                    self._slot_adapter)
+            else:
+                cache, nxt, lp = _tick(
+                    self.params, self.config, self._cache, tok_dev,
+                    pos_dev)
+            self._cache = cache
+        if it is not None:
+            t1 = _now()
+            it["dispatch_ms"] = (t1 - t0) * 1e3
+        with annotate("engine.tick_readback"):
+            nxt_np = np.asarray(nxt)
+            lp_np = np.asarray(lp)
+        if it is not None:
+            it["readback_ms"] = (_now() - t1) * 1e3
+        return nxt_np, lp_np
+
+    def _spec_tokens(self, drafts: Dict[int, List[int]]) -> np.ndarray:
+        """The widened verify tick's input: [last_token, draft...] per
+        slot. Slots without a draft pad by repeating their last token —
+        their column-0 output is bit-identical to the plain tick's, so
+        mixed batches cost one program and zero correctness."""
+        toks = np.repeat(self._tokens[:, None], self.speculate_k + 1,
+                         axis=1)
         for slot, d in drafts.items():
             toks[slot, 1:1 + len(d)] = d
-        tok_dev = jnp.asarray(toks)
-        pos_dev = jnp.asarray(self._pos)
-        if lora_live:
-            cache, nxt, lp = self.lora_pool.dispatch_tick(
-                lambda la: _tick_lora(
-                    self.params, self.config, self._cache, tok_dev,
-                    pos_dev, la),
-                self._slot_adapter)
-        else:
-            cache, nxt, lp = _tick(
-                self.params, self.config, self._cache, tok_dev, pos_dev)
-        self._cache = cache
-        nxt_np = np.asarray(nxt)
-        lp_np = np.asarray(lp)
+        return toks
+
+    def _spec_emit(self, drafts: Dict[int, List[int]], nxt_np,
+                   lp_np) -> None:
+        """The verify tick's walk over the slots: emit the greedy
+        chain's longest agreement with each draft."""
         self.spec_verify_ticks += 1
         m = spec_metrics()
         for slot, req in enumerate(self._slot_req):
@@ -1143,11 +1298,32 @@ class ContinuousBatchingEngine:
     # ------------------------------------------------------------ loop
 
     def _loop(self) -> None:
+        name_thread(threading.current_thread().name)
         while not self._stopped.is_set():
+            # the pass's record (module docstring); None, and no clock
+            # read anywhere below, while the flight recorder is off
+            it: Optional[Dict[str, Any]] = None
+            if reqtrace.enabled():
+                t_top = _now()
+                it = {"engine_id": self.engine_id, "ts": time.time(),
+                      "live": self.max_batch - len(self._free),
+                      "max_batch": self.max_batch,
+                      "pending": self._pending.qsize(),
+                      "admit_ms": 0.0, "admissions": [],
+                      "dispatch_ms": 0.0, "readback_ms": 0.0,
+                      "emit_ms": 0.0, "total_ms": 0.0}
             self._apply_pending_swap()
             self._apply_cancels()
-            self._admit()
+            t_admit = _clock(it)
+            with annotate("engine.admit"):
+                self._admit(it)
+            t_tick = _clock(it)
+            if it is not None and it["admissions"]:
+                it["admit_ms"] = (t_tick - t_admit) * 1e3
             if all(r is None for r in self._slot_req):
+                if it is not None and (it["live"] or it["admissions"]):
+                    # every slot finished or was cancelled in this pass
+                    self._record_pass(it, t_top)
                 self._stopped.wait(self.idle_sleep_s)
                 continue
             lora_live = (self.lora_pool is not None
@@ -1155,26 +1331,29 @@ class ContinuousBatchingEngine:
             drafts = (self._collect_drafts() if self.speculate_k
                       else {})
             if drafts:
-                self._spec_tick(drafts, lora_live)
-                continue
-            if lora_live:
-                cache, nxt, lp = self.lora_pool.dispatch_tick(
-                    lambda la: _tick_lora(
-                        self.params, self.config, self._cache,
-                        jnp.asarray(self._tokens),
-                        jnp.asarray(self._pos), la),
-                    self._slot_adapter)
+                # the verify tick counts its dispatch from here: the
+                # drafting above is bookkeeping
+                t_tick, toks = _clock(it), self._spec_tokens(drafts)
             else:
-                cache, nxt, lp = _tick(
-                    self.params, self.config, self._cache,
-                    jnp.asarray(self._tokens), jnp.asarray(self._pos))
-            self._cache = cache
-            nxt_np = np.asarray(nxt)
-            lp_np = np.asarray(lp)
-            for slot, req in enumerate(self._slot_req):
-                if req is None:
-                    continue
-                self._pos[slot] += 1
-                tok = int(nxt_np[slot])
-                self._tokens[slot] = tok
-                self._emit(req, tok, float(lp_np[slot]))
+                toks = self._tokens
+            nxt_np, lp_np = self._run_tick(toks, lora_live, it, t_tick)
+            t_emit = _clock(it)
+            with annotate("engine.emit"):
+                if drafts:
+                    self._spec_emit(drafts, nxt_np, lp_np)
+                else:
+                    for slot, req in enumerate(self._slot_req):
+                        if req is None:
+                            continue
+                        self._pos[slot] += 1
+                        tok = int(nxt_np[slot])
+                        self._tokens[slot] = tok
+                        self._emit(req, tok, float(lp_np[slot]))
+            if it is not None:
+                it["emit_ms"] = (_now() - t_emit) * 1e3
+                self._record_pass(it, t_top)
+
+    @staticmethod
+    def _record_pass(it: Dict[str, Any], t_top: float) -> None:
+        it["total_ms"] = (_now() - t_top) * 1e3
+        reqtrace.store().record_loop(it)

@@ -446,6 +446,12 @@ class PagedKVCache:
         # historical single-tier behavior bit-identically
         self._arena: Optional[Any] = None
         self._events: List[Dict[str, Any]] = []
+        # programs launched by block writes, extracts and copy-on-write
+        # merges (one each), and what the latest commit() made of them:
+        # (dispatches, blocks inserted) — the engine's loop record reads
+        # it on the thread that committed
+        self._dispatches = 0
+        self.last_commit: Tuple[int, int] = (0, 0)
         self._stats: Dict[str, int] = {
             k: 0 for k in ("lookups", "hits", "partial_hits", "misses",
                            "reused_tokens", "prefilled_tokens",
@@ -804,6 +810,7 @@ class PagedKVCache:
     def _write_locked(self, bid: int, bk, bv) -> None:
         """One block write under the lock — the int8 pool quantizes on
         commit (donated, O(block) in place either way)."""
+        self._dispatches += 1
         if self.int8:
             (self._pool_k, self._pool_v, self._scale_k,
              self._scale_v) = _write_block_q(
@@ -817,6 +824,7 @@ class PagedKVCache:
                     filled_old: int) -> None:
         """Copy-on-write merge under the lock (int8: dequant the shared
         rows, merge, requantize the widened block)."""
+        self._dispatches += 1
         if self.int8:
             (self._pool_k, self._pool_v, self._scale_k,
              self._scale_v) = _cow_extend_block_q(
@@ -829,6 +837,11 @@ class PagedKVCache:
                 np.int32(src), bk, bv, np.int32(filled_old))
         self._stats["cow_copies"] += 1
         kvcache_metrics()["cow_copies"].inc()
+
+    def _extract_locked(self, ck, cv, start: int):
+        """One block's rows out of a prefill's fill, under the lock."""
+        self._dispatches += 1
+        return _extract_block(ck, cv, np.int32(start), self.block_size)
 
     def note_prefilled(self, n_tokens: int) -> None:
         with self._lock:
@@ -849,6 +862,7 @@ class PagedKVCache:
         plen = len(tokens)
         n_full, tail = divmod(plen, bs)
         with self._lock:
+            before = (self._dispatches, self._stats["inserted_blocks"])
             table = list(match.bids)
             digest = _ns_root(namespace)
             now = next(self._tick)
@@ -873,7 +887,7 @@ class PagedKVCache:
                 if bid is None:
                     exhausted = True
                     break
-                bk, bv = _extract_block(ck, cv, np.int32(i * bs), bs)
+                bk, bv = self._extract_locked(ck, cv, i * bs)
                 if (i == match.full_blocks
                         and match.partial_bid is not None):
                     # the matched SHARED partial block sits at this
@@ -893,6 +907,9 @@ class PagedKVCache:
                                          parent, n_full, tail, table,
                                          now, namespace)
             util = 1.0 - len(self._free) / self.num_blocks
+            self.last_commit = (
+                self._dispatches - before[0],
+                self._stats["inserted_blocks"] - before[1])
         kvcache_metrics()["utilization"].set(util)
         return table
 
@@ -925,7 +942,7 @@ class PagedKVCache:
         bid = self._alloc_locked()
         if bid is None:
             return
-        bk, bv = _extract_block(ck, cv, np.int32(n_full * bs), bs)
+        bk, bv = self._extract_locked(ck, cv, n_full * bs)
         if tail_partial is not None:
             # extending a SHARED cached block: copy-on-write — the old
             # entry stays indexed for future shorter matches

@@ -20,6 +20,22 @@ training StepTimer:
   the phase-sum-vs-wall invariant) — failover/preemption replays
   re-stamp the same phases tagged with their attempt number, child
   spans under the same request id;
+- a phase may carry **parts**: child spans measured by the layer below
+  on its own clock and handed over with the phase. ``decode_first_token``
+  has two, from the engine's thread (models/engine.py): ``engine_queue``
+  (``submit()`` to the pop in ``_admit``: the wait for a tick boundary
+  and for the prefills ahead) and ``engine_prefill`` (that pop to the
+  first token's ``_emit``: this request's own lookup, prefill, pool
+  commit and splice). Parts stay inside their phase's record — the flat
+  ``phases`` list gains no entry, so the phase-sum invariant never sees
+  them — and surface in ``phase_ms`` as ``<phase>.<part>``; their sum
+  never exceeds the phase (what is left of ``decode_first_token`` is
+  the wake-up of the router's thread on the stream's queue);
+- the store also holds the **engine loop ring**: one record per
+  iteration of every ``ContinuousBatchingEngine`` in the process
+  (``record_loop`` / ``loop_records``; fields in models/engine.py's
+  docstring), bounded at ``LOOP_RING_CAP`` records, under the same
+  switch and the same lock as the request summaries;
 - a completed trace lands in the process-local
   :class:`RequestTraceStore` under **tail-based retention**: every
   anomalous outcome (shed/error/deadline/disconnect/preempt/failover)
@@ -53,6 +69,7 @@ Knobs (all live-retunable through util/envknobs.py):
 """
 from __future__ import annotations
 
+import collections
 import random
 import threading
 import time
@@ -66,6 +83,10 @@ from typing import Any, Dict, Iterator, List, Optional
 PHASES = ("qos_admission", "queue_reserve", "prefill", "kv_transfer",
           "decode_first_token", "decode_steady", "sse_flush")
 CONCURRENT_PHASES = frozenset({"sse_flush"})
+
+# Engine loop records kept per process (oldest dropped first): two
+# minutes of an 8 ms tick, half an hour of a 55 ms one.
+LOOP_RING_CAP = 32768
 
 # Outcomes whose traces tail-based retention always keeps.
 ANOMALOUS_OUTCOMES = frozenset({"shed", "error", "deadline",
@@ -154,11 +175,16 @@ class RequestTrace:
 
     def add_phase(self, name: str, dur_ms: float, *,
                   t_ms: Optional[float] = None,
-                  concurrent: bool = False, **attrs: Any) -> None:
+                  concurrent: bool = False,
+                  parts: Optional[Dict[str, Optional[float]]] = None,
+                  **attrs: Any) -> None:
         """Append an already-measured phase (the gateway's accumulated
         sse_flush; retroactive qos_admission). ``concurrent`` marks
         phases that overlap others and are excluded from the
-        phase-sum invariant."""
+        phase-sum invariant. ``parts`` ({name: ms}, None values
+        skipped) are child spans of this phase, each clipped to what
+        the ones before it left of ``dur_ms``: give the part that ends
+        with the phase first."""
         dur_ms = float(dur_ms)
         rec: Dict[str, Any] = {
             "phase": str(name),
@@ -167,6 +193,17 @@ class RequestTrace:
             "dur_ms": round(dur_ms, 3)}
         if concurrent or name in CONCURRENT_PHASES:
             rec["concurrent"] = True
+        if parts:
+            left = max(0.0, rec["dur_ms"])
+            kept: Dict[str, float] = {}
+            for part, ms in parts.items():
+                if ms is None:
+                    continue
+                ms = min(round(max(0.0, float(ms)), 3), left)
+                kept[str(part)] = ms
+                left = round(left - ms, 3)
+            if kept:
+                rec["parts"] = kept
         if attrs:
             rec.update(attrs)
         with self._lock:
@@ -221,6 +258,9 @@ class RequestTrace:
                 phase_ms[p["phase"]] = round(
                     phase_ms.get(p["phase"], 0.0)
                     + float(p.get("dur_ms", 0.0)), 3)
+                for part, ms in (p.get("parts") or {}).items():
+                    key = f"{p['phase']}.{part}"
+                    phase_ms[key] = round(phase_ms.get(key, 0.0) + ms, 3)
             rec: Dict[str, Any] = {
                 "kind": "trace",
                 "request_id": self.request_id,
@@ -357,19 +397,47 @@ def p99_attribution(summaries: List[Dict[str, Any]]) -> Dict[str, Any]:
         for ph in (s.get("phase_ms") or {}):
             if ph not in names:
                 names.append(ph)
-    phases: Dict[str, Dict[str, float]] = {}
-    for ph in names:
+    # "<phase>.<part>" is a child of <phase> (RequestTrace.add_phase's
+    # parts): listed under its parent, never beside it
+    top = [ph for ph in names
+           if "." not in ph or ph.split(".", 1)[0] not in names]
+
+    def _row(ph: str) -> Optional[Dict[str, Any]]:
         lo, hi = _mean(p50, ph), _mean(p99, ph)
         if lo == 0.0 and hi == 0.0:
+            return None
+        return {"p50_ms": round(lo, 3), "p99_ms": round(hi, 3),
+                "delta_ms": round(hi - lo, 3)}
+
+    phases: Dict[str, Dict[str, Any]] = {
+        ph: row for ph in top if (row := _row(ph)) is not None}
+    deltas = {ph: row["delta_ms"] for ph, row in phases.items()}
+    part_deltas: Dict[str, float] = {}
+    for ph in names:
+        parent, _, part = ph.partition(".")
+        if ph in top or parent not in phases:
             continue
-        phases[ph] = {"p50_ms": round(lo, 3), "p99_ms": round(hi, 3),
-                      "delta_ms": round(hi - lo, 3)}
+        row = _row(ph)
+        if row is not None:
+            phases[parent].setdefault("parts", {})[part] = row
+            part_deltas[ph] = row["delta_ms"]
     tail_owner = None
-    deltas = {ph: v["delta_ms"] for ph, v in phases.items()}
     if deltas:
         tail_owner = max(deltas, key=lambda ph: deltas[ph])
         if deltas[tail_owner] <= 0.0:
             tail_owner = None
+    owner_delta = deltas.get(tail_owner, 0.0)
+    if tail_owner is not None:
+        # a part owns the tail only where it holds more of the growth
+        # than every OTHER top-level phase; else its parent does
+        others = max([d for ph, d in deltas.items() if ph != tail_owner],
+                     default=0.0)
+        mine = {ph: d for ph, d in part_deltas.items()
+                if ph.split(".", 1)[0] == tail_owner}
+        if mine:
+            best = max(mine, key=lambda ph: mine[ph])
+            if mine[best] > max(others, 0.0):
+                tail_owner, owner_delta = best, mine[best]
     out: Dict[str, Any] = {
         "n": n,
         "p50_cohort": len(p50),
@@ -381,7 +449,7 @@ def p99_attribution(summaries: List[Dict[str, Any]]) -> Dict[str, Any]:
     }
     if tail_owner is not None:
         gap = sum(d for d in deltas.values() if d > 0)
-        out["tail_share"] = round(deltas[tail_owner] / gap, 4) \
+        out["tail_share"] = round(owner_delta / gap, 4) \
             if gap > 0 else 0.0
     return out
 
@@ -492,6 +560,8 @@ class RequestTraceStore:
         self._lock = threading.Lock()
         self._kept: Dict[str, Dict[str, Any]] = {}  # insertion-ordered
         self._summaries: List[Dict[str, Any]] = []
+        self._loop_ring: "collections.deque[Dict[str, Any]]" = \
+            collections.deque(maxlen=LOOP_RING_CAP)
         self._seq = 0
         self._completed = 0
         self._dropped = 0
@@ -541,6 +611,7 @@ class RequestTraceStore:
                 self._preempted += 1
             summary = {"seq": seq,
                        "request_id": rec.get("request_id"),
+                       "ts": rec.get("ts"),
                        "total_ms": total_ms,
                        "outcome": outcome,
                        "phase_ms": dict(rec.get("phase_ms") or {})}
@@ -607,7 +678,18 @@ class RequestTraceStore:
                 continue
             del self._kept[rid]
 
+    def record_loop(self, rec: Dict[str, Any]) -> None:
+        """One iteration of an engine's loop (models/engine.py builds
+        the record on its own thread and never touches it again)."""
+        with self._lock:
+            self._loop_ring.append(rec)
+
     # -------------------------------------------------------- reading
+
+    def loop_records(self) -> List[Dict[str, Any]]:
+        """The engine loop ring, oldest first."""
+        with self._lock:
+            return list(self._loop_ring)
 
     def seq(self) -> int:
         with self._lock:

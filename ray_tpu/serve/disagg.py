@@ -104,6 +104,20 @@ _DEATH_TYPES = (ActorError, WorkerCrashedError, ConnectionError,
                 EOFError, OSError)
 
 
+def _first_token_parts(src: Any) -> Dict[str, Optional[float]]:
+    """The engine's own split of a first token's wait, as the parts of
+    the flight recorder's ``decode_first_token``: read off the
+    colocated engine's TokenStream, or off a decode replica's pull
+    reply. The prefill ends with the phase, so it goes first
+    (``RequestTrace.add_phase`` clips parts in order): what a
+    disaggregated request queued while its ``kv_transfer`` was still
+    open is not counted twice."""
+    get = src.get if isinstance(src, dict) \
+        else lambda key: getattr(src, key, None)
+    return {"engine_prefill": get("prefill_ms"),
+            "engine_queue": get("queue_ms")}
+
+
 def _is_pool_exhausted(e: BaseException) -> bool:
     """An adapter-pool-exhausted failure (serve/lora.py
     LoraPoolExhausted) — matched by name because the exception may
@@ -1122,12 +1136,19 @@ class DecodeServer:
             # per-request speculation accounting rides the final pull
             # so the router's decode_steady span can carry
             # accept/reject counts without an extra round trip
-            return {"tokens": toks, "done": True,
-                    "spec_proposed": int(getattr(req, "spec_proposed",
-                                                 0)),
-                    "spec_accepted": int(getattr(req, "spec_accepted",
-                                                 0))}
-        return {"tokens": toks, "done": done}
+            out = {"tokens": toks, "done": True,
+                   "spec_proposed": int(getattr(req, "spec_proposed",
+                                                0)),
+                   "spec_accepted": int(getattr(req, "spec_accepted",
+                                                0))}
+        else:
+            out = {"tokens": toks, "done": done}
+        if toks:
+            # the engine's split of the first token's wait, for the
+            # router's decode_first_token phase (two floats a pull)
+            out["queue_ms"] = entry[0].queue_ms
+            out["prefill_ms"] = entry[0].prefill_ms
+        return out
 
     def cancel_decode(self, hid: str,
                       reason: Optional[str] = None) -> bool:
@@ -2374,8 +2395,12 @@ class DisaggRouter:
                     if t_first_tok is None:
                         t_first_tok = time.perf_counter()
                         if tr is not None:
-                            tr.add_phase("decode_first_token",
-                                         (t_first_tok - t_dec) * 1e3)
+                            # the engine's own split of this wait
+                            # rides the phase as its parts
+                            tr.add_phase(
+                                "decode_first_token",
+                                (t_first_tok - t_dec) * 1e3,
+                                parts=_first_token_parts(stream))
                     n_attempt_toks += 1
                     if not first_emitted:
                         first_emitted = True
@@ -2629,9 +2654,11 @@ class DisaggRouter:
                         if t_first_tok is None:
                             t_first_tok = time.perf_counter()
                             if tr is not None:
-                                tr.add_phase("decode_first_token",
-                                             (t_first_tok - t_dec)
-                                             * 1e3, replica=rep.rid)
+                                tr.add_phase(
+                                    "decode_first_token",
+                                    (t_first_tok - t_dec) * 1e3,
+                                    replica=rep.rid,
+                                    parts=_first_token_parts(out))
                         n_attempt_toks += len(toks)
                         history.extend(int(t) for t in toks)
                         if pslot is not None:

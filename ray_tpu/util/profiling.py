@@ -15,6 +15,8 @@ Driver-side:
     ray_tpu.util.profiling.profile_actor(handle, seconds=5)  # remote
 Annotations: `annotate("fwd")` marks regions inside jitted host code
 (jax.profiler.TraceAnnotation) so they show up on the trace timeline.
+The serving engine's loop wraps its boundaries in them (the `engine.*`
+spans, models/engine.py) on a thread `name_thread` labels `cb-engine`.
 """
 from __future__ import annotations
 
@@ -79,6 +81,19 @@ def annotate(name: str, **kwargs):
     import jax
 
     return jax.profiler.TraceAnnotation(name, **kwargs)
+
+
+def name_thread(name: str) -> None:
+    """Label the calling thread for the profiler: a trace's host lines
+    carry the OS thread's name, which `threading.Thread(name=)` does
+    not set before Python 3.14. Linux only; elsewhere the line keeps
+    the process's name."""
+    import ctypes
+
+    try:
+        ctypes.CDLL(None).prctl(15, name.encode()[:15], 0, 0, 0)
+    except (AttributeError, OSError):  # no prctl: not Linux
+        pass
 
 
 def save_device_memory_profile(path: Optional[str] = None) -> str:
