@@ -8,8 +8,10 @@ params/activations with fp32 norm stats, flash attention (Pallas on TPU),
 static shapes, one spec tree serving dp/fsdp/tp by changing only the mesh.
 
 GQA + tp note: num_kv_heads must divide by the tp degree in use (as in
-every tp Llama deployment); kv heads are repeated to query heads right
-before attention, which XLA lowers to a broadcast (no HBM copy)."""
+every tp Llama deployment). The training block repeats kv heads to query
+heads for the flash kernel; the cache paths contract per kv group
+(`_cache_attention`), because a repeat of the whole KV slab IS an HBM
+copy: XLA cannot fuse it into the reduction that reads it."""
 from __future__ import annotations
 
 from dataclasses import dataclass
@@ -120,11 +122,34 @@ def _qkv(h: jax.Array, p: Params, c: LlamaConfig):
 
 
 def _repeat_kv(k: jax.Array, v: jax.Array, c: LlamaConfig):
+    # llama_block's alone (the flash kernel wants equal head counts):
+    # on a KV slab this is an HBM copy, see _cache_attention
     if c.num_kv_heads != c.num_heads:  # GQA: broadcast kv to query heads
         rep = c.num_heads // c.num_kv_heads
         k = jnp.repeat(k, rep, axis=2)
         v = jnp.repeat(v, rep, axis=2)
     return k, v
+
+
+def _cache_attention(q: jax.Array, ck: jax.Array, cv: jax.Array,
+                     positions: jax.Array, c: LlamaConfig) -> jax.Array:
+    """Masked attention of q [B, t, n_heads, hd] over the cache as it
+    lies, ck/cv [B, S, n_kv, hd] in their own dtype; query (b, j) sees
+    rows <= positions[b, j]. Heads are contracted per kv group: head h
+    is (g, r) = (h // rep, h % rep), the order jnp.repeat(axis=2) gave,
+    so `wo` sees the same columns. Returns [B, t, d_model]."""
+    b, t = q.shape[0], q.shape[1]
+    rep = c.num_heads // c.num_kv_heads
+    qg = q.reshape(b, t, c.num_kv_heads, rep, c.head_dim)
+    scores = jnp.einsum("btgrd,bsgd->bgrts", qg, ck,
+                        preferred_element_type=jnp.float32)
+    scores = scores / (c.head_dim ** 0.5)
+    col = jnp.arange(ck.shape[1])[None, None, None, None, :]
+    visible = col <= positions[:, None, None, :, None]
+    scores = jnp.where(visible, scores, -1e30)
+    probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+    a = jnp.einsum("bgrts,bsgd->btgrd", probs, cv)
+    return a.reshape(b, t, c.d_model)
 
 
 def _mlp_res(x: jax.Array, p: Params) -> jax.Array:
@@ -154,7 +179,10 @@ def llama_block_cached(x: jax.Array, p: Params, cos: jax.Array,
     """KV-cache path (prefill AND decode — tokens land at position `pos`
     and attend over everything written so far). Static shapes: the
     cache is the full [B, S, n_kv, hd] window and masking does the
-    truncation, the standard fixed-shape TPU decode layout.
+    truncation, the standard fixed-shape TPU decode layout. Heads are
+    contracted per kv group against the cache as it lies
+    (`_cache_attention`); `_repeat_kv` remains for `llama_block` alone,
+    whose flash kernel wants equal head counts.
     Returns (x, new_cache_for_this_block)."""
     c = config
     b, t, _ = x.shape
@@ -167,18 +195,9 @@ def llama_block_cached(x: jax.Array, p: Params, cos: jax.Array,
         cache["k"], k.astype(cache["k"].dtype), (0, pos, 0, 0))
     cv = jax.lax.dynamic_update_slice(
         cache["v"], v.astype(cache["v"].dtype), (0, pos, 0, 0))
-    kk, vv = _repeat_kv(ck, cv, c)
-    s = kk.shape[1]
     # decode t is tiny (1 for autoregressive steps): plain masked
     # attention over the cache window — flash brings nothing at t=1
-    scores = jnp.einsum("bthd,bshd->bhts", q, kk,
-                        preferred_element_type=jnp.float32)
-    scores = scores / (c.head_dim ** 0.5)
-    col = jnp.arange(s)[None, None, None, :]
-    visible = col <= positions[:, None, :, None]
-    scores = jnp.where(visible, scores, -1e30)
-    probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
-    a = jnp.einsum("bhts,bshd->bthd", probs, vv).reshape(b, t, c.d_model)
+    a = _cache_attention(q, ck, cv, positions, c)
     x = x + _mm(a, p["attn"]["wo"])
     return _mlp_res(x, p), {"k": ck, "v": cv}
 
@@ -212,7 +231,9 @@ def llama_block_decode(x: jax.Array, p: Params, cos: jax.Array,
     bit-identity the speculation oracle rests on. Rows past a query's
     position stay invisible, which is also why rejected draft rows
     need no rollback: they are overwritten before any later query can
-    see them.
+    see them. Heads are contracted per kv group (`_cache_attention`),
+    never against a repeated slab; `_repeat_kv` remains for
+    `llama_block` alone, whose flash kernel wants equal head counts.
 
     `lora` (optional, serve/lora.py mixed-tenant decode): this layer's
     per-slot adapter selections — ``{"wq": (a [B,D,r], b [B,r,D]),
@@ -244,16 +265,7 @@ def llama_block_decode(x: jax.Array, p: Params, cos: jax.Array,
         k.astype(cache["k"].dtype))
     cv = cache["v"].at[rows[:, None], positions].set(
         v.astype(cache["v"].dtype))
-    kk, vv = _repeat_kv(ck, cv, c)
-    s = kk.shape[1]
-    scores = jnp.einsum("bthd,bshd->bhts", q, kk,
-                        preferred_element_type=jnp.float32)
-    scores = scores / (c.head_dim ** 0.5)
-    col = jnp.arange(s)[None, None, None, :]
-    visible = col <= positions[:, None, :, None]
-    scores = jnp.where(visible, scores, -1e30)
-    probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
-    a = jnp.einsum("bhts,bshd->bthd", probs, vv).reshape(b, t, c.d_model)
+    a = _cache_attention(q, ck, cv, positions, c)
     x = x + _mm(a, p["attn"]["wo"])
     return _mlp_res(x, p), {"k": ck, "v": cv}
 
