@@ -1181,8 +1181,8 @@ def _int8_logit_probe(params, config, args,
     probe = _model_fns(config)[1](config, 1, max_len=1)
     empty = jnp.zeros((len(probe), 0) + probe[0]["k"].shape[2:],
                       probe[0]["k"].dtype)
-    ref_logits, ck, cv = _prefill_paged(params, prompt, config, empty,
-                                        empty)
+    ref_logits, ck, cv, _ = _prefill_paged(params, prompt, config, empty,
+                                           empty)
     kv = PagedKVCache(config, block_size=args.block_size,
                       num_blocks=max(args.pool_blocks or 32, 16),
                       int8=True)
@@ -1190,8 +1190,8 @@ def _int8_logit_probe(params, config, args,
     kv.commit(prompt[0], ck, cv, m)
     m2 = kv.lookup(prompt[0], max_tokens=prompt.shape[1] - 1)
     pk, pv = kv.gather(m2)
-    q_logits, _, _ = _prefill_paged(params, prompt[:, m2.tokens:],
-                                    config, pk, pv)
+    q_logits, _, _, _ = _prefill_paged(params, prompt[:, m2.tokens:],
+                                       config, pk, pv)
     ref = np.asarray(ref_logits[0, :config.vocab_size], np.float32)
     got = np.asarray(q_logits[0, :config.vocab_size], np.float32)
     rel = float(np.max(np.abs(got - ref))

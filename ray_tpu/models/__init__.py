@@ -4,6 +4,12 @@ The reference ships no models of its own (Ray wraps user torch modules);
 the rebuild's north-star workloads (BASELINE.md) need a flagship LM, so
 GPT-2 lives here as a pure-functional JAX implementation with first-class
 sharding rules for every mesh axis the parallel layer exposes.
+
+Served by `ContinuousBatchingEngine` (`generate._model_fns`): GPT-2, the
+Llama block, and Nemotron-H (`nemotron_h.py`: Mamba-2, attention and
+LatentMoE layers; its slots own recurrent state, so the engine refuses
+it a prefix pool, speculation, a LoRA pool and disaggregated adoption).
+`moe_transformer.py` trains and is not served.
 """
 from .gpt2 import (  # noqa: F401
     GPT2Config,
@@ -23,6 +29,13 @@ from .llama import (  # noqa: F401
     llama_init,
     llama_loss,
     llama_partition_specs,
+)
+from .nemotron_h import (  # noqa: F401
+    NemotronHConfig,
+    nemotron_h_forward,
+    nemotron_h_init,
+    nemotron_h_loss,
+    nemotron_h_partition_specs,
 )
 from .moe_transformer import (  # noqa: F401
     MoEConfig,

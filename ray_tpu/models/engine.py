@@ -65,6 +65,23 @@ Surfaces: ``util.state.speculation_stats()``, ``ray_tpu speculate``,
 ``ray_tpu_spec_acceptance_rate``), and spec_accept / spec_reject
 instant markers in the merged timeline's kvcache lane.
 
+The cache protocol is one for every family (`generate._model_fns`): the
+decode slab is the family's own pytree, a list of entries. An entry with
+"k"/"v" `[B, S, H, hd]` has a sequence axis: a prefill hands back its
+rows stacked `[L_kv, S, H, hd]` (what the paged pool commits, a
+disaggregated transfer ships and `_splice_slot` writes by rows `[0,
+plen)`). Any other entry is a slot's STATE with no sequence axis (a
+recurrence's state, a convolution's tail: models/nemotron_h.py): a
+prefill hands back the state it ended in, and the splice writes it
+WHOLE. Three things the engine takes for granted of keys and values do
+not hold for such a family, and are refused with a ValueError that names
+the reason rather than served silently wrong: a prefix pool
+(``prefix_cache=True``; left to its default the engine builds none: a
+block-aligned prefix cannot resume a recurrence without a snapshot of
+the state), speculation (``speculate_k > 0``: a state a draft advanced
+cannot be un-advanced), a ``lora_pool``, and ``adopt_prefill`` (a
+transfer carries keys and values only).
+
 The loop keeps a clock of its own. While the per-request flight
 recorder is on (``RAY_TPU_REQTRACE``, observability/requests.py), every
 pass of ``_loop`` that had a live slot or admitted something leaves ONE
@@ -81,13 +98,19 @@ one entry per request admitted in the pass (``rid``, ``prompt_tokens``,
 read-back of its logits, the commit between them taken out),
 ``commit_ms`` with ``commit_dispatches`` (programs the pool commit
 launched: each block's extract and its write or copy-on-write) and
-``commit_blocks``, ``splice_ms``; an adoption has ``prefill_ms`` 0);
+``commit_blocks``, ``splice_ms``, and for a family with state
+``state_bytes``, what the splice wrote whole; an adoption has
+``prefill_ms`` 0);
 ``dispatch_ms`` (from the end of ``_admit`` to the return of the tick
 call: two uploads and the dispatch; the speculative tick counts from
 its own start, its drafting is bookkeeping); ``readback_ms`` (the
 tokens and log-probabilities read back: BLOCKED on the device, so not
-host work); ``emit_ms`` (the walk over the slots: emit, finish, queue
-puts); ``total_ms`` (the whole pass; what the parts leave is
+host work; where the family's decode hands back counters of the step,
+they come with the same read-back and land in the record under their
+own names: ``moe_pairs_held``, token-expert pairs that fell on experts
+held here, summed over the expert layers, and ``moe_rows_max``, the most
+rows one held expert got); ``emit_ms`` (the walk over the slots: emit,
+finish, queue puts); ``total_ms`` (the whole pass; what the parts leave is
 bookkeeping: swap, cancels, drafting, telemetry push). A request
 carries three stamps of the same clock (``submit()`` returns, ``_admit``
 pops it, ``_emit`` puts its first token) and ``TokenStream`` exposes
@@ -181,29 +204,49 @@ def spec_metrics() -> Dict[str, Any]:
     return _spec_metrics
 
 
-@functools.partial(jax.jit, static_argnums=(2,))
-def _prefill_paged(params, suffix, config, prefix_k, prefix_v):
-    """Prefill a single sequence's SUFFIX on top of a cached prefix
-    ([L, c, H, hd]; c=0 is the full-prefill program). The window is the
-    full max_seq_len slab — the same reduction shapes as generate()'s
-    prefill, so cached and uncached paths stay bit-identical — and the
-    returned cache is the stacked [L, S, H, hd] single-sequence fill.
-    One compile per distinct (cached, suffix) length pair."""
-    fwd = _model_fns(config)[0]
+def _prefill_body(params, suffix, config, prefix_k, prefix_v):
+    """What `_prefill_paged` and `_prefill_paged_lora` trace. The
+    family's single-sequence cache (`init_cache(config, 1)`) is laid out
+    with the cached prefix in the rows of its key-value entries, the
+    family's `forward_cached` fills it from position c on, and it comes
+    back as the engine passes a sequence around: the key-value entries
+    stacked [L_kv, S, H, hd] (what the paged pool, the splice and the
+    transfer between replicas speak), then the entries that have no
+    sequence axis, each as the family left it (a slot's state; the empty
+    list for a family that has none)."""
+    fwd, init_cache, _ = _model_fns(config)
     c = prefix_k.shape[1]
-    layers = prefix_k.shape[0]
-    base_k = jnp.zeros((layers, config.max_seq_len) + prefix_k.shape[2:],
-                       prefix_k.dtype)
+    cache = list(init_cache(config, 1))
+    kv_at = [i for i, blk in enumerate(cache) if "k" in blk]
+    if c and len(kv_at) != len(cache):
+        raise ValueError(
+            "a cached prefix cannot resume a recurrent state: this "
+            "family prefills every prompt from position 0")
+    base_k = jnp.zeros((len(kv_at), config.max_seq_len)
+                       + prefix_k.shape[2:], prefix_k.dtype)
     base_v = jnp.zeros_like(base_k)
     if c:
         base_k = base_k.at[:, :c].set(prefix_k)
         base_v = base_v.at[:, :c].set(prefix_v)
-    cache = [{"k": base_k[layer][None], "v": base_v[layer][None]}
-             for layer in range(layers)]
+    for j, i in enumerate(kv_at):
+        cache[i] = {"k": base_k[j][None], "v": base_v[j][None]}
     logits, cache = fwd(params, suffix, config, cache, c)
-    ck = jnp.stack([blk["k"][0] for blk in cache])
-    cv = jnp.stack([blk["v"][0] for blk in cache])
-    return logits[:, -1], ck, cv
+    ck = jnp.stack([cache[i]["k"][0] for i in kv_at])
+    cv = jnp.stack([cache[i]["v"][0] for i in kv_at])
+    state = [blk for blk in cache if "k" not in blk]
+    return logits[:, -1], ck, cv, state
+
+
+@functools.partial(jax.jit, static_argnums=(2,))
+def _prefill_paged(params, suffix, config, prefix_k, prefix_v):
+    """Prefill a single sequence's SUFFIX on top of a cached prefix
+    ([L, c, H, hd]; c=0 is the full-prefill program, and the only one a
+    family with state has). The window is the full max_seq_len slab —
+    the same reduction shapes as generate()'s prefill, so cached and
+    uncached paths stay bit-identical. Returns (last logits, ck, cv,
+    state): `_prefill_body`. One compile per distinct (cached, suffix)
+    length pair."""
+    return _prefill_body(params, suffix, config, prefix_k, prefix_v)
 
 
 @functools.partial(jax.jit, static_argnums=(2,))
@@ -214,22 +257,8 @@ def _prefill_paged_lora(params, suffix, config, prefix_k, prefix_v,
     per-request single-tenant, so the merged weights never persist —
     only the decode tick pays the scatter-gathered per-slot form). One
     compile per distinct (cached, suffix, rank) shape triple."""
-    params = merge_lora_params(params, config, lora)
-    fwd = _model_fns(config)[0]
-    c = prefix_k.shape[1]
-    layers = prefix_k.shape[0]
-    base_k = jnp.zeros((layers, config.max_seq_len) + prefix_k.shape[2:],
-                       prefix_k.dtype)
-    base_v = jnp.zeros_like(base_k)
-    if c:
-        base_k = base_k.at[:, :c].set(prefix_k)
-        base_v = base_v.at[:, :c].set(prefix_v)
-    cache = [{"k": base_k[layer][None], "v": base_v[layer][None]}
-             for layer in range(layers)]
-    logits, cache = fwd(params, suffix, config, cache, c)
-    ck = jnp.stack([blk["k"][0] for blk in cache])
-    cv = jnp.stack([blk["v"][0] for blk in cache])
-    return logits[:, -1], ck, cv
+    return _prefill_body(merge_lora_params(params, config, lora), suffix,
+                         config, prefix_k, prefix_v)
 
 
 def _prefill_with_cache(params, config, kv_cache, prompt, empty_prefix,
@@ -240,9 +269,10 @@ def _prefill_with_cache(params, config, kv_cache, prompt, empty_prefix,
     lookup → gather → `_prefill_paged` on the suffix → commit +
     prefix_hit event → greedy first token + its logprob score. ONE
     implementation keeps the two paths bit-identical (the disagg
-    equivalence tests depend on it). Returns `(ck, cv, block_table,
-    first, score, outcome, reused, suffix_len)`; the caller owns the
-    returned pins (empty list when no cache).
+    equivalence tests depend on it). Returns `(ck, cv, state,
+    block_table, first, score, outcome, reused, suffix_len)` (`state`:
+    `_prefill_body`); the caller owns the returned pins (empty list
+    when no cache).
 
     `adapter`/`namespace` (multi-tenant LoRA, serve/lora.py): prefill
     under one tenant's adapter slice, with the prefix cache keyed by
@@ -274,11 +304,11 @@ def _prefill_with_cache(params, config, kv_cache, prompt, empty_prefix,
     # the prefill's
     with annotate("engine.prefill", rid=rid, prompt_tokens=plen):
         if adapter is not None:
-            last_logits, ck, cv = _prefill_paged_lora(
+            last_logits, ck, cv, state = _prefill_paged_lora(
                 params, suffix, config, prefix_k, prefix_v, adapter)
         else:
-            last_logits, ck, cv = _prefill_paged(params, suffix, config,
-                                                 prefix_k, prefix_v)
+            last_logits, ck, cv, state = _prefill_paged(
+                params, suffix, config, prefix_k, prefix_v)
         table: List[Any] = []
         if kv_cache is not None:
             kv_cache.note_prefilled(suffix.shape[1])
@@ -305,25 +335,37 @@ def _prefill_with_cache(params, config, kv_cache, prompt, empty_prefix,
         commit_ms = (t3 - t2) * 1e3
         parts.update(lookup_ms=(t1 - t0) * 1e3, commit_ms=commit_ms,
                      prefill_ms=(_now() - t1) * 1e3 - commit_ms)
-    return (ck, cv, table, first, score, outcome, int(reused),
+    return (ck, cv, state, table, first, score, outcome, int(reused),
             int(suffix.shape[1]))
 
 
 @functools.partial(jax.jit, static_argnums=(4, 5),
                    donate_argnums=(0,))
-def _splice_slot(cache, ck, cv, slot, config, plen):
-    """Write a prefilled sequence's [0, plen) rows into batch slot
-    `slot` of the decode slab — with the slab donated this lowers to an
-    in-place O(plen) row update per layer, never a full-cache copy."""
+def _splice_slot(cache, ck, cv, slot, config, plen, state=()):
+    """Write a prefilled sequence into batch slot `slot` of the decode
+    slab, which is the family's own pytree: an entry with "k"/"v" has a
+    sequence axis and takes rows [0, plen) of its layer of ck/cv; any
+    other entry is a slot's state and takes the next entry of `state`
+    WHOLE. With the slab donated this lowers to an in-place update per
+    entry, O(plen) rows and the state's bytes, never a full-cache
+    copy."""
     del config
-    out = []
-    for layer, blk in enumerate(cache):
-        out.append({
-            "k": jax.lax.dynamic_update_slice(
-                blk["k"], ck[layer, :plen][None], (slot, 0, 0, 0)),
-            "v": jax.lax.dynamic_update_slice(
-                blk["v"], cv[layer, :plen][None], (slot, 0, 0, 0)),
-        })
+    out, layer, states = [], 0, iter(state)
+    for blk in cache:
+        if "k" in blk:
+            out.append({
+                "k": jax.lax.dynamic_update_slice(
+                    blk["k"], ck[layer, :plen][None], (slot, 0, 0, 0)),
+                "v": jax.lax.dynamic_update_slice(
+                    blk["v"], cv[layer, :plen][None], (slot, 0, 0, 0)),
+            })
+            layer += 1
+        else:
+            out.append(jax.tree.map(
+                lambda slab, one: jax.lax.dynamic_update_slice(
+                    slab, one.astype(slab.dtype),
+                    (slot,) + (0,) * (slab.ndim - 1)),
+                blk, next(states)))
     return out
 
 
@@ -337,15 +379,18 @@ def _tick(params, config, cache, tokens, pos_vec):
     KV rows stay masked until overwritten). jit specializes per shape,
     and the verify's row j is bit-identical to j sequential one-token
     ticks — the accept rule's whole contract, shared math by
-    construction because this IS the same function."""
-    logits, cache = _model_fns(config)[2](params, tokens, config, cache,
-                                          pos_vec)
+    construction because this IS the same function. A family's decode
+    may hand back a third value, a dict of small counters of the step
+    (models/nemotron_h.py: what its expert layers saw); it comes back
+    beside the tokens, None for a family that has none."""
+    logits, cache, *counts = _model_fns(config)[2](params, tokens, config,
+                                                   cache, pos_vec)
     live = logits[..., :config.vocab_size].astype(jnp.float32)
     nxt = jnp.argmax(live, axis=-1).astype(jnp.int32)
     # per-slot logprob of the chosen (greedy = max-logit) token — the
     # rollout score stream (ray_tpu.online samplers record it per token)
     lp = jnp.max(live, axis=-1) - jax.nn.logsumexp(live, axis=-1)
-    return cache, nxt, lp
+    return cache, nxt, lp, (counts[0] if counts else None)
 
 
 @functools.partial(jax.jit, static_argnums=(1,), donate_argnums=(2,))
@@ -516,7 +561,8 @@ class ContinuousBatchingEngine:
                  draft_source: Optional[Callable[[List[int], int],
                                                  List[int]]] = None,
                  kv_int8: Optional[bool] = None):
-        # config: any family _model_fns knows (LlamaConfig, GPT2Config)
+        # config: any family _model_fns knows (LlamaConfig, GPT2Config,
+        # NemotronHConfig)
         self.params = params
         self.config = config
         self.max_batch = max_batch
@@ -530,6 +576,22 @@ class ContinuousBatchingEngine:
         self._pending_swap: Optional[tuple] = None
         self.swap_count = 0
         self._cache = _model_fns(config)[1](config, max_batch)
+        # the slab is the family's own pytree: entries with "k"/"v" have
+        # a sequence axis, any other entry is a slot's state (what it
+        # weighs: kv_stats)
+        self._state_bytes_per_slot = sum(
+            x.size * x.dtype.itemsize // max_batch
+            for blk in self._cache if "k" not in blk
+            for x in jax.tree.leaves(blk))
+        self._kv_bytes_per_token = sum(
+            x.size * x.dtype.itemsize // (max_batch * x.shape[1])
+            for blk in self._cache if "k" in blk
+            for x in jax.tree.leaves(blk))
+        if speculate_k is None:
+            speculate_k = default_speculate_k()
+        if self.stateful:
+            self._refuse_for_state(prefix_cache, speculate_k, lora_pool)
+            prefix_cache = False
         # paged KV prefix cache (models/kvcache.py); RAY_TPU_KV_* env
         # knobs supply defaults, constructor args win
         from ray_tpu.util import envknobs
@@ -564,8 +626,6 @@ class ContinuousBatchingEngine:
         # slot verified in one widened tick; 0 = the classic loop.
         # `draft_source(ctx, k) -> tokens` overrides the prompt-lookup
         # proposer (tests script full/partial/zero acceptance with it).
-        if speculate_k is None:
-            speculate_k = default_speculate_k()
         self.speculate_k = max(0, int(speculate_k))
         self.draft_source = draft_source
         # cross-request output memory: greedy decode under fixed
@@ -636,6 +696,35 @@ class ContinuousBatchingEngine:
         self._thread.start()
 
     # ------------------------------------------------------------- API
+    @property
+    def stateful(self) -> bool:
+        """Whether a slot owns state with no sequence axis (a recurrent
+        family) beside, or instead of, rows of keys and values."""
+        return self._state_bytes_per_slot > 0
+
+    @staticmethod
+    def _refuse_for_state(prefix_cache, speculate_k, lora_pool) -> None:
+        """What the engine takes for granted of keys and values and a
+        recurrent state does not give, refused in words (module
+        docstring). `prefix_cache=None` simply builds no pool."""
+        family = "this family's slots own recurrent state: "
+        if prefix_cache:
+            raise ValueError(
+                family + "a block-aligned prefix of keys and values "
+                "cannot resume a recurrence without a snapshot of the "
+                "state at that block, which the pool does not keep "
+                "(prefix_cache=True)")
+        if speculate_k:
+            raise ValueError(
+                family + "a rejected draft's rows need no copy-back, but "
+                "a state the draft has advanced cannot be un-advanced "
+                f"(speculate_k={speculate_k})")
+        if lora_pool is not None:
+            raise ValueError(
+                family + "the adapter pool's targets are attention "
+                "projections of every block, and the per-tenant prefix "
+                "namespaces need the prefix pool (lora_pool)")
+
     def submit(self, prompt_tokens, max_new_tokens: int,
                eos_token: Optional[int] = None,
                adapter_id: Optional[str] = None) -> "_Request":
@@ -709,6 +798,12 @@ class ContinuousBatchingEngine:
         engine would; without them drafting starts from the emitted
         history alone (correctness unaffected)."""
         plen = int(prompt_len)
+        if self.stateful:
+            raise ValueError(
+                "this family's slots own recurrent state: an adoption "
+                "carries ck/cv rows only, and a prefill replica has no "
+                "way to hand over the state its prefill ended in "
+                "(adopt_prefill)")
         if plen < 1:
             raise ValueError("prompt_len must be >= 1")
         if plen + max_new_tokens > self.config.max_seq_len:
@@ -892,6 +987,9 @@ class ContinuousBatchingEngine:
             cancelled=self.cancelled,
             cancelled_by_reason=dict(self.cancelled_by_reason),
             lora=self.lora_pool is not None,
+            stateful=self.stateful,
+            state_bytes_per_slot=self._state_bytes_per_slot,
+            kv_bytes_per_token=self._kv_bytes_per_token,
         )
         s.update(self.speculation_stats())
         if self.kv_cache is None:
@@ -984,14 +1082,17 @@ class ContinuousBatchingEngine:
             self.publish_kv_telemetry()
 
     def _splice(self, ck, cv, slot: int, plen: int,
-                entry: Optional[Dict[str, Any]]) -> None:
+                entry: Optional[Dict[str, Any]], state=()) -> None:
         """Both admission paths' write into the decode slab."""
         t0 = _clock(entry)
         with annotate("engine.splice"):
             self._cache = _splice_slot(self._cache, ck, cv,
-                                       np.int32(slot), self.config, plen)
+                                       np.int32(slot), self.config, plen,
+                                       tuple(state))
         if entry is not None:
             entry["splice_ms"] = (_now() - t0) * 1e3
+            if self.stateful:
+                entry["state_bytes"] = self._state_bytes_per_slot
         self.spliced_tokens += plen
 
     @staticmethod
@@ -1056,7 +1157,7 @@ class ContinuousBatchingEngine:
                 req.lora_slot, with_version=True)
             namespace = self.lora_pool.cache_namespace(req.adapter_id,
                                                        aver)
-        ck, cv, table, first, score, outcome, reused, suffix_len = \
+        ck, cv, state, table, first, score, outcome, reused, suffix_len = \
             _prefill_with_cache(self.params, self.config, self.kv_cache,
                                 req.prompt, self._empty_prefix,
                                 event_extra={"rid": req.rid},
@@ -1071,7 +1172,7 @@ class ContinuousBatchingEngine:
             req.block_table = table
         self.prefill_calls += 1
         self.prefilled_tokens += suffix_len
-        self._splice(ck, cv, slot, plen, entry)
+        self._splice(ck, cv, slot, plen, entry, state)
         self.admitted += 1
         self.prefill_admitted += 1
         req.slot = slot
@@ -1214,6 +1315,7 @@ class ContinuousBatchingEngine:
                       live=self.max_batch - len(self._free)):
             tok_dev = jnp.asarray(toks)
             pos_dev = jnp.asarray(self._pos)
+            counts = None
             if lora_live:
                 cache, nxt, lp = self.lora_pool.dispatch_tick(
                     lambda la: _tick_lora(
@@ -1221,7 +1323,7 @@ class ContinuousBatchingEngine:
                         pos_dev, la),
                     self._slot_adapter)
             else:
-                cache, nxt, lp = _tick(
+                cache, nxt, lp, counts = _tick(
                     self.params, self.config, self._cache, tok_dev,
                     pos_dev)
             self._cache = cache
@@ -1231,6 +1333,10 @@ class ContinuousBatchingEngine:
         with annotate("engine.tick_readback"):
             nxt_np = np.asarray(nxt)
             lp_np = np.asarray(lp)
+            if it is not None and counts:
+                # the step is over once the tokens are here: these come
+                # without another wait for the device
+                it.update({k: int(v) for k, v in counts.items()})
         if it is not None:
             it["readback_ms"] = (_now() - t1) * 1e3
         return nxt_np, lp_np

@@ -34,6 +34,15 @@ def _model_fns(config):
 
     if isinstance(config, GPT2Config):
         return gpt2_forward_cached, gpt2_init_kv_cache, gpt2_decode
+    from .nemotron_h import (NemotronHConfig, nemotron_h_decode,
+                             nemotron_h_forward_cached,
+                             nemotron_h_init_cache)
+
+    if isinstance(config, NemotronHConfig):
+        # a cache with state beside keys and values: module docstring of
+        # models/nemotron_h.py, and the engine's splice
+        return (nemotron_h_forward_cached, nemotron_h_init_cache,
+                nemotron_h_decode)
     raise TypeError(f"no generation support for {type(config).__name__}")
 
 

@@ -448,6 +448,11 @@ class PrefillServer:
                 self.kv_cache.invalidate(
                     namespace=_p.cache_namespace(tenant, old)))
         probe = _model_fns(config)[1](config, 1, max_len=1)
+        if any("k" not in blk for blk in probe):
+            raise ValueError(
+                "this family's slots own recurrent state: a transfer "
+                "carries ck/cv rows only, so it cannot be served "
+                "disaggregated (engine.adopt_prefill refuses it too)")
         shape = probe[0]["k"].shape  # [1, 1, H, hd]
         self._empty_prefix = jnp.zeros(
             (len(probe), 0) + shape[2:], probe[0]["k"].dtype)
@@ -543,8 +548,8 @@ class PrefillServer:
             if t3 is not None:
                 kvp_info["tier3"] = t3
         try:
-            ck, cv, table, first, score, outcome, reused, suffix_len = \
-                _prefill_with_cache(self.params, self.config,
+            ck, cv, _state, table, first, score, outcome, reused, \
+                suffix_len = _prefill_with_cache(self.params, self.config,
                                     self.kv_cache, prompt,
                                     self._empty_prefix, adapter=adapter,
                                     namespace=namespace)
