@@ -97,7 +97,8 @@ one entry per request admitted in the pass (``rid``, ``prompt_tokens``,
 (lookup + gather), ``prefill_ms`` (the ``_prefill_paged`` call and the
 read-back of its logits, the commit between them taken out),
 ``commit_ms`` with ``commit_dispatches`` (programs the pool commit
-launched: each block's extract and its write or copy-on-write) and
+launched: its one program once for the keys' pool and once for the
+values', whatever the blocks; 0 where nothing was new) and
 ``commit_blocks``, ``splice_ms``, and for a family with state
 ``state_bytes``, what the splice wrote whole; an adoption has
 ``prefill_ms`` 0);
@@ -300,8 +301,8 @@ def _prefill_with_cache(params, config, kv_cache, prompt, empty_prefix,
     t1 = t2 = t3 = _clock(parts)
     # engine.prefill runs to the read-back of the logits, the commit
     # nested in it: the device works on the prefill while the host
-    # dispatches the commit's programs, so the span's self time is
-    # the prefill's
+    # plans the commit and queues its writes behind it, so the span's
+    # self time is the prefill's
     with annotate("engine.prefill", rid=rid, prompt_tokens=plen):
         if adapter is not None:
             last_logits, ck, cv, state = _prefill_paged_lora(

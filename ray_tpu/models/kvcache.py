@@ -4,8 +4,12 @@ prompt must not re-prefill it per request (vLLM's PagedAttention prefix
 cache, rebuilt for this engine's fixed-slab TPU decode design).
 
 Layout: one device pool per engine, K and V each
-``[layers, num_blocks, block_size, kv_heads, head_dim]`` in the model's
-cache dtype. Blocks are the unit of sharing:
+``[layers, num_blocks, block_size, kv_heads * head_dim]`` in the model's
+cache dtype: heads and head_dim lie flat in one axis, so a block's rows
+stay whole lanes for heads of any width (with a 64-wide head_dim as an
+axis of its own the chip's default layout makes ``num_blocks`` the
+minor-most axis, and a program that wants another order copies the
+whole pool in and out). Blocks are the unit of sharing:
 
 - **hash-chained index** — block ``i`` of a prompt is keyed by
   ``H(chain_digest(blocks < i), tokens_i)``, so a lookup walks the
@@ -38,8 +42,9 @@ matched again (in-flight slots keep decoding off their own slab copy).
 pool stores K/V as int8 with per-block-CHANNEL fp32 scales (amax over
 the block's token rows, one scale per (layer, head, head_dim) channel
 — the channel-wise shape that keeps RoPE'd K's per-dim dynamic range).
-Quantize-on-commit and dequantize-on-gather are donated jits, O(block)
-in place like every other pool mutation, so the HALVED bytes per block
+Quantize-on-commit (inside the commit's one program, donated and in
+place like every other pool mutation) and dequantize-on-gather are
+jits, so the HALVED bytes per block
 buy a doubled default pool (``resolve_pool_config`` sizes 2x blocks
 when int8 is on and the pool wasn't pinned explicitly) — bigger decode
 batches and higher prefix-cache residency for the same HBM. Everything
@@ -51,7 +56,7 @@ tests/test_speculate.py.
 **Tiered KV plane** (``serve/kvplane.py``): the pool is tier 1 of a
 three-tier hierarchy. ``attach_arena()`` hooks a host-RAM arena into
 the eviction path — a block evicted under pool pressure spills its
-int8+per-block-channel-scales wire form (``_write_block_q``'s layout)
+int8+per-block-channel-scales wire form (``_payload_locked``'s layout)
 to the arena instead of dying, and a later ``lookup()`` whose chain
 walk breaks consults the arena and re-adopts the block through the
 normal insert path (int8 pools round-trip bit-exactly; fp pools
@@ -174,141 +179,137 @@ def prefix_digests(tokens, block_size: int,
 
 # --------------------------------------------------------- device ops
 # All pool mutation is jitted with the pool donated, so XLA updates the
-# arrays in place: a block write touches O(block) bytes, never O(pool).
-
-@functools.partial(jax.jit, donate_argnums=(0, 1))
-def _write_block(pool_k, pool_v, bid, blk_k, blk_v):
-    """pool [L,N,bs,H,hd] <- blk [L,bs,H,hd] at block row `bid`."""
-    return (jax.lax.dynamic_update_slice(
-                pool_k, blk_k[:, None], (0, bid, 0, 0, 0)),
-            jax.lax.dynamic_update_slice(
-                pool_v, blk_v[:, None], (0, bid, 0, 0, 0)))
-
-
-@functools.partial(jax.jit, donate_argnums=(0, 1))
-def _cow_extend_block(pool_k, pool_v, dst, src, blk_k, blk_v, filled_old):
-    """Copy-on-write: rows ``< filled_old`` come from the SHARED block
-    `src` (the copy), rows ``>= filled_old`` from the freshly prefilled
-    `blk` (the write); the merge lands in `dst`."""
-    sizes = (pool_k.shape[0], 1) + pool_k.shape[2:]
-    old_k = jax.lax.dynamic_slice(pool_k, (0, src, 0, 0, 0), sizes)[:, 0]
-    old_v = jax.lax.dynamic_slice(pool_v, (0, src, 0, 0, 0), sizes)[:, 0]
-    row = jnp.arange(pool_k.shape[2])[None, :, None, None]
-    merged_k = jnp.where(row < filled_old, old_k, blk_k)
-    merged_v = jnp.where(row < filled_old, old_v, blk_v)
-    return (jax.lax.dynamic_update_slice(
-                pool_k, merged_k[:, None], (0, dst, 0, 0, 0)),
-            jax.lax.dynamic_update_slice(
-                pool_v, merged_v[:, None], (0, dst, 0, 0, 0)))
-
-
-@functools.partial(jax.jit, static_argnums=(3,))
-def _gather_prefix(pool_k, pool_v, bids, ntok):
-    """Assemble a matched prefix: block rows `bids` concatenated along
-    the token axis, truncated to the matched token count (the tail
-    block may be partial)."""
-    k = jnp.take(pool_k, bids, axis=1)      # [L, n, bs, H, hd]
-    v = jnp.take(pool_v, bids, axis=1)
-    ll, n, bs = k.shape[0], k.shape[1], k.shape[2]
-    k = k.reshape((ll, n * bs) + k.shape[3:])[:, :ntok]
-    v = v.reshape((ll, n * bs) + v.shape[3:])[:, :ntok]
-    return k, v
-
-
-# int8 pool twins: per-block-CHANNEL symmetric quantization — one fp32
-# scale per (layer, head, head_dim) channel, amax'd over the block's
-# token rows. Same donation discipline as the fp ops: a commit touches
-# O(block) bytes of the int8 pool + scale pool, never O(pool).
+# arrays in place: a commit touches O(prompt) bytes, the adoption of one
+# spilled block O(block), never O(pool).
 
 def _quantize(blk):
-    """[L, bs, H, hd] float -> (int8 same shape, f32 scale
-    [L, 1, H, hd]). amax==0 channels take scale 1 so 0/0 never NaNs
-    (their rows quantize to exact 0 either way)."""
+    """[..., bs, W] float -> (int8 same shape, f32 scale [..., 1, W]):
+    per-block-CHANNEL symmetric quantization, one scale per (layer,
+    head, head_dim) channel, amax'd over the block's token rows.
+    amax==0 channels take scale 1 so 0/0 never NaNs (their rows
+    quantize to exact 0 either way)."""
     f = blk.astype(jnp.float32)
-    amax = jnp.max(jnp.abs(f), axis=1, keepdims=True)
+    amax = jnp.max(jnp.abs(f), axis=-2, keepdims=True)
     scale = jnp.where(amax > 0, amax / 127.0, 1.0)
     q = jnp.clip(jnp.round(f / scale), -127, 127).astype(jnp.int8)
     return q, scale
 
 
-@functools.partial(jax.jit, donate_argnums=(0, 1, 2, 3))
-def _write_block_q(pool_k, pool_v, sk, sv, bid, blk_k, blk_v):
-    """Quantize-on-commit: pool [L,N,bs,H,hd] int8 + scales
-    [L,N,1,H,hd] f32 <- blk [L,bs,H,hd] at block row `bid`."""
-    qk, sck = _quantize(blk_k)
-    qv, scv = _quantize(blk_v)
-    at = (0, bid, 0, 0, 0)
-    return (jax.lax.dynamic_update_slice(pool_k, qk[:, None], at),
-            jax.lax.dynamic_update_slice(pool_v, qv[:, None], at),
-            jax.lax.dynamic_update_slice(sk, sck[:, None], at),
-            jax.lax.dynamic_update_slice(sv, scv[:, None], at))
+def _flat(x):
+    """[L, rows, H, hd] -> [L, 1, rows, H*hd]: one block (or its
+    scales, rows = 1) as the pool holds it."""
+    return x.reshape((x.shape[0], 1, x.shape[1], -1))
 
 
-@functools.partial(jax.jit, donate_argnums=(0, 1, 2, 3))
-def _cow_extend_block_q(pool_k, pool_v, sk, sv, dst, src, blk_k, blk_v,
-                        filled_old):
-    """int8 copy-on-write: dequantize the SHARED block's rows
-    ``< filled_old``, merge with the freshly prefilled rows, requantize
-    the merged block (its own channel scales) into `dst`."""
-    sizes = (pool_k.shape[0], 1) + pool_k.shape[2:]
-    ssizes = (sk.shape[0], 1) + sk.shape[2:]
-    row = jnp.arange(pool_k.shape[2])[None, :, None, None]
+@functools.partial(jax.jit, donate_argnums=(0,))
+def _commit_blocks(pool, c, ids, cow):
+    """A whole commit's writes to ONE pool (keys or values) in one
+    program. `pool` is ``(blocks [L,N,bs,W],)`` in the cache dtype, or
+    ``(int8 blocks, f32 scales [L,N,1,W])``; `c` ``[L,S,H,hd]`` is a
+    prefill's fill as `_prefill_paged` returned it, cut into ``S // bs``
+    blocks where it lies. ``ids[i]`` is the pool row that takes the
+    prompt's block `i`; an id past the pool means "not written" (the
+    scatter drops it), so the shape is the same for every prompt length
+    and the program compiles once for a window and a pool.
 
-    def _old(pool, scales):
-        q = jax.lax.dynamic_slice(pool, (0, src, 0, 0, 0), sizes)[:, 0]
-        s = jax.lax.dynamic_slice(scales, (0, src, 0, 0, 0),
-                                  ssizes)[:, 0]
-        return q.astype(jnp.float32) * s
+    `cow` = (shared row, position, rows kept) is the commit's one
+    copy-on-write: the block at `position` takes its first `rows kept`
+    token rows from the SHARED pool row and the rest from `c` (0 rows
+    kept: no merge). The int8 pool merges in float32 (the shared rows
+    dequantized) and then quantizes every block by its own channel
+    scales. Temporaries are O(c), never O(pool)."""
+    data = pool[0]
+    layers, _, bs, width = data.shape
+    nb = ids.shape[0]
+    src, pos, kept = cow
+    blocks = c[:, :nb * bs].reshape((layers, nb, bs, width))
+    one = (layers, 1, bs, width)
+    shared = jax.lax.dynamic_slice(data, (0, src, 0, 0), one)
+    if len(pool) == 2:
+        blocks = blocks.astype(jnp.float32)
+        shared = shared.astype(jnp.float32) * jax.lax.dynamic_slice(
+            pool[1], (0, src, 0, 0), (layers, 1, 1, width))
+    at = (0, pos, 0, 0)
+    row = jnp.arange(bs)[None, None, :, None]
+    blocks = jax.lax.dynamic_update_slice(
+        blocks, jnp.where(row < kept, shared,
+                          jax.lax.dynamic_slice(blocks, at, one)), at)
+    if len(pool) == 1:
+        return (data.at[:, ids].set(blocks, mode="drop",
+                                    unique_indices=True),)
+    q, scale = _quantize(blocks)
+    return (data.at[:, ids].set(q, mode="drop", unique_indices=True),
+            _write_scales(pool[1], scale, ids))
 
-    merged_k = jnp.where(row < filled_old, _old(pool_k, sk),
-                         blk_k.astype(jnp.float32))
-    merged_v = jnp.where(row < filled_old, _old(pool_v, sv),
-                         blk_v.astype(jnp.float32))
-    qk, sck = _quantize(merged_k)
-    qv, scv = _quantize(merged_v)
-    at = (0, dst, 0, 0, 0)
-    return (jax.lax.dynamic_update_slice(pool_k, qk[:, None], at),
-            jax.lax.dynamic_update_slice(pool_v, qv[:, None], at),
-            jax.lax.dynamic_update_slice(sk, sck[:, None], at),
-            jax.lax.dynamic_update_slice(sv, scv[:, None], at))
+
+def _write_scales(scales, new, ids):
+    """``scales[:, ids[i]] = new[:, i]`` for the ids inside the pool,
+    one row at a time: a scale row is no whole tile, and XLA's scatter
+    would copy the scale pool to another layout and back, where a row's
+    update stays in place. An id past the pool rewrites the last row
+    with itself."""
+    last = scales.shape[1] - 1
+
+    def body(i, s):
+        at = jnp.minimum(ids[i], last)
+        row = jnp.where(ids[i] <= last,
+                        jax.lax.dynamic_slice_in_dim(new, i, 1, axis=1),
+                        jax.lax.dynamic_slice_in_dim(s, at, 1, axis=1))
+        return jax.lax.dynamic_update_slice_in_dim(s, row, at, axis=1)
+
+    return jax.lax.fori_loop(0, ids.shape[0], body, scales)
+
+
+@functools.partial(jax.jit, donate_argnums=(0, 1))
+def _write_block(pool_k, pool_v, bid, blk_k, blk_v):
+    """pool [L,N,bs,W] <- blk [L,bs,H,hd] at block row `bid` (a spilled
+    block's re-entry; a commit goes through `_commit_blocks`)."""
+    at = (0, bid, 0, 0)
+    return (jax.lax.dynamic_update_slice(pool_k, _flat(blk_k), at),
+            jax.lax.dynamic_update_slice(pool_v, _flat(blk_v), at))
+
+
+def _unflat(x, ntok, heads):
+    """Gathered blocks [L, n, rows, W] -> [L, n*rows, H, hd][:, :ntok]."""
+    ll, n, rows = x.shape[:3]
+    return x.reshape((ll, n * rows) + heads)[:, :ntok]
+
+
+@functools.partial(jax.jit, static_argnums=(3, 4))
+def _gather_prefix(pool_k, pool_v, bids, ntok, heads):
+    """Assemble a matched prefix: block rows `bids` concatenated along
+    the token axis, truncated to the matched token count (the tail
+    block may be partial), `heads` = (H, hd) apart again."""
+    return (_unflat(jnp.take(pool_k, bids, axis=1), ntok, heads),
+            _unflat(jnp.take(pool_v, bids, axis=1), ntok, heads))
 
 
 @functools.partial(jax.jit, donate_argnums=(0, 1, 2, 3))
 def _write_block_qraw(pool_k, pool_v, sk, sv, bid, qk, qv, sck, scv):
-    """Adopt an already-quantized wire-format block (tier-2/3 re-entry)
-    into the int8 pool VERBATIM — no requantize, so a spill/readopt
-    round trip is bit-exact for int8 pools."""
-    at = (0, bid, 0, 0, 0)
-    return (jax.lax.dynamic_update_slice(pool_k, qk[:, None], at),
-            jax.lax.dynamic_update_slice(pool_v, qv[:, None], at),
-            jax.lax.dynamic_update_slice(sk, sck[:, None], at),
-            jax.lax.dynamic_update_slice(sv, scv[:, None], at))
+    """Adopt an already-quantized wire-format block (tier-2/3 re-entry:
+    int8 [L,bs,H,hd], scales [L,1,H,hd]) into the int8 pool VERBATIM —
+    no requantize, so a spill/readopt round trip is bit-exact for int8
+    pools."""
+    at = (0, bid, 0, 0)
+    return (jax.lax.dynamic_update_slice(pool_k, _flat(qk), at),
+            jax.lax.dynamic_update_slice(pool_v, _flat(qv), at),
+            jax.lax.dynamic_update_slice(sk, _flat(sck), at),
+            jax.lax.dynamic_update_slice(sv, _flat(scv), at))
 
 
-@functools.partial(jax.jit, static_argnums=(5, 6))
-def _gather_prefix_q(pool_k, pool_v, sk, sv, bids, ntok, dtype):
+@functools.partial(jax.jit, static_argnums=(5, 6, 7))
+def _gather_prefix_q(pool_k, pool_v, sk, sv, bids, ntok, heads, dtype):
     """Dequant-on-gather: assemble a matched prefix out of the int8
     pool back into the cache dtype — downstream (suffix prefill,
     splice, decode) sees ordinary fp KV, so everything outside the
     quantized pool stays bit-exact plumbing."""
     def _deq(pool, scales):
-        q = jnp.take(pool, bids, axis=1)       # [L, n, bs, H, hd]
-        s = jnp.take(scales, bids, axis=1)     # [L, n, 1, H, hd]
-        x = (q.astype(jnp.float32) * s).astype(dtype)
-        ll, n, bs = x.shape[0], x.shape[1], x.shape[2]
-        return x.reshape((ll, n * bs) + x.shape[3:])[:, :ntok]
+        q = jnp.take(pool, bids, axis=1)       # [L, n, bs, W]
+        s = jnp.take(scales, bids, axis=1)     # [L, n, 1, W]
+        return _unflat((q.astype(jnp.float32) * s).astype(dtype), ntok,
+                       heads)
 
     return _deq(pool_k, sk), _deq(pool_v, sv)
-
-
-@functools.partial(jax.jit, static_argnums=(3,))
-def _extract_block(ck, cv, start, block_size):
-    """One block's rows ``[start, start+block_size)`` out of a filled
-    single-sequence cache ``[L, S, H, hd]`` (start traced: one compiled
-    program serves every block offset)."""
-    sizes = (ck.shape[0], block_size) + ck.shape[2:]
-    return (jax.lax.dynamic_slice(ck, (0, start, 0, 0), sizes),
-            jax.lax.dynamic_slice(cv, (0, start, 0, 0), sizes))
 
 
 # ----------------------------------------------------- prometheus (lazy)
@@ -420,13 +421,14 @@ class PagedKVCache:
         self.num_blocks = int(num_blocks)
         self.dtype = probe[0]["k"].dtype
         self.int8 = kv_int8_default() if int8 is None else bool(int8)
-        shape = (self.layers, self.num_blocks, self.block_size, heads,
-                 head_dim)
+        self._heads = (int(heads), int(head_dim))
+        shape = (self.layers, self.num_blocks, self.block_size,
+                 heads * head_dim)
         pool_dtype = jnp.int8 if self.int8 else self.dtype
         self._pool_k = jnp.zeros(shape, pool_dtype)
         self._pool_v = jnp.zeros(shape, pool_dtype)
         if self.int8:
-            sshape = (self.layers, self.num_blocks, 1, heads, head_dim)
+            sshape = (self.layers, self.num_blocks, 1, heads * head_dim)
             self._scale_k = jnp.zeros(sshape, jnp.float32)
             self._scale_v = jnp.zeros(sshape, jnp.float32)
         self._empty_k = jnp.zeros((self.layers, 0, heads, head_dim),
@@ -446,10 +448,10 @@ class PagedKVCache:
         # historical single-tier behavior bit-identically
         self._arena: Optional[Any] = None
         self._events: List[Dict[str, Any]] = []
-        # programs launched by block writes, extracts and copy-on-write
-        # merges (one each), and what the latest commit() made of them:
-        # (dispatches, blocks inserted) — the engine's loop record reads
-        # it on the thread that committed
+        # programs launched by commits (`_write_planned_locked`), and
+        # what the latest commit() made of them: (dispatches, blocks
+        # inserted) — the engine's loop record reads it on the thread
+        # that committed
         self._dispatches = 0
         self.last_commit: Tuple[int, int] = (0, 0)
         self._stats: Dict[str, int] = {
@@ -569,9 +571,10 @@ class PagedKVCache:
             if self.int8:
                 return _gather_prefix_q(self._pool_k, self._pool_v,
                                         self._scale_k, self._scale_v,
-                                        bids, match.tokens, self.dtype)
+                                        bids, match.tokens, self._heads,
+                                        self.dtype)
             return _gather_prefix(self._pool_k, self._pool_v, bids,
-                                  match.tokens)
+                                  match.tokens, self._heads)
 
     # ------------------------------------------------ tiered KV plane
 
@@ -587,21 +590,20 @@ class PagedKVCache:
 
     def _payload_locked(self, b: _Block) -> Dict[str, Any]:
         """One block's tier-2/3 wire-format payload: int8 K/V + f32
-        per-block-channel scales (``_write_block_q``'s layout) plus the
+        per-block-channel scales, heads and head_dim apart, plus the
         index identity needed to re-adopt it. int8 pools hand out their
         bytes verbatim (lossless round trip); fp pools quantize on
         spill, re-entering within the int8 tolerance contract."""
         bid = b.bid
         if self.int8:
-            qk = np.asarray(self._pool_k[:, bid])
-            qv = np.asarray(self._pool_v[:, bid])
-            sk = np.asarray(self._scale_k[:, bid])
-            sv = np.asarray(self._scale_v[:, bid])
+            wire = (self._pool_k[:, bid], self._scale_k[:, bid],
+                    self._pool_v[:, bid], self._scale_v[:, bid])
         else:
-            qk_j, sk_j = _quantize(self._pool_k[:, bid])
-            qv_j, sv_j = _quantize(self._pool_v[:, bid])
-            qk, sk = np.asarray(qk_j), np.asarray(sk_j)
-            qv, sv = np.asarray(qv_j), np.asarray(sv_j)
+            wire = (_quantize(self._pool_k[:, bid])
+                    + _quantize(self._pool_v[:, bid]))
+        # [L, rows, W] as the pool holds it -> the wire's [L, rows, H, hd]
+        qk, sk, qv, sv = (np.asarray(x).reshape(x.shape[:2] + self._heads)
+                          for x in wire)
         return {"index_key": b.index_key, "tokens": b.tokens,
                 "filled": b.filled, "ns": b.ns,
                 "parent_digest": b.parent_digest,
@@ -807,41 +809,31 @@ class PagedKVCache:
 
     # ------------------------------------------------------------ commit
 
-    def _write_locked(self, bid: int, bk, bv) -> None:
-        """One block write under the lock — the int8 pool quantizes on
-        commit (donated, O(block) in place either way)."""
-        self._dispatches += 1
-        if self.int8:
-            (self._pool_k, self._pool_v, self._scale_k,
-             self._scale_v) = _write_block_q(
-                self._pool_k, self._pool_v, self._scale_k,
-                self._scale_v, np.int32(bid), bk, bv)
-        else:
-            self._pool_k, self._pool_v = _write_block(
-                self._pool_k, self._pool_v, np.int32(bid), bk, bv)
-
-    def _cow_locked(self, dst: int, src: int, bk, bv,
-                    filled_old: int) -> None:
-        """Copy-on-write merge under the lock (int8: dequant the shared
-        rows, merge, requantize the widened block)."""
-        self._dispatches += 1
-        if self.int8:
-            (self._pool_k, self._pool_v, self._scale_k,
-             self._scale_v) = _cow_extend_block_q(
-                self._pool_k, self._pool_v, self._scale_k,
-                self._scale_v, np.int32(dst), np.int32(src), bk, bv,
-                np.int32(filled_old))
-        else:
-            self._pool_k, self._pool_v = _cow_extend_block(
-                self._pool_k, self._pool_v, np.int32(dst),
-                np.int32(src), bk, bv, np.int32(filled_old))
+    def _plan_cow_locked(self, cow: List[int], match: PrefixMatch,
+                         position: int) -> None:
+        """The commit's one copy-on-write: the block at `position`
+        widens the matched SHARED partial, which keeps its own pool row
+        and stays indexed for future shorter matches."""
+        cow[:] = (match.partial_bid, position, match.partial_len)
         self._stats["cow_copies"] += 1
         kvcache_metrics()["cow_copies"].inc()
 
-    def _extract_locked(self, ck, cv, start: int):
-        """One block's rows out of a prefill's fill, under the lock."""
-        self._dispatches += 1
-        return _extract_block(ck, cv, np.int32(start), self.block_size)
+    def _write_planned_locked(self, ck, cv, ids, cow) -> None:
+        """Everything a commit planned, in place: `_commit_blocks` once
+        for the keys' pool and once for the values' (one compiled
+        program, two launches)."""
+        cow = tuple(np.int32(x) for x in cow)
+        if self.int8:
+            self._pool_k, self._scale_k = _commit_blocks(
+                (self._pool_k, self._scale_k), ck, ids, cow)
+            self._pool_v, self._scale_v = _commit_blocks(
+                (self._pool_v, self._scale_v), cv, ids, cow)
+        else:
+            (self._pool_k,) = _commit_blocks((self._pool_k,), ck, ids,
+                                             cow)
+            (self._pool_v,) = _commit_blocks((self._pool_v,), cv, ids,
+                                             cow)
+        self._dispatches += 2
 
     def note_prefilled(self, n_tokens: int) -> None:
         with self._lock:
@@ -856,7 +848,14 @@ class PagedKVCache:
         request's pinned block table (matched + inserted). Stops quietly
         when the pool is exhausted — caching is best-effort, the slot's
         own slab copy is already correct. `namespace` must match the
-        paired lookup()'s."""
+        paired lookup()'s.
+
+        Two steps under the lock. The PLAN is host bookkeeping alone:
+        walk the blocks, reuse what is indexed, allocate (and evict) for
+        what is not, index it, and note its pool row at its position in
+        `ids`. The WRITE is `_commit_blocks` on each pool, whatever the
+        number of blocks; a commit that planned nothing launches
+        nothing."""
         tokens = np.asarray(tokens).reshape(-1)
         bs = self.block_size
         plen = len(tokens)
@@ -868,6 +867,12 @@ class PagedKVCache:
             now = next(self._tick)
             parent: Optional[int] = None
             exhausted = False
+            # position i: the pool row for the prompt's block i; rows
+            # past the pool (each its own, the scatter's indices are
+            # unique) are not written
+            ids = self.num_blocks + np.arange(ck.shape[1] // bs,
+                                              dtype=np.int32)
+            cow = [0, 0, 0]
             for i in range(n_full):
                 blk = tuple(int(t) for t in tokens[i * bs:(i + 1) * bs])
                 nxt = _chain(digest, blk)
@@ -887,25 +892,23 @@ class PagedKVCache:
                 if bid is None:
                     exhausted = True
                     break
-                bk, bv = self._extract_locked(ck, cv, i * bs)
                 if (i == match.full_blocks
                         and match.partial_bid is not None):
                     # the matched SHARED partial block sits at this
                     # position and this prompt widens it to a full
-                    # block: copy-on-write (the original stays indexed
-                    # for future shorter matches)
-                    self._cow_locked(bid, match.partial_bid, bk, bv,
-                                     match.partial_len)
-                else:
-                    self._write_locked(bid, bk, bv)
+                    # block
+                    self._plan_cow_locked(cow, match, i)
+                ids[i] = bid
                 self._insert_locked(bid, ("full", nxt), blk, bs, parent,
                                     now, namespace, digest)
                 table.append(bid)
                 parent, digest = bid, nxt
             if tail and not exhausted:
-                self._commit_tail_locked(tokens, ck, cv, match, digest,
-                                         parent, n_full, tail, table,
-                                         now, namespace)
+                self._plan_tail_locked(tokens, ids, cow, match, digest,
+                                       parent, n_full, tail, table, now,
+                                       namespace)
+            if self._stats["inserted_blocks"] > before[1]:
+                self._write_planned_locked(ck, cv, ids, cow)
             util = 1.0 - len(self._free) / self.num_blocks
             self.last_commit = (
                 self._dispatches - before[0],
@@ -913,16 +916,16 @@ class PagedKVCache:
         kvcache_metrics()["utilization"].set(util)
         return table
 
-    def _commit_tail_locked(self, tokens, ck, cv, match, digest, parent,
-                            n_full, tail, table, now,
-                            namespace: Optional[str] = None) -> None:
-        bs = self.block_size
-        if (n_full + 1) * bs > ck.shape[1]:
+    def _plan_tail_locked(self, tokens, ids, cow, match, digest, parent,
+                          n_full, tail, table, now,
+                          namespace: Optional[str] = None) -> None:
+        if n_full >= len(ids):
             # the tail block's nominal extent crosses the cache window
             # (block_size not dividing max_seq_len, prompt near max):
-            # dynamic_slice would clamp the start and cache shifted
-            # rows — skip caching this tail, correctness first
+            # the fill has no whole block there — skip caching this
+            # tail, correctness first
             return
+        bs = self.block_size
         tail_toks = tuple(int(t) for t in tokens[n_full * bs:])
         # the matched partial is the TAIL's predecessor only when it sat
         # at the final block position (otherwise it was widened to a
@@ -942,14 +945,10 @@ class PagedKVCache:
         bid = self._alloc_locked()
         if bid is None:
             return
-        bk, bv = self._extract_locked(ck, cv, n_full * bs)
         if tail_partial is not None:
-            # extending a SHARED cached block: copy-on-write — the old
-            # entry stays indexed for future shorter matches
-            self._cow_locked(bid, tail_partial, bk, bv,
-                             match.partial_len)
-        else:
-            self._write_locked(bid, bk, bv)
+            # extending a SHARED cached block
+            self._plan_cow_locked(cow, match, n_full)
+        ids[n_full] = bid
         self._insert_locked(bid, ("partial", digest, tail_toks),
                             tail_toks, tail, parent, now, namespace,
                             digest)

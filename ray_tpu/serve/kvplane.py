@@ -9,7 +9,7 @@ bounded by one replica's HBM.
   unchanged.
 - **Tier 2** (``HostArena``) is a bounded per-replica host-RAM arena.
   A block evicted from the HBM pool under pressure spills its
-  int8+per-block-channel-scales wire-format payload (``_write_block_q``'s
+  int8+per-block-channel-scales wire-format payload (``_payload_locked``'s
   layout) here instead of dying; a later lookup whose chain walk breaks
   re-adopts the block through the pool's normal insert path. LRU within
   the arena, byte-bounded (``RAY_TPU_KVPLANE_ARENA_BYTES``). int8 pools

@@ -159,6 +159,200 @@ def test_allocator_skips_tail_crossing_cache_window():
     pool.release(m.bids)
 
 
+# ------------------------- one program a commit vs a block at a time
+
+def _np_quantize(block):
+    """[L, bs, W] float32 -> (int8, float32 scales [L, 1, W]): the int8
+    pool's per-block-channel quantization, in numpy."""
+    f = np.asarray(block, np.float32)
+    amax = np.max(np.abs(f), axis=1, keepdims=True)
+    scale = np.where(amax > 0, amax / np.float32(127.0),
+                     np.float32(1.0)).astype(np.float32)
+    return np.clip(np.round(f / scale), -127, 127).astype(np.int8), scale
+
+
+class _BlockAtATime:
+    """A plain reference for ``PagedKVCache.commit``: the same walk over
+    the prompt's blocks, each new block cut out of the fill and written
+    to its pool row on its own (a copy-on-write first takes the shared
+    row's leading token rows), in numpy. It shares the pool's allocator
+    and index, and holds the device arrays' content itself."""
+
+    def __init__(self, pool):
+        self.pool = pool
+        self.k = np.zeros(pool._pool_k.shape, np.float32)
+        self.v = np.zeros(pool._pool_v.shape, np.float32)
+        self.sk = self.sv = None
+        if pool.int8:
+            self.sk = np.zeros(pool._scale_k.shape, np.float32)
+            self.sv = np.zeros(pool._scale_v.shape, np.float32)
+
+    def _write(self, data, scales, bid, fill, start, cow):
+        bs = self.pool.block_size
+        block = np.asarray(fill, np.float32)[:, start:start + bs]
+        block = block.reshape(block.shape[:2] + (-1,))
+        if cow is not None:
+            src, kept = cow
+            old = data[:, src] * (scales[:, src] if self.pool.int8
+                                  else np.float32(1.0))
+            block = np.where(np.arange(bs)[None, :, None] < kept, old,
+                             block)
+        if self.pool.int8:
+            data[:, bid], scales[:, bid] = _np_quantize(block)
+        else:
+            data[:, bid] = block
+
+    def _new_block(self, bid, ck, cv, start, cow=None):
+        p = self.pool
+        if cow is not None:
+            p._stats["cow_copies"] += 1
+        self._write(self.k, self.sk, bid, ck, start, cow)
+        self._write(self.v, self.sv, bid, cv, start, cow)
+
+    def commit(self, tokens, ck, cv, match):
+        from ray_tpu.models.kvcache import _chain, _ns_root
+        p, bs = self.pool, self.pool.block_size
+        tokens = np.asarray(tokens).reshape(-1)
+        n_full, tail = divmod(len(tokens), bs)
+        inserted = p._stats["inserted_blocks"]
+        table, digest, parent = list(match.bids), _ns_root(None), None
+        now = next(p._tick)
+        for i in range(n_full):
+            blk = tuple(int(t) for t in tokens[i * bs:(i + 1) * bs])
+            nxt = _chain(digest, blk)
+            if i < match.full_blocks:
+                parent, digest = match.bids[i], nxt
+                continue
+            bid = p._full_index.get(nxt)
+            if bid is not None:
+                p._blocks[bid].ref += 1
+                p._blocks[bid].last_used = now
+            else:
+                bid = p._alloc_locked()
+                if bid is None:
+                    return table, p._stats["inserted_blocks"] - inserted
+                widened = (i == match.full_blocks
+                           and match.partial_bid is not None)
+                self._new_block(bid, ck, cv, i * bs,
+                                (match.partial_bid, match.partial_len)
+                                if widened else None)
+                p._insert_locked(bid, ("full", nxt), blk, bs, parent,
+                                 now, None, digest)
+            table.append(bid)
+            parent, digest = bid, nxt
+        partial = (match.partial_bid if match.full_blocks == n_full
+                   else None)
+        if (tail and (n_full + 1) * bs <= np.shape(ck)[1]
+                and not (partial is not None
+                         and match.partial_len == tail)):
+            toks = tuple(int(t) for t in tokens[n_full * bs:])
+            bid = p._partial_index.get(digest, {}).get(toks)
+            if bid is not None:
+                p._blocks[bid].ref += 1
+                p._blocks[bid].last_used = now
+                table.append(bid)
+            else:
+                bid = p._alloc_locked()
+                if bid is not None:
+                    self._new_block(bid, ck, cv, n_full * bs,
+                                    (partial, match.partial_len)
+                                    if partial is not None else None)
+                    p._insert_locked(bid, ("partial", digest, toks),
+                                     toks, tail, parent, now, None,
+                                     digest)
+                    table.append(bid)
+        return table, p._stats["inserted_blocks"] - inserted
+
+    def block(self, bid):
+        """(k, v) of one pool row as ``gather`` hands them back."""
+        p = self.pool
+        out = []
+        for data, scales in ((self.k, self.sk), (self.v, self.sv)):
+            x = data[:, bid]
+            if p.int8:
+                x = (x * scales[:, bid]).astype(p.dtype)
+            out.append(x.reshape(x.shape[:2] + p._heads))
+        return out
+
+
+def _tok(start, n):
+    return np.arange(start, start + n, dtype=np.int32)
+
+
+# name -> (block size, pool blocks, steps); a step is (tokens, the most
+# a lookup may match, the fill's seed, release the table afterwards)
+_BASE6 = _tok(1, 6)
+_COMMIT_CASES = {
+    "miss": (BS, 8, [(_tok(1, 8), 7, 10, False)]),
+    "full_hit_extended": (BS, 8, [
+        (_tok(1, 8), 7, 11, False),
+        (np.concatenate([_tok(1, 8), _tok(40, 9)]), 16, 12, False)]),
+    "shared_partial_widened_to_a_full_block": (BS, 8, [
+        (_BASE6, 5, 13, False),
+        (np.concatenate([_BASE6, _tok(50, 4)]), 9, 14, False)]),
+    "shared_partial_widened_in_the_tail": (BS, 8, [
+        (_BASE6, 5, 15, False),
+        (np.concatenate([_BASE6, _tok(60, 1)]), 6, 16, False)]),
+    "partial_tail": (BS, 8, [(_tok(1, 7), 6, 17, False),
+                             (_tok(1, 7), 6, 18, False)]),
+    "tail_crosses_the_window": (24, 8, [(_tok(1, 122), 121, 19, False)]),
+    "pool_exhausted_mid_commit": (BS, 3, [(_tok(1, 18), 17, 20, False)]),
+    "window_not_divisible_by_the_block": (24, 8, [
+        (_tok(1, 50), 49, 21, False),
+        (np.concatenate([_tok(1, 48), _tok(90, 30)]), 77, 22, False)]),
+    "evicts_the_least_recently_used": (BS, 4, [
+        (_tok(1, 8), 7, 23, True), (_tok(20, 8), 7, 24, True),
+        (_tok(40, 10), 9, 25, False)]),
+}
+
+
+@pytest.mark.parametrize("int8", [False, True], ids=["float", "int8"])
+@pytest.mark.parametrize("case", sorted(_COMMIT_CASES))
+def test_commit_in_one_program_equals_a_block_at_a_time(case, int8):
+    """What `commit` leaves in the pool, returns and counts, against the
+    reference that writes one block at a time: two pools go through the
+    same steps, one committed by the program under test, the other by
+    `_BlockAtATime`."""
+    bs, blocks, steps = _COMMIT_CASES[case]
+    pool = PagedKVCache(CFG, block_size=bs, num_blocks=blocks, int8=int8)
+    twin = PagedKVCache(CFG, block_size=bs, num_blocks=blocks, int8=int8)
+    ref = _BlockAtATime(twin)
+    for tokens, most, seed, release in steps:
+        ck, cv = _fake_kv(seed)
+        match, match_ref = (p.lookup(tokens, most) for p in (pool, twin))
+        assert (match.bids, match.tokens, match.partial_bid) == (
+            match_ref.bids, match_ref.tokens, match_ref.partial_bid)
+        before = pool.stats()["inserted_blocks"]
+        table = pool.commit(tokens, ck, cv, match)
+        table_ref, inserted_ref = ref.commit(tokens, ck, cv, match_ref)
+        assert table == table_ref
+        assert pool.last_commit[1] == inserted_ref \
+            == pool.stats()["inserted_blocks"] - before
+        # a commit is its one program on each of the two pools, or none
+        assert pool.last_commit[0] == (2 if inserted_ref else 0)
+        assert pool.stats() == twin.stats()
+        for bid in range(blocks):
+            one = type(match)([bid], bs, 1, None, 0, "hit")
+            got_k, got_v = pool.gather(one)
+            want_k, want_v = ref.block(bid)
+            # the float pool holds the fill's bits; a scale computed by
+            # XLA and by numpy may differ in its last place
+            tol = dict(rtol=1e-6, atol=1e-6) if int8 else dict(rtol=0)
+            np.testing.assert_allclose(np.asarray(got_k), want_k, **tol)
+            np.testing.assert_allclose(np.asarray(got_v), want_v, **tol)
+        if release:
+            pool.release(table)
+            twin.release(table_ref)
+    if case == "pool_exhausted_mid_commit":
+        assert len(table) == 3 and pool.stats()["free_blocks"] == 0
+    if case == "tail_crosses_the_window":
+        assert len(table) == 5
+    if case.startswith("shared_partial"):
+        assert pool.stats()["cow_copies"] == 1
+    if case == "evicts_the_least_recently_used":
+        assert pool.stats()["evictions"] == 3
+
+
 # ------------------------------------------------ engine bit-identity
 
 def test_cached_engine_bit_identical_to_uncached(model):
