@@ -15,7 +15,11 @@ works on an oversubscribed host (pure spinning burns whole scheduler quanta
 on a 1-core box, and sched_yield is a near no-op under EEVDF).
 
 Header layout (24 bytes): seq u64 | ack u64 | payload_len u64. A seq of
-2**64-1 marks the channel closed."""
+2**64-1 marks the channel closed. Each word has ONE writer (seq and
+payload_len the writer's, ack the reader's) and is stored whole through a
+u64 view of the header: `struct.pack_into` zeroes its whole span before it
+fills it, and a peer that spins on the header then reads seq 0 (a message
+that was never sent) or ack == seq == 0 (a slot that is not free)."""
 from __future__ import annotations
 
 import os
@@ -46,13 +50,15 @@ class Channel:
         if _attach_name is None:
             self._shm = shared_memory.SharedMemory(
                 create=True, size=_HDR.size + capacity)
-            self._shm.buf[:_HDR.size] = _HDR.pack(0, 0, 0)
+            self._shm.buf[:_HDR.size] = _HDR.pack(0, 0, 0)  # no peer yet
             self._owner = True
             for path in (self._fifo_path("d"), self._fifo_path("a")):
                 os.mkfifo(path)
         else:
             self._shm = shared_memory.SharedMemory(name=_attach_name)
             self._owner = False
+        # [seq, ack, payload_len]; released before the segment is closed
+        self._hdr = self._shm.buf[:_HDR.size].cast("Q")
         self._fd_data: Optional[int] = None
         self._fd_ack: Optional[int] = None
 
@@ -104,8 +110,9 @@ class Channel:
                 f"buffer_size_bytes")
         deadline = None if timeout is None else time.monotonic() + timeout
         spins = 0
+        hdr = self._hdr
         while True:
-            seq, ack, _ = _HDR.unpack_from(self._shm.buf, 0)
+            seq, ack = hdr[0], hdr[1]
             if seq == _CLOSED:
                 raise ChannelClosedError
             if ack == seq:  # previous value consumed — slot free
@@ -117,7 +124,8 @@ class Channel:
                         "channel writer timed out waiting for ack")
                 self._park("a", deadline)
         self._shm.buf[_HDR.size:_HDR.size + len(payload)] = payload
-        _HDR.pack_into(self._shm.buf, 0, seq + 1, ack, len(payload))
+        hdr[2] = len(payload)
+        hdr[0] = seq + 1  # publishes the payload: last
         self._ring("d")
 
     # -- reader side --------------------------------------------------------
@@ -125,13 +133,14 @@ class Channel:
              ) -> Tuple[int, bytes]:
         deadline = None if timeout is None else time.monotonic() + timeout
         spins = 0
+        hdr = self._hdr
         while True:
-            seq, ack, length = _HDR.unpack_from(self._shm.buf, 0)
+            seq = hdr[0]
             if seq == _CLOSED:
                 raise ChannelClosedError
             if seq != last_seq:
-                data = bytes(self._shm.buf[_HDR.size:_HDR.size + length])
-                _HDR.pack_into(self._shm.buf, 0, seq, seq, length)  # ack
+                data = bytes(self._shm.buf[_HDR.size:_HDR.size + hdr[2]])
+                hdr[1] = seq  # ack
                 self._ring("a")
                 return seq, data
             spins += 1
@@ -143,7 +152,7 @@ class Channel:
     # -- lifecycle ----------------------------------------------------------
     def close(self) -> None:
         try:
-            _HDR.pack_into(self._shm.buf, 0, _CLOSED, 0, 0)
+            self._hdr[0] = _CLOSED
             self._ring("d")
             self._ring("a")
         except Exception:  # noqa: BLE001 — already unlinked
@@ -159,6 +168,7 @@ class Channel:
                     pass
                 setattr(self, attr, None)
         try:
+            self._hdr.release()
             self._shm.close()
         except Exception:  # noqa: BLE001
             pass

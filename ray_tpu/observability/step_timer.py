@@ -65,8 +65,8 @@ def summarize_records(records, ema_alpha: float = _EMA_ALPHA
     """Per-phase summary over a window of step records (the StepTimer
     record schema: ``<phase>_ms`` keys plus ``other_ms``/``total_ms``):
     mean / p50 / p99 plus a trailing EMA in record order — the ONE
-    derivation shared by the oracle validation harness, the conductor's
-    train_progress aggregation, and bench.py, instead of each
+    derivation shared by the oracle validation harness and the
+    conductor's train_progress aggregation, instead of each
     re-deriving stats from raw records."""
     phases: Dict[str, Dict[str, float]] = {}
     for name in (*PHASES, "other", "total"):

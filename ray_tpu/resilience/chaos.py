@@ -404,8 +404,7 @@ class ServeChaosMonkey:
         return n
 
     def reset_counts(self) -> None:
-        """Zero the cumulative request/token counters. bench_serve
-        calls this on every replica at measurement start, so a plan's
+        """Zero the cumulative request/token counters, so a plan's
         ``at=request:N`` / ``at=token:K`` counts the Nth MEASURED
         request / Kth measured token instead of including warm-up
         traffic (the PR-12 known limit). LETHAL latches persist — a

@@ -70,8 +70,8 @@ mirrored into the resilience lane — they ARE the grace flow).
 Knobs (env, all overridable per-instance): RAY_TPU_AUTOSCALE_TARGET_P99_MS
 (the SLO), RAY_TPU_AUTOSCALE_UP_DELAY_S / _DOWN_DELAY_S / _COOLDOWN_S
 (hysteresis), RAY_TPU_AUTOSCALE_INTERVAL_S (tick), _DRAIN_GRACE_S (the
-drain window), _WINDOW_S (signal recency). The acceptance benchmark is
-``python -m ray_tpu.bench_serve --autoscale --compare-static``.
+drain window), _WINDOW_S (signal recency). The zero-dropped drain is
+held by ``tests/test_autoscale.py::test_scale_down_drains_zero_dropped_inflight``.
 """
 from __future__ import annotations
 

@@ -71,8 +71,8 @@ bound per decode replica, default 8), ``RAY_TPU_DISAGG_RETRY_AFTER_S``
 (shed hint, default 1.0), ``RAY_TPU_FAILOVER_ATTEMPTS`` (bounded
 failover budget, default 2), ``RAY_TPU_MAX_ADOPTIONS_PER_TICK`` (decode
 adoption cap, models/engine.py), plus the kvcache knobs on the prefill
-tier. The open-loop acceptance benchmark lives in
-``ray_tpu/bench_serve.py`` (``--chaos`` for the fault-injection run).
+tier. The bit-identity and zero-dropped invariants are held by
+``tests/test_disagg.py`` and ``tests/test_servefault.py``.
 """
 from __future__ import annotations
 
@@ -782,26 +782,6 @@ class PrefillServer:
             return False
         return self.lora_pool.refresh(tenant)
 
-    def reset_chaos_counts(self) -> bool:
-        """Zero the chaos monkey's request/token counters so a
-        `kill_replica at=request:N` plan counts from the MEASURED
-        phase, not from warm-up traffic (bench_serve calls this at
-        measurement start)."""
-        if self._chaos is not None:
-            self._chaos.reset_counts()
-        return self._chaos is not None
-
-    def invalidate_prefix_cache(self) -> bool:
-        """Drop the whole prefix index (every namespace). bench_serve's
-        bit-identity verdict calls it before the sequential re-runs so
-        they re-prefill cache-cold — the re-check then covers the
-        prefill path too, instead of replaying whatever the mixed run
-        cached."""
-        if self.kv_cache is None:
-            return False
-        self.kv_cache.invalidate()
-        return True
-
     def prepare_for_shutdown(self, timeout_s: float = 30.0) -> bool:
         """Grace drain (the serve/replica.py shape, reused by autoscale
         scale-down): wait until every published transfer has been acked
@@ -1214,12 +1194,6 @@ class DecodeServer:
         if self.lora_pool is None:
             return False
         return self.lora_pool.refresh(tenant)
-
-    def reset_chaos_counts(self) -> bool:
-        """Zero the chaos monkey's counters (see PrefillServer twin)."""
-        if self._chaos is not None:
-            self._chaos.reset_counts()
-        return self._chaos is not None
 
     def prepare_for_shutdown(self, timeout_s: float = 30.0) -> bool:
         """Grace drain (the serve/replica.py shape, reused by autoscale
@@ -2232,9 +2206,9 @@ class DisaggRouter:
         """One request end-to-end. `on_first_token()` (optional) fires
         the moment the first token exists — at prefill completion under
         disaggregation — which is what the harness's TTFT measures.
-        `token_sleep_s` simulates a slow client consuming the stream
-        (bench_serve.py's backpressure knob): decode ticks must keep
-        serving OTHER requests while this one drains slowly.
+        `token_sleep_s` simulates a slow client consuming the stream:
+        decode ticks must keep serving OTHER requests while this one
+        drains slowly.
         `deadline_s` bounds the request's total wall time — past it the
         request sheds with cause ``deadline`` instead of occupying a
         slot forever.
@@ -2790,17 +2764,6 @@ class DisaggRouter:
                         pslot.cancel_fn = None
 
     # ------------------------------------------------------------ telemetry
-
-    def reset_signal_windows(self) -> None:
-        """Fresh recent-signal windows. Callers that warm compile
-        caches through the router (bench_serve's off-the-clock phase)
-        reset before attaching an autoscaler — multi-second first
-        compiles would otherwise read as a TTFT-SLO breach for a whole
-        window and trigger spurious scale-ups."""
-        self._ttft_win = SlidingWindow()
-        self._depth_win = SlidingWindow()
-        self._pf_inflight_win = SlidingWindow()
-        self._cache_win = SlidingWindow()
 
     def signals(self) -> Dict[str, Any]:
         """The autoscale policy's input snapshot (recent windows; keys

@@ -41,7 +41,7 @@ The tiny research checkpoints ship no tokenizer, so the default
 and renders token ids as space-joined integers on decode — every
 surface stays bit-checkable against the engine oracle. ``prompt`` may
 also be a raw token-id list (the OpenAI array-of-tokens form), which
-is what bench_serve --http and the tests drive.
+is what the tests drive.
 
 Per repo convention the gateway gets the full surface treatment:
 ``util.state.gateway_status()``, ``ray_tpu gateway``, dashboard
@@ -218,11 +218,6 @@ class GatewayServer:
             self._chaos_fired = False
             return True
         return False
-
-    def reset_chaos_counts(self) -> bool:
-        if self._chaos is not None:
-            self._chaos.reset_counts()
-        return True
 
     # ------------------------------------------------------- accounting
 
@@ -461,10 +456,10 @@ class GatewayServer:
                 hdr = request.headers.get("X-Request-Deadline")
                 if hdr:
                     deadline_s = float(hdr)
-                # bench/test extension: router-side slow-client pacing
-                # (bench_serve's backpressure knob) — tiny research
-                # checkpoints decode faster than any real socket, so
-                # real-pacing scenarios need the stream held open
+                # test extension: router-side slow-client pacing —
+                # tiny research checkpoints decode faster than any
+                # real socket, so real-pacing scenarios need the
+                # stream held open
                 token_sleep_s = min(
                     1.0, max(0.0, float(body.get("token_sleep_s", 0))))
             except (TypeError, ValueError) as e:

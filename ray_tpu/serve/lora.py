@@ -56,8 +56,8 @@ Surfaces (the full treatment): ``util.state.lora_status()``,
 ``ray_tpu_lora_pool_utilization``, and a ``lora`` merged-timeline lane
 with page_in / evict / swap instant markers. Knobs:
 ``RAY_TPU_LORA_POOL_SLOTS`` (adapter rows beside the null row, default
-8), ``RAY_TPU_LORA_RANK_MAX`` (pool rank ceiling, default 8). The
-acceptance benchmark is ``bench_serve --tenants N --tenant-zipf``.
+8), ``RAY_TPU_LORA_RANK_MAX`` (pool rank ceiling, default 8). Paging
+and isolation are held by ``tests/test_lora.py`` (pool and router tests).
 """
 from __future__ import annotations
 

@@ -1,5 +1,5 @@
 """JAX's persistent compilation cache, enabled the same way by every
-process that will own a chip (chip_smoke.py's and bench.py's children,
+process that will own a chip (chip_smoke.py's children, the benchmark,
 chip workers): compiled programs are shared between those processes and
 found again by the next run.
 

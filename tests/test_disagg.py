@@ -242,46 +242,59 @@ def test_serve_router_sheds_with_max_queued_requests(disagg_cluster):
 
 # ------------------------------------------------ load harness smoke
 
-def test_load_harness_smoke_records_and_sheds(model):
-    """bench_serve.run_load at tiny config: the record carries the
-    acceptance metrics (TTFT p50/p99, tokens/s, shed rate) and under a
-    burst past capacity the shed knee engages while the queue bound
-    holds."""
-    from ray_tpu import bench_serve
+def test_burst_past_capacity_sheds_with_cause_and_is_recorded(model):
+    """16 callers released together against 2 slots + queue depth 1:
+    the overflow sheds with an attributed cause while the rest
+    complete, nothing errors, the pending high-water holds at capacity
+    + depth, and the flight recorder's report over exactly these
+    requests names a tail owner and the slowest request's phases."""
+    from ray_tpu.observability import requests as reqtrace
 
     eng = _colocated_engine(model, max_batch=2)
     router = DisaggRouter(colocated=eng, max_queue_depth=1)
-    prompts = bench_serve.make_prompts(CFG, n_distinct=4, block_size=BS,
-                                       seed=0)
+    n = 16
+    lock = threading.Lock()
+    completed, shed_causes, errors = [], [], []
+    start = threading.Barrier(n)
+
+    def one(i):
+        start.wait(timeout=60)
+        try:
+            toks = router.generate([1, 2, 3, 4 + i], 4)
+            with lock:
+                completed.append(len(toks))
+        except RequestShedError as e:
+            with lock:
+                shed_causes.append(getattr(e, "cause", None))
+        except Exception as e:  # noqa: BLE001 — the assertion below
+            with lock:
+                errors.append(repr(e))
+
+    threads = [threading.Thread(target=one, args=(i,)) for i in range(n)]
     try:
-        for p in prompts:
-            router.generate(p, 2)  # warm compiles off the clock
-        rec = bench_serve.run_load(
-            router, prompts, n_requests=16, max_new_tokens=4,
-            rate_rps=64.0, arrival="burst", burst_size=16,
-            slow_client_frac=0.25, token_sleep_s=0.01, seed=0)
+        router.generate([1, 2, 3, 4], 2)  # compile before the burst
+        seq0 = reqtrace.store().seq()
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
     finally:
         eng.stop()
-    for key in ("ttft_p50_ms", "ttft_p99_ms", "tokens_per_sec",
-                "shed_rate", "completed", "shed"):
-        assert key in rec, key
-    assert rec["completed"] >= 1
-    assert rec["errors"] == 0
-    assert rec["shed"] >= 1 and rec["shed_rate"] > 0
-    assert rec["ttft_p50_ms"] is not None
-    # the flight recorder's report rides along in every bench record:
-    # p99 attribution + the top slowest requests' phase breakdowns
-    rt = rec.get("request_trace")
-    assert rt is not None and rt["n_traced"] >= rec["completed"]
-    assert "tail_owner" in rt["p99_attribution"]
-    assert rt["slowest"] and rt["slowest"][0]["phase_ms"]
+    assert not any(t.is_alive() for t in threads)
+    assert errors == []
+    assert len(completed) + len(shed_causes) == n
+    assert completed and all(c == 4 for c in completed)
+    assert shed_causes and all(shed_causes), shed_causes
+    st = router.stats()
+    assert st["shed"] == len(shed_causes)
+    assert sum(st["sheds_by_cause"].values()) == len(shed_causes)
     # shedding engaged BEFORE queue depth became unbounded
-    assert router.stats()["max_pending"] <= 2 + 1
-    # arrival schedules are well-formed for every shape
-    for shape in ("uniform", "burst", "diurnal"):
-        offs = bench_serve.arrival_offsets(16, 8.0, shape)
-        assert len(offs) == 16
-        assert all(b >= a for a, b in zip(offs, offs[1:]))
+    assert st["max_pending"] <= 2 + 1
+    traces = reqtrace.store().summaries_since(seq0)
+    assert len(traces) >= len(completed)
+    assert "tail_owner" in reqtrace.p99_attribution(traces)
+    slowest = max(traces, key=lambda s: s.get("total_ms", 0.0))
+    assert slowest["phase_ms"]
 
 
 # ----------------------------------------------- e2e surface check

@@ -79,6 +79,22 @@ def stack(model):
     engine.stop()
 
 
+@pytest.fixture
+def full_tier(model):
+    """One decode slot and no queue behind the gateway: whoever holds
+    the slot fills the tier."""
+    engine = ContinuousBatchingEngine(model, CFG, max_batch=1)
+    router = DisaggRouter(colocated=engine, max_queue_depth=0)
+    gw = GatewayServer(router, model="tiny",
+                       vocab_size=CFG.vocab_size,
+                       qos=QosGate(router=router), max_tokens_cap=800)
+    host, port = gw.ready()
+    yield SimpleNamespace(engine=engine, router=router, host=host,
+                          port=port)
+    gw.stop()
+    engine.stop()
+
+
 def _post(host, port, path, body=None, headers=None, raw=None,
           timeout=60.0):
     conn = http.client.HTTPConnection(host, port, timeout=timeout)
@@ -241,62 +257,96 @@ def test_chat_stream_matches_chat_nonstream(stack):
 # ------------------------------------------- preemption bit-identity
 
 
-def test_preempted_batch_stream_is_bit_identical(model):
+def test_preempted_batch_stream_is_bit_identical(full_tier):
     """An interactive arrival on a full tier preempts a batch slot;
     the preempted stream replays with its history and must still
     deliver EXACTLY the uninterrupted greedy decode."""
-    engine = ContinuousBatchingEngine(model, CFG, max_batch=1)
-    router = DisaggRouter(colocated=engine, max_queue_depth=0)
-    gw = GatewayServer(router, model="tiny",
-                       vocab_size=CFG.vocab_size,
-                       qos=QosGate(router=router), max_tokens_cap=800)
-    host, port = gw.ready()
-    try:
-        prompt, n = [7, 8, 9], 600
-        expected = _oracle_text(engine, prompt, n)
+    engine, router = full_tier.engine, full_tier.router
+    host, port = full_tier.host, full_tier.port
+    prompt, n = [7, 8, 9], 600
+    expected = _oracle_text(engine, prompt, n)
 
-        out = {}
+    out = {}
 
-        def batch_client():
-            conn, resp = _post(host, port, "/v1/completions",
-                               body={"model": "tiny", "prompt": prompt,
-                                     "max_tokens": n, "stream": True,
-                                     "priority": "batch"},
-                               timeout=180.0)
-            chunks, saw_done = _drain_sse(resp)
-            out["batch"] = ("".join(c["choices"][0]["text"]
-                                    for c in chunks), saw_done,
-                            resp.status)
-            conn.close()
-
-        th = threading.Thread(target=batch_client, daemon=True)
-        th.start()
-        # land inside the engine-production window of the 600-token
-        # batch decode, with the single slot occupied -> must preempt
-        time.sleep(0.8)
+    def batch_client():
         conn, resp = _post(host, port, "/v1/completions",
-                           body={"model": "tiny", "prompt": [4, 5],
-                                 "max_tokens": 16,
-                                 "priority": "interactive"},
-                           timeout=120.0)
-        assert resp.status == 200
-        inter = json.loads(resp.read())["choices"][0]["text"]
+                           body={"model": "tiny", "prompt": prompt,
+                                 "max_tokens": n, "stream": True,
+                                 "priority": "batch"},
+                           timeout=180.0)
+        head, _ = _drain_sse(resp, stop_after=1)
+        streaming.set()
+        chunks, saw_done = _drain_sse(resp)
+        out["batch"] = ("".join(c["choices"][0]["text"]
+                                for c in head + chunks), saw_done,
+                        resp.status)
         conn.close()
-        assert inter == _oracle_text(engine, [4, 5], 16)
-        th.join(timeout=120)
-        assert not th.is_alive()
 
-        text, saw_done, status = out["batch"]
-        assert status == 200 and saw_done
-        assert text == expected
-        rt = router.stats()
-        assert rt["preemptions"] >= 1
-        assert rt["preempted_requests"] >= 1
-        assert engine.kv_stats()["cancelled_by_reason"].get(
-            "preempt", 0) >= 1
-    finally:
-        gw.stop()
-        engine.stop()
+    streaming = threading.Event()
+    th = threading.Thread(target=batch_client, daemon=True)
+    th.start()
+    # land inside the engine-production window of the 600-token
+    # batch decode, with the single slot occupied -> must preempt:
+    # the first frame on the wire says the slot is taken, and 600
+    # tokens outlast one POST on any host
+    assert streaming.wait(timeout=120)
+    conn, resp = _post(host, port, "/v1/completions",
+                       body={"model": "tiny", "prompt": [4, 5],
+                             "max_tokens": 16,
+                             "priority": "interactive"},
+                       timeout=120.0)
+    assert resp.status == 200
+    inter = json.loads(resp.read())["choices"][0]["text"]
+    conn.close()
+    assert inter == _oracle_text(engine, [4, 5], 16)
+    th.join(timeout=120)
+    assert not th.is_alive()
+
+    text, saw_done, status = out["batch"]
+    assert status == 200 and saw_done
+    assert text == expected
+    rt = router.stats()
+    assert rt["preemptions"] >= 1
+    assert rt["preempted_requests"] >= 1
+    assert engine.kv_stats()["cancelled_by_reason"].get(
+        "preempt", 0) >= 1
+
+
+def test_batch_arrival_on_full_tier_sheds_with_cause(full_tier):
+    """A batch-class arrival cannot preempt: while a batch stream holds
+    the only slot (queue depth 0), a second batch request is refused
+    over the wire with 503, ``Retry-After`` and an attributed
+    ``X-Shed-Cause`` — and the holder's stream is untouched by it."""
+    engine, router = full_tier.engine, full_tier.router
+    host, port = full_tier.host, full_tier.port
+    prompt, n = [7, 8, 9], 40
+    expected = _oracle_text(engine, prompt, n)
+    conn, resp = _post(host, port, "/v1/completions",
+                       body={"model": "tiny", "prompt": prompt,
+                             "max_tokens": n, "stream": True,
+                             "priority": "batch",
+                             "token_sleep_s": 0.05},
+                       timeout=120.0)
+    assert resp.status == 200
+    # the first frame on the wire: the slot is held from here until
+    # the paced stream ends, about 2 s later
+    head, _ = _drain_sse(resp, stop_after=1)
+    conn2, shed = _post(host, port, "/v1/completions",
+                        body={"model": "tiny", "prompt": [4, 5],
+                              "max_tokens": 8, "priority": "batch"})
+    assert shed.status == 503
+    assert shed.headers["X-Shed-Cause"] == "capacity"
+    assert int(shed.headers["Retry-After"]) >= 1
+    assert json.loads(shed.read())["error"]["type"] == "overloaded"
+    conn2.close()
+    rest, saw_done = _drain_sse(resp)
+    conn.close()
+    assert saw_done
+    assert "".join(c["choices"][0]["text"]
+                   for c in head + rest) == expected
+    rt = router.stats()
+    assert rt["sheds_by_cause"] == {"capacity": 1}
+    assert rt["preemptions"] == 0
 
 
 # --------------------------------------------------- disconnect reaping

@@ -516,6 +516,41 @@ def test_router_directory_hit_and_holder_death_fallback(
     assert rks["directory_hits"] == rs["directory_hits"]
 
 
+def test_cold_prefill_tier_readopts_from_tier3_bit_identical(
+        kvplane_cluster, model):
+    """Tier 3 outlives its holder: the whole prefill tier leaves the
+    router (the old replica stays alive, so its published chunks do),
+    a cold replica takes its place, and the replay is routed by the
+    directory's fallback hint, adopts the prefix from the object store
+    and gives the same tokens as before the swap."""
+    old = PrefillServer(model, CFG, kv_block_size=BS,
+                        kv_pool_blocks=32, kv_int8=True, kvplane=True)
+    cold = PrefillServer(model, CFG, kv_block_size=BS,
+                         kv_pool_blocks=32, kv_int8=True, kvplane=True)
+    dec = DecodeServer(model, CFG, max_batch=2)
+    router = DisaggRouter(decode=[dec], prefill=[old],
+                          max_queue_depth=4, affinity_tokens=BS)
+    prompts = [list(range(40 * i + 2001, 40 * i + 2014))
+               for i in range(3)]  # 3 full blocks each + a tail
+    try:
+        ref = [router.generate(p, 5) for p in prompts]
+        assert old.kvplane_stats()["tier3_publishes"] >= len(prompts)
+        for r in router.tier_replicas("prefill"):
+            router.remove_dead("prefill", r["rid"])
+        router.add_prefill(cold)
+        before = router.stats()["directory_fallbacks"]
+        got = [router.generate(p, 5) for p in prompts]
+    finally:
+        dec.stop()
+    assert got == ref
+    assert router.stats()["directory_fallbacks"] - before == len(prompts)
+    kst = cold.kvplane_stats()
+    assert kst["tier3_adopts"] == len(prompts), kst
+    assert kst["tier3_adopted_blocks"] == 3 * len(prompts)
+    assert kst["tier3_reused_tokens"] == 3 * BS * len(prompts)
+    assert cold.stats()["reused_tokens"] >= 3 * BS * len(prompts)
+
+
 # --------------------------- chunk-fabric per-caller attribution
 
 def test_chunk_fetcher_caller_attribution(kvplane_cluster):

@@ -331,7 +331,7 @@ def _check_uneven_cases(rng, B, H, D):
 
 
 def test_set_default_blocks_affects_trace():
-    """set_default_blocks (the bench autotune hook) changes the block
+    """set_default_blocks (the block-size hook) changes the block
     sizes unpinned calls trace with, and results stay correct across
     block configurations."""
     from ray_tpu.ops import attention
@@ -347,39 +347,5 @@ def test_set_default_blocks_affects_trace():
             out = flash_attention(q, k, v, causal=True)
             np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                        atol=2e-5, rtol=2e-5)
-    finally:
-        attention.set_default_blocks(*orig)
-
-
-def test_bench_autotune_mechanics(tmp_path):
-    """The bench's block sweep runs a real (CPU) train step per
-    candidate, picks a winner, and leaves it installed."""
-    import optax
-
-    import bench as bench_mod
-    from ray_tpu.models.gpt2 import (GPT2Config, gpt2_init, gpt2_loss,
-                                     gpt2_partition_specs)
-    from ray_tpu.ops import attention
-    from ray_tpu.parallel.mesh import MeshConfig, make_mesh
-    from ray_tpu.train.trainer import TrainStep
-
-    cfg = GPT2Config.tiny()
-    mesh = make_mesh(MeshConfig(dp=-1), devices=jax.devices()[:1])
-
-    def make_step():
-        return TrainStep(
-            lambda p, b: gpt2_loss(p, b["tokens"], b["targets"], cfg),
-            optax.adamw(1e-3), mesh, gpt2_partition_specs(cfg))
-
-    params = gpt2_init(cfg, jax.random.PRNGKey(0))
-    batch = {"tokens": jnp.zeros((2, 64), jnp.int32),
-             "targets": jnp.zeros((2, 64), jnp.int32)}
-    orig = (attention.DEFAULT_BLOCK_Q, attention.DEFAULT_BLOCK_K)
-    try:
-        chosen = bench_mod._autotune_flash_blocks(
-            make_step, params, batch, warmup=1, iters=1)
-        assert chosen is not None
-        assert (attention.DEFAULT_BLOCK_Q,
-                attention.DEFAULT_BLOCK_K) == chosen
     finally:
         attention.set_default_blocks(*orig)

@@ -1,11 +1,10 @@
 """Step-time oracle (ISSUE-10 acceptance surface): the roofline model's
 constants table pinned to the peak-FLOPs table, predicted step-time
 breakdowns for every dryrun layout, the seeded calibration fit, the
-unmodeled-collective blind-spot finding, bench regression attribution,
-and the one-set-of-numbers consistency check across state API / CLI /
-dashboard / Prometheus / merged-timeline counter track — with a real
-predicted-vs-measured residual recorded for a real (virtual-cluster)
-training run.
+unmodeled-collective blind-spot finding, and the one-set-of-numbers
+consistency check across state API / CLI / dashboard / Prometheus /
+merged-timeline counter track — with a real predicted-vs-measured
+residual recorded for a real (virtual-cluster) training run.
 
 The `oracle` marker tags the scenarios; everything here is tier-1-safe
 on CPU — cluster tests run on a module-scoped cluster with
@@ -14,9 +13,7 @@ validation exercises plumbing and calibration math, not the absolute
 TPU constants (the module's documented caveat)."""
 from __future__ import annotations
 
-import importlib.util
 import json
-import os
 import time
 
 import pytest
@@ -27,8 +24,6 @@ from ray_tpu.observability.gang import summarize_run
 from ray_tpu.observability.step_timer import summarize_records
 
 pytestmark = pytest.mark.oracle
-
-REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 # ------------------------------------------------- constants (property)
@@ -308,68 +303,6 @@ def test_gang_phase_summary_uses_shared_summarize():
     expected = summarize_records(
         [steps[s][0] for s in sorted(steps)])["phases"]
     assert ps == expected
-
-
-# -------------------------------------------- bench attribution (satellite)
-
-def _load_bench_module():
-    spec = importlib.util.spec_from_file_location(
-        "bench_for_test", os.path.join(REPO_ROOT, "bench.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def test_bench_regression_attribution(tmp_path):
-    """Satellite: the newest valid prior record is the baseline, the
-    phase with the largest positive delta is named, and cpu_fallback /
-    failed / breakdown-less records are never attributed against."""
-    bench = _load_bench_module()
-    metric = "gpt2_125m_train_tokens_per_sec_per_chip"
-
-    def write(name, parsed):
-        (tmp_path / name).write_text(json.dumps({"parsed": parsed}))
-
-    write("BENCH_r01.json", {
-        "metric": metric, "value": 100000.0,
-        "step_breakdown": {"data_wait_ms": 0.0, "compile_ms": 50.0,
-                           "device_step_ms": 10.0}})
-    # newer rounds that must all be SKIPPED as baselines:
-    write("BENCH_r02.json", {"metric": metric, "value": 110000.0})
-    write("BENCH_r03.json", {
-        "metric": f"{metric}_cpu".replace(metric, "gpt2_tiny_cpu"),
-        "value": 6000.0,
-        "step_breakdown": {"device_step_ms": 400.0}})
-    write("BENCH_r04.json", {
-        "metric": metric, "value": 0.0, "error": "tpu path failed",
-        "cpu_fallback": {"value": 6500.0}})
-
-    rec = {"metric": metric, "value": 90000.0,
-           "step_breakdown": {"data_wait_ms": 0.0, "compile_ms": 48.0,
-                              "device_step_ms": 13.0,
-                              # summary key, NOT a phase: must never be
-                              # attributed (would double-count the
-                              # device_step phase as 2-sample noise)
-                              "device_step_p99_ms": 99.0}}
-    out = bench._attribute_regression(rec, bench_dir=str(tmp_path))
-    reg = out["regression"]
-    assert reg["phase"] == "device_step"
-    assert reg["delta_ms"] == pytest.approx(3.0)
-    assert reg["pct"] == pytest.approx(30.0)
-    assert reg["vs"] == "BENCH_r01.json"
-
-    # a strictly faster run records regression=None, not a phantom phase
-    fast = {"metric": metric, "value": 120000.0,
-            "step_breakdown": {"data_wait_ms": 0.0, "compile_ms": 40.0,
-                               "device_step_ms": 8.0}}
-    assert bench._attribute_regression(
-        fast, bench_dir=str(tmp_path))["regression"] is None
-
-    # no valid baseline at all: the record passes through untouched
-    lonely = {"metric": "other_metric", "value": 1.0,
-              "step_breakdown": {"device_step_ms": 1.0}}
-    assert "regression" not in bench._attribute_regression(
-        lonely, bench_dir=str(tmp_path))
 
 
 # --------------------------------------------- cluster (virtual) coverage

@@ -63,10 +63,12 @@ def test_pipelined_tasks_not_inverted(tmp_path):
 
 
 def test_actor_churn_floor():
-    """Regression guard for 4-actors/s churn: with the fork server
-    (fork_server.py) create+call+kill waves must sustain >= 10/s even
-    on a loaded 1-core CI host (measured ~36/s idle)."""
-    import time
+    """Regression guard for 4-actors/s churn, which was a cold
+    interpreter start per actor: every actor's worker must be a fork of
+    the session's template (fork_server.py, ~10 ms a spawn), and waves
+    of create+call+kill must complete and return the right values. No
+    rate is asserted: a rate here is the host's, not the mechanism's."""
+    import os
 
     import ray_tpu
 
@@ -78,24 +80,27 @@ def test_actor_churn_floor():
                 self.v = v
 
             def get(self):
-                return self.v
+                return self.v, os.getppid()
 
         a = Cell.remote(0)
         ray_tpu.get(a.get.remote())
         ray_tpu.kill(a)  # warm (fork server boots on first spawn)
 
-        n, wave, done = 24, 8, 0
-        t0 = time.perf_counter()
+        n, wave, done, parents = 24, 8, 0, set()
         while done < n:
             k = min(wave, n - done)
             actors = [Cell.remote(i) for i in range(k)]
             got = ray_tpu.get([x.get.remote() for x in actors],
                               timeout=120.0)
-            assert got == list(range(k))
+            assert [v for v, _ in got] == list(range(k))
+            parents.update(ppid for _, ppid in got)
             for x in actors:
                 ray_tpu.kill(x)
             done += k
-        rate = n / (time.perf_counter() - t0)
+
+        if not os.environ.get("RAY_TPU_NO_FORK_SERVER"):
+            assert len(parents) == 1, parents
+            with open(f"/proc/{parents.pop()}/cmdline", "rb") as f:
+                assert b"ray_tpu._private.fork_server" in f.read()
     finally:
         ray_tpu.shutdown()
-    assert rate >= 10.0, f"actor churn regressed to {rate:.1f}/s"

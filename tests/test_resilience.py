@@ -375,6 +375,13 @@ def _sgd_train_fn(cfg):
             ckpt = Checkpoint(d)
         report({"step": step, "loss": loss,
                 "world": ctx.get_world_size()}, checkpoint=ckpt)
+        if step == cfg.get("await_preemption_after_step"):
+            # the notice rides pubsub: the next step starts when it has
+            # landed here, not after a sleep a loaded host outlasts
+            deadline = _t.monotonic() + 60.0
+            while preemption_requested() is None \
+                    and _t.monotonic() < deadline:
+                _t.sleep(0.01)
         if cfg.get("step_sleep"):
             _t.sleep(float(cfg["step_sleep"]))
 
@@ -550,7 +557,8 @@ def test_preempt_quarantine_elastic_restart_scenario(chaos_cluster,
     # 3 workers need 3 CPUs: STRICT_PACK can only land on flaky-host
     trainer = JaxTrainer(
         _sgd_train_fn,
-        train_loop_config={"n_steps": N_STEPS, "step_sleep": 0.06},
+        train_loop_config={"n_steps": N_STEPS, "step_sleep": 0.06,
+                           "await_preemption_after_step": 2},
         scaling_config=ScalingConfig(num_workers=3, min_workers=2,
                                      setup_jax_distributed=False),
         run_config=RunConfig(name="chaos-accept",
@@ -569,10 +577,11 @@ def test_preempt_quarantine_elastic_restart_scenario(chaos_cluster,
     for m in result.metrics_history:
         assert m["loss"] == pytest.approx(expected[m["step"] - 1],
                                           rel=1e-12)
-    # the restart resumed from the grace checkpoint (taken at the step
-    # after the preemption broadcast), not from scratch
+    # the restart resumed from the grace checkpoint (taken at step 3,
+    # the step after every rank has the preemption notice), not from
+    # scratch and not from a later one
     first_resumed = result.metrics_history[0]["step"]
-    assert 3 < first_resumed <= 6, first_resumed
+    assert first_resumed == 4, first_resumed
     # elastic re-form: capacity without flaky-host is the 2-CPU head
     assert result.metrics["world"] == 2
     assert trainer.scaling_config.num_workers == 2
@@ -597,10 +606,15 @@ def test_preempt_quarantine_elastic_restart_scenario(chaos_cluster,
     # Prometheus surface: the event counter rode the metrics pipeline
     from ray_tpu.util import metrics as metrics_mod
 
-    metrics_mod.flush()
-    text = state.prometheus_metrics()
-    assert "ray_tpu_resilience_events_total" in text
-    assert 'kind="preemption"' in text
+    metrics_mod.flush()  # a notify: poll for what it carries
+    deadline = time.monotonic() + 30.0
+    while True:
+        text = state.prometheus_metrics()
+        if "ray_tpu_resilience_events_total" in text \
+                and 'kind="preemption"' in text:
+            break
+        assert time.monotonic() < deadline, text[-2000:]
+        time.sleep(0.05)
 
 
 @pytest.mark.chaos

@@ -75,11 +75,11 @@ def test_roofline_module_is_lint_covered():
 
 
 def test_disagg_modules_are_lint_covered():
-    """Disaggregated serving (serve/disagg.py) and its load harness
-    (bench_serve.py) are inside the self-lint set: the walk parses
-    them and they carry zero error findings of their own (a
-    rename/move would silently drop them from coverage)."""
-    for rel in (os.path.join("serve", "disagg.py"), "bench_serve.py"):
+    """Disaggregated serving (serve/disagg.py) is inside the
+    self-lint set: the walk parses it and it carries zero error
+    findings of its own (a rename/move would silently drop it from
+    coverage)."""
+    for rel in (os.path.join("serve", "disagg.py"),):
         path = os.path.join(PACKAGE_ROOT, rel)
         assert os.path.exists(path), rel
         assert errors(lint_path(path)) == [], rel
@@ -149,16 +149,15 @@ def test_unsupervised_actor_call_rule_fires():
 
 def test_lora_modules_are_lint_covered():
     """Multi-tenant LoRA serving (serve/lora.py, online/lora.py) and
-    the modules it rewired (models/engine.py, serve/disagg.py,
-    bench_serve.py) are inside the self-lint set and carry zero error
+    the modules it rewired (models/engine.py, serve/disagg.py) are
+    inside the self-lint set and carry zero error
     findings — and zero unkeyed-tenant-cache findings after
     suppressions (every prefix-cache lookup in lora-aware code passes
     the tenant namespace)."""
     for rel in (os.path.join("serve", "lora.py"),
                 os.path.join("online", "lora.py"),
                 os.path.join("models", "engine.py"),
-                os.path.join("serve", "disagg.py"),
-                "bench_serve.py"):
+                os.path.join("serve", "disagg.py")):
         path = os.path.join(PACKAGE_ROOT, rel)
         assert os.path.exists(path), rel
         findings = lint_path(path)
@@ -279,8 +278,7 @@ def test_speculation_modules_are_lint_covered():
     for rel in (os.path.join("models", "engine.py"),
                 os.path.join("models", "kvcache.py"),
                 os.path.join("serve", "lora.py"),
-                os.path.join("serve", "disagg.py"),
-                "bench_serve.py"):
+                os.path.join("serve", "disagg.py")):
         path = os.path.join(PACKAGE_ROOT, rel)
         assert os.path.exists(path), rel
         findings = lp(path)
@@ -359,16 +357,15 @@ def test_sync_io_in_gateway_handler_rule_fires():
 
 def test_requesttrace_modules_are_lint_covered():
     """The flight recorder (observability/requests.py) and the traced
-    modules its rule activates in (serve/disagg.py, serve/gateway.py,
-    bench_serve.py) are inside the self-lint set, carry zero error
+    modules its rule activates in (serve/disagg.py, serve/gateway.py)
+    are inside the self-lint set, carry zero error
     findings, and — context discipline — zero
     `unpropagated-request-context` findings after suppressions: every
     cross-tier serve dispatch in a traced module records its hop."""
     for rel in (os.path.join("observability", "requests.py"),
                 os.path.join("observability", "timeline.py"),
                 os.path.join("serve", "disagg.py"),
-                os.path.join("serve", "gateway.py"),
-                "bench_serve.py"):
+                os.path.join("serve", "gateway.py")):
         path = os.path.join(PACKAGE_ROOT, rel)
         assert os.path.exists(path), rel
         findings = lint_path(path)

@@ -123,8 +123,12 @@ def test_metrics_in_worker(cluster):
         return True
 
     assert ray_tpu.get(emits_metrics.remote())
-    text = state.prometheus_metrics()
-    assert "test_worker_side_total" in text
+    # the worker's flush() is a notify: the task's reply can reach the
+    # driver before the conductor has handled the push
+    deadline = time.monotonic() + 30.0
+    while "test_worker_side_total" not in state.prometheus_metrics():
+        assert time.monotonic() < deadline
+        time.sleep(0.05)
 
 
 def test_cluster_summary(cluster):
