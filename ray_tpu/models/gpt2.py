@@ -181,46 +181,101 @@ def gpt2_forward(params: Params, tokens: jax.Array,
 # ------------------------------------------------------- KV-cache decode
 
 
+def _heads_per_row(config: GPT2Config) -> int:
+    """How many heads the cache holds side by side in one row: heads
+    narrower than the chip's 128 lanes are packed into rows of whole
+    lane tiles, at most two of them (heads of 64: 4 to a row of 256
+    lanes; of 32, where there are only 4: 4 to a row of 128). The count
+    divides num_heads; 1 where no such count exists and for heads of
+    128 and wider, which fill their lanes alone. Why two tiles and not
+    one: PERF.md section 6, PR 30 (the decode tick is the same either
+    way; the programs of one-tile rows took longer to load)."""
+    c = config
+    if c.head_dim >= 128:
+        return 1
+    for p in range(min(c.num_heads, 256 // c.head_dim), 1, -1):
+        if c.num_heads % p == 0 and (p * c.head_dim) % 128 == 0:
+            return p
+    return 1
+
+
 def gpt2_init_kv_cache(config: GPT2Config, batch_size: int,
                        max_len: int = 0, dtype: Any = None) -> list:
-    """Per-layer K/V buffers [B, S, heads, head_dim] (same layout as
-    models/llama.py init_kv_cache — learned positions instead of rope)."""
+    """Per-layer K/V buffers [B, S, heads // p, p * head_dim], p =
+    `_heads_per_row`: a row holds p consecutive heads side by side, so
+    it fills whole 128-lane tiles (GPT-2 small: [B, S, 3, 256]; models/
+    llama.py init_kv_cache has a head of 128 to a row) and a program
+    reads and updates the slab where it lies. With head_dim 64 alone as
+    the last axis the chip's default layout made S the minor-most one,
+    and every tick copied every entry to the scatter's layout and
+    back."""
     c = config
     s = max_len or c.max_seq_len
     dt = dtype or c.dtype
-    return [{"k": jnp.zeros((batch_size, s, c.num_heads, c.head_dim), dt),
-             "v": jnp.zeros((batch_size, s, c.num_heads, c.head_dim), dt)}
+    p = _heads_per_row(c)
+    shape = (batch_size, s, c.num_heads // p, p * c.head_dim)
+    return [{"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
             for _ in range(c.num_layers)]
+
+
+def _qkv_rows(qkv: jax.Array, cache: Params):
+    """Split the fused projection [B, t, 3D] into q, k, v in the cache's
+    own row shape [B, t, heads // p, p * head_dim] (a few KB reshaped,
+    never the slab): head r of a row lies in lanes [r * head_dim,
+    (r + 1) * head_dim). k and v come in the cache's dtype."""
+    b, t = qkv.shape[0], qkv.shape[1]
+    rows = (b, t) + cache["k"].shape[2:]
+    q, k, v = (x.reshape(rows) for x in jnp.split(qkv, 3, axis=-1))
+    return q, k.astype(cache["k"].dtype), v.astype(cache["v"].dtype)
+
+
+def _cache_attention(q: jax.Array, ck: jax.Array, cv: jax.Array,
+                     positions: jax.Array, head_dim: int) -> jax.Array:
+    """Masked attention over the cache as it lies: q [B, t, g, W] and
+    ck/cv [B, S, g, W] hold p = W // head_dim heads to a row; query
+    (b, j) sees rows <= positions[b, j] (positions [B, t] or [1, t]).
+    Rows are contracted whole, the grouped form of
+    llama._cache_attention with g rows and p queries a row: the query
+    of head r is its row with every lane outside its own head_dim set
+    to zero, so its scores are exactly its own (the other lanes add
+    0 * k), and its output is its own lanes of the row its
+    probabilities give. Nothing narrower than a row is ever formed.
+    Returns [B, t, g * W], heads in their order."""
+    b, t, g, w = q.shape
+    p = w // head_dim
+    own = jnp.arange(w)[None, :] // head_dim == jnp.arange(p)[:, None]
+    qr = jnp.where(own, q[:, :, :, None, :], 0)          # [B, t, g, p, W]
+    scores = jnp.einsum("btgrd,bsgd->bgrts", qr, ck,
+                        preferred_element_type=jnp.float32)
+    scores = scores / (head_dim ** 0.5)
+    col = jnp.arange(ck.shape[1])[None, None, None, None, :]
+    visible = col <= positions[:, None, None, :, None]
+    scores = jnp.where(visible, scores, -1e30)
+    probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+    a = jnp.einsum("bgrts,bsgd->btgrd", probs, cv)       # [B, t, g, p, W]
+    out = a[:, :, :, 0]
+    for r in range(1, p):
+        out = jnp.where(own[r], a[:, :, :, r], out)
+    return out.reshape(b, t, g * w)
 
 
 def _block_cached(x: jax.Array, p: Params, config: GPT2Config,
                   cache: Params, pos: jax.Array):
     """Cache-path block: tokens at [pos, pos+t) attend the full written
-    prefix — the GPT-2 analog of llama_block_cached."""
+    prefix — the GPT-2 analog of llama_block_cached. The new rows go
+    into the cache in its own row shape (`_qkv_rows`), and the
+    attention reads it where it lies (`_cache_attention`)."""
     c = config
-    b, t, _ = x.shape
+    t = x.shape[1]
     h = layer_norm(x, p["ln_1"]["scale"], p["ln_1"]["bias"])
     qkv = jnp.dot(h, p["attn"]["qkv"],
                   preferred_element_type=jnp.float32).astype(c.dtype)
     qkv = qkv + p["attn"]["qkv_b"]
-    q, k, v = jnp.split(qkv, 3, axis=-1)
-    q = q.reshape(b, t, c.num_heads, c.head_dim)
-    k = k.reshape(b, t, c.num_heads, c.head_dim)
-    v = v.reshape(b, t, c.num_heads, c.head_dim)
-    ck = jax.lax.dynamic_update_slice(
-        cache["k"], k.astype(cache["k"].dtype), (0, pos, 0, 0))
-    cv = jax.lax.dynamic_update_slice(
-        cache["v"], v.astype(cache["v"].dtype), (0, pos, 0, 0))
-    s = ck.shape[1]
-    scores = jnp.einsum("bthd,bshd->bhts", q, ck,
-                        preferred_element_type=jnp.float32)
-    scores = scores / (c.head_dim ** 0.5)
+    q, k, v = _qkv_rows(qkv, cache)
+    ck = jax.lax.dynamic_update_slice(cache["k"], k, (0, pos, 0, 0))
+    cv = jax.lax.dynamic_update_slice(cache["v"], v, (0, pos, 0, 0))
     positions = pos + jnp.arange(t)[None, :]
-    col = jnp.arange(s)[None, None, None, :]
-    visible = col <= positions[:, None, :, None]
-    scores = jnp.where(visible, scores, -1e30)
-    probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
-    a = jnp.einsum("bhts,bshd->bthd", probs, cv).reshape(b, t, c.d_model)
+    a = _cache_attention(q, ck, cv, positions, c.head_dim)
     return _mlp_res(_attn_proj_res(x, a, p, c), p, c), {"k": ck, "v": cv}
 
 
@@ -232,6 +287,9 @@ def _block_decode(x: jax.Array, p: Params, config: GPT2Config,
     pos_vec [B] is each slot's BASE position (t == 1: the classic
     one-token tick; t == k+1: the speculative verify pass — see
     llama_block_decode for the masking contract the oracle rests on).
+    One row a slot and position is scattered into the cache in its own
+    row shape, and the attention is `_block_cached`'s
+    (`_cache_attention`), so a verify row is a sequential tick's math.
 
     `lora` (optional, serve/lora.py mixed-tenant decode): this layer's
     per-slot adapter selection for the fused qkv projection —
@@ -247,25 +305,16 @@ def _block_decode(x: jax.Array, p: Params, config: GPT2Config,
 
         qkv = qkv + lora_delta(h, *lora["qkv"], lora["scale"])
     qkv = qkv + p["attn"]["qkv_b"]
-    q, k, v = jnp.split(qkv, 3, axis=-1)
-    q = q.reshape(b, t, c.num_heads, c.head_dim)
-    k = k.reshape(b, t, c.num_heads, c.head_dim)
-    v = v.reshape(b, t, c.num_heads, c.head_dim)
-    rows = jnp.arange(b)
+    q, k, v = _qkv_rows(qkv, cache)
     positions = pos_vec[:, None] + jnp.arange(t)[None, :]   # [B, t]
-    ck = cache["k"].at[rows[:, None], positions].set(
-        k.astype(cache["k"].dtype))
-    cv = cache["v"].at[rows[:, None], positions].set(
-        v.astype(cache["v"].dtype))
-    s = ck.shape[1]
-    scores = jnp.einsum("bthd,bshd->bhts", q, ck,
-                        preferred_element_type=jnp.float32)
-    scores = scores / (c.head_dim ** 0.5)
-    col = jnp.arange(s)[None, None, None, :]
-    visible = col <= positions[:, None, :, None]
-    scores = jnp.where(visible, scores, -1e30)
-    probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
-    a = jnp.einsum("bhts,bshd->bthd", probs, cv).reshape(b, t, c.d_model)
+    # the row axis is indexed too, so the scatter's window is one row of
+    # lanes: with the rows of a position as its window the chip wants
+    # the whole entry in another layout, and copies it there and back
+    at = (jnp.arange(b)[:, None, None], positions[:, :, None],
+          jnp.arange(k.shape[2])[None, None, :])
+    ck = cache["k"].at[at].set(k)
+    cv = cache["v"].at[at].set(v)
+    a = _cache_attention(q, ck, cv, positions, c.head_dim)
     return _mlp_res(_attn_proj_res(x, a, p, c), p, c), {"k": ck, "v": cv}
 
 

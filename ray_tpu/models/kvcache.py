@@ -9,7 +9,11 @@ cache dtype: heads and head_dim lie flat in one axis, so a block's rows
 stay whole lanes for heads of any width (with a 64-wide head_dim as an
 axis of its own the chip's default layout makes ``num_blocks`` the
 minor-most axis, and a program that wants another order copies the
-whole pool in and out). Blocks are the unit of sharing:
+whole pool in and out). A family's own cache may hold the same row in
+another split of that flat axis (models/gpt2.py: heads of 64 four to a
+row of 256 lanes, ``[B, S, 3, 256]``), and `_heads` is whatever trailing
+pair the family's cache has: the flat axis is the same bytes in the
+same order either way. Blocks are the unit of sharing:
 
 - **hash-chained index** — block ``i`` of a prompt is keyed by
   ``H(chain_digest(blocks < i), tokens_i)``, so a lookup walks the

@@ -123,3 +123,50 @@ def test_gpt2_engine_matches_generate():
             assert g == want, p
     finally:
         eng.stop()
+
+
+def test_gpt2_engine_prefix_pool_matches_generate():
+    """GPT-2's row-packed cache through the paged pool: a prompt is
+    committed, a second one that shares its prefix is gathered from the
+    pool and spliced, and both decode to generate()'s tokens."""
+    from ray_tpu.models.gpt2 import GPT2Config, gpt2_init
+
+    cfg = dataclasses.replace(GPT2Config.tiny(), dtype=jnp.float32)
+    params = gpt2_init(cfg, jax.random.PRNGKey(3))
+    eng = ContinuousBatchingEngine(params, cfg, max_batch=2,
+                                   kv_block_size=4, kv_pool_blocks=16)
+    try:
+        shared = [7, 3, 9, 1, 4, 4, 8, 2, 6, 5]
+        prompts = [shared + [11, 12], shared + [13]]
+        got = [eng.generate(p, 5) for p in prompts]
+        stats = eng.kv_stats()
+        assert stats["reused_tokens"] >= 8, stats
+        for p, g in zip(prompts, got):
+            want = np.asarray(generate(params, cfg,
+                                       jnp.asarray([p], jnp.int32),
+                                       max_new_tokens=5))[0].tolist()
+            assert g == want, p
+    finally:
+        eng.stop()
+
+
+@pytest.mark.parametrize("small_width,slab", [(False, 1_048_576),
+                                              (True, 50_331_648)])
+def test_gpt2_tick_aliases_every_cache_entry(small_width, slab):
+    """The donated slab comes back in place: the compiled tick aliases
+    as many bytes as the slab holds, for tiny()'s heads of 32 and for
+    two layers at small()'s width, heads of 64."""
+    from ray_tpu.models.engine import _tick
+    from ray_tpu.models.gpt2 import (GPT2Config, gpt2_init,
+                                     gpt2_init_kv_cache)
+
+    cfg = dataclasses.replace(GPT2Config.small(), num_layers=2) \
+        if small_width else GPT2Config.tiny()
+    params = jax.eval_shape(lambda: gpt2_init(cfg, jax.random.PRNGKey(0)))
+    cache = jax.eval_shape(lambda: gpt2_init_kv_cache(cfg, 8))
+    vec = jax.ShapeDtypeStruct((8,), jnp.int32)
+    assert slab == sum(x.size * x.dtype.itemsize
+                       for x in jax.tree.leaves(cache))
+    mem = _tick.lower(params, cfg, cache, vec, vec).compile() \
+        .memory_analysis()
+    assert mem.alias_size_in_bytes == slab

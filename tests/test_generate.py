@@ -131,3 +131,60 @@ def test_gpt2_generation_matches_full_forward():
         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         np.testing.assert_array_equal(out[:, i], np.asarray(nxt))
         seq = jnp.concatenate([seq, nxt[:, None]], axis=1)
+
+
+def _per_head_attention(q, ck, cv, positions):
+    """The plain per-head einsums of a [B, S, heads, head_dim] cache:
+    what `gpt2._cache_attention` is held to, to the bit."""
+    hd = q.shape[-1]
+    scores = jnp.einsum("bthd,bshd->bhts", q, ck,
+                        preferred_element_type=jnp.float32)
+    scores = scores / (hd ** 0.5)
+    col = jnp.arange(ck.shape[1])[None, None, None, :]
+    visible = col <= positions[:, None, :, None]
+    scores = jnp.where(visible, scores, -1e30)
+    probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+    a = jnp.einsum("bhts,bshd->bthd", probs, cv)
+    return a.reshape(q.shape[0], q.shape[1], -1)
+
+
+@pytest.mark.parametrize("t", [1, 3])
+@pytest.mark.parametrize("p,hd", [(1, 128), (2, 64), (4, 32), (4, 64)])
+def test_gpt2_row_packed_attention_matches_per_head(p, hd, t):
+    """p heads to a row of 128 lanes (heads of 128, 64, 32) or of 256
+    (4 heads of 64, GPT-2 small's row), per-slot positions, the
+    one-token tick (t = 1) and the verify form (t = 3): the row-wise
+    contraction gives each head's own scores and output, to the bit."""
+    from ray_tpu.models.gpt2 import _cache_attention
+
+    b, s, heads = 4, 64, 4
+    rng = np.random.default_rng(7 * p + hd + t)
+
+    def rand(*shape):
+        return jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
+
+    q, ck, cv = rand(b, t, heads, hd), rand(b, s, heads, hd), \
+        rand(b, s, heads, hd)
+    positions = jnp.asarray(rng.integers(0, s - t, (b, 1)), jnp.int32) \
+        + jnp.arange(t)[None, :]
+    want = _per_head_attention(q, ck, cv, positions)
+    got = _cache_attention(q.reshape(b, t, heads // p, p * hd),
+                           ck.reshape(b, s, heads // p, p * hd),
+                           cv.reshape(b, s, heads // p, p * hd),
+                           positions, hd)
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(want, np.float32))
+
+
+@pytest.mark.parametrize("name,lanes", [("small", 256), ("tiny", 128)])
+def test_gpt2_cache_rows_fill_whole_lane_tiles(name, lanes):
+    """Heads of 64 and of 32 are held several to a row of whole
+    128-lane tiles, and every layer has its entry."""
+    from ray_tpu.models.gpt2 import GPT2Config, gpt2_init_kv_cache
+
+    cfg = getattr(GPT2Config, name)()
+    cache = jax.eval_shape(lambda: gpt2_init_kv_cache(cfg, 2, 16))
+    assert len(cache) == cfg.num_layers
+    for blk in cache:
+        for side in ("k", "v"):
+            assert blk[side].shape == (2, 16, cfg.d_model // lanes, lanes)
