@@ -8,7 +8,11 @@ sharding rules for every mesh axis the parallel layer exposes.
 Served by `ContinuousBatchingEngine` (`generate._model_fns`): GPT-2, the
 Llama block, and Nemotron-H (`nemotron_h.py`: Mamba-2, attention and
 LatentMoE layers; its slots own recurrent state, so the engine refuses
-it a prefix pool, speculation, a LoRA pool and disaggregated adoption).
+it a prefix pool, speculation, a LoRA pool and disaggregated adoption),
+and Kimi-Linear (`kimi_linear.py`: delta-rule and latent-attention mixers
+over dense and expert layers; beside its state a slot owns the engine's
+third kind of cache entry, ONE latent row a token in a single array, from
+which keys and values are both made; refused what Nemotron-H is).
 `moe_transformer.py` trains and is not served.
 """
 from .gpt2 import (  # noqa: F401
@@ -36,6 +40,13 @@ from .nemotron_h import (  # noqa: F401
     nemotron_h_init,
     nemotron_h_loss,
     nemotron_h_partition_specs,
+)
+from .kimi_linear import (  # noqa: F401
+    KimiLinearConfig,
+    kimi_linear_forward,
+    kimi_linear_init,
+    kimi_linear_loss,
+    kimi_linear_partition_specs,
 )
 from .moe_transformer import (  # noqa: F401
     MoEConfig,

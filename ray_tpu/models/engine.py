@@ -66,14 +66,18 @@ Surfaces: ``util.state.speculation_stats()``, ``ray_tpu speculate``,
 instant markers in the merged timeline's kvcache lane.
 
 The cache protocol is one for every family (`generate._model_fns`): the
-decode slab is the family's own pytree, a list of entries. An entry with
-"k"/"v" `[B, S, H, hd]` has a sequence axis: a prefill hands back its
-rows stacked `[L_kv, S, H, hd]` (what the paged pool commits, a
-disaggregated transfer ships and `_splice_slot` writes by rows `[0,
-plen)`). Any other entry is a slot's STATE with no sequence axis (a
-recurrence's state, a convolution's tail: models/nemotron_h.py): a
-prefill hands back the state it ended in, and the splice writes it
-WHOLE. Three things the engine takes for granted of keys and values do
+decode slab is the family's own pytree, a list of entries of three
+kinds. An entry with "k"/"v" `[B, S, H, hd]` has a sequence axis: a
+prefill hands back its rows stacked `[L_kv, S, H, hd]` (what the paged
+pool commits, a disaggregated transfer ships and `_splice_slot` writes
+by rows `[0, plen)`). An entry with "k" ALONE `[B, S, ...]` has a
+sequence axis and one array: a latent row a token, from which keys and
+values are both made (models/kimi_linear.py); its rows are stacked and
+spliced as keys are, and no values travel beside them (`cv` is None).
+Any other entry is a slot's STATE with no sequence axis (a recurrence's
+state, a convolution's tail: models/nemotron_h.py): a prefill hands
+back the state it ended in, and the splice writes it WHOLE. Three
+things the engine takes for granted of keys and values do
 not hold for such a family, and are refused with a ValueError that names
 the reason rather than served silently wrong: a prefix pool
 (``prefix_cache=True``; left to its default the engine builds none: a
@@ -89,7 +93,9 @@ record in the recorder's process-local store
 (``reqtrace.store().loop_records()``; a bounded ring, nothing is pushed
 to the conductor), all from ``time.perf_counter()`` on this thread:
 ``engine_id``; ``ts`` (``time.time()`` at the top of the pass); ``live``
-(slots decoding at the top, before admission) and ``max_batch``;
+(slots decoding at the top, before admission), ``live_rows`` (the sum of
+those slots' positions: the cache rows the pass's tick has a reason to
+read) and ``max_batch``;
 ``pending`` (requests waiting in ``_pending``); ``admit_ms`` (inside
 ``_admit``; 0 where nothing was admitted or adopted); ``admissions``,
 one entry per request admitted in the pass (``rid``, ``prompt_tokens``,
@@ -109,8 +115,10 @@ tokens and log-probabilities read back: BLOCKED on the device, so not
 host work; where the family's decode hands back counters of the step,
 they come with the same read-back and land in the record under their
 own names: ``moe_pairs_held``, token-expert pairs that fell on experts
-held here, summed over the expert layers, and ``moe_rows_max``, the most
-rows one held expert got); ``emit_ms`` (the walk over the slots: emit,
+held here, summed over the expert layers, ``moe_rows_max``, the most
+rows one held expert got, and where the family counts them
+``moe_experts_hit``, the held experts that got a row, summed likewise);
+``emit_ms`` (the walk over the slots: emit,
 finish, queue puts); ``total_ms`` (the whole pass; what the parts leave is
 bookkeeping: swap, cancels, drafting, telemetry push). A request
 carries three stamps of the same clock (``submit()`` returns, ``_admit``
@@ -214,26 +222,32 @@ def _prefill_body(params, suffix, config, prefix_k, prefix_v):
     stacked [L_kv, S, H, hd] (what the paged pool, the splice and the
     transfer between replicas speak), then the entries that have no
     sequence axis, each as the family left it (a slot's state; the empty
-    list for a family that has none)."""
+    list for a family that has none). A family whose sequence entries
+    hold one array (a latent row: "k" alone) gets `cv` None, and
+    `prefix_v` is not read."""
     fwd, init_cache, _ = _model_fns(config)
     c = prefix_k.shape[1]
     cache = list(init_cache(config, 1))
     kv_at = [i for i, blk in enumerate(cache) if "k" in blk]
+    paired = all("v" in cache[i] for i in kv_at)
     if c and len(kv_at) != len(cache):
         raise ValueError(
             "a cached prefix cannot resume a recurrent state: this "
             "family prefills every prompt from position 0")
     base_k = jnp.zeros((len(kv_at), config.max_seq_len)
                        + prefix_k.shape[2:], prefix_k.dtype)
-    base_v = jnp.zeros_like(base_k)
+    base_v = jnp.zeros_like(base_k) if paired else None
     if c:
         base_k = base_k.at[:, :c].set(prefix_k)
-        base_v = base_v.at[:, :c].set(prefix_v)
+        if paired:
+            base_v = base_v.at[:, :c].set(prefix_v)
     for j, i in enumerate(kv_at):
-        cache[i] = {"k": base_k[j][None], "v": base_v[j][None]}
+        cache[i] = {"k": base_k[j][None]}
+        if paired:
+            cache[i]["v"] = base_v[j][None]
     logits, cache = fwd(params, suffix, config, cache, c)
     ck = jnp.stack([cache[i]["k"][0] for i in kv_at])
-    cv = jnp.stack([cache[i]["v"][0] for i in kv_at])
+    cv = jnp.stack([cache[i]["v"][0] for i in kv_at]) if paired else None
     state = [blk for blk in cache if "k" not in blk]
     return logits[:, -1], ck, cv, state
 
@@ -344,22 +358,23 @@ def _prefill_with_cache(params, config, kv_cache, prompt, empty_prefix,
                    donate_argnums=(0,))
 def _splice_slot(cache, ck, cv, slot, config, plen, state=()):
     """Write a prefilled sequence into batch slot `slot` of the decode
-    slab, which is the family's own pytree: an entry with "k"/"v" has a
-    sequence axis and takes rows [0, plen) of its layer of ck/cv; any
-    other entry is a slot's state and takes the next entry of `state`
-    WHOLE. With the slab donated this lowers to an in-place update per
-    entry, O(plen) rows and the state's bytes, never a full-cache
-    copy."""
+    slab, which is the family's own pytree: an entry with "k" has a
+    sequence axis and takes rows [0, plen) of its layer of ck (and of cv
+    where it holds "v" too; a latent entry holds "k" alone); any other
+    entry is a slot's state and takes the next entry of `state` WHOLE.
+    With the slab donated this lowers to an in-place update per entry,
+    O(plen) rows and the state's bytes, never a full-cache copy."""
     del config
     out, layer, states = [], 0, iter(state)
     for blk in cache:
         if "k" in blk:
-            out.append({
-                "k": jax.lax.dynamic_update_slice(
-                    blk["k"], ck[layer, :plen][None], (slot, 0, 0, 0)),
-                "v": jax.lax.dynamic_update_slice(
-                    blk["v"], cv[layer, :plen][None], (slot, 0, 0, 0)),
-            })
+            at = (slot,) + (0,) * (blk["k"].ndim - 1)
+            new = {"k": jax.lax.dynamic_update_slice(
+                blk["k"], ck[layer, :plen][None], at)}
+            if "v" in blk:
+                new["v"] = jax.lax.dynamic_update_slice(
+                    blk["v"], cv[layer, :plen][None], at)
+            out.append(new)
             layer += 1
         else:
             out.append(jax.tree.map(
@@ -563,7 +578,7 @@ class ContinuousBatchingEngine:
                                                  List[int]]] = None,
                  kv_int8: Optional[bool] = None):
         # config: any family _model_fns knows (LlamaConfig, GPT2Config,
-        # NemotronHConfig)
+        # NemotronHConfig, KimiLinearConfig)
         self.params = params
         self.config = config
         self.max_batch = max_batch
@@ -577,9 +592,10 @@ class ContinuousBatchingEngine:
         self._pending_swap: Optional[tuple] = None
         self.swap_count = 0
         self._cache = _model_fns(config)[1](config, max_batch)
-        # the slab is the family's own pytree: entries with "k"/"v" have
-        # a sequence axis, any other entry is a slot's state (what it
-        # weighs: kv_stats)
+        # the slab is the family's own pytree: entries with "k" (and
+        # "v", unless one latent row serves for both) have a sequence
+        # axis, any other entry is a slot's state (what it weighs:
+        # kv_stats)
         self._state_bytes_per_slot = sum(
             x.size * x.dtype.itemsize // max_batch
             for blk in self._cache if "k" not in blk
@@ -646,7 +662,7 @@ class ContinuousBatchingEngine:
         self._spec_events: List[Dict[str, Any]] = []
         if self.speculate_k:
             spec_metrics()  # lazy registration before the first tick
-        shape = self._cache[0]["k"].shape  # [maxB, S, H, hd]
+        shape = self._cache[0]["k"].shape  # [maxB, S, H, hd] or [., ., row]
         self._empty_prefix = jnp.zeros(
             (len(self._cache), 0) + shape[2:], self._cache[0]["k"].dtype)
         # admission accounting (kv_stats / acceptance surface) — split
@@ -1414,6 +1430,9 @@ class ContinuousBatchingEngine:
                 t_top = _now()
                 it = {"engine_id": self.engine_id, "ts": time.time(),
                       "live": self.max_batch - len(self._free),
+                      "live_rows": sum(
+                          int(self._pos[slot]) for slot, r in
+                          enumerate(self._slot_req) if r is not None),
                       "max_batch": self.max_batch,
                       "pending": self._pending.qsize(),
                       "admit_ms": 0.0, "admissions": [],

@@ -43,6 +43,15 @@ def _model_fns(config):
         # models/nemotron_h.py, and the engine's splice
         return (nemotron_h_forward_cached, nemotron_h_init_cache,
                 nemotron_h_decode)
+    from .kimi_linear import (KimiLinearConfig, kimi_linear_decode,
+                              kimi_linear_forward_cached,
+                              kimi_linear_init_cache)
+
+    if isinstance(config, KimiLinearConfig):
+        # state beside ONE latent row a token: module docstring of
+        # models/kimi_linear.py, and the engine's third kind of entry
+        return (kimi_linear_forward_cached, kimi_linear_init_cache,
+                kimi_linear_decode)
     raise TypeError(f"no generation support for {type(config).__name__}")
 
 

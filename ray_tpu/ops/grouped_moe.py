@@ -12,6 +12,14 @@ elsewhere last), the rows gathered in that order, and the two expert
 products are `jax.lax.ragged_dot` over the groups: on a TPU XLA's own
 grouped-matmul kernel, which reads each held expert's weights once and
 visits only the rows that exist; elsewhere its reference lowering.
+
+Two callers, two expert shapes: `models/nemotron_h.py` (experts in a
+latent space, `w1` [held, L, I] under relu squared) and
+`models/kimi_linear.py` (SwiGLU experts at full hidden width: gate and up
+packed in ONE `w1` [held, D, 2 I], and an `activation` that maps the
+[rows, 2 I] product to `silu(gate) * up` [rows, I], which `w2` [held, I, D]
+takes). `held_experts` asks nothing of the activation but that it keeps
+the rows.
 """
 from __future__ import annotations
 
@@ -46,7 +54,8 @@ def held_experts(u: jax.Array, chosen: jax.Array, weights: jax.Array,
                  ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """sum over a token's chosen experts HELD HERE of weight_e *
     (activation(u W1_e) W2_e). u [T, L]; chosen, weights [T, k] over all
-    experts; w1 [H, L, I], w2 [H, I, L] are experts first .. first+H-1.
+    experts; w1 [H, L, I] (or [H, L, 2 I] under an activation that
+    halves the width), w2 [H, I, L] are experts first .. first+H-1.
     Returns ([T, L] float32, {"pairs_held": pairs that fell on held
     experts, "rows_max": the most rows one held expert got}, int32)."""
     t, k = chosen.shape

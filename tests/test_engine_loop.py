@@ -23,9 +23,9 @@ from ray_tpu.util import envknobs, profiling
 CFG = dataclasses.replace(LlamaConfig.tiny(), dtype=jnp.float32)
 BS = 4
 PROMPT = list(range(1, 20))  # 19 tokens: four blocks of 4 and a tail of 3
-FIELDS = {"engine_id", "ts", "live", "max_batch", "pending", "admit_ms",
-          "admissions", "dispatch_ms", "readback_ms", "emit_ms",
-          "total_ms"}
+FIELDS = {"engine_id", "ts", "live", "live_rows", "max_batch", "pending",
+          "admit_ms", "admissions", "dispatch_ms", "readback_ms",
+          "emit_ms", "total_ms"}
 ADMISSION_FIELDS = {"rid", "prompt_tokens", "suffix_tokens",
                     "reused_tokens", "lookup_ms", "prefill_ms",
                     "commit_ms", "commit_dispatches", "commit_blocks",
