@@ -12,7 +12,8 @@ matter how requests interleave). New requests prefill into a
 free slot (one jitted prefill per distinct (cached-prefix, suffix)
 length pair — exact lengths, so cache rows beyond a slot's own depth
 are never attended) and JOIN the running batch between ticks; finished
-sequences (EOS or their token budget) free their slot between ticks.
+sequences (EOS or their token budget) free their slot between ticks
+(the loop keeps one tick queued on the chip meanwhile: below).
 Slots the engine isn't using decode garbage that nothing reads — the
 cost of static shapes, paid once, instead of a recompile per batch
 composition.
@@ -86,41 +87,93 @@ the state), speculation (``speculate_k > 0``: a state a draft advanced
 cannot be un-advanced), a ``lora_pool``, and ``adopt_prefill`` (a
 transfer carries keys and values only).
 
+The loop keeps one tick ahead. The token vector and the position vector
+of the decode step live on the chip: `_tick` hands back, beside the
+cache, the chosen tokens and the positions advanced, which are, where
+they lie, the next tick's inputs. A steady pass of ``_loop`` launches
+tick N+1 from the outputs of tick N, which is still in flight, and only
+then blocks on tick N's tokens, walks the slots, emits, and at the top
+of the next pass applies swaps and cancels and admits: the host's whole
+pass runs while the chip computes, and nothing is uploaded. What the
+host learns a tick late is put right at the next boundary, in program
+order. A request whose budget ends with the tick in flight is known
+before the launch and is not decoded for again. One that ends by EOS or
+by a cancel has a row in the tick already launched: that token is
+DISCARDED on the host (never emitted, scored or drafted from), and the
+slot is free after the walk as ever. The rows of which the host knows
+better (a slot admitted or adopted: its first token and its prompt's
+length; a slot that finished: dead) are written into the device vectors
+by one small program (`_set_rows`) queued behind the tick in flight and
+ahead of the next launch, as the splice of an admission is, so the
+host's word wins; a splice writes a slot's state whole, so nothing of
+a discarded step survives in a family that has state, and for keys and
+values the stale row lies past the new prompt and stays masked until
+overwritten. A dead slot is HELD inside the program (`_chosen`: its
+token comes back as it went in, its position stands still), so the
+scatter and the position lookup of every family see for it what they
+saw when the host uploaded its mirror every tick, and no position runs
+past the window. A weight swap holds from the next LAUNCH: the tick in
+flight finishes on the weights it was launched with. The depth is what
+the engine can see, not a knob: a pass that carries drafts needs the
+host's tokens to draft from, so a speculating engine reads the tick in
+flight first and runs the verify tick with nothing queued behind it;
+every other pass keeps one tick queued unless no slot's budget outlives
+the tick it reads, with one exception: a prompt longer than half the
+window is not prefilled behind the tick in flight
+(`_long_prompt_waits`). Its prefill would hold that tick's tokens, ready
+within a tick, for as long as it runs, so the loop reads the tick and
+emits first, admits with nothing on the chip as it did before the
+lookahead, launches the next tick and ends the pass without reading it;
+the pass after keeps one queued again. ``kv_stats()`` counts
+``lookahead_ticks`` (launched behind a tick in flight) and
+``lookahead_discarded``.
+
 The loop keeps a clock of its own. While the per-request flight
 recorder is on (``RAY_TPU_REQTRACE``, observability/requests.py), every
-pass of ``_loop`` that had a live slot or admitted something leaves ONE
+pass of ``_loop`` that read a tick back or admitted something leaves ONE
 record in the recorder's process-local store
 (``reqtrace.store().loop_records()``; a bounded ring, nothing is pushed
 to the conductor), all from ``time.perf_counter()`` on this thread:
-``engine_id``; ``ts`` (``time.time()`` at the top of the pass); ``live``
-(slots decoding at the top, before admission), ``live_rows`` (the sum of
-those slots' positions: the cache rows the pass's tick has a reason to
-read) and ``max_batch``;
-``pending`` (requests waiting in ``_pending``); ``admit_ms`` (inside
-``_admit``; 0 where nothing was admitted or adopted); ``admissions``,
+``engine_id``; ``ts`` (``time.time()`` at the top of the pass);
+``max_batch``; ``pending`` (requests waiting in ``_pending`` at the
+top); ``admit_ms`` (inside ``_admit``; 0 where nothing was admitted or
+adopted); ``admissions``,
 one entry per request admitted in the pass (``rid``, ``prompt_tokens``,
 ``suffix_tokens``, ``reused_tokens`` and the self times ``lookup_ms``
 (lookup + gather), ``prefill_ms`` (the ``_prefill_paged`` call and the
-read-back of its logits, the commit between them taken out),
+read-back of its logits, the commit between them taken out; the prefill
+queues behind the tick in flight, whose rest it therefore holds),
 ``commit_ms`` with ``commit_dispatches`` (programs the pool commit
 launched: its one program once for the keys' pool and once for the
 values', whatever the blocks; 0 where nothing was new) and
 ``commit_blocks``, ``splice_ms``, and for a family with state
 ``state_bytes``, what the splice wrote whole; an adoption has
 ``prefill_ms`` 0);
-``dispatch_ms`` (from the end of ``_admit`` to the return of the tick
-call: two uploads and the dispatch; the speculative tick counts from
-its own start, its drafting is bookkeeping); ``readback_ms`` (the
-tokens and log-probabilities read back: BLOCKED on the device, so not
-host work; where the family's decode hands back counters of the step,
-they come with the same read-back and land in the record under their
-own names: ``moe_pairs_held``, token-expert pairs that fell on experts
-held here, summed over the expert layers, ``moe_rows_max``, the most
-rows one held expert got, and where the family counts them
-``moe_experts_hit``, the held experts that got a row, summed likewise);
-``emit_ms`` (the walk over the slots: emit,
-finish, queue puts); ``total_ms`` (the whole pass; what the parts leave is
-bookkeeping: swap, cancels, drafting, telemetry push). A request
+``dispatch_ms`` (inside ``_launch``, every launch of the pass: the
+lookahead's dispatch, before it ``_set_rows`` in a pass that follows an
+admission or a finish, and the tick's own where nothing was in flight;
+NO uploads in a steady pass; 0 in a pass that launches nothing because
+no budget outlives the tick it reads; the speculative tick uploads its
+tokens as ever, its drafting is bookkeeping); ``readback_ms`` (the wait
+for the tokens and log-probabilities of the tick launched a pass
+earlier: BLOCKED on the device, so not host work, and most of a tick
+now that the host's pass runs under it); ``emit_ms`` (the walk over
+that tick's slots: emit, finish, queue puts, discards); ``inflight``
+(ticks queued on the chip while the pass was blocked on its read-back:
+1 in a steady pass, 0 in a pass with drafts, with nothing left to
+decode for, or that admits a long prompt) and ``discarded`` (rows of the tick read thrown away
+because their request had finished by EOS or been cancelled since the
+launch); ``total_ms`` (the whole pass; what the parts leave is
+bookkeeping: swap, cancels, drafting, telemetry push). ``live`` (the
+slots the tick decoded for), ``live_rows`` (the sum of their positions:
+the cache rows the tick had a reason to read) and, where the family's
+decode hands back counters of the step, those under their own names
+(``moe_pairs_held``, token-expert pairs that fell on experts held here,
+summed over the expert layers, ``moe_rows_max``, the most rows one held
+expert got, and where the family counts them ``moe_experts_hit``, the
+held experts that got a row, summed likewise) all describe ONE tick: the
+one the pass READ BACK, as the host knew it at its launch (a row
+discarded later is still among ``live``). A request
 carries three stamps of the same clock (``submit()`` returns, ``_admit``
 pops it, ``_emit`` puts its first token) and ``TokenStream`` exposes
 their differences as ``queue_ms`` and ``prefill_ms``, which the router
@@ -147,7 +200,8 @@ import queue
 import threading
 import time
 from collections import OrderedDict
-from typing import Any, Callable, Dict, Iterator, List, Optional
+from typing import (Any, Callable, Dict, Iterator, List, NamedTuple,
+                    Optional)
 
 import jax
 import jax.numpy as jnp
@@ -385,8 +439,26 @@ def _splice_slot(cache, ck, cv, slot, config, plen, state=()):
     return out
 
 
+def _chosen(logits, config, tokens, pos_vec, live):
+    """What both ticks hand back beside the cache: the greedy token of
+    each slot, its log-probability, and the position vector advanced, so
+    that a tick's outputs are, as they lie, the next tick's inputs.
+    `live` [B] (int32, 1 or 0) HOLDS a dead slot: its token comes back
+    as it went in and its position stands still, so a slot nobody
+    decodes for feeds the program the same row tick after tick, never a
+    position past the window. None advances every slot."""
+    lv = logits[..., :config.vocab_size].astype(jnp.float32)
+    nxt = jnp.argmax(lv, axis=-1).astype(jnp.int32)
+    # per-slot logprob of the chosen (greedy = max-logit) token — the
+    # rollout score stream (ray_tpu.online samplers record it per token)
+    lp = jnp.max(lv, axis=-1) - jax.nn.logsumexp(lv, axis=-1)
+    if live is None:
+        return nxt, lp, pos_vec + 1
+    return jnp.where(live != 0, nxt, tokens), lp, pos_vec + live
+
+
 @functools.partial(jax.jit, static_argnums=(1,), donate_argnums=(2,))
-def _tick(params, config, cache, tokens, pos_vec):
+def _tick(params, config, cache, tokens, pos_vec, live=None):
     """One decode step — shape-polymorphic over the token axis:
     tokens [B] is the classic one-token tick; tokens [B, k+1] is the
     speculative VERIFY pass (column 0 each slot's last token, columns
@@ -395,22 +467,24 @@ def _tick(params, config, cache, tokens, pos_vec):
     KV rows stay masked until overwritten). jit specializes per shape,
     and the verify's row j is bit-identical to j sequential one-token
     ticks — the accept rule's whole contract, shared math by
-    construction because this IS the same function. A family's decode
-    may hand back a third value, a dict of small counters of the step
-    (models/nemotron_h.py: what its expert layers saw); it comes back
-    beside the tokens, None for a family that has none."""
+    construction because this IS the same function.
+
+    Returns (cache, tokens, log-probabilities, counters, positions):
+    the chosen tokens and the positions advanced are what the NEXT
+    one-token tick takes, so the loop launches it from them while they
+    are still on the chip (`_chosen`: `live` holds the dead slots). A
+    family's decode may hand back a third value, a dict of small
+    counters of the step (models/nemotron_h.py: what its expert layers
+    saw); it comes back beside the tokens, None for a family that has
+    none."""
     logits, cache, *counts = _model_fns(config)[2](params, tokens, config,
                                                    cache, pos_vec)
-    live = logits[..., :config.vocab_size].astype(jnp.float32)
-    nxt = jnp.argmax(live, axis=-1).astype(jnp.int32)
-    # per-slot logprob of the chosen (greedy = max-logit) token — the
-    # rollout score stream (ray_tpu.online samplers record it per token)
-    lp = jnp.max(live, axis=-1) - jax.nn.logsumexp(live, axis=-1)
-    return cache, nxt, lp, (counts[0] if counts else None)
+    nxt, lp, pos_next = _chosen(logits, config, tokens, pos_vec, live)
+    return cache, nxt, lp, (counts[0] if counts else None), pos_next
 
 
 @functools.partial(jax.jit, static_argnums=(1,), donate_argnums=(2,))
-def _tick_lora(params, config, cache, tokens, pos_vec, lora):
+def _tick_lora(params, config, cache, tokens, pos_vec, lora, live=None):
     """The mixed-tenant decode tick: one jitted ragged-batch step with
     PER-SLOT adapter indices (`lora["idx"]`) gathering each slot's
     low-rank deltas out of the resident adapter-pool stacks —
@@ -421,13 +495,41 @@ def _tick_lora(params, config, cache, tokens, pos_vec, lora):
     slot actually holds an adapter; pool shapes are static, so this is
     ONE extra compiled program per engine. Shape-polymorphic like
     `_tick`: tokens [B, k+1] is the speculative verify pass, with the
-    adapter deltas applied at every position."""
+    adapter deltas applied at every position. Hands back what `_tick`
+    does, less the counters."""
     logits, cache = _model_fns(config)[2](params, tokens, config, cache,
                                           pos_vec, lora)
-    live = logits[..., :config.vocab_size].astype(jnp.float32)
-    nxt = jnp.argmax(live, axis=-1).astype(jnp.int32)
-    lp = jnp.max(live, axis=-1) - jax.nn.logsumexp(live, axis=-1)
-    return cache, nxt, lp
+    nxt, lp, pos_next = _chosen(logits, config, tokens, pos_vec, live)
+    return cache, nxt, lp, pos_next
+
+
+@jax.jit
+def _set_rows(tokens, pos_vec, live, fresh):
+    """The one way the host writes into the tick's device vectors: `fresh`
+    int32 [4, B] holds a mark on the slots of which the host knows
+    better (admitted, adopted, finished or cancelled since the last
+    launch), and their token, position and liveness from the host's
+    mirror. Queued behind the tick in flight, whose outputs the vectors
+    are, so the host's word wins in program order."""
+    take = fresh[0] != 0
+    return tuple(jnp.where(take, new, old) for new, old in
+                 zip(fresh[1:], (tokens, pos_vec, live)))
+
+
+class _Flight(NamedTuple):
+    """A tick on the chip whose tokens the host has not read yet: the
+    device outputs, and what the host knew at the launch. `reqs[slot]`
+    is the request the tick decodes for at that slot (None: a dead slot,
+    or one whose budget ends with the tick ahead of this one); `live`
+    and `live_rows` are their count and the sum of their positions."""
+
+    nxt: Any
+    lp: Any
+    counts: Optional[Dict[str, Any]]
+    reqs: List[Optional["_Request"]]
+    live: int
+    live_rows: int
+    drafts: Optional[Dict[int, List[int]]]
 
 
 class _Adoption:
@@ -682,8 +784,18 @@ class ContinuousBatchingEngine:
         self.max_prefills_admitted_per_tick = 0
         self.max_adoptions_admitted_per_tick = 0
         self._last_stats_push = 0.0
+        # the host's mirror of each slot's last token and position, as
+        # of the newest tick READ; the tick's own copies stay on the
+        # chip (`_dev`: tokens, positions, liveness, the outputs of the
+        # newest tick launched) and the host writes only the rows it
+        # knows better (`_dirty`, `_set_rows`)
         self._tokens = np.zeros(max_batch, np.int32)
         self._pos = np.zeros(max_batch, np.int32)
+        self._dev = tuple(jnp.zeros(max_batch, jnp.int32)
+                          for _ in range(3))
+        self._dirty = np.zeros(max_batch, bool)
+        self.lookahead_ticks = 0      # launched behind a tick in flight
+        self.lookahead_discarded = 0  # their rows a finished slot left
         self._slot_req: List[Optional[_Request]] = [None] * max_batch
         self._free = list(range(max_batch))
         # multi-tenant LoRA (serve/lora.py AdapterPool, duck-typed so
@@ -882,9 +994,11 @@ class ContinuousBatchingEngine:
         """Queue a live weight swap; the decode loop applies it between
         ticks (never mid-tick), so in-flight requests keep their KV
         caches and keep decoding — under the new weights from the next
-        tick on — with no restart and no drop. Returns an Event set once
-        the swap has been applied. Two swaps queued between the same two
-        ticks coalesce: the newer wins, both events fire."""
+        tick LAUNCHED on; the one already on the chip finishes on the
+        old and its token is still emitted — with no restart and no
+        drop. Returns an Event set once the swap has been applied. Two
+        swaps queued between the same two ticks coalesce: the newer
+        wins, both events fire."""
         ev = threading.Event()
         with self._lock:
             prev = self._pending_swap
@@ -926,7 +1040,9 @@ class ContinuousBatchingEngine:
         itself): the decode loop frees its slot — and releases its KV
         pins and LoRA adapter pin — at the NEXT TICK BOUNDARY instead
         of decoding the abandoned request to completion (the PR-12
-        deadline path used to waste every remaining tick on it). The
+        deadline path used to waste every remaining tick on it; the
+        tick already launched for it is read and its row discarded,
+        never emitted). The
         freed slot is immediately re-admittable. Returns False when the
         request already finished (or was already cancelled); the
         stream's consumer sees a normal end-of-stream. `reason`
@@ -1007,6 +1123,8 @@ class ContinuousBatchingEngine:
             stateful=self.stateful,
             state_bytes_per_slot=self._state_bytes_per_slot,
             kv_bytes_per_token=self._kv_bytes_per_token,
+            lookahead_ticks=self.lookahead_ticks,
+            lookahead_discarded=self.lookahead_discarded,
         )
         s.update(self.speculation_stats())
         if self.kv_cache is None:
@@ -1098,6 +1216,25 @@ class ContinuousBatchingEngine:
         if adopted or admitted:
             self.publish_kv_telemetry()
 
+    def _long_prompt_waits(self) -> bool:
+        """Whether the next request to admit has a prompt longer than
+        half the window, and a slot to go to. Its prefill would hold the
+        tokens of the tick in flight, which are ready within a tick, for
+        as long as it runs (130 to 170 ms for 3,072 and 4,096 tokens of
+        Mistral's eight layers on a v5e, PERF.md PR 32): a finished
+        request would learn of its last token that much later. So the
+        loop reads that tick first and lets the chip wait for the
+        host's part of the admission, as it did before the lookahead;
+        a shorter prompt is queued behind the tick in flight. Not in the
+        pass that follows an admission: its tick was launched behind
+        that prefill and has only begun."""
+        if not self._free:
+            return False
+        with self._pending.mutex:
+            head = self._pending.queue[0] if self._pending.queue else None
+        return (head is not None
+                and 2 * head.prompt.shape[1] > self.config.max_seq_len)
+
     def _splice(self, ck, cv, slot: int, plen: int,
                 entry: Optional[Dict[str, Any]], state=()) -> None:
         """Both admission paths' write into the decode slab."""
@@ -1149,6 +1286,7 @@ class ContinuousBatchingEngine:
         self._slot_adapter[slot] = req.lora_slot
         self._tokens[slot] = adoption.first_token
         self._pos[slot] = plen
+        self._dirty[slot] = True
         self._emit(req, adoption.first_token, adoption.score)
         return True
 
@@ -1197,6 +1335,7 @@ class ContinuousBatchingEngine:
         self._slot_adapter[slot] = req.lora_slot
         self._tokens[slot] = first
         self._pos[slot] = plen
+        self._dirty[slot] = True
         self._emit(req, first, score)
         return True
 
@@ -1221,6 +1360,7 @@ class ContinuousBatchingEngine:
         if slot is not None:
             self._slot_req[slot] = None
             self._slot_adapter[slot] = 0
+            self._dirty[slot] = True  # dead from the next launch on
         if self.kv_cache is not None and req.block_table:
             self.kv_cache.release(req.block_table)
             req.block_table = []
@@ -1322,41 +1462,111 @@ class ContinuousBatchingEngine:
             out, self._spec_events = self._spec_events, []
         return out
 
-    def _run_tick(self, toks: np.ndarray, lora_live: bool,
-                  it: Optional[Dict[str, Any]], t0: float):
-        """Upload, dispatch and read back one decode step (`toks` [B]
-        or the speculative [B, k+1]); `t0` is where the pass's
-        dispatch time counts from. Returns the host copies of the
-        chosen tokens and their log-probabilities."""
-        with annotate("engine.tick_dispatch",
-                      live=self.max_batch - len(self._free)):
-            tok_dev = jnp.asarray(toks)
-            pos_dev = jnp.asarray(self._pos)
+    def _launch(self, it: Optional[Dict[str, Any]],
+                behind: Optional[_Flight] = None,
+                drafts: Optional[Dict[int, List[int]]] = None
+                ) -> Optional[_Flight]:
+        """Queue one decode step on the chip and return without reading
+        it. Its tokens and positions are the newest launched tick's
+        outputs where they lie (`_dev`); nothing is uploaded but the
+        rows the host knows better (`_dirty`), by one small program
+        queued ahead of the step (`_set_rows`). With `behind`, the tick
+        in flight whose tokens the host has not read, this is the
+        LOOKAHEAD: it decodes for the slots whose budget outlives that
+        tick (what ends a request one tick late, an EOS or a cancel,
+        leaves a row for `_land` to discard) and is not launched, None,
+        where no slot's does. `drafts` makes it the speculative verify
+        tick, [B, k+1] built from the host's mirror, which is whole
+        because nothing is in flight; the device vectors are stale after
+        it."""
+        reqs: List[Optional[_Request]] = []
+        rows = 0
+        for slot, req in enumerate(self._slot_req):
+            ahead = int(behind is not None and req is not None
+                        and behind.reqs[slot] is req)
+            if req is None or req.produced + ahead >= req.max_new:
+                reqs.append(None)
+            else:
+                reqs.append(req)
+                rows += int(self._pos[slot]) + ahead
+        live = sum(r is not None for r in reqs)
+        if not live:
+            return None
+        t0 = _clock(it)
+        with annotate("engine.tick_dispatch", live=live):
+            if drafts:
+                tok = jnp.asarray(self._spec_tokens(drafts))
+                pos, mask = jnp.asarray(self._pos), None
+                self._dirty[:] = True
+            else:
+                if self._dirty.any():
+                    fresh = np.empty((4, self.max_batch), np.int32)
+                    fresh[0], fresh[1] = self._dirty, self._tokens
+                    fresh[2] = self._pos
+                    fresh[3] = [r is not None for r in self._slot_req]
+                    self._dev = _set_rows(*self._dev, fresh)
+                    self._dirty[:] = False
+                tok, pos, mask = self._dev
             counts = None
-            if lora_live:
-                cache, nxt, lp = self.lora_pool.dispatch_tick(
+            if (self.lora_pool is not None
+                    and bool(self._slot_adapter.any())):
+                cache, nxt, lp, pos_next = self.lora_pool.dispatch_tick(
                     lambda la: _tick_lora(
-                        self.params, self.config, self._cache, tok_dev,
-                        pos_dev, la),
+                        self.params, self.config, self._cache, tok, pos,
+                        la, mask),
                     self._slot_adapter)
             else:
-                cache, nxt, lp, counts = _tick(
-                    self.params, self.config, self._cache, tok_dev,
-                    pos_dev)
+                cache, nxt, lp, counts, pos_next = _tick(
+                    self.params, self.config, self._cache, tok, pos, mask)
             self._cache = cache
+            if not drafts:
+                self._dev = (nxt, pos_next, mask)
+        if behind is not None:
+            self.lookahead_ticks += 1
         if it is not None:
-            t1 = _now()
-            it["dispatch_ms"] = (t1 - t0) * 1e3
+            it["dispatch_ms"] += (_now() - t0) * 1e3
+        return _Flight(nxt, lp, counts, reqs, live, rows, drafts)
+
+    def _land(self, flight: _Flight, it: Optional[Dict[str, Any]],
+              inflight: int = 0) -> None:
+        """Read a tick's tokens and log-probabilities back (BLOCKED on
+        the device; `inflight`: the ticks queued behind it meanwhile)
+        and walk its slots: emit, finish, queue puts. A row whose
+        request finished or was cancelled after the launch is DISCARDED:
+        never emitted, never scored, never in `req.ctx`. The pass's
+        record takes the tick's `live`, `live_rows` and counters, so
+        that they describe one tick, the one read here."""
+        t0 = _clock(it)
         with annotate("engine.tick_readback"):
-            nxt_np = np.asarray(nxt)
-            lp_np = np.asarray(lp)
-            if it is not None and counts:
+            nxt_np = np.asarray(flight.nxt)
+            lp_np = np.asarray(flight.lp)
+            if it is not None and flight.counts:
                 # the step is over once the tokens are here: these come
                 # without another wait for the device
-                it.update({k: int(v) for k, v in counts.items()})
+                it.update({k: int(v) for k, v in flight.counts.items()})
+        t1 = _clock(it)
+        discarded = 0
+        with annotate("engine.emit"):
+            if flight.drafts:
+                self._spec_emit(flight.drafts, nxt_np, lp_np)
+            else:
+                for slot, req in enumerate(flight.reqs):
+                    if req is None:
+                        continue
+                    if req.finished:
+                        discarded += 1
+                        continue
+                    self._pos[slot] += 1
+                    tok = int(nxt_np[slot])
+                    self._tokens[slot] = tok
+                    self._emit(req, tok, float(lp_np[slot]))
+        self.lookahead_discarded += discarded
         if it is not None:
-            it["readback_ms"] = (_now() - t1) * 1e3
-        return nxt_np, lp_np
+            it["readback_ms"] += (t1 - t0) * 1e3
+            it["emit_ms"] += (_now() - t1) * 1e3
+            it["discarded"] += discarded
+            it.update(live=flight.live, live_rows=flight.live_rows,
+                      inflight=inflight)
 
     def _spec_tokens(self, drafts: Dict[int, List[int]]) -> np.ndarray:
         """The widened verify tick's input: [last_token, draft...] per
@@ -1422,6 +1632,10 @@ class ContinuousBatchingEngine:
 
     def _loop(self) -> None:
         name_thread(threading.current_thread().name)
+        # the tick on the chip whose tokens the host has not read
+        flight: Optional[_Flight] = None
+        # the pass before this one admitted a prompt
+        chained = False
         while not self._stopped.is_set():
             # the pass's record (module docstring); None, and no clock
             # read anywhere below, while the flight recorder is off
@@ -1429,54 +1643,60 @@ class ContinuousBatchingEngine:
             if reqtrace.enabled():
                 t_top = _now()
                 it = {"engine_id": self.engine_id, "ts": time.time(),
-                      "live": self.max_batch - len(self._free),
-                      "live_rows": sum(
-                          int(self._pos[slot]) for slot, r in
-                          enumerate(self._slot_req) if r is not None),
+                      "live": 0, "live_rows": 0,
                       "max_batch": self.max_batch,
                       "pending": self._pending.qsize(),
                       "admit_ms": 0.0, "admissions": [],
                       "dispatch_ms": 0.0, "readback_ms": 0.0,
-                      "emit_ms": 0.0, "total_ms": 0.0}
+                      "emit_ms": 0.0, "total_ms": 0.0,
+                      "inflight": 0, "discarded": 0}
+            busy = flight is not None or len(self._free) < self.max_batch
+            # a swap holds from the next LAUNCH: the tick in flight
+            # finishes on the weights it was launched with
             self._apply_pending_swap()
             self._apply_cancels()
+            # a long prompt's prefill is not queued behind the tick in
+            # flight (`_long_prompt_waits`): that tick is read and its
+            # tokens go out first, and the pass ends with the next tick
+            # launched and not read
+            held = (flight is not None and not chained
+                    and not self.speculate_k and self._long_prompt_waits())
+            if held:
+                self._land(flight, it)
+                flight = None
+            prefills = self.prefill_admitted
             t_admit = _clock(it)
             with annotate("engine.admit"):
                 self._admit(it)
-            t_tick = _clock(it)
             if it is not None and it["admissions"]:
-                it["admit_ms"] = (t_tick - t_admit) * 1e3
-            if all(r is None for r in self._slot_req):
-                if it is not None and (it["live"] or it["admissions"]):
-                    # every slot finished or was cancelled in this pass
-                    self._record_pass(it, t_top)
+                it["admit_ms"] = (_now() - t_admit) * 1e3
+            chained = self.prefill_admitted != prefills
+            drafts: Dict[int, List[int]] = {}
+            if self.speculate_k:
+                if flight is not None:
+                    # drafts are made from the host's tokens: the tick
+                    # in flight is read first
+                    self._land(flight, it)
+                    flight = None
+                drafts = self._collect_drafts()
+            ahead = None
+            if drafts:
+                flight = self._launch(it, drafts=drafts)
+            else:
+                if flight is None:
+                    flight = self._launch(it)
+                if flight is not None and not held:
+                    ahead = self._launch(it, behind=flight)
+            # one tick read a pass, the one its record describes: after
+            # a hold the tick just launched is the next pass's to read
+            if flight is not None and not held:
+                self._land(flight, it, int(ahead is not None))
+                flight = ahead
+            elif not (held or busy
+                      or (it is not None and it["admissions"])):
                 self._stopped.wait(self.idle_sleep_s)
                 continue
-            lora_live = (self.lora_pool is not None
-                         and bool(self._slot_adapter.any()))
-            drafts = (self._collect_drafts() if self.speculate_k
-                      else {})
-            if drafts:
-                # the verify tick counts its dispatch from here: the
-                # drafting above is bookkeeping
-                t_tick, toks = _clock(it), self._spec_tokens(drafts)
-            else:
-                toks = self._tokens
-            nxt_np, lp_np = self._run_tick(toks, lora_live, it, t_tick)
-            t_emit = _clock(it)
-            with annotate("engine.emit"):
-                if drafts:
-                    self._spec_emit(drafts, nxt_np, lp_np)
-                else:
-                    for slot, req in enumerate(self._slot_req):
-                        if req is None:
-                            continue
-                        self._pos[slot] += 1
-                        tok = int(nxt_np[slot])
-                        self._tokens[slot] = tok
-                        self._emit(req, tok, float(lp_np[slot]))
             if it is not None:
-                it["emit_ms"] = (_now() - t_emit) * 1e3
                 self._record_pass(it, t_top)
 
     @staticmethod

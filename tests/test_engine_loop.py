@@ -2,7 +2,9 @@
 iteration in the flight recorder's store, the first token's wait split
 into the engine's queue and the request's own prefill, the same
 boundaries as `engine.*` spans in a `jax.profiler` trace, and nothing
-of it while `RAY_TPU_REQTRACE=0`."""
+of it while `RAY_TPU_REQTRACE=0`. And the loop's one tick of lookahead
+(PR 32): every stream is what decoding alone gives, token for token and
+score for score, whatever the host learns a tick late."""
 from __future__ import annotations
 
 import dataclasses
@@ -11,10 +13,12 @@ import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from ray_tpu.models import engine as engine_mod
 from ray_tpu.models.engine import ContinuousBatchingEngine
+from ray_tpu.models.generate import _model_fns, generate
 from ray_tpu.models.llama import LlamaConfig, llama_init
 from ray_tpu.observability import requests as reqtrace
 from ray_tpu.serve.disagg import DecodeServer, DisaggRouter, PrefillServer
@@ -25,7 +29,7 @@ BS = 4
 PROMPT = list(range(1, 20))  # 19 tokens: four blocks of 4 and a tail of 3
 FIELDS = {"engine_id", "ts", "live", "live_rows", "max_batch", "pending",
           "admit_ms", "admissions", "dispatch_ms", "readback_ms",
-          "emit_ms", "total_ms"}
+          "emit_ms", "total_ms", "inflight", "discarded"}
 ADMISSION_FIELDS = {"rid", "prompt_tokens", "suffix_tokens",
                     "reused_tokens", "lookup_ms", "prefill_ms",
                     "commit_ms", "commit_dispatches", "commit_blocks",
@@ -61,6 +65,7 @@ def _ring(eng):
 
 def test_one_record_per_iteration_and_the_parts_fit(engine):
     assert len(engine.generate(PROMPT, 6)) == 6
+    engine.stop()  # the last pass is recorded after its last token
     ring = _ring(engine)
     # the admitting pass emits the prefill's token and one tick's;
     # every further pass emits one
@@ -70,8 +75,19 @@ def test_one_record_per_iteration_and_the_parts_fit(engine):
         parts = (r["admit_ms"] + r["dispatch_ms"] + r["readback_ms"]
                  + r["emit_ms"])
         assert 0.0 < parts <= r["total_ms"]
-        assert min(r["dispatch_ms"], r["readback_ms"], r["emit_ms"]) > 0
-    assert [r["live"] for r in ring] == [0, 1, 1, 1, 1]
+        assert min(r["readback_ms"], r["emit_ms"]) > 0
+    # `live` and `live_rows` are those of the tick the pass read back:
+    # the request is in all five, one row deeper in each
+    assert [r["live"] for r in ring] == [1, 1, 1, 1, 1]
+    assert [r["live_rows"] for r in ring] == [19, 20, 21, 22, 23]
+    # the admitting pass launches its tick and the one after it; every
+    # pass launches one more while it waits, but for the last: the
+    # budget says that no slot outlives the tick it reads
+    assert [r["inflight"] for r in ring] == [1, 1, 1, 1, 0]
+    assert [r["dispatch_ms"] > 0 for r in ring] == [True] * 4 + [False]
+    assert all(r["discarded"] == 0 for r in ring)
+    stats = engine.kv_stats()
+    assert (stats["lookahead_ticks"], stats["lookahead_discarded"]) == (4, 0)
     assert ring[0]["pending"] == 1 and ring[1]["pending"] == 0
     assert all(a["ts"] <= b["ts"] for a, b in zip(ring, ring[1:]))
     # nothing was admitted after the first pass, so nothing is charged
@@ -84,17 +100,33 @@ def test_live_is_the_slots_in_flight(engine):
                for i, n in enumerate(budgets)]
     for s in streams:
         assert len(list(s)) == s._req.max_new
+    engine.stop()  # the last pass is recorded after its last token
     ring = _ring(engine)
     admitted = {a["rid"]: i for i, r in enumerate(ring)
                 for a in r["admissions"]}
     assert sorted(admitted) == [0, 1, 2]
+    # admitted in pass i, a request is first in the tick that pass
+    # launches: the one it reads too where it began with nothing on the
+    # chip, else the one the next pass reads. With a budget of n it is
+    # in n - 1 ticks (the prefill gave its first token)
+    first = {rid: i if i == 0 or not ring[i - 1]["inflight"] else i + 1
+             for rid, i in admitted.items()}
     for j, r in enumerate(ring):
-        # admitted in pass i with a budget of n, a request decodes at
-        # the top of passes i+1 .. i+n-2 (two tokens in pass i)
-        want = sum(1 for rid, i in admitted.items()
-                   if i < j <= i + budgets[rid] - 2)
-        assert r["live"] == want, (j, r["live"], want)
+        want = [rid for rid, i in first.items()
+                if i <= j <= i + budgets[rid] - 2]
+        assert r["live"] == len(want), (j, r["live"], want)
+        # three prompt tokens, and a row more for each tick before
+        assert r["live_rows"] == sum(3 + j - first[rid] for rid in want)
         assert len(r["admissions"]) <= engine.max_prefills_per_tick
+        # a tick is queued behind the one read unless no slot is left
+        # to decode for: no budget outlives the tick read, and nothing
+        # was admitted since its launch
+        outlives = [rid for rid in want
+                    if j < first[rid] + budgets[rid] - 2]
+        joins = [rid for rid, i in first.items() if i == j + 1]
+        assert r["inflight"] == int(bool(outlives or joins)), j
+    assert len(ring) == max(first[rid] + budgets[rid] - 1
+                            for rid in first)
 
 
 def test_admission_entry_on_a_cold_and_a_warm_cache(engine):
@@ -186,6 +218,7 @@ def test_speculative_tick_records_the_same_fields(model):
         draft_source=lambda ctx, k: [ctx[-1]] * k)
     try:
         assert len(eng.generate([4, 5, 6], 8)) == 8
+        stats = eng.kv_stats()
     finally:
         eng.stop()
     ring = _ring(eng)
@@ -195,6 +228,251 @@ def test_speculative_tick_records_the_same_fields(model):
         assert min(r["dispatch_ms"], r["readback_ms"], r["emit_ms"]) > 0
         assert (r["admit_ms"] + r["dispatch_ms"] + r["readback_ms"]
                 + r["emit_ms"]) <= r["total_ms"]
+        # a pass with drafts is launched from the host's tokens and read
+        # at once: nothing is queued behind it, nothing thrown away
+        assert (r["live"], r["inflight"], r["discarded"]) == (1, 0, 0)
+    assert len(ring) == eng.spec_verify_ticks
+    assert stats["lookahead_ticks"] == 0
+
+
+def test_a_pass_without_drafts_looks_ahead_and_one_with_drains(model):
+    """A proposer that drafts in some passes only: the draftless ones
+    keep a tick queued, the drafting ones read it first, and the stream
+    is generate()'s either way."""
+    prompt = [4, 5, 6, 7]
+    eng = ContinuousBatchingEngine(
+        model, CFG, max_batch=2, speculate_k=2,
+        draft_source=lambda ctx, k: [ctx[-1]] * k
+        if len(ctx) % 5 == 0 else [])
+    try:
+        got = eng.generate(prompt, 24)
+        stats = eng.kv_stats()
+    finally:
+        eng.stop()
+    want = np.asarray(generate(model, CFG, jnp.asarray([prompt], jnp.int32),
+                               max_new_tokens=24))[0].tolist()
+    assert got == want
+    ring = _ring(eng)
+    assert eng.spec_verify_ticks >= 2
+    assert stats["lookahead_ticks"] >= 2
+    # a verify tick is never queued behind, a lookahead always is
+    assert sum(r["inflight"] for r in ring) == stats["lookahead_ticks"]
+    assert sum(not r["inflight"] for r in ring) >= eng.spec_verify_ticks
+    assert all(r["discarded"] == 0 for r in ring)
+
+
+# ------------------------------------------------- one tick of lookahead
+
+def _stirred(params):
+    """Norm weights are ones and the layers a whisper at init, and the
+    greedy stream then one token over and over: make every leaf count."""
+    keys = iter(jax.random.split(jax.random.PRNGKey(4), 200))
+    return jax.tree.map(lambda x: x + 0.05 * jax.random.normal(
+        next(keys), x.shape, x.dtype), params)
+
+
+def _family(name):
+    """A tiny model of each family the engine serves, in float32."""
+    if name == "llama":
+        return CFG, llama_init(CFG, jax.random.PRNGKey(0))
+    if name == "gpt2":
+        from ray_tpu.models.gpt2 import GPT2Config, gpt2_init
+
+        cfg = dataclasses.replace(GPT2Config.tiny(), dtype=jnp.float32)
+        return cfg, _stirred(gpt2_init(cfg, jax.random.PRNGKey(3)))
+    if name == "nemotron_h":
+        from ray_tpu.models.nemotron_h import (NemotronHConfig,
+                                               nemotron_h_init)
+
+        cfg = dataclasses.replace(NemotronHConfig.tiny(),
+                                  dtype=jnp.float32)
+        return cfg, nemotron_h_init(cfg, jax.random.PRNGKey(0))
+    from ray_tpu.models import kimi_linear as kl
+
+    cfg = dataclasses.replace(kl.KimiLinearConfig.tiny(),
+                              dtype=jnp.float32)
+    return cfg, _stirred(kl.kimi_linear_init(cfg, jax.random.PRNGKey(3)))
+
+
+def _alone(params, cfg, prompt, n):
+    """generate()'s algorithm for one sequence, with the scores beside
+    the tokens: the family's `forward_cached` on a cache of one, a token
+    at a time. Not the engine's ragged decode."""
+    fwd, init_cache, _ = _model_fns(cfg)
+    step = jax.jit(fwd, static_argnums=(2,))
+    logits, cache = jax.jit(
+        lambda prm, run, cache: fwd(prm, run, cfg, cache, 0))(
+        params, jnp.asarray([prompt], jnp.int32), init_cache(cfg, 1))
+    toks, scores = [], []
+    for i in range(n):
+        lp = jax.nn.log_softmax(
+            logits[0, -1, :cfg.vocab_size].astype(jnp.float32))
+        toks.append(int(jnp.argmax(lp)))
+        scores.append(float(lp[toks[-1]]))
+        logits, cache = step(params, jnp.asarray([[toks[-1]]], jnp.int32),
+                             cfg, cache, jnp.int32(len(prompt) + i))
+    return toks, scores
+
+
+@pytest.mark.parametrize("family", ["gpt2", "llama", "nemotron_h",
+                                    "kimi_linear"])
+def test_streams_under_the_lookahead_are_what_each_decodes_alone(family):
+    """Two slots and seven requests, so that every finish frees a slot
+    that the very next pass admits into: budget finishes, an EOS the
+    host learns of after the next tick was launched (its row is
+    discarded), a cancel in mid-flight (likewise), and a request whose
+    last token lands on the window's last row. A family whose slots own
+    state gets it overwritten whole by the splice, or these would
+    differ."""
+    cfg, params = _family(family)
+    rng = np.random.default_rng(11)
+    plen, window = 7, cfg.max_seq_len
+    prompts = [rng.integers(1, 500, plen).tolist() for _ in range(7)]
+    budgets = [100, 9, 5, 40, 6, 8, window - plen]
+    CANCEL, EOS, LAST = 0, 3, 6
+    want = [_alone(params, cfg, p, n) for p, n in zip(prompts, budgets)]
+    one = np.asarray(generate(params, cfg, jnp.asarray([prompts[1]],
+                                                       jnp.int32),
+                              max_new_tokens=budgets[1]))[0].tolist()
+    assert want[1][0] == one
+    # an EOS in mid-budget: a token the stream has not shown before
+    toks = want[EOS][0]
+    at = next(j for j in range(1, budgets[EOS] - 2)
+              if toks[j] not in toks[:j])
+    eos = [None] * 7
+    eos[EOS] = toks[at]
+    want[EOS] = (toks[:at + 1], want[EOS][1][:at + 1])
+    eng = ContinuousBatchingEngine(params, cfg, max_batch=2)
+    streams = []
+    emit = eng._emit
+
+    def emit_then_cancel(req, tok, score=0.0):
+        # the cancel falls in the walk over a tick's tokens, the next
+        # tick already launched: on the loop's thread, so that where it
+        # lands does not hang on how the threads are scheduled
+        emit(req, tok, score)
+        if req is streams[CANCEL]._req and req.produced == 4:
+            assert eng.cancel_slot(req) is True
+
+    eng._emit = emit_then_cancel
+    try:
+        streams.append(eng.stream(prompts[0], budgets[0]))
+        streams += [eng.stream(p, n, eos_token=e) for p, n, e
+                    in zip(prompts[1:], budgets[1:], eos[1:])]
+        got = [[int(t) for t in s] for s in streams]
+        stats = eng.kv_stats()
+    finally:
+        eng.stop()
+    want[CANCEL] = (want[CANCEL][0][:4], want[CANCEL][1][:4])
+    for i, (stream, (toks, scores)) in enumerate(zip(streams, want)):
+        assert got[i] == toks, i
+        # the discarded token left no score either
+        np.testing.assert_allclose(stream.scores, scores, atol=2e-4,
+                                   rtol=0, err_msg=str(i))
+    assert len(got[LAST]) == window - plen
+    ring = _ring(eng)
+    # exactly one row thrown away for the EOS and one for the cancel;
+    # a budget's end is known before the launch and costs none
+    assert stats["lookahead_discarded"] == 2 == sum(
+        r["discarded"] for r in ring)
+    assert stats["cancelled"] == 1 and stats["admitted"] == 7
+    assert stats["lookahead_ticks"] == sum(r["inflight"] for r in ring)
+    # a pass keeps a tick queued unless every slot's budget ends with
+    # the tick it reads: the last pass, and at most the few in which
+    # both slots ended together
+    assert ring[-1]["inflight"] == 0
+    assert stats["lookahead_ticks"] >= len(ring) - 4
+    assert all(0 <= r["live_rows"] <= 2 * (window - 1) for r in ring)
+
+
+def test_a_swap_with_a_tick_in_flight_holds_from_the_next_launch():
+    """The tick in flight finishes on the weights it was launched with
+    and the next is launched on the new: no token is dropped or emitted
+    twice over the swap, and a request after it is a fresh engine's."""
+    from ray_tpu.models.gpt2 import GPT2Config, gpt2_init
+
+    cfg = dataclasses.replace(GPT2Config.tiny(), dtype=jnp.float32)
+    old = _stirred(gpt2_init(cfg, jax.random.PRNGKey(0)))
+    new = jax.tree.map(lambda x: x * 1.25, old)
+    prompt, n = [1, 2, 3], 90
+    eng = ContinuousBatchingEngine(old, cfg, max_batch=2, params_version=1)
+    fresh = ContinuousBatchingEngine(new, cfg, max_batch=2)
+    try:
+        stream = eng.stream(prompt, n)
+        head = [next(stream) for _ in range(5)]
+        assert eng.update_params(new, version=2).wait(timeout=30.0)
+        got = head + [int(t) for t in stream]
+        assert len(got) == n and len(stream.scores) == n
+        assert eng.swap_count == 1 and eng.params_version == 2
+        # up to the first token the new weights chose, the stream is the
+        # old weights'
+        before = _alone(old, cfg, prompt, n)[0]
+        cut = next((j for j in range(n) if got[j] != before[j]), n)
+        assert 5 <= cut
+        for p in ([5, 6], [9, 9, 9, 9]):
+            assert eng.generate(p, 8) == fresh.generate(p, 8)
+    finally:
+        eng.stop()
+        fresh.stop()
+    # the swap emptied nothing: every pass of the long request but its
+    # last kept a tick queued, one pass a token
+    ring = _ring(eng)[:n - 1]
+    assert [r["inflight"] for r in ring] == [1] * (n - 2) + [0]
+    assert all(r["discarded"] == 0 for r in ring)
+
+
+def test_a_long_prompt_is_not_prefilled_behind_the_tick_in_flight(model):
+    """A prompt longer than half the window is admitted with nothing on
+    the chip: the tick in flight is read and its tokens go out first,
+    and the pass ends with the next tick launched. A short prompt, and
+    the admission that follows another, queue behind the tick in flight.
+    Every stream is what it decodes alone."""
+    window = CFG.max_seq_len
+    rng = np.random.default_rng(5)
+    long1 = rng.integers(1, 500, window // 2 + 6).tolist()
+    long2 = rng.integers(1, 500, window // 2 + 9).tolist()
+    short = rng.integers(1, 500, window // 2).tolist()
+    first = [9, 8, 7]
+    eng = ContinuousBatchingEngine(model, CFG, max_batch=4)
+    later = {}
+    emit = eng._emit
+
+    def emit_then_submit(req, tok, score=0.0):
+        # on the loop's thread, in the walk over a tick's tokens with
+        # the next tick launched: where the submissions land does not
+        # hang on how the threads are scheduled
+        emit(req, tok, score)
+        if req is head._req and req.produced == 4:
+            later["long1"] = eng.stream(long1, 5)
+            later["long2"] = eng.stream(long2, 5)
+        if req is head._req and req.produced == 40:
+            later["short"] = eng.stream(short, 5)
+
+    eng._emit = emit_then_submit
+    try:
+        head = eng.stream(first, 90)
+        got = {"first": [int(t) for t in head]}
+        got.update({k: [int(t) for t in v] for k, v in later.items()})
+    finally:
+        eng.stop()
+    for name, prompt, n in (("first", first, 90), ("long1", long1, 5),
+                            ("long2", long2, 5), ("short", short, 5)):
+        assert got[name] == _alone(model, CFG, prompt, n)[0], name
+    ring = _ring(eng)
+    at = {a["prompt_tokens"]: i for i, r in enumerate(ring)
+          for a in r["admissions"] if a["rid"] > 0}
+    held, chained, queued = (ring[at[len(p)]] for p in (long1, long2, short))
+    # the long prompt's pass read the tick in flight, with the first
+    # request alone in it, and left nothing queued behind it ...
+    assert (held["inflight"], held["live"]) == (0, 1)
+    assert held["readback_ms"] > 0 and held["admit_ms"] > 0
+    assert ring[at[len(long1)] - 1]["inflight"] == 1
+    # ... the one behind it and the short one kept a tick queued
+    assert at[len(long2)] == at[len(long1)] + 1
+    assert chained["inflight"] == 1 and queued["inflight"] == 1
+    for r in ring:
+        assert r["dispatch_ms"] + r["emit_ms"] <= (
+            r["total_ms"] - r["readback_ms"] - r["admit_ms"]) + 1e-6
 
 
 @pytest.mark.parametrize("path", ["colocated", "disagg"])

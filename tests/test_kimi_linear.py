@@ -277,9 +277,10 @@ def test_the_engine_refuses_for_this_familys_state_in_words(params):
 # ----------------- the older families' programs, as before the protocol
 
 def _parent_programs():
-    """`_prefill_paged`, `_splice_slot` and `_tick` as the parent commit
-    had them (a sequence entry always held "k" AND "v"), under the
-    engine's own names so that the lowered modules are named alike."""
+    """`_prefill_paged`, `_splice_slot` and `_tick` as PR 31's parent
+    commit had them (a sequence entry always held "k" AND "v"), under
+    the engine's own names so that the lowered modules are named alike.
+    `_tick` is that commit's but for its last output (PR 32)."""
 
     @functools.partial(jax.jit, static_argnums=(2,))
     def _prefill_paged(params, suffix, config, prefix_k, prefix_v):
@@ -329,7 +330,9 @@ def _parent_programs():
         live = logits[..., :config.vocab_size].astype(jnp.float32)
         nxt = jnp.argmax(live, axis=-1).astype(jnp.int32)
         lp = jnp.max(live, axis=-1) - jax.nn.logsumexp(live, axis=-1)
-        return cache, nxt, lp, (counts[0] if counts else None)
+        # PR 32: the position vector advanced comes back beside them,
+        # the next tick's input where it lies; nothing else is new
+        return cache, nxt, lp, (counts[0] if counts else None), pos_vec + 1
 
     return {"_prefill_paged": _prefill_paged, "_splice_slot": _splice_slot,
             "_tick": _tick}

@@ -519,13 +519,19 @@ def test_preempted_stream_resumes_as_child_span_one_id(model):
                                timeout=180.0)
             out["rid"] = resp.getheader("X-Request-Id")
             out["status"] = resp.status
+            resp.readline()
+            streaming.set()
             while resp.readline():
                 pass
             conn.close()
 
+        streaming = threading.Event()
         th = threading.Thread(target=batch_client, daemon=True)
         th.start()
-        time.sleep(0.8)       # land inside the production window
+        # land inside the production window: the first frame on the
+        # wire says the slot is taken, and 600 tokens outlast one POST
+        # (a sleep of 0.8 s no longer does: PR 32's loop is done by then)
+        assert streaming.wait(timeout=120)
         conn, resp = _post(host, port, "/v1/completions",
                            body={"model": "tiny", "prompt": [4, 5],
                                  "max_tokens": 16,
