@@ -12,7 +12,12 @@ it a prefix pool, speculation, a LoRA pool and disaggregated adoption),
 and Kimi-Linear (`kimi_linear.py`: delta-rule and latent-attention mixers
 over dense and expert layers; beside its state a slot owns the engine's
 third kind of cache entry, ONE latent row a token in a single array, from
-which keys and values are both made; refused what Nemotron-H is).
+which keys and values are both made; refused what Nemotron-H is), and
+DeepSeek-V2 (`deepseek_v2.py`: rotary latent attention under YaRN over a
+dense layer and group-routed expert layers; its cache is latent rows
+ALONE, no values and no state, so the engine builds it no prefix pool and
+refuses it adoption, speculation and a LoRA pool, each for what the pool
+lacks).
 `moe_transformer.py` trains and is not served.
 """
 from .gpt2 import (  # noqa: F401
@@ -47,6 +52,13 @@ from .kimi_linear import (  # noqa: F401
     kimi_linear_init,
     kimi_linear_loss,
     kimi_linear_partition_specs,
+)
+from .deepseek_v2 import (  # noqa: F401
+    DeepseekV2Config,
+    deepseek_v2_forward,
+    deepseek_v2_init,
+    deepseek_v2_loss,
+    deepseek_v2_partition_specs,
 )
 from .moe_transformer import (  # noqa: F401
     MoEConfig,

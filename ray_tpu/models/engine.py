@@ -87,6 +87,20 @@ the state), speculation (``speculate_k > 0``: a state a draft advanced
 cannot be un-advanced), a ``lora_pool``, and ``adopt_prefill`` (a
 transfer carries keys and values only).
 
+A cache may hold latent entries ALONE: no values and no state
+(models/deepseek_v2.py). Such a family has nothing a block-aligned
+prefix could not resume, but the paged pool itself speaks keys AND
+values: it sizes a block `[heads, head_dim]` from a key tensor and
+commits `ck, cv` side by side (models/kvcache.py; the latent pool is
+ROADMAP R3). So the engine builds it no pool (left to its default;
+``prefix_cache=True`` is refused by name) and serves every prompt from
+position 0, and refuses what stands on the pool or on pairs of keys and
+values: ``adopt_prefill`` and the disaggregated transfer (they carry
+`ck` and `cv`), speculation (its first proposer drafts from the pool's
+token chains, and the family's decode has no ``[B, k+1]`` verify form)
+and a ``lora_pool`` (its per-tenant prefix namespaces are the pool's).
+Each ValueError names what the pool lacks (``_refuse_for_latent``).
+
 The loop keeps one tick ahead. The token vector and the position vector
 of the decode step live on the chip: `_tick` hands back, beside the
 cache, the chosen tokens and the positions advanced, which are, where
@@ -146,9 +160,13 @@ queues behind the tick in flight, whose rest it therefore holds),
 ``commit_ms`` with ``commit_dispatches`` (programs the pool commit
 launched: its one program once for the keys' pool and once for the
 values', whatever the blocks; 0 where nothing was new) and
-``commit_blocks``, ``splice_ms``, and for a family with state
-``state_bytes``, what the splice wrote whole; an adoption has
-``prefill_ms`` 0);
+``commit_blocks``, ``splice_ms``, for a family with state
+``state_bytes``, what the splice wrote whole, and where the family's
+``forward_cached`` has a form that counts the run, its counters under their own
+names (models/deepseek_v2.py: ``moe_pairs_held`` and ``moe_rows_max``,
+what the grouped products saw over the prompt, and ``attn_blocks``, the
+blocks of scores the prompt form computed; ``kv_stats()`` holds their
+totals as ``prefill_counters``); an adoption has ``prefill_ms`` 0);
 ``dispatch_ms`` (inside ``_launch``, every launch of the pass: the
 lookahead's dispatch, before it ``_set_rows`` in a pass that follows an
 admission or a finish, and the tick's own where nothing was in flight;
@@ -267,6 +285,17 @@ def spec_metrics() -> Dict[str, Any]:
     return _spec_metrics
 
 
+LATENT_ONLY = ("this family's cache holds one latent row a token and no "
+               "values: ")
+
+
+def latent_only(cache) -> bool:
+    """Whether a family's cache (`init_cache`) is sequence entries of one
+    array each ("k" and no "v") and nothing else."""
+    return all("k" in blk and "v" not in blk and len(blk) == 1
+               for blk in cache)
+
+
 def _prefill_body(params, suffix, config, prefix_k, prefix_v):
     """What `_prefill_paged` and `_prefill_paged_lora` trace. The
     family's single-sequence cache (`init_cache(config, 1)`) is laid out
@@ -278,7 +307,10 @@ def _prefill_body(params, suffix, config, prefix_k, prefix_v):
     sequence axis, each as the family left it (a slot's state; the empty
     list for a family that has none). A family whose sequence entries
     hold one array (a latent row: "k" alone) gets `cv` None, and
-    `prefix_v` is not read."""
+    `prefix_v` is not read. Last, the counters of the run where the
+    family's `forward_cached` carries a counted form of itself
+    (`with_counters`: a dict of small numbers, models/deepseek_v2.py),
+    else None."""
     fwd, init_cache, _ = _model_fns(config)
     c = prefix_k.shape[1]
     cache = list(init_cache(config, 1))
@@ -299,11 +331,15 @@ def _prefill_body(params, suffix, config, prefix_k, prefix_v):
         cache[i] = {"k": base_k[j][None]}
         if paired:
             cache[i]["v"] = base_v[j][None]
-    logits, cache = fwd(params, suffix, config, cache, c)
+    counted = getattr(fwd, "with_counters", None)
+    if counted is None:
+        (logits, cache), counts = fwd(params, suffix, config, cache, c), None
+    else:
+        logits, cache, counts = counted(params, suffix, config, cache, c)
     ck = jnp.stack([cache[i]["k"][0] for i in kv_at])
     cv = jnp.stack([cache[i]["v"][0] for i in kv_at]) if paired else None
     state = [blk for blk in cache if "k" not in blk]
-    return logits[:, -1], ck, cv, state
+    return logits[:, -1], ck, cv, state, counts
 
 
 @functools.partial(jax.jit, static_argnums=(2,))
@@ -313,7 +349,7 @@ def _prefill_paged(params, suffix, config, prefix_k, prefix_v):
     family with state has). The window is the full max_seq_len slab —
     the same reduction shapes as generate()'s prefill, so cached and
     uncached paths stay bit-identical. Returns (last logits, ck, cv,
-    state): `_prefill_body`. One compile per distinct (cached, suffix)
+    state, counters): `_prefill_body`. One compile per distinct (cached, suffix)
     length pair."""
     return _prefill_body(params, suffix, config, prefix_k, prefix_v)
 
@@ -339,9 +375,9 @@ def _prefill_with_cache(params, config, kv_cache, prompt, empty_prefix,
     prefix_hit event → greedy first token + its logprob score. ONE
     implementation keeps the two paths bit-identical (the disagg
     equivalence tests depend on it). Returns `(ck, cv, state,
-    block_table, first, score, outcome, reused, suffix_len)` (`state`:
-    `_prefill_body`); the caller owns the returned pins (empty list
-    when no cache).
+    block_table, first, score, outcome, reused, suffix_len, counters)`
+    (`state` and `counters`, a dict of ints or None: `_prefill_body`);
+    the caller owns the returned pins (empty list when no cache).
 
     `adapter`/`namespace` (multi-tenant LoRA, serve/lora.py): prefill
     under one tenant's adapter slice, with the prefix cache keyed by
@@ -373,10 +409,10 @@ def _prefill_with_cache(params, config, kv_cache, prompt, empty_prefix,
     # self time is the prefill's
     with annotate("engine.prefill", rid=rid, prompt_tokens=plen):
         if adapter is not None:
-            last_logits, ck, cv, state = _prefill_paged_lora(
+            last_logits, ck, cv, state, counts = _prefill_paged_lora(
                 params, suffix, config, prefix_k, prefix_v, adapter)
         else:
-            last_logits, ck, cv, state = _prefill_paged(
+            last_logits, ck, cv, state, counts = _prefill_paged(
                 params, suffix, config, prefix_k, prefix_v)
         table: List[Any] = []
         if kv_cache is not None:
@@ -397,6 +433,8 @@ def _prefill_with_cache(params, config, kv_cache, prompt, empty_prefix,
                     event.update(event_extra)
                 kv_cache.record_event(event)
         live = np.asarray(last_logits[0, :config.vocab_size], np.float32)
+        if counts is not None:
+            counts = {k: int(v) for k, v in jax.device_get(counts).items()}
     first = int(np.argmax(live))
     m = float(live[first])
     score = -float(np.log(np.exp(live - m).sum()))  # m - logsumexp
@@ -405,7 +443,7 @@ def _prefill_with_cache(params, config, kv_cache, prompt, empty_prefix,
         parts.update(lookup_ms=(t1 - t0) * 1e3, commit_ms=commit_ms,
                      prefill_ms=(_now() - t1) * 1e3 - commit_ms)
     return (ck, cv, state, table, first, score, outcome, int(reused),
-            int(suffix.shape[1]))
+            int(suffix.shape[1]), counts)
 
 
 @functools.partial(jax.jit, static_argnums=(4, 5),
@@ -711,6 +749,9 @@ class ContinuousBatchingEngine:
         if self.stateful:
             self._refuse_for_state(prefix_cache, speculate_k, lora_pool)
             prefix_cache = False
+        elif self.latent_only:
+            self._refuse_for_latent(prefix_cache, speculate_k, lora_pool)
+            prefix_cache = False
         # paged KV prefix cache (models/kvcache.py); RAY_TPU_KV_* env
         # knobs supply defaults, constructor args win
         from ray_tpu.util import envknobs
@@ -772,6 +813,8 @@ class ContinuousBatchingEngine:
         # another replica (serve/disagg.py)
         self.prefill_calls = 0
         self.prefilled_tokens = 0
+        # totals of what the family's prefills counted (module docstring)
+        self.prefill_counters: Dict[str, int] = {}
         self.spliced_tokens = 0
         self.admitted = 0            # total slots admitted (both phases)
         self.prefill_admitted = 0
@@ -830,6 +873,36 @@ class ContinuousBatchingEngine:
         """Whether a slot owns state with no sequence axis (a recurrent
         family) beside, or instead of, rows of keys and values."""
         return self._state_bytes_per_slot > 0
+
+    @property
+    def latent_only(self) -> bool:
+        """Whether the cache is latent rows alone: sequence entries of
+        one array each ("k" and no "v"), and no state."""
+        return latent_only(self._cache)
+
+    @staticmethod
+    def _refuse_for_latent(prefix_cache, speculate_k, lora_pool) -> None:
+        """What stands on the paged pool, or on keys and values in
+        pairs, refused in words for a cache of latent rows alone (module
+        docstring). `prefix_cache=None` simply builds no pool."""
+        if prefix_cache:
+            raise ValueError(
+                LATENT_ONLY + "the paged pool sizes a block [heads, "
+                "head_dim] from a key tensor and commits keys and values "
+                "side by side, and has no block of one latent row "
+                "(prefix_cache=True)")
+        if speculate_k:
+            raise ValueError(
+                LATENT_ONLY + "the pool proposer drafts from the paged "
+                "pool's token chains, which this cache has none of, and "
+                "the family's decode has no [B, k+1] verify form "
+                f"(speculate_k={speculate_k})")
+        if lora_pool is not None:
+            raise ValueError(
+                LATENT_ONLY + "the adapter pool's per-tenant prefix "
+                "namespaces are the paged pool's, and its targets are "
+                "the attention projections of the families it knows "
+                "(lora_pool)")
 
     @staticmethod
     def _refuse_for_state(prefix_cache, speculate_k, lora_pool) -> None:
@@ -932,6 +1005,12 @@ class ContinuousBatchingEngine:
                 "this family's slots own recurrent state: an adoption "
                 "carries ck/cv rows only, and a prefill replica has no "
                 "way to hand over the state its prefill ended in "
+                "(adopt_prefill)")
+        if self.latent_only:
+            raise ValueError(
+                LATENT_ONLY + "an adoption carries ck and cv rows in "
+                "pairs, as the paged pool and the transfer between "
+                "replicas speak them, and there are no values to carry "
                 "(adopt_prefill)")
         if plen < 1:
             raise ValueError("prompt_len must be >= 1")
@@ -1121,6 +1200,8 @@ class ContinuousBatchingEngine:
             cancelled_by_reason=dict(self.cancelled_by_reason),
             lora=self.lora_pool is not None,
             stateful=self.stateful,
+            latent_only=self.latent_only,
+            prefill_counters=dict(self.prefill_counters),
             state_bytes_per_slot=self._state_bytes_per_slot,
             kv_bytes_per_token=self._kv_bytes_per_token,
             lookahead_ticks=self.lookahead_ticks,
@@ -1312,15 +1393,18 @@ class ContinuousBatchingEngine:
                 req.lora_slot, with_version=True)
             namespace = self.lora_pool.cache_namespace(req.adapter_id,
                                                        aver)
-        ck, cv, state, table, first, score, outcome, reused, suffix_len = \
-            _prefill_with_cache(self.params, self.config, self.kv_cache,
-                                req.prompt, self._empty_prefix,
-                                event_extra={"rid": req.rid},
-                                adapter=adapter,
-                                namespace=namespace, parts=entry)
+        (ck, cv, state, table, first, score, outcome, reused, suffix_len,
+         counts) = _prefill_with_cache(
+            self.params, self.config, self.kv_cache, req.prompt,
+            self._empty_prefix, event_extra={"rid": req.rid},
+            adapter=adapter, namespace=namespace, parts=entry)
         if entry is not None:
             entry["suffix_tokens"] = suffix_len
             entry["reused_tokens"] = reused
+            entry.update(counts or {})
+        for name, n in (counts or {}).items():
+            self.prefill_counters[name] = \
+                self.prefill_counters.get(name, 0) + n
         if self.kv_cache is not None:
             req.cache_outcome = outcome
             req.reused_tokens = reused

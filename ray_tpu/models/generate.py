@@ -24,7 +24,10 @@ from .llama import LlamaConfig, init_kv_cache, llama_forward_cached
 def _model_fns(config):
     """(forward_cached, init_cache, ragged_decode) for the config's
     model family — generation and the continuous-batching engine are
-    model-agnostic over this cache protocol."""
+    model-agnostic over this cache protocol. A `forward_cached` may
+    carry, as its attribute `with_counters`, a form of itself that hands
+    back a third value, a dict of small counters of the run (the engine's
+    admission record), as `ragged_decode` may hand one back itself."""
     if isinstance(config, LlamaConfig):
         from .llama import llama_decode
 
@@ -52,6 +55,15 @@ def _model_fns(config):
         # models/kimi_linear.py, and the engine's third kind of entry
         return (kimi_linear_forward_cached, kimi_linear_init_cache,
                 kimi_linear_decode)
+    from .deepseek_v2 import (DeepseekV2Config, deepseek_v2_decode,
+                              deepseek_v2_forward_cached,
+                              deepseek_v2_init_cache)
+
+    if isinstance(config, DeepseekV2Config):
+        # ONE latent row a token and nothing else: module docstring of
+        # models/deepseek_v2.py
+        return (deepseek_v2_forward_cached, deepseek_v2_init_cache,
+                deepseek_v2_decode)
     raise TypeError(f"no generation support for {type(config).__name__}")
 
 

@@ -386,6 +386,7 @@ class PrefillServer:
                  lora_rank_max: Optional[int] = None,
                  kvplane: Optional[bool] = None,
                  kvplane_arena_bytes: Optional[int] = None):
+        from ray_tpu.models.engine import LATENT_ONLY, latent_only
         from ray_tpu.models.generate import _model_fns
         from ray_tpu.models.kvcache import (PagedKVCache,
                                             kv_int8_default,
@@ -414,6 +415,13 @@ class PrefillServer:
         if kv_int8 is None:
             kv_int8 = kv_int8_default()
         self.kv_int8 = bool(kv_int8)
+        probe = _model_fns(config)[1](config, 1, max_len=1)
+        if latent_only(probe):
+            raise ValueError(
+                LATENT_ONLY + "a transfer carries ck and cv rows in "
+                "pairs and the prefill tier's pool commits them side by "
+                "side, so it cannot be served disaggregated "
+                "(engine.adopt_prefill refuses it too)")
         block_size, pool_blocks = resolve_pool_config(
             config, kv_block_size, kv_pool_blocks, int8=self.kv_int8)
         self.kv_cache: Optional[PagedKVCache] = (
@@ -447,7 +455,6 @@ class PrefillServer:
                 lambda tenant, old, _p=self.lora_pool:
                 self.kv_cache.invalidate(
                     namespace=_p.cache_namespace(tenant, old)))
-        probe = _model_fns(config)[1](config, 1, max_len=1)
         if any("k" not in blk for blk in probe):
             raise ValueError(
                 "this family's slots own recurrent state: a transfer "
@@ -549,7 +556,8 @@ class PrefillServer:
                 kvp_info["tier3"] = t3
         try:
             ck, cv, _state, table, first, score, outcome, reused, \
-                suffix_len = _prefill_with_cache(self.params, self.config,
+                suffix_len, _counts = _prefill_with_cache(
+                                    self.params, self.config,
                                     self.kv_cache, prompt,
                                     self._empty_prefix, adapter=adapter,
                                     namespace=namespace)

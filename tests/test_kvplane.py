@@ -169,7 +169,7 @@ def _filled_pool(model, prompt: np.ndarray, *, int8: bool,
 
     empty = jnp.zeros((CFG.num_layers, 0, CFG.num_kv_heads,
                        CFG.head_dim), jnp.float32)
-    _, ck, cv, _ = _prefill_paged(model, prompt[None], CFG, empty, empty)
+    _, ck, cv, _, _ = _prefill_paged(model, prompt[None], CFG, empty, empty)
     kv = PagedKVCache(CFG, block_size=BS, num_blocks=num_blocks,
                       int8=int8)
     arena = HostArena(max_bytes=arena_bytes, replica="unit")

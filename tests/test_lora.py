@@ -230,8 +230,8 @@ def test_kvcache_namespace_unit(tiny):
     cfg, params = tiny
     kv = PagedKVCache(cfg, block_size=4, num_blocks=16)
     toks = np.arange(1, 13, dtype=np.int32)
-    _, ck, cv, _ = _prefill_paged(params, toks[None, :], cfg,
-                                  kv._empty_k, kv._empty_k)
+    _, ck, cv, _, _ = _prefill_paged(params, toks[None, :], cfg,
+                                     kv._empty_k, kv._empty_k)
     for ns in ("a", "b", None):
         m = kv.lookup(toks, max_tokens=11, namespace=ns)
         assert m.outcome == "miss"

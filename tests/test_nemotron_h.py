@@ -226,7 +226,7 @@ def test_the_splice_writes_rows_of_one_and_the_whole_of_the_other(tiny):
     empty = jnp.zeros((len(slab), 0, cfg.num_kv_heads, cfg.head_dim),
                       cfg.dtype)
     prompt = jnp.asarray([_prompts()[0]], jnp.int32)
-    _, ck, cv, state = engine_mod._prefill_paged(params, prompt, cfg,
+    _, ck, cv, state, _ = engine_mod._prefill_paged(params, prompt, cfg,
                                                  empty, empty)
     assert ck.shape[0] == cfg.pattern.count("*")
     assert len(state) == cfg.pattern.count("M")

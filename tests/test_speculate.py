@@ -223,8 +223,8 @@ def test_int8_pool_roundtrip_within_rtol(model):
     prompt = np.asarray(_prompts(seed=19, n=1)[0] * 2, np.int32)[None]
     empty = jnp.zeros((CFG.num_layers, 0, CFG.num_kv_heads,
                        CFG.head_dim), jnp.float32)
-    ref_logits, ck, cv, _ = _prefill_paged(model, prompt, CFG, empty,
-                                           empty)
+    ref_logits, ck, cv, _, _ = _prefill_paged(model, prompt, CFG, empty,
+                                              empty)
     kv = PagedKVCache(CFG, block_size=BS, num_blocks=32, int8=True)
     m = kv.lookup(prompt[0], max_tokens=prompt.shape[1] - 1)
     table = kv.commit(prompt[0], ck, cv, m)
@@ -238,8 +238,8 @@ def test_int8_pool_roundtrip_within_rtol(model):
     assert np.abs(got_k - ref_k).max() / denom < 0.05
     # logit-level: a suffix prefill over the dequantized prefix stays
     # within the rtol contract of the exact-prefix prefill
-    q_logits, _, _, _ = _prefill_paged(model, prompt[:, m2.tokens:], CFG,
-                                       gk, gv)
+    q_logits, _, _, _, _ = _prefill_paged(model, prompt[:, m2.tokens:],
+                                          CFG, gk, gv)
     ref = np.asarray(ref_logits[0, :CFG.vocab_size], np.float32)
     got = np.asarray(q_logits[0, :CFG.vocab_size], np.float32)
     assert np.abs(got - ref).max() / (np.abs(ref).max() + 1e-9) < 0.05
