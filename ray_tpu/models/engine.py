@@ -145,10 +145,13 @@ the pass after keeps one queued again. ``kv_stats()`` counts
 The loop keeps a clock of its own. While the per-request flight
 recorder is on (``RAY_TPU_REQTRACE``, observability/requests.py), every
 pass of ``_loop`` that read a tick back or admitted something leaves ONE
-record in the recorder's process-local store
+record (a speculating pass that reads the tick in flight before it
+drafts leaves one there and one at its end: a record holds one landing)
+in the recorder's process-local store
 (``reqtrace.store().loop_records()``; a bounded ring, nothing is pushed
 to the conductor), all from ``time.perf_counter()`` on this thread:
-``engine_id``; ``ts`` (``time.time()`` at the top of the pass);
+``engine_id``; ``pass`` (the engine's count of recorded passes: this
+record's number); ``ts`` (``time.time()`` at the top of the pass);
 ``max_batch``; ``pending`` (requests waiting in ``_pending`` at the
 top); ``admit_ms`` (inside ``_admit``; 0 where nothing was admitted or
 adopted); ``admissions``,
@@ -196,12 +199,71 @@ carries three stamps of the same clock (``submit()`` returns, ``_admit``
 pops it, ``_emit`` puts its first token) and ``TokenStream`` exposes
 their differences as ``queue_ms`` and ``prefill_ms``, which the router
 hands to the flight recorder as the two parts of
-``decode_first_token``. The same boundaries are
-``util.profiling.annotate`` spans on this thread (``engine.admit``,
+``decode_first_token``. The same boundaries are profiler spans
+(``jax.profiler.TraceAnnotation``) on this thread (``engine.admit``,
 ``engine.prefill``, ``engine.pool_commit``, ``engine.splice``,
 ``engine.tick_dispatch``, ``engine.tick_readback``, ``engine.emit``),
-so a ``jax.profiler`` session shows them beside the device's programs.
+so a ``jax.profiler`` session shows them beside the device's programs;
+each carries ``pass``, the number its pass's record takes in the ring
+(the engine's count of recorded passes), and ``rid`` where it serves one
+request: the spans are the ring's picture in the profiler, the ring is
+what is read.
 With the recorder off the loop reads no clock and builds no record.
+
+The loop keeps a ledger of the gaps it makes (`_GapLedger`, PR 35). A
+tick's tokens go out when its read-back returns (a LANDING); the time
+from one landing to the next is the gap every stream that took a token
+from both ticks sees between them. The record of a pass that landed a
+tick carries what that gap held, all from the stamps above and two more
+(around the read of a prefill's logits): ``gap_ms`` (this landing less
+the one before; ABSENT where no request took a token from both ticks:
+the engine stood idle, or every stream is new); ``gap_streams`` (the
+requests that took a token from both: a slot that joined, one that
+finished and a row discarded count in neither, so a reader weighs a gap
+once a stream that felt it, as a client's percentile does; a request's
+own first gap, from its prefill's token to its first tick's, is in no
+count); ``gap_admissions`` (the admissions, adoptions among them, whose
+programs were launched between the two landings: an accumulator emptied
+at each landing, so an admission is counted in the gap that ENDS with
+the first landing after its launch, wherever the loop puts ``_admit``;
+their prompts' lengths are in ``admissions``);
+``gap_blocked_ms`` (of the gap, the time this thread was blocked on a
+chip at work: the tick's read-back, a prefill's logits); and
+``gap_empty_ms`` with ``gap_empty_by``, the time the chip was STARVED
+and the host's step that ran meanwhile. The chip runs its programs in
+the order they were launched, and the ledger counts the launches at the
+sites this thread has, the implicit ones too (the slice of a prefill's
+logits is a program; the pool's gather and its commit's; `_splice_slot`,
+`_set_rows`; reading a prefill's counters launches nothing, they are
+there once its logits are). When a blocking read-back returns and the
+program it read is the newest launched, the chip is empty from that
+return, to within a launch's latency, and it does none of the streams'
+or a prompt's work until the next ``_tick`` or ``_prefill_paged`` is
+launched: the stretch ends at that launch's return, and what the chip
+runs between (`_splice_slot`, `_set_rows`: well under a millisecond
+together) counts as starved, it IS the admission's chain. The steps,
+under the span's name where there is one: ``emit`` (the walk over a
+tick read with nothing queued behind it), ``lookup`` (a second
+admission of a pass, or one after a hold), ``prefill`` (the launch of
+such an admission's ``_prefill_paged``), ``first_token`` (argmax and
+log-sum over the vocabulary, the counters' read, the first ``_emit``),
+``splice``, ``tick_dispatch`` (with its ``_set_rows``) and
+``bookkeeping`` for what has no span. The parts need not sum to the gap
+(the rest is the host's work under a busy chip);
+``gap_blocked_ms + gap_empty_ms <= gap_ms`` holds. A speculative verify
+tick is one landing like any other. An entry of ``admissions`` carries
+``prefills_waited``: ``prefill_admitted`` at the pop in ``_admit`` less
+its value at ``submit()``, the other requests' prefills that ran while
+this one waited (``TokenStream.prefills_waited``, which the router puts
+on the request's ``decode_first_token`` phase beside the two parts;
+counted with the recorder off too). ``kv_stats()["gaps"]`` holds the
+totals an operator reads without a benchmark, over the gaps that have a
+``gap_ms``: ``stream_gaps`` (the sum of ``gap_streams``),
+``with_admission`` (those of them whose gap held an admission),
+``chip_blocked_ms`` and ``chip_empty_ms`` by step. Their rates over the
+wall's time say what bounds the decode: blocked near 1, the chip; empty
+above a few hundredths, the host's chain at admissions; the rest is the
+host's work under a busy chip. All zero while the recorder is off.
 
 Per-request token queues make it the natural producer for Serve's
 streaming path; `ContinuousBatchingEngine` is thread-safe for
@@ -219,7 +281,7 @@ import threading
 import time
 from collections import OrderedDict
 from typing import (Any, Callable, Dict, Iterator, List, NamedTuple,
-                    Optional)
+                    Optional, Tuple)
 
 import jax
 import jax.numpy as jnp
@@ -227,7 +289,7 @@ import numpy as np
 
 from ray_tpu.observability import requests as reqtrace
 from ray_tpu.ops import dispatch
-from ray_tpu.util.profiling import annotate, name_thread
+from ray_tpu.util.profiling import name_thread
 
 from .generate import _model_fns, merge_lora_params
 from .kvcache import PagedKVCache, resolve_pool_config
@@ -242,6 +304,153 @@ _now = time.perf_counter
 def _clock(rec: Optional[dict]) -> float:
     """A reading for the loop record `rec`; none is taken without one."""
     return _now() if rec is not None else 0.0
+
+
+class _GapLedger:
+    """What the loop keeps between two landings of a tick (module
+    docstring, "a ledger of the gaps"). One an engine, touched by its
+    loop's thread alone; `kv_stats()` reads the totals from any thread.
+    Counting launches reads no clock, and nothing else here runs until
+    `read` has been called, which the loop does with the recorder on."""
+
+    def __init__(self) -> None:
+        self.passes = 0      # passes recorded in the ring so far
+        self.launches = 0    # programs this thread has sent to the chip
+        self.done = 0        # the newest a read-back has shown finished
+        # since when the chip has been starved, and the host's step now
+        self.empty_from: Optional[float] = None
+        self.step = "bookkeeping"
+        # the last landing, and the number of landings so far (a
+        # request keeps the number of the last it took a token from)
+        self.t_land: Optional[float] = None
+        self.landing = 0
+        # emptied at each landing
+        self.admissions = 0
+        self.blocked_s = 0.0
+        self.empty_s: Dict[str, float] = {}
+        # nothing read or admitted since `idle` emptied them
+        self.clean = True
+        # kv_stats()["gaps"]
+        self.stream_gaps = 0
+        self.with_admission = 0
+        self.chip_blocked_ms = 0.0
+        self.chip_empty_ms: Dict[str, float] = {}
+
+    def _charge(self, t: float) -> None:
+        self.empty_s[self.step] = (self.empty_s.get(self.step, 0.0)
+                                   + t - self.empty_from)
+        self.empty_from = t
+
+    def launched(self, n: int = 1, work: bool = False) -> None:
+        """`n` programs went to the chip. `work`: the last of them is a
+        `_tick` or a `_prefill_paged`, whose launch's return ends a
+        starved stretch."""
+        self.launches += n
+        if work and self.empty_from is not None:
+            self._charge(_now())
+            self.empty_from = None
+
+    def mark(self, step: str, t: Optional[float] = None) -> None:
+        """The host's step from here on (`t`: a reading of now, where
+        the caller has one). It matters while the chip is starved
+        alone (`read` names the step a starved stretch begins in), so
+        the two callers a steady pass goes through ask first."""
+        if self.empty_from is not None:
+            self._charge(_now() if t is None else t)
+        self.step = step
+
+    def read(self, t0: float, t1: float, seq: int, step: str) -> None:
+        """A blocking read-back of what launch number `seq` made took
+        from `t0` to `t1`, and the host goes on to `step`. It shows
+        every launch up to `seq` finished: where that is the newest,
+        the chip is empty from `t1`. A read with the chip already empty
+        is not time blocked on its work."""
+        self.clean = False
+        if self.empty_from is not None:
+            self._charge(t1)
+        else:
+            self.blocked_s += t1 - t0
+            if seq > self.done:
+                self.done = seq
+            if self.done == self.launches:
+                self.empty_from = t1
+        self.step = step
+
+    def admitted(self) -> None:
+        self.clean = False
+        self.admissions += 1
+
+    def land(self, t1: float, streams: int, it: Dict[str, Any]) -> None:
+        """Close the gap that ends with the landing at `t1`, which
+        `streams` requests felt (they took a token from landing number
+        `landing` too: `_land`'s walk), into the pass's record. Starved
+        time is charged at the next `mark` or launch, and the walk over
+        the tick's slots makes neither: what the accumulators hold here
+        lies before `t1`. A steady pass finds them empty."""
+        by: Dict[str, float] = {}
+        empty_ms = 0.0
+        if self.empty_s:
+            by = {k: v * 1e3 for k, v in self.empty_s.items()}
+            empty_ms = sum(by.values())
+            self.empty_s = {}
+        it["gap_streams"] = streams
+        it["gap_admissions"] = self.admissions
+        it["gap_blocked_ms"] = self.blocked_s * 1e3
+        it["gap_empty_ms"] = empty_ms
+        it["gap_empty_by"] = by
+        if streams and self.t_land is not None:
+            it["gap_ms"] = (t1 - self.t_land) * 1e3
+            self.stream_gaps += streams
+            if self.admissions:
+                self.with_admission += streams
+            self.chip_blocked_ms += it["gap_blocked_ms"]
+            for k, v in by.items():
+                self.chip_empty_ms[k] = self.chip_empty_ms.get(k, 0.0) + v
+        self.t_land = t1
+        self.landing += 1
+        self.admissions = 0
+        self.blocked_s = 0.0
+
+    def idle(self) -> None:
+        """Nothing decodes (or the recorder is off): the next landing
+        ends no gap, and an idle chip is not a starved one."""
+        if self.clean:
+            return
+        self.clean = True
+        self.empty_from = self.t_land = None
+        self.admissions = 0
+        self.blocked_s = 0.0
+        self.empty_s = {}
+
+    def totals(self) -> Dict[str, Any]:
+        return {"stream_gaps": self.stream_gaps,
+                "with_admission": self.with_admission,
+                "chip_blocked_ms": self.chip_blocked_ms,
+                "chip_empty_ms": dict(self.chip_empty_ms)}
+
+
+class _NoLedger(_GapLedger):
+    """The prefill tier's, which has no loop and no landing: it counts
+    nothing, and its spans take no `pass`."""
+
+    def launched(self, n: int = 1, work: bool = False) -> None:
+        pass
+
+    def mark(self, step: str, t: Optional[float] = None) -> None:
+        pass
+
+
+_NO_LEDGER = _NoLedger()
+
+
+def _span(name: str, gaps: _GapLedger, **stats: Any):
+    """An `engine.*` span of the pass under way, the one way this
+    module opens one: `pass` is the number its record takes in the ring.
+    (What `util.profiling.annotate` is, less a frame and an import a
+    span: a steady pass opens four, and its Python runs cold.)"""
+    if gaps is not _NO_LEDGER:
+        stats["pass"] = gaps.passes
+    return jax.profiler.TraceAnnotation(name, **stats)
 
 
 def default_speculate_k() -> int:
@@ -369,7 +578,7 @@ def _prefill_paged_lora(params, suffix, config, prefix_k, prefix_v,
 
 def _prefill_with_cache(params, config, kv_cache, prompt, empty_prefix,
                         event_extra=None, adapter=None, namespace=None,
-                        parts=None):
+                        parts=None, gaps=_NO_LEDGER):
     """The prefill-behind-the-prefix-cache sequence shared by the
     colocated engine's `_admit_one` and the disagg `PrefillServer`:
     lookup → gather → `_prefill_paged` on the suffix → commit +
@@ -387,42 +596,51 @@ def _prefill_with_cache(params, config, kv_cache, prompt, empty_prefix,
 
     `parts`: a dict the engine's loop record wants filled with this
     admission's self times and commit counts (module docstring); None
-    reads no clock. The profiler spans are there either way."""
+    reads no clock. The profiler spans are there either way.
+
+    `gaps`: the engine's `_GapLedger`, which counts the programs
+    launched here, takes the read of the logits (with `parts`) and gives
+    the spans their `pass`; the prefill tier has none."""
     plen = prompt.shape[1]
     prompt_np = prompt[0]
     rid = (event_extra or {}).get("rid", -1)
     outcome, reused = "miss", 0
     t0 = _clock(parts)
+    gaps.mark("lookup", t0)
     if kv_cache is not None:
         match = kv_cache.lookup(prompt_np, max_tokens=plen - 1,
                                 namespace=namespace)
         outcome, reused = match.outcome, match.tokens
         prefix_k, prefix_v = kv_cache.gather(match)
+        gaps.launched(int(match.tokens > 0))
     else:
         match = None
         prefix_k = prefix_v = empty_prefix
     cached = int(prefix_k.shape[1])
     suffix = prompt[:, cached:]
     t1 = t2 = t3 = _clock(parts)
+    gaps.mark("prefill", t1)
     # engine.prefill runs to the read-back of the logits, the commit
     # nested in it: the device works on the prefill while the host
     # plans the commit and queues its writes behind it, so the span's
     # self time is the prefill's
-    with annotate("engine.prefill", rid=rid, prompt_tokens=plen):
+    with _span("engine.prefill", gaps, rid=rid, prompt_tokens=plen):
         if adapter is not None:
             last_logits, ck, cv, state, counts = _prefill_paged_lora(
                 params, suffix, config, prefix_k, prefix_v, adapter)
         else:
             last_logits, ck, cv, state, counts = _prefill_paged(
                 params, suffix, config, prefix_k, prefix_v)
+        gaps.launched(work=True)
         table: List[Any] = []
         if kv_cache is not None:
             kv_cache.note_prefilled(suffix.shape[1])
             if parts is not None:
                 t2 = _now()
-            with annotate("engine.pool_commit", rid=rid):
+            with _span("engine.pool_commit", gaps, rid=rid):
                 table = kv_cache.commit(prompt_np, ck, cv, match,
                                         namespace=namespace)
+            gaps.launched(kv_cache.last_commit[0])
             if parts is not None:
                 t3 = _now()
                 parts["commit_dispatches"], parts["commit_blocks"] = \
@@ -433,7 +651,14 @@ def _prefill_with_cache(params, config, kv_cache, prompt, empty_prefix,
                 if event_extra:
                     event.update(event_extra)
                 kv_cache.record_event(event)
-        live = np.asarray(last_logits[0, :config.vocab_size], np.float32)
+        # the slice is a program of its own, the newest launched: the
+        # chip is empty once it is read
+        live = last_logits[0, :config.vocab_size]
+        gaps.launched()
+        t4 = _clock(parts)
+        live = np.asarray(live, np.float32)
+        if parts is not None:
+            gaps.read(t4, _now(), gaps.launches, "first_token")
         if counts is not None:
             counts = {k: int(v) for k, v in jax.device_get(counts).items()}
     first = int(np.argmax(live))
@@ -560,7 +785,8 @@ class _Flight(NamedTuple):
     device outputs, and what the host knew at the launch. `reqs[slot]`
     is the request the tick decodes for at that slot (None: a dead slot,
     or one whose budget ends with the tick ahead of this one); `live`
-    and `live_rows` are their count and the sum of their positions."""
+    and `live_rows` are their count and the sum of their positions;
+    `seq` is the ledger's count of launches with this tick the newest."""
 
     nxt: Any
     lp: Any
@@ -569,6 +795,7 @@ class _Flight(NamedTuple):
     live: int
     live_rows: int
     drafts: Optional[Dict[int, List[int]]]
+    seq: int
 
 
 class _Adoption:
@@ -645,6 +872,14 @@ class _Request:
         self.t_submit: Optional[float] = None
         self.t_admit: Optional[float] = None
         self.t_first: Optional[float] = None
+        # the engine's `prefill_admitted` when the request was handed
+        # in, and at the pop in _admit how far it had moved on: the
+        # other requests' prefills this one waited behind
+        self.prefills_before = 0
+        self.prefills_waited: Optional[int] = None
+        # the ledger's number of the last landing this request took a
+        # token from (recorder on)
+        self.landed = -1
 
 
 class TokenStream:
@@ -654,7 +889,8 @@ class TokenStream:
     Serve's streaming replica reads it to label the TTFT histogram.
     ``queue_ms`` / ``prefill_ms`` split the first token's wait on the
     engine's own clock, set before the first token arrives too (None
-    with the flight recorder off)."""
+    with the flight recorder off); ``prefills_waited`` counts the other
+    requests' prefills that ran in ``queue_ms``."""
 
     def __init__(self, req: _Request, timeout_s: float):
         self._req = req
@@ -694,6 +930,13 @@ class TokenStream:
         if r.t_admit is None or r.t_first is None:
             return None
         return (r.t_first - r.t_admit) * 1e3
+
+    @property
+    def prefills_waited(self) -> Optional[int]:
+        """Prefills of other requests the engine ran between this
+        request's hand-in and its own admission (None until then): each
+        is a stair in its time to first token."""
+        return self._req.prefills_waited
 
     @property
     def scores(self) -> List[float]:
@@ -840,6 +1083,7 @@ class ContinuousBatchingEngine:
         self._dirty = np.zeros(max_batch, bool)
         self.lookahead_ticks = 0      # launched behind a tick in flight
         self.lookahead_discarded = 0  # their rows a finished slot left
+        self._gaps = _GapLedger()     # module docstring
         self._slot_req: List[Optional[_Request]] = [None] * max_batch
         self._free = list(range(max_batch))
         # multi-tenant LoRA (serve/lora.py AdapterPool, duck-typed so
@@ -954,6 +1198,7 @@ class ContinuousBatchingEngine:
         req.ctx_has_prompt = True
         req.adapter_id = adapter_id
         req.lora_slot = lora_slot
+        req.prefills_before = self.prefill_admitted
         if reqtrace.enabled():
             req.t_submit = _now()
         self._pending.put(req)
@@ -1063,6 +1308,7 @@ class ContinuousBatchingEngine:
         req.reused_tokens = int(reused_tokens)
         req.adapter_id = adapter_id
         req.lora_slot = lora_slot
+        req.prefills_before = self.prefill_admitted
         if reqtrace.enabled():
             req.t_submit = _now()
         self._pending_adopt.put(_Adoption(req, plen, ck, cv,
@@ -1207,6 +1453,11 @@ class ContinuousBatchingEngine:
             kv_bytes_per_token=self._kv_bytes_per_token,
             lookahead_ticks=self.lookahead_ticks,
             lookahead_discarded=self.lookahead_discarded,
+            # stream-gaps counted, those that held an admission, the
+            # milliseconds blocked on the chip and those it was starved
+            # for by the host's step (module docstring: what the
+            # benchmark's gap readers read)
+            gaps=self._gaps.totals(),
             # which traced shapes of the expert layers' grouped product
             # took the streamed kernel (the process's, not this engine's)
             grouped_product=dispatch.kernel_choices("grouped_product"),
@@ -1320,28 +1571,34 @@ class ContinuousBatchingEngine:
         return (head is not None
                 and 2 * head.prompt.shape[1] > self.config.max_seq_len)
 
-    def _splice(self, ck, cv, slot: int, plen: int,
+    def _splice(self, req: _Request, ck, cv, slot: int, plen: int,
                 entry: Optional[Dict[str, Any]], state=()) -> None:
-        """Both admission paths' write into the decode slab."""
+        """Both admission paths' write into the decode slab; the
+        request's first `_emit` follows it."""
         t0 = _clock(entry)
-        with annotate("engine.splice"):
+        self._gaps.mark("splice", t0)
+        with _span("engine.splice", self._gaps, rid=req.rid):
             self._cache = _splice_slot(self._cache, ck, cv,
                                        np.int32(slot), self.config, plen,
                                        tuple(state))
+        self._gaps.launched()
+        self._gaps.mark("first_token")
         if entry is not None:
+            self._gaps.admitted()
             entry["splice_ms"] = (_now() - t0) * 1e3
             if self.stateful:
                 entry["state_bytes"] = self._state_bytes_per_slot
         self.spliced_tokens += plen
 
-    @staticmethod
-    def _admission(it: Optional[Dict[str, Any]], req: _Request,
+    def _admission(self, it: Optional[Dict[str, Any]], req: _Request,
                    plen: int) -> Optional[Dict[str, Any]]:
         """Open `req`'s entry in the pass's record and stamp the pop."""
+        req.prefills_waited = self.prefill_admitted - req.prefills_before
         if it is None:
             return None
         req.t_admit = _now()
         entry = {"rid": req.rid, "prompt_tokens": plen,
+                 "prefills_waited": req.prefills_waited,
                  "suffix_tokens": 0, "reused_tokens": 0,
                  "lookup_ms": 0.0, "prefill_ms": 0.0, "commit_ms": 0.0,
                  "commit_dispatches": 0, "commit_blocks": 0,
@@ -1363,7 +1620,7 @@ class ContinuousBatchingEngine:
             entry["reused_tokens"] = req.reused_tokens
         with self._lock:
             slot = self._free.pop()
-        self._splice(adoption.ck, adoption.cv, slot, plen, entry)
+        self._splice(req, adoption.ck, adoption.cv, slot, plen, entry)
         self.admitted += 1
         self.adopted += 1
         req.slot = slot
@@ -1373,6 +1630,7 @@ class ContinuousBatchingEngine:
         self._pos[slot] = plen
         self._dirty[slot] = True
         self._emit(req, adoption.first_token, adoption.score)
+        self._gaps.mark("bookkeeping")
         return True
 
     def _admit_one(self, req: _Request,
@@ -1401,7 +1659,8 @@ class ContinuousBatchingEngine:
          counts) = _prefill_with_cache(
             self.params, self.config, self.kv_cache, req.prompt,
             self._empty_prefix, event_extra={"rid": req.rid},
-            adapter=adapter, namespace=namespace, parts=entry)
+            adapter=adapter, namespace=namespace, parts=entry,
+            gaps=self._gaps)
         if entry is not None:
             entry["suffix_tokens"] = suffix_len
             entry["reused_tokens"] = reused
@@ -1415,7 +1674,7 @@ class ContinuousBatchingEngine:
             req.block_table = table
         self.prefill_calls += 1
         self.prefilled_tokens += suffix_len
-        self._splice(ck, cv, slot, plen, entry, state)
+        self._splice(req, ck, cv, slot, plen, entry, state)
         self.admitted += 1
         self.prefill_admitted += 1
         req.slot = slot
@@ -1425,6 +1684,7 @@ class ContinuousBatchingEngine:
         self._pos[slot] = plen
         self._dirty[slot] = True
         self._emit(req, first, score)
+        self._gaps.mark("bookkeeping")
         return True
 
     def _finish(self, req: _Request) -> None:
@@ -1580,8 +1840,11 @@ class ContinuousBatchingEngine:
         live = sum(r is not None for r in reqs)
         if not live:
             return None
+        gaps = self._gaps
         t0 = _clock(it)
-        with annotate("engine.tick_dispatch", live=live):
+        if gaps.empty_from is not None:
+            gaps.mark("tick_dispatch", t0)
+        with _span("engine.tick_dispatch", gaps, live=live):
             if drafts:
                 tok = jnp.asarray(self._spec_tokens(drafts))
                 pos, mask = jnp.asarray(self._pos), None
@@ -1594,6 +1857,7 @@ class ContinuousBatchingEngine:
                     fresh[3] = [r is not None for r in self._slot_req]
                     self._dev = _set_rows(*self._dev, fresh)
                     self._dirty[:] = False
+                    gaps.launched()
                 tok, pos, mask = self._dev
             counts = None
             if (self.lora_pool is not None
@@ -1606,6 +1870,7 @@ class ContinuousBatchingEngine:
             else:
                 cache, nxt, lp, counts, pos_next = _tick(
                     self.params, self.config, self._cache, tok, pos, mask)
+            gaps.launched(work=True)
             self._cache = cache
             if not drafts:
                 self._dev = (nxt, pos_next, mask)
@@ -1613,7 +1878,8 @@ class ContinuousBatchingEngine:
             self.lookahead_ticks += 1
         if it is not None:
             it["dispatch_ms"] += (_now() - t0) * 1e3
-        return _Flight(nxt, lp, counts, reqs, live, rows, drafts)
+        return _Flight(nxt, lp, counts, reqs, live, rows, drafts,
+                       gaps.launches)
 
     def _land(self, flight: _Flight, it: Optional[Dict[str, Any]],
               inflight: int = 0) -> None:
@@ -1623,9 +1889,11 @@ class ContinuousBatchingEngine:
         request finished or was cancelled after the launch is DISCARDED:
         never emitted, never scored, never in `req.ctx`. The pass's
         record takes the tick's `live`, `live_rows` and counters, so
-        that they describe one tick, the one read here."""
+        that they describe one tick, the one read here, and the gap
+        this landing ends (`_GapLedger.land`)."""
+        gaps = self._gaps
         t0 = _clock(it)
-        with annotate("engine.tick_readback"):
+        with _span("engine.tick_readback", gaps):
             nxt_np = np.asarray(flight.nxt)
             lp_np = np.asarray(flight.lp)
             if it is not None and flight.counts:
@@ -1633,9 +1901,19 @@ class ContinuousBatchingEngine:
                 # without another wait for the device
                 it.update({k: int(v) for k, v in flight.counts.items()})
         t1 = _clock(it)
+        # the ledger's number of this landing (0: recorder off), and the
+        # requests that take a token from it and took one from the last
+        landing = streams = 0
+        if it is not None:
+            gaps.read(t0, t1, flight.seq, "emit")
+            landing = gaps.landing + 1
         discarded = 0
-        with annotate("engine.emit"):
+        with _span("engine.emit", gaps):
             if flight.drafts:
+                for req in self._slot_req if landing else ():
+                    if req is not None:
+                        streams += req.landed == landing - 1
+                        req.landed = landing
                 self._spec_emit(flight.drafts, nxt_np, lp_np)
             else:
                 for slot, req in enumerate(flight.reqs):
@@ -1644,14 +1922,21 @@ class ContinuousBatchingEngine:
                     if req.finished:
                         discarded += 1
                         continue
+                    if landing:
+                        streams += req.landed == landing - 1
+                        req.landed = landing
                     self._pos[slot] += 1
                     tok = int(nxt_np[slot])
                     self._tokens[slot] = tok
                     self._emit(req, tok, float(lp_np[slot]))
         self.lookahead_discarded += discarded
         if it is not None:
+            gaps.land(t1, streams, it)
+            t2 = _now()
+            if gaps.empty_from is not None:
+                gaps.mark("bookkeeping", t2)
             it["readback_ms"] += (t1 - t0) * 1e3
-            it["emit_ms"] += (_now() - t1) * 1e3
+            it["emit_ms"] += (t2 - t1) * 1e3
             it["discarded"] += discarded
             it.update(live=flight.live, live_rows=flight.live_rows,
                       inflight=inflight)
@@ -1727,17 +2012,7 @@ class ContinuousBatchingEngine:
         while not self._stopped.is_set():
             # the pass's record (module docstring); None, and no clock
             # read anywhere below, while the flight recorder is off
-            it: Optional[Dict[str, Any]] = None
-            if reqtrace.enabled():
-                t_top = _now()
-                it = {"engine_id": self.engine_id, "ts": time.time(),
-                      "live": 0, "live_rows": 0,
-                      "max_batch": self.max_batch,
-                      "pending": self._pending.qsize(),
-                      "admit_ms": 0.0, "admissions": [],
-                      "dispatch_ms": 0.0, "readback_ms": 0.0,
-                      "emit_ms": 0.0, "total_ms": 0.0,
-                      "inflight": 0, "discarded": 0}
+            it, t_top = self._open_record()
             busy = flight is not None or len(self._free) < self.max_batch
             # a swap holds from the next LAUNCH: the tick in flight
             # finishes on the weights it was launched with
@@ -1754,7 +2029,7 @@ class ContinuousBatchingEngine:
                 flight = None
             prefills = self.prefill_admitted
             t_admit = _clock(it)
-            with annotate("engine.admit"):
+            with _span("engine.admit", self._gaps):
                 self._admit(it)
             if it is not None and it["admissions"]:
                 it["admit_ms"] = (_now() - t_admit) * 1e3
@@ -1766,6 +2041,11 @@ class ContinuousBatchingEngine:
                     # in flight is read first
                     self._land(flight, it)
                     flight = None
+                    if it is not None:
+                        # one landing a record: the tick launched below
+                        # lands in the next
+                        self._record_pass(it, t_top)
+                        it, t_top = self._open_record()
                 drafts = self._collect_drafts()
             ahead = None
             if drafts:
@@ -1782,12 +2062,29 @@ class ContinuousBatchingEngine:
                 flight = ahead
             elif not (held or busy
                       or (it is not None and it["admissions"])):
+                self._gaps.idle()
                 self._stopped.wait(self.idle_sleep_s)
                 continue
             if it is not None:
                 self._record_pass(it, t_top)
 
-    @staticmethod
-    def _record_pass(it: Dict[str, Any], t_top: float) -> None:
+    def _open_record(self) -> Tuple[Optional[Dict[str, Any]], float]:
+        """A pass's record and the clock at its top; (None, 0.0) while
+        the flight recorder is off."""
+        if not reqtrace.enabled():
+            self._gaps.idle()
+            return None, 0.0
+        return {"engine_id": self.engine_id, "ts": time.time(),
+                "pass": self._gaps.passes,
+                "live": 0, "live_rows": 0,
+                "max_batch": self.max_batch,
+                "pending": self._pending.qsize(),
+                "admit_ms": 0.0, "admissions": [],
+                "dispatch_ms": 0.0, "readback_ms": 0.0,
+                "emit_ms": 0.0, "total_ms": 0.0,
+                "inflight": 0, "discarded": 0}, _now()
+
+    def _record_pass(self, it: Dict[str, Any], t_top: float) -> None:
         it["total_ms"] = (_now() - t_top) * 1e3
+        self._gaps.passes += 1
         reqtrace.store().record_loop(it)

@@ -118,6 +118,14 @@ def _first_token_parts(src: Any) -> Dict[str, Optional[float]]:
             "engine_queue": get("queue_ms")}
 
 
+def _first_token_waited(src: Any) -> Dict[str, int]:
+    """The other requests' prefills the engine ran in ``engine_queue``,
+    a count and so an attribute of the phase, not a part of it."""
+    waited = src.get("prefills_waited") if isinstance(src, dict) \
+        else getattr(src, "prefills_waited", None)
+    return {} if waited is None else {"prefills_waited": int(waited)}
+
+
 def _is_pool_exhausted(e: BaseException) -> bool:
     """An adapter-pool-exhausted failure (serve/lora.py
     LoraPoolExhausted) — matched by name because the exception may
@@ -1138,9 +1146,10 @@ class DecodeServer:
             out = {"tokens": toks, "done": done}
         if toks:
             # the engine's split of the first token's wait, for the
-            # router's decode_first_token phase (two floats a pull)
+            # router's decode_first_token phase (three numbers a pull)
             out["queue_ms"] = entry[0].queue_ms
             out["prefill_ms"] = entry[0].prefill_ms
+            out["prefills_waited"] = entry[0].prefills_waited
         return out
 
     def cancel_decode(self, hid: str,
@@ -2387,7 +2396,8 @@ class DisaggRouter:
                             tr.add_phase(
                                 "decode_first_token",
                                 (t_first_tok - t_dec) * 1e3,
-                                parts=_first_token_parts(stream))
+                                parts=_first_token_parts(stream),
+                                **_first_token_waited(stream))
                     n_attempt_toks += 1
                     if not first_emitted:
                         first_emitted = True
@@ -2645,7 +2655,8 @@ class DisaggRouter:
                                     "decode_first_token",
                                     (t_first_tok - t_dec) * 1e3,
                                     replica=rep.rid,
-                                    parts=_first_token_parts(out))
+                                    parts=_first_token_parts(out),
+                                    **_first_token_waited(out))
                         n_attempt_toks += len(toks)
                         history.extend(int(t) for t in toks)
                         if pslot is not None:

@@ -4,11 +4,14 @@ into the engine's queue and the request's own prefill, the same
 boundaries as `engine.*` spans in a `jax.profiler` trace, and nothing
 of it while `RAY_TPU_REQTRACE=0`. And the loop's one tick of lookahead
 (PR 32): every stream is what decoding alone gives, token for token and
-score for score, whatever the host learns a tick late."""
+score for score, whatever the host learns a tick late. And the ledger of
+the gaps between landings (PR 35): what each gap held, when the chip was
+starved and by which step, and the prefills a request waited behind."""
 from __future__ import annotations
 
 import dataclasses
 import glob
+import itertools
 import os
 
 import jax
@@ -27,13 +30,20 @@ from ray_tpu.util import envknobs, profiling
 CFG = dataclasses.replace(LlamaConfig.tiny(), dtype=jnp.float32)
 BS = 4
 PROMPT = list(range(1, 20))  # 19 tokens: four blocks of 4 and a tail of 3
-FIELDS = {"engine_id", "ts", "live", "live_rows", "max_batch", "pending",
-          "admit_ms", "admissions", "dispatch_ms", "readback_ms",
-          "emit_ms", "total_ms", "inflight", "discarded"}
-ADMISSION_FIELDS = {"rid", "prompt_tokens", "suffix_tokens",
-                    "reused_tokens", "lookup_ms", "prefill_ms",
-                    "commit_ms", "commit_dispatches", "commit_blocks",
-                    "splice_ms"}
+# every record; one of a pass that landed a tick has the gap's too, and
+# `gap_ms` where a stream felt the gap
+FIELDS = {"engine_id", "pass", "ts", "live", "live_rows", "max_batch",
+          "pending", "admit_ms", "admissions", "dispatch_ms",
+          "readback_ms", "emit_ms", "total_ms", "inflight", "discarded"}
+GAP_FIELDS = {"gap_streams", "gap_admissions", "gap_blocked_ms",
+              "gap_empty_ms", "gap_empty_by"}
+LANDED = FIELDS | GAP_FIELDS
+STEPS = {"bookkeeping", "lookup", "prefill", "first_token", "splice",
+         "tick_dispatch", "emit"}
+ADMISSION_FIELDS = {"rid", "prompt_tokens", "prefills_waited",
+                    "suffix_tokens", "reused_tokens", "lookup_ms",
+                    "prefill_ms", "commit_ms", "commit_dispatches",
+                    "commit_blocks", "splice_ms"}
 
 
 @pytest.fixture(scope="module")
@@ -70,8 +80,9 @@ def test_one_record_per_iteration_and_the_parts_fit(engine):
     # the admitting pass emits the prefill's token and one tick's;
     # every further pass emits one
     assert len(ring) == 5
+    assert [r["pass"] for r in ring] == [0, 1, 2, 3, 4]
     for r in ring:
-        assert set(r) == FIELDS and r["max_batch"] == 4
+        assert set(r) - {"gap_ms"} == LANDED and r["max_batch"] == 4
         parts = (r["admit_ms"] + r["dispatch_ms"] + r["readback_ms"]
                  + r["emit_ms"])
         assert 0.0 < parts <= r["total_ms"]
@@ -224,7 +235,7 @@ def test_speculative_tick_records_the_same_fields(model):
     ring = _ring(eng)
     assert eng.spec_verify_ticks >= 1 and ring
     for r in ring:
-        assert set(r) == FIELDS
+        assert set(r) - {"gap_ms"} == LANDED
         assert min(r["dispatch_ms"], r["readback_ms"], r["emit_ms"]) > 0
         assert (r["admit_ms"] + r["dispatch_ms"] + r["readback_ms"]
                 + r["emit_ms"]) <= r["total_ms"]
@@ -475,6 +486,270 @@ def test_a_long_prompt_is_not_prefilled_behind_the_tick_in_flight(model):
             r["total_ms"] - r["readback_ms"] - r["admit_ms"]) + 1e-6
 
 
+# ------------------------------------------------------ the gap ledger
+
+def _scripted(eng, head_prompt, head_budget, script):
+    """A head request, and on the loop's own thread (in the walk over a
+    tick's tokens, so that where they land does not hang on how the
+    threads are scheduled) the submissions `script` names: {tokens the
+    head has produced: [(prompt, budget, eos), ...]}. Returns the
+    streams in the order they were handed in, all read to their end."""
+    streams = []
+    emit = eng._emit
+
+    def emit_then_submit(req, tok, score=0.0):
+        emit(req, tok, score)
+        if req is streams[0]._req:
+            for prompt, budget, eos in script.get(req.produced, ()):
+                streams.append(eng.stream(prompt, budget, eos_token=eos))
+
+    eng._emit = emit_then_submit
+    streams.append(eng.stream(head_prompt, head_budget))
+    tokens = [[int(t) for t in streams[0]]]
+    tokens += [[int(t) for t in s] for s in streams[1:]]
+    return streams, tokens
+
+
+def _gaps_fit(ring):
+    """What holds of every record of a pass that landed a tick."""
+    for r in ring:
+        if "gap_streams" not in r:
+            assert not GAP_FIELDS & set(r) and "gap_ms" not in r
+            continue
+        assert set(r) - {"gap_ms"} == LANDED
+        assert set(r["gap_empty_by"]) <= STEPS
+        assert r["gap_empty_ms"] == pytest.approx(
+            sum(r["gap_empty_by"].values()))
+        assert min([r["gap_blocked_ms"], r["gap_empty_ms"]]
+                   + list(r["gap_empty_by"].values())) >= 0.0
+        assert ("gap_ms" in r) == (r["gap_streams"] >= 1)
+        if "gap_ms" in r:
+            assert r["gap_blocked_ms"] + r["gap_empty_ms"] \
+                <= r["gap_ms"] + 1e-6
+            assert r["gap_streams"] <= r["live"]
+
+
+def test_consecutive_gaps_tile_the_time_between_landings(engine):
+    lands = []
+    land = engine._gaps.land
+
+    def noting(t1, streams, it):
+        lands.append(t1)
+        land(t1, streams, it)
+
+    engine._gaps.land = noting
+    assert len(engine.generate(PROMPT, 9)) == 9
+    engine.stop()
+    ring = _ring(engine)
+    assert len(ring) == len(lands) == 8
+    # the first landing ends no gap: nothing took a token before it
+    assert "gap_ms" not in ring[0] and ring[0]["gap_streams"] == 0
+    gaps = [r["gap_ms"] for r in ring[1:]]
+    assert gaps == pytest.approx(
+        [(b - a) * 1e3 for a, b in zip(lands, lands[1:])])
+    assert sum(gaps) == pytest.approx((lands[-1] - lands[0]) * 1e3)
+    assert [r["gap_streams"] for r in ring] == [0] + [1] * 7
+    _gaps_fit(ring)
+
+
+def test_a_steady_pass_leaves_the_chip_nothing_to_wait_for(engine):
+    """With a tick queued behind the one read, the chip is never
+    starved; the admitting pass's chain is, by the steps that ran."""
+    engine.generate(PROMPT, 9)
+    engine.stop()
+    ring = _ring(engine)
+    assert [r["inflight"] for r in ring] == [1] * 7 + [0]
+    for r in ring[1:]:
+        assert (r["gap_empty_ms"], r["gap_empty_by"]) == (0.0, {})
+        assert 0.0 < r["gap_blocked_ms"] <= r["gap_ms"]
+        assert r["gap_admissions"] == 0
+    first = ring[0]
+    assert first["gap_admissions"] == 1
+    # (`bookkeeping`: the telemetry push between `_admit` and `_launch`)
+    assert {"first_token", "splice", "tick_dispatch"} \
+        <= set(first["gap_empty_by"]) \
+        <= {"first_token", "splice", "tick_dispatch", "bookkeeping"}
+    assert first["gap_blocked_ms"] > 0.0
+    # an idle engine's chip is not a starved one: a second request
+    # after a pause finds the ledger as the first did
+    engine_gaps = engine.kv_stats()["gaps"]
+    assert engine_gaps["stream_gaps"] == 7
+    assert engine_gaps["with_admission"] == 0
+    assert engine_gaps["chip_empty_ms"] == {}
+    assert engine_gaps["chip_blocked_ms"] == pytest.approx(
+        sum(r["gap_blocked_ms"] for r in ring[1:]))
+
+
+@pytest.mark.parametrize("hold", [False, True])
+def test_an_admission_is_counted_in_the_gap_its_launch_fell_in(model, hold):
+    """In the gap that ENDS with the first landing after the admission's
+    programs were launched, and in no other: the admitting pass's own
+    landing where the prompt is queued behind the tick in flight, the
+    next pass's after a hold (`_long_prompt_waits`: the held pass reads
+    its tick BEFORE it admits)."""
+    window = CFG.max_seq_len
+    rng = np.random.default_rng(7)
+    late = rng.integers(1, 500, window // 2 + 5 if hold else 9).tolist()
+    eng = ContinuousBatchingEngine(model, CFG, max_batch=4)
+    try:
+        streams, tokens = _scripted(eng, [9, 8, 7], 30,
+                                    {6: [(late, 4, None)]})
+    finally:
+        eng.stop()
+    assert [len(t) for t in tokens] == [30, 4]
+    assert tokens[1] == _alone(model, CFG, late, 4)[0]
+    ring = _ring(eng)
+    _gaps_fit(ring)
+    (at,) = [i for i, r in enumerate(ring)
+             if [a["rid"] for a in r["admissions"]] == [1]]
+    counted = at + 1 if hold else at
+    assert ring[at]["inflight"] == (0 if hold else 1)
+    for i, r in enumerate(ring):
+        assert r["gap_admissions"] == (i in (0, counted)), i
+    # the head felt that gap, and it held the prefill: the thread was
+    # blocked on its logits, and the chip starved over the chain after
+    gap = ring[counted]
+    assert gap["gap_streams"] == 1
+    assert gap["gap_ms"] >= ring[at]["admissions"][0]["prefill_ms"]
+    assert gap["gap_blocked_ms"] > 0.0
+    assert {"first_token", "splice", "tick_dispatch"} \
+        <= set(gap["gap_empty_by"])
+    if hold:
+        # the held pass read its tick with nothing behind it, and the
+        # host walked, looked up and launched the prefill meanwhile
+        assert {"emit", "lookup", "prefill"} <= set(gap["gap_empty_by"])
+        assert ring[at]["gap_empty_ms"] == 0.0
+    stats = eng.kv_stats()["gaps"]
+    assert stats["with_admission"] == 1
+    assert stats["stream_gaps"] == sum(
+        r["gap_streams"] for r in ring if "gap_ms" in r)
+
+
+def test_gap_streams_leaves_out_a_joined_a_finished_and_a_discarded_row(
+        model):
+    """A stream feels a gap if it took a token from the tick before AND
+    from this one: not the slot that joined, not the one whose budget
+    ended with the tick before, not the row thrown away after an EOS
+    (which `live` still counts: the host knew no better at the launch)."""
+    rng = np.random.default_rng(3)
+    prompts = [rng.integers(1, 500, 7).tolist() for _ in range(3)]
+    budgets = [40, 5, 12]
+    toks = _alone(model, CFG, prompts[2], budgets[2])[0]
+    at = next(j for j in range(1, budgets[2] - 2)
+              if toks[j] not in toks[:j])
+    eng = ContinuousBatchingEngine(model, CFG, max_batch=4)
+    try:
+        streams, tokens = _scripted(eng, prompts[0], budgets[0], {
+            4: [(prompts[1], budgets[1], None)],
+            12: [(prompts[2], budgets[2], toks[at])]})
+    finally:
+        eng.stop()
+    assert tokens[2] == toks[:at + 1]
+    ring = _ring(eng)
+    _gaps_fit(ring)
+    admitted = {a["rid"]: i for i, r in enumerate(ring)
+                for a in r["admissions"]}
+    # admitted in pass i, a request is first in the tick pass i reads
+    # where that pass began with nothing on the chip, else in the next
+    first = {rid: i if i == 0 or not ring[i - 1]["inflight"] else i + 1
+             for rid, i in admitted.items()}
+    # the ticks it took a token from: one less than it emitted
+    last = {rid: first[rid] + len(tokens[rid]) - 2 for rid in first}
+    for j, r in enumerate(ring):
+        felt = [rid for rid in first if first[rid] < j <= last[rid]]
+        assert r["gap_streams"] == len(felt), (j, felt)
+    joined, ended, thrown = first[1], last[1] + 1, last[2] + 1
+    assert ring[joined]["live"] == 2 and ring[joined]["gap_streams"] == 1
+    assert ring[ended]["gap_streams"] == 1
+    assert ring[ended - 1]["gap_streams"] == 2
+    assert ring[thrown]["discarded"] == 1 and ring[thrown]["live"] == 2
+    assert ring[thrown]["gap_streams"] == 1
+
+
+@pytest.mark.parametrize("kind", ["plain", "speculative",
+                                  "drafts_at_times", "two_a_pass"])
+def test_the_parts_of_a_gap_fit_it(model, kind):
+    """Blocked and starved time are stretches of the gap that do not
+    overlap, and a record holds ONE landing, whatever the loop's shape:
+    the lookahead, a verify tick read with nothing behind it, a
+    speculating pass without drafts (it reads the lookahead, then
+    launches and reads a plain tick), two admissions in one pass."""
+    calls = itertools.count()
+    kw = {"speculative": dict(speculate_k=2,
+                              draft_source=lambda ctx, k: [ctx[-1]] * k),
+          "drafts_at_times": dict(
+              speculate_k=2, draft_source=lambda ctx, k:
+              [ctx[-1]] * k if next(calls) % 3 == 0 else []),
+          "two_a_pass": dict(max_prefills_per_tick=2)}.get(kind, {})
+    rng = np.random.default_rng(13)
+    others = [(rng.integers(1, 500, n).tolist(), 6, None) for n in (5, 11)]
+    eng = ContinuousBatchingEngine(model, CFG, max_batch=4, **kw)
+    lands = []
+    land = eng._gaps.land
+
+    def noting(t1, streams, it):
+        lands.append(t1)
+        land(t1, streams, it)
+
+    eng._gaps.land = noting
+    try:
+        streams, tokens = _scripted(eng, [4, 5, 6], 24, {5: others})
+        stats = eng.kv_stats()["gaps"]
+    finally:
+        eng.stop()
+    assert [len(t) for t in tokens] == [24, 6, 6]
+    ring = _ring(eng)
+    _gaps_fit(ring)
+    assert [r["pass"] for r in ring] == list(range(len(ring)))
+    # every landing has a record of its own, and the gaps tile the time
+    # between the landings
+    landed = [r for r in ring if "gap_streams" in r]
+    assert len(landed) == len(lands)
+    for i, r in enumerate(landed):
+        if "gap_ms" in r:
+            assert r["gap_ms"] == pytest.approx(
+                (lands[i] - lands[i - 1]) * 1e3)
+    if kind == "drafts_at_times":
+        # both shapes of a speculating pass were taken
+        assert {r["inflight"] for r in landed} == {0, 1}
+        assert eng.spec_proposed > 0
+    felt = [r for r in ring if "gap_ms" in r]
+    assert len(felt) >= 8
+    if kind == "speculative":
+        # nothing is queued behind a verify tick: every gap has the
+        # walk, the drafting and the next dispatch with the chip empty
+        assert all({"emit", "bookkeeping", "tick_dispatch"}
+                   <= set(r["gap_empty_by"]) for r in felt)
+    if kind == "two_a_pass":
+        (both,) = [r for r in ring if len(r["admissions"]) == 2]
+        assert both["gap_admissions"] == 2
+        # the second's lookup and launch ran behind the first's logits
+        assert {"lookup", "prefill"} <= set(both["gap_empty_by"])
+    # the operator's totals are the ring's sums
+    assert stats["stream_gaps"] == sum(r["gap_streams"] for r in felt)
+    assert stats["with_admission"] == sum(
+        r["gap_streams"] for r in felt if r["gap_admissions"])
+    assert stats["chip_blocked_ms"] == pytest.approx(
+        sum(r["gap_blocked_ms"] for r in felt))
+    for step in STEPS:
+        assert stats["chip_empty_ms"].get(step, 0.0) == pytest.approx(
+            sum(r["gap_empty_by"].get(step, 0.0) for r in felt))
+
+
+def test_prefills_waited_counts_the_prefills_a_request_stood_behind(engine):
+    lone = engine.stream(PROMPT, 3)
+    assert lone.prefills_waited is None or lone.prefills_waited == 0
+    assert len(list(lone)) == 3 and lone.prefills_waited == 0
+    # two handed in together: the second waits for the first's prefill
+    streams, tokens = _scripted(engine, [9, 8, 7], 20, {
+        5: [([1, 2, 3, 4], 3, None), ([5, 6, 7, 8, 9], 3, None)]})
+    engine.stop()
+    assert [s.prefills_waited for s in streams] == [0, 0, 1]
+    waited = {a["rid"]: a["prefills_waited"] for r in _ring(engine)
+              for a in r["admissions"]}
+    assert waited == {0: 0, 1: 0, 2: 0, 3: 1}
+
+
 @pytest.mark.parametrize("path", ["colocated", "disagg"])
 def test_router_hands_the_split_to_the_flight_recorder(model, path):
     if path == "colocated":
@@ -511,6 +786,9 @@ def test_router_hands_the_split_to_the_flight_recorder(model, path):
         first = next(ph for ph in kept["phases"]
                      if ph["phase"] == "decode_first_token")
         assert set(first["parts"]) == {"engine_queue", "engine_prefill"}
+        # the count of prefills waited behind rides the phase: one
+        # request at a time, so none
+        assert first["prefills_waited"] == 0
         # parts are children: the flat list, and with it the
         # phase-sum invariant, does not see them
         seq_ms = sum(ph["dur_ms"] for ph in kept["phases"]
@@ -597,6 +875,12 @@ def test_recorder_off_reads_no_clock_and_builds_no_record(
     assert reads == []
     assert reqtrace.store().loop_records() == []
     assert stream.queue_ms is None and stream.prefill_ms is None
+    # the ledger stays at zero; the prefills waited behind are a count,
+    # not a reading, and are kept
+    assert eng.kv_stats()["gaps"] == {
+        "stream_gaps": 0, "with_admission": 0, "chip_blocked_ms": 0.0,
+        "chip_empty_ms": {}}
+    assert stream.prefills_waited == 0
     # and on again it reads: the switch is live
     monkeypatch.setenv("RAY_TPU_REQTRACE", "1")
     envknobs.clear()
@@ -608,7 +892,8 @@ def test_recorder_off_reads_no_clock_and_builds_no_record(
     assert len(reads) >= 10 and _ring(eng)
 
 
-def test_profiler_trace_holds_the_engine_spans(engine, tmp_path):
+def _traced_events(engine, tmp_path):
+    """The `engine.*` events of a second request, traced on the CPU."""
     engine.generate(PROMPT, 3)  # compile outside the trace
     with profiling.profile(log_dir=str(tmp_path)):
         engine.generate([2] + PROMPT, 4)
@@ -620,7 +905,11 @@ def test_profiler_trace_holds_the_engine_spans(engine, tmp_path):
              for plane in data.planes for line in plane.lines]
     lines = [(name, evs) for name, evs in lines if evs]
     assert [name for name, _evs in lines] == ["cb-engine"]
-    events = lines[0][1]
+    return lines[0][1]
+
+
+def test_profiler_trace_holds_the_engine_spans(engine, tmp_path):
+    events = _traced_events(engine, tmp_path)
     assert {e.name for e in events} == {
         "engine.admit", "engine.prefill", "engine.pool_commit",
         "engine.splice", "engine.tick_dispatch", "engine.tick_readback",
@@ -631,9 +920,13 @@ def test_profiler_trace_holds_the_engine_spans(engine, tmp_path):
         return ev, ev.start_ns, ev.start_ns + ev.duration_ns
 
     prefill, p0, p1 = one("engine.prefill")
-    assert dict(prefill.stats) == {"rid": 1, "prompt_tokens": 20}
+    admitted = dict(prefill.stats).pop("pass")
+    assert dict(prefill.stats) == {"rid": 1, "prompt_tokens": 20,
+                                   "pass": admitted}
     commit, c0, c1 = one("engine.pool_commit")
-    assert dict(commit.stats) == {"rid": 1}
+    assert dict(commit.stats) == {"rid": 1, "pass": admitted}
+    assert dict(one("engine.splice")[0].stats) == {"rid": 1,
+                                                   "pass": admitted}
     assert p0 <= c0 and c1 <= p1
     _splice, s0, _s1 = one("engine.splice")
     admits = [(e.start_ns, e.start_ns + e.duration_ns) for e in events
@@ -641,4 +934,35 @@ def test_profiler_trace_holds_the_engine_spans(engine, tmp_path):
     assert any(a0 <= p0 and p1 <= s0 <= a1 for a0, a1 in admits)
     ticks = [e for e in events if e.name == "engine.tick_dispatch"]
     assert len(ticks) == 3 and all(
-        dict(e.stats) == {"live": 1} for e in ticks)
+        set(dict(e.stats)) == {"live", "pass"}
+        and dict(e.stats)["live"] == 1 for e in ticks)
+
+
+def test_a_span_carries_the_pass_of_its_ring_record(engine, tmp_path):
+    """From a span in the profiler to the ring: `pass` on every
+    `engine.*` event is the `pass` of the record its pass left."""
+    events = _traced_events(engine, tmp_path)
+    engine.stop()
+    ring = {r["pass"]: r for r in _ring(engine)}
+    assert sorted(ring) == list(range(len(ring)))
+    by_pass = {}
+    for e in events:
+        by_pass.setdefault(dict(e.stats)["pass"], []).append(e.name)
+    # the admitting pass of the traced request: its record holds the
+    # admission, its spans the whole chain and two launches
+    (admitting,) = [n for n, r in ring.items()
+                    if [a["rid"] for a in r["admissions"]] == [1]]
+    assert sorted(by_pass[admitting]) == sorted([
+        "engine.admit", "engine.prefill", "engine.pool_commit",
+        "engine.splice", "engine.tick_dispatch", "engine.tick_dispatch",
+        "engine.tick_readback", "engine.emit"])
+    # every pass that read a tick back has the two spans of `_land`,
+    # and one dispatch where its record says a tick was queued behind
+    for n, names in by_pass.items():
+        if "engine.tick_readback" not in names:
+            continue  # an idle pass: its spans carry the next number
+        r = ring[n]
+        assert names.count("engine.emit") == 1
+        if n != admitting:
+            assert names.count("engine.tick_dispatch") == r["inflight"]
+        assert (r["readback_ms"] > 0) and "gap_streams" in r
