@@ -211,8 +211,9 @@ def train_phase(cfg: Any, *, per_chip_batch: int = 32, seq: int = 1024,
         if hlo_dump:
             with open(hlo_dump, "w") as f:
                 f.write("\n".join(c["hlo"] for c in calls) + "\n")
-        rows = {"flash": per_chip_batch * cfg.num_heads,
-                "fused": per_chip_batch * seq}
+        # the flash kernels read [batch, seq, heads * head_dim] as it
+        # lies (two heads of 64 a 128-lane block); the fused CE its rows
+        rows = {"flash": per_chip_batch, "fused": per_chip_batch * seq}
         for kernel in TRAIN_KERNELS:
             mine = [c for c in calls if c["kernel"] == kernel]
             want = cfg.num_layers if kernel.startswith("flash") else 1
@@ -234,7 +235,10 @@ def train_phase(cfg: Any, *, per_chip_batch: int = 32, seq: int = 1024,
         "ok": True, "devices": n_dev, "mesh": seen["mesh"],
         "per_chip_batch": per_chip_batch, "seq": seq, "steps": steps,
         "loss_first": losses[0], "loss_last": losses[-1],
-        "kernels": [{k: c[k] for k in ("op", "shape", "choice", "shards")}
+        # with what each entry point chose from the shape (flash
+        # attention: each kernel's blocks and the share of the square
+        # they visit; under 1.0, the causal skipping engaged)
+        "kernels": [{k: v for k, v in c.items() if k != "reason"}
                     for c in choices.values()],
         "custom_calls": {k: sum(1 for c in calls if c["kernel"] == k)
                          for k in TRAIN_KERNELS} if calls else None,

@@ -31,15 +31,17 @@ _interpret_depth = 0
 
 
 def record_choice(op: str, shape: Sequence[int], choice: str,
-                  reason: str = "", shards: int = 1) -> None:
+                  reason: str = "", shards: int = 1, **chosen: Any) -> None:
     """Called at trace time by a kernel entry point: `choice` is
     "pallas" or "reference", `reason` says why a reference was taken,
     `shards` is how many per-device pieces `per_shard` cut the call
-    into (1: the call sees the global shape)."""
+    into (1: the call sees the global shape), `chosen` what else the
+    entry point selected from the shape (flash attention: each kernel's
+    blocks and the share of the square they compute)."""
     key = (op, tuple(int(s) for s in shape))
     with _lock:
         _choices[key] = {"op": op, "shape": key[1], "choice": choice,
-                         "reason": reason, "shards": int(shards)}
+                         "reason": reason, "shards": int(shards), **chosen}
 
 
 def kernel_choices(op: Optional[str] = None) -> list:

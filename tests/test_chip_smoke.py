@@ -102,13 +102,13 @@ def test_serve_phase_tiny_in_process():
 def test_custom_calls_reads_kernel_name_and_first_operand():
     hlo = '''
   %fusion.1 = bf16[8]{0} fusion(%p), kind=kLoop
-  %flash_fwd.3 = (bf16[384,1024,64]{2,1,0:T(8,128)(2,1)}, f32[384,1024,8]{2,1,0:T(8,128)}) custom-call(%bitcast.227, %bitcast.224), custom_call_target="tpu_custom_call", operand_layout_constraints={bf16[384,1024,64]{2,1,0}, bf16[384,1024,64]{2,1,0}}, metadata={op_name="jit(step)/jvp()/shard_map/flash_fwd/pallas_call" stack_frame_id=47}, backend_config={"custom_call_config":{"body":"TUzv"}}
+  %flash_fwd.3 = (bf16[32,1024,768]{2,1,0:T(8,128)(2,1)}, f32[32,12,1024,8]{3,2,1,0:T(8,128)}) custom-call(%bitcast.227, %bitcast.224), custom_call_target="tpu_custom_call", operand_layout_constraints={bf16[32,1024,768]{2,1,0}, bf16[32,1024,768]{2,1,0}}, metadata={op_name="jit(step)/jvp()/shard_map/flash_fwd/pallas_call" stack_frame_id=47}, backend_config={"custom_call_config":{"body":"TUzv"}}
   %custom-call.77 = f32[32768,768]{1,0:T(8,128)} custom-call(%bitcast.195, %custom-call.13), custom_call_target="tpu_custom_call", operand_layout_constraints={bf16[32768,768]{1,0}, bf16[50304,768]{1,0}}, metadata={op_name="jit(step)/transpose(jvp(fused_ce_dx))/pallas_call" stack_frame_id=90}
   %custom-call.9 = f32[4]{0} custom-call(%x), custom_call_target="Sharding"
 '''
     calls = chip_smoke.custom_calls(hlo)
     assert [(c["kernel"], c["operand0"]) for c in calls] == [
-        ("flash_fwd", [384, 1024, 64]), ("fused_ce_dx", [32768, 768])]
+        ("flash_fwd", [32, 1024, 768]), ("fused_ce_dx", [32768, 768])]
     assert all("TUzv" not in c["hlo"] for c in calls)
 
 

@@ -127,8 +127,8 @@ def test_flash_block_sizes(block):
 
 
 def test_flash_non_block_multiple_length():
-    """T=640 is a multiple of 128 but not of the 512 default block: must
-    not hit the pallas path with clamped (corrupt) pl.ds reads."""
+    """T=640 is a multiple of 128 and of no larger block: the rule cuts
+    its pairs to 128, so no pl.ds read is clamped (and corrupted)."""
     q, k, v = _rand_qkv(jax.random.PRNGKey(7), 1, 640, 640, 2, 64,
                         jnp.float32)
     out = flash_attention(q, k, v, True)
@@ -330,22 +330,112 @@ def _check_uneven_cases(rng, B, H, D):
                 err_msg=f"tq={tq} tk={tk} causal={causal} d{name}")
 
 
-def test_set_default_blocks_affects_trace():
-    """set_default_blocks (the block-size hook) changes the block
-    sizes unpinned calls trace with, and results stay correct across
-    block configurations."""
-    from ray_tpu.ops import attention
+def _share_by_elements(tq, tk, blocks, causal):
+    """`executed_block_share` the slow way, from the mask's elements: a
+    block of query rows visits the keys up to the last one any of its rows
+    sees; a block of keys is visited from the first query row that sees
+    any of it."""
+    keep = np.tril(np.ones((tq, tk), bool), k=tk - tq) if causal \
+        else np.ones((tq, tk), bool)
+    visited = 0
+    for bq in (blocks.fwd[0], blocks.dq[0]):
+        for first in range(0, tq, bq):
+            cols = np.flatnonzero(keep[first:first + bq].any(axis=0))
+            visited += bq * (cols.max() + 1 if cols.size else 0)
+    bk = blocks.dkv[1]
+    for first in range(0, tk, bk):
+        rows = np.flatnonzero(keep[:, first:first + bk].any(axis=1))
+        visited += bk * (tq - rows.min() if rows.size else 0)
+    return visited / (3.0 * tq * tk)
 
-    q, k, v = _rand_qkv(jax.random.PRNGKey(3), 1, 256, 256, 2, 64,
-                        jnp.float32)
-    ref = mha_reference(q, k, v, True, q.shape[-1] ** -0.5)
-    orig = (attention.DEFAULT_BLOCK_Q, attention.DEFAULT_BLOCK_K)
-    try:
-        for bq, bk in ((256, 256), (128, 256), (256, 128), (128, 128)):
-            attention.set_default_blocks(bq, bk)
-            assert attention.DEFAULT_BLOCK_Q == bq
-            out = flash_attention(q, k, v, causal=True)
-            np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                       atol=2e-5, rtol=2e-5)
-    finally:
-        attention.set_default_blocks(*orig)
+
+# (batch, tq, tk, heads, head_dim, causal): the training cell's shape, a
+# decode-style tq != tk, heads of 128, a length only 128 divides, a
+# non-causal call, a per-shard piece with an odd head count, float32
+_RULE_SHAPES = [
+    (32, 1024, 1024, 12, 64, True, jnp.bfloat16),
+    (2, 512, 1024, 4, 64, True, jnp.bfloat16),
+    (2, 2048, 2048, 4, 128, True, jnp.bfloat16),
+    (1, 640, 640, 2, 64, True, jnp.bfloat16),
+    (2, 1024, 1024, 2, 64, False, jnp.bfloat16),
+    (8, 1024, 1024, 3, 64, True, jnp.bfloat16),
+    (1, 1024, 1024, 2, 64, True, jnp.float32),
+]
+
+
+@pytest.mark.parametrize("b,tq,tk,h,d,causal,dtype", _RULE_SHAPES)
+def test_flash_blocks_chosen_from_shape(b, tq, tk, h, d, causal, dtype):
+    """`choose_blocks` gives every kernel a pair that divides its lengths,
+    and the traced call records that pair and the share of the square it
+    computes; nothing but the shape is read."""
+    from ray_tpu.ops import attention, dispatch
+
+    blocks = attention.choose_blocks(tq, tk, d, causal, dtype)
+    assert set(blocks._fields) == {"fwd", "dq", "dkv"}
+    for bq, bk in blocks:
+        assert bq % 128 == 0 and bk % 128 == 0
+        assert tq % bq == 0 and tk % bk == 0
+    share = attention.executed_block_share(tq, tk, blocks, causal)
+    assert share == pytest.approx(_share_by_elements(tq, tk, blocks, causal))
+    if not causal:
+        assert share == 1.0
+    elif tq == tk and tq >= 1024:
+        # the skipping engages where the training cell runs
+        assert share < 1.0
+
+    dispatch.reset_kernel_choices()
+    q = jax.ShapeDtypeStruct((b, tq, h, d), dtype)
+    kv = jax.ShapeDtypeStruct((b, tk, h, d), dtype)
+    jax.eval_shape(lambda q, k, v: flash_attention(q, k, v, causal),
+                   q, kv, kv)
+    (rec,) = dispatch.kernel_choices("flash_attention")
+    assert rec["choice"] == "pallas" and rec["shape"] == (b, tq, h, d, tk)
+    assert rec["blocks"] == blocks._asdict()
+    assert rec["executed_block_share"] == pytest.approx(share)
+
+
+def test_flash_pinned_blocks_are_recorded():
+    """A test that pins block_q/block_k gives the pair to all three
+    kernels, and the record says so."""
+    from ray_tpu.ops import dispatch
+
+    dispatch.reset_kernel_choices()
+    q = jax.ShapeDtypeStruct((1, 512, 2, 64), jnp.float32)
+    jax.eval_shape(lambda q: flash_attention(q, q, q, True, None, 128, 256),
+                   q)
+    (rec,) = dispatch.kernel_choices("flash_attention")
+    assert rec["blocks"] == {"fwd": (128, 256), "dq": (128, 256),
+                             "dkv": (128, 256)}
+    # query blocks of 128 visit 128, 256, 384, 512 keys (fwd and dq: 5/8 of
+    # the square); key blocks of 256 are visited by 512 and 256 rows (3/4)
+    assert rec["executed_block_share"] == pytest.approx((5 / 8 + 5 / 8 + 3 / 4) / 3)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_flash_rule_blocks_match_reference_at_1024(dtype):
+    """Forward and the three gradients at the training cell's length with
+    the rule's own blocks (none passed): the skipped blocks and the
+    mask-free loop under the diagonal change no number."""
+    from ray_tpu.ops import dispatch
+
+    q, k, v = _rand_qkv(jax.random.PRNGKey(11), 1, 1024, 1024, 2, 64, dtype)
+
+    def loss(attn):
+        def f(q, k, v):
+            out = attn(q, k, v, True)
+            return jnp.sum(out.astype(jnp.float32) ** 2), out
+        return jax.value_and_grad(f, argnums=(0, 1, 2), has_aux=True)
+
+    dispatch.reset_kernel_choices()
+    (_, out), grads = loss(flash_attention)(q, k, v)
+    (_, ref), grads_ref = loss(mha_reference)(q, k, v)
+    (rec,) = dispatch.kernel_choices("flash_attention")
+    assert rec["choice"] == "pallas" and rec["executed_block_share"] < 1.0
+    tol = dict(atol=6e-2, rtol=3e-2) if dtype == jnp.bfloat16 \
+        else dict(atol=1e-4, rtol=1e-3)
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(ref, np.float32), **_tol(dtype))
+    for g, gr, name in zip(grads, grads_ref, "qkv"):
+        np.testing.assert_allclose(
+            np.asarray(g, np.float32), np.asarray(gr, np.float32),
+            err_msg=f"d{name}", **tol)
