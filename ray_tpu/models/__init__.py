@@ -17,7 +17,14 @@ DeepSeek-V2 (`deepseek_v2.py`: rotary latent attention under YaRN over a
 dense layer and group-routed expert layers; its cache is latent rows
 ALONE, no values and no state, so the engine builds it no prefix pool and
 refuses it adoption, speculation and a LoRA pool, each for what the pool
-lacks).
+lacks), and SmallThinker (`smallthinker.py`: grouped-query layers that
+see the whole sequence beside layers that see a window, under a router
+that reads ahead of the attention; a window layer's keys and values are
+the engine's fourth kind of entry, a RING shorter than `max_seq_len`
+beside the global layers' full-length entries in one slab, so the engine
+builds it no prefix pool and refuses it adoption, speculation and a LoRA
+pool: most layers have forgotten what a block-aligned prefix would
+resume).
 `moe_transformer.py` trains and is not served.
 """
 from .gpt2 import (  # noqa: F401
@@ -59,6 +66,13 @@ from .deepseek_v2 import (  # noqa: F401
     deepseek_v2_init,
     deepseek_v2_loss,
     deepseek_v2_partition_specs,
+)
+from .smallthinker import (  # noqa: F401
+    SmallThinkerConfig,
+    smallthinker_forward,
+    smallthinker_init,
+    smallthinker_loss,
+    smallthinker_partition_specs,
 )
 from .moe_transformer import (  # noqa: F401
     MoEConfig,

@@ -67,7 +67,7 @@ Surfaces: ``util.state.speculation_stats()``, ``ray_tpu speculate``,
 instant markers in the merged timeline's kvcache lane.
 
 The cache protocol is one for every family (`generate._model_fns`): the
-decode slab is the family's own pytree, a list of entries of three
+decode slab is the family's own pytree, a list of entries of four
 kinds. An entry with "k"/"v" `[B, S, H, hd]` has a sequence axis: a
 prefill hands back its rows stacked `[L_kv, S, H, hd]` (what the paged
 pool commits, a disaggregated transfer ships and `_splice_slot` writes
@@ -100,6 +100,36 @@ values: ``adopt_prefill`` and the disaggregated transfer (they carry
 token chains, and the family's decode has no ``[B, k+1]`` verify form)
 and a ``lora_pool`` (its per-tenant prefix namespaces are the pool's).
 Each ValueError names what the pool lacks (``_refuse_for_latent``).
+
+The fourth kind is the RING: a sequence entry ("k" and "v" `[B, rows, H,
+hd]`) whose `rows` is SHORTER than ``config.max_seq_len``, beside
+entries of the full length in the same slab (models/smallthinker.py:
+layers that see a window of 4,096 positions beside layers that see all
+16,384). The token at position p lies in row `p mod rows`, its key stored
+as the attention reads it, so the order of the rows never matters to a
+softmax. The engine learns this from the slab's own shapes
+(``ring_rows``), never from a family's name, and it is the family that
+masks and wraps. What changes here is small: a prefill hands the
+sequence entries back as ONE STACK FOR EACH ROW COUNT (`ck`, `cv` are
+then tuples, in the order the slab first shows each count; a family with
+one count gets the one stack it always got, so the pool's commit, the
+transfer and adoption see what they saw), a ring's stack as the ring
+would lie after the prompt (the last `min(plen, rows)` positions, each at
+`p mod rows`); `_splice_slot` writes rows `[0, min(plen, rows))` of each
+entry, O(rows held) and in place; a dead slot's position stands still
+(`_chosen`), so its ring row does too; the loop's record of a tick gains
+``live_rows_window`` (the sum over the live slots of `min(position,
+rows)` for the shortest row count: what of the rings the tick had a
+reason to read) and ``kv_stats()["slab"]`` says, for each row count, the
+layers that hold it and the bytes a slot costs. What stands on the pool
+or on one block shape is refused in words (``_refuse_for_ring``):
+``prefix_cache=True`` (a block-aligned prefix cannot be resumed where
+layers have forgotten all but their last rows, and the pool has one block
+shape and one length; left to its default the engine builds none and
+prefills from position 0), speculation (a rejected draft's rows have
+overwritten ring rows the window still sees), a ``lora_pool`` and
+``adopt_prefill`` with the disaggregated transfer (one stack of the
+prompt's length).
 
 The loop keeps one tick ahead. The token vector and the position vector
 of the decode step live on the chip: `_tick` hands back, beside the
@@ -168,8 +198,10 @@ values', whatever the blocks; 0 where nothing was new) and
 ``forward_cached`` has a form that counts the run, its counters under their own
 names (models/deepseek_v2.py: ``moe_pairs_held`` and ``moe_rows_max``,
 what the grouped products saw over the prompt, and ``attn_blocks``, the
-blocks of scores the prompt form computed; ``kv_stats()`` holds their
-totals as ``prefill_counters``); an adoption has ``prefill_ms`` 0);
+blocks of scores the prompt form computed; models/smallthinker.py also
+``attn_blocks_causal``, what a causal walk with no window would have
+visited; ``kv_stats()`` holds their totals as ``prefill_counters``); an
+adoption has ``prefill_ms`` 0);
 ``dispatch_ms`` (inside ``_launch``, every launch of the pass: the
 lookahead's dispatch, before it ``_set_rows`` in a pass that follows an
 admission or a finish, and the tick's own where nothing was in flight;
@@ -187,7 +219,8 @@ because their request had finished by EOS or been cancelled since the
 launch); ``total_ms`` (the whole pass; what the parts leave is
 bookkeeping: swap, cancels, drafting, telemetry push). ``live`` (the
 slots the tick decoded for), ``live_rows`` (the sum of their positions:
-the cache rows the tick had a reason to read) and, where the family's
+the cache rows the tick had a reason to read), where the slab holds a
+ring ``live_rows_window`` (above) and, where the family's
 decode hands back counters of the step, those under their own names
 (``moe_pairs_held``, token-expert pairs that fell on experts held here,
 summed over the expert layers, ``moe_rows_max``, the most rows one held
@@ -506,6 +539,29 @@ def latent_only(cache) -> bool:
                for blk in cache)
 
 
+RING = ("this family's cache holds a ring (layers that keep only their "
+        "last rows, fewer than max_seq_len): ")
+
+
+def _row_counts(cache) -> Dict[int, List[int]]:
+    """The sequence entries of a cache by their rows, in the order the
+    cache first shows each count: {rows: [entry indices]}."""
+    by_rows: Dict[int, List[int]] = {}
+    for i, blk in enumerate(cache):
+        if "k" in blk:
+            by_rows.setdefault(blk["k"].shape[1], []).append(i)
+    return by_rows
+
+
+def ring_rows(cache, max_seq_len: int) -> Optional[int]:
+    """The rows of the shortest sequence entry of a family's cache
+    (`init_cache`, or its shapes) where that is shorter than the window:
+    such an entry is a ring (module docstring). None for a cache without
+    one."""
+    shortest = min(_row_counts(cache), default=max_seq_len)
+    return shortest if shortest < max_seq_len else None
+
+
 def _prefill_body(params, suffix, config, prefix_k, prefix_v):
     """What `_prefill_paged` and `_prefill_paged_lora` trace. The
     family's single-sequence cache (`init_cache(config, 1)`) is laid out
@@ -517,37 +573,46 @@ def _prefill_body(params, suffix, config, prefix_k, prefix_v):
     sequence axis, each as the family left it (a slot's state; the empty
     list for a family that has none). A family whose sequence entries
     hold one array (a latent row: "k" alone) gets `cv` None, and
-    `prefix_v` is not read. Last, the counters of the run where the
-    family's `forward_cached` carries a counted form of itself
-    (`with_counters`: a dict of small numbers, models/deepseek_v2.py),
-    else None."""
+    `prefix_v` is not read. A family whose sequence entries have MORE
+    THAN ONE row count (a ring beside full-length entries) gets `ck` and
+    `cv` as tuples, one stack a row count in the order the cache first
+    shows each, and has no cached prefix to lay out. Last, the counters
+    of the run where the family's `forward_cached` carries a counted form
+    of itself (`with_counters`: a dict of small numbers,
+    models/deepseek_v2.py), else None."""
     fwd, init_cache, _ = _model_fns(config)
     c = prefix_k.shape[1]
     cache = list(init_cache(config, 1))
-    kv_at = [i for i, blk in enumerate(cache) if "k" in blk]
+    by_rows = _row_counts(cache)
+    kv_at = [i for at in by_rows.values() for i in at]
     paired = all("v" in cache[i] for i in kv_at)
-    if c and len(kv_at) != len(cache):
+    if c and (len(kv_at) != len(cache) or len(by_rows) > 1):
         raise ValueError(
-            "a cached prefix cannot resume a recurrent state: this "
-            "family prefills every prompt from position 0")
-    base_k = jnp.zeros((len(kv_at), config.max_seq_len)
-                       + prefix_k.shape[2:], prefix_k.dtype)
-    base_v = jnp.zeros_like(base_k) if paired else None
-    if c:
-        base_k = base_k.at[:, :c].set(prefix_k)
-        if paired:
-            base_v = base_v.at[:, :c].set(prefix_v)
-    for j, i in enumerate(kv_at):
-        cache[i] = {"k": base_k[j][None]}
-        if paired:
-            cache[i]["v"] = base_v[j][None]
+            "a cached prefix cannot resume a recurrent state or a ring: "
+            "this family prefills every prompt from position 0")
+    for rows, at in by_rows.items():
+        base_k = jnp.zeros((len(at), rows) + prefix_k.shape[2:],
+                           prefix_k.dtype)
+        base_v = jnp.zeros_like(base_k) if paired else None
+        if c:
+            base_k = base_k.at[:, :c].set(prefix_k)
+            if paired:
+                base_v = base_v.at[:, :c].set(prefix_v)
+        for j, i in enumerate(at):
+            cache[i] = {"k": base_k[j][None]}
+            if paired:
+                cache[i]["v"] = base_v[j][None]
     counted = getattr(fwd, "with_counters", None)
     if counted is None:
         (logits, cache), counts = fwd(params, suffix, config, cache, c), None
     else:
         logits, cache, counts = counted(params, suffix, config, cache, c)
-    ck = jnp.stack([cache[i]["k"][0] for i in kv_at])
-    cv = jnp.stack([cache[i]["v"][0] for i in kv_at]) if paired else None
+    ck = tuple(jnp.stack([cache[i]["k"][0] for i in at])
+               for at in by_rows.values())
+    cv = tuple(jnp.stack([cache[i]["v"][0] for i in at]) if paired else None
+               for at in by_rows.values())
+    if len(by_rows) == 1:     # the one stack every consumer speaks
+        ck, cv = ck[0], cv[0]
     state = [blk for blk in cache if "k" not in blk]
     return logits[:, -1], ck, cv, state, counts
 
@@ -677,23 +742,31 @@ def _prefill_with_cache(params, config, kv_cache, prompt, empty_prefix,
 def _splice_slot(cache, ck, cv, slot, config, plen, state=()):
     """Write a prefilled sequence into batch slot `slot` of the decode
     slab, which is the family's own pytree: an entry with "k" has a
-    sequence axis and takes rows [0, plen) of its layer of ck (and of cv
-    where it holds "v" too; a latent entry holds "k" alone); any other
-    entry is a slot's state and takes the next entry of `state` WHOLE.
-    With the slab donated this lowers to an in-place update per entry,
-    O(plen) rows and the state's bytes, never a full-cache copy."""
+    sequence axis and takes rows [0, min(plen, rows)) of its layer of ck
+    (and of cv where it holds "v" too; a latent entry holds "k" alone):
+    all of a ring the prompt has wrapped, which the prefill handed back
+    as it lies. `ck` and `cv` are one stack, or a tuple of stacks, one a
+    row count in the order the slab first shows each (`_prefill_body`).
+    Any other entry is a slot's state and takes the next entry of `state`
+    WHOLE. With the slab donated this lowers to an in-place update per
+    entry, O(rows held) and the state's bytes, never a full-cache copy."""
     del config
-    out, layer, states = [], 0, iter(state)
+    stacks = {rows: j for j, rows in enumerate(_row_counts(cache))}
+    if not isinstance(ck, tuple):
+        ck, cv = (ck,), (cv,)
+    out, layer, states = [], [0] * len(stacks), iter(state)
     for blk in cache:
         if "k" in blk:
+            rows = blk["k"].shape[1]
+            j, n = stacks[rows], min(plen, rows)
             at = (slot,) + (0,) * (blk["k"].ndim - 1)
             new = {"k": jax.lax.dynamic_update_slice(
-                blk["k"], ck[layer, :plen][None], at)}
+                blk["k"], ck[j][layer[j], :n][None], at)}
             if "v" in blk:
                 new["v"] = jax.lax.dynamic_update_slice(
-                    blk["v"], cv[layer, :plen][None], at)
+                    blk["v"], cv[j][layer[j], :n][None], at)
             out.append(new)
-            layer += 1
+            layer[j] += 1
         else:
             out.append(jax.tree.map(
                 lambda slab, one: jax.lax.dynamic_update_slice(
@@ -785,8 +858,10 @@ class _Flight(NamedTuple):
     device outputs, and what the host knew at the launch. `reqs[slot]`
     is the request the tick decodes for at that slot (None: a dead slot,
     or one whose budget ends with the tick ahead of this one); `live`
-    and `live_rows` are their count and the sum of their positions;
-    `seq` is the ledger's count of launches with this tick the newest."""
+    and `live_rows` are their count and the sum of their positions,
+    `live_rows_window` the sum of `min(position, rows)` for the slab's
+    ring (None without one); `seq` is the ledger's count of launches
+    with this tick the newest."""
 
     nxt: Any
     lp: Any
@@ -794,6 +869,7 @@ class _Flight(NamedTuple):
     reqs: List[Optional["_Request"]]
     live: int
     live_rows: int
+    live_rows_window: Optional[int]
     drafts: Optional[Dict[int, List[int]]]
     seq: int
 
@@ -962,7 +1038,8 @@ class ContinuousBatchingEngine:
                                                  List[int]]] = None,
                  kv_int8: Optional[bool] = None):
         # config: any family _model_fns knows (LlamaConfig, GPT2Config,
-        # NemotronHConfig, KimiLinearConfig)
+        # NemotronHConfig, KimiLinearConfig, DeepseekV2Config,
+        # SmallThinkerConfig)
         self.params = params
         self.config = config
         self.max_batch = max_batch
@@ -988,6 +1065,16 @@ class ContinuousBatchingEngine:
             x.size * x.dtype.itemsize // (max_batch * x.shape[1])
             for blk in self._cache if "k" in blk
             for x in jax.tree.leaves(blk))
+        # for each row count of the slab's sequence entries: the layers
+        # that hold it and the bytes a slot costs (kv_stats)
+        self._slab = [
+            {"rows": rows, "layers": len(at), "bytes_per_slot": sum(
+                x.size * x.dtype.itemsize // max_batch
+                for i in at for x in jax.tree.leaves(self._cache[i]))}
+            for rows, at in _row_counts(self._cache).items()]
+        # the rows of the slab's ring, where a sequence entry is shorter
+        # than the window (module docstring); None for a slab without one
+        self.ring_rows = ring_rows(self._cache, config.max_seq_len)
         if speculate_k is None:
             speculate_k = default_speculate_k()
         if self.stateful:
@@ -995,6 +1082,9 @@ class ContinuousBatchingEngine:
             prefix_cache = False
         elif self.latent_only:
             self._refuse_for_latent(prefix_cache, speculate_k, lora_pool)
+            prefix_cache = False
+        elif self.ring_rows:
+            self._refuse_for_ring(prefix_cache, speculate_k, lora_pool)
             prefix_cache = False
         # paged KV prefix cache (models/kvcache.py); RAY_TPU_KV_* env
         # knobs supply defaults, constructor args win
@@ -1149,6 +1239,32 @@ class ContinuousBatchingEngine:
                 "the attention projections of the families it knows "
                 "(lora_pool)")
 
+    def _refuse_for_ring(self, prefix_cache, speculate_k, lora_pool
+                         ) -> None:
+        """What stands on the paged pool or on one block shape, refused
+        in words for a slab that holds a ring (module docstring).
+        `prefix_cache=None` simply builds no pool."""
+        rows = self.ring_rows
+        if prefix_cache:
+            raise ValueError(
+                RING + "a block-aligned prefix cannot be resumed where "
+                f"layers have forgotten all but their last {rows} rows, "
+                "and the paged pool has one block shape and one length "
+                "for every layer (prefix_cache=True)")
+        if speculate_k:
+            raise ValueError(
+                RING + "a rejected draft's rows need no copy-back only "
+                "while they stay masked, and in a ring they have "
+                "overwritten rows the window still sees; the pool "
+                "proposer drafts from the paged pool's token chains, "
+                "which this cache has none of "
+                f"(speculate_k={speculate_k})")
+        if lora_pool is not None:
+            raise ValueError(
+                RING + "the adapter pool's per-tenant prefix namespaces "
+                "are the paged pool's, which this cache cannot have "
+                "(lora_pool)")
+
     @staticmethod
     def _refuse_for_state(prefix_cache, speculate_k, lora_pool) -> None:
         """What the engine takes for granted of keys and values and a
@@ -1258,6 +1374,13 @@ class ContinuousBatchingEngine:
                 "pairs, as the paged pool and the transfer between "
                 "replicas speak them, and there are no values to carry "
                 "(adopt_prefill)")
+        if self.ring_rows:
+            raise ValueError(
+                RING + "an adoption carries ONE stack of ck and cv rows "
+                "of the prompt's length, as the paged pool and the "
+                "transfer between replicas speak them, and a ring's "
+                f"{self.ring_rows} rows are a stack of their own, each "
+                "row at its position mod the ring (adopt_prefill)")
         if plen < 1:
             raise ValueError("prompt_len must be >= 1")
         if plen + max_new_tokens > self.config.max_seq_len:
@@ -1451,6 +1574,8 @@ class ContinuousBatchingEngine:
             prefill_counters=dict(self.prefill_counters),
             state_bytes_per_slot=self._state_bytes_per_slot,
             kv_bytes_per_token=self._kv_bytes_per_token,
+            ring_rows=self.ring_rows,
+            slab=[dict(entry) for entry in self._slab],
             lookahead_ticks=self.lookahead_ticks,
             lookahead_discarded=self.lookahead_discarded,
             # stream-gaps counted, those that held an admission, the
@@ -1461,6 +1586,8 @@ class ContinuousBatchingEngine:
             # which traced shapes of the expert layers' grouped product
             # took the streamed kernel (the process's, not this engine's)
             grouped_product=dispatch.kernel_choices("grouped_product"),
+            # and of the grouped-query prompt form (ops/swa.py)
+            gqa_prefill=dispatch.kernel_choices("gqa_prefill"),
         )
         s.update(self.speculation_stats())
         if self.kv_cache is None:
@@ -1829,6 +1956,8 @@ class ContinuousBatchingEngine:
         it."""
         reqs: List[Optional[_Request]] = []
         rows = 0
+        ring = self.ring_rows
+        rows_window = 0 if ring else None
         for slot, req in enumerate(self._slot_req):
             ahead = int(behind is not None and req is not None
                         and behind.reqs[slot] is req)
@@ -1837,6 +1966,8 @@ class ContinuousBatchingEngine:
             else:
                 reqs.append(req)
                 rows += int(self._pos[slot]) + ahead
+                if ring:
+                    rows_window += min(int(self._pos[slot]) + ahead, ring)
         live = sum(r is not None for r in reqs)
         if not live:
             return None
@@ -1878,8 +2009,8 @@ class ContinuousBatchingEngine:
             self.lookahead_ticks += 1
         if it is not None:
             it["dispatch_ms"] += (_now() - t0) * 1e3
-        return _Flight(nxt, lp, counts, reqs, live, rows, drafts,
-                       gaps.launches)
+        return _Flight(nxt, lp, counts, reqs, live, rows, rows_window,
+                       drafts, gaps.launches)
 
     def _land(self, flight: _Flight, it: Optional[Dict[str, Any]],
               inflight: int = 0) -> None:
@@ -1940,6 +2071,8 @@ class ContinuousBatchingEngine:
             it["discarded"] += discarded
             it.update(live=flight.live, live_rows=flight.live_rows,
                       inflight=inflight)
+            if flight.live_rows_window is not None:
+                it["live_rows_window"] = flight.live_rows_window
 
     def _spec_tokens(self, drafts: Dict[int, List[int]]) -> np.ndarray:
         """The widened verify tick's input: [last_token, draft...] per

@@ -64,6 +64,16 @@ def _model_fns(config):
         # models/deepseek_v2.py
         return (deepseek_v2_forward_cached, deepseek_v2_init_cache,
                 deepseek_v2_decode)
+    from .smallthinker import (SmallThinkerConfig, smallthinker_decode,
+                               smallthinker_forward_cached,
+                               smallthinker_init_cache)
+
+    if isinstance(config, SmallThinkerConfig):
+        # keys and values at TWO row counts, the shorter a ring: module
+        # docstring of models/smallthinker.py, and the engine's fourth
+        # kind of entry
+        return (smallthinker_forward_cached, smallthinker_init_cache,
+                smallthinker_decode)
     raise TypeError(f"no generation support for {type(config).__name__}")
 
 

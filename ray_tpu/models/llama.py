@@ -137,7 +137,9 @@ def _cache_attention(q: jax.Array, ck: jax.Array, cv: jax.Array,
     lies, ck/cv [B, S, n_kv, hd] in their own dtype; query (b, j) sees
     rows <= positions[b, j]. Heads are contracted per kv group: head h
     is (g, r) = (h // rep, h % rep), the order jnp.repeat(axis=2) gave,
-    so `wo` sees the same columns. Returns [B, t, d_model]."""
+    so `wo` sees the same columns. Returns [B, t, n_heads hd] (d_model
+    here; `models/smallthinker.py`, whose heads do not add up to its
+    hidden size, calls this too)."""
     b, t = q.shape[0], q.shape[1]
     rep = c.num_heads // c.num_kv_heads
     qg = q.reshape(b, t, c.num_kv_heads, rep, c.head_dim)
@@ -149,7 +151,7 @@ def _cache_attention(q: jax.Array, ck: jax.Array, cv: jax.Array,
     scores = jnp.where(visible, scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
     a = jnp.einsum("bgrts,bsgd->btgrd", probs, cv)
-    return a.reshape(b, t, c.d_model)
+    return a.reshape(b, t, c.num_heads * c.head_dim)
 
 
 def _mlp_res(x: jax.Array, p: Params) -> jax.Array:

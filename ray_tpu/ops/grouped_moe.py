@@ -33,7 +33,7 @@ see, its buffer's rows, the experts held and the weights' shape, and
 records the choice in `ops/dispatch` under `grouped_product`
 (`STREAM_ROWS_AN_EXPERT` says where the number comes from).
 
-Three callers, three expert shapes: `models/nemotron_h.py` (experts in
+Four callers, three expert shapes: `models/nemotron_h.py` (experts in
 a latent space, `w1` [held, L, I] under relu squared),
 `models/kimi_linear.py` (SwiGLU experts at full hidden width: gate and up
 packed in ONE `w1` [held, D, 2 I], and an `activation` that maps the
@@ -46,7 +46,11 @@ keeps the rows. Two routers over sigmoid scores share
 `sigmoid_topk_route` (the bias chooses); the third,
 `softmax_group_limited_route`, scores by softmax and lets only the best
 GROUPS of experts compete, as a deployment that keeps a group on a chip
-does.
+does; the fourth, `softmax_topk_route`, is the plain one: the k largest
+logits, weighed by their softmax over the chosen alone
+(`models/smallthinker.py`: every expert held, `w1` [64, 2560, 1536] under
+`relu(gate) * up`, and a router that reads the layer's input, so its
+choice and the sort below do not wait for the layer's attention).
 """
 from __future__ import annotations
 
@@ -131,6 +135,18 @@ def softmax_group_limited_route(h: jax.Array, w_router: jax.Array, k: int,
     masked = jnp.where(keep[:, :, None], grouped, 0.0).reshape(t, e)
     weights, chosen = jax.lax.top_k(masked, k)
     return chosen.astype(jnp.int32), weights * scale
+
+
+def softmax_topk_route(h: jax.Array, w_router: jax.Array, k: int
+                       ) -> Tuple[jax.Array, jax.Array]:
+    """The k largest logits in float32 over every expert, weighed by their
+    softmax over the chosen k: a softmax over ALL experts renormalised
+    over the chosen is the same numbers. h [T, D], w_router [D, E] ->
+    (experts [T, k] int32, weights [T, k] float32)."""
+    logits = jnp.dot(h.astype(F32), w_router.astype(F32),
+                     precision=jax.lax.Precision.HIGHEST)
+    top, chosen = jax.lax.top_k(logits, k)
+    return chosen.astype(jnp.int32), jax.nn.softmax(top, axis=-1)
 
 
 # ------------------------------------------- the streamed grouped product

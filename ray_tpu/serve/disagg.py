@@ -394,7 +394,8 @@ class PrefillServer:
                  lora_rank_max: Optional[int] = None,
                  kvplane: Optional[bool] = None,
                  kvplane_arena_bytes: Optional[int] = None):
-        from ray_tpu.models.engine import LATENT_ONLY, latent_only
+        from ray_tpu.models.engine import (LATENT_ONLY, RING, latent_only,
+                                           ring_rows)
         from ray_tpu.models.generate import _model_fns
         from ray_tpu.models.kvcache import (PagedKVCache,
                                             kv_int8_default,
@@ -423,12 +424,21 @@ class PrefillServer:
         if kv_int8 is None:
             kv_int8 = kv_int8_default()
         self.kv_int8 = bool(kv_int8)
-        probe = _model_fns(config)[1](config, 1, max_len=1)
+        import jax
+
+        # the family's cache as shapes alone: nothing is allocated
+        probe = jax.eval_shape(lambda: _model_fns(config)[1](config, 1))
         if latent_only(probe):
             raise ValueError(
                 LATENT_ONLY + "a transfer carries ck and cv rows in "
                 "pairs and the prefill tier's pool commits them side by "
                 "side, so it cannot be served disaggregated "
+                "(engine.adopt_prefill refuses it too)")
+        if ring_rows(probe, config.max_seq_len):
+            raise ValueError(
+                RING + "a transfer carries ONE stack of ck and cv rows of "
+                "the prompt's length and the prefill tier's pool has one "
+                "block shape, so it cannot be served disaggregated "
                 "(engine.adopt_prefill refuses it too)")
         block_size, pool_blocks = resolve_pool_config(
             config, kv_block_size, kv_pool_blocks, int8=self.kv_int8)
@@ -468,7 +478,7 @@ class PrefillServer:
                 "this family's slots own recurrent state: a transfer "
                 "carries ck/cv rows only, so it cannot be served "
                 "disaggregated (engine.adopt_prefill refuses it too)")
-        shape = probe[0]["k"].shape  # [1, 1, H, hd]
+        shape = probe[0]["k"].shape  # [1, rows, H, hd]
         self._empty_prefix = jnp.zeros(
             (len(probe), 0) + shape[2:], probe[0]["k"].dtype)
         # retention bounds how many unacked transfers this server keeps
