@@ -621,11 +621,16 @@ def _prefill_body(params, suffix, config, prefix_k, prefix_v):
 def _prefill_paged(params, suffix, config, prefix_k, prefix_v):
     """Prefill a single sequence's SUFFIX on top of a cached prefix
     ([L, c, H, hd]; c=0 is the full-prefill program, and the only one a
-    family with state has). The window is the full max_seq_len slab —
-    the same reduction shapes as generate()'s prefill, so cached and
-    uncached paths stay bit-identical. Returns (last logits, ck, cv,
-    state, counters): `_prefill_body`. One compile per distinct (cached, suffix)
-    length pair."""
+    family with state has). The cache is the full max_seq_len slab and
+    the family's `forward_cached` is generate()'s prefill, so an engine
+    and generate() run one program over a prompt. A cached prefix and
+    none need NOT share reduction shapes: a family may attend over the
+    run alone at c=0 and over the slab on top of a prefix
+    (`models/llama.py` for a prompt over one block of its prompt form),
+    so a replay through the prefix cache can let a near-tie fall the
+    other way. Returns (last logits, ck, cv, state, counters):
+    `_prefill_body`. One compile per distinct (cached, suffix) length
+    pair."""
     return _prefill_body(params, suffix, config, prefix_k, prefix_v)
 
 

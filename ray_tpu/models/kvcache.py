@@ -41,6 +41,11 @@ recompute (same absolute RoPE positions, same window length, and masked
 softmax contributes exact zeros for unwritten rows), and because a
 weight swap invalidates the whole index — stale-generation KV is never
 matched again (in-flight slots keep decoding off their own slab copy).
+It holds to the bit while a family's prefill has ONE form: `models/llama.py`
+attends over the run alone for a prompt of more than one block of its
+prompt form, so there a suffix on top of cached rows and a prefill of the
+whole prompt agree to rounding, and a near-tie of two logits may fall the
+other way.
 
 **int8 blocks** (``RAY_TPU_KV_INT8=1`` or the ``int8=`` ctor arg): the
 pool stores K/V as int8 with per-block-CHANNEL fp32 scales (amax over

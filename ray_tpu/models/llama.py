@@ -11,7 +11,19 @@ GQA + tp note: num_kv_heads must divide by the tp degree in use (as in
 every tp Llama deployment). The training block repeats kv heads to query
 heads for the flash kernel; the cache paths contract per kv group
 (`_cache_attention`), because a repeat of the whole KV slab IS an HBM
-copy: XLA cannot fuse it into the reduction that reads it."""
+copy: XLA cannot fuse it into the reduction that reads it.
+
+A prefill takes one of two forms, chosen from what `llama_forward_cached`
+is given (`_is_prompt`): a run of more than `_PROMPT_BLOCK` tokens from a
+concrete position 0 attends over its OWN keys and values through the
+prompt form (`ops/swa.prompt_attention`: no [H, T, S] scores, nothing
+read of the slab's empty rows); every other run (a suffix on top of a
+cached prefix, a prompt of at most one block, the tick) attends over the
+slab. The cache comes back the same either way, so the two forms differ
+in their reduction shapes alone: a prompt replayed through a cached
+prefix meets the other program, and a near-tie of two logits may fall
+the other way. `ops/dispatch.kernel_choices("gqa_prefill")` lists the
+shapes that took the prompt form."""
 from __future__ import annotations
 
 from dataclasses import dataclass
@@ -24,6 +36,7 @@ from jax.sharding import PartitionSpec as P
 from ..ops.attention import flash_attention
 from ..ops.layers import rms_norm
 from ..ops.rope import apply_rope, rope_table
+from ..ops.swa import prompt_attention
 
 Params = Dict[str, Any]
 
@@ -154,6 +167,24 @@ def _cache_attention(q: jax.Array, ck: jax.Array, cv: jax.Array,
     return a.reshape(b, t, c.num_heads * c.head_dim)
 
 
+# the prompt form's block: the kernel's default and the one measured
+# (PERF.md, PR 37 and PR 38). A run of at most one block has no blocks to
+# skip, and the tiny models' prompts keep the slab form's numbers.
+_PROMPT_BLOCK = 512
+
+
+def _is_prompt(pos: Any, t: int) -> bool:
+    """Whether `t` tokens at `pos` are a prompt the prompt form takes:
+    longer than one block, from a position 0 that is known while tracing
+    (a traced `pos`, as a decode scan carries, is not)."""
+    if t <= _PROMPT_BLOCK:
+        return False
+    try:
+        return int(pos) == 0
+    except TypeError:       # a tracer
+        return False
+
+
 def _mlp_res(x: jax.Array, p: Params) -> jax.Array:
     h = rms_norm(x, p["ffn_norm"]["scale"])
     gate = jax.nn.silu(_mm(h, p["mlp"]["w_gate"]).astype(jnp.float32))
@@ -184,7 +215,10 @@ def llama_block_cached(x: jax.Array, p: Params, cos: jax.Array,
     truncation, the standard fixed-shape TPU decode layout. Heads are
     contracted per kv group against the cache as it lies
     (`_cache_attention`); `_repeat_kv` remains for `llama_block` alone,
-    whose flash kernel wants equal head counts.
+    whose flash kernel wants equal head counts. A prompt from position
+    0 (`_is_prompt`) attends over its own rows alone, as the cache holds
+    them: what `_cache_attention` would see of the slab, without the
+    rows past the run.
     Returns (x, new_cache_for_this_block)."""
     c = config
     b, t, _ = x.shape
@@ -193,13 +227,18 @@ def llama_block_cached(x: jax.Array, p: Params, cos: jax.Array,
     positions = jnp.broadcast_to(pos + jnp.arange(t)[None, :], (b, t))
     q = apply_rope(q, cos, sin, positions)
     k = apply_rope(k, cos, sin, positions)
-    ck = jax.lax.dynamic_update_slice(
-        cache["k"], k.astype(cache["k"].dtype), (0, pos, 0, 0))
-    cv = jax.lax.dynamic_update_slice(
-        cache["v"], v.astype(cache["v"].dtype), (0, pos, 0, 0))
-    # decode t is tiny (1 for autoregressive steps): plain masked
-    # attention over the cache window — flash brings nothing at t=1
-    a = _cache_attention(q, ck, cv, positions, c)
+    k = k.astype(cache["k"].dtype)
+    v = v.astype(cache["v"].dtype)
+    ck = jax.lax.dynamic_update_slice(cache["k"], k, (0, pos, 0, 0))
+    cv = jax.lax.dynamic_update_slice(cache["v"], v, (0, pos, 0, 0))
+    if _is_prompt(pos, t):
+        a, _ = prompt_attention(q, k.astype(q.dtype), v.astype(q.dtype),
+                                None, _PROMPT_BLOCK)
+        a = a.reshape(b, t, c.num_heads * c.head_dim)
+    else:
+        # decode t is tiny (1 for autoregressive steps): plain masked
+        # attention over the cache window — flash brings nothing at t=1
+        a = _cache_attention(q, ck, cv, positions, c)
     x = x + _mm(a, p["attn"]["wo"])
     return _mlp_res(x, p), {"k": ck, "v": cv}
 
@@ -333,7 +372,8 @@ def llama_forward_cached(params: Params, tokens: jax.Array,
                          pos: jax.Array):
     """Append `tokens` [B, T] at position `pos` (scalar int32); returns
     (logits [B, T, padded_vocab] fp32, new_cache). pos=0 with the whole
-    prompt is prefill; T=1 afterwards is autoregressive decode."""
+    prompt is prefill (through the prompt form where `_is_prompt` says
+    so: module docstring); T=1 afterwards is autoregressive decode."""
     c = config
     cos, sin = rope_table(c.head_dim, c.max_seq_len, c.rope_theta)
     x = params["tok_emb"][tokens]

@@ -219,9 +219,13 @@ def _prefill_kernel(q_ref, k_ref, v_ref, o_ref, *, block: int,
     o_ref[...] = (acc / l).astype(o_ref.dtype)
 
 
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7))
 def _prefill_pallas(q, k, v, block: int, window: Optional[int],
                     tokens: int, visited: int, interpret: bool
                     ) -> jax.Array:
+    """Jitted on its own so that the layers of a program, which call it
+    at one shape, share ONE lowering of the kernel (each costs a model's
+    set-up some 30 ms, a cache hit or not: PERF.md, PR 38)."""
     bg, nb, rows, d = q.shape
     t = k.shape[1]
     per_head = pl.BlockSpec((None, t, d), lambda g, i: (g, 0, 0))
