@@ -24,7 +24,11 @@ the engine's fourth kind of entry, a RING shorter than `max_seq_len`
 beside the global layers' full-length entries in one slab, so the engine
 builds it no prefix pool and refuses it adoption, speculation and a LoRA
 pool: most layers have forgotten what a block-aligned prefix would
-resume).
+resume), and Jamba (`jamba.py`: layers of two sublayers, a Mamba-1 or
+multi-query attention mixer and a dense SwiGLU; a slot owns a
+per-channel float32 state [16, 5120] and a convolution tail a Mamba
+layer, stacked by RUN of layers, beside one key-value head's rows; the
+head is the embedding; refused what Nemotron-H is).
 `moe_transformer.py` trains and is not served.
 """
 from .gpt2 import (  # noqa: F401
@@ -73,6 +77,13 @@ from .smallthinker import (  # noqa: F401
     smallthinker_init,
     smallthinker_loss,
     smallthinker_partition_specs,
+)
+from .jamba import (  # noqa: F401
+    JambaConfig,
+    jamba_forward,
+    jamba_init,
+    jamba_loss,
+    jamba_partition_specs,
 )
 from .moe_transformer import (  # noqa: F401
     MoEConfig,

@@ -200,7 +200,9 @@ names (models/deepseek_v2.py: ``moe_pairs_held`` and ``moe_rows_max``,
 what the grouped products saw over the prompt, and ``attn_blocks``, the
 blocks of scores the prompt form computed; models/smallthinker.py also
 ``attn_blocks_causal``, what a causal walk with no window would have
-visited; ``kv_stats()`` holds their totals as ``prefill_counters``); an
+visited; models/jamba.py ``scan_blocks``, the blocks of tokens the
+selective scan walked over all Mamba layers; ``kv_stats()`` holds their
+totals as ``prefill_counters``); an
 adoption has ``prefill_ms`` 0);
 ``dispatch_ms`` (inside ``_launch``, every launch of the pass: the
 lookahead's dispatch, before it ``_set_rows`` in a pass that follows an
@@ -1044,7 +1046,7 @@ class ContinuousBatchingEngine:
                  kv_int8: Optional[bool] = None):
         # config: any family _model_fns knows (LlamaConfig, GPT2Config,
         # NemotronHConfig, KimiLinearConfig, DeepseekV2Config,
-        # SmallThinkerConfig)
+        # SmallThinkerConfig, JambaConfig)
         self.params = params
         self.config = config
         self.max_batch = max_batch
@@ -1593,6 +1595,8 @@ class ContinuousBatchingEngine:
             grouped_product=dispatch.kernel_choices("grouped_product"),
             # and of the grouped-query prompt form (ops/swa.py)
             gqa_prefill=dispatch.kernel_choices("gqa_prefill"),
+            # and of the Mamba-1 selective scan (ops/mamba1.py)
+            selective_scan=dispatch.kernel_choices("selective_scan"),
         )
         s.update(self.speculation_stats())
         if self.kv_cache is None:

@@ -74,6 +74,13 @@ def _model_fns(config):
         # kind of entry
         return (smallthinker_forward_cached, smallthinker_init_cache,
                 smallthinker_decode)
+    from .jamba import (JambaConfig, jamba_decode, jamba_forward_cached,
+                        jamba_init_cache)
+
+    if isinstance(config, JambaConfig):
+        # Mamba-1 state a RUN of layers beside ONE key-value head's rows:
+        # module docstring of models/jamba.py
+        return jamba_forward_cached, jamba_init_cache, jamba_decode
     raise TypeError(f"no generation support for {type(config).__name__}")
 
 

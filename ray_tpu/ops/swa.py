@@ -46,10 +46,24 @@ from . import dispatch
 F32 = jnp.float32
 _NEG = -1e30
 _LOG2E = 1.4426950408889634
-# one head's keys and values stand in VMEM twice (the next head's arrive
-# under the compute): 16 MB at 16,384 tokens of 128 numbers; a block's
-# scores for seven stacked heads are 7 MB in float32, of a v5e's 128
+# what the kernel holds of a v5e's 128 MB of VMEM: one head's keys and
+# values twice (the next head's arrive under the compute), 16 MB at 16,384
+# tokens of 128 numbers and 34 MB at 32,768; a block of queries and its
+# output twice, the float32 accumulator, and the block's scores
 _VMEM_LIMIT = 100 << 20
+# the float32 scores of one block of queries against one key block, `rep x
+# block` rows of `block`: 7 MB for seven stacked heads at 512, the most
+# measured (PERF.md, PR 37); `_fit_block` keeps a wider group under it
+_SCORE_BYTES = 8 << 20
+
+
+def _fit_block(block: int, rep: int) -> int:
+    """The caller's block, halved (not under 128) until the scores of
+    `rep` stacked query heads fit `_SCORE_BYTES`: 512 stays 512 for a
+    group of 4 or 7 (and 8), a group of 20 takes 256."""
+    while block > 128 and 4 * rep * block * block > _SCORE_BYTES:
+        block //= 2
+    return block
 
 
 def _first_block(i, block: int, window: Optional[int]):
@@ -262,9 +276,12 @@ def prompt_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     G, d], head h of key-value head h // (H / G). Returns ([B, T, H, d] in
     q's dtype, the blocks of scores one head's walk visited). A prompt
     that is not a whole number of blocks is padded with rows no real
-    query sees; a window no shorter than the prompt is no window."""
+    query sees; a window no shorter than the prompt is no window. `block`
+    is the most a block of tokens may be: a group too wide for it takes a
+    smaller one (`_fit_block`)."""
     b, t, h, d = q.shape
     groups = k.shape[2]
+    block = _fit_block(block, h // groups)
     if window is not None and window >= t:
         window = None
     if t <= block:

@@ -88,6 +88,41 @@ def test_the_walks_at_the_served_lengths_by_hand():
     assert (3 * 243 + 496) / (4 * 496) == pytest.approx(0.617, abs=5e-4)
 
 
+@pytest.mark.parametrize("rep,block", [(4, 512), (7, 512), (8, 512),
+                                       (20, 256), (64, 128), (512, 128)])
+def test_the_block_follows_from_the_groups_size(rep, block):
+    """Mistral's group of 4 and SmallThinker's of 7 keep the 512 their
+    cells were measured at; Jamba's of 20 takes 256: 5,120 stacked rows
+    and 5.2 MB of float32 scores a key block, under SmallThinker's 7.3."""
+    assert swa._fit_block(512, rep) == block
+    assert 4 * rep * block * block <= swa._SCORE_BYTES or block == 128
+    assert swa._fit_block(8, rep) == 8      # a caller's smaller block stands
+
+
+def test_one_key_value_head_under_twenty_query_heads():
+    """Multi-query attention at Jamba's group through the prompt form, a
+    length that ends in a ragged block: the kernel in interpret mode, its
+    blocks and the dense softmax. The fitted block is in the walk: 600
+    tokens at 256 are three blocks, 6 visited (512 would give 3)."""
+    q, k, v = _qkv(600, seed=3, heads=20, groups=1, d=32)
+    q, k, v = q[:1], k[:1], v[:1]
+    want = swa.plain_attention(q, k, v)
+    dispatch.reset_kernel_choices()
+    blocks, visited = swa.prompt_attention(q, k, v)
+    assert dispatch.kernel_choices("gqa_prefill")[0]["choice"] == "reference"
+    with dispatch.pallas_interpret():
+        kernel, n_kernel = swa.prompt_attention(q, k, v)
+    assert visited == n_kernel == 6
+    choice = dispatch.kernel_choices("gqa_prefill")[0]
+    assert choice["choice"] == "pallas" \
+        and tuple(choice["shape"]) == (1, 600, 20, 1, 32, 0)
+    np.testing.assert_allclose(blocks, want, atol=2e-6, rtol=0)
+    np.testing.assert_allclose(kernel, want, atol=2e-6, rtol=0)
+    np.testing.assert_allclose(
+        kernel, _dense(np.asarray(q), np.asarray(k), np.asarray(v), None),
+        atol=2e-6, rtol=0)
+
+
 def test_the_blocks_can_be_differentiated():
     q, k, v = _qkv(24)
     grad = jax.grad(lambda x: swa.prompt_attention(x, k, v, W, BLOCK)[0]
