@@ -117,7 +117,8 @@ transfer and adoption see what they saw), a ring's stack as the ring
 would lie after the prompt (the last `min(plen, rows)` positions, each at
 `p mod rows`); `_splice_slot` writes rows `[0, min(plen, rows))` of each
 entry, O(rows held) and in place; a dead slot's position stands still
-(`_chosen`), so its ring row does too; the loop's record of a tick gains
+at 0 (`_chosen`, `_finish`), so its ring row does too, at row 0; the
+loop's record of a tick gains
 ``live_rows_window`` (the sum over the live slots of `min(position,
 rows)` for the shortest row count: what of the rings the tick had a
 reason to read) and ``kv_stats()["slab"]`` says, for each row count, the
@@ -156,7 +157,14 @@ overwritten. A dead slot is HELD inside the program (`_chosen`: its
 token comes back as it went in, its position stands still), so the
 scatter and the position lookup of every family see for it what they
 saw when the host uploaded its mirror every tick, and no position runs
-past the window. A weight swap holds from the next LAUNCH: the tick in
+past the window. It stands still AT 0: `_finish` parks the host's
+mirror there, and the `_set_rows` that tells the chip of the death
+carries the position with it. The tick's attention walks each slot's
+rows up to its position (`ops/swa.decode_attention`), so a slot left
+where its last request ended would have that request's rows read tick
+after tick for nobody; parked, it costs one block, and its scatter lands
+in row 0, which the next `_splice_slot` overwrites (a ring's row 0
+likewise). A weight swap holds from the next LAUNCH: the tick in
 flight finishes on the weights it was launched with. The depth is what
 the engine can see, not a knob: a pass that carries drafts needs the
 host's tokens to draft from, so a speculating engine reads the tick in
@@ -221,7 +229,12 @@ because their request had finished by EOS or been cancelled since the
 launch); ``total_ms`` (the whole pass; what the parts leave is
 bookkeeping: swap, cancels, drafting, telemetry push). ``live`` (the
 slots the tick decoded for), ``live_rows`` (the sum of their positions:
-the cache rows the tick had a reason to read), where the slab holds a
+the cache rows the tick had a reason to read), ``slab_rows_read`` (the
+rows one layer's walk over the slab's longest entries visits, ALL slots
+at the positions the tick was launched with: whole blocks, a dead slot's
+one block, by the function the kernel's walk uses,
+`ops/swa.decode_rows_read`; ``max_batch`` x rows for a family whose tick
+reads every row, `tick_walk_block`), where the slab holds a
 ring ``live_rows_window`` (above) and, where the family's
 decode hands back counters of the step, those under their own names
 (``moe_pairs_held``, token-expert pairs that fell on experts held here,
@@ -324,6 +337,7 @@ import numpy as np
 
 from ray_tpu.observability import requests as reqtrace
 from ray_tpu.ops import dispatch
+from ray_tpu.ops.swa import decode_rows_read
 from ray_tpu.util.profiling import name_thread
 
 from .generate import _model_fns, merge_lora_params
@@ -564,6 +578,32 @@ def ring_rows(cache, max_seq_len: int) -> Optional[int]:
     return shortest if shortest < max_seq_len else None
 
 
+_TICK_WALKS: Dict[tuple, Optional[int]] = {}
+
+
+def tick_walk_block(params, config, cache) -> Optional[int]:
+    """The block of rows by which a tick of this family walks the
+    slab's longest entries (`ops/swa.decode_attention`), None where its
+    tick reads every row of every slot. Learnt, once a config and batch,
+    from the program itself and never from a family's name: the family's
+    decode is traced over shapes alone and `ops/dispatch` asked what the
+    decode form recorded for an entry of this batch and these rows."""
+    rows = max(_row_counts(cache), default=0)
+    batch = jax.tree.leaves(cache)[0].shape[0]
+    key = (config, batch, rows)
+    if key not in _TICK_WALKS:
+        vec = jax.ShapeDtypeStruct((batch,), jnp.int32)
+        jax.eval_shape(
+            lambda p, c, tok, pos: _model_fns(config)[2](p, tok, config, c,
+                                                        pos),
+            params, cache, vec, vec)
+        _TICK_WALKS[key] = next(
+            (e["block"] for e in dispatch.kernel_choices("gqa_decode")
+             if e["shape"][:2] == (batch, 1) and e["shape"][5] == rows),
+            None)
+    return _TICK_WALKS[key]
+
+
 def _prefill_body(params, suffix, config, prefix_k, prefix_v):
     """What `_prefill_paged` and `_prefill_paged_lora` trace. The
     family's single-sequence cache (`init_cache(config, 1)`) is laid out
@@ -788,9 +828,10 @@ def _chosen(logits, config, tokens, pos_vec, live):
     each slot, its log-probability, and the position vector advanced, so
     that a tick's outputs are, as they lie, the next tick's inputs.
     `live` [B] (int32, 1 or 0) HOLDS a dead slot: its token comes back
-    as it went in and its position stands still, so a slot nobody
-    decodes for feeds the program the same row tick after tick, never a
-    position past the window. None advances every slot."""
+    as it went in and its position stands still (at 0, where `_finish`
+    parked it), so a slot nobody decodes for feeds the program the same
+    row tick after tick, never a position past the window. None
+    advances every slot."""
     lv = logits[..., :config.vocab_size].astype(jnp.float32)
     nxt = jnp.argmax(lv, axis=-1).astype(jnp.int32)
     # per-slot logprob of the chosen (greedy = max-logit) token — the
@@ -867,7 +908,8 @@ class _Flight(NamedTuple):
     or one whose budget ends with the tick ahead of this one); `live`
     and `live_rows` are their count and the sum of their positions,
     `live_rows_window` the sum of `min(position, rows)` for the slab's
-    ring (None without one); `seq` is the ledger's count of launches
+    ring (None without one), `slab_rows_read` the rows one layer's walk
+    reads over ALL slots; `seq` is the ledger's count of launches
     with this tick the newest."""
 
     nxt: Any
@@ -877,6 +919,7 @@ class _Flight(NamedTuple):
     live: int
     live_rows: int
     live_rows_window: Optional[int]
+    slab_rows_read: int
     drafts: Optional[Dict[int, List[int]]]
     seq: int
 
@@ -1082,6 +1125,10 @@ class ContinuousBatchingEngine:
         # the rows of the slab's ring, where a sequence entry is shorter
         # than the window (module docstring); None for a slab without one
         self.ring_rows = ring_rows(self._cache, config.max_seq_len)
+        # the rows of the slab's longest entries, and the block by which
+        # the tick's attention walks them (None: it reads them all)
+        self._slab_rows = max(_row_counts(self._cache), default=0)
+        self._walk_block = tick_walk_block(params, config, self._cache)
         if speculate_k is None:
             speculate_k = default_speculate_k()
         if self.stateful:
@@ -1595,6 +1642,8 @@ class ContinuousBatchingEngine:
             grouped_product=dispatch.kernel_choices("grouped_product"),
             # and of the grouped-query prompt form (ops/swa.py)
             gqa_prefill=dispatch.kernel_choices("gqa_prefill"),
+            # and of the decode form, shapes (B, t, H, G, d, S)
+            gqa_decode=dispatch.kernel_choices("gqa_decode"),
             # and of the Mamba-1 selective scan (ops/mamba1.py)
             selective_scan=dispatch.kernel_choices("selective_scan"),
         )
@@ -1844,7 +1893,11 @@ class ContinuousBatchingEngine:
         if slot is not None:
             self._slot_req[slot] = None
             self._slot_adapter[slot] = 0
-            self._dirty[slot] = True  # dead from the next launch on
+            # dead from the next launch on, and PARKED: the same
+            # `_set_rows` puts its position at 0, so the tick's walk
+            # reads one block for it and not the dead request's rows
+            self._pos[slot] = 0
+            self._dirty[slot] = True
         if self.kv_cache is not None and req.block_table:
             self.kv_cache.release(req.block_table)
             req.block_table = []
@@ -1967,9 +2020,11 @@ class ContinuousBatchingEngine:
         rows = 0
         ring = self.ring_rows
         rows_window = 0 if ring else None
+        at = self._pos + (self.speculate_k if drafts else 0)
         for slot, req in enumerate(self._slot_req):
             ahead = int(behind is not None and req is not None
                         and behind.reqs[slot] is req)
+            at[slot] += ahead
             if req is None or req.produced + ahead >= req.max_new:
                 reqs.append(None)
             else:
@@ -1980,6 +2035,11 @@ class ContinuousBatchingEngine:
         live = sum(r is not None for r in reqs)
         if not live:
             return None
+        # what one layer's walk reads of the slab at the positions this
+        # tick is launched with, ALL slots: the dead ones' one block too
+        rows_read = self.max_batch * self._slab_rows \
+            if self._walk_block is None else decode_rows_read(
+                at, self._walk_block, self._slab_rows)
         gaps = self._gaps
         t0 = _clock(it)
         if gaps.empty_from is not None:
@@ -2019,7 +2079,7 @@ class ContinuousBatchingEngine:
         if it is not None:
             it["dispatch_ms"] += (_now() - t0) * 1e3
         return _Flight(nxt, lp, counts, reqs, live, rows, rows_window,
-                       drafts, gaps.launches)
+                       rows_read, drafts, gaps.launches)
 
     def _land(self, flight: _Flight, it: Optional[Dict[str, Any]],
               inflight: int = 0) -> None:
@@ -2079,6 +2139,7 @@ class ContinuousBatchingEngine:
             it["emit_ms"] += (t2 - t1) * 1e3
             it["discarded"] += discarded
             it.update(live=flight.live, live_rows=flight.live_rows,
+                      slab_rows_read=flight.slab_rows_read,
                       inflight=inflight)
             if flight.live_rows_window is not None:
                 it["live_rows_window"] = flight.live_rows_window

@@ -369,7 +369,7 @@ def _attn_run(x: jax.Array, p: Params, c: JambaConfig, cache: Params | None,
         else:
             positions = jnp.broadcast_to(pos + jnp.arange(t)[None, :],
                                          (b, t))
-            a = _cache_attention(q, cache["k"], cache["v"], positions, c)
+            a = _cache_attention(q, cache["k"], cache["v"], positions)
         return x + _attn_out(a, p), cache
 
 
@@ -381,7 +381,7 @@ def _attn_tick(x: jax.Array, p: Params, c: JambaConfig, cache: Params,
         at = (jnp.arange(x.shape[0])[:, None], positions)
         ck = cache["k"].at[at].set(k.astype(cache["k"].dtype))
         cv = cache["v"].at[at].set(v.astype(cache["v"].dtype))
-        a = _cache_attention(q, ck, cv, positions, c)
+        a = _cache_attention(q, ck, cv, positions)
         return x + _attn_out(a, p), {"k": ck, "v": cv}
 
 

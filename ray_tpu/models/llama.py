@@ -13,17 +13,24 @@ heads for the flash kernel; the cache paths contract per kv group
 (`_cache_attention`), because a repeat of the whole KV slab IS an HBM
 copy: XLA cannot fuse it into the reduction that reads it.
 
-A prefill takes one of two forms, chosen from what `llama_forward_cached`
-is given (`_is_prompt`): a run of more than `_PROMPT_BLOCK` tokens from a
-concrete position 0 attends over its OWN keys and values through the
-prompt form (`ops/swa.prompt_attention`: no [H, T, S] scores, nothing
-read of the slab's empty rows); every other run (a suffix on top of a
-cached prefix, a prompt of at most one block, the tick) attends over the
-slab. The cache comes back the same either way, so the two forms differ
-in their reduction shapes alone: a prompt replayed through a cached
-prefix meets the other program, and a near-tie of two logits may fall
-the other way. `ops/dispatch.kernel_choices("gqa_prefill")` lists the
-shapes that took the prompt form."""
+A run of tokens attends in one of THREE forms, chosen from what
+`llama_forward_cached` and `llama_decode` are given, shapes and a
+concrete position alone. A run of more than `_PROMPT_BLOCK` tokens from
+a concrete position 0 (`_is_prompt`) attends over its OWN keys and
+values through the prompt form (`ops/swa.prompt_attention`: no [H, T, S]
+scores, nothing read of the slab's empty rows). A run of at most
+`ops/swa.DECODE_ROWS` rows is a tick's (one token a slot, or the
+speculative verify's k + 1) and takes the decode form
+(`ops/swa.decode_attention`, through `_cache_attention`): each slot's
+rows up to its own position, block by block, and not the
+`max_batch x max_seq_len` rows of the slab. Every other run (a suffix
+on top of a cached prefix, a prompt of at most one block) attends over
+the whole slab, masked (`_slab_attention`). The cache comes back the
+same whichever ran, so the forms differ in their reduction shapes
+alone: a prompt replayed through a cached prefix meets another program,
+and a near-tie of two logits may fall the other way.
+`ops/dispatch.kernel_choices("gqa_prefill")` and `("gqa_decode")` list
+the shapes that took the prompt and the decode form."""
 from __future__ import annotations
 
 from dataclasses import dataclass
@@ -36,7 +43,7 @@ from jax.sharding import PartitionSpec as P
 from ..ops.attention import flash_attention
 from ..ops.layers import rms_norm
 from ..ops.rope import apply_rope, rope_table
-from ..ops.swa import prompt_attention
+from ..ops.swa import DECODE_ROWS, decode_attention, prompt_attention
 
 Params = Dict[str, Any]
 
@@ -145,26 +152,43 @@ def _repeat_kv(k: jax.Array, v: jax.Array, c: LlamaConfig):
 
 
 def _cache_attention(q: jax.Array, ck: jax.Array, cv: jax.Array,
-                     positions: jax.Array, c: LlamaConfig) -> jax.Array:
+                     positions: jax.Array) -> jax.Array:
     """Masked attention of q [B, t, n_heads, hd] over the cache as it
     lies, ck/cv [B, S, n_kv, hd] in their own dtype; query (b, j) sees
     rows <= positions[b, j]. Heads are contracted per kv group: head h
     is (g, r) = (h // rep, h % rep), the order jnp.repeat(axis=2) gave,
     so `wo` sees the same columns. Returns [B, t, n_heads hd] (d_model
     here; `models/smallthinker.py`, whose heads do not add up to its
-    hidden size, calls this too)."""
-    b, t = q.shape[0], q.shape[1]
-    rep = c.num_heads // c.num_kv_heads
-    qg = q.reshape(b, t, c.num_kv_heads, rep, c.head_dim)
+    hidden size, calls this too).
+
+    A run of at most `DECODE_ROWS` rows is a tick's (one token a slot,
+    or the speculative verify's k + 1) and takes the decode form
+    (`ops/swa.decode_attention`): each slot's rows up to its position,
+    block by block. A longer run (a suffix on a cached prefix, a prompt
+    of at most one block, an uncached forward) takes the slab form: its
+    [t, S] scores spread the slab's read over their rows, and nothing
+    says its slots are short. The choice reads `q`'s shape alone."""
+    if q.shape[1] <= DECODE_ROWS:
+        return decode_attention(q, ck, cv, positions)
+    return _slab_attention(q, ck, cv, positions)
+
+
+def _slab_attention(q: jax.Array, ck: jax.Array, cv: jax.Array,
+                    positions: jax.Array) -> jax.Array:
+    """`_cache_attention` over ALL rows of ALL slots: float32 scores of
+    every query against the whole entry, masked afterwards."""
+    b, t, heads, hd = q.shape
+    groups = ck.shape[2]
+    qg = q.reshape(b, t, groups, heads // groups, hd)
     scores = jnp.einsum("btgrd,bsgd->bgrts", qg, ck,
                         preferred_element_type=jnp.float32)
-    scores = scores / (c.head_dim ** 0.5)
+    scores = scores / (hd ** 0.5)
     col = jnp.arange(ck.shape[1])[None, None, None, None, :]
     visible = col <= positions[:, None, None, :, None]
     scores = jnp.where(visible, scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
     a = jnp.einsum("bgrts,bsgd->btgrd", probs, cv)
-    return a.reshape(b, t, c.num_heads * c.head_dim)
+    return a.reshape(b, t, heads * hd)
 
 
 # the prompt form's block: the kernel's default and the one measured
@@ -236,9 +260,9 @@ def llama_block_cached(x: jax.Array, p: Params, cos: jax.Array,
                                 None, _PROMPT_BLOCK)
         a = a.reshape(b, t, c.num_heads * c.head_dim)
     else:
-        # decode t is tiny (1 for autoregressive steps): plain masked
-        # attention over the cache window — flash brings nothing at t=1
-        a = _cache_attention(q, ck, cv, positions, c)
+        # a suffix or a short prompt over the whole slab, a step of
+        # `generate()` through the decode form: `_cache_attention`
+        a = _cache_attention(q, ck, cv, positions)
     x = x + _mm(a, p["attn"]["wo"])
     return _mlp_res(x, p), {"k": ck, "v": cv}
 
@@ -306,7 +330,7 @@ def llama_block_decode(x: jax.Array, p: Params, cos: jax.Array,
         k.astype(cache["k"].dtype))
     cv = cache["v"].at[rows[:, None], positions].set(
         v.astype(cache["v"].dtype))
-    a = _cache_attention(q, ck, cv, positions, c)
+    a = _cache_attention(q, ck, cv, positions)
     x = x + _mm(a, p["attn"]["wo"])
     return _mlp_res(x, p), {"k": ck, "v": cv}
 

@@ -45,7 +45,7 @@ from jax.sharding import PartitionSpec as P
 from ..ops.grouped_moe import held_experts, sigmoid_topk_route
 from ..ops.layers import rms_norm
 from ..ops.mamba2 import causal_conv, gated_group_norm, ssd_scan, ssd_step
-from .llama import _cache_attention, _mm
+from .llama import _cache_attention, _mm, _slab_attention
 
 Params = Dict[str, Any]
 F32 = jnp.float32
@@ -110,12 +110,6 @@ class NemotronHConfig:
     def conv_dim(self) -> int:
         return self.d_inner + 2 * self.n_groups * self.ssm_state_size
 
-    @property
-    def attn_view(self) -> "_AttnView":
-        """What `llama._cache_attention` reads of a config."""
-        return _AttnView(self.num_heads, self.num_kv_heads, self.head_dim,
-                         self.num_heads * self.head_dim)
-
     @staticmethod
     def tiny() -> "NemotronHConfig":  # tests / dry runs
         return NemotronHConfig(
@@ -125,14 +119,6 @@ class NemotronHConfig:
             head_dim=16, n_routed_experts=16, experts_held=4,
             num_experts_per_tok=3, moe_intermediate_size=32,
             moe_latent_size=32, moe_shared_expert_intermediate_size=64)
-
-
-@dataclass(frozen=True)
-class _AttnView:
-    num_heads: int
-    num_kv_heads: int
-    head_dim: int
-    d_model: int
 
 
 def _relu2(x: jax.Array) -> jax.Array:
@@ -265,7 +251,7 @@ def _attention(h: jax.Array, p: Params, c: NemotronHConfig, cache: Params,
     rows = jnp.arange(h.shape[0])[:, None]
     ck = cache["k"].at[rows, positions].set(k.astype(cache["k"].dtype))
     cv = cache["v"].at[rows, positions].set(v.astype(cache["v"].dtype))
-    a = _cache_attention(q, ck, cv, positions, c.attn_view)
+    a = _cache_attention(q, ck, cv, positions)
     return _mm(a, p["wo"]), {"k": ck, "v": cv}
 
 
@@ -281,7 +267,7 @@ def _attention_prefill(h: jax.Array, p: Params, c: NemotronHConfig,
     cv = jax.lax.dynamic_update_slice(
         cache["v"], v.astype(cache["v"].dtype), (0, pos, 0, 0))
     positions = jnp.broadcast_to(pos + jnp.arange(t)[None, :], (b, t))
-    a = _cache_attention(q, ck, cv, positions, c.attn_view)
+    a = _cache_attention(q, ck, cv, positions)
     return _mm(a, p["wo"]), {"k": ck, "v": cv}
 
 
@@ -290,7 +276,8 @@ def _attention_uncached(h: jax.Array, p: Params, c: NemotronHConfig
     b, t, _ = h.shape
     q, k, v = _qkv(h, p, c)
     positions = jnp.broadcast_to(jnp.arange(t)[None, :], (b, t))
-    return _mm(_cache_attention(q, k, v, positions, c.attn_view), p["wo"])
+    # no cache and no tick: the run over itself, which a loss differentiates
+    return _mm(_slab_attention(q, k, v, positions), p["wo"])
 
 
 def latent_moe(h: jax.Array, p: Params, c: NemotronHConfig

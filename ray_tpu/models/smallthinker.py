@@ -241,7 +241,7 @@ def _attn_decode(x: jax.Array, p: Params, c: SmallThinkerConfig,
         at = (jnp.arange(x.shape[0])[:, None], positions % rows)
         ck = cache["k"].at[at].set(k.astype(cache["k"].dtype))
         cv = cache["v"].at[at].set(v.astype(cache["v"].dtype))
-        a = _cache_attention(q, ck, cv, jnp.minimum(positions, rows - 1), c)
+        a = _cache_attention(q, ck, cv, jnp.minimum(positions, rows - 1))
         return _attn_out(a, p), {"k": ck, "v": cv}
 
 

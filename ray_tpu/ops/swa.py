@@ -1,7 +1,8 @@
-"""Prompt-form attention for grouped-query heads, over the whole prompt
-or under a sliding window: what a served model's prefill needs where
-`rep` query heads share one key-value head and a layer may see only the
-last `window` positions (`models/smallthinker.py`).
+"""Attention for grouped-query heads as a served model needs it, where
+`rep` query heads share one key-value head: the PROMPT form over a whole
+prompt or under a sliding window (a layer may see only the last `window`
+positions, `models/smallthinker.py`), and the DECODE form of a tick over
+the engine's slab.
 
 A module of its own beside `ops/mla.py`, whose pattern it follows, and
 not a third family in `ops/attention.py`: the flash kernels there are the
@@ -26,6 +27,37 @@ query heads stacked, and whose walk is a band.
             matrix unit is fed rep x block rows and a key block is read
             once for all of them). Elsewhere the same blocks in
             `jax.numpy`, which is also the kernel's reference.
+  decode    a tick's run (`decode_attention`: one token a slot, or the
+            speculative verify's k + 1) over the slab entry [B, S, G, d]
+            WHERE IT LIES: no transpose, no repeat, no copy; a block of
+            rows is all G heads, contiguous. Slot b's walk visits the
+            blocks 0 .. `positions[b, -1] // block` and no other
+            (`decode_blocks`), under a running softmax with float32
+            scores and accumulator, so a tick reads the rows its slots
+            hold and not `max_batch x max_seq_len`; a parked slot
+            (position 0: `models/engine.py` `_finish`) costs one block.
+            Row j of a run masks by its own position, and a block it
+            sees nothing of leaves its max, sum and accumulator untouched
+            to the bit: a verify row is a sequential tick's. On a TPU it
+            is the Pallas kernel `gqa_decode_t<t>`, ONE call a layer: the
+            positions scalar-prefetched, the queries resident, the walk
+            the kernel's own loops (slots, then a slot's blocks: a
+            dynamic trip count) over blocks it copies from HBM itself,
+            two copies ahead of the products (the next slots' first
+            blocks behind a slot's last, so 29 parked slots stream like
+            one long one). The entry is read as [B, S G, d], which on
+            the chip is the same bytes (as [B, S, G d] it is not: XLA
+            then copies the slab), and a slot's `t x H` query rows are
+            the rows of ONE product against a block's `block x G` key
+            rows, padded to whole sublanes in VMEM, never in HBM; a score
+            of another key-value head's key is masked like a row past
+            the position, so the matrix unit takes each key tile once for
+            all heads. The block
+            follows from the entry's shape (`_decode_block`). Elsewhere
+            the same blocks in `jax.numpy`, the kernel's reference.
+            `dispatch.kernel_choices("gqa_decode")` lists the shapes (B,
+            t, H, G, d, S) and which ran; `decode_rows_read` is the
+            host's count of the rows a walk reads.
 
 The window counts the query's own position: query i sees keys j with
 `i - window < j <= i`, so a ring of `window` rows holds exactly what a
@@ -38,6 +70,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -310,3 +343,301 @@ def prompt_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                               block, window, t, visited,
                               dispatch.interpret_forced())
     return _unstacked(out, b, block)[:, :t], visited
+
+
+# ---------------------------------------------------------------- decode
+# what one DMA of the decode walk moves of a slot's keys (and as much of
+# its values): `_decode_block` sizes a block of rows to it. Large enough
+# that a copy runs at the memory's rate and the loop's own cost a block
+# is small beside it, small enough that a parked slot's one block is
+# cheap (PERF.md, PR 41: the sweep)
+_DECODE_BLOCK_BYTES = 256 << 10
+# the columns of scores (key rows x key-value heads) one step of the
+# kernel holds in float32: a block of more is walked in chunks of this many
+_DECODE_CHUNK = 1024
+# blocks of keys (and of values) in VMEM: the copies run two ahead of the
+# products, which hides a copy's latency behind the block before it
+_DECODE_BUFFERS = 3
+# the most rows a run may have and still be a tick's: the speculative
+# verify's k + 1 at any k served. A longer run is a suffix or a prompt,
+# whose [t, S] scores pay for the slab's read with their own rows
+DECODE_ROWS = 8
+
+
+def _decode_block(rows: int, groups: int, d: int, itemsize: int) -> int:
+    """The rows of one block of the decode walk over a slab entry [B,
+    rows, groups, d]: the power of two whose keys fill
+    `_DECODE_BLOCK_BYTES`, not under 128 and not over the entry."""
+    block = 128
+    while 2 * block * groups * d * itemsize <= _DECODE_BLOCK_BYTES:
+        block *= 2
+    return min(block, rows)
+
+
+def decode_blocks(positions, block: int, rows: int):
+    """The blocks a slot's walk visits: from block 0 to the one that holds
+    the slot's last position (a position past the entry visits every
+    block and no more). Shared by the kernel, its reference and the
+    host's count (numpy or jax.numpy in, the same out)."""
+    return (positions // block + 1).clip(1, -(-rows // block))
+
+
+def decode_rows_read(positions, block: int, rows: int) -> int:
+    """The rows one layer's walk reads over ALL slots at these positions
+    (numpy, [B] or [B, t]: a slot's last position ends its walk): whole
+    blocks, a parked slot's one block."""
+    last = np.asarray(positions).reshape(len(positions), -1)[:, -1]
+    return int(decode_blocks(last, block, rows).sum()) * block
+
+
+def _block_start(j, block: int, rows: int):
+    """Where block `j` is read from: its own start, held inside an entry
+    whose rows are no whole number of blocks (the last block then reads
+    rows the one before it held, which `_block_seen` masks)."""
+    return jnp.minimum(j * block, rows - block)
+
+
+def _block_seen(q_at, k_at, j, block: int):
+    return (k_at <= q_at) & (k_at >= j * block)
+
+
+def _decode_walk(q, ck, cv, positions, block: int):
+    """The decode form's running softmax in `jax.numpy`, block by block
+    as the kernel walks: q [B, t, H, d], ck, cv [B, S, G, d], positions
+    [B, t] -> (max, sum, accumulator) [B, G, rep, t, .], float32. Every
+    slot steps through the most blocks any slot needs; a step past a
+    slot's last block leaves its carry as it was."""
+    b, t, h, d = q.shape
+    s_rows, g = ck.shape[1], ck.shape[2]
+    qg = q.reshape(b, t, g, h // g, d)
+    q_at = positions[:, None, None, :, None]
+    last = decode_blocks(positions[:, -1], block, s_rows)
+    scale = d ** -0.5
+
+    def step(j, carry):
+        m, l, acc = carry
+        at = _block_start(j, block, s_rows)
+        k_j = jax.lax.dynamic_slice_in_dim(ck, at, block, 1)
+        v_j = jax.lax.dynamic_slice_in_dim(cv, at, block, 1)
+        s = jnp.einsum("btgrd,bsgd->bgrts", qg, k_j,
+                       preferred_element_type=F32) * scale
+        k_at = at + jnp.arange(block)
+        s = jnp.where(_block_seen(q_at, k_at, j, block), s, _NEG)
+        m_new = jnp.maximum(m, s.max(-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m - m_new)
+        new = (m_new, alpha * l + p.sum(-1, keepdims=True),
+               acc * alpha + jnp.einsum(
+                   "bgrts,bsgd->bgrtd", p.astype(q.dtype), v_j,
+                   preferred_element_type=F32))
+        walks = (j < last)[:, None, None, None, None]
+        return jax.tree.map(lambda a, b_: jnp.where(walks, a, b_),
+                            new, carry)
+
+    lead = (b, g, h // g, t)
+    init = (jnp.full(lead + (1,), _NEG, F32), jnp.zeros(lead + (1,), F32),
+            jnp.zeros(lead + (d,), F32))
+    return jax.lax.fori_loop(0, jnp.max(last), step, init)
+
+
+def _decode_blocked(q, ck, cv, positions, block: int) -> jax.Array:
+    b, t, h, d = q.shape
+    _, l, acc = _decode_walk(q, ck, cv, positions, block)
+    return (acc / l).astype(q.dtype).transpose(0, 3, 1, 2, 4).reshape(
+        b, t, h * d)
+
+
+def _decode_kernel(pos_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem,
+                   q_s, m_s, l_s, acc_s, *, block: int, chunk: int,
+                   groups: int, t: int):
+    """The whole tick's walk, one slot after another. Refs: pos [B, t] in
+    SMEM; q [B, t x H, d], every slot's query heads as the model has
+    them, resident (a tick's queries are a few hundred KB); k, v [B, S x
+    G, d], the slab entry as it lies in HBM with rows and key-value heads
+    read as ONE axis (on the chip [B, S, G, d] and [B, S G, d] are the
+    same bytes; [B, S, G d] is not, and costs a copy of the slab); o as
+    q. Scratch: `kbuf.shape[0]` blocks of keys and as many of values with
+    their DMA semaphores, one slot's queries scaled and padded to whole
+    sublanes, their running max, sum and accumulator.
+
+    ALL heads of a slot are the rows of one product against a block of
+    `block x G` key rows, and a score whose key row belongs to another
+    key-value head than its query's is masked like a row past the
+    position: the matrix unit takes each key tile ONCE for the 32 rows,
+    where a product a key-value head would take a strided eighth of the
+    block for 4. The walk is the kernel's own loop: slot b takes
+    `positions[b, -1] // block + 1` steps, so a block past the slot's
+    last costs neither a copy nor a product. The blocks of ALL slots are
+    one sequence of copies that runs `buffers - 1` ahead of the
+    products, over a slot's end into the next slot's first blocks, so
+    that a slab of parked slots streams like one long slot. The scale
+    and log2(e) are folded into the queries; products take the cache's
+    dtype (or the queries', the wider) and accumulate in float32."""
+    slots, rows, d = q_ref.shape
+    buffers = kbuf.shape[0]
+    padded = q_s.shape[0]
+    s_rows = k_hbm.shape[1] // groups
+    cd = jnp.promote_types(q_ref.dtype, kbuf.dtype)
+    heads = rows // t
+    rep = heads // groups
+
+    def copies(slot, j, buf):
+        at = pl.ds(pl.multiple_of(
+            _block_start(j, block, s_rows) * groups, 8), block * groups)
+        return (pltpu.make_async_copy(k_hbm.at[slot, at, :], kbuf.at[buf],
+                                      sem.at[0, buf]),
+                pltpu.make_async_copy(v_hbm.at[slot, at, :], vbuf.at[buf],
+                                      sem.at[1, buf]))
+
+    def start(slot, j, buf):
+        @pl.when(slot < slots)
+        def _():
+            for c in copies(slot, j, buf):
+                c.start()
+
+    def blocks_of(slot):
+        return decode_blocks(pos_ref[jnp.minimum(slot, slots - 1), t - 1],
+                             block, s_rows)
+
+    def after(slot, j):
+        """The block that follows block `j` of `slot` in the sequence."""
+        more = j + 1 < blocks_of(slot)
+        return jnp.where(more, slot, slot + 1), jnp.where(more, j + 1, 0)
+
+    ahead = (jnp.int32(0), jnp.int32(0))
+    for buf in range(buffers - 1):
+        start(*ahead, buf)
+        ahead = after(*ahead)
+    # row r of a slot is head r mod H of query r // H of the run (the
+    # padding rows go with the last query); its key-value head is
+    # (r mod H) // rep. Small static counts, so comparisons, no division
+    row = jax.lax.broadcasted_iota(jnp.int32, (padded, 1), 0)
+    query = sum((row >= i * heads).astype(jnp.int32) for i in range(1, t))
+    head = row - query * heads
+    g_row = sum((head >= g * rep).astype(jnp.int32)
+                for g in range(1, groups))
+    # column c of a chunk is key-value head c mod G of key row c // G
+    col = jax.lax.broadcasted_iota(jnp.int32, (padded, chunk * groups), 1)
+    mine = (col % groups) == g_row
+    k_in = col // groups
+    q_s[...] = jnp.zeros(q_s.shape, q_s.dtype)
+
+    def slot_walk(b, carry):
+        q_s[:rows, :] = q_ref[b].astype(F32) * (d ** -0.5 * _LOG2E)
+        m_s[...] = jnp.full(m_s.shape, _NEG, F32)
+        l_s[...] = jnp.zeros(l_s.shape, F32)
+        acc_s[...] = jnp.zeros(acc_s.shape, F32)
+        q_at = jnp.full((padded, 1), pos_ref[b, t - 1], jnp.int32)
+        for i in range(t - 1):
+            q_at = jnp.where(query == i, pos_ref[b, i], q_at)
+        q = q_s[...].astype(cd)
+
+        def step(j, carry):
+            done, a_slot, a_j = carry
+            buf = jax.lax.rem(done, buffers)
+            start(a_slot, a_j, jax.lax.rem(done + buffers - 1, buffers))
+            for c in copies(b, j, buf):
+                c.wait()
+            at = _block_start(j, block, s_rows)
+            for c0 in range(0, block, chunk):
+                at_c = (buf, slice(c0 * groups, (c0 + chunk) * groups))
+                s = jax.lax.dot_general(
+                    q, kbuf[at_c].astype(cd), (((1,), (1,)), ((), ())),
+                    preferred_element_type=F32)
+                s = jnp.where(
+                    mine & _block_seen(q_at, at + c0 + k_in, j, block),
+                    s, _NEG)
+                m_prev = m_s[...]
+                m_new = jnp.maximum(m_prev, jnp.max(s, -1, keepdims=True))
+                p = jnp.exp2(s - m_new)
+                alpha = jnp.exp2(m_prev - m_new)
+                m_s[...] = m_new
+                l_s[...] = alpha * l_s[...] + jnp.sum(p, -1, keepdims=True)
+                acc_s[...] = acc_s[...] * alpha + jax.lax.dot_general(
+                    p.astype(cd), vbuf[at_c].astype(cd),
+                    (((1,), (0,)), ((), ())), preferred_element_type=F32)
+            return (done + 1,) + after(a_slot, a_j)
+
+        carry = jax.lax.fori_loop(0, blocks_of(b), step, carry)
+        o_ref[b] = (acc_s[:rows, :] / l_s[:rows, :]).astype(o_ref.dtype)
+        return carry
+
+    jax.lax.fori_loop(0, slots, slot_walk, (jnp.int32(0),) + ahead)
+
+
+@functools.partial(jax.jit, static_argnums=(4, 5))
+def _decode_pallas(q, ck, cv, positions, block: int, interpret: bool
+                   ) -> jax.Array:
+    """Jitted on its own so that the layers of a tick share one lowering
+    (`_prefill_pallas`). q [B, t, H, d], ck, cv [B, S, G, d] as the slab
+    holds them, positions [B, t] int32 -> [B, t, H d]."""
+    b, t, heads, d = q.shape
+    s_rows, groups = ck.shape[1], ck.shape[2]
+    rows = t * heads
+    chunk = max(128, _DECODE_CHUNK // groups)
+    if block % chunk:       # a short entry is one block, and one chunk
+        chunk = block
+    padded = -(-rows // 8) * 8
+    merged = lambda x: x.reshape(b, s_rows * groups, d)
+    resident = pl.BlockSpec((b, rows, d), lambda i, pos: (0, 0, 0))
+    where_it_lies = pl.BlockSpec(memory_space=pl.ANY)
+    visited = b * -(-s_rows // block)
+    out = pl.pallas_call(
+        functools.partial(_decode_kernel, block=block, chunk=chunk,
+                          groups=groups, t=t),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(1,),
+            in_specs=[resident, where_it_lies, where_it_lies],
+            out_specs=resident,
+            scratch_shapes=[
+                pltpu.VMEM((_DECODE_BUFFERS, block * groups, d), ck.dtype),
+                pltpu.VMEM((_DECODE_BUFFERS, block * groups, d), cv.dtype),
+                pltpu.SemaphoreType.DMA((2, _DECODE_BUFFERS)),
+                pltpu.VMEM((padded, d), F32),
+                pltpu.VMEM((padded, 1), F32),
+                pltpu.VMEM((padded, 1), F32),
+                pltpu.VMEM((padded, d), F32)]),
+        out_shape=jax.ShapeDtypeStruct((b, rows, d), q.dtype),
+        interpret=interpret,
+        # the run's rows are in the name, so that a trace tells a tick's
+        # call from a verify pass's
+        name=f"gqa_decode_t{t}",
+        compiler_params=None if interpret else pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        cost_estimate=pl.CostEstimate(
+            flops=4 * visited * rows * block * groups * d,
+            bytes_accessed=2 * q.size * q.dtype.itemsize
+            + 2 * visited * block * groups * d * ck.dtype.itemsize,
+            transcendentals=visited * rows * block * groups),
+    )(positions, q.reshape(b, rows, d), merged(ck), merged(cv))
+    return out.reshape(b, t, heads * d)
+
+
+def decode_attention(q: jax.Array, ck: jax.Array, cv: jax.Array,
+                     positions: jax.Array) -> jax.Array:
+    """A tick's attention over the slab where it lies: q [B, t, H, d] (t
+    = 1, or the speculative verify's k + 1), ck, cv [B, S, G, d] in their
+    own dtype, query (b, j) seeing rows `<= positions[b, j]` of slot b
+    (positions [B, t] int32, ascending along t). Returns [B, t, H d] in
+    q's dtype: what the masked softmax over all S rows gives, from a walk
+    that ends at each slot's last block (module docstring). The block
+    follows from the entry's shape (`_decode_block`)."""
+    b, t, h, d = q.shape
+    s_rows, groups = ck.shape[1], ck.shape[2]
+    block = _decode_block(s_rows, groups, d, ck.dtype.itemsize)
+    shape = (b, t, h, groups, d, s_rows)
+    positions = positions.astype(jnp.int32)
+    interpret = dispatch.interpret_forced()
+    reason = dispatch.backend_reason()
+    if not reason and groups & (groups - 1):
+        reason = f"{groups} key-value heads are no power of two"
+    if not reason and not interpret and (d % 128 or s_rows % 16):
+        reason = (f"rows of {d} numbers or an entry of {s_rows} rows do "
+                  "not fill the kernel's tiles")
+    if reason:
+        dispatch.record_choice("gqa_decode", shape, "reference", reason,
+                               block=block)
+        return _decode_blocked(q, ck, cv, positions, block)
+    dispatch.record_choice("gqa_decode", shape, "pallas", block=block)
+    return _decode_pallas(q, ck, cv, positions, block, interpret)

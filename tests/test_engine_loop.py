@@ -37,7 +37,8 @@ FIELDS = {"engine_id", "pass", "ts", "live", "live_rows", "max_batch",
           "readback_ms", "emit_ms", "total_ms", "inflight", "discarded"}
 GAP_FIELDS = {"gap_streams", "gap_admissions", "gap_blocked_ms",
               "gap_empty_ms", "gap_empty_by"}
-LANDED = FIELDS | GAP_FIELDS
+# and `slab_rows_read`, what the tick's walk read of the slab (PR 41)
+LANDED = FIELDS | GAP_FIELDS | {"slab_rows_read"}
 STEPS = {"bookkeeping", "lookup", "prefill", "first_token", "splice",
          "tick_dispatch", "emit"}
 ADMISSION_FIELDS = {"rid", "prompt_tokens", "prefills_waited",
@@ -138,6 +139,61 @@ def test_live_is_the_slots_in_flight(engine):
         assert r["inflight"] == int(bool(outlives or joins)), j
     assert len(ring) == max(first[rid] + budgets[rid] - 1
                             for rid in first)
+
+
+# rows of 2 KB of keys (4 heads of 128 float32), so the decode form's
+# block is the served 128 rows and a window of 384 holds three
+WALK_CFG = LlamaConfig(vocab_size=64, max_seq_len=384, num_layers=1,
+                       num_heads=4, num_kv_heads=4, d_model=512, d_ff=128,
+                       dtype=jnp.float32)
+LONG_PROMPT = [1 + i % 60 for i in range(150)]     # into the second block
+
+
+def test_a_dead_slot_is_parked_at_row_0_and_costs_one_block():
+    """After a request finishes, the next launch's position vector holds
+    0 at its slot, and the tick's record reads one block for it where it
+    read the request's two."""
+    params = llama_init(WALK_CFG, jax.random.PRNGKey(1))
+    eng = ContinuousBatchingEngine(params, WALK_CFG, max_batch=2,
+                                   prefix_cache=False)
+    try:
+        assert eng.kv_stats()["gqa_decode"] and eng._walk_block == 128
+        short = eng.stream(LONG_PROMPT, 4)
+        long = eng.stream([5, 6, 7], 12)
+        assert len(list(short)) == 4 and len(list(long)) == 12
+        slot = short._req.slot
+        eng.stop()
+        ring = [r for r in _ring(eng) if "slab_rows_read" in r]
+        # two blocks for the long prompt's slot and one beside it, be
+        # that slot empty yet or live
+        both = [r for r in ring if r["live"] == 2]
+        assert both and {r["slab_rows_read"] for r in both} == {3 * 128}
+        assert ring[0]["slab_rows_read"] == 3 * 128
+        # the short request gone: one block a slot, dead or live
+        assert ring[-1]["live"] == 1 and ring[-1]["slab_rows_read"] == 2 * 128
+        assert {r["slab_rows_read"] for r in ring} == {3 * 128, 2 * 128}
+        assert eng._pos[slot] == 0
+        assert int(np.asarray(eng._dev[1])[slot]) == 0
+        assert int(np.asarray(eng._dev[2])[slot]) == 0
+    finally:
+        eng.stop()
+
+
+def test_a_request_spliced_into_a_parked_slot_decodes_what_it_does_fresh():
+    """The parked slot's scatter lands in row 0, which the next splice
+    overwrites: the request after decodes its own tokens."""
+    params = llama_init(WALK_CFG, jax.random.PRNGKey(1))
+
+    def serve(prompts):
+        eng = ContinuousBatchingEngine(params, WALK_CFG, max_batch=1,
+                                       prefix_cache=False)
+        try:
+            return [eng.generate(p, 8) for p in prompts]
+        finally:
+            eng.stop()
+
+    later = [9, 8, 7, 6, 5]
+    assert serve([LONG_PROMPT, later])[1] == serve([later])[0]
 
 
 def test_admission_entry_on_a_cold_and_a_warm_cache(engine):

@@ -3,6 +3,8 @@ kernel in interpret mode against its `jax.numpy` blocks and against the
 dense masked softmax, the blocks a walk visits by hand, the choice
 recorded in `ops/dispatch`; and `ops/grouped_moe.softmax_topk_route`
 against both readings of the two published router keys."""
+import contextlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -151,3 +153,125 @@ def test_softmax_topk_route_is_both_readings_of_the_router_keys():
     np.testing.assert_allclose(
         weights, picked / picked.sum(-1, keepdims=True), atol=1e-6)
     np.testing.assert_allclose(weights.sum(-1), 1.0, atol=1e-6)
+
+
+# ------------------------------------------------------------- decode form
+S_ROWS = 300        # no whole number of blocks of 128: the last is held
+
+
+def _slab(seed, t, groups, rep, d=16, batch=4, rows=S_ROWS):
+    rng = np.random.default_rng(seed)
+    q = jnp.asarray(rng.normal(size=(batch, t, groups * rep, d)),
+                    jnp.float32)
+    ck, cv = (jnp.asarray(rng.normal(size=(batch, rows, groups, d)),
+                          jnp.float32) for _ in range(2))
+    return q, ck, cv
+
+
+def _positions(base, t):
+    return jnp.asarray(np.asarray(base)[:, None] + np.arange(t)[None],
+                       jnp.int32)
+
+
+@pytest.fixture()
+def blocks_of_128(monkeypatch):
+    """Toy rows are a few hundred bytes: the served block's bytes would
+    make one block of the whole entry."""
+    monkeypatch.setattr(swa, "_DECODE_BLOCK_BYTES", 1)
+
+
+# the edges of a block and of the entry; a parked slot, a one-row slot
+# and a full one beside a slot mid-block; a ring's positions as
+# `smallthinker._attn_decode` clamps them once it has wrapped
+BASES = {"edges": [0, 127, 128, S_ROWS - 1],
+         "mixed": [0, 0, S_ROWS - 1, 200],
+         "ring": [S_ROWS - 1] * 4}
+
+
+@pytest.mark.parametrize("where", list(BASES))
+@pytest.mark.parametrize("t", [1, 4])
+@pytest.mark.parametrize("groups,rep", [(8, 4), (4, 7), (2, 16), (1, 20)])
+def test_the_decode_form_is_the_slab_form(groups, rep, t, where,
+                                          blocks_of_128):
+    from ray_tpu.models.llama import _slab_attention
+
+    q, ck, cv = _slab(groups + t, t, groups, rep)
+    base = np.minimum(BASES[where], S_ROWS - t)
+    pos = _positions(base, t)
+    want = _slab_attention(q, ck, cv, pos)
+    dispatch.reset_kernel_choices()
+    blocks = swa.decode_attention(q, ck, cv, pos)
+    choice = dispatch.kernel_choices("gqa_decode")[0]
+    assert choice["choice"] == "reference" and choice["block"] == 128
+    with dispatch.pallas_interpret():
+        kernel = swa.decode_attention(q, ck, cv, pos)
+    choice = dispatch.kernel_choices("gqa_decode")[0]
+    assert choice["choice"] == "pallas" and tuple(choice["shape"]) == (
+        4, t, groups * rep, groups, 16, S_ROWS)
+    np.testing.assert_allclose(blocks, want, atol=2e-6, rtol=0)
+    np.testing.assert_allclose(kernel, want, atol=2e-6, rtol=0)
+
+
+@pytest.mark.parametrize("form", ["blocks", "kernel"])
+@pytest.mark.parametrize("groups,rep", [(8, 4), (1, 20)])
+def test_a_verify_row_is_the_sequential_ticks_to_the_bit(groups, rep, form,
+                                                         blocks_of_128):
+    """Row j of a run of four is what a one-token tick at its position
+    gives, bit for bit: what the speculation's accept rule stands on
+    (`tests/test_speculate.py` holds the engine's tokens to it). The run
+    crosses a block's edge (126 .. 129), so rows 0 and 1 meet a block
+    they see nothing of."""
+    q, ck, cv = _slab(7, 4, groups, rep)
+    pos = _positions([126, 0, S_ROWS - 4, 254], 4)
+    how = dispatch.pallas_interpret if form == "kernel" \
+        else contextlib.nullcontext
+    with how():
+        run = swa.decode_attention(q, ck, cv, pos)
+        for j in range(4):
+            one = swa.decode_attention(q[:, j:j + 1], ck, cv,
+                                       pos[:, j:j + 1])
+            np.testing.assert_array_equal(run[:, j:j + 1], one)
+
+
+def test_a_block_wholly_masked_for_a_row_leaves_its_carry_alone(
+        blocks_of_128):
+    """Row 0 stands at position 5 and row 1 at 299: the walk visits three
+    blocks, and row 0's max, sum and accumulator after it are, to the
+    bit, what a walk that ends at block 0 leaves."""
+    q, ck, cv = _slab(11, 2, 2, 3, batch=1)
+    both = swa._decode_walk(q, ck, cv, jnp.asarray([[5, 299]]), 128)
+    alone = swa._decode_walk(q[:, :1], ck, cv, jnp.asarray([[5]]), 128)
+    for run, tick in zip(both, alone):          # [B, G, rep, t, .]
+        np.testing.assert_array_equal(run[:, :, :, :1], tick)
+    # and row 1 did walk on: 300 keys in its sum for row 0's six
+    assert float(both[1][0, 0, 0, 1, 0]) > float(both[1][0, 0, 0, 0, 0])
+
+
+@pytest.mark.parametrize("positions,block,rows,read", [
+    # 1 + 1 + 2 + 18 blocks of 128
+    ([0, 127, 128, 2303], 128, 2304, 22 * 128),
+    # a verify pass: the run's last position ends the walk
+    ([[126, 127, 128], [0, 1, 2]], 128, 2304, 3 * 128),
+    # 29 parked slots and three live ones, as `mistral-chat` holds them
+    ([0] * 29 + [450, 520, 610], 128, 2304, (29 + 4 + 5 + 5) * 128),
+    # an entry of no whole number of blocks has three, the last held
+    ([299, 0], 128, 300, 4 * 128),
+    # a position past the entry visits every block and no more
+    ([5000], 256, 1024, 4 * 256),
+    ([0, 0], 300, 300, 600)])
+def test_the_rows_a_walk_reads_by_hand(positions, block, rows, read):
+    assert swa.decode_rows_read(np.asarray(positions), block, rows) == read
+
+
+@pytest.mark.parametrize("rows,groups,d,block", [
+    (2304, 8, 128, 128),        # mistral-chat: 2 KB a row of keys
+    (4160, 8, 128, 128),        # mistral-summarize
+    (16384, 4, 128, 256),       # smallthinker's global layers
+    (4096, 4, 128, 256),        # and its rings
+    (33280, 1, 128, 1024),      # jamba: one key-value head
+    (1536, 2, 128, 512),        # nemotron
+    (96, 2, 16, 96)])           # never over the entry
+def test_the_decode_block_follows_from_the_entrys_shape(rows, groups, d,
+                                                        block):
+    assert swa._decode_block(rows, groups, d, 2) == block
+    assert block * groups * d * 2 <= swa._DECODE_BLOCK_BYTES
