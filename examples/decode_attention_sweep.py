@@ -1,6 +1,6 @@
 """A tick's attention ALONE on the chip: `ops/swa.py`'s decode kernel
 (`gqa_decode_t<t>`) against the slab form it replaces
-(`models/llama.py` `_slab_attention`: float32 scores of every query
+(`ops/swa.py` `slab_attention`: float32 scores of every query
 against ALL rows of ALL slots, masked afterwards) at the slab shapes the
 served cells hold, with slots as full as their cells leave them. The
 block `_decode_block` chooses, and the rule by which a shape keeps the
@@ -29,7 +29,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ray_tpu.models.llama import _slab_attention
 from ray_tpu.ops import swa
 
 HBM_BYTES_PER_S = 819e9
@@ -106,7 +105,7 @@ def main():
         base = _positions(rng, b, live, lo, min(hi, s - t + 1), parked)
         pos = jnp.asarray(base[:, None] + np.arange(t)[None], jnp.int32)
         chosen = swa._decode_block(s, g, d, ck.dtype.itemsize)
-        paths = {"slab": (_slab_attention, None)}
+        paths = {"slab": (swa.slab_attention, None)}
         for block in sorted({chosen} | {int(x) for x in
                                         args.blocks.split(",") if x}):
             if block <= s and (toy or block % 128 == 0):

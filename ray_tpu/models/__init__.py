@@ -1,35 +1,16 @@
 """ray_tpu.models: flagship model families, TPU-first.
 
 The reference ships no models of its own (Ray wraps user torch modules);
-the rebuild's north-star workloads (BASELINE.md) need a flagship LM, so
-GPT-2 lives here as a pure-functional JAX implementation with first-class
-sharding rules for every mesh axis the parallel layer exposes.
+the rebuild's north-star workloads (BASELINE.md) need flagship LMs, so
+they live here as pure-functional JAX implementations with sharding
+rules for every mesh axis the parallel layer exposes.
 
-Served by `ContinuousBatchingEngine` (`generate._model_fns`): GPT-2, the
-Llama block, and Nemotron-H (`nemotron_h.py`: Mamba-2, attention and
-LatentMoE layers; its slots own recurrent state, so the engine refuses
-it a prefix pool, speculation, a LoRA pool and disaggregated adoption),
-and Kimi-Linear (`kimi_linear.py`: delta-rule and latent-attention mixers
-over dense and expert layers; beside its state a slot owns the engine's
-third kind of cache entry, ONE latent row a token in a single array, from
-which keys and values are both made; refused what Nemotron-H is), and
-DeepSeek-V2 (`deepseek_v2.py`: rotary latent attention under YaRN over a
-dense layer and group-routed expert layers; its cache is latent rows
-ALONE, no values and no state, so the engine builds it no prefix pool and
-refuses it adoption, speculation and a LoRA pool, each for what the pool
-lacks), and SmallThinker (`smallthinker.py`: grouped-query layers that
-see the whole sequence beside layers that see a window, under a router
-that reads ahead of the attention; a window layer's keys and values are
-the engine's fourth kind of entry, a RING shorter than `max_seq_len`
-beside the global layers' full-length entries in one slab, so the engine
-builds it no prefix pool and refuses it adoption, speculation and a LoRA
-pool: most layers have forgotten what a block-aligned prefix would
-resume), and Jamba (`jamba.py`: layers of two sublayers, a Mamba-1 or
-multi-query attention mixer and a dense SwiGLU; a slot owns a
-per-channel float32 state [16, 5120] and a convolution tail a Mamba
-layer, stacked by RUN of layers, beside one key-value head's rows; the
-head is the embedding; refused what Nemotron-H is).
-`moe_transformer.py` trains and is not served.
+A family is ONE file that ends in `FAMILY = Family(...)`
+(`family.py`: the record, the kinds of entry a cache is made of, the
+slab's layout and what each kind is refused). `generate` and
+`ContinuousBatchingEngine` find it from the config's class; no list
+here or anywhere names a family for them. The names below are
+re-exports. `moe_transformer.py` trains and is not served.
 """
 from .gpt2 import (  # noqa: F401
     GPT2Config,

@@ -56,12 +56,13 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..ops.grouped_moe import held_experts, softmax_group_limited_route
-from ..ops.layers import rms_norm
+from ..ops.grouped_moe import (held_counts, held_experts,
+                               softmax_group_limited_route)
+from ..ops.layers import mm, rms_norm
 from ..ops.mla import (absorbed_attention, latent_row, prompt_attention,
                        row_width)
 from ..ops.rope import apply_rope, yarn_mscale, yarn_table
-from .llama import _mm
+from .family import Family
 
 Params = Dict[str, Any]
 F32 = jnp.float32
@@ -210,10 +211,10 @@ def _mla_inputs(h: jax.Array, p: Params, c: DeepseekV2Config, rope,
     [B,T,rank], the rotated shared key part [B,T,d_r], W_kvb as [rank, H,
     d_n + d_v])."""
     b, t, _ = h.shape
-    q = _mm(rms_norm(_mm(h, p["w_qa"]), p["q_norm"], c.norm_eps),
+    q = mm(rms_norm(mm(h, p["w_qa"]), p["q_norm"], c.norm_eps),
             p["w_qb"]).reshape(
         b, t, c.num_heads, c.qk_nope_head_dim + c.qk_rope_head_dim)
-    lat, k_r = jnp.split(_mm(h, p["w_kva"]), [c.kv_lora_rank], -1)
+    lat, k_r = jnp.split(mm(h, p["w_kva"]), [c.kv_lora_rank], -1)
     cos, sin = rope
     q_r = apply_rope(q[..., c.qk_nope_head_dim:], cos, sin, positions)
     k_r = apply_rope(k_r[:, :, None, :], cos, sin, positions)[:, :, 0]
@@ -330,21 +331,6 @@ def _head(x: jax.Array, params: Params, c: DeepseekV2Config) -> jax.Array:
         return jnp.dot(h, params["lm_head"], preferred_element_type=F32)
 
 
-def _counts(sizes: list) -> Dict[str, jax.Array]:
-    """What the expert layers' grouped products saw, from the rows each
-    held expert got in each expert layer: `moe_pairs_held`, token-expert
-    pairs that fell on held experts, summed over the layers;
-    `moe_experts_hit`, held experts that got a row, summed likewise;
-    `moe_rows_max`, the most rows one held expert got in one layer."""
-    if not sizes:
-        zero = jnp.int32(0)
-        return {"moe_pairs_held": zero, "moe_rows_max": zero,
-                "moe_experts_hit": zero}
-    rows = jnp.stack(sizes)
-    return {"moe_pairs_held": rows.sum(), "moe_rows_max": rows.max(),
-            "moe_experts_hit": (rows > 0).sum().astype(jnp.int32)}
-
-
 # ------------------------------------------------------------- the model
 
 def _prefill(params: Params, tokens: jax.Array, c: DeepseekV2Config,
@@ -363,7 +349,7 @@ def _prefill(params: Params, tokens: jax.Array, c: DeepseekV2Config,
         blocks += n
         x, rows = _ffn(x + y, p, c)
         sizes += [] if rows is None else [rows]
-    return x, new_cache, dict(_counts(sizes),
+    return x, new_cache, dict(held_counts(sizes),
                               attn_blocks=jnp.int32(blocks))
 
 
@@ -430,14 +416,11 @@ def deepseek_v2_forward_cached(params: Params, tokens: jax.Array,
                                config: DeepseekV2Config, cache: list,
                                pos: Any):
     """`deepseek_v2_forward_counted` less its counters: the cache
-    protocol's (logits, cache). The engine's prefill finds the counted
-    form under `with_counters` and puts what it hands back into the
+    protocol's (logits, cache). The engine's prefill takes the counted
+    form (`FAMILY.forward_counted`) and puts what it hands back into the
     admission's record."""
     return deepseek_v2_forward_counted(params, tokens, config, cache,
                                        pos)[:2]
-
-
-deepseek_v2_forward_cached.with_counters = deepseek_v2_forward_counted
 
 
 def deepseek_v2_decode(params: Params, tokens: jax.Array,
@@ -445,8 +428,8 @@ def deepseek_v2_decode(params: Params, tokens: jax.Array,
                        pos_vec: jax.Array):
     """One step for a ragged batch: tokens [B], slot b at position
     pos_vec[b]. Returns (logits [B, vocab] float32, the new cache, the
-    expert layers' counts for the engine's loop record: `_counts`). There
-    is no [B, k+1] verify form."""
+    expert layers' counts for the engine's loop record: `held_counts`).
+    There is no [B, k+1] verify form."""
     c = config
     if tokens.ndim != 1:
         raise ValueError("this family's decode has no verify form: "
@@ -461,7 +444,7 @@ def deepseek_v2_decode(params: Params, tokens: jax.Array,
                                       positions)
         x, rows = _ffn(x + y, p, c)
         sizes += [] if rows is None else [rows]
-    return _head(x[:, 0], params, c), new_cache, _counts(sizes)
+    return _head(x[:, 0], params, c), new_cache, held_counts(sizes)
 
 
 def deepseek_v2_partition_specs(config: DeepseekV2Config) -> Params:
@@ -481,3 +464,12 @@ def deepseek_v2_partition_specs(config: DeepseekV2Config) -> Params:
               for i in range(config.num_layers)]
     return {"tok_emb": P("tp", "fsdp"), "norm_f": norm,
             "lm_head": P("fsdp", "tp"), "blocks": blocks}
+
+
+FAMILY = Family(
+    config_type=DeepseekV2Config, init=deepseek_v2_init,
+    forward=deepseek_v2_forward, loss=deepseek_v2_loss,
+    partition_specs=deepseek_v2_partition_specs,
+    init_cache=deepseek_v2_init_cache,
+    forward_cached=deepseek_v2_forward_cached, decode=deepseek_v2_decode,
+    forward_counted=deepseek_v2_forward_counted)

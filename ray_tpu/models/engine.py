@@ -66,71 +66,32 @@ Surfaces: ``util.state.speculation_stats()``, ``ray_tpu speculate``,
 ``ray_tpu_spec_acceptance_rate``), and spec_accept / spec_reject
 instant markers in the merged timeline's kvcache lane.
 
-The cache protocol is one for every family (`generate._model_fns`): the
-decode slab is the family's own pytree, a list of entries of four
-kinds. An entry with "k"/"v" `[B, S, H, hd]` has a sequence axis: a
-prefill hands back its rows stacked `[L_kv, S, H, hd]` (what the paged
-pool commits, a disaggregated transfer ships and `_splice_slot` writes
-by rows `[0, plen)`). An entry with "k" ALONE `[B, S, ...]` has a
-sequence axis and one array: a latent row a token, from which keys and
-values are both made (models/kimi_linear.py); its rows are stacked and
-spliced as keys are, and no values travel beside them (`cv` is None).
-Any other entry is a slot's STATE with no sequence axis (a recurrence's
-state, a convolution's tail: models/nemotron_h.py): a prefill hands
-back the state it ended in, and the splice writes it WHOLE. Three
-things the engine takes for granted of keys and values do
-not hold for such a family, and are refused with a ValueError that names
-the reason rather than served silently wrong: a prefix pool
-(``prefix_cache=True``; left to its default the engine builds none: a
-block-aligned prefix cannot resume a recurrence without a snapshot of
-the state), speculation (``speculate_k > 0``: a state a draft advanced
-cannot be un-advanced), a ``lora_pool``, and ``adopt_prefill`` (a
-transfer carries keys and values only).
-
-A cache may hold latent entries ALONE: no values and no state
-(models/deepseek_v2.py). Such a family has nothing a block-aligned
-prefix could not resume, but the paged pool itself speaks keys AND
-values: it sizes a block `[heads, head_dim]` from a key tensor and
-commits `ck, cv` side by side (models/kvcache.py; the latent pool is
-ROADMAP R3). So the engine builds it no pool (left to its default;
-``prefix_cache=True`` is refused by name) and serves every prompt from
-position 0, and refuses what stands on the pool or on pairs of keys and
-values: ``adopt_prefill`` and the disaggregated transfer (they carry
-`ck` and `cv`), speculation (its first proposer drafts from the pool's
-token chains, and the family's decode has no ``[B, k+1]`` verify form)
-and a ``lora_pool`` (its per-tenant prefix namespaces are the pool's).
-Each ValueError names what the pool lacks (``_refuse_for_latent``).
-
-The fourth kind is the RING: a sequence entry ("k" and "v" `[B, rows, H,
-hd]`) whose `rows` is SHORTER than ``config.max_seq_len``, beside
-entries of the full length in the same slab (models/smallthinker.py:
-layers that see a window of 4,096 positions beside layers that see all
-16,384). The token at position p lies in row `p mod rows`, its key stored
-as the attention reads it, so the order of the rows never matters to a
-softmax. The engine learns this from the slab's own shapes
-(``ring_rows``), never from a family's name, and it is the family that
-masks and wraps. What changes here is small: a prefill hands the
-sequence entries back as ONE STACK FOR EACH ROW COUNT (`ck`, `cv` are
-then tuples, in the order the slab first shows each count; a family with
-one count gets the one stack it always got, so the pool's commit, the
-transfer and adoption see what they saw), a ring's stack as the ring
-would lie after the prompt (the last `min(plen, rows)` positions, each at
-`p mod rows`); `_splice_slot` writes rows `[0, min(plen, rows))` of each
-entry, O(rows held) and in place; a dead slot's position stands still
-at 0 (`_chosen`, `_finish`), so its ring row does too, at row 0; the
-loop's record of a tick gains
-``live_rows_window`` (the sum over the live slots of `min(position,
-rows)` for the shortest row count: what of the rings the tick had a
-reason to read) and ``kv_stats()["slab"]`` says, for each row count, the
-layers that hold it and the bytes a slot costs. What stands on the pool
-or on one block shape is refused in words (``_refuse_for_ring``):
-``prefix_cache=True`` (a block-aligned prefix cannot be resumed where
-layers have forgotten all but their last rows, and the pool has one block
-shape and one length; left to its default the engine builds none and
-prefills from position 0), speculation (a rejected draft's rows have
-overwritten ring rows the window still sees), a ``lora_pool`` and
-``adopt_prefill`` with the disaggregated transfer (one stack of the
-prompt's length).
+The cache protocol is one for every family, and `models/family.py`
+holds it: the family's record (`family_of`), the four kinds of entry its
+cache may be made of (keys and values in pairs, a latent row alone, a
+RING shorter than ``config.max_seq_len``, a slot's STATE with no sequence
+axis), the slab's layout learnt from its shapes and never from a family's
+name (`slab_spec`), and what each kind is refused in words (`refuse`: a
+prefix pool, speculation, a ``lora_pool``, ``adopt_prefill``, the
+transfer between replicas; an option left to its default asks for
+nothing, so such a family simply gets no pool and prefills every prompt
+from position 0). What the engine does with them: a prefill hands the
+sequence entries back stacked `[L_kv, S, H, hd]` (what the paged pool
+commits, a disaggregated transfer ships and `_splice_slot` writes by rows
+`[0, plen)`), with `cv` None where a latent row serves for keys and
+values both, ONE STACK FOR EACH ROW COUNT where the slab holds a ring
+(`ck`, `cv` are then tuples, in the order the slab first shows each
+count; a family with one count gets the one stack it always got), a
+ring's stack as the ring would lie after the prompt (the last `min(plen,
+rows)` positions, each at `p mod rows`); and the state it ended in, which
+the splice writes WHOLE. `_splice_slot` writes rows `[0, min(plen,
+rows))` of each entry, O(rows held) and in place; a dead slot's position
+stands still at 0 (`_chosen`, `_finish`), so its ring row does too, at
+row 0; the loop's record of a tick gains ``live_rows_window`` (the sum
+over the live slots of `min(position, rows)` for the shortest row count:
+what of the rings the tick had a reason to read) and
+``kv_stats()["slab"]`` says, for each row count, the layers that hold it
+and the bytes a slot costs.
 
 The loop keeps one tick ahead. The token vector and the position vector
 of the decode step live on the chip: `_tick` hands back, beside the
@@ -224,17 +185,18 @@ now that the host's pass runs under it); ``emit_ms`` (the walk over
 that tick's slots: emit, finish, queue puts, discards); ``inflight``
 (ticks queued on the chip while the pass was blocked on its read-back:
 1 in a steady pass, 0 in a pass with drafts, with nothing left to
-decode for, or that admits a long prompt) and ``discarded`` (rows of the tick read thrown away
-because their request had finished by EOS or been cancelled since the
-launch); ``total_ms`` (the whole pass; what the parts leave is
-bookkeeping: swap, cancels, drafting, telemetry push). ``live`` (the
+decode for, or that admits a long prompt) and ``discarded`` (rows of
+the tick read thrown away because their request had finished by EOS or
+been cancelled since the launch); ``total_ms`` (the whole pass; what
+the parts leave is bookkeeping: swap, cancels, drafting, telemetry
+push). ``live`` (the
 slots the tick decoded for), ``live_rows`` (the sum of their positions:
 the cache rows the tick had a reason to read), ``slab_rows_read`` (the
 rows one layer's walk over the slab's longest entries visits, ALL slots
 at the positions the tick was launched with: whole blocks, a dead slot's
 one block, by the function the kernel's walk uses,
 `ops/swa.decode_rows_read`; ``max_batch`` x rows for a family whose tick
-reads every row, `tick_walk_block`), where the slab holds a
+reads every row: `Family.decode_walks`), where the slab holds a
 ring ``live_rows_window`` (above) and, where the family's
 decode hands back counters of the step, those under their own names
 (``moe_pairs_held``, token-expert pairs that fell on experts held here,
@@ -337,10 +299,11 @@ import numpy as np
 
 from ray_tpu.observability import requests as reqtrace
 from ray_tpu.ops import dispatch
-from ray_tpu.ops.swa import decode_rows_read
+from ray_tpu.ops.swa import decode_block, decode_rows_read
 from ray_tpu.util.profiling import name_thread
 
-from .generate import _model_fns, merge_lora_params
+from .family import family_of, refuse, row_counts, slab_spec
+from .generate import merge_lora_params
 from .kvcache import PagedKVCache, resolve_pool_config
 
 _DONE = object()
@@ -544,91 +507,47 @@ def spec_metrics() -> Dict[str, Any]:
     return _spec_metrics
 
 
-LATENT_ONLY = ("this family's cache holds one latent row a token and no "
-               "values: ")
+@functools.partial(jax.jit, static_argnums=(2,))
+def _prefill_paged(params, suffix, config, prefix_k, prefix_v, lora=None):
+    """Prefill a single sequence's SUFFIX on top of a cached prefix
+    ([L, c, H, hd]; c=0 is the full-prefill program, and the only one a
+    family with state has). The cache is the full max_seq_len slab and
+    the family's `forward_cached` is generate()'s prefill, so an engine
+    and generate() run one program over a prompt. A cached prefix and
+    none need NOT share reduction shapes: a family may attend over the
+    run alone at c=0 and over the slab on top of a prefix
+    (`models/llama.py` for a prompt over one block of its prompt form),
+    so a replay through the prefix cache can let a near-tie fall the
+    other way. One compile per distinct (cached, suffix) length pair.
 
-
-def latent_only(cache) -> bool:
-    """Whether a family's cache (`init_cache`) is sequence entries of one
-    array each ("k" and no "v") and nothing else."""
-    return all("k" in blk and "v" not in blk and len(blk) == 1
-               for blk in cache)
-
-
-RING = ("this family's cache holds a ring (layers that keep only their "
-        "last rows, fewer than max_seq_len): ")
-
-
-def _row_counts(cache) -> Dict[int, List[int]]:
-    """The sequence entries of a cache by their rows, in the order the
-    cache first shows each count: {rows: [entry indices]}."""
-    by_rows: Dict[int, List[int]] = {}
-    for i, blk in enumerate(cache):
-        if "k" in blk:
-            by_rows.setdefault(blk["k"].shape[1], []).append(i)
-    return by_rows
-
-
-def ring_rows(cache, max_seq_len: int) -> Optional[int]:
-    """The rows of the shortest sequence entry of a family's cache
-    (`init_cache`, or its shapes) where that is shorter than the window:
-    such an entry is a ring (module docstring). None for a cache without
-    one."""
-    shortest = min(_row_counts(cache), default=max_seq_len)
-    return shortest if shortest < max_seq_len else None
-
-
-_TICK_WALKS: Dict[tuple, Optional[int]] = {}
-
-
-def tick_walk_block(params, config, cache) -> Optional[int]:
-    """The block of rows by which a tick of this family walks the
-    slab's longest entries (`ops/swa.decode_attention`), None where its
-    tick reads every row of every slot. Learnt, once a config and batch,
-    from the program itself and never from a family's name: the family's
-    decode is traced over shapes alone and `ops/dispatch` asked what the
-    decode form recorded for an entry of this batch and these rows."""
-    rows = max(_row_counts(cache), default=0)
-    batch = jax.tree.leaves(cache)[0].shape[0]
-    key = (config, batch, rows)
-    if key not in _TICK_WALKS:
-        vec = jax.ShapeDtypeStruct((batch,), jnp.int32)
-        jax.eval_shape(
-            lambda p, c, tok, pos: _model_fns(config)[2](p, tok, config, c,
-                                                        pos),
-            params, cache, vec, vec)
-        _TICK_WALKS[key] = next(
-            (e["block"] for e in dispatch.kernel_choices("gqa_decode")
-             if e["shape"][:2] == (batch, 1) and e["shape"][5] == rows),
-            None)
-    return _TICK_WALKS[key]
-
-
-def _prefill_body(params, suffix, config, prefix_k, prefix_v):
-    """What `_prefill_paged` and `_prefill_paged_lora` trace. The
-    family's single-sequence cache (`init_cache(config, 1)`) is laid out
-    with the cached prefix in the rows of its key-value entries, the
+    The family's single-sequence cache (`init_cache(config, 1)`) is laid
+    out with the cached prefix in the rows of its key-value entries, the
     family's `forward_cached` fills it from position c on, and it comes
-    back as the engine passes a sequence around: the key-value entries
-    stacked [L_kv, S, H, hd] (what the paged pool, the splice and the
-    transfer between replicas speak), then the entries that have no
-    sequence axis, each as the family left it (a slot's state; the empty
-    list for a family that has none). A family whose sequence entries
-    hold one array (a latent row: "k" alone) gets `cv` None, and
-    `prefix_v` is not read. A family whose sequence entries have MORE
-    THAN ONE row count (a ring beside full-length entries) gets `ck` and
-    `cv` as tuples, one stack a row count in the order the cache first
-    shows each, and has no cached prefix to lay out. Last, the counters
-    of the run where the family's `forward_cached` carries a counted form
-    of itself (`with_counters`: a dict of small numbers,
-    models/deepseek_v2.py), else None."""
-    fwd, init_cache, _ = _model_fns(config)
+    back as the engine passes a sequence around: (last logits, ck, cv,
+    state, counters). `ck`, `cv`: the key-value entries stacked [L_kv, S,
+    H, hd] (what the paged pool, the splice and the transfer between
+    replicas speak); a family whose sequence entries hold one array (a
+    latent row: "k" alone) gets `cv` None, and `prefix_v` is not read; a
+    family whose sequence entries have MORE THAN ONE row count (a ring
+    beside full-length entries) gets `ck` and `cv` as tuples, one stack
+    a row count in the order the cache first shows each
+    (`family.row_counts`), and has no cached prefix to lay out. `state`:
+    the entries that have no sequence axis, each as the family left it
+    (the empty list for a family that has none). `counters`: those of
+    the run where the family's record has a `forward_counted` (a dict of
+    small numbers, models/deepseek_v2.py), else None.
+
+    `lora`: ONE tenant's adapter, its low-rank deltas merged into the
+    target leaves INSIDE the jit (prefill is per-request single-tenant,
+    so the merged weights never persist; only the decode tick pays the
+    scatter-gathered per-slot form). One more compile per rank."""
+    if lora is not None:
+        params = merge_lora_params(params, config, lora)
+    family, spec = family_of(config), slab_spec(config, 1)
     c = prefix_k.shape[1]
-    cache = list(init_cache(config, 1))
-    by_rows = _row_counts(cache)
-    kv_at = [i for at in by_rows.values() for i in at]
-    paired = all("v" in cache[i] for i in kv_at)
-    if c and (len(kv_at) != len(cache) or len(by_rows) > 1):
+    cache = list(family.init_cache(config, 1))
+    by_rows, paired = spec.by_rows, spec.paired
+    if c and (spec.stateful or len(by_rows) > 1):
         raise ValueError(
             "a cached prefix cannot resume a recurrent state or a ring: "
             "this family prefills every prompt from position 0")
@@ -644,11 +563,12 @@ def _prefill_body(params, suffix, config, prefix_k, prefix_v):
             cache[i] = {"k": base_k[j][None]}
             if paired:
                 cache[i]["v"] = base_v[j][None]
-    counted = getattr(fwd, "with_counters", None)
-    if counted is None:
-        (logits, cache), counts = fwd(params, suffix, config, cache, c), None
+    if family.forward_counted is None:
+        (logits, cache), counts = family.forward_cached(
+            params, suffix, config, cache, c), None
     else:
-        logits, cache, counts = counted(params, suffix, config, cache, c)
+        logits, cache, counts = family.forward_counted(
+            params, suffix, config, cache, c)
     ck = tuple(jnp.stack([cache[i]["k"][0] for i in at])
                for at in by_rows.values())
     cv = tuple(jnp.stack([cache[i]["v"][0] for i in at]) if paired else None
@@ -657,35 +577,6 @@ def _prefill_body(params, suffix, config, prefix_k, prefix_v):
         ck, cv = ck[0], cv[0]
     state = [blk for blk in cache if "k" not in blk]
     return logits[:, -1], ck, cv, state, counts
-
-
-@functools.partial(jax.jit, static_argnums=(2,))
-def _prefill_paged(params, suffix, config, prefix_k, prefix_v):
-    """Prefill a single sequence's SUFFIX on top of a cached prefix
-    ([L, c, H, hd]; c=0 is the full-prefill program, and the only one a
-    family with state has). The cache is the full max_seq_len slab and
-    the family's `forward_cached` is generate()'s prefill, so an engine
-    and generate() run one program over a prompt. A cached prefix and
-    none need NOT share reduction shapes: a family may attend over the
-    run alone at c=0 and over the slab on top of a prefix
-    (`models/llama.py` for a prompt over one block of its prompt form),
-    so a replay through the prefix cache can let a near-tie fall the
-    other way. Returns (last logits, ck, cv, state, counters):
-    `_prefill_body`. One compile per distinct (cached, suffix) length
-    pair."""
-    return _prefill_body(params, suffix, config, prefix_k, prefix_v)
-
-
-@functools.partial(jax.jit, static_argnums=(2,))
-def _prefill_paged_lora(params, suffix, config, prefix_k, prefix_v,
-                        lora):
-    """`_prefill_paged` under ONE tenant's LoRA adapter: the low-rank
-    deltas are merged into the target leaves INSIDE the jit (prefill is
-    per-request single-tenant, so the merged weights never persist —
-    only the decode tick pays the scatter-gathered per-slot form). One
-    compile per distinct (cached, suffix, rank) shape triple."""
-    return _prefill_body(merge_lora_params(params, config, lora), suffix,
-                         config, prefix_k, prefix_v)
 
 
 def _prefill_with_cache(params, config, kv_cache, prompt, empty_prefix,
@@ -698,7 +589,7 @@ def _prefill_with_cache(params, config, kv_cache, prompt, empty_prefix,
     implementation keeps the two paths bit-identical (the disagg
     equivalence tests depend on it). Returns `(ck, cv, state,
     block_table, first, score, outcome, reused, suffix_len, counters)`
-    (`state` and `counters`, a dict of ints or None: `_prefill_body`);
+    (`state` and `counters`, a dict of ints or None: `_prefill_paged`);
     the caller owns the returned pins (empty list when no cache).
 
     `adapter`/`namespace` (multi-tenant LoRA, serve/lora.py): prefill
@@ -737,12 +628,8 @@ def _prefill_with_cache(params, config, kv_cache, prompt, empty_prefix,
     # plans the commit and queues its writes behind it, so the span's
     # self time is the prefill's
     with _span("engine.prefill", gaps, rid=rid, prompt_tokens=plen):
-        if adapter is not None:
-            last_logits, ck, cv, state, counts = _prefill_paged_lora(
-                params, suffix, config, prefix_k, prefix_v, adapter)
-        else:
-            last_logits, ck, cv, state, counts = _prefill_paged(
-                params, suffix, config, prefix_k, prefix_v)
+        last_logits, ck, cv, state, counts = _prefill_paged(
+            params, suffix, config, prefix_k, prefix_v, adapter)
         gaps.launched(work=True)
         table: List[Any] = []
         if kv_cache is not None:
@@ -793,12 +680,12 @@ def _splice_slot(cache, ck, cv, slot, config, plen, state=()):
     (and of cv where it holds "v" too; a latent entry holds "k" alone):
     all of a ring the prompt has wrapped, which the prefill handed back
     as it lies. `ck` and `cv` are one stack, or a tuple of stacks, one a
-    row count in the order the slab first shows each (`_prefill_body`).
+    row count in the order the slab first shows each (`_prefill_paged`).
     Any other entry is a slot's state and takes the next entry of `state`
     WHOLE. With the slab donated this lowers to an in-place update per
     entry, O(rows held) and the state's bytes, never a full-cache copy."""
     del config
-    stacks = {rows: j for j, rows in enumerate(_row_counts(cache))}
+    stacks = {rows: j for j, rows in enumerate(row_counts(cache))}
     if not isinstance(ck, tuple):
         ck, cv = (ck,), (cv,)
     out, layer, states = [], [0] * len(stacks), iter(state)
@@ -843,7 +730,7 @@ def _chosen(logits, config, tokens, pos_vec, live):
 
 
 @functools.partial(jax.jit, static_argnums=(1,), donate_argnums=(2,))
-def _tick(params, config, cache, tokens, pos_vec, live=None):
+def _tick(params, config, cache, tokens, pos_vec, live=None, lora=None):
     """One decode step — shape-polymorphic over the token axis:
     tokens [B] is the classic one-token tick; tokens [B, k+1] is the
     speculative VERIFY pass (column 0 each slot's last token, columns
@@ -861,31 +748,23 @@ def _tick(params, config, cache, tokens, pos_vec, live=None):
     family's decode may hand back a third value, a dict of small
     counters of the step (models/nemotron_h.py: what its expert layers
     saw); it comes back beside the tokens, None for a family that has
-    none."""
-    logits, cache, *counts = _model_fns(config)[2](params, tokens, config,
-                                                   cache, pos_vec)
+    none.
+
+    `lora` makes it the mixed-tenant tick: PER-SLOT adapter indices
+    (`lora["idx"]`) gather each slot's low-rank deltas out of the
+    resident adapter-pool stacks, ``base @ x + scatter-gathered (B·A)
+    @ x`` at the LoRA-target leaves (serve/lora.py), at every position
+    of a verify pass too. Slots on the null adapter (index 0: zero A/B,
+    scale 0) compute a bit-identical base-only step, so mixed batches
+    never perturb base traffic. Passed only when a live slot actually
+    holds an adapter; pool shapes are static, so this is ONE extra
+    compiled program per engine."""
+    args = (params, tokens, config, cache, pos_vec)
+    if lora is not None:
+        args += (lora,)
+    logits, cache, *counts = family_of(config).decode(*args)
     nxt, lp, pos_next = _chosen(logits, config, tokens, pos_vec, live)
     return cache, nxt, lp, (counts[0] if counts else None), pos_next
-
-
-@functools.partial(jax.jit, static_argnums=(1,), donate_argnums=(2,))
-def _tick_lora(params, config, cache, tokens, pos_vec, lora, live=None):
-    """The mixed-tenant decode tick: one jitted ragged-batch step with
-    PER-SLOT adapter indices (`lora["idx"]`) gathering each slot's
-    low-rank deltas out of the resident adapter-pool stacks —
-    ``base @ x + scatter-gathered (B·A) @ x`` at the LoRA-target leaves
-    (serve/lora.py). Slots on the null adapter (index 0: zero A/B,
-    scale 0) compute a bit-identical base-only step, so mixed batches
-    never perturb base traffic. Chosen over `_tick` only when a live
-    slot actually holds an adapter; pool shapes are static, so this is
-    ONE extra compiled program per engine. Shape-polymorphic like
-    `_tick`: tokens [B, k+1] is the speculative verify pass, with the
-    adapter deltas applied at every position. Hands back what `_tick`
-    does, less the counters."""
-    logits, cache = _model_fns(config)[2](params, tokens, config, cache,
-                                          pos_vec, lora)
-    nxt, lp, pos_next = _chosen(logits, config, tokens, pos_vec, live)
-    return cache, nxt, lp, pos_next
 
 
 @jax.jit
@@ -1087,9 +966,7 @@ class ContinuousBatchingEngine:
                  draft_source: Optional[Callable[[List[int], int],
                                                  List[int]]] = None,
                  kv_int8: Optional[bool] = None):
-        # config: any family _model_fns knows (LlamaConfig, GPT2Config,
-        # NemotronHConfig, KimiLinearConfig, DeepseekV2Config,
-        # SmallThinkerConfig, JambaConfig)
+        # config: of any family that has a record (models/family.py)
         self.params = params
         self.config = config
         self.max_batch = max_batch
@@ -1102,44 +979,29 @@ class ContinuousBatchingEngine:
         self.params_version = params_version
         self._pending_swap: Optional[tuple] = None
         self.swap_count = 0
-        self._cache = _model_fns(config)[1](config, max_batch)
-        # the slab is the family's own pytree: entries with "k" (and
-        # "v", unless one latent row serves for both) have a sequence
-        # axis, any other entry is a slot's state (what it weighs:
-        # kv_stats)
-        self._state_bytes_per_slot = sum(
-            x.size * x.dtype.itemsize // max_batch
-            for blk in self._cache if "k" not in blk
-            for x in jax.tree.leaves(blk))
-        self._kv_bytes_per_token = sum(
-            x.size * x.dtype.itemsize // (max_batch * x.shape[1])
-            for blk in self._cache if "k" in blk
-            for x in jax.tree.leaves(blk))
-        # for each row count of the slab's sequence entries: the layers
-        # that hold it and the bytes a slot costs (kv_stats)
-        self._slab = [
-            {"rows": rows, "layers": len(at), "bytes_per_slot": sum(
-                x.size * x.dtype.itemsize // max_batch
-                for i in at for x in jax.tree.leaves(self._cache[i]))}
-            for rows, at in _row_counts(self._cache).items()]
-        # the rows of the slab's ring, where a sequence entry is shorter
-        # than the window (module docstring); None for a slab without one
-        self.ring_rows = ring_rows(self._cache, config.max_seq_len)
-        # the rows of the slab's longest entries, and the block by which
-        # the tick's attention walks them (None: it reads them all)
-        self._slab_rows = max(_row_counts(self._cache), default=0)
-        self._walk_block = tick_walk_block(params, config, self._cache)
+        family = family_of(config)
+        # the slab is the family's own pytree; what it is made of, from
+        # its shapes (models/family.py): kv_stats() reads it
+        self._spec = spec = slab_spec(config, max_batch)
+        self.stateful = spec.stateful
+        self.latent_only = spec.latent_only
+        self.ring_rows = spec.ring_rows
+        # the block by which the tick's attention walks the slab's
+        # longest entries (None: it reads every row of every slot)
+        self._walk_block = None
+        if family.decode_walks:
+            self._walk_block = decode_block(spec.longest.shape,
+                                            spec.longest.dtype)
         if speculate_k is None:
             speculate_k = default_speculate_k()
-        if self.stateful:
-            self._refuse_for_state(prefix_cache, speculate_k, lora_pool)
+        # what a cache that is not full-length keys and values cannot
+        # give is refused in words; left to its default it gets no pool
+        refuse(spec, "prefix_cache", prefix_cache)
+        refuse(spec, "speculate_k", speculate_k, k=speculate_k)
+        refuse(spec, "lora_pool", lora_pool is not None)
+        if spec.kind is not None:
             prefix_cache = False
-        elif self.latent_only:
-            self._refuse_for_latent(prefix_cache, speculate_k, lora_pool)
-            prefix_cache = False
-        elif self.ring_rows:
-            self._refuse_for_ring(prefix_cache, speculate_k, lora_pool)
-            prefix_cache = False
+        self._cache = family.init_cache(config, max_batch)
         # paged KV prefix cache (models/kvcache.py); RAY_TPU_KV_* env
         # knobs supply defaults, constructor args win
         from ray_tpu.util import envknobs
@@ -1193,9 +1055,7 @@ class ContinuousBatchingEngine:
         self._spec_events: List[Dict[str, Any]] = []
         if self.speculate_k:
             spec_metrics()  # lazy registration before the first tick
-        shape = self._cache[0]["k"].shape  # [maxB, S, H, hd] or [., ., row]
-        self._empty_prefix = jnp.zeros(
-            (len(self._cache), 0) + shape[2:], self._cache[0]["k"].dtype)
+        self._empty_prefix = jnp.zeros(spec.stack_shape(0), spec.dtype)
         # admission accounting (kv_stats / acceptance surface) — split
         # per phase: prefill admissions vs adoptions of KV prefilled on
         # another replica (serve/disagg.py)
@@ -1257,91 +1117,6 @@ class ContinuousBatchingEngine:
         self._thread.start()
 
     # ------------------------------------------------------------- API
-    @property
-    def stateful(self) -> bool:
-        """Whether a slot owns state with no sequence axis (a recurrent
-        family) beside, or instead of, rows of keys and values."""
-        return self._state_bytes_per_slot > 0
-
-    @property
-    def latent_only(self) -> bool:
-        """Whether the cache is latent rows alone: sequence entries of
-        one array each ("k" and no "v"), and no state."""
-        return latent_only(self._cache)
-
-    @staticmethod
-    def _refuse_for_latent(prefix_cache, speculate_k, lora_pool) -> None:
-        """What stands on the paged pool, or on keys and values in
-        pairs, refused in words for a cache of latent rows alone (module
-        docstring). `prefix_cache=None` simply builds no pool."""
-        if prefix_cache:
-            raise ValueError(
-                LATENT_ONLY + "the paged pool sizes a block [heads, "
-                "head_dim] from a key tensor and commits keys and values "
-                "side by side, and has no block of one latent row "
-                "(prefix_cache=True)")
-        if speculate_k:
-            raise ValueError(
-                LATENT_ONLY + "the pool proposer drafts from the paged "
-                "pool's token chains, which this cache has none of, and "
-                "the family's decode has no [B, k+1] verify form "
-                f"(speculate_k={speculate_k})")
-        if lora_pool is not None:
-            raise ValueError(
-                LATENT_ONLY + "the adapter pool's per-tenant prefix "
-                "namespaces are the paged pool's, and its targets are "
-                "the attention projections of the families it knows "
-                "(lora_pool)")
-
-    def _refuse_for_ring(self, prefix_cache, speculate_k, lora_pool
-                         ) -> None:
-        """What stands on the paged pool or on one block shape, refused
-        in words for a slab that holds a ring (module docstring).
-        `prefix_cache=None` simply builds no pool."""
-        rows = self.ring_rows
-        if prefix_cache:
-            raise ValueError(
-                RING + "a block-aligned prefix cannot be resumed where "
-                f"layers have forgotten all but their last {rows} rows, "
-                "and the paged pool has one block shape and one length "
-                "for every layer (prefix_cache=True)")
-        if speculate_k:
-            raise ValueError(
-                RING + "a rejected draft's rows need no copy-back only "
-                "while they stay masked, and in a ring they have "
-                "overwritten rows the window still sees; the pool "
-                "proposer drafts from the paged pool's token chains, "
-                "which this cache has none of "
-                f"(speculate_k={speculate_k})")
-        if lora_pool is not None:
-            raise ValueError(
-                RING + "the adapter pool's per-tenant prefix namespaces "
-                "are the paged pool's, which this cache cannot have "
-                "(lora_pool)")
-
-    @staticmethod
-    def _refuse_for_state(prefix_cache, speculate_k, lora_pool) -> None:
-        """What the engine takes for granted of keys and values and a
-        recurrent state does not give, refused in words (module
-        docstring). `prefix_cache=None` simply builds no pool."""
-        family = "this family's slots own recurrent state: "
-        if prefix_cache:
-            raise ValueError(
-                family + "a block-aligned prefix of keys and values "
-                "cannot resume a recurrence without a snapshot of the "
-                "state at that block, which the pool does not keep "
-                "(prefix_cache=True)")
-        if speculate_k:
-            raise ValueError(
-                family + "a rejected draft's rows need no copy-back, but "
-                "a state the draft has advanced cannot be un-advanced "
-                f"(speculate_k={speculate_k})")
-        if lora_pool is not None:
-            raise ValueError(
-                family + "the adapter pool's targets are attention "
-                "projections of every block, and the per-tenant prefix "
-                "namespaces need the prefix pool (lora_pool)")
-
     def submit(self, prompt_tokens, max_new_tokens: int,
                eos_token: Optional[int] = None,
                adapter_id: Optional[str] = None) -> "_Request":
@@ -1416,25 +1191,7 @@ class ContinuousBatchingEngine:
         engine would; without them drafting starts from the emitted
         history alone (correctness unaffected)."""
         plen = int(prompt_len)
-        if self.stateful:
-            raise ValueError(
-                "this family's slots own recurrent state: an adoption "
-                "carries ck/cv rows only, and a prefill replica has no "
-                "way to hand over the state its prefill ended in "
-                "(adopt_prefill)")
-        if self.latent_only:
-            raise ValueError(
-                LATENT_ONLY + "an adoption carries ck and cv rows in "
-                "pairs, as the paged pool and the transfer between "
-                "replicas speak them, and there are no values to carry "
-                "(adopt_prefill)")
-        if self.ring_rows:
-            raise ValueError(
-                RING + "an adoption carries ONE stack of ck and cv rows "
-                "of the prompt's length, as the paged pool and the "
-                "transfer between replicas speak them, and a ring's "
-                f"{self.ring_rows} rows are a stack of their own, each "
-                "row at its position mod the ring (adopt_prefill)")
+        refuse(self._spec, "adopt_prefill")
         if plen < 1:
             raise ValueError("prompt_len must be >= 1")
         if plen + max_new_tokens > self.config.max_seq_len:
@@ -1444,22 +1201,21 @@ class ContinuousBatchingEngine:
             # tier and the colocated fallback diverge at the
             # sequence-length boundary
             raise ValueError("prompt + max_new_tokens exceeds max_seq_len")
-        ref = self._cache[0]["k"]
         # validate the FULL layout on the caller's thread: a mismatch
         # surfacing inside _splice_slot would kill the decode loop
         # thread and wedge every request on this engine. Dtype is part
         # of the layout — asarray below would otherwise silently cast
         # a float32 prefill tier into a bfloat16 decode pool and break
         # bit-identity with no error anywhere.
-        want = (len(self._cache), plen) + tuple(ref.shape[2:])
+        want, dtype = self._spec.stack_shape(plen), self._spec.dtype
         got_k = jnp.asarray(ck)
         got_v = jnp.asarray(cv)
         if (tuple(got_k.shape) != want or tuple(got_v.shape) != want
-                or got_k.dtype != ref.dtype or got_v.dtype != ref.dtype):
+                or got_k.dtype != dtype or got_v.dtype != dtype):
             raise ValueError(
                 f"adopted KV layout k={tuple(got_k.shape)}:{got_k.dtype} "
                 f"v={tuple(got_v.shape)}:{got_v.dtype} does not match "
-                f"this engine's cache layout {want}:{ref.dtype} — the "
+                f"this engine's cache layout {want}:{dtype} — the "
                 f"prefill and decode tiers must run the same model "
                 f"config")
         ck, cv = got_k, got_v
@@ -1626,10 +1382,10 @@ class ContinuousBatchingEngine:
             stateful=self.stateful,
             latent_only=self.latent_only,
             prefill_counters=dict(self.prefill_counters),
-            state_bytes_per_slot=self._state_bytes_per_slot,
-            kv_bytes_per_token=self._kv_bytes_per_token,
+            state_bytes_per_slot=self._spec.state_bytes_per_slot,
+            kv_bytes_per_token=self._spec.kv_bytes_per_token,
             ring_rows=self.ring_rows,
-            slab=[dict(entry) for entry in self._slab],
+            slab=[dict(entry) for entry in self._spec.slab],
             lookahead_ticks=self.lookahead_ticks,
             lookahead_discarded=self.lookahead_discarded,
             # stream-gaps counted, those that held an admission, the
@@ -1772,7 +1528,7 @@ class ContinuousBatchingEngine:
             self._gaps.admitted()
             entry["splice_ms"] = (_now() - t0) * 1e3
             if self.stateful:
-                entry["state_bytes"] = self._state_bytes_per_slot
+                entry["state_bytes"] = self._spec.state_bytes_per_slot
         self.spliced_tokens += plen
 
     def _admission(self, it: Optional[Dict[str, Any]], req: _Request,
@@ -2037,9 +1793,9 @@ class ContinuousBatchingEngine:
             return None
         # what one layer's walk reads of the slab at the positions this
         # tick is launched with, ALL slots: the dead ones' one block too
-        rows_read = self.max_batch * self._slab_rows \
+        rows_read = self.max_batch * self._spec.rows \
             if self._walk_block is None else decode_rows_read(
-                at, self._walk_block, self._slab_rows)
+                at, self._walk_block, self._spec.rows)
         gaps = self._gaps
         t0 = _clock(it)
         if gaps.empty_from is not None:
@@ -2059,14 +1815,14 @@ class ContinuousBatchingEngine:
                     self._dirty[:] = False
                     gaps.launched()
                 tok, pos, mask = self._dev
-            counts = None
             if (self.lora_pool is not None
                     and bool(self._slot_adapter.any())):
-                cache, nxt, lp, pos_next = self.lora_pool.dispatch_tick(
-                    lambda la: _tick_lora(
-                        self.params, self.config, self._cache, tok, pos,
-                        la, mask),
-                    self._slot_adapter)
+                cache, nxt, lp, counts, pos_next = \
+                    self.lora_pool.dispatch_tick(
+                        lambda la: _tick(
+                            self.params, self.config, self._cache, tok,
+                            pos, mask, la),
+                        self._slot_adapter)
             else:
                 cache, nxt, lp, counts, pos_next = _tick(
                     self.params, self.config, self._cache, tok, pos, mask)

@@ -1,0 +1,200 @@
+"""What a model family gives the serving stack, and what its cache is
+made of: the ONE module the engine, the paged pool, the prefill tier and
+`generate` ask. A family is a file of its own that ends in `FAMILY =
+Family(...)` over the functions it has; nothing here, and no table or
+import list anywhere, names one.
+
+The cache (`init_cache(config, batch)`) is the family's own pytree, a
+list of entries of four kinds, told apart by SHAPE and never by a
+family's name. "k" and "v" `[B, rows, H, hd]`: keys and values with a
+sequence axis. "k" ALONE `[B, rows, ...]`: one latent row a token, from
+which keys and values are both made. Either with `rows` shorter than
+`config.max_seq_len`, beside entries of the full length: a RING (the
+token at position p lies in row `p mod rows`). Any other entry: a slot's
+STATE, with no sequence axis, written whole. What the serving stack
+takes for granted of full-length keys and values in pairs does not hold
+for the other three, and is refused in words (`refuse`) rather than
+served silently wrong.
+"""
+from __future__ import annotations
+
+import functools
+import sys
+from dataclasses import dataclass
+from typing import Any, Callable, Dict, List, Optional, Tuple
+
+import jax
+
+
+@dataclass(frozen=True)
+class Family:
+    """A family's functions. `forward_counted`: a `forward_cached` that
+    hands back a third value, a dict of small counters of the run (the
+    engine's admission record). `lora_targets(config)`: the leaves of
+    every block's ["attn"] an adapter applies to, `((name, in, out),
+    ...)`. `decode_walks`: the tick attends through
+    `ops/swa.decode_attention`, each slot's rows up to its position."""
+
+    config_type: type
+    init: Callable
+    forward: Callable
+    loss: Callable
+    partition_specs: Callable
+    init_cache: Callable
+    forward_cached: Callable
+    decode: Callable
+    forward_counted: Optional[Callable] = None
+    lora_targets: Optional[Callable] = None
+    decode_walks: bool = False
+
+
+def family_of(config: Any, support: str = "generation") -> Family:
+    """The record of the module that defines the config's class, or the
+    nearest base of it that has one (a subclass defined elsewhere is
+    served as its base is)."""
+    for cls in type(config).__mro__:
+        rec = getattr(sys.modules.get(cls.__module__), "FAMILY", None)
+        if isinstance(rec, Family) and isinstance(config, rec.config_type):
+            return rec
+    raise TypeError(f"no {support} support for {type(config).__name__}")
+
+
+def row_counts(cache) -> Dict[int, List[int]]:
+    """The sequence entries of a cache by their rows, in the order the
+    cache first shows each count: {rows: [entry indices]}."""
+    by_rows: Dict[int, List[int]] = {}
+    for i, blk in enumerate(cache):
+        if "k" in blk:
+            by_rows.setdefault(blk["k"].shape[1], []).append(i)
+    return by_rows
+
+
+def _nbytes(tree, per: int) -> int:
+    return sum(x.size * x.dtype.itemsize // per
+               for x in jax.tree.leaves(tree))
+
+
+class SlabSpec:
+    """The layout of a family's cache for `batch` slots, from the shapes
+    of `init_cache` alone (nothing is allocated): `slab_spec`."""
+
+    def __init__(self, config: Any, batch: int):
+        cache = jax.eval_shape(
+            lambda: family_of(config).init_cache(config, batch))
+        self.by_rows = row_counts(cache)
+        seq = [cache[i] for at in self.by_rows.values() for i in at]
+        self.paired = all("v" in blk for blk in seq)    # "v" beside "k"
+        self.latent_only = len(seq) == len(cache) and all(
+            len(blk) == 1 for blk in seq)
+        self.state_bytes_per_slot = sum(
+            _nbytes(blk, batch) for blk in cache if "k" not in blk)
+        self.stateful = self.state_bytes_per_slot > 0
+        self.kv_bytes_per_token = sum(
+            _nbytes(blk, batch * blk["k"].shape[1]) for blk in seq)
+        # kv_stats()["slab"]: for each row count, its layers and cost
+        self.slab = [{"rows": rows, "layers": len(at), "bytes_per_slot": sum(
+            _nbytes(cache[i], batch) for i in at)}
+            for rows, at in self.by_rows.items()]
+        # the longest entries' rows, and the first of them ("k": its
+        # shape and dtype); the ring's rows, None without one
+        self.rows = max(self.by_rows)
+        self.longest = cache[self.by_rows[self.rows][0]]["k"]
+        shortest = min(self.by_rows)
+        self.ring_rows = shortest if shortest < config.max_seq_len else None
+        self.kind = ("state" if self.stateful else "latent"
+                     if self.latent_only else "ring" if self.ring_rows
+                     else None)
+        # what a pool block, a cached prefix and a transfer are made of:
+        # a stack [layers, n, *row_shape] of the first entry's rows (an
+        # entry is a layer wherever such a stack is used)
+        self.layers = len(cache)
+        self.row_shape: Tuple[int, ...] = tuple(seq[0]["k"].shape[2:])
+        self.dtype = seq[0]["k"].dtype
+
+    def stack_shape(self, n: int) -> Tuple[int, ...]:
+        return (self.layers, n) + self.row_shape
+
+
+slab_spec = functools.lru_cache(maxsize=64)(SlabSpec)
+_KINDS = {
+    "state": "this family's slots own recurrent state: ",
+    "latent": ("this family's cache holds one latent row a token and no "
+               "values: "),
+    "ring": ("this family's cache holds a ring (layers that keep only their "
+             "last rows, fewer than max_seq_len): "),
+}
+_ENDS = {
+    "prefix_cache": " (prefix_cache=True)",
+    "speculate_k": " (speculate_k={k})",
+    "lora_pool": " (lora_pool)",
+    "adopt_prefill": " (adopt_prefill)",
+    "transfer": (", so it cannot be served disaggregated "
+                 "(engine.adopt_prefill refuses it too)"),
+}
+_WHY = {
+    ("state", "prefix_cache"):
+        "a block-aligned prefix of keys and values cannot resume a "
+        "recurrence without a snapshot of the state at that block, which "
+        "the pool does not keep",
+    ("state", "speculate_k"):
+        "a rejected draft's rows need no copy-back, but a state the draft "
+        "has advanced cannot be un-advanced",
+    ("state", "lora_pool"):
+        "the adapter pool's targets are attention projections of every "
+        "block, and the per-tenant prefix namespaces need the prefix pool",
+    ("state", "adopt_prefill"):
+        "an adoption carries ck/cv rows only, and a prefill replica has no "
+        "way to hand over the state its prefill ended in",
+    ("state", "transfer"): "a transfer carries ck/cv rows only",
+    ("latent", "prefix_cache"):
+        "the paged pool sizes a block [heads, head_dim] from a key tensor "
+        "and commits keys and values side by side, and has no block of one "
+        "latent row",
+    ("latent", "speculate_k"):
+        "the pool proposer drafts from the paged pool's token chains, "
+        "which this cache has none of, and the family's decode has no "
+        "[B, k+1] verify form",
+    ("latent", "lora_pool"):
+        "the adapter pool's per-tenant prefix namespaces are the paged "
+        "pool's, and its targets are the attention projections of the "
+        "families it knows",
+    ("latent", "adopt_prefill"):
+        "an adoption carries ck and cv rows in pairs, as the paged pool and "
+        "the transfer between replicas speak them, and there are no values "
+        "to carry",
+    ("latent", "transfer"):
+        "a transfer carries ck and cv rows in pairs and the prefill tier's "
+        "pool commits them side by side",
+    ("ring", "prefix_cache"):
+        "a block-aligned prefix cannot be resumed where layers have "
+        "forgotten all but their last {rows} rows, and the paged pool has "
+        "one block shape and one length for every layer",
+    ("ring", "speculate_k"):
+        "a rejected draft's rows need no copy-back only while they stay "
+        "masked, and in a ring they have overwritten rows the window still "
+        "sees; the pool proposer drafts from the paged pool's token chains, "
+        "which this cache has none of",
+    ("ring", "lora_pool"):
+        "the adapter pool's per-tenant prefix namespaces are the paged "
+        "pool's, which this cache cannot have",
+    ("ring", "adopt_prefill"):
+        "an adoption carries ONE stack of ck and cv rows of the prompt's "
+        "length, as the paged pool and the transfer between replicas speak "
+        "them, and a ring's {rows} rows are a stack of their own, each row "
+        "at its position mod the ring",
+    ("ring", "transfer"):
+        "a transfer carries ONE stack of ck and cv rows of the prompt's "
+        "length and the prefill tier's pool has one block shape",
+}
+
+
+def refuse(spec: SlabSpec, capability: str, asked: Any = True, **detail
+           ) -> None:
+    """The ValueError that names why a cache of `spec.kind` cannot give
+    `capability`, where it was `asked` for (an option left to its
+    default asks for nothing: `prefix_cache=None` simply builds no
+    pool). Nothing for a cache of full-length keys and values."""
+    if asked and spec.kind is not None:
+        raise ValueError((_KINDS[spec.kind] + _WHY[spec.kind, capability]
+                          + _ENDS[capability]).format(
+                              rows=spec.ring_rows, **detail))

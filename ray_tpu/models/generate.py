@@ -18,89 +18,28 @@ from typing import Any, Callable, Iterator, Optional
 import jax
 import jax.numpy as jnp
 
-from .llama import LlamaConfig, init_kv_cache, llama_forward_cached
+from .family import family_of
 
 
 def _model_fns(config):
-    """(forward_cached, init_cache, ragged_decode) for the config's
-    model family — generation and the continuous-batching engine are
-    model-agnostic over this cache protocol. A `forward_cached` may
-    carry, as its attribute `with_counters`, a form of itself that hands
-    back a third value, a dict of small counters of the run (the engine's
-    admission record), as `ragged_decode` may hand one back itself."""
-    if isinstance(config, LlamaConfig):
-        from .llama import llama_decode
-
-        return llama_forward_cached, init_kv_cache, llama_decode
-    from .gpt2 import (GPT2Config, gpt2_decode, gpt2_forward_cached,
-                       gpt2_init_kv_cache)
-
-    if isinstance(config, GPT2Config):
-        return gpt2_forward_cached, gpt2_init_kv_cache, gpt2_decode
-    from .nemotron_h import (NemotronHConfig, nemotron_h_decode,
-                             nemotron_h_forward_cached,
-                             nemotron_h_init_cache)
-
-    if isinstance(config, NemotronHConfig):
-        # a cache with state beside keys and values: module docstring of
-        # models/nemotron_h.py, and the engine's splice
-        return (nemotron_h_forward_cached, nemotron_h_init_cache,
-                nemotron_h_decode)
-    from .kimi_linear import (KimiLinearConfig, kimi_linear_decode,
-                              kimi_linear_forward_cached,
-                              kimi_linear_init_cache)
-
-    if isinstance(config, KimiLinearConfig):
-        # state beside ONE latent row a token: module docstring of
-        # models/kimi_linear.py, and the engine's third kind of entry
-        return (kimi_linear_forward_cached, kimi_linear_init_cache,
-                kimi_linear_decode)
-    from .deepseek_v2 import (DeepseekV2Config, deepseek_v2_decode,
-                              deepseek_v2_forward_cached,
-                              deepseek_v2_init_cache)
-
-    if isinstance(config, DeepseekV2Config):
-        # ONE latent row a token and nothing else: module docstring of
-        # models/deepseek_v2.py
-        return (deepseek_v2_forward_cached, deepseek_v2_init_cache,
-                deepseek_v2_decode)
-    from .smallthinker import (SmallThinkerConfig, smallthinker_decode,
-                               smallthinker_forward_cached,
-                               smallthinker_init_cache)
-
-    if isinstance(config, SmallThinkerConfig):
-        # keys and values at TWO row counts, the shorter a ring: module
-        # docstring of models/smallthinker.py, and the engine's fourth
-        # kind of entry
-        return (smallthinker_forward_cached, smallthinker_init_cache,
-                smallthinker_decode)
-    from .jamba import (JambaConfig, jamba_decode, jamba_forward_cached,
-                        jamba_init_cache)
-
-    if isinstance(config, JambaConfig):
-        # Mamba-1 state a RUN of layers beside ONE key-value head's rows:
-        # module docstring of models/jamba.py
-        return jamba_forward_cached, jamba_init_cache, jamba_decode
-    raise TypeError(f"no generation support for {type(config).__name__}")
+    """(forward_cached, init_cache, decode) of the config's family: the
+    three of its record (`models/family.py`) the older callers unpack."""
+    f = family_of(config)
+    return f.forward_cached, f.init_cache, f.decode
 
 
 def lora_targets(config):
     """The LoRA-target leaves of a model family as
     ``((leaf_name, in_dim, out_dim), ...)`` — each names an entry of
-    every block's ``["attn"]`` sub-tree. This table is the ONE place
-    the serving stack (serve/lora.py AdapterPool, the engine's
+    every block's ``["attn"]`` sub-tree, from the family's record. The
+    ONE place the serving stack (serve/lora.py AdapterPool, the engine's
     mixed-tenant decode, the per-tenant online trainer) learns which
     projections an adapter applies to, so the pool layout, the decode
     gather, and the prefill merge can never disagree."""
-    if isinstance(config, LlamaConfig):
-        kv_dim = config.num_kv_heads * config.head_dim
-        return (("wq", config.d_model, config.d_model),
-                ("wv", config.d_model, kv_dim))
-    from .gpt2 import GPT2Config
-
-    if isinstance(config, GPT2Config):
-        return (("qkv", config.d_model, 3 * config.d_model),)
-    raise TypeError(f"no LoRA support for {type(config).__name__}")
+    targets = family_of(config, "LoRA").lora_targets
+    if targets is None:
+        raise TypeError(f"no LoRA support for {type(config).__name__}")
+    return targets(config)
 
 
 def merge_lora_params(params, config, lora):
@@ -146,7 +85,7 @@ def _sample_fn(vocab_size: int, temperature: float, top_k: int):
 
 @functools.partial(jax.jit, static_argnums=(2,))
 def _prefill(params, prompt, config, cache):
-    fwd = _model_fns(config)[0]
+    fwd = family_of(config).forward_cached
     logits, cache = fwd(params, prompt, config, cache, 0)
     return logits[:, -1], cache
 
@@ -155,7 +94,7 @@ def _decode_many(params, config, cache, first_token, start_pos, steps,
                  key, temperature, top_k):
     sample = _sample_fn(config.vocab_size, temperature, top_k)
 
-    fwd = _model_fns(config)[0]
+    fwd = family_of(config).forward_cached
 
     def step(carry, _):
         cache, tok, pos, key = carry
@@ -173,7 +112,7 @@ _decode_many_jit = jax.jit(
     _decode_many, static_argnums=(1, 5, 7, 8), donate_argnums=(2,))
 
 
-def generate(params: Any, config: LlamaConfig, prompt: jax.Array, *,
+def generate(params: Any, config: Any, prompt: jax.Array, *,
              max_new_tokens: int, temperature: float = 0.0,
              top_k: int = 0, key: Optional[jax.Array] = None,
              eos_token: Optional[int] = None) -> jax.Array:
@@ -187,7 +126,7 @@ def generate(params: Any, config: LlamaConfig, prompt: jax.Array, *,
             f"prompt ({t0}) + max_new_tokens ({max_new_tokens}) exceeds "
             f"max_seq_len ({config.max_seq_len})")
     key = key if key is not None else jax.random.PRNGKey(0)
-    cache = _model_fns(config)[1](config, b)
+    cache = family_of(config).init_cache(config, b)
     last_logits, cache = _prefill(params, prompt, config, cache)
     key, k0 = jax.random.split(key)
     first = _sample_fn(config.vocab_size, temperature, top_k)(
@@ -208,7 +147,7 @@ def generate(params: Any, config: LlamaConfig, prompt: jax.Array, *,
     return toks
 
 
-def stream_generate(params: Any, config: LlamaConfig, prompt: jax.Array,
+def stream_generate(params: Any, config: Any, prompt: jax.Array,
                     *, max_new_tokens: int, temperature: float = 0.0,
                     top_k: int = 0, key: Optional[jax.Array] = None,
                     eos_token: Optional[int] = None
@@ -222,7 +161,7 @@ def stream_generate(params: Any, config: LlamaConfig, prompt: jax.Array,
         raise ValueError("prompt + max_new_tokens exceeds max_seq_len")
     key = key if key is not None else jax.random.PRNGKey(0)
     sample = _sample_fn(config.vocab_size, temperature, top_k)
-    cache = _model_fns(config)[1](config, b)
+    cache = family_of(config).init_cache(config, b)
     last_logits, cache = _prefill(params, prompt, config, cache)
     key, sub = jax.random.split(key)
     tok = sample(sub, last_logits)
@@ -249,7 +188,7 @@ def _stream_step(params, cache, config, tok, pos, temperature, top_k,
     # module-level so the compiled step is shared across every
     # stream_generate call with the same (config, sampling) — a serving
     # replica must not recompile per request
-    fwd = _model_fns(config)[0]
+    fwd = family_of(config).forward_cached
     logits, cache = fwd(params, tok[:, None], config, cache, pos)
     key, sub = jax.random.split(key)
     nxt = _sample_fn(config.vocab_size, temperature, top_k)(

@@ -25,6 +25,7 @@ from jax.sharding import PartitionSpec as P
 
 from ..ops.attention import flash_attention
 from ..ops.layers import layer_norm
+from .family import Family
 
 Params = Dict[str, Any]
 
@@ -235,7 +236,7 @@ def _cache_attention(q: jax.Array, ck: jax.Array, cv: jax.Array,
     ck/cv [B, S, g, W] hold p = W // head_dim heads to a row; query
     (b, j) sees rows <= positions[b, j] (positions [B, t] or [1, t]).
     Rows are contracted whole, the grouped form of
-    llama._cache_attention with g rows and p queries a row: the query
+    ops/swa.cache_attention with g rows and p queries a row: the query
     of head r is its row with every lane outside its own head_dim set
     to zero, so its scores are exactly its own (the other lanes add
     0 * k), and its output is its own lanes of the row its
@@ -459,3 +460,14 @@ def gpt2_partition_specs(config: GPT2Config) -> Params:
         "ln_f": {"scale": P(), "bias": P()},
         "blocks": [block for _ in range(config.num_layers)],
     }
+
+
+def gpt2_lora_targets(config: GPT2Config):
+    return (("qkv", config.d_model, 3 * config.d_model),)
+
+
+FAMILY = Family(
+    config_type=GPT2Config, init=gpt2_init, forward=gpt2_forward,
+    loss=gpt2_loss, partition_specs=gpt2_partition_specs,
+    init_cache=gpt2_init_kv_cache, forward_cached=gpt2_forward_cached,
+    decode=gpt2_decode, lora_targets=gpt2_lora_targets)

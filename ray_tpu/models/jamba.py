@@ -20,7 +20,7 @@ embedding (tied).
              through the prompt form over the run alone
              (`ops/swa.prompt_attention`, whose block follows from the
              group's size); a suffix and the decode tick through
-             `llama._cache_attention` over the cache as it lies.
+             `ops/swa.cache_attention` over the cache as it lies.
   mlp        SwiGLU, `W_down(silu(h W_gate) * h W_up)`, dense in every
              layer (`num_experts` 1).
 
@@ -58,11 +58,11 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..ops.layers import rms_norm
+from ..ops.layers import mm, rms_norm
 from ..ops.mamba1 import selective_scan, selective_step
 from ..ops.mamba2 import causal_conv
-from ..ops.swa import prompt_attention
-from .llama import _cache_attention, _mm
+from ..ops.swa import cache_attention, prompt_attention
+from .family import Family
 
 Params = Dict[str, Any]
 F32 = jnp.float32
@@ -208,7 +208,7 @@ def _mamba_inputs(x: jax.Array, p: Params, c: JambaConfig, tail: jax.Array):
     float32, a [N, C], the inputs of the convolution for the next tail)."""
     m = p["mamba"]
     h = _norm(x, p["norm1"]["scale"], c)
-    raw, z = jnp.split(_mm(h, m["w_in"]), 2, axis=-1)
+    raw, z = jnp.split(mm(h, m["w_in"]), 2, axis=-1)
     u, _ = causal_conv(raw, tail, m["conv_w"], m["conv_b"])
     # the inputs the convolution saw (the tail, then the run): a ragged
     # block takes its new tail from among them
@@ -332,9 +332,9 @@ def _qkv(x: jax.Array, p: Params, c: JambaConfig):
     b, t, _ = x.shape
     h = _norm(x, p["norm1"]["scale"], c)
     a = p["attn"]
-    return (_mm(h, a["wq"]).reshape(b, t, c.num_heads, c.head_dim),
-            _mm(h, a["wk"]).reshape(b, t, c.num_kv_heads, c.head_dim),
-            _mm(h, a["wv"]).reshape(b, t, c.num_kv_heads, c.head_dim))
+    return (mm(h, a["wq"]).reshape(b, t, c.num_heads, c.head_dim),
+            mm(h, a["wk"]).reshape(b, t, c.num_kv_heads, c.head_dim),
+            mm(h, a["wv"]).reshape(b, t, c.num_kv_heads, c.head_dim))
 
 
 def _attn_out(a: jax.Array, p: Params) -> jax.Array:
@@ -369,7 +369,7 @@ def _attn_run(x: jax.Array, p: Params, c: JambaConfig, cache: Params | None,
         else:
             positions = jnp.broadcast_to(pos + jnp.arange(t)[None, :],
                                          (b, t))
-            a = _cache_attention(q, cache["k"], cache["v"], positions)
+            a = cache_attention(q, cache["k"], cache["v"], positions)
         return x + _attn_out(a, p), cache
 
 
@@ -381,7 +381,7 @@ def _attn_tick(x: jax.Array, p: Params, c: JambaConfig, cache: Params,
         at = (jnp.arange(x.shape[0])[:, None], positions)
         ck = cache["k"].at[at].set(k.astype(cache["k"].dtype))
         cv = cache["v"].at[at].set(v.astype(cache["v"].dtype))
-        a = _cache_attention(q, ck, cv, positions)
+        a = cache_attention(q, ck, cv, positions)
         return x + _attn_out(a, p), {"k": ck, "v": cv}
 
 
@@ -500,12 +500,9 @@ def jamba_forward_counted(params: Params, tokens: jax.Array,
 def jamba_forward_cached(params: Params, tokens: jax.Array,
                          config: JambaConfig, cache: list, pos: Any):
     """`jamba_forward_counted` less its counters: the cache protocol's
-    (logits, cache). The engine's prefill finds the counted form under
-    `with_counters`."""
+    (logits, cache). The engine's prefill takes the counted form
+    (`FAMILY.forward_counted`)."""
     return jamba_forward_counted(params, tokens, config, cache, pos)[:2]
-
-
-jamba_forward_cached.with_counters = jamba_forward_counted
 
 
 def jamba_decode(params: Params, tokens: jax.Array, config: JambaConfig,
@@ -549,3 +546,11 @@ def jamba_partition_specs(config: JambaConfig) -> Params:
     }
     return {"tok_emb": P("tp", "fsdp"), "norm_f": norm,
             "runs": [kinds[kind] for kind, _ in config.runs]}
+
+
+FAMILY = Family(
+    config_type=JambaConfig, init=jamba_init, forward=jamba_forward,
+    loss=jamba_loss, partition_specs=jamba_partition_specs,
+    init_cache=jamba_init_cache, forward_cached=jamba_forward_cached,
+    decode=jamba_decode, forward_counted=jamba_forward_counted,
+    decode_walks=True)

@@ -47,11 +47,11 @@ from jax.sharding import PartitionSpec as P
 
 from ..ops.grouped_moe import held_experts, sigmoid_topk_route
 from ..ops.kda import kda_scan, kda_step, l2_normalize
-from ..ops.layers import rms_norm
+from ..ops.layers import mm, rms_norm
 from ..ops.mamba2 import causal_conv
 from ..ops.mla import (absorbed_attention, expanded_attention, latent_row,
                        row_width)
-from .llama import _mm
+from .family import Family
 
 Params = Dict[str, Any]
 F32 = jnp.float32
@@ -221,14 +221,14 @@ def _kda(h: jax.Array, p: Params, c: KimiLinearConfig, cache: Params
     b, t, _ = h.shape
     nh, dk, lo = c.kda_num_heads, c.kda_head_dim, c.kda_low_rank
     qkv, d_lo, g_lo, beta = jnp.split(
-        _mm(h, p["w_in"]),
+        mm(h, p["w_in"]),
         [3 * c.kda_dim, 3 * c.kda_dim + lo, 3 * c.kda_dim + 2 * lo], -1)
     qkv, tail = causal_conv(qkv, cache["conv"], p["conv_w"],
                             jnp.zeros((), c.dtype))
     q, k, v = (x.reshape(b, t, nh, dk) for x in jnp.split(qkv, 3, -1))
     q, k = l2_normalize(q) * dk ** -0.5, l2_normalize(k)
     g = -jnp.exp(p["A_log"])[:, None] * jax.nn.softplus(
-        _mm(d_lo, p["w_decay"]).astype(F32)
+        mm(d_lo, p["w_decay"]).astype(F32)
         + p["dt_bias"]).reshape(b, t, nh, dk)
     beta = jax.nn.sigmoid(beta.astype(F32))
     if t == 1:
@@ -237,9 +237,9 @@ def _kda(h: jax.Array, p: Params, c: KimiLinearConfig, cache: Params
         o = o[:, None]
     else:
         o, state = kda_scan(q, k, v, g, beta, cache["state"], c.chunk_size)
-    gate = jax.nn.sigmoid(_mm(g_lo, p["w_gate"]).astype(F32))
+    gate = jax.nn.sigmoid(mm(g_lo, p["w_gate"]).astype(F32))
     o = rms_norm(o, p["norm"], c.norm_eps).reshape(b, t, c.kda_dim) * gate
-    return (_mm(o.astype(h.dtype), p["w_out"]),
+    return (mm(o.astype(h.dtype), p["w_out"]),
             {"state": state, "conv": tail})
 
 
@@ -248,9 +248,9 @@ def _mla_inputs(h: jax.Array, p: Params, c: KimiLinearConfig):
     latent c [B,T,rank], the shared key part k_r [B,T,d_r], W_kvb as
     [rank, H, d_n + d_v])."""
     b, t, _ = h.shape
-    q = _mm(h, p["wq"]).reshape(
+    q = mm(h, p["wq"]).reshape(
         b, t, c.num_heads, c.qk_nope_head_dim + c.qk_rope_head_dim)
-    lat, k_r = jnp.split(_mm(h, p["w_kva"]), [c.kv_lora_rank], -1)
+    lat, k_r = jnp.split(mm(h, p["w_kva"]), [c.kv_lora_rank], -1)
     w_kvb = p["w_kvb"].reshape(c.kv_lora_rank, c.num_heads,
                                c.qk_nope_head_dim + c.v_head_dim)
     return (q[..., :c.qk_nope_head_dim], q[..., c.qk_nope_head_dim:],
@@ -258,7 +258,7 @@ def _mla_inputs(h: jax.Array, p: Params, c: KimiLinearConfig):
 
 
 def _mla_out(a: jax.Array, p: Params) -> jax.Array:
-    return _mm(a.reshape(a.shape[:2] + (-1,)), p["wo"])
+    return mm(a.reshape(a.shape[:2] + (-1,)), p["wo"])
 
 
 def _mla_prefill(h: jax.Array, p: Params, c: KimiLinearConfig,
@@ -291,7 +291,7 @@ def _mla_decode(h: jax.Array, p: Params, c: KimiLinearConfig,
 
 def _dense_mlp(h: jax.Array, p: Params) -> jax.Array:
     mid = _swiglu(jnp.dot(h, p["w1"], preferred_element_type=F32))
-    return _mm(mid.astype(h.dtype), p["w2"])
+    return mm(mid.astype(h.dtype), p["w2"])
 
 
 def expert_layer(h: jax.Array, p: Params, c: KimiLinearConfig
@@ -308,7 +308,7 @@ def expert_layer(h: jax.Array, p: Params, c: KimiLinearConfig
     routed, counts = held_experts(flat, chosen, weights, p["w1"], p["w2"],
                                   c.first_expert, _swiglu)
     mid = _swiglu(jnp.dot(flat, p["s1"], preferred_element_type=F32))
-    out = routed.astype(h.dtype) + _mm(mid.astype(h.dtype), p["s2"])
+    out = routed.astype(h.dtype) + mm(mid.astype(h.dtype), p["s2"])
     local = chosen.reshape(-1) - c.first_expert
     here = (local >= 0) & (local < c.experts_held)
     hit = jnp.zeros((c.experts_held + 1,), bool).at[
@@ -495,3 +495,11 @@ def kimi_linear_partition_specs(config: KimiLinearConfig) -> Params:
               for i, kind in enumerate(config.pattern)]
     return {"tok_emb": P("tp", "fsdp"), "norm_f": norm,
             "lm_head": P("fsdp", "tp"), "blocks": blocks}
+
+
+FAMILY = Family(
+    config_type=KimiLinearConfig, init=kimi_linear_init,
+    forward=kimi_linear_forward, loss=kimi_linear_loss,
+    partition_specs=kimi_linear_partition_specs,
+    init_cache=kimi_linear_init_cache,
+    forward_cached=kimi_linear_forward_cached, decode=kimi_linear_decode)

@@ -419,16 +419,16 @@ class PagedKVCache:
 
     def __init__(self, config: Any, *, block_size: int, num_blocks: int,
                  int8: Optional[bool] = None):
-        from .generate import _model_fns
+        from .family import slab_spec
 
         if block_size < 1 or num_blocks < 1:
             raise ValueError("block_size and num_blocks must be >= 1")
-        probe = _model_fns(config)[1](config, 1, max_len=block_size)
-        _, _, heads, head_dim = probe[0]["k"].shape
-        self.layers = len(probe)
+        spec = slab_spec(config, 1)
+        heads, head_dim = spec.row_shape
+        self.layers = spec.layers
         self.block_size = int(block_size)
         self.num_blocks = int(num_blocks)
-        self.dtype = probe[0]["k"].dtype
+        self.dtype = spec.dtype
         self.int8 = kv_int8_default() if int8 is None else bool(int8)
         self._heads = (int(heads), int(head_dim))
         shape = (self.layers, self.num_blocks, self.block_size,

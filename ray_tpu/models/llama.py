@@ -10,7 +10,7 @@ static shapes, one spec tree serving dp/fsdp/tp by changing only the mesh.
 GQA + tp note: num_kv_heads must divide by the tp degree in use (as in
 every tp Llama deployment). The training block repeats kv heads to query
 heads for the flash kernel; the cache paths contract per kv group
-(`_cache_attention`), because a repeat of the whole KV slab IS an HBM
+(`cache_attention`), because a repeat of the whole KV slab IS an HBM
 copy: XLA cannot fuse it into the reduction that reads it.
 
 A run of tokens attends in one of THREE forms, chosen from what
@@ -21,11 +21,11 @@ values through the prompt form (`ops/swa.prompt_attention`: no [H, T, S]
 scores, nothing read of the slab's empty rows). A run of at most
 `ops/swa.DECODE_ROWS` rows is a tick's (one token a slot, or the
 speculative verify's k + 1) and takes the decode form
-(`ops/swa.decode_attention`, through `_cache_attention`): each slot's
+(`ops/swa.decode_attention`, through `cache_attention`): each slot's
 rows up to its own position, block by block, and not the
 `max_batch x max_seq_len` rows of the slab. Every other run (a suffix
 on top of a cached prefix, a prompt of at most one block) attends over
-the whole slab, masked (`_slab_attention`). The cache comes back the
+the whole slab, masked (`slab_attention`). The cache comes back the
 same whichever ran, so the forms differ in their reduction shapes
 alone: a prompt replayed through a cached prefix meets another program,
 and a near-tie of two logits may fall the other way.
@@ -41,9 +41,10 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..ops.attention import flash_attention
-from ..ops.layers import rms_norm
+from ..ops.layers import mm, rms_norm
 from ..ops.rope import apply_rope, rope_table
-from ..ops.swa import DECODE_ROWS, decode_attention, prompt_attention
+from ..ops.swa import cache_attention, prompt_attention
+from .family import Family
 
 Params = Dict[str, Any]
 
@@ -129,66 +130,22 @@ def llama_init(config: LlamaConfig, key: jax.Array) -> Params:
     return params
 
 
-def _mm(x: jax.Array, w: jax.Array) -> jax.Array:
-    return jnp.dot(x, w, preferred_element_type=jnp.float32).astype(x.dtype)
-
-
 def _qkv(h: jax.Array, p: Params, c: LlamaConfig):
     b, t, _ = h.shape
-    q = _mm(h, p["attn"]["wq"]).reshape(b, t, c.num_heads, c.head_dim)
-    k = _mm(h, p["attn"]["wk"]).reshape(b, t, c.num_kv_heads, c.head_dim)
-    v = _mm(h, p["attn"]["wv"]).reshape(b, t, c.num_kv_heads, c.head_dim)
+    q = mm(h, p["attn"]["wq"]).reshape(b, t, c.num_heads, c.head_dim)
+    k = mm(h, p["attn"]["wk"]).reshape(b, t, c.num_kv_heads, c.head_dim)
+    v = mm(h, p["attn"]["wv"]).reshape(b, t, c.num_kv_heads, c.head_dim)
     return q, k, v
 
 
 def _repeat_kv(k: jax.Array, v: jax.Array, c: LlamaConfig):
     # llama_block's alone (the flash kernel wants equal head counts):
-    # on a KV slab this is an HBM copy, see _cache_attention
+    # on a KV slab this is an HBM copy, see cache_attention
     if c.num_kv_heads != c.num_heads:  # GQA: broadcast kv to query heads
         rep = c.num_heads // c.num_kv_heads
         k = jnp.repeat(k, rep, axis=2)
         v = jnp.repeat(v, rep, axis=2)
     return k, v
-
-
-def _cache_attention(q: jax.Array, ck: jax.Array, cv: jax.Array,
-                     positions: jax.Array) -> jax.Array:
-    """Masked attention of q [B, t, n_heads, hd] over the cache as it
-    lies, ck/cv [B, S, n_kv, hd] in their own dtype; query (b, j) sees
-    rows <= positions[b, j]. Heads are contracted per kv group: head h
-    is (g, r) = (h // rep, h % rep), the order jnp.repeat(axis=2) gave,
-    so `wo` sees the same columns. Returns [B, t, n_heads hd] (d_model
-    here; `models/smallthinker.py`, whose heads do not add up to its
-    hidden size, calls this too).
-
-    A run of at most `DECODE_ROWS` rows is a tick's (one token a slot,
-    or the speculative verify's k + 1) and takes the decode form
-    (`ops/swa.decode_attention`): each slot's rows up to its position,
-    block by block. A longer run (a suffix on a cached prefix, a prompt
-    of at most one block, an uncached forward) takes the slab form: its
-    [t, S] scores spread the slab's read over their rows, and nothing
-    says its slots are short. The choice reads `q`'s shape alone."""
-    if q.shape[1] <= DECODE_ROWS:
-        return decode_attention(q, ck, cv, positions)
-    return _slab_attention(q, ck, cv, positions)
-
-
-def _slab_attention(q: jax.Array, ck: jax.Array, cv: jax.Array,
-                    positions: jax.Array) -> jax.Array:
-    """`_cache_attention` over ALL rows of ALL slots: float32 scores of
-    every query against the whole entry, masked afterwards."""
-    b, t, heads, hd = q.shape
-    groups = ck.shape[2]
-    qg = q.reshape(b, t, groups, heads // groups, hd)
-    scores = jnp.einsum("btgrd,bsgd->bgrts", qg, ck,
-                        preferred_element_type=jnp.float32)
-    scores = scores / (hd ** 0.5)
-    col = jnp.arange(ck.shape[1])[None, None, None, None, :]
-    visible = col <= positions[:, None, None, :, None]
-    scores = jnp.where(visible, scores, -1e30)
-    probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-    a = jnp.einsum("bgrts,bsgd->btgrd", probs, cv)
-    return a.reshape(b, t, heads * hd)
 
 
 # the prompt form's block: the kernel's default and the one measured
@@ -211,9 +168,9 @@ def _is_prompt(pos: Any, t: int) -> bool:
 
 def _mlp_res(x: jax.Array, p: Params) -> jax.Array:
     h = rms_norm(x, p["ffn_norm"]["scale"])
-    gate = jax.nn.silu(_mm(h, p["mlp"]["w_gate"]).astype(jnp.float32))
-    up = _mm(h, p["mlp"]["w_up"]).astype(jnp.float32)
-    return x + _mm((gate * up).astype(x.dtype), p["mlp"]["w_down"])
+    gate = jax.nn.silu(mm(h, p["mlp"]["w_gate"]).astype(jnp.float32))
+    up = mm(h, p["mlp"]["w_up"]).astype(jnp.float32)
+    return x + mm((gate * up).astype(x.dtype), p["mlp"]["w_down"])
 
 
 def llama_block(x: jax.Array, p: Params, cos: jax.Array, sin: jax.Array,
@@ -226,7 +183,7 @@ def llama_block(x: jax.Array, p: Params, cos: jax.Array, sin: jax.Array,
     k = apply_rope(k, cos, sin)
     k, v = _repeat_kv(k, v, c)
     a = flash_attention(q, k, v, True).reshape(b, t, c.d_model)
-    x = x + _mm(a, p["attn"]["wo"])
+    x = x + mm(a, p["attn"]["wo"])
     return _mlp_res(x, p)
 
 
@@ -238,10 +195,10 @@ def llama_block_cached(x: jax.Array, p: Params, cos: jax.Array,
     cache is the full [B, S, n_kv, hd] window and masking does the
     truncation, the standard fixed-shape TPU decode layout. Heads are
     contracted per kv group against the cache as it lies
-    (`_cache_attention`); `_repeat_kv` remains for `llama_block` alone,
+    (`cache_attention`); `_repeat_kv` remains for `llama_block` alone,
     whose flash kernel wants equal head counts. A prompt from position
     0 (`_is_prompt`) attends over its own rows alone, as the cache holds
-    them: what `_cache_attention` would see of the slab, without the
+    them: what `cache_attention` would see of the slab, without the
     rows past the run.
     Returns (x, new_cache_for_this_block)."""
     c = config
@@ -261,9 +218,9 @@ def llama_block_cached(x: jax.Array, p: Params, cos: jax.Array,
         a = a.reshape(b, t, c.num_heads * c.head_dim)
     else:
         # a suffix or a short prompt over the whole slab, a step of
-        # `generate()` through the decode form: `_cache_attention`
-        a = _cache_attention(q, ck, cv, positions)
-    x = x + _mm(a, p["attn"]["wo"])
+        # `generate()` through the decode form: `cache_attention`
+        a = cache_attention(q, ck, cv, positions)
+    x = x + mm(a, p["attn"]["wo"])
     return _mlp_res(x, p), {"k": ck, "v": cv}
 
 
@@ -296,7 +253,7 @@ def llama_block_decode(x: jax.Array, p: Params, cos: jax.Array,
     bit-identity the speculation oracle rests on. Rows past a query's
     position stay invisible, which is also why rejected draft rows
     need no rollback: they are overwritten before any later query can
-    see them. Heads are contracted per kv group (`_cache_attention`),
+    see them. Heads are contracted per kv group (`cache_attention`),
     never against a repeated slab; `_repeat_kv` remains for
     `llama_block` alone, whose flash kernel wants equal head counts.
 
@@ -314,10 +271,10 @@ def llama_block_decode(x: jax.Array, p: Params, cos: jax.Array,
     else:
         from ..ops.layers import lora_delta
 
-        q = _mm(h, p["attn"]["wq"]) + lora_delta(
+        q = mm(h, p["attn"]["wq"]) + lora_delta(
             h, *lora["wq"], lora["scale"])
-        k = _mm(h, p["attn"]["wk"])
-        v = _mm(h, p["attn"]["wv"]) + lora_delta(
+        k = mm(h, p["attn"]["wk"])
+        v = mm(h, p["attn"]["wv"]) + lora_delta(
             h, *lora["wv"], lora["scale"])
         q = q.reshape(b, t, c.num_heads, c.head_dim)
         k = k.reshape(b, t, c.num_kv_heads, c.head_dim)
@@ -330,8 +287,8 @@ def llama_block_decode(x: jax.Array, p: Params, cos: jax.Array,
         k.astype(cache["k"].dtype))
     cv = cache["v"].at[rows[:, None], positions].set(
         v.astype(cache["v"].dtype))
-    a = _cache_attention(q, ck, cv, positions)
-    x = x + _mm(a, p["attn"]["wo"])
+    a = cache_attention(q, ck, cv, positions)
+    x = x + mm(a, p["attn"]["wo"])
     return _mlp_res(x, p), {"k": ck, "v": cv}
 
 
@@ -443,3 +400,16 @@ def llama_partition_specs(config: LlamaConfig) -> Params:
         "lm_head": P("fsdp", "tp"),
         "blocks": [block for _ in range(config.num_layers)],
     }
+
+
+def llama_lora_targets(config: LlamaConfig):
+    kv_dim = config.num_kv_heads * config.head_dim
+    return (("wq", config.d_model, config.d_model),
+            ("wv", config.d_model, kv_dim))
+
+
+FAMILY = Family(
+    config_type=LlamaConfig, init=llama_init, forward=llama_forward,
+    loss=llama_loss, partition_specs=llama_partition_specs,
+    init_cache=init_kv_cache, forward_cached=llama_forward_cached,
+    decode=llama_decode, lora_targets=llama_lora_targets, decode_walks=True)

@@ -15,10 +15,10 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..ops.layers import rms_norm
+from ..ops.layers import mm, rms_norm
 from ..ops.moe import load_balancing_loss, moe_ffn
 from ..ops.rope import rope_table
-from .llama import LlamaConfig, _mm
+from .llama import LlamaConfig
 
 Params = Dict[str, Any]
 
@@ -119,9 +119,9 @@ def _moe_block(x: jax.Array, p: Params, cos, sin,
     c = config
     b, t, _ = x.shape
     h = rms_norm(x, p["attn_norm"]["scale"])
-    q = _mm(h, p["attn"]["wq"]).reshape(b, t, c.num_heads, c.head_dim)
-    k = _mm(h, p["attn"]["wk"]).reshape(b, t, c.num_kv_heads, c.head_dim)
-    v = _mm(h, p["attn"]["wv"]).reshape(b, t, c.num_kv_heads, c.head_dim)
+    q = mm(h, p["attn"]["wq"]).reshape(b, t, c.num_heads, c.head_dim)
+    k = mm(h, p["attn"]["wk"]).reshape(b, t, c.num_kv_heads, c.head_dim)
+    v = mm(h, p["attn"]["wv"]).reshape(b, t, c.num_kv_heads, c.head_dim)
     q = apply_rope(q, cos, sin)
     k = apply_rope(k, cos, sin)
     if c.num_kv_heads != c.num_heads:
@@ -129,7 +129,7 @@ def _moe_block(x: jax.Array, p: Params, cos, sin,
         k = jnp.repeat(k, rep, axis=2)
         v = jnp.repeat(v, rep, axis=2)
     a = flash_attention(q, k, v, True).reshape(b, t, c.d_model)
-    x = x + _mm(a, p["attn"]["wo"])
+    x = x + mm(a, p["attn"]["wo"])
 
     h = rms_norm(x, p["ffn_norm"]["scale"])
     y, logits = moe_ffn(

@@ -10,7 +10,7 @@ and `pattern` says which, a character a layer:
      recurrence state [H, P, N] and the last K-1 inputs of the
      convolution.
   *  grouped-query attention with no positional embedding, over the
-     cache as it lies (`llama._cache_attention`, at whatever ratio of
+     cache as it lies (`ops/swa.cache_attention`, at whatever ratio of
      query to key-value heads the configuration has).
   E  a LatentMoE layer (`ops/grouped_moe.py`): a sigmoid router over ALL
      `n_routed_experts`, the chosen experts' scores normalised and
@@ -43,9 +43,10 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..ops.grouped_moe import held_experts, sigmoid_topk_route
-from ..ops.layers import rms_norm
+from ..ops.layers import mm, rms_norm
 from ..ops.mamba2 import causal_conv, gated_group_norm, ssd_scan, ssd_step
-from .llama import _cache_attention, _mm, _slab_attention
+from ..ops.swa import cache_attention, slab_attention
+from .family import Family
 
 Params = Dict[str, Any]
 F32 = jnp.float32
@@ -198,7 +199,7 @@ def nemotron_h_init(config: NemotronHConfig, key: jax.Array) -> Params:
 def _mamba_inputs(h: jax.Array, p: Params, c: NemotronHConfig):
     """h [B, T, D] -> (z [B,T,di], xbc [B,T,C] before the convolution,
     dt [B,T,H] float32 after softplus)."""
-    proj = _mm(h, p["w_in"])
+    proj = mm(h, p["w_in"])
     z, xbc, dt = jnp.split(proj, [c.d_inner, c.d_inner + c.conv_dim], -1)
     return z, xbc, jax.nn.softplus(dt.astype(F32) + p["dt_bias"])
 
@@ -215,7 +216,7 @@ def _mamba_split(xbc: jax.Array, c: NemotronHConfig):
 def _mamba_out(y: jax.Array, z: jax.Array, p: Params,
                c: NemotronHConfig) -> jax.Array:
     y = y.reshape(z.shape).astype(z.dtype)
-    return _mm(gated_group_norm(y, z, p["norm"], c.n_groups, c.norm_eps),
+    return mm(gated_group_norm(y, z, p["norm"], c.n_groups, c.norm_eps),
                p["w_out"])
 
 
@@ -238,9 +239,9 @@ def _mamba(h: jax.Array, p: Params, c: NemotronHConfig,
 
 def _qkv(h: jax.Array, p: Params, c: NemotronHConfig):
     b, t, _ = h.shape
-    return (_mm(h, p["wq"]).reshape(b, t, c.num_heads, c.head_dim),
-            _mm(h, p["wk"]).reshape(b, t, c.num_kv_heads, c.head_dim),
-            _mm(h, p["wv"]).reshape(b, t, c.num_kv_heads, c.head_dim))
+    return (mm(h, p["wq"]).reshape(b, t, c.num_heads, c.head_dim),
+            mm(h, p["wk"]).reshape(b, t, c.num_kv_heads, c.head_dim),
+            mm(h, p["wv"]).reshape(b, t, c.num_kv_heads, c.head_dim))
 
 
 def _attention(h: jax.Array, p: Params, c: NemotronHConfig, cache: Params,
@@ -251,8 +252,8 @@ def _attention(h: jax.Array, p: Params, c: NemotronHConfig, cache: Params,
     rows = jnp.arange(h.shape[0])[:, None]
     ck = cache["k"].at[rows, positions].set(k.astype(cache["k"].dtype))
     cv = cache["v"].at[rows, positions].set(v.astype(cache["v"].dtype))
-    a = _cache_attention(q, ck, cv, positions)
-    return _mm(a, p["wo"]), {"k": ck, "v": cv}
+    a = cache_attention(q, ck, cv, positions)
+    return mm(a, p["wo"]), {"k": ck, "v": cv}
 
 
 def _attention_prefill(h: jax.Array, p: Params, c: NemotronHConfig,
@@ -267,8 +268,8 @@ def _attention_prefill(h: jax.Array, p: Params, c: NemotronHConfig,
     cv = jax.lax.dynamic_update_slice(
         cache["v"], v.astype(cache["v"].dtype), (0, pos, 0, 0))
     positions = jnp.broadcast_to(pos + jnp.arange(t)[None, :], (b, t))
-    a = _cache_attention(q, ck, cv, positions)
-    return _mm(a, p["wo"]), {"k": ck, "v": cv}
+    a = cache_attention(q, ck, cv, positions)
+    return mm(a, p["wo"]), {"k": ck, "v": cv}
 
 
 def _attention_uncached(h: jax.Array, p: Params, c: NemotronHConfig
@@ -277,7 +278,7 @@ def _attention_uncached(h: jax.Array, p: Params, c: NemotronHConfig
     q, k, v = _qkv(h, p, c)
     positions = jnp.broadcast_to(jnp.arange(t)[None, :], (b, t))
     # no cache and no tick: the run over itself, which a loss differentiates
-    return _mm(_slab_attention(q, k, v, positions), p["wo"])
+    return mm(slab_attention(q, k, v, positions), p["wo"])
 
 
 def latent_moe(h: jax.Array, p: Params, c: NemotronHConfig
@@ -290,11 +291,11 @@ def latent_moe(h: jax.Array, p: Params, c: NemotronHConfig
     chosen, weights = sigmoid_topk_route(
         flat, p["router"], p["router_bias"], c.num_experts_per_tok,
         c.routed_scaling_factor, c.norm_topk_prob)
-    routed, counts = held_experts(_mm(flat, p["w_down"]), chosen, weights,
+    routed, counts = held_experts(mm(flat, p["w_down"]), chosen, weights,
                                   p["w1"], p["w2"], c.first_expert, _relu2)
-    out = _mm(routed.astype(h.dtype), p["w_up"])
+    out = mm(routed.astype(h.dtype), p["w_up"])
     mid = _relu2(jnp.dot(flat, p["s1"], preferred_element_type=F32))
-    out = out + _mm(mid.astype(h.dtype), p["s2"])
+    out = out + mm(mid.astype(h.dtype), p["s2"])
     return out.reshape(lead + (out.shape[-1],)), counts
 
 
@@ -452,3 +453,11 @@ def nemotron_h_partition_specs(config: NemotronHConfig) -> Params:
     return {"tok_emb": P("tp", "fsdp"), "norm_f": norm,
             "lm_head": P("fsdp", "tp"),
             "blocks": [kinds[kind] for kind in config.pattern]}
+
+
+FAMILY = Family(
+    config_type=NemotronHConfig, init=nemotron_h_init,
+    forward=nemotron_h_forward, loss=nemotron_h_loss,
+    partition_specs=nemotron_h_partition_specs,
+    init_cache=nemotron_h_init_cache, forward_cached=nemotron_h_forward_cached,
+    decode=nemotron_h_decode, decode_walks=True)

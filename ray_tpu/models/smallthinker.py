@@ -64,12 +64,11 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..ops.grouped_moe import held_experts, softmax_topk_route
-from ..ops.layers import rms_norm
+from ..ops.grouped_moe import held_counts, held_experts, softmax_topk_route
+from ..ops.layers import mm, rms_norm
 from ..ops.rope import apply_rope, rope_table
-from ..ops.swa import prompt_attention, visited_blocks
-from .deepseek_v2 import _counts
-from .llama import _cache_attention, _mm
+from ..ops.swa import cache_attention, prompt_attention, visited_blocks
+from .family import Family
 
 Params = Dict[str, Any]
 F32 = jnp.float32
@@ -184,9 +183,9 @@ def _qkv(x: jax.Array, p: Params, c: SmallThinkerConfig, layer: int, rope,
     where the layer has rotary positions."""
     b, t, _ = x.shape
     h = rms_norm(x, p["norm1"]["scale"], c.norm_eps).astype(c.dtype)
-    q = _mm(h, p["attn"]["wq"]).reshape(b, t, c.num_heads, c.head_dim)
-    k = _mm(h, p["attn"]["wk"]).reshape(b, t, c.num_kv_heads, c.head_dim)
-    v = _mm(h, p["attn"]["wv"]).reshape(b, t, c.num_kv_heads, c.head_dim)
+    q = mm(h, p["attn"]["wq"]).reshape(b, t, c.num_heads, c.head_dim)
+    k = mm(h, p["attn"]["wk"]).reshape(b, t, c.num_kv_heads, c.head_dim)
+    v = mm(h, p["attn"]["wv"]).reshape(b, t, c.num_kv_heads, c.head_dim)
     if c.rope_layout[layer]:
         q = apply_rope(q, *rope, positions)
         k = apply_rope(k, *rope, positions)
@@ -241,7 +240,7 @@ def _attn_decode(x: jax.Array, p: Params, c: SmallThinkerConfig,
         at = (jnp.arange(x.shape[0])[:, None], positions % rows)
         ck = cache["k"].at[at].set(k.astype(cache["k"].dtype))
         cv = cache["v"].at[at].set(v.astype(cache["v"].dtype))
-        a = _cache_attention(q, ck, cv, jnp.minimum(positions, rows - 1))
+        a = cache_attention(q, ck, cv, jnp.minimum(positions, rows - 1))
         return _attn_out(a, p), {"k": ck, "v": cv}
 
 
@@ -318,7 +317,8 @@ def _prefill(params: Params, tokens: jax.Array, c: SmallThinkerConfig,
         sizes.append(rows)
     causal = c.num_layers * visited_blocks(tokens.shape[1], c.attn_block,
                                            None)[1]
-    return x, new_cache, dict(_counts(sizes), attn_blocks=jnp.int32(blocks),
+    return x, new_cache, dict(held_counts(sizes),
+                              attn_blocks=jnp.int32(blocks),
                               attn_blocks_causal=jnp.int32(causal))
 
 
@@ -394,13 +394,10 @@ def smallthinker_forward_cached(params: Params, tokens: jax.Array,
                                 config: SmallThinkerConfig, cache: list,
                                 pos: Any):
     """`smallthinker_forward_counted` less its counters: the cache
-    protocol's (logits, cache). The engine's prefill finds the counted
-    form under `with_counters`."""
+    protocol's (logits, cache). The engine's prefill takes the counted
+    form (`FAMILY.forward_counted`)."""
     return smallthinker_forward_counted(params, tokens, config, cache,
                                         pos)[:2]
-
-
-smallthinker_forward_cached.with_counters = smallthinker_forward_counted
 
 
 def smallthinker_decode(params: Params, tokens: jax.Array,
@@ -409,7 +406,7 @@ def smallthinker_decode(params: Params, tokens: jax.Array,
     """One step for a ragged batch: tokens [B], slot b at position
     pos_vec[b]. Returns (logits [B, vocab] float32, the new cache, the
     expert layers' counts for the engine's loop record:
-    `deepseek_v2._counts`). There is no [B, k+1] verify form."""
+    `ops/grouped_moe.held_counts`). There is no [B, k+1] verify form."""
     c = config
     if tokens.ndim != 1:
         raise ValueError("this family's decode has no verify form: "
@@ -424,7 +421,7 @@ def smallthinker_decode(params: Params, tokens: jax.Array,
                                        positions)
         x, rows = _ffn(x + y, chosen, weights, p, c)
         sizes.append(rows)
-    return _head(x[:, 0], params, c), new_cache, _counts(sizes)
+    return _head(x[:, 0], params, c), new_cache, held_counts(sizes)
 
 
 def smallthinker_partition_specs(config: SmallThinkerConfig) -> Params:
@@ -438,3 +435,12 @@ def smallthinker_partition_specs(config: SmallThinkerConfig) -> Params:
     return {"tok_emb": P("tp", "fsdp"), "norm_f": norm,
             "lm_head": P("fsdp", "tp"),
             "blocks": [block for _ in range(config.num_layers)]}
+
+
+FAMILY = Family(
+    config_type=SmallThinkerConfig, init=smallthinker_init,
+    forward=smallthinker_forward, loss=smallthinker_loss,
+    partition_specs=smallthinker_partition_specs,
+    init_cache=smallthinker_init_cache,
+    forward_cached=smallthinker_forward_cached, decode=smallthinker_decode,
+    forward_counted=smallthinker_forward_counted, decode_walks=True)

@@ -320,3 +320,18 @@ def held_experts(u: jax.Array, chosen: jax.Array, weights: jax.Array,
     result = out[back].reshape(t, k, -1).sum(axis=1)
     return result, {"pairs_held": sizes.sum(), "rows_max": sizes.max(),
                     "sizes": sizes}
+
+
+def held_counts(sizes: list) -> Dict[str, jax.Array]:
+    """What the expert layers' grouped products saw, from the rows each
+    held expert got in each expert layer: `moe_pairs_held`, token-expert
+    pairs that fell on held experts, summed over the layers;
+    `moe_experts_hit`, held experts that got a row, summed likewise;
+    `moe_rows_max`, the most rows one held expert got in one layer."""
+    if not sizes:
+        zero = jnp.int32(0)
+        return {"moe_pairs_held": zero, "moe_rows_max": zero,
+                "moe_experts_hit": zero}
+    rows = jnp.stack(sizes)
+    return {"moe_pairs_held": rows.sum(), "moe_rows_max": rows.max(),
+            "moe_experts_hit": (rows > 0).sum().astype(jnp.int32)}

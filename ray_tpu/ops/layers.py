@@ -31,6 +31,12 @@ def rms_norm(x: jax.Array, scale: jax.Array,
             * scale.astype(jnp.float32)).astype(x.dtype)
 
 
+def mm(x: jax.Array, w: jax.Array) -> jax.Array:
+    """`x @ w` accumulated in float32, handed back in x's dtype: the
+    projection every served family's block makes."""
+    return jnp.dot(x, w, preferred_element_type=jnp.float32).astype(x.dtype)
+
+
 def lora_delta(h: jax.Array, a: jax.Array, b: jax.Array,
                scale: jax.Array) -> jax.Array:
     """Per-slot scatter-gathered LoRA contribution for a ragged decode

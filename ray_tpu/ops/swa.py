@@ -374,6 +374,14 @@ def _decode_block(rows: int, groups: int, d: int, itemsize: int) -> int:
     return min(block, rows)
 
 
+def decode_block(shape: Tuple[int, ...], dtype) -> int:
+    """The block `decode_attention` walks a slab entry [B, rows, groups,
+    d] of this dtype by: what the kernel's wrapper asks, and what a host
+    that counts the walk's rows asks too (`decode_rows_read`)."""
+    _, rows, groups, d = shape
+    return _decode_block(rows, groups, d, jnp.dtype(dtype).itemsize)
+
+
 def decode_blocks(positions, block: int, rows: int):
     """The blocks a slot's walk visits: from block 0 to the one that holds
     the slot's last position (a position past the entry visits every
@@ -622,10 +630,10 @@ def decode_attention(q: jax.Array, ck: jax.Array, cv: jax.Array,
     (positions [B, t] int32, ascending along t). Returns [B, t, H d] in
     q's dtype: what the masked softmax over all S rows gives, from a walk
     that ends at each slot's last block (module docstring). The block
-    follows from the entry's shape (`_decode_block`)."""
+    follows from the entry's shape (`decode_block`)."""
     b, t, h, d = q.shape
     s_rows, groups = ck.shape[1], ck.shape[2]
-    block = _decode_block(s_rows, groups, d, ck.dtype.itemsize)
+    block = decode_block(ck.shape, ck.dtype)
     shape = (b, t, h, groups, d, s_rows)
     positions = positions.astype(jnp.int32)
     interpret = dispatch.interpret_forced()
@@ -641,3 +649,43 @@ def decode_attention(q: jax.Array, ck: jax.Array, cv: jax.Array,
         return _decode_blocked(q, ck, cv, positions, block)
     dispatch.record_choice("gqa_decode", shape, "pallas", block=block)
     return _decode_pallas(q, ck, cv, positions, block, interpret)
+
+
+def cache_attention(q: jax.Array, ck: jax.Array, cv: jax.Array,
+                    positions: jax.Array) -> jax.Array:
+    """Masked attention of q [B, t, n_heads, hd] over the cache as it
+    lies, ck/cv [B, S, n_kv, hd] in their own dtype; query (b, j) sees
+    rows <= positions[b, j]. Heads are contracted per kv group: head h
+    is (g, r) = (h // rep, h % rep), the order jnp.repeat(axis=2) gave,
+    so `wo` sees the same columns. Returns [B, t, n_heads hd] (d_model
+    for `models/llama.py`; `models/smallthinker.py`, whose heads do not
+    add up to its hidden size, calls this too).
+
+    A run of at most `DECODE_ROWS` rows is a tick's (one token a slot,
+    or the speculative verify's k + 1) and takes the decode form
+    (`decode_attention`): each slot's rows up to its position, block by
+    block. A longer run (a suffix on a cached prefix, a prompt of at
+    most one block, an uncached forward) takes the slab form: its
+    [t, S] scores spread the slab's read over their rows, and nothing
+    says its slots are short. The choice reads `q`'s shape alone."""
+    if q.shape[1] <= DECODE_ROWS:
+        return decode_attention(q, ck, cv, positions)
+    return slab_attention(q, ck, cv, positions)
+
+
+def slab_attention(q: jax.Array, ck: jax.Array, cv: jax.Array,
+                   positions: jax.Array) -> jax.Array:
+    """`cache_attention` over ALL rows of ALL slots: float32 scores of
+    every query against the whole entry, masked afterwards."""
+    b, t, heads, hd = q.shape
+    groups = ck.shape[2]
+    qg = q.reshape(b, t, groups, heads // groups, hd)
+    scores = jnp.einsum("btgrd,bsgd->bgrts", qg, ck,
+                        preferred_element_type=jnp.float32)
+    scores = scores / (hd ** 0.5)
+    col = jnp.arange(ck.shape[1])[None, None, None, None, :]
+    visible = col <= positions[:, None, None, :, None]
+    scores = jnp.where(visible, scores, -1e30)
+    probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+    a = jnp.einsum("bgrts,bsgd->btgrd", probs, cv)
+    return a.reshape(b, t, heads * hd)

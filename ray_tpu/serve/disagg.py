@@ -394,9 +394,7 @@ class PrefillServer:
                  lora_rank_max: Optional[int] = None,
                  kvplane: Optional[bool] = None,
                  kvplane_arena_bytes: Optional[int] = None):
-        from ray_tpu.models.engine import (LATENT_ONLY, RING, latent_only,
-                                           ring_rows)
-        from ray_tpu.models.generate import _model_fns
+        from ray_tpu.models.family import refuse, slab_spec
         from ray_tpu.models.kvcache import (PagedKVCache,
                                             kv_int8_default,
                                             resolve_pool_config)
@@ -424,22 +422,10 @@ class PrefillServer:
         if kv_int8 is None:
             kv_int8 = kv_int8_default()
         self.kv_int8 = bool(kv_int8)
-        import jax
-
-        # the family's cache as shapes alone: nothing is allocated
-        probe = jax.eval_shape(lambda: _model_fns(config)[1](config, 1))
-        if latent_only(probe):
-            raise ValueError(
-                LATENT_ONLY + "a transfer carries ck and cv rows in "
-                "pairs and the prefill tier's pool commits them side by "
-                "side, so it cannot be served disaggregated "
-                "(engine.adopt_prefill refuses it too)")
-        if ring_rows(probe, config.max_seq_len):
-            raise ValueError(
-                RING + "a transfer carries ONE stack of ck and cv rows of "
-                "the prompt's length and the prefill tier's pool has one "
-                "block shape, so it cannot be served disaggregated "
-                "(engine.adopt_prefill refuses it too)")
+        # a transfer carries ck and cv, one stack of the prompt's
+        # length: any other cache is refused before a pool is built
+        spec = slab_spec(config, 1)
+        refuse(spec, "transfer")
         block_size, pool_blocks = resolve_pool_config(
             config, kv_block_size, kv_pool_blocks, int8=self.kv_int8)
         self.kv_cache: Optional[PagedKVCache] = (
@@ -473,14 +459,7 @@ class PrefillServer:
                 lambda tenant, old, _p=self.lora_pool:
                 self.kv_cache.invalidate(
                     namespace=_p.cache_namespace(tenant, old)))
-        if any("k" not in blk for blk in probe):
-            raise ValueError(
-                "this family's slots own recurrent state: a transfer "
-                "carries ck/cv rows only, so it cannot be served "
-                "disaggregated (engine.adopt_prefill refuses it too)")
-        shape = probe[0]["k"].shape  # [1, rows, H, hd]
-        self._empty_prefix = jnp.zeros(
-            (len(probe), 0) + shape[2:], probe[0]["k"].dtype)
+        self._empty_prefix = jnp.zeros(spec.stack_shape(0), spec.dtype)
         # retention bounds how many unacked transfers this server keeps
         # alive; size it past the decode tier's admitted bound
         # (decode_replicas * (max_batch + queue_depth)) — transfers are

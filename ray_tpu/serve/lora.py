@@ -25,7 +25,7 @@ The pieces, and where each lives:
   the pool lock, so same-device stream order makes dispatch the only
   critical section while the compute overlaps freely. shardlint's
   ``undonated-pool-write`` rule guards the discipline.
-- **Cross-tenant batched decode** (models/engine.py ``_tick_lora`` +
+- **Cross-tenant batched decode** (models/engine.py ``_tick(lora=)`` +
   the model families' ``*_decode(lora=)``): one decode tick serves
   mixed tenants via per-slot adapter indices gathering each slot's
   A/B out of these stacks — ``base @ x + scatter-gathered (B·A) @ x``

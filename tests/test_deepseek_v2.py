@@ -19,8 +19,8 @@ if ROOT not in sys.path:
 
 from benchmarks.harness import reference  # noqa: E402
 from ray_tpu.models import deepseek_v2 as ds  # noqa: E402
-from ray_tpu.models import engine as engine_mod  # noqa: E402
 from ray_tpu.models.engine import ContinuousBatchingEngine  # noqa: E402
+from ray_tpu.models.family import family_of, slab_spec  # noqa: E402
 from ray_tpu.models.generate import _model_fns, generate  # noqa: E402
 from ray_tpu.observability import requests as reqtrace  # noqa: E402
 
@@ -73,7 +73,7 @@ def test_prefill_then_decode_is_the_references_one_forward_pass(params):
     reference."""
     step, init_cache, _ = _model_fns(CFG)
     assert step is ds.deepseek_v2_forward_cached
-    step = step.with_counters
+    step = family_of(CFG).forward_counted
     want = reference.logits(CONF, params, TOKENS[:30])
     logits, cache, counts = step(params, TOKENS[None, :21], CFG,
                                  init_cache(CFG, 1), 0)
@@ -94,7 +94,7 @@ def test_prefill_then_decode_is_the_references_one_forward_pass(params):
 def test_the_cache_is_latent_rows_alone_and_the_tick_counts(params):
     cache = ds.deepseek_v2_init_cache(CFG, 4)
     assert [sorted(e) for e in cache] == [["k"]] * 3
-    assert engine_mod.latent_only(cache)
+    assert slab_spec(CFG, 4).latent_only
     _, _, counts = ds.deepseek_v2_decode(
         params, jnp.asarray(TOKENS[:4]), CFG, cache,
         jnp.zeros(4, jnp.int32))

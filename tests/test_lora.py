@@ -160,9 +160,15 @@ def test_mixed_batch_bit_identity(engines):
     # the base slot of the mixed batch is bit-identical to TODAY's
     # engine (no lora machinery at all) — the null-adapter oracle
     assert mixed[2] == base.generate(PROMPT, 6)
-    # ...and the adapters actually did something
-    assert mixed[0] != mixed[2] and mixed[1] != mixed[2]
-    assert mixed[0] != mixed[1]
+    # ...and the adapters actually did something. One this weak (rank
+    # 3, scale 1) need not flip an argmax of the tiny model, so the
+    # claim is held to the per-token log-probabilities the streams
+    # carry: an adapter that did something moves a score even where
+    # the greedy token holds
+    scores = [s.scores for s in streams]
+    assert all(len(sc) == 6 for sc in scores)
+    assert scores[0] != scores[2] and scores[1] != scores[2]
+    assert scores[0] != scores[1]
 
 
 @pytest.mark.slow

@@ -17,7 +17,7 @@ from ray_tpu.models.llama import (LlamaConfig, init_kv_cache, llama_decode,
 from ray_tpu.models.moe_transformer import (MoEConfig, moe_forward,
                                             moe_init, moe_loss,
                                             moe_partition_specs)
-from ray_tpu.ops.layers import rms_norm
+from ray_tpu.ops.layers import mm, rms_norm
 from ray_tpu.ops.rope import apply_rope, rope_table
 from ray_tpu.parallel.mesh import MeshConfig, make_mesh
 from ray_tpu.train.trainer import TrainStep
@@ -217,7 +217,7 @@ def _ref_cache_forward(params, toks, cfg, cache, positions):
         probs = jax.nn.softmax(jnp.where(seen, scores, -1e30), axis=-1)
         a = jnp.einsum("bhts,bshd->bthd", probs, vv)
         a = a.reshape(b, t, c.d_model).astype(x.dtype)
-        x = llama._mlp_res(x + llama._mm(a, p["attn"]["wo"]), p)
+        x = llama._mlp_res(x + mm(a, p["attn"]["wo"]), p)
         new_cache.append({"k": ck, "v": cv})
     x = rms_norm(x, params["norm_f"]["scale"])
     return jnp.dot(x, params["lm_head"],

@@ -23,6 +23,7 @@ from benchmarks.harness import configs, reference  # noqa: E402
 from ray_tpu.models import engine as engine_mod  # noqa: E402
 from ray_tpu.models import llama, smallthinker  # noqa: E402
 from ray_tpu.models.engine import ContinuousBatchingEngine  # noqa: E402
+from ray_tpu.models.family import slab_spec  # noqa: E402
 from ray_tpu.models.generate import (_model_fns, generate,  # noqa: E402
                                      stream_generate)
 from ray_tpu.models.smallthinker import SmallThinkerConfig  # noqa: E402
@@ -170,13 +171,12 @@ def test_a_slot_at_the_published_widths_costs_176_megabytes():
         cache = jax.eval_shape(lambda: _model_fns(cfg)[1](cfg, 16))
         assert sum(x.size * x.dtype.itemsize
                    for x in jax.tree.leaves(cache)) == 16 * want
-        assert engine_mod.ring_rows(cache, 16384) == 4096
+        assert slab_spec(cfg, 16).ring_rows == 4096
         one = 2048 * (layers // 4) * (16384 + 3 * 4096)
         assert want == one
     # a window no shorter than the cell's positions is no ring
     cfg = configs.program_config(conf, 4096)
-    assert engine_mod.ring_rows(
-        jax.eval_shape(lambda: _model_fns(cfg)[1](cfg, 1)), 4096) is None
+    assert slab_spec(cfg, 1).ring_rows is None
 
 
 def test_what_stands_on_the_pool_is_refused_in_words(toy):
@@ -239,7 +239,7 @@ def test_the_families_that_were_there_get_the_stack_and_splice_they_had():
         np.testing.assert_array_equal(out[i]["v"][2, :9], cv[i, :9])
         assert float(out[i]["k"][2, 9:].min()) == 3.0 \
             == float(out[i]["v"][:2].min())
-    assert engine_mod.ring_rows(slab, 32) is None
+    assert slab_spec(cfg, 3).ring_rows is None
     eng = ContinuousBatchingEngine(params, cfg, max_batch=2)
     try:
         stats = eng.kv_stats()

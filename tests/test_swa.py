@@ -193,12 +193,11 @@ BASES = {"edges": [0, 127, 128, S_ROWS - 1],
 @pytest.mark.parametrize("groups,rep", [(8, 4), (4, 7), (2, 16), (1, 20)])
 def test_the_decode_form_is_the_slab_form(groups, rep, t, where,
                                           blocks_of_128):
-    from ray_tpu.models.llama import _slab_attention
 
     q, ck, cv = _slab(groups + t, t, groups, rep)
     base = np.minimum(BASES[where], S_ROWS - t)
     pos = _positions(base, t)
-    want = _slab_attention(q, ck, cv, pos)
+    want = swa.slab_attention(q, ck, cv, pos)
     dispatch.reset_kernel_choices()
     blocks = swa.decode_attention(q, ck, cv, pos)
     choice = dispatch.kernel_choices("gqa_decode")[0]
