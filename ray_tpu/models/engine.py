@@ -79,9 +79,10 @@ from position 0). What the engine does with them: a prefill hands the
 sequence entries back stacked `[L_kv, S, H, hd]` (what the paged pool
 commits, a disaggregated transfer ships and `_splice_slot` writes by rows
 `[0, plen)`), with `cv` None where a latent row serves for keys and
-values both, ONE STACK FOR EACH ROW COUNT where the slab holds a ring
-(`ck`, `cv` are then tuples, in the order the slab first shows each
-count; a family with one count gets the one stack it always got), a
+values both, ONE STACK FOR EACH (ROW COUNT, ROW SHAPE) where the slab
+holds a ring or entries of several widths (`family.stacks`: `ck`, `cv`
+are then tuples, in the order the slab first shows each; a family with
+one count and one width gets the one stack it always got), a
 ring's stack as the ring would lie after the prompt (the last `min(plen,
 rows)` positions, each at `p mod rows`); and the state it ended in, which
 the splice writes WHOLE. `_splice_slot` writes rows `[0, min(plen,
@@ -302,7 +303,7 @@ from ray_tpu.ops import dispatch
 from ray_tpu.ops.swa import decode_block, decode_rows_read
 from ray_tpu.util.profiling import name_thread
 
-from .family import family_of, refuse, row_counts, slab_spec
+from .family import family_of, refuse, slab_spec, stacks
 from .generate import merge_lora_params
 from .kvcache import PagedKVCache, resolve_pool_config
 
@@ -529,9 +530,11 @@ def _prefill_paged(params, suffix, config, prefix_k, prefix_v, lora=None):
     replicas speak); a family whose sequence entries hold one array (a
     latent row: "k" alone) gets `cv` None, and `prefix_v` is not read; a
     family whose sequence entries have MORE THAN ONE row count (a ring
-    beside full-length entries) gets `ck` and `cv` as tuples, one stack
-    a row count in the order the cache first shows each
-    (`family.row_counts`), and has no cached prefix to lay out. `state`:
+    beside full-length entries) or width (an indexer's narrow keys
+    beside latent rows) gets `ck` and `cv` as tuples, one stack a (row
+    count, row shape) in the order the cache first shows each
+    (`family.stacks`), each at its own width, and has no cached prefix to
+    lay out. `state`:
     the entries that have no sequence axis, each as the family left it
     (the empty list for a family that has none). `counters`: those of
     the run where the family's record has a `forward_counted` (a dict of
@@ -546,14 +549,13 @@ def _prefill_paged(params, suffix, config, prefix_k, prefix_v, lora=None):
     family, spec = family_of(config), slab_spec(config, 1)
     c = prefix_k.shape[1]
     cache = list(family.init_cache(config, 1))
-    by_rows, paired = spec.by_rows, spec.paired
-    if c and (spec.stateful or len(by_rows) > 1):
+    by_shape, paired = spec.stacks, spec.paired
+    if c and (spec.stateful or len(by_shape) > 1):
         raise ValueError(
             "a cached prefix cannot resume a recurrent state or a ring: "
             "this family prefills every prompt from position 0")
-    for rows, at in by_rows.items():
-        base_k = jnp.zeros((len(at), rows) + prefix_k.shape[2:],
-                           prefix_k.dtype)
+    for (rows, *row_shape), at in by_shape.items():
+        base_k = jnp.zeros((len(at), rows, *row_shape), prefix_k.dtype)
         base_v = jnp.zeros_like(base_k) if paired else None
         if c:
             base_k = base_k.at[:, :c].set(prefix_k)
@@ -570,10 +572,10 @@ def _prefill_paged(params, suffix, config, prefix_k, prefix_v, lora=None):
         logits, cache, counts = family.forward_counted(
             params, suffix, config, cache, c)
     ck = tuple(jnp.stack([cache[i]["k"][0] for i in at])
-               for at in by_rows.values())
+               for at in by_shape.values())
     cv = tuple(jnp.stack([cache[i]["v"][0] for i in at]) if paired else None
-               for at in by_rows.values())
-    if len(by_rows) == 1:     # the one stack every consumer speaks
+               for at in by_shape.values())
+    if len(by_shape) == 1:    # the one stack every consumer speaks
         ck, cv = ck[0], cv[0]
     state = [blk for blk in cache if "k" not in blk]
     return logits[:, -1], ck, cv, state, counts
@@ -680,19 +682,20 @@ def _splice_slot(cache, ck, cv, slot, config, plen, state=()):
     (and of cv where it holds "v" too; a latent entry holds "k" alone):
     all of a ring the prompt has wrapped, which the prefill handed back
     as it lies. `ck` and `cv` are one stack, or a tuple of stacks, one a
-    row count in the order the slab first shows each (`_prefill_paged`).
+    (row count, row shape) in the order the slab first shows each
+    (`_prefill_paged`).
     Any other entry is a slot's state and takes the next entry of `state`
     WHOLE. With the slab donated this lowers to an in-place update per
     entry, O(rows held) and the state's bytes, never a full-cache copy."""
     del config
-    stacks = {rows: j for j, rows in enumerate(row_counts(cache))}
+    stack_of = {shape: j for j, shape in enumerate(stacks(cache))}
     if not isinstance(ck, tuple):
         ck, cv = (ck,), (cv,)
-    out, layer, states = [], [0] * len(stacks), iter(state)
+    out, layer, states = [], [0] * len(stack_of), iter(state)
     for blk in cache:
         if "k" in blk:
             rows = blk["k"].shape[1]
-            j, n = stacks[rows], min(plen, rows)
+            j, n = stack_of[tuple(blk["k"].shape[1:])], min(plen, rows)
             at = (slot,) + (0,) * (blk["k"].ndim - 1)
             new = {"k": jax.lax.dynamic_update_slice(
                 blk["k"], ck[j][layer[j], :n][None], at)}

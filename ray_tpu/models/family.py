@@ -15,6 +15,15 @@ STATE, with no sequence axis, written whole. What the serving stack
 takes for granted of full-length keys and values in pairs does not hold
 for the other three, and is refused in words (`refuse`) rather than
 served silently wrong.
+
+The kinds combine. A cache that is latent AND ring holds latent rows
+alone, some entries of the full length and some in rings: what either
+kind is refused, it is refused, in words of its own. And the entries
+under ONE row count may differ in WIDTH (latent rows beside the narrow
+keys of an indexer, an entry each): a layer may leave more than one
+entry, an entry is what is stacked, and a prefill hands the sequence
+entries back one stack for each (rows, row shape) the cache shows
+(`stacks`), never assuming the first entry's width of the others.
 """
 from __future__ import annotations
 
@@ -69,6 +78,17 @@ def row_counts(cache) -> Dict[int, List[int]]:
     return by_rows
 
 
+def stacks(cache) -> Dict[Tuple[int, ...], List[int]]:
+    """The sequence entries of a cache by what can be stacked: {(rows,
+    *row shape): [entry indices]}, in the order the cache first shows
+    each. One key for a cache of one row count and one width."""
+    by_shape: Dict[Tuple[int, ...], List[int]] = {}
+    for i, blk in enumerate(cache):
+        if "k" in blk:
+            by_shape.setdefault(tuple(blk["k"].shape[1:]), []).append(i)
+    return by_shape
+
+
 def _nbytes(tree, per: int) -> int:
     return sum(x.size * x.dtype.itemsize // per
                for x in jax.tree.leaves(tree))
@@ -82,6 +102,7 @@ class SlabSpec:
         cache = jax.eval_shape(
             lambda: family_of(config).init_cache(config, batch))
         self.by_rows = row_counts(cache)
+        self.stacks = stacks(cache)
         seq = [cache[i] for at in self.by_rows.values() for i in at]
         self.paired = all("v" in blk for blk in seq)    # "v" beside "k"
         self.latent_only = len(seq) == len(cache) and all(
@@ -101,12 +122,16 @@ class SlabSpec:
         self.longest = cache[self.by_rows[self.rows][0]]["k"]
         shortest = min(self.by_rows)
         self.ring_rows = shortest if shortest < config.max_seq_len else None
-        self.kind = ("state" if self.stateful else "latent"
+        self.kind = ("state" if self.stateful else "latent_ring"
+                     if self.latent_only and self.ring_rows else "latent"
                      if self.latent_only else "ring" if self.ring_rows
                      else None)
         # what a pool block, a cached prefix and a transfer are made of:
         # a stack [layers, n, *row_shape] of the first entry's rows (an
-        # entry is a layer wherever such a stack is used)
+        # entry is a layer wherever such a stack is used). Only a cache
+        # of ONE stack (`len(self.stacks) == 1`) is ever asked for it:
+        # entries of several widths or row counts are a kind that
+        # `refuse` turns away from every consumer of such a stack
         self.layers = len(cache)
         self.row_shape: Tuple[int, ...] = tuple(seq[0]["k"].shape[2:])
         self.dtype = seq[0]["k"].dtype
@@ -122,6 +147,9 @@ _KINDS = {
                "values: "),
     "ring": ("this family's cache holds a ring (layers that keep only their "
              "last rows, fewer than max_seq_len): "),
+    "latent_ring": ("this family's cache holds one latent row a token and "
+                    "no values, some of them in rings (layers that keep "
+                    "only their last rows, fewer than max_seq_len): "),
 }
 _ENDS = {
     "prefix_cache": " (prefix_cache=True)",
@@ -185,6 +213,29 @@ _WHY = {
     ("ring", "transfer"):
         "a transfer carries ONE stack of ck and cv rows of the prompt's "
         "length and the prefill tier's pool has one block shape",
+    ("latent_ring", "prefix_cache"):
+        "the paged pool has one block shape [heads, head_dim] and one "
+        "length for every layer, keys and values side by side: no block of "
+        "one latent row, none of a narrower entry beside it, and no prefix "
+        "to resume where layers have forgotten all but their last {rows} "
+        "rows",
+    ("latent_ring", "speculate_k"):
+        "a rejected draft's rows in a ring have overwritten rows the "
+        "window still sees, the pool proposer drafts from the paged pool's "
+        "token chains, which this cache has none of, and the family's "
+        "decode has no [B, k+1] verify form",
+    ("latent_ring", "lora_pool"):
+        "the adapter pool's per-tenant prefix namespaces are the paged "
+        "pool's, which this cache cannot have, and its targets are the "
+        "attention projections of the families it knows",
+    ("latent_ring", "adopt_prefill"):
+        "an adoption carries ONE stack of ck and cv rows in pairs, of the "
+        "prompt's length, and here there are no values to carry, entries "
+        "of several widths, and a ring's {rows} rows are a stack of their "
+        "own, each row at its position mod the ring",
+    ("latent_ring", "transfer"):
+        "a transfer carries ONE stack of ck and cv rows in pairs, of the "
+        "prompt's length, and the prefill tier's pool has one block shape",
 }
 
 

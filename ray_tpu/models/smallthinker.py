@@ -67,7 +67,8 @@ from jax.sharding import PartitionSpec as P
 from ..ops.grouped_moe import held_counts, held_experts, softmax_topk_route
 from ..ops.layers import mm, rms_norm
 from ..ops.rope import apply_rope, rope_table
-from ..ops.swa import cache_attention, prompt_attention, visited_blocks
+from ..ops.swa import (cache_attention, prompt_attention, ring_rows,
+                       visited_blocks)
 from .family import Family
 
 Params = Dict[str, Any]
@@ -201,15 +202,6 @@ def _scope(c: SmallThinkerConfig, layer: int) -> str:
     return "swa" if c.window_layout[layer] else "global_attn"
 
 
-def _ring(x: jax.Array, rows: int) -> jax.Array:
-    """x [B, T, ...] at positions 0 .. T-1 as a ring of `rows` rows holds
-    it: the last min(T, rows) positions, each at `p mod rows`."""
-    t = x.shape[1]
-    if t <= rows:
-        return x
-    return jnp.roll(x[:, t - rows:], (t - rows) % rows, axis=1)
-
-
 def _attn_prefill(x: jax.Array, p: Params, c: SmallThinkerConfig,
                   layer: int, rope, cache: Params | None):
     """A run of tokens from position 0: the prompt form over the run
@@ -223,7 +215,7 @@ def _attn_prefill(x: jax.Array, p: Params, c: SmallThinkerConfig,
         if cache is not None:
             rows = cache["k"].shape[1]
             cache = {n: jax.lax.dynamic_update_slice(
-                cache[n], _ring(new, rows).astype(cache[n].dtype),
+                cache[n], ring_rows(new, rows).astype(cache[n].dtype),
                 (0, 0, 0, 0)) for n, new in (("k", k), ("v", v))}
         return _attn_out(a.reshape(a.shape[:2] + (-1,)), p), cache, blocks
 

@@ -29,6 +29,20 @@ makes them from c.
             128 heads it does 128 x (576 + 512) x 2 FLOPs for each
             1,280-byte row it reads, 218 FLOP/B: at the ridge of a v5e,
             where Kimi-Linear's 32 heads (54 FLOP/B) are bandwidth-bound.
+            With `visible` the rows seen are the caller's, not `<=
+            position`: a RING of latent rows (`ring_visible`: the token at
+            position p lies in row `p mod rows`, and a row counts while
+            its token is one of the last `window`), or rows gathered by a
+            selection (`ops/dsa.py`).
+  band      over a prompt whose layer sees the last `window` positions
+            (query t sees `t - window < s <= t`): `band_prompt_attention`,
+            blocks of `block >= window - 1` queries, each against its own
+            block of keys and the one before, one softmax over the two.
+            On a TPU the Pallas kernel `mla_band_w<W>_t<T>` (one program
+            a head and block of queries; only those two blocks of the
+            head's keys and values are fetched), elsewhere the same in
+            `jax.numpy`. `ops/swa.ring_rows` lays a prompt's rows out as the
+            ring holds them after it.
 
 Every form takes the softmax `scale`; left None it is (d_n + d_r)^-1/2,
 by the division the first caller's numbers were made with. A YaRN model
@@ -110,12 +124,15 @@ def expanded_attention(q_n: jax.Array, q_r: jax.Array, c: jax.Array,
 
 
 def absorbed_attention(q_n: jax.Array, q_r: jax.Array, rows: jax.Array,
-                       positions: jax.Array, w_kvb: jax.Array,
-                       scale: Optional[float] = None) -> jax.Array:
+                       positions: Optional[jax.Array], w_kvb: jax.Array,
+                       scale: Optional[float] = None,
+                       visible: Optional[jax.Array] = None) -> jax.Array:
     """Attention of q over the cache as it lies: rows [B, S, width] are
-    `latent_row`s, query (b, j) sees rows <= positions[b, j]. q_n [B, t,
-    H, d_n], q_r [B, t, H, d_r], w_kvb [rank, H, d_n + d_v]. Returns
-    [B, t, H, d_v] in q's dtype."""
+    `latent_row`s, query (b, j) sees rows <= positions[b, j], or, given
+    `visible` [B, t, S] bool, the rows it marks (`positions` is then not
+    read; every query must see a row). q_n [B, t, H, d_n], q_r [B, t, H,
+    d_r], w_kvb [rank, H, d_n + d_v]. Returns [B, t, H, d_v] in q's
+    dtype."""
     rank, d_n, d_r = w_kvb.shape[0], q_n.shape[-1], q_r.shape[-1]
     w_uk, w_uv = w_kvb[..., :d_n], w_kvb[..., d_n:]
     q_c = jnp.einsum("bthd,chd->bthc", q_n, w_uk,
@@ -124,8 +141,10 @@ def absorbed_attention(q_n: jax.Array, q_r: jax.Array, rows: jax.Array,
                         _padded([q_c, q_r], rows.shape[-1]), rows,
                         preferred_element_type=F32)
     scores = _scaled(scores, d_n + d_r, scale)
-    col = jnp.arange(rows.shape[1])[None, None, None, :]
-    scores = jnp.where(col <= positions[:, None, :, None], scores, -1e30)
+    if visible is None:
+        col = jnp.arange(rows.shape[1])[None, None, :]
+        visible = col <= positions[:, :, None]
+    scores = jnp.where(visible[:, None], scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1).astype(q_n.dtype)
     # the values are the first `rank` lanes of the key row: the sum is
     # taken over the whole row where it lies and cut afterwards
@@ -301,3 +320,150 @@ def prompt_attention(q_n: jax.Array, q_r: jax.Array, c: jax.Array,
                               dispatch.interpret_forced())
         blocks = nb * (nb + 1) // 2
     return out[:, :t], blocks
+
+
+# -------------------------------------------------------- the band, rings
+
+def ring_visible(positions: jax.Array, rows: int, window: int) -> jax.Array:
+    """Which rows of a ring of `rows >= window` rows the query at
+    `positions` [B, t] sees, its own row written: row r holds the newest
+    position `p <= position` with `p mod rows == r`, and counts while
+    `position - p < window` and `p >= 0`. Returns [B, t, rows] bool."""
+    age = (positions[..., None] - jnp.arange(rows)) % rows
+    return (age < window) & (age <= positions[..., None])
+
+
+def _band_seen(block: int, window: int, n):
+    """(which keys of the block before, which of the own block) a query
+    of block `n` sees, [block, block] bool each, by their places in their
+    blocks: the key at `j` of the block before lies `block + i - j`
+    behind the query at `i`; block 0 has no block before it."""
+    i = jax.lax.broadcasted_iota(jnp.int32, (block, block), 0)
+    j = jax.lax.broadcasted_iota(jnp.int32, (block, block), 1)
+    return (block + i - j < jnp.where(n > 0, window, 0),
+            (j <= i) & (i - j < window))
+
+
+def _band_blocked(q_n, q_r, k_n, k_r, v, scale: float, block: int,
+                  window: int) -> jax.Array:
+    """The band in `jax.numpy`: q_n [H, Tp, d_n], q_r [H, Tp, d_r], k_n
+    [H, Tp, d_n], k_r [Tp, d_r], v [H, Tp, d_v] -> [H, Tp, d_v]."""
+    h, tp, d_v = v.shape
+    nb = tp // block
+
+    def cut(x):                 # [.., Tp, d] -> [nb, .., block, d]
+        return jnp.moveaxis(
+            x.reshape(x.shape[:-2] + (nb, block, x.shape[-1])), -3, 0)
+
+    def shifted(x):             # block i holds block i - 1 (block 0: itself)
+        return jnp.concatenate([x[:1], x[:-1]], 0)
+
+    kn_b, kr_b, v_b = cut(k_n), cut(k_r), cut(v)
+
+    def q_block(args):
+        n, qn_i, qr_i, kn_0, kr_0, v_0, kn_1, kr_1, v_1 = args
+        before, own = _band_seen(block, window, n)
+
+        def scores(kn, kr, seen):
+            s = (jnp.einsum("htd,hsd->hts", qn_i, kn,
+                            preferred_element_type=F32)
+                 + jnp.einsum("htd,sd->hts", qr_i, kr,
+                              preferred_element_type=F32)) * scale
+            return jnp.where(seen[None], s, _NEG)
+
+        s = jnp.concatenate([scores(kn_0, kr_0, before),
+                             scores(kn_1, kr_1, own)], -1)
+        p = jax.nn.softmax(s, -1).astype(v.dtype)
+        return jnp.einsum("hts,hsd->htd", p,
+                          jnp.concatenate([v_0, v_1], 1)).astype(v.dtype)
+
+    out = jax.lax.map(q_block, (
+        jnp.arange(nb), cut(q_n), cut(q_r), shifted(kn_b), shifted(kr_b),
+        shifted(v_b), kn_b, kr_b, v_b))
+    return jnp.moveaxis(out, 0, 1).reshape(h, tp, d_v)
+
+
+def _band_kernel(qn_ref, qr_ref, kn0_ref, kn1_ref, kr0_ref, kr1_ref,
+                 v0_ref, v1_ref, o_ref, *, scale: float, block: int,
+                 window: int):
+    """One (head, block of queries) program: the block's queries against
+    the block of keys before theirs (refs `0`; block 0 has none, and is
+    handed its own again, masked whole) and their own (refs `1`), one
+    softmax over both."""
+    qi = pl.program_id(1)
+    cd = qn_ref.dtype
+    fold = scale * _LOG2E
+    qn = (qn_ref[...].astype(F32) * fold).astype(cd)
+    qr = (qr_ref[...].astype(F32) * fold).astype(cd)
+    last = (((1,), (1,)), ((), ()))
+    before, own = _band_seen(block, window, qi)
+
+    def scores(kn_ref, kr_ref, seen):
+        s = jax.lax.dot_general(qn, kn_ref[...], last,
+                                preferred_element_type=F32) \
+            + jax.lax.dot_general(qr, kr_ref[...], last,
+                                  preferred_element_type=F32)
+        return jnp.where(seen, s, _NEG)
+
+    s0 = scores(kn0_ref, kr0_ref, before)
+    s1 = scores(kn1_ref, kr1_ref, own)
+    m = jnp.maximum(jnp.max(s0, -1, keepdims=True),
+                    jnp.max(s1, -1, keepdims=True))
+    p0, p1 = jnp.exp2(s0 - m), jnp.exp2(s1 - m)
+    total = jnp.sum(p0, -1, keepdims=True) + jnp.sum(p1, -1, keepdims=True)
+    rows = (((1,), (0,)), ((), ()))
+    acc = jax.lax.dot_general(p0.astype(cd), v0_ref[...], rows,
+                              preferred_element_type=F32) \
+        + jax.lax.dot_general(p1.astype(cd), v1_ref[...], rows,
+                              preferred_element_type=F32)
+    o_ref[...] = (acc / total).astype(o_ref.dtype)
+
+
+def _band_pallas(q_n, q_r, k_n, k_r, v, scale: float, block: int,
+                 window: int, tokens: int, interpret: bool) -> jax.Array:
+    h, tp, d_n = q_n.shape
+    d_r, d_v = q_r.shape[-1], v.shape[-1]
+    own = lambda d: pl.BlockSpec((None, block, d), lambda g, i: (g, i, 0))
+    before = lambda d: pl.BlockSpec(
+        (None, block, d), lambda g, i: (g, jnp.maximum(i - 1, 0), 0))
+    return pl.pallas_call(
+        functools.partial(_band_kernel, scale=scale, block=block,
+                          window=window),
+        grid=(h, tp // block),
+        in_specs=[own(d_n), own(d_r), before(d_n), own(d_n),
+                  pl.BlockSpec((block, d_r),
+                               lambda g, i: (jnp.maximum(i - 1, 0), 0)),
+                  pl.BlockSpec((block, d_r), lambda g, i: (i, 0)),
+                  before(d_v), own(d_v)],
+        out_specs=own(d_v),
+        out_shape=jax.ShapeDtypeStruct((h, tp, d_v), q_n.dtype),
+        interpret=interpret,
+        name=f"mla_band_w{window}_t{tokens}",
+        compiler_params=None if interpret else pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
+    )(q_n, q_r, k_n, k_n, k_r, k_r, v, v)
+
+
+def band_prompt_attention(q_n: jax.Array, q_r: jax.Array, k_n: jax.Array,
+                          k_r: jax.Array, v: jax.Array, scale: float,
+                          window: int, block: int, tokens: int
+                          ) -> jax.Array:
+    """Attention of some heads over a prompt from position 0 in which
+    query t sees `t - window < s <= t`: q_n [H, Tp, d_n], q_r [H, Tp,
+    d_r], k_n [H, Tp, d_n], k_r [Tp, d_r] (the ONE rotated key part), v
+    [H, Tp, d_v], Tp a whole number of `block >= window - 1`, of which
+    the first `tokens` rows are the prompt. Returns [H, Tp, d_v] in q's
+    dtype. ONE sequence: the caller maps over a batch, and expands keys
+    and values for as many heads as it can hold."""
+    h, tp, d_n = q_n.shape
+    if block < window - 1 or tp % block:
+        raise ValueError(f"the band of {window} needs whole blocks of at "
+                         f"least {window - 1} (block {block}, {tp} rows)")
+    shape = (tokens, h, d_n + q_r.shape[-1], v.shape[-1], window, block)
+    reason = dispatch.backend_reason()
+    if reason:
+        dispatch.record_choice("mla_band", shape, "reference", reason)
+        return _band_blocked(q_n, q_r, k_n, k_r, v, scale, block, window)
+    dispatch.record_choice("mla_band", shape, "pallas")
+    return _band_pallas(q_n, q_r, k_n, k_r, v, scale, block, window,
+                        tokens, dispatch.interpret_forced())

@@ -136,6 +136,15 @@ def _seen(q_at, k_at, window: Optional[int]):
     return seen
 
 
+def ring_rows(x: jax.Array, rows: int) -> jax.Array:
+    """x [B, T, ...] at positions 0 .. T-1 as a ring of `rows` rows holds
+    it: the last min(T, rows) positions, each at `p mod rows`."""
+    t = x.shape[1]
+    if t <= rows:
+        return x
+    return jnp.roll(x[:, t - rows:], (t - rows) % rows, axis=1)
+
+
 def plain_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     window: Optional[int] = None) -> jax.Array:
     """The masked softmax as it stands: q [B, T, H, d], k, v [B, T, G, d],
