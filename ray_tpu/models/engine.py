@@ -126,7 +126,15 @@ rows up to its position (`ops/swa.decode_attention`), so a slot left
 where its last request ended would have that request's rows read tick
 after tick for nobody; parked, it costs one block, and its scatter lands
 in row 0, which the next `_splice_slot` overwrites (a ring's row 0
-likewise). A weight swap holds from the next LAUNCH: the tick in
+likewise). And it costs NO state where the family's state step walks
+(`Family.state_walks`: `ops/mamba2.ssd_step`): the step is handed the
+device's liveness vector, the one `_set_rows` writes and `_chosen`
+reads, visits the slots it holds live and neither reads nor writes a
+dead slot's state, which the next `_splice_slot` writes whole (a slot
+whose budget ends with the tick ahead is still live on the chip for one
+step more, as its row is still decoded). A family whose step does not
+walk steps every slot's state, tick after tick, for nobody. A weight
+swap holds from the next LAUNCH: the tick in
 flight finishes on the weights it was launched with. The depth is what
 the engine can see, not a knob: a pass that carries drafts needs the
 host's tokens to draft from, so a speculating engine reads the tick in
@@ -197,7 +205,12 @@ rows one layer's walk over the slab's longest entries visits, ALL slots
 at the positions the tick was launched with: whole blocks, a dead slot's
 one block, by the function the kernel's walk uses,
 `ops/swa.decode_rows_read`; ``max_batch`` x rows for a family whose tick
-reads every row: `Family.decode_walks`), where the slab holds a
+reads every row: `Family.decode_walks`), for a family with state
+``state_slots_stepped`` (the slot-states ONE layer's state step visits:
+the slots the chip held live at the launch, counted on the host from
+what `_set_rows` last wrote, where the step walks them,
+`Family.state_walks`; ``max_batch`` for a program that steps every
+slot's), where the slab holds a
 ring ``live_rows_window`` (above) and, where the family's
 decode hands back counters of the step, those under their own names
 (``moe_pairs_held``, token-expert pairs that fell on experts held here,
@@ -751,7 +764,9 @@ def _tick(params, config, cache, tokens, pos_vec, live=None, lora=None):
     family's decode may hand back a third value, a dict of small
     counters of the step (models/nemotron_h.py: what its expert layers
     saw); it comes back beside the tokens, None for a family that has
-    none.
+    none. A family whose state step walks the live slots
+    (`Family.state_walks`) is handed `live` too, so a dead slot's state
+    is neither read nor written.
 
     `lora` makes it the mixed-tenant tick: PER-SLOT adapter indices
     (`lora["idx"]`) gather each slot's low-rank deltas out of the
@@ -765,7 +780,12 @@ def _tick(params, config, cache, tokens, pos_vec, live=None, lora=None):
     args = (params, tokens, config, cache, pos_vec)
     if lora is not None:
         args += (lora,)
-    logits, cache, *counts = family_of(config).decode(*args)
+    family = family_of(config)
+    # a state step that walks the live slots walks by THIS vector, the
+    # one `_set_rows` writes and `_chosen` reads (the host's mirror is a
+    # tick stale: the loop launches a tick ahead)
+    walk = {"live": live} if family.state_walks and live is not None else {}
+    logits, cache, *counts = family.decode(*args, **walk)
     nxt, lp, pos_next = _chosen(logits, config, tokens, pos_vec, live)
     return cache, nxt, lp, (counts[0] if counts else None), pos_next
 
@@ -791,8 +811,9 @@ class _Flight(NamedTuple):
     and `live_rows` are their count and the sum of their positions,
     `live_rows_window` the sum of `min(position, rows)` for the slab's
     ring (None without one), `slab_rows_read` the rows one layer's walk
-    reads over ALL slots; `seq` is the ledger's count of launches
-    with this tick the newest."""
+    reads over ALL slots, `state_slots_stepped` the slot-states one
+    layer's state step visits (None for a family without state); `seq`
+    is the ledger's count of launches with this tick the newest."""
 
     nxt: Any
     lp: Any
@@ -802,6 +823,7 @@ class _Flight(NamedTuple):
     live_rows: int
     live_rows_window: Optional[int]
     slab_rows_read: int
+    state_slots_stepped: Optional[int]
     drafts: Optional[Dict[int, List[int]]]
     seq: int
 
@@ -995,6 +1017,9 @@ class ContinuousBatchingEngine:
         if family.decode_walks:
             self._walk_block = decode_block(spec.longest.shape,
                                             spec.longest.dtype)
+        # the tick's state step visits the slots the DEVICE's liveness
+        # vector holds live, and no other (else: every slot's)
+        self._state_walks = spec.stateful and family.state_walks
         if speculate_k is None:
             speculate_k = default_speculate_k()
         # what a cache that is not full-length keys and values cannot
@@ -1088,6 +1113,11 @@ class ContinuousBatchingEngine:
         self._dev = tuple(jnp.zeros(max_batch, jnp.int32)
                           for _ in range(3))
         self._dirty = np.zeros(max_batch, bool)
+        # the liveness `_set_rows` last wrote into `_dev`: what the chip
+        # holds, which the host's `_slot_req` runs a launch ahead of
+        self._dev_live = np.zeros(max_batch, bool)
+        self.ticks_launched = 0
+        self.state_slots_stepped = 0  # over them, one layer's step
         self.lookahead_ticks = 0      # launched behind a tick in flight
         self.lookahead_discarded = 0  # their rows a finished slot left
         self._gaps = _GapLedger()     # module docstring
@@ -1391,6 +1421,10 @@ class ContinuousBatchingEngine:
             slab=[dict(entry) for entry in self._spec.slab],
             lookahead_ticks=self.lookahead_ticks,
             lookahead_discarded=self.lookahead_discarded,
+            # over the ticks launched, the slot-states ONE layer's state
+            # step visited (`max_batch` a tick where it steps every slot)
+            ticks_launched=self.ticks_launched,
+            state_slots_stepped=self.state_slots_stepped,
             # stream-gaps counted, those that held an admission, the
             # milliseconds blocked on the chip and those it was starved
             # for by the host's step (module docstring: what the
@@ -1405,6 +1439,8 @@ class ContinuousBatchingEngine:
             gqa_decode=dispatch.kernel_choices("gqa_decode"),
             # and of the Mamba-1 selective scan (ops/mamba1.py)
             selective_scan=dispatch.kernel_choices("selective_scan"),
+            # and of the Mamba-2 state step, shapes (B, H, P, G, N)
+            state_step=dispatch.kernel_choices("state_step"),
         )
         s.update(self.speculation_stats())
         if self.kv_cache is None:
@@ -1815,6 +1851,7 @@ class ContinuousBatchingEngine:
                     fresh[2] = self._pos
                     fresh[3] = [r is not None for r in self._slot_req]
                     self._dev = _set_rows(*self._dev, fresh)
+                    self._dev_live[self._dirty] = fresh[3][self._dirty]
                     self._dirty[:] = False
                     gaps.launched()
                 tok, pos, mask = self._dev
@@ -1833,12 +1870,22 @@ class ContinuousBatchingEngine:
             self._cache = cache
             if not drafts:
                 self._dev = (nxt, pos_next, mask)
+        # the slot-states one layer's step visits: the slots the chip
+        # holds live (one whose budget ends with the tick ahead among
+        # them: the host learns of its end a launch before the chip)
+        stepped = None
+        if self.stateful:
+            stepped = int(self._dev_live.sum()) \
+                if self._state_walks and mask is not None \
+                else self.max_batch
+            self.state_slots_stepped += stepped
+        self.ticks_launched += 1
         if behind is not None:
             self.lookahead_ticks += 1
         if it is not None:
             it["dispatch_ms"] += (_now() - t0) * 1e3
         return _Flight(nxt, lp, counts, reqs, live, rows, rows_window,
-                       rows_read, drafts, gaps.launches)
+                       rows_read, stepped, drafts, gaps.launches)
 
     def _land(self, flight: _Flight, it: Optional[Dict[str, Any]],
               inflight: int = 0) -> None:
@@ -1902,6 +1949,8 @@ class ContinuousBatchingEngine:
                       inflight=inflight)
             if flight.live_rows_window is not None:
                 it["live_rows_window"] = flight.live_rows_window
+            if flight.state_slots_stepped is not None:
+                it["state_slots_stepped"] = flight.state_slots_stepped
 
     def _spec_tokens(self, drafts: Dict[int, List[int]]) -> np.ndarray:
         """The widened verify tick's input: [last_token, draft...] per

@@ -42,7 +42,10 @@ class Family:
     engine's admission record). `lora_targets(config)`: the leaves of
     every block's ["attn"] an adapter applies to, `((name, in, out),
     ...)`. `decode_walks`: the tick attends through
-    `ops/swa.decode_attention`, each slot's rows up to its position."""
+    `ops/swa.decode_attention`, each slot's rows up to its position.
+    `state_walks`: the tick's state step visits the live slots alone:
+    `decode` takes `live` [B], the tick's own liveness vector, beside its
+    other arguments (`ops/mamba2.ssd_step`)."""
 
     config_type: type
     init: Callable
@@ -55,6 +58,7 @@ class Family:
     forward_counted: Optional[Callable] = None
     lora_targets: Optional[Callable] = None
     decode_walks: bool = False
+    state_walks: bool = False
 
 
 def family_of(config: Any, support: str = "generation") -> Family:

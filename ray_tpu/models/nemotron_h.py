@@ -29,14 +29,15 @@ entry whole. `forward_cached` continues from whatever the cache holds
 (the chunked scan from a carried state), hands back the logits of the
 LAST position only (`num_logits_to_keep` 1: a [T, vocab] float32 block
 at the published vocabulary has no room beside the weights), and
-`decode` runs one recurrence step for every slot at its own position and
+`decode` runs one recurrence step for every slot at its own position (for
+the slots that are `live`, where the engine's tick says which) and
 reports what the expert layers' grouped products saw.
 """
 from __future__ import annotations
 
 import math
 from dataclasses import dataclass
-from typing import Any, Dict, List, Tuple
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -220,16 +221,18 @@ def _mamba_out(y: jax.Array, z: jax.Array, p: Params,
                p["w_out"])
 
 
-def _mamba(h: jax.Array, p: Params, c: NemotronHConfig,
-           cache: Params) -> Tuple[jax.Array, Params]:
+def _mamba(h: jax.Array, p: Params, c: NemotronHConfig, cache: Params,
+           live: Optional[jax.Array] = None) -> Tuple[jax.Array, Params]:
     """h [B, T, D] on top of the state in `cache`: the chunked scan for a
-    run of tokens, the recurrence itself for one token a row."""
+    run of tokens, the recurrence itself for one token a row (of the rows
+    that are `live`, where the caller knows which: `ops/mamba2.ssd_step`)."""
     z, xbc, dt = _mamba_inputs(h, p, c)
     xbc, tail = causal_conv(xbc, cache["conv"], p["conv_w"], p["conv_b"])
     a = -jnp.exp(p["A_log"])
     if h.shape[1] == 1:
         x, bm, cm = _mamba_split(xbc[:, 0], c)
-        y, state = ssd_step(x, dt[:, 0], a, bm, cm, p["D"], cache["ssm"])
+        y, state = ssd_step(x, dt[:, 0], a, bm, cm, p["D"], cache["ssm"],
+                            live)
     else:
         x, bm, cm = _mamba_split(xbc, c)
         y, state = ssd_scan(x, dt, a, bm, cm, p["D"], cache["ssm"],
@@ -400,9 +403,12 @@ def nemotron_h_forward_cached(params: Params, tokens: jax.Array,
 
 def nemotron_h_decode(params: Params, tokens: jax.Array,
                       config: NemotronHConfig, cache: list,
-                      pos_vec: jax.Array):
+                      pos_vec: jax.Array,
+                      live: Optional[jax.Array] = None):
     """One step for a ragged batch: tokens [B], slot b at position
-    pos_vec[b]. Returns (logits [B, vocab] float32, the new cache, the
+    pos_vec[b]; `live` [B] (0: a slot nobody decodes for, whose state the
+    Mamba layers then leave as it lies; None: every slot is stepped).
+    Returns (logits [B, vocab] float32, the new cache, the
     expert layers' counts for the engine's loop record: token-expert
     pairs that fell on held experts, summed over the layers, and the most
     rows one held expert got). A state cannot be un-advanced, so there is
@@ -420,7 +426,8 @@ def nemotron_h_decode(params: Params, tokens: jax.Array,
         with jax.named_scope(_SCOPE[kind]):
             h = rms_norm(x, p["norm"]["scale"], c.norm_eps)
             if kind == "M":
-                y, new_cache[at] = _mamba(h, p["mamba"], c, cache[at])
+                y, new_cache[at] = _mamba(h, p["mamba"], c, cache[at],
+                                          live)
             elif kind == "*":
                 y, new_cache[at] = _attention(h, p["attn"], c, cache[at],
                                               positions)
@@ -460,4 +467,4 @@ FAMILY = Family(
     forward=nemotron_h_forward, loss=nemotron_h_loss,
     partition_specs=nemotron_h_partition_specs,
     init_cache=nemotron_h_init_cache, forward_cached=nemotron_h_forward_cached,
-    decode=nemotron_h_decode, decode_walks=True)
+    decode=nemotron_h_decode, decode_walks=True, state_walks=True)

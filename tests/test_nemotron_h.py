@@ -250,3 +250,112 @@ def test_the_prefill_hands_back_the_last_positions_logits_only(tiny):
     assert logits.shape == (1, 1, cfg.vocab_size)
     full = nemotron_h_forward(params, toks, cfg)
     np.testing.assert_allclose(logits[0, 0], full[0, -1], atol=2e-2)
+
+
+# ------------------------------------------- a dead slot's state (PR 47)
+
+def _ticks(engine):
+    return [r for r in reqtrace.store().loop_records()
+            if r["engine_id"] == engine.engine_id
+            and "state_slots_stepped" in r]
+
+
+@pytest.mark.parametrize("kernel", [False, True])
+def test_a_slot_that_stood_dead_between_two_live_ones_is_admitted_again(
+        tiny, kernel):
+    """The state step visits the slots the chip holds live: slot 1's
+    request ends, the slot stands dead between two that decode on, and the
+    request spliced into it later decodes what it does on a fresh engine;
+    its neighbours decode what they do beside a slot that never died. On
+    the CPU the step is the plain one over every row; under interpret
+    mode the kernel, which leaves a dead slot's state where it lies."""
+    from contextlib import nullcontext
+
+    from ray_tpu.ops import dispatch
+
+    # a window of its own for each form: the tick's program is traced
+    # once a config, under whichever form the process then had
+    cfg, params = tiny
+    cfg = dataclasses.replace(cfg, max_seq_len=120 if kernel else 124)
+    left, short, right = _prompts()
+    later = [7, 11, 13, 17, 19]
+
+    def serve(dies):
+        reqtrace._reset_store_for_tests()
+        engine = ContinuousBatchingEngine(params, cfg, max_batch=3)
+        try:
+            a = engine.stream(left, 100)
+            b = engine.stream(short, 4 if dies else 100)
+            c = engine.stream(right, 100)
+            out = {}
+            if dies:
+                assert len(list(b)) == 4
+                # the slot stands dead for several ticks beside two live
+                seen = len(_ticks(engine))
+                until = time.time() + 60
+                while len(_ticks(engine)) < seen + 8 and time.time() < until:
+                    time.sleep(0.002)
+                between = len(_ticks(engine))
+                assert (a._req.slot, b._req.slot, c._req.slot) == (2, 1, 0)
+                d = engine.stream(later, 8)
+                out["later"] = list(d)
+                assert d._req.slot == 1
+            out["left"], out["right"] = list(a), list(c)
+            stats = engine.kv_stats()
+        finally:
+            engine.stop()
+        ticks = _ticks(engine)
+        reqtrace._reset_store_for_tests()
+        return out, ticks, stats, (seen, between) if dies else None
+
+    with dispatch.pallas_interpret() if kernel else nullcontext():
+        dispatch.reset_kernel_choices()
+        died, ticks, stats, (seen, between) = serve(True)
+        never, _, _, _ = serve(False)
+        fresh = ContinuousBatchingEngine(params, cfg, max_batch=3)
+        try:
+            assert died["later"] == fresh.generate(later, 8)
+        finally:
+            fresh.stop()
+    assert len(died["left"]) == 100 and died["left"] == never["left"]
+    assert died["right"] == never["right"]
+    # one layer's step visits the slots the chip held live at the launch:
+    # the ones the tick decodes for, and at most those whose budget ended
+    # with the tick ahead (the chip learns of an end one launch later)
+    assert all(r["live"] <= r["state_slots_stepped"] <= 3 for r in ticks)
+    assert ticks[0]["state_slots_stepped"] <= 2 or ticks[0]["live"] == 3
+    # while the slot stood dead: the tick launched AHEAD of its last one
+    # still held it live on the chip, every tick after the two live ones
+    last = max(i for i in range(between) if ticks[i]["live"] == 3)
+    dead = [r["state_slots_stepped"] for r in ticks[last + 1:between]]
+    assert {r["live"] for r in ticks[last + 1:between]} == {2}
+    assert len(dead) >= 4 and dead[0] == 3 and set(dead[1:]) == {2}
+    assert 3 in {r["state_slots_stepped"] for r in ticks[between:]}
+    assert stats["state_slots_stepped"] >= sum(
+        r["state_slots_stepped"] for r in ticks)
+    assert stats["ticks_launched"] >= len(ticks)
+    c = cfg
+    (choice,) = [s for s in stats["state_step"] if s["shape"] == (
+        3, c.mamba_num_heads, c.mamba_head_dim, c.n_groups,
+        c.ssm_state_size)]
+    assert choice["choice"] == ("pallas" if kernel else "reference")
+
+
+def test_a_family_whose_step_does_not_walk_steps_every_slot():
+    """Jamba's `selective_step` steps all slots' state: its ticks say so,
+    and a family without state says nothing."""
+    from ray_tpu.models.jamba import JambaConfig, jamba_init
+
+    cfg = JambaConfig.tiny()
+    reqtrace._reset_store_for_tests()
+    engine = ContinuousBatchingEngine(
+        jamba_init(cfg, jax.random.PRNGKey(0)), cfg, max_batch=3)
+    try:
+        engine.generate([5, 6, 7, 8, 9], 4)
+        stats = engine.kv_stats()
+    finally:
+        engine.stop()
+    ticks = _ticks(engine)
+    reqtrace._reset_store_for_tests()
+    assert ticks and {r["state_slots_stepped"] for r in ticks} == {3}
+    assert stats["state_slots_stepped"] == 3 * stats["ticks_launched"]
