@@ -4,23 +4,36 @@
 against ALL rows of ALL slots, masked afterwards) at the slab shapes the
 served cells hold, with slots as full as their cells leave them. The
 block `_decode_block` chooses, and the rule by which a shape keeps the
-slab form, stand on this table (PERF.md section 6, PR 41).
+slab form, stand on this table (PERF.md section 6, PR 41). And the
+absorbed form over latent rows (`ops/mla.py` `absorbed_attention`): the
+walk `mla_decode_t<t>` against the plain form over every row, at the two
+served latent shapes with 5, 13, 43 and 100% of the entry's rows live
+(PERF.md section 6, PR 48).
 
     chiprun --chips 1 -- python3 examples/decode_attention_sweep.py
 
-A time is the wall clock of one jitted chain of `--chain` calls, each
-fed the one before's output (as a tick's layers are), over the calls;
-`floor_us` is what the rows the walk visits (keys and values, whole
-blocks) need at 819 GB/s. Fails without a TPU; `--toy 1` walks the same
-code at toy widths in interpret mode, on any backend, and its times mean
-nothing.
+A grouped-query time is the wall clock of one jitted chain of `--chain`
+calls, each fed the one before's output (as a tick's layers are), over
+the calls; `floor_us` is what the rows the walk visits (keys and values,
+whole blocks) need at 819 GB/s. A latent time is the DEVICE's: each path
+runs `CALLS` times under one profiler trace, `us` is the mean duration of
+its program (the fold of W_uk into the query and W_uv's product
+included, the same in every path) and `kernel_us` of the kernel's own
+event. Fails without a TPU; `--toy 1` walks the same code at toy widths
+in interpret mode, on any backend, and its times mean nothing (the
+latent cases then take none).
 """
 from __future__ import annotations
 
 import argparse
+import contextlib
+import glob
 import json
 import os
+import re
+import shutil
 import sys
+import tempfile
 import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
@@ -29,7 +42,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ray_tpu.ops import swa
+from ray_tpu.ops import dispatch, mla, swa
 
 HBM_BYTES_PER_S = 819e9
 
@@ -54,6 +67,141 @@ CASES = {
 }
 TOY = {name: ((4, t, 2 * h // g, 2, 32, 96), 2, 40, 90, parked)
        for name, ((_, t, h, g, _, _), _, _, _, parked) in CASES.items()}
+
+
+# name: ((B, t, H, d_n, d_r, d_v, rank, S), the slots its cell holds live,
+# the softmax scale). A share f of the entry's rows is live in
+# max(those, f x B) slots at one position each, the others parked at 0
+LATENT = {
+    "kimi-linear": ((128, 1, 32, 128, 64, 128, 512, 2816), 34, None),
+    "deepseek-v2": ((16, 1, 128, 128, 64, 128, 512, 8448), 16, 0.1147),
+}
+LATENT_TOY = {name: ((4, 1, h // 8, 16, 8, 16, 128, 384), 2, scale)
+              for name, ((_, _, h, *_), _, scale) in LATENT.items()}
+SHARES = (0.05, 0.13, 0.43, 1.0)
+CALLS = 10
+
+
+def device_times(trace_dir):
+    """{program: ([durations of its events], [those of the walk's kernel
+    inside each])} from the trace's first device plane, in us."""
+    from jax.profiler import ProfileData
+    (path,) = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                        recursive=True)
+    plane = min((p for p in ProfileData.from_file(path).planes
+                 if p.name.startswith("/device:TPU:")), key=lambda p: p.name)
+    lines = {line.name: [(ev.name, ev.start_ns, ev.duration_ns)
+                         for ev in line.events] for line in plane.lines}
+    ops = [ev for ev in lines["XLA Ops"] if "mla_decode" in ev[0]]
+    times = {}
+    for name, start, dur in lines["XLA Modules"]:
+        m = re.match(r"jit_(v\d+)\b", name)
+        if m:
+            whole, kernel = times.setdefault(m.group(1), ([], []))
+            whole.append(dur / 1e3)
+            kernel.append(sum(d for _n, s, d in ops
+                              if start <= s < start + dur) / 1e3)
+    return times
+
+
+def latent_case(name, blocks, toy, rng):
+    """One latent shape: the plain form and the walk at each block, at
+    each share of live rows. The block is the one thing of the walk a
+    shape chooses (`ops/swa.decode_block`); another is had by moving the
+    bytes it is sized to, for the length of a trace."""
+    (b, t, h, d_n, d_r, d_v, rank, s), cell_live, scale = \
+        (LATENT_TOY if toy else LATENT)[name]
+    dtype = jnp.float32 if toy else jnp.bfloat16
+    key = jax.random.split(jax.random.PRNGKey(0), 5)
+    q_n = jax.random.normal(key[0], (b, t, h, d_n), dtype)
+    q_r = jax.random.normal(key[1], (b, t, h, d_r), dtype)
+    width = mla.row_width(rank, d_r)
+    rows = mla.latent_row(jax.random.normal(key[2], (b, s, rank), dtype),
+                          jax.random.normal(key[3], (b, s, d_r), dtype),
+                          width, dtype)
+    w_kvb = (0.05 * jax.random.normal(key[4], (rank, h, d_n + d_v))
+             ).astype(dtype)
+    row_bytes = width * rows.dtype.itemsize
+    chosen = swa.decode_block(rows.shape, rows.dtype)
+    served = swa._DECODE_BLOCK_BYTES
+
+    def plain(q_n, q_r, rows, pos):
+        seen = jnp.arange(s)[None, None, :] <= pos[:, :, None]
+        return mla.absorbed_attention(q_n, q_r, rows, None, w_kvb, scale,
+                                      seen)
+
+    def walk(q_n, q_r, rows, pos):
+        return mla.absorbed_attention(q_n, q_r, rows, pos, w_kvb, scale)
+
+    paths = {"plain (every row, twice)": (plain, None, served)}
+    for block in sorted({chosen} | set(blocks)):
+        if block <= s and (toy or block % 128 == 0):
+            # one latent array a step: the rule doubles a block while
+            # the one it has fits the bytes, so half of `block` just does
+            paths[f"walk {block}"] = (walk, block, served if block == chosen
+                                      else block // 2 * row_bytes)
+
+    def program(fn, name):
+        """A program of its own a path, found in the trace by its name."""
+        run = lambda *a: fn(*a)
+        run.__name__ = name
+        return jax.jit(run)
+
+    jitted = {label: program(fn, f"v{i}")
+              for i, (label, (fn, _, _)) in enumerate(paths.items())}
+    records = []
+    for share in SHARES:
+        live = min(b, max(cell_live, int(np.ceil(share * b))))
+        base = np.zeros((b,), np.int64)
+        base[rng.permutation(b)[:live]] = min(
+            int(share * b * s / live), s - t + 1) - 1
+        pos = jnp.asarray(base[:, None] + np.arange(t)[None], jnp.int32)
+        args = (q_n, q_r, rows, pos)
+        recs, want = {}, None
+        for label, fn in jitted.items():
+            _, block, sized_to = paths[label]
+            swa._DECODE_BLOCK_BYTES = sized_to
+            try:
+                assert block in (None, swa.decode_block(rows.shape,
+                                                        rows.dtype))
+                with (dispatch.pallas_interpret() if toy
+                      else contextlib.nullcontext()):
+                    one = np.asarray(fn(*args).astype(jnp.float32))
+            finally:
+                swa._DECODE_BLOCK_BYTES = served
+            want = one if want is None else want
+            read = b * s * 2 if block is None else swa.decode_rows_read(
+                np.asarray(pos), block, s)
+            recs[label] = {
+                "case": name, "shape": [b, t, h, width, rank, s],
+                "live_share": share, "live": live,
+                "live_rows": int(base.sum() + live), "path": label,
+                "chosen": block == chosen, "rows_read": read,
+                "floor_us": read * row_bytes / HBM_BYTES_PER_S * 1e6,
+                "max_abs_diff": float(np.abs(one - want).max())}
+        if not toy:
+            trace_dir = tempfile.mkdtemp(prefix="decode_attention_sweep_")
+            try:
+                jax.profiler.start_trace(trace_dir)
+                for fn in jitted.values():
+                    for _ in range(CALLS):
+                        out = fn(*args)
+                    jax.block_until_ready(out)
+                jax.profiler.stop_trace()
+                times = device_times(trace_dir)
+            finally:
+                shutil.rmtree(trace_dir, ignore_errors=True)
+            for i, label in enumerate(jitted):
+                whole, kernel = times.get(f"v{i}", ([], []))
+                recs[label].update(us=float(np.mean(whole)),
+                                   kernel_us=float(np.mean(kernel)),
+                                   calls=len(whole))
+                recs[label]["floor_share"] = recs[label]["floor_us"] / (
+                    recs[label]["kernel_us"] or recs[label]["us"])
+        for rec in recs.values():
+            records.append(rec)
+            print(json.dumps(rec), flush=True)
+    return records
 
 
 def chained(fn, n):
@@ -82,7 +230,7 @@ def kernel_at(block, toy):
 
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("--cases", default=",".join(CASES))
+    ap.add_argument("--cases", default=",".join(list(CASES) + list(LATENT)))
     ap.add_argument("--blocks", default="128,256,512,1024,2048")
     ap.add_argument("--chain", type=int, default=16)
     ap.add_argument("--toy", type=int, default=0)
@@ -97,6 +245,11 @@ def main():
     rng = np.random.default_rng(0)
     records = []
     for name in args.cases.split(","):
+        if name in LATENT:
+            records += latent_case(
+                name, [int(x) for x in args.blocks.split(",") if x], toy,
+                rng)
+            continue
         (b, t, h, g, d, s), live, lo, hi, parked = cases[name]
         key = jax.random.split(jax.random.PRNGKey(0), 3)
         q = jax.random.normal(key[0], (b, t, h, d), dtype)

@@ -257,7 +257,7 @@ def _mla_prefill(h: jax.Array, p: Params, c: DeepseekV2Config, rope,
 def _mla_decode(h: jax.Array, p: Params, c: DeepseekV2Config, rope,
                 cache: Params, positions: jax.Array):
     """One token a row at `positions` [B, 1]: its row is written where it
-    belongs and the absorbed form reads the cache as it lies."""
+    belongs and the absorbed form walks each slot's rows up to it."""
     with jax.named_scope("mla_absorbed"):
         q_n, q_r, lat, k_r, w_kvb = _mla_inputs(h, p, c, rope, positions)
         rows = latent_row(lat, k_r, c.latent_row, cache["k"].dtype)
@@ -472,4 +472,4 @@ FAMILY = Family(
     partition_specs=deepseek_v2_partition_specs,
     init_cache=deepseek_v2_init_cache,
     forward_cached=deepseek_v2_forward_cached, decode=deepseek_v2_decode,
-    forward_counted=deepseek_v2_forward_counted)
+    forward_counted=deepseek_v2_forward_counted, decode_walks=True)

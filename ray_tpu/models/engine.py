@@ -122,7 +122,8 @@ saw when the host uploaded its mirror every tick, and no position runs
 past the window. It stands still AT 0: `_finish` parks the host's
 mirror there, and the `_set_rows` that tells the chip of the death
 carries the position with it. The tick's attention walks each slot's
-rows up to its position (`ops/swa.decode_attention`), so a slot left
+rows up to its position (`ops/swa.decode_attention` over keys and
+values, `ops/mla.absorbed_attention` over latent rows), so a slot left
 where its last request ended would have that request's rows read tick
 after tick for nobody; parked, it costs one block, and its scatter lands
 in row 0, which the next `_splice_slot` overwrites (a ring's row 0
@@ -203,9 +204,11 @@ slots the tick decoded for), ``live_rows`` (the sum of their positions:
 the cache rows the tick had a reason to read), ``slab_rows_read`` (the
 rows one layer's walk over the slab's longest entries visits, ALL slots
 at the positions the tick was launched with: whole blocks, a dead slot's
-one block, by the function the kernel's walk uses,
-`ops/swa.decode_rows_read`; ``max_batch`` x rows for a family whose tick
-reads every row: `Family.decode_walks`), for a family with state
+one block, by the function the kernels' walks use,
+`ops/swa.decode_rows_read`, for keys and values and for latent rows
+alike; ``max_batch`` x rows for a family whose tick reads every row or
+the rows a selection marks, GPT-2 and `dots3_note`:
+`Family.decode_walks`), for a family with state
 ``state_slots_stepped`` (the slot-states ONE layer's state step visits:
 the slots the chip held live at the launch, counted on the host from
 what `_set_rows` last wrote, where the step walks them,
@@ -1012,7 +1015,8 @@ class ContinuousBatchingEngine:
         self.latent_only = spec.latent_only
         self.ring_rows = spec.ring_rows
         # the block by which the tick's attention walks the slab's
-        # longest entries (None: it reads every row of every slot)
+        # longest entries, keys and values or latent rows (None: it
+        # reads every row of every slot)
         self._walk_block = None
         if family.decode_walks:
             self._walk_block = decode_block(spec.longest.shape,
@@ -1437,6 +1441,9 @@ class ContinuousBatchingEngine:
             gqa_prefill=dispatch.kernel_choices("gqa_prefill"),
             # and of the decode form, shapes (B, t, H, G, d, S)
             gqa_decode=dispatch.kernel_choices("gqa_decode"),
+            # and of the absorbed form over latent rows (ops/mla.py),
+            # shapes (B, t, H, width, rank, S)
+            mla_decode=dispatch.kernel_choices("mla_decode"),
             # and of the Mamba-1 selective scan (ops/mamba1.py)
             selective_scan=dispatch.kernel_choices("selective_scan"),
             # and of the Mamba-2 state step, shapes (B, H, P, G, N)

@@ -41,8 +41,12 @@ class Family:
     hands back a third value, a dict of small counters of the run (the
     engine's admission record). `lora_targets(config)`: the leaves of
     every block's ["attn"] an adapter applies to, `((name, in, out),
-    ...)`. `decode_walks`: the tick attends through
-    `ops/swa.decode_attention`, each slot's rows up to its position.
+    ...)`. `decode_walks`: the tick's attention walks
+    each slot's rows up to its position, block by block
+    (`ops/swa.decode_attention` over keys and values,
+    `ops/mla.absorbed_attention` by positions over latent rows), by the
+    block `ops/swa.decode_block` gives for the slab's longest entry; a
+    tick that reads every row, or rows the caller marks, does not.
     `state_walks`: the tick's state step visits the live slots alone:
     `decode` takes `live` [B], the tick's own liveness vector, beside its
     other arguments (`ops/mamba2.ssd_step`)."""

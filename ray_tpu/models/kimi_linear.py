@@ -278,7 +278,7 @@ def _mla_decode(h: jax.Array, p: Params, c: KimiLinearConfig,
                 cache: Params, positions: jax.Array
                 ) -> Tuple[jax.Array, Params]:
     """One token a row at `positions` [B, 1]: its row is written where it
-    belongs and the absorbed form reads the cache as it lies."""
+    belongs and the absorbed form walks each slot's rows up to it."""
     q_n, q_r, lat, k_r, w_kvb = _mla_inputs(h, p, c)
     rows = latent_row(lat, k_r, c.latent_row, cache["k"].dtype)
     slab = cache["k"].at[jnp.arange(h.shape[0])[:, None], positions].set(
@@ -502,4 +502,5 @@ FAMILY = Family(
     forward=kimi_linear_forward, loss=kimi_linear_loss,
     partition_specs=kimi_linear_partition_specs,
     init_cache=kimi_linear_init_cache,
-    forward_cached=kimi_linear_forward_cached, decode=kimi_linear_decode)
+    forward_cached=kimi_linear_forward_cached, decode=kimi_linear_decode,
+    decode_walks=True)

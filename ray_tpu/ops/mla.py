@@ -21,19 +21,44 @@ makes them from c.
             q_r . k_r over d_r against the ONE shared key part); elsewhere
             the same blocks in `jax.numpy`, which is also the kernel's
             reference. A prompt of one block takes the expanded form.
-  absorbed  over the cache in a decode tick: W_kvb's key half is folded
-            into the query (q_n W_uk^T, rank wide), the scores are taken
-            against the rows as they lie (H query heads to one shared
-            row), the weighted sum of rows goes through W_uv. Same
-            numbers, no per-head key or value formed over the cache. At
-            128 heads it does 128 x (576 + 512) x 2 FLOPs for each
-            1,280-byte row it reads, 218 FLOP/B: at the ridge of a v5e,
-            where Kimi-Linear's 32 heads (54 FLOP/B) are bandwidth-bound.
+  absorbed  over the cache: W_kvb's key half is folded into the query
+            (q_n W_uk^T, rank wide), the scores are taken against the
+            rows as they lie (H query heads to one shared row), the
+            weighted sum of rows goes through W_uv. Same numbers, no
+            per-head key or value formed over the cache. At 128 heads it
+            does 128 x (576 + 512) x 2 FLOPs for each 1,280-byte row it
+            reads, 218 FLOP/B: at the ridge of a v5e, where Kimi-Linear's
+            32 heads (54 FLOP/B) are bandwidth-bound. A tick's run (at
+            most `ops/swa.DECODE_ROWS` rows a slot, seeing rows `<=
+            position`) WALKS (`_decode_kernel`): slot b's blocks of rows
+            from block 0 to the one that holds its last position and no
+            other (`ops/swa.decode_blocks`, the rule `ops/swa.py`'s decode
+            form walks keys and values by), under a running softmax
+            with float32 scores, sum and accumulator, so a tick reads
+            the rows its slots hold and not `max_batch x max_seq_len`
+            twice; a parked slot costs one block. On a TPU it is the
+            Pallas kernel `mla_decode_t<t>`, ONE call a layer: the
+            positions scalar-prefetched, the folded queries resident,
+            slots and a slot's blocks the kernel's own loops over blocks
+            it copies from HBM itself, two ahead of the products. A block
+            is fetched ONCE: the values are the first `rank` lanes of the
+            key row, so the weighted sum is taken over the block the
+            scores were taken against, in VMEM. All H heads (and the
+            run's t rows) are the rows of one product against the block;
+            a block every row of the run sees whole is not masked. The
+            block follows from the entry's shape (`ops/swa.decode_block`).
+            Elsewhere (a backend
+            without Mosaic, a longer run) the PLAIN form: two passes over
+            every row of every slot with [B, H, t, S] float32 scores
+            between them, masked afterwards; it is the walk's reference.
+            `dispatch.kernel_choices("mla_decode")` lists the shapes (B,
+            t, H, width, rank, S) and which ran.
             With `visible` the rows seen are the caller's, not `<=
-            position`: a RING of latent rows (`ring_visible`: the token at
-            position p lies in row `p mod rows`, and a row counts while
-            its token is one of the last `window`), or rows gathered by a
-            selection (`ops/dsa.py`).
+            position`, and the form is the plain one: a RING of latent
+            rows (`ring_visible`: the token at position p lies in row `p
+            mod rows`, and a row counts while its token is one of the
+            last `window`), or rows gathered by a selection
+            (`ops/dsa.py`).
   band      over a prompt whose layer sees the last `window` positions
             (query t sees `t - window < s <= t`): `band_prompt_attention`,
             blocks of `block >= window - 1` queries, each against its own
@@ -59,6 +84,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from . import dispatch
+from .swa import (DECODE_ROWS, _DECODE_BUFFERS, _block_seen, _block_start,
+                  decode_block, decode_blocks)
 
 F32 = jnp.float32
 LANES = 128
@@ -128,29 +155,228 @@ def absorbed_attention(q_n: jax.Array, q_r: jax.Array, rows: jax.Array,
                        scale: Optional[float] = None,
                        visible: Optional[jax.Array] = None) -> jax.Array:
     """Attention of q over the cache as it lies: rows [B, S, width] are
-    `latent_row`s, query (b, j) sees rows <= positions[b, j], or, given
-    `visible` [B, t, S] bool, the rows it marks (`positions` is then not
-    read; every query must see a row). q_n [B, t, H, d_n], q_r [B, t, H,
-    d_r], w_kvb [rank, H, d_n + d_v]. Returns [B, t, H, d_v] in q's
-    dtype."""
+    `latent_row`s, query (b, j) sees rows <= positions[b, j] (ascending
+    along t), or, given `visible` [B, t, S] bool, the rows it marks
+    (`positions` is then not read; every query must see a row). q_n [B,
+    t, H, d_n], q_r [B, t, H, d_r], w_kvb [rank, H, d_n + d_v]. Returns
+    [B, t, H, d_v] in q's dtype. A tick's run by positions walks each
+    slot's blocks up to its last position; `visible`, a longer run and a
+    backend without Mosaic take the plain form over every row (module
+    docstring). The choice reads the shapes alone, and is recorded as
+    `mla_decode`."""
     rank, d_n, d_r = w_kvb.shape[0], q_n.shape[-1], q_r.shape[-1]
     w_uk, w_uv = w_kvb[..., :d_n], w_kvb[..., d_n:]
     q_c = jnp.einsum("bthd,chd->bthc", q_n, w_uk,
                      preferred_element_type=F32).astype(q_n.dtype)
-    scores = jnp.einsum("bthw,bsw->bhts",
-                        _padded([q_c, q_r], rows.shape[-1]), rows,
-                        preferred_element_type=F32)
-    scores = _scaled(scores, d_n + d_r, scale)
-    if visible is None:
-        col = jnp.arange(rows.shape[1])[None, None, :]
-        visible = col <= positions[:, :, None]
-    scores = jnp.where(visible[:, None], scores, -1e30)
-    probs = jax.nn.softmax(scores, axis=-1).astype(q_n.dtype)
-    # the values are the first `rank` lanes of the key row: the sum is
-    # taken over the whole row where it lies and cut afterwards
-    mixed = jnp.einsum("bhts,bsw->bthw", probs, rows)[..., :rank]
+    q = _padded([q_c, q_r], rows.shape[-1])
+    block = decode_block(rows.shape, rows.dtype)
+    shape = q.shape + (rank, rows.shape[1])
+    reason = _walk_refused(q, rows, visible)
+    if reason:
+        dispatch.record_choice("mla_decode", shape, "reference", reason,
+                               block=block)
+        scores = jnp.einsum("bthw,bsw->bhts", q, rows,
+                            preferred_element_type=F32)
+        scores = _scaled(scores, d_n + d_r, scale)
+        if visible is None:
+            col = jnp.arange(rows.shape[1])[None, None, :]
+            visible = col <= positions[:, :, None]
+        scores = jnp.where(visible[:, None], scores, -1e30)
+        probs = jax.nn.softmax(scores, axis=-1).astype(q_n.dtype)
+        # the values are the first `rank` lanes of the key row: the sum
+        # is taken over the whole row where it lies and cut afterwards
+        mixed = jnp.einsum("bhts,bsw->bthw", probs, rows)[..., :rank]
+    else:
+        dispatch.record_choice("mla_decode", shape, "pallas", block=block)
+        mixed = _decode_pallas(
+            q, rows, positions.astype(jnp.int32), rank,
+            float((d_n + d_r) ** -0.5 if scale is None else scale), block,
+            dispatch.interpret_forced())
     return jnp.einsum("bthc,chd->bthd", mixed, w_uv,
                       preferred_element_type=F32).astype(q_n.dtype)
+
+
+# ------------------------------------------------------- the decode walk
+
+def _decode_kernel(pos_ref, q_ref, rows_hbm, o_ref, buf, sem, m_s, l_s,
+                   acc_s, *, block: int, t: int, heads: int, fold: float):
+    """The whole tick's walk, one slot after another, as
+    `ops/swa._decode_kernel` walks keys and values. Refs: pos [B, t] in
+    SMEM; q [B, padded, width], a slot's `t x H` folded query rows
+    `[q_c | q_r | 0]` padded to whole sublanes, resident; rows [B, S,
+    width], the slab entry as it lies in HBM; o [B, padded, v_width].
+    Scratch: `buf.shape[0]` blocks of rows with their DMA semaphores, one
+    slot's running max, sum and accumulator.
+
+    A block is copied ONCE and serves twice: the scores are taken against
+    its whole rows, the weighted sum over their first `v_width` lanes
+    (the latent; a whole number of lane tiles, so the cut is free). Slot
+    b takes `decode_blocks(positions[b, -1])` steps, the blocks of ALL
+    slots are one sequence of copies that runs `buffers - 1` ahead of the
+    products, and the blocks every row of the run sees whole (those
+    before the one that holds the run's FIRST position) come first and
+    are not masked: the selects over [t x H, block] scores are the vector
+    unit's work, and only a slot's last blocks need them. A block's
+    scores are ONE product and its accumulator is rescaled once: halves
+    of a block, each with its own rescale of [H, rank] float32, ran a
+    layer of 128 heads in 296 us for 171 (PERF.md, PR 48). The scale and
+    log2(e) multiply the float32 scores, so the query is the plain form's
+    to the bit; products take the rows' dtype (or the queries', the
+    wider) and accumulate in float32."""
+    slots, padded, _ = q_ref.shape
+    buffers = buf.shape[0]
+    s_rows = rows_hbm.shape[1]
+    v_width = acc_s.shape[1]
+    cd = jnp.promote_types(q_ref.dtype, buf.dtype)
+
+    def copy(slot, j, at):
+        rows = pl.ds(pl.multiple_of(_block_start(j, block, s_rows), 8),
+                     block)
+        return pltpu.make_async_copy(rows_hbm.at[slot, rows, :], buf.at[at],
+                                     sem.at[at])
+
+    def start(slot, j, at):
+        @pl.when(slot < slots)
+        def _():
+            copy(slot, j, at).start()
+
+    def blocks_of(slot):
+        return decode_blocks(pos_ref[jnp.minimum(slot, slots - 1), t - 1],
+                             block, s_rows)
+
+    def after(slot, j):
+        """The block that follows block `j` of `slot` in the sequence."""
+        more = j + 1 < blocks_of(slot)
+        return jnp.where(more, slot, slot + 1), jnp.where(more, j + 1, 0)
+
+    ahead = (jnp.int32(0), jnp.int32(0))
+    for at in range(buffers - 1):
+        start(*ahead, at)
+        ahead = after(*ahead)
+    # row r of a slot is a head of query r // H of the run (the padding
+    # rows go with the last query): a small static count, so comparisons
+    row = jax.lax.broadcasted_iota(jnp.int32, (padded, 1), 0)
+    query = sum((row >= i * heads).astype(jnp.int32) for i in range(1, t))
+    k_in = jax.lax.broadcasted_iota(jnp.int32, (padded, block), 1)
+
+    def slot_walk(b, carry):
+        m_s[...] = jnp.full(m_s.shape, _NEG, F32)
+        l_s[...] = jnp.zeros(l_s.shape, F32)
+        acc_s[...] = jnp.zeros(acc_s.shape, F32)
+        q_at = jnp.full((padded, 1), pos_ref[b, t - 1], jnp.int32)
+        for i in range(t - 1):
+            q_at = jnp.where(query == i, pos_ref[b, i], q_at)
+
+        def step(j, carry, masked):
+            done, a_slot, a_j = carry
+            at = jax.lax.rem(done, buffers)
+            start(a_slot, a_j, jax.lax.rem(done + buffers - 1, buffers))
+            copy(b, j, at).wait()
+            rows = buf[at].astype(cd)
+            s = jax.lax.dot_general(
+                q_ref[b].astype(cd), rows, (((1,), (1,)), ((), ())),
+                preferred_element_type=F32) * fold
+            if masked:
+                k_at = _block_start(j, block, s_rows) + k_in
+                s = jnp.where(_block_seen(q_at, k_at, j, block), s, _NEG)
+            m_prev = m_s[...]
+            m_new = jnp.maximum(m_prev, jnp.max(s, -1, keepdims=True))
+            p = jnp.exp2(s - m_new)
+            alpha = jnp.exp2(m_prev - m_new)
+            m_s[...] = m_new
+            l_s[...] = alpha * l_s[...] + jnp.sum(p, -1, keepdims=True)
+            acc_s[...] = acc_s[...] * alpha + jax.lax.dot_general(
+                p.astype(cd), rows[:, :v_width], (((1,), (0,)), ((), ())),
+                preferred_element_type=F32)
+            return (done + 1,) + after(a_slot, a_j)
+
+        last = blocks_of(b)
+        whole = jnp.minimum(
+            jnp.minimum((pos_ref[b, 0] + 1) // block, s_rows // block), last)
+        carry = jax.lax.fori_loop(
+            0, whole, functools.partial(step, masked=False), carry)
+        carry = jax.lax.fori_loop(
+            whole, last, functools.partial(step, masked=True), carry)
+        o_ref[b] = (acc_s[...] / l_s[...]).astype(o_ref.dtype)
+        return carry
+
+    jax.lax.fori_loop(0, slots, slot_walk, (jnp.int32(0),) + ahead)
+
+
+def _sublanes(rows: int) -> int:
+    """A slot's `t x H` query rows up to whole packed sublanes."""
+    return -(-rows // 16) * 16
+
+
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6))
+def _decode_pallas(q, rows, positions, rank: int, scale: float, block: int,
+                   interpret: bool) -> jax.Array:
+    """Jitted on its own so that the layers of a tick share one lowering.
+    q [B, t, H, width], rows [B, S, width] as the slab holds them,
+    positions [B, t] int32 -> [B, t, H, rank] in q's dtype."""
+    b, t, heads, width = q.shape
+    s_rows = rows.shape[1]
+    padded = _sublanes(t * heads)
+    v_width = rank if rank % LANES == 0 else width
+    stacked = jnp.pad(q.reshape(b, t * heads, width),
+                      ((0, 0), (0, padded - t * heads), (0, 0)))
+    resident = lambda w: pl.BlockSpec((b, padded, w),
+                                      lambda i, pos: (0, 0, 0))
+    visited = b * -(-s_rows // block)
+    out = pl.pallas_call(
+        functools.partial(_decode_kernel, block=block, t=t, heads=heads,
+                          fold=scale * _LOG2E),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(1,),
+            in_specs=[resident(width), pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=resident(v_width),
+            scratch_shapes=[
+                pltpu.VMEM((_DECODE_BUFFERS, block, width), rows.dtype),
+                pltpu.SemaphoreType.DMA((_DECODE_BUFFERS,)),
+                pltpu.VMEM((padded, 1), F32),
+                pltpu.VMEM((padded, 1), F32),
+                pltpu.VMEM((padded, v_width), F32)]),
+        out_shape=jax.ShapeDtypeStruct((b, padded, v_width), q.dtype),
+        interpret=interpret,
+        # the run's rows are in the name, so that a trace tells a tick's
+        # call from a verify pass's
+        name=f"mla_decode_t{t}",
+        compiler_params=None if interpret else pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        cost_estimate=pl.CostEstimate(
+            flops=2 * visited * block * padded * (width + v_width),
+            bytes_accessed=(stacked.size + b * padded * v_width)
+            * q.dtype.itemsize
+            + visited * block * width * rows.dtype.itemsize,
+            transcendentals=visited * block * padded),
+    )(positions, stacked, rows)
+    return out[:, :t * heads, :rank].reshape(b, t, heads, rank)
+
+
+def _walk_refused(q: jax.Array, rows: jax.Array,
+                  visible: Optional[jax.Array]) -> str:
+    """Why the absorbed form of q [B, t, H, width] over rows [B, S,
+    width] cannot walk; "" where it can."""
+    b, t, heads, width = q.shape
+    s_rows = rows.shape[1]
+    if visible is not None:
+        return "the caller's `visible` rows are no walk up to a position"
+    if t > DECODE_ROWS:
+        return f"a run of {t} rows is no tick's (over {DECODE_ROWS})"
+    reason = dispatch.backend_reason()
+    if reason:
+        return reason
+    if not dispatch.interpret_forced() and (width % LANES or s_rows % 16):
+        return (f"rows of {width} numbers or an entry of {s_rows} rows do "
+                "not fill the kernel's tiles")
+    # queries and outputs of every slot are resident, twice (the
+    # pipeline's two buffers): 19 MB at 128 slots of 32 heads
+    resident = 2 * b * _sublanes(t * heads) * 2 * width * q.dtype.itemsize
+    if resident > _VMEM_LIMIT // 2:
+        return (f"{resident} bytes of queries and outputs exceed the "
+                "kernel's VMEM")
+    return ""
 
 
 # ------------------------------------------------------ the prompt form

@@ -373,22 +373,32 @@ _DECODE_BUFFERS = 3
 DECODE_ROWS = 8
 
 
-def _decode_block(rows: int, groups: int, d: int, itemsize: int) -> int:
+def _decode_block(rows: int, groups: int, d: int, itemsize: int,
+                  arrays: int = 2) -> int:
     """The rows of one block of the decode walk over a slab entry [B,
     rows, groups, d]: the power of two whose keys fill
-    `_DECODE_BLOCK_BYTES`, not under 128 and not over the entry."""
+    `_DECODE_BLOCK_BYTES` (a step copies `arrays` = 2 such blocks, keys
+    and values; where ONE array is both, its one copy may be as large as
+    the two together), not under 128 and not over the entry."""
     block = 128
-    while 2 * block * groups * d * itemsize <= _DECODE_BLOCK_BYTES:
+    while block * groups * d * itemsize * arrays <= _DECODE_BLOCK_BYTES:
         block *= 2
     return min(block, rows)
 
 
 def decode_block(shape: Tuple[int, ...], dtype) -> int:
-    """The block `decode_attention` walks a slab entry [B, rows, groups,
-    d] of this dtype by: what the kernel's wrapper asks, and what a host
+    """The block a tick's walk takes a slab entry of this dtype by: keys
+    and values [B, rows, groups, d] (`decode_attention`), or latent rows
+    [B, rows, width], ONE row a token that is key and value, so a step
+    copies one array where the other copies two and its block holds
+    twice the rows at the same bytes (`ops/mla.absorbed_attention`: 256
+    rows of 1,280 bytes). What the kernels' wrappers ask, and what a host
     that counts the walk's rows asks too (`decode_rows_read`)."""
+    itemsize = jnp.dtype(dtype).itemsize
+    if len(shape) == 3:
+        return _decode_block(shape[1], 1, shape[2], itemsize, arrays=1)
     _, rows, groups, d = shape
-    return _decode_block(rows, groups, d, jnp.dtype(dtype).itemsize)
+    return _decode_block(rows, groups, d, itemsize)
 
 
 def decode_blocks(positions, block: int, rows: int):

@@ -179,6 +179,51 @@ def test_a_dead_slot_is_parked_at_row_0_and_costs_one_block():
         eng.stop()
 
 
+def test_a_latent_familys_tick_walks_its_rows_and_counts_them(monkeypatch):
+    """DeepSeek-V2's one latent row a token, with blocks of 128 rows in
+    an entry of 384: the tick takes `mla_decode`'s kernel (interpreted),
+    its tokens are the plain form's, and the ring counts the walk's rows
+    by the function the kernel walks by: two blocks for the slot whose
+    prompt reaches into the second, one for a slot beside it, be it empty
+    yet, live or parked."""
+    from ray_tpu.models import deepseek_v2 as ds
+    from ray_tpu.ops import dispatch, swa
+
+    monkeypatch.setattr(swa, "_DECODE_BLOCK_BYTES", 1)
+    cfg = dataclasses.replace(ds.DeepseekV2Config.tiny(), max_seq_len=384,
+                              dtype=jnp.float32)
+    params = ds.deepseek_v2_init(cfg, jax.random.PRNGKey(2))
+    with dispatch.pallas_interpret():
+        eng = ContinuousBatchingEngine(params, cfg, max_batch=2)
+        try:
+            assert eng._walk_block == 128
+            short = eng.stream(LONG_PROMPT, 4)
+            long = eng.stream([5, 6, 7], 12)
+            got = [[int(t) for t in s] for s in (short, long)]
+            slot = short._req.slot
+            eng.stop()
+            took = [c for c in eng.kv_stats()["mla_decode"]
+                    if tuple(c["shape"])[:2] + tuple(c["shape"])[-1:]
+                    == (2, 1, 384)]
+            assert eng._pos[slot] == 0
+        finally:
+            eng.stop()
+    assert took and all(c["choice"] == "pallas" and c["block"] == 128
+                        for c in took)
+    for prompt, out in zip((LONG_PROMPT, [5, 6, 7]), got):
+        want = generate(params, cfg, jnp.asarray(prompt)[None],
+                        max_new_tokens=len(out))[0]
+        assert out == [int(t) for t in want]
+    ring = [r for r in _ring(eng) if "slab_rows_read" in r]
+    both = [r for r in ring if r["live"] == 2]
+    read = lambda *at: swa.decode_rows_read(np.asarray(at), 128, 384)
+    assert both and {r["slab_rows_read"] for r in both} \
+        == {read(len(LONG_PROMPT), 3)} == {3 * 128}
+    assert ring[-1]["live"] == 1 \
+        and ring[-1]["slab_rows_read"] == read(0, 14) == 2 * 128
+    assert {r["slab_rows_read"] for r in ring} == {3 * 128, 2 * 128}
+
+
 def test_a_request_spliced_into_a_parked_slot_decodes_what_it_does_fresh():
     """The parked slot's scatter lands in row 0, which the next splice
     overwrites: the request after decodes its own tokens."""

@@ -19,7 +19,7 @@ from ray_tpu.models.family import (Family, family_of, refuse, row_counts,
                                    slab_spec)
 from ray_tpu.models.generate import _model_fns, lora_targets
 from ray_tpu.models.llama import LlamaConfig
-from ray_tpu.ops import dispatch, swa
+from ray_tpu.ops import dispatch, mla, swa
 
 # tiny(): (module, kind, ring, latent_only, stateful, state bytes a slot,
 # bytes a token, [(rows, layers, bytes a slot)], entries, walks)
@@ -31,9 +31,9 @@ TINY = {
     "NemotronHConfig": ("nemotron_h", "state", None, False, True, 18688,
                         128, [(128, 1, 16384)], 3, True),
     "KimiLinearConfig": ("kimi_linear", "state", None, False, True, 15744,
-                         256, [(128, 1, 32768)], 4, False),
+                         256, [(128, 1, 32768)], 4, True),
     "DeepseekV2Config": ("deepseek_v2", "latent", None, True, False, 0,
-                         768, [(128, 3, 98304)], 3, False),
+                         768, [(128, 3, 98304)], 3, True),
     "SmallThinkerConfig": ("smallthinker", "ring", 8, False, False, 0, 640,
                            [(128, 2, 32768), (8, 3, 3072)], 5, True),
     "JambaConfig": ("jamba", "state", None, False, True, 8448, 128,
@@ -147,10 +147,11 @@ def test_every_refusal_is_one_row_of_one_table(name, capability):
 
 @pytest.mark.parametrize("name", sorted(TINY))
 def test_the_engine_asks_the_kernels_own_rule_for_the_walk(name):
-    """The block `slab_rows_read` is counted by is the one
-    `decode_attention` records for the slab's longest entry, and None for
-    a family whose tick reads every row. The engine is built with no
-    weights: nothing is traced to learn it."""
+    """The block `slab_rows_read` is counted by is the one the tick's
+    attention records for the slab's longest entry (`decode_attention`
+    over keys and values, `absorbed_attention` over latent rows), and
+    None for a family whose tick reads every row. The engine is built
+    with no weights: nothing is traced to learn it."""
     cfg = _tiny(name)
     eng = ContinuousBatchingEngine(None, cfg, max_batch=3)
     try:
@@ -170,14 +171,51 @@ def test_the_engine_asks_the_kernels_own_rule_for_the_walk(name):
         assert block is None and not family_of(cfg).decode_walks
         return
     entry = spec.longest
-    _, rows, groups, d = entry.shape
-    q = jnp.zeros((3, 1, 2 * groups, d), entry.dtype)
     kv = jnp.zeros(entry.shape, entry.dtype)
-    swa.decode_attention(q, kv, kv, jnp.zeros((3, 1), jnp.int32))
-    took = [c for c in dispatch.kernel_choices("gqa_decode")
-            if tuple(c["shape"]) == (3, 1, 2 * groups, groups, d, rows)]
+    at = jnp.zeros((3, 1), jnp.int32)
+    if entry.ndim == 3:
+        _, rows, width = entry.shape
+        q = jnp.zeros((3, 1, 2, 8), entry.dtype)
+        mla.absorbed_attention(q, q, kv, at,
+                               jnp.zeros((16, 2, 16), entry.dtype))
+        took = [c for c in dispatch.kernel_choices("mla_decode")
+                if tuple(c["shape"]) == (3, 1, 2, width, 16, rows)]
+    else:
+        _, rows, groups, d = entry.shape
+        q = jnp.zeros((3, 1, 2 * groups, d), entry.dtype)
+        swa.decode_attention(q, kv, kv, at)
+        took = [c for c in dispatch.kernel_choices("gqa_decode")
+                if tuple(c["shape"]) == (3, 1, 2 * groups, groups, d, rows)]
     assert took and all(c["block"] == block for c in took)
     assert block == swa.decode_block(entry.shape, entry.dtype)
+
+
+def test_a_tick_over_marked_rows_does_not_walk():
+    """`dots3_note`'s tick hands `absorbed_attention` the rows a selection
+    or a ring's window marks (`visible`): no walk up to a position, so
+    the family says so, the engine counts every row, and every latent
+    layer of the traced tick keeps the plain form though a kernel could
+    run."""
+    from ray_tpu.models import dots3_note as m
+    cfg = m.Dots3NoteConfig.tiny()
+    assert not family_of(cfg).decode_walks
+    eng = ContinuousBatchingEngine(None, cfg, max_batch=2)
+    try:
+        assert eng._walk_block is None
+    finally:
+        eng.stop()
+    params = jax.eval_shape(lambda: m.dots3_note_init(
+        cfg, jax.random.PRNGKey(0)))
+    cache = jax.eval_shape(lambda: m.dots3_note_init_cache(cfg, 2))
+    dispatch.reset_kernel_choices()
+    with dispatch.pallas_interpret():
+        jax.eval_shape(
+            lambda p, c: m.dots3_note_decode(
+                p, jnp.zeros((2,), jnp.int32), cfg, c,
+                jnp.zeros((2,), jnp.int32)), params, cache)
+    took = dispatch.kernel_choices("mla_decode")
+    assert took and all(c["choice"] == "reference"
+                        and "`visible`" in c["reason"] for c in took)
 
 
 @pytest.mark.parametrize("what,call,words", [

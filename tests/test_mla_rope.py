@@ -6,6 +6,7 @@ the absorbed form with rotated parts, and Kimi-Linear's numbers
 bit for bit under the default scale."""
 import math
 import os
+import re
 import sys
 
 import jax
@@ -17,7 +18,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-from ray_tpu.ops import dispatch, mla, rope  # noqa: E402
+from ray_tpu.ops import dispatch, mla, rope, swa  # noqa: E402
 from ray_tpu.ops.grouped_moe import (sigmoid_topk_route,  # noqa: E402
                                      softmax_group_limited_route)
 
@@ -227,3 +228,134 @@ def test_the_first_callers_numbers_are_bit_for_bit_under_the_default_scale():
                                           24 ** -0.5), F32),
         np.asarray(expanded_before(q_n, q_r, c, k_r, w_kvb), F32),
         atol=0.02, rtol=0)
+
+
+# ------------------------------------------------ the absorbed form's walk
+
+@pytest.fixture()
+def blocks_of_128(monkeypatch):
+    """Toy rows are a few hundred bytes: the served block's bytes would
+    make one block of the whole entry."""
+    monkeypatch.setattr(swa, "_DECODE_BLOCK_BYTES", 1)
+
+
+def _slab(seed, b, t, h, rows, dtype=jnp.bfloat16, rank=128, d_r=64,
+          d_n=32, d_v=32):
+    """A run of t queries a slot over a slab entry [b, rows, 256]: a
+    latent of 128 and a shared key part of 64, as served in miniature."""
+    k = jax.random.split(jax.random.PRNGKey(seed), 5)
+    q_n = jax.random.normal(k[0], (b, t, h, d_n), dtype)
+    q_r = jax.random.normal(k[1], (b, t, h, d_r), dtype)
+    slab = mla.latent_row(jax.random.normal(k[2], (b, rows, rank), dtype),
+                          jax.random.normal(k[3], (b, rows, d_r), dtype),
+                          mla.row_width(rank, d_r), dtype)
+    w_kvb = (0.2 * jax.random.normal(k[4], (rank, h, d_n + d_v))
+             ).astype(dtype)
+    return q_n, q_r, slab, w_kvb
+
+
+def _run_positions(base, t):
+    return jnp.asarray(np.asarray(base)[:, None] + np.arange(t)[None],
+                       jnp.int32)
+
+
+# (rows of the entry, where each slot's run ENDS)
+WALKS = {"ragged": (384, [5, 127, 128, 300]),
+         "parked": (384, [0, 0, 383, 200]),
+         "last-row": (384, [383, 383, 255, 256]),
+         "no-whole-blocks": (300, [299, 171, 172, 40])}
+
+
+@pytest.mark.parametrize("where", list(WALKS))
+@pytest.mark.parametrize("t", [1, swa.DECODE_ROWS])
+@pytest.mark.parametrize("heads,scale", [(32, None), (128, 0.1147)])
+def test_the_walk_is_the_plain_absorbed_form(heads, scale, t, where,
+                                             blocks_of_128):
+    """bf16 rows and queries as served: the walk's numbers are the plain
+    form's in another order of summation, to bf16's rounding."""
+    rows, ends = WALKS[where]
+    q_n, q_r, slab, w_kvb = _slab(heads + t, 4, t, heads, rows)
+    pos = _run_positions(np.maximum(np.asarray(ends) - (t - 1), 0), t)
+    dispatch.reset_kernel_choices()
+    want = mla.absorbed_attention(q_n, q_r, slab, pos, w_kvb, scale)
+    choice, = dispatch.kernel_choices("mla_decode")
+    assert choice["choice"] == "reference" and "cpu" in choice["reason"]
+    with dispatch.pallas_interpret():
+        got = mla.absorbed_attention(q_n, q_r, slab, pos, w_kvb, scale)
+    choice, = dispatch.kernel_choices("mla_decode")
+    assert (choice["choice"], choice["block"], tuple(choice["shape"])) == (
+        "pallas", 128, (4, t, heads, 256, 128, rows))
+    want, got = np.asarray(want, F32), np.asarray(got, F32)
+    # one bf16 step at the outputs' size, and the mean far under it
+    assert np.abs(got - want).max() <= 2.0 ** -7 * np.abs(want).max()
+    assert np.abs(got - want).mean() <= 2.0 ** -10 * np.abs(want).max()
+
+
+def test_the_walk_never_reads_a_block_past_a_slots_last(blocks_of_128):
+    """The blocks past the one that holds a slot's position hold NaN, as
+    a slab's unwritten rows may hold anything: the plain form multiplies
+    them by a probability of 0 and gives NaN, the walk does not."""
+    q_n, q_r, slab, w_kvb = _slab(3, 3, 1, 32, 384, dtype=F32)
+    pos = jnp.asarray([[0], [127], [200]], jnp.int32)
+    last = np.asarray([128, 128, 256])
+    clean = mla.absorbed_attention(q_n, q_r, slab, pos, w_kvb)
+    dirty = jnp.where(np.arange(384)[None, :, None] >= last[:, None, None],
+                      jnp.nan, slab)
+    assert np.isnan(np.asarray(
+        mla.absorbed_attention(q_n, q_r, dirty, pos, w_kvb))).all()
+    with dispatch.pallas_interpret():
+        got = mla.absorbed_attention(q_n, q_r, dirty, pos, w_kvb)
+    np.testing.assert_allclose(got, clean, atol=3e-6, rtol=0)
+
+
+@pytest.mark.parametrize("what,words", [
+    ("visible", "`visible`"), ("long-run", "no tick's"),
+    ("no-mosaic", "backend is cpu"), ("queries", "VMEM")])
+def test_what_cannot_walk_takes_the_plain_form_and_says_why(what, words):
+    t = swa.DECODE_ROWS + 1 if what == "long-run" else 2
+    q_n, q_r, slab, w_kvb = _slab(5, 2, t, 4, 256, dtype=F32)
+    pos = _run_positions([100, 7], t)
+    want = mla.absorbed_attention(q_n, q_r, slab, pos, w_kvb, 0.2)
+    visible = jnp.arange(256)[None, None] <= pos[..., None]
+    dispatch.reset_kernel_choices()
+    if what == "queries":
+        # every slot's queries are resident: too many of them are not
+        shapes = [jax.ShapeDtypeStruct((4096,) + x.shape[1:], x.dtype)
+                  for x in (q_n, q_r, slab, pos)]
+        with dispatch.pallas_interpret():
+            jax.eval_shape(lambda a, b, c, d: mla.absorbed_attention(
+                a, b, c, d, w_kvb, 0.2), *shapes)
+    elif what == "no-mosaic":
+        mla.absorbed_attention(q_n, q_r, slab, pos, w_kvb, 0.2)
+    else:
+        with dispatch.pallas_interpret():
+            got = mla.absorbed_attention(
+                q_n, q_r, slab, None if what == "visible" else pos, w_kvb,
+                0.2, visible if what == "visible" else None)
+        np.testing.assert_array_equal(got, want)
+    choice, = dispatch.kernel_choices("mla_decode")
+    assert choice["choice"] == "reference" and words in choice["reason"]
+
+
+@pytest.mark.parametrize("b,heads,rows", [(128, 32, 2816), (16, 128, 8448)],
+                         ids=["kimi-linear", "deepseek-v2"])
+def test_the_walk_takes_the_served_entry_where_it_lies(b, heads, rows):
+    """Lowered for a TPU at the served shapes (no chip and no TPU compiler
+    needed to lower): the slab entry is the kernel's own operand, as the
+    program's argument, and no other op makes an array of its shape (no
+    copy, no pad, no transpose of 173 or 461 MB a layer)."""
+    q = jax.ShapeDtypeStruct((b, 1, heads, 640), jnp.bfloat16)
+    slab = jax.ShapeDtypeStruct((b, rows, 640), jnp.bfloat16)
+    pos = jax.ShapeDtypeStruct((b, 1), jnp.int32)
+    block = swa.decode_block(slab.shape, slab.dtype)
+    assert block == 256
+    text = jax.jit(lambda q, s, p: mla._decode_pallas(
+        q, s, p, 512, 0.1147, block, False)).trace(q, slab, pos).lower(
+        lowering_platforms=("tpu",)).as_text()
+    entry = f"tensor<{b}x{rows}x640xbf16>"
+    (call,) = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert "mla_decode_t1" in call and entry in call
+    made = [ln for ln in text.splitlines()
+            if re.search(r"-> (\(.*)?" + re.escape(entry), ln)
+            and "func.func" not in ln]
+    assert not made, made

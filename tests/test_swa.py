@@ -262,6 +262,21 @@ def test_the_rows_a_walk_reads_by_hand(positions, block, rows, read):
     assert swa.decode_rows_read(np.asarray(positions), block, rows) == read
 
 
+@pytest.mark.parametrize("shape,block", [
+    ((128, 2816, 640), 256),        # kimi-linear: 1,280 bytes a latent row
+    ((16, 8448, 640), 256),         # deepseek-v2
+    ((2, 96, 128), 96),             # never over the entry
+    ((32, 2304, 8, 128), 128)])     # keys and values: `_decode_block`'s
+def test_one_latent_array_a_step_doubles_the_blocks_rows(shape, block):
+    """A latent row is key and value: a step copies one array where keys
+    and values are two, so at the same bytes a step its block holds twice
+    the rows; the rule reads the entry's rank."""
+    assert swa.decode_block(shape, jnp.bfloat16) == block
+    if len(shape) == 3:
+        assert block == shape[1] or block == 2 * swa._decode_block(
+            shape[1], 1, shape[2], 2)
+
+
 @pytest.mark.parametrize("rows,groups,d,block", [
     (2304, 8, 128, 128),        # mistral-chat: 2 KB a row of keys
     (4160, 8, 128, 128),        # mistral-summarize
