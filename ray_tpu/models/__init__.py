@@ -10,7 +10,10 @@ A family is ONE file that ends in `FAMILY = Family(...)`
 slab's layout and what each kind is refused). `generate` and
 `ContinuousBatchingEngine` find it from the config's class; no list
 here or anywhere names a family for them. The names below are
-re-exports. `moe_transformer.py` trains and is not served.
+re-exports; `dots3_note.py` (latent attention under a learned selection)
+and `keye_vl2.py` (grouped-query attention under one, keys and values in
+pairs beside an index key in the slab) are imported where they are used.
+`moe_transformer.py` trains and is not served.
 """
 from .gpt2 import (  # noqa: F401
     GPT2Config,

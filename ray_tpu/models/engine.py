@@ -549,7 +549,9 @@ def _prefill_paged(params, suffix, config, prefix_k, prefix_v, lora=None):
     beside full-length entries) or width (an indexer's narrow keys
     beside latent rows) gets `ck` and `cv` as tuples, one stack a (row
     count, row shape) in the order the cache first shows each
-    (`family.stacks`), each at its own width, and has no cached prefix to
+    (`family.stacks`), each at its own width, a stack's `cv` None where
+    ITS entries hold no values (`SlabSpec.paired_stacks`: an indexer's
+    keys beside keys and values in pairs), and has no cached prefix to
     lay out. `state`:
     the entries that have no sequence axis, each as the family left it
     (the empty list for a family that has none). `counters`: those of
@@ -565,12 +567,12 @@ def _prefill_paged(params, suffix, config, prefix_k, prefix_v, lora=None):
     family, spec = family_of(config), slab_spec(config, 1)
     c = prefix_k.shape[1]
     cache = list(family.init_cache(config, 1))
-    by_shape, paired = spec.stacks, spec.paired
+    by_shape, pairs = spec.stacks, spec.paired_stacks
     if c and (spec.stateful or len(by_shape) > 1):
         raise ValueError(
             "a cached prefix cannot resume a recurrent state or a ring: "
             "this family prefills every prompt from position 0")
-    for (rows, *row_shape), at in by_shape.items():
+    for ((rows, *row_shape), at), paired in zip(by_shape.items(), pairs):
         base_k = jnp.zeros((len(at), rows, *row_shape), prefix_k.dtype)
         base_v = jnp.zeros_like(base_k) if paired else None
         if c:
@@ -590,7 +592,7 @@ def _prefill_paged(params, suffix, config, prefix_k, prefix_v, lora=None):
     ck = tuple(jnp.stack([cache[i]["k"][0] for i in at])
                for at in by_shape.values())
     cv = tuple(jnp.stack([cache[i]["v"][0] for i in at]) if paired else None
-               for at in by_shape.values())
+               for at, paired in zip(by_shape.values(), pairs))
     if len(by_shape) == 1:    # the one stack every consumer speaks
         ck, cv = ck[0], cv[0]
     state = [blk for blk in cache if "k" not in blk]

@@ -24,6 +24,13 @@ keys of an indexer, an entry each): a layer may leave more than one
 entry, an entry is what is stacked, and a prefill hands the sequence
 entries back one stack for each (rows, row shape) the cache shows
 (`stacks`), never assuming the first entry's width of the others.
+
+So may keys and values IN PAIRS stand beside a narrower entry WITHOUT
+values under the same row count (an indexer's keys beside grouped-query
+heads: a cache that is pairs AND index): a stack of it is in pairs or is
+not (`paired_stacks`), the slab serves it as it serves any entry, and
+what takes ONE stack of pairs for the whole cache (the paged pool, an
+adoption, the transfer) is refused it in words of its own.
 """
 from __future__ import annotations
 
@@ -112,7 +119,11 @@ class SlabSpec:
         self.by_rows = row_counts(cache)
         self.stacks = stacks(cache)
         seq = [cache[i] for at in self.by_rows.values() for i in at]
-        self.paired = all("v" in blk for blk in seq)    # "v" beside "k"
+        # "v" beside "k", stack by stack (a stack's entries are alike)
+        # and for the whole cache
+        self.paired_stacks = tuple("v" in cache[at[0]]
+                                   for at in self.stacks.values())
+        self.paired = all(self.paired_stacks)
         self.latent_only = len(seq) == len(cache) and all(
             len(blk) == 1 for blk in seq)
         self.state_bytes_per_slot = sum(
@@ -132,8 +143,9 @@ class SlabSpec:
         self.ring_rows = shortest if shortest < config.max_seq_len else None
         self.kind = ("state" if self.stateful else "latent_ring"
                      if self.latent_only and self.ring_rows else "latent"
-                     if self.latent_only else "ring" if self.ring_rows
-                     else None)
+                     if self.latent_only else "pairs_index"
+                     if any(self.paired_stacks) and not self.paired
+                     else "ring" if self.ring_rows else None)
         # what a pool block, a cached prefix and a transfer are made of:
         # a stack [layers, n, *row_shape] of the first entry's rows (an
         # entry is a layer wherever such a stack is used). Only a cache
@@ -158,6 +170,10 @@ _KINDS = {
     "latent_ring": ("this family's cache holds one latent row a token and "
                     "no values, some of them in rings (layers that keep "
                     "only their last rows, fewer than max_seq_len): "),
+    "pairs_index": ("this family's cache holds keys and values in pairs "
+                    "and, beside them under the same row count, an "
+                    "indexer's keys with no values (a narrower entry of "
+                    "its own a layer): "),
 }
 _ENDS = {
     "prefix_cache": " (prefix_cache=True)",
@@ -244,6 +260,29 @@ _WHY = {
     ("latent_ring", "transfer"):
         "a transfer carries ONE stack of ck and cv rows in pairs, of the "
         "prompt's length, and the prefill tier's pool has one block shape",
+    ("pairs_index", "prefix_cache"):
+        "the paged pool has ONE block shape [heads, head_dim], keys and "
+        "values side by side, and no block of an index key; and a prompt "
+        "resumed behind a cached prefix must score the prefix's rows too, "
+        "which the pool does not keep the index keys of",
+    ("pairs_index", "speculate_k"):
+        "the pool proposer drafts from the paged pool's token chains, "
+        "which this cache has none of, and the family's decode has no "
+        "[B, k+1] verify form (each drafted token would select rows of its "
+        "own)",
+    ("pairs_index", "lora_pool"):
+        "the adapter pool's per-tenant prefix namespaces are the paged "
+        "pool's, which this cache cannot have, and its targets are the "
+        "attention projections of the families it knows, not an indexer's",
+    ("pairs_index", "adopt_prefill"):
+        "an adoption carries ONE stack of ck and cv rows in pairs, as the "
+        "paged pool and the transfer between replicas speak them, and the "
+        "index keys are a second stack, of another width and with no "
+        "values, without which the first tick cannot select",
+    ("pairs_index", "transfer"):
+        "a transfer carries ONE stack of ck and cv rows in pairs and the "
+        "prefill tier's pool has one block shape: the index keys would be "
+        "left behind",
 }
 
 

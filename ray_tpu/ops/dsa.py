@@ -1,7 +1,10 @@
-"""Learned sparse attention over latent rows: an INDEXER scores every
-visible row for a query, the `topk` best rows are kept, and latent
-attention (`ops/mla.py`) runs over the kept rows alone. The first op in
-the tree that takes a SET of rows.
+"""Learned sparse attention: an INDEXER scores every visible row for a
+query, the `topk` best rows are kept, and attention runs over the kept
+rows alone: latent attention (`ops/mla.py`, one latent row a token that
+all heads share) or GROUPED-QUERY attention (keys and values in pairs,
+`group` query heads to a head of them). The score and the choice are one
+code for both; what attends differs. The first op in the tree that takes
+a SET of rows.
 
   score   `I[t, s] = sum_j w[t, j] relu(q_I[t, j] . k_I[s])`: `heads`
           index heads of `dim` against ONE index key a token (its own
@@ -17,7 +20,9 @@ the tree that takes a SET of rows.
           is flipped), and the mask is `score >= that`. Scores tied with
           the k-th are ALL kept (`lax.top_k` keeps the earliest): one row
           more than `topk`, where the reference keeps `topk`. In a tick
-          the set is `lax.top_k`'s indices, and the rows are gathered.
+          the set is `lax.top_k`'s indices, and the rows are gathered; a
+          caller may have the tick stop at the furthest live position
+          (`tick_rows`, `top_rows`: the same set from shorter sorts).
   attend  over a prompt, the blocked prompt form under the mask
           (`selected_prompt_attention`): every visible block of scores is
           still computed and the unselected pairs weigh nothing, a sound
@@ -25,11 +30,17 @@ the tree that takes a SET of rows.
           the kernel's time its SELECTED pairs would need is the
           benchmark's `dsa_attention_roofline.tput`). In a tick, the
           absorbed form over the `topk` gathered rows
-          (`mla.absorbed_attention` with `visible`).
+          (`mla.absorbed_attention` with `visible`). For grouped-query
+          heads the same two: `gqa_selected_prompt_attention` (the
+          `group` query heads of a key head stacked on ONE block of its
+          keys, so that a block of keys and a tile of the mask are read
+          and decoded once for all of them) and `gqa_selected_tick` (a
+          gather of the `topk` rows of keys and of values a slot, and
+          attention over them).
 
-On a TPU the three prompt steps are Pallas kernels, named for a trace:
-`dsa_index_t<T>`, `dsa_select_t<T>`, `mla_selected_t<T>` (T the prompt's
-length); elsewhere the same numbers in `jax.numpy`, which is also the
+On a TPU the prompt steps are Pallas kernels, named for a trace:
+`dsa_index_t<T>`, `dsa_select_t<T>`, `mla_selected_t<T>`,
+`gqa_selected_t<T>` (T the prompt's length); elsewhere the same numbers in `jax.numpy`, which is also the
 kernels' reference. Everything here is ONE sequence (no batch axis): the
 caller maps over a batch.
 """
@@ -251,24 +262,95 @@ def block_selection(q_i: jax.Array, k_i: jax.Array, w: jax.Array, q0,
     return _select_pallas(scores, topk, tokens, ts, interpret)
 
 
+def tick_rows(slab_rows: int, topk: int) -> Tuple[int, ...]:
+    """The row counts, ascending, at which a tick's selection may stop
+    (`tick_selection`'s `upto`), the slab's own last. A slab is a power
+    of two and a tail (the longest prompt and the longest answer: 32,768
+    + 1,024), and `lax.top_k` on a TPU is a sort of the next power of two
+    (33,792 scores cost what 65,536 do, 0.314 ms for four slots; 32,768
+    cost 0.181, 16,384 0.080, 8,192 0.035: the chip, PERF.md PR 49): so
+    each smaller power of two with that tail, down to twice `topk`."""
+    head = 1 << (slab_rows.bit_length() - 1)
+    tail = slab_rows - head
+    out, n = [slab_rows], head // 2
+    while n >= 2 * topk:
+        out.append(n + tail)
+        n //= 2
+    return tuple(sorted(out))
+
+
+def top_rows(scores: jax.Array, k: int) -> Tuple[jax.Array, jax.Array]:
+    """`lax.top_k(scores, k)` over [B, S], the largest power of two of the
+    rows and the tail behind it sorted apart and their best merged by ONE
+    small sort that carries the rows along (a lookup of the merged rows
+    costs a gather 10 ns an entry, 0.087 ms a layer for four slots: the
+    chip, PERF.md PR 49): the same values and rows in the same order as
+    the one sort of twice the rows that a length just past a power of two
+    costs (a float32's bits order as `lax.top_k` orders the numbers, -0.0
+    under 0.0, once the negative half is flipped; a tie goes to the
+    earlier row in each sort, and the head's rows stand before the
+    tail's). A length that is a power of two, or whose power of two is
+    under 4 k rows (one sort is as cheap there), takes `lax.top_k`."""
+    rows = scores.shape[-1]
+    head = 1 << (rows.bit_length() - 1)
+    if head == rows or head < 4 * k:
+        return tuple(jax.lax.top_k(scores, min(k, rows)))
+    best_h, at_h = jax.lax.top_k(scores[:, :head], k)
+    best_t, at_t = jax.lax.top_k(scores[:, head:], min(k, rows - head))
+    best = jnp.concatenate([best_h, best_t], -1)
+    bits = jax.lax.bitcast_convert_type(best, jnp.int32)
+    # ~x = -x - 1: the largest number first, and no overflow
+    order = ~jnp.where(bits < 0, bits ^ 0x7FFFFFFF, bits)
+    _, best, at = jax.lax.sort(
+        (order, best, jnp.concatenate([at_h, at_t + head], -1)),
+        dimension=-1, num_keys=1, is_stable=True)
+    return best[:, :k], at[:, :k]
+
+
+def tick_upto(positions: jax.Array, upto: Tuple[int, ...]) -> jax.Array:
+    """Which of `upto` (ascending row counts, the slab's own last) a tick
+    stops at: the first that holds every slot's position."""
+    return (positions.max() >= jnp.asarray(upto[:-1], jnp.int32)).sum()
+
+
 def tick_selection(q_i: jax.Array, k_i: jax.Array, w: jax.Array,
-                   positions: jax.Array, topk: int
+                   positions: jax.Array, topk: int,
+                   upto: Tuple[int, ...] = ()
                    ) -> Tuple[jax.Array, jax.Array]:
     """A decode tick's set, for every slot: q_i [B, heads, dim], k_i [B,
     S, dim] (the slab's index keys as they lie), w [B, heads], positions
     [B] -> (rows [B, k] int32, seen [B, k] bool: False for the entries
-    that stand for nothing while `position + 1 < k`), k = min(topk, S)."""
-    with jax.named_scope("dsa_index_tick"):
-        # every head at once: [B, heads, S] float32 is small beside a
-        # tick, and the slab's keys are read ONCE (a head at a time, as
-        # `index_scores` takes a block of a prompt's queries, would read
-        # them `heads` times)
-        s = jnp.einsum("bhd,bsd->bhs", q_i, k_i, preferred_element_type=F32)
-        scores = (jax.nn.relu(s) * w[..., None].astype(F32)).sum(1)
-        seen = jnp.arange(k_i.shape[1])[None, :] <= positions[:, None]
-        scores = jnp.where(seen, scores, NEG)
-    with jax.named_scope("dsa_select_tick"):
-        best, rows = jax.lax.top_k(scores, min(topk, scores.shape[-1]))
+    that stand for nothing while `position + 1 < k`), k = min(topk, S).
+
+    With `upto` (`tick_rows`) only the first rows of the slab are scored
+    and sorted, as many as `tick_upto` says hold every slot's position:
+    the rows behind are unseen to every slot, so the set is the same, row
+    for row (a dead slot stands at position 0)."""
+    def select(q_i, k_i, w, positions, rows=None):
+        if rows is not None:
+            k_i = k_i[:, :rows]
+        with jax.named_scope("dsa_index_tick"):
+            # every head at once: [B, heads, S] float32 is small beside a
+            # tick, and the slab's keys are read ONCE (a head at a time,
+            # as `index_scores` takes a block of a prompt's queries, would
+            # read them `heads` times)
+            s = jnp.einsum("bhd,bsd->bhs", q_i, k_i,
+                           preferred_element_type=F32)
+            scores = (jax.nn.relu(s) * w[..., None].astype(F32)).sum(1)
+            seen = jnp.arange(k_i.shape[1])[None, :] <= positions[:, None]
+            scores = jnp.where(seen, scores, NEG)
+        with jax.named_scope("dsa_select_tick"):
+            if rows is None:
+                return jax.lax.top_k(scores, min(topk, scores.shape[-1]))
+            return top_rows(scores, min(topk, scores.shape[-1]))
+
+    if len(upto) > 1:
+        best, rows = jax.lax.switch(
+            tick_upto(positions, upto),
+            [functools.partial(select, rows=n) for n in upto],
+            q_i, k_i, w, positions)
+    else:
+        best, rows = select(q_i, k_i, w, positions)
     return rows, best > NEG / 2
 
 
@@ -423,3 +505,161 @@ def selected_prompt_attention(q_n: jax.Array, q_r: jax.Array,
     dispatch.record_choice("mla_selected", shape, "pallas")
     return _selected_pallas(q_n, q_r, k_n, k_r, v, tiles, scale, block,
                             tokens, dispatch.interpret_forced())
+
+
+# ------------------------- grouped-query heads under the mask, and a tick
+
+def _gqa_masked_blocked(q, k, v, tiles, scale: float, block: int
+                        ) -> jax.Array:
+    """The grouped-query prompt form under a mask in `jax.numpy`: q [H,
+    Tp, d], k and v [G, Tp, d] (query head h reads head `h // (H / G)`),
+    tiles [nb, nb or nb / 8, block, block] int8 -> [H, Tp, d]; a block of
+    queries at a time over every key."""
+    h, tp, d = q.shape
+    g = k.shape[0]
+    nb = tp // block
+    per = tiles.shape[1]
+
+    def q_block(args):
+        q_i, m_i = args                   # [H, blk, d], [per, blk, blk]
+        m_i = jnp.concatenate([_kept(m_i, b) for b in range(nb // per)])
+        seen = jnp.moveaxis(m_i, 0, 1).reshape(block, tp)[None, None]
+        s = jnp.einsum("gjtd,gsd->gjts", q_i.reshape(g, h // g, block, d),
+                       k, preferred_element_type=F32) * scale
+        s = jnp.where(seen, s, NEG)
+        p = jnp.where(seen, jnp.exp(s - s.max(-1, keepdims=True)), 0.0)
+        out = jnp.einsum("gjts,gsd->gjtd", p.astype(v.dtype), v,
+                         preferred_element_type=F32)
+        return (out / p.sum(-1, keepdims=True)).astype(v.dtype
+                                                       ).reshape(h, block, d)
+
+    cut = jnp.moveaxis(q.reshape(h, nb, block, d), 1, 0)
+    out = jax.lax.map(q_block, (cut, tiles))
+    return jnp.moveaxis(out, 0, 1).reshape(h, tp, d)
+
+
+def _gqa_selected_kernel(q_ref, k_ref, v_ref, m_ref, o_ref, *, scale: float,
+                         block: int, per: int):
+    """One (head of keys, block of queries) program. Refs: q [group,
+    block, d], the query heads that read this head of keys; k and v [Tp,
+    d] of this head; m [per, block, block] int8, the mask of this block of
+    queries as `mask_tiles` lays it out; o [group, block, d]. The `group`
+    heads' queries stand as ONE [group * block, d] operand, so a block of
+    keys is read, and its tile of the mask decoded, once for all of them.
+    The blocks of keys up to the diagonal are walked under a running
+    softmax, which starts ABOVE `NEG`: a pair the mask leaves out then
+    weighs exp2(NEG - m) = 0 even before the row's first kept key. The
+    mask holds the causal rule already."""
+    qi = pl.program_id(1)
+    group, _, d = q_ref.shape
+    cd = q_ref.dtype
+    rows = group * block
+    q = (q_ref[...].astype(F32) * (scale * _LOG2E)).astype(cd
+                                                           ).reshape(rows, d)
+
+    def step(ki, carry):
+        m_prev, l_prev, acc = carry
+        at = pl.ds(pl.multiple_of(ki * block, block), block)
+        s = jax.lax.dot_general(q, k_ref[at, :], (((1,), (1,)), ((), ())),
+                                preferred_element_type=F32)
+        kept = _kept(m_ref[ki % per], ki // per)
+        s = jnp.where(kept[None], s.reshape(group, block, block), NEG
+                      ).reshape(rows, block)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp2(s - m_new)
+        alpha = jnp.exp2(m_prev - m_new)
+        acc = acc * alpha + jax.lax.dot_general(
+            p.astype(cd), v_ref[at, :], (((1,), (0,)), ((), ())),
+            preferred_element_type=F32)
+        return m_new, alpha * l_prev + jnp.sum(p, -1, keepdims=True), acc
+
+    carry = (jnp.full((rows, 1), NEG / 2, F32), jnp.zeros((rows, 1), F32),
+             jnp.zeros((rows, d), F32))
+    _, l, acc = jax.lax.fori_loop(0, qi + 1, step, carry)
+    o_ref[...] = (acc / l).reshape(group, block, d).astype(o_ref.dtype)
+
+
+def _gqa_selected_pallas(q, k, v, tiles, scale: float, block: int,
+                         tokens: int, interpret: bool) -> jax.Array:
+    h, tp, d = q.shape
+    g = k.shape[0]
+    group, nb, per = h // g, tp // block, tiles.shape[1]
+    per_head = pl.BlockSpec((None, tp, d), lambda j, i: (j, 0, 0))
+    per_block = pl.BlockSpec((None, group, block, d),
+                             lambda j, i: (j, 0, i, 0))
+    out = pl.pallas_call(
+        functools.partial(_gqa_selected_kernel, scale=scale, block=block,
+                          per=per),
+        grid=(g, nb),
+        in_specs=[per_block, per_head, per_head,
+                  pl.BlockSpec((None, per, block, block),
+                               lambda j, i: (i, 0, 0, 0))],
+        out_specs=per_block,
+        out_shape=jax.ShapeDtypeStruct((g, group, tp, d), q.dtype),
+        interpret=interpret,
+        name=f"gqa_selected_t{tokens}",
+        compiler_params=None if interpret else pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=_VMEM_LIMIT),
+    )(q.reshape(g, group, tp, d), k, v, tiles)
+    return out.reshape(h, tp, d)
+
+
+def gqa_selected_prompt_attention(q: jax.Array, k: jax.Array, v: jax.Array,
+                                  tiles: jax.Array, scale: float,
+                                  block: int, tokens: int) -> jax.Array:
+    """Attention of grouped-query heads over a prompt from position 0
+    under the selection: q [H, Tp, d] (rotated), k (rotated) and v [G,
+    Tp, d], query head h reading head `h // (H / G)` of them; tiles
+    `mask_tiles` of every block's `block_selection`. Returns [H, Tp, d]
+    in q's dtype. Every visible block of scores is computed and the
+    unselected pairs weigh nothing, as `selected_prompt_attention`."""
+    h, tp, d = q.shape
+    g = k.shape[0]
+    if h % g:
+        raise ValueError(f"{h} query heads over {g} heads of keys")
+    group = h // g
+    shape = (tokens, h, g, d, block)
+    # what a program holds: a head's keys and values twice (the next
+    # head's on their way), the block's mask twice, the stacked queries'
+    # scores in float32 about three times over
+    resident = (4 * tp * max(d, 128) * q.dtype.itemsize
+                + 2 * tiles.shape[1] * block * block
+                + 3 * group * block * block * 4)
+    reason = dispatch.backend_reason() or (
+        "" if resident < _VMEM_LIMIT * 7 // 8 else
+        f"{resident} bytes of a head's keys and values, a block's mask and "
+        f"{group} heads' scores exceed the kernel's VMEM")
+    if reason:
+        dispatch.record_choice("gqa_selected", shape, "reference", reason)
+        return _gqa_masked_blocked(q, k, v, tiles, scale, block)
+    dispatch.record_choice("gqa_selected", shape, "pallas")
+    return _gqa_selected_pallas(q, k, v, tiles, scale, block, tokens,
+                                dispatch.interpret_forced())
+
+
+def gqa_selected_tick(q: jax.Array, keys: jax.Array, values: jax.Array,
+                      picked: jax.Array, seen: jax.Array, scale: float
+                      ) -> jax.Array:
+    """A decode tick's attention over the rows `tick_selection` picked:
+    q [B, H, d] (rotated), keys and values [B, S, G, d] as the slab holds
+    them, picked [B, k] int32, seen [B, k] bool -> [B, H, d] in q's
+    dtype. The `k` rows of keys and of values a slot are gathered (never
+    a row the indexer left out) and every query head attends its head of
+    them; an entry of `picked` that stands for nothing weighs 0."""
+    b, h, d = q.shape
+    g = keys.shape[2]
+    with jax.named_scope("gqa_gather_tick"):
+        at = picked[:, :, None, None]
+        k = jnp.take_along_axis(keys, at, axis=1)           # [B, k, G, d]
+        v = jnp.take_along_axis(values, at, axis=1)
+    with jax.named_scope("gqa_selected_tick"):
+        s = jnp.einsum("bgjd,bkgd->bgjk", q.reshape(b, g, h // g, d), k,
+                       preferred_element_type=F32) * scale
+        ok = seen[:, None, None, :]
+        s = jnp.where(ok, s, NEG)
+        p = jnp.where(ok, jnp.exp(s - s.max(-1, keepdims=True)), 0.0)
+        out = jnp.einsum("bgjk,bkgd->bgjd", p.astype(v.dtype), v,
+                         preferred_element_type=F32)
+        return (out / p.sum(-1, keepdims=True)).astype(q.dtype
+                                                       ).reshape(b, h, d)

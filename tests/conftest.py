@@ -131,6 +131,28 @@ def pytest_configure(config):
         "log_to_driver=0 — select with `-m oracle`")
 
 
+@pytest.fixture(scope="module", autouse=True)
+def _release_compiled_programs():
+    """Let go of what a test file compiled once the file is done. A
+    worker of the tier-1 run goes through dozens of files, every program
+    it compiles for the CPU keeps three mappings of machine code and data
+    a kernel until JAX's caches drop it, and Linux gives a process 65,530
+    mappings (`vm.max_map_count`): the worker that passes them dies
+    inside its next compile (PR 49: twice in
+    `test_yardstick_smallthinker.py`, whose eager decode compiles a
+    program a position, at 62,000 mappings of which 61,000 were such
+    triplets; 200 small programs are 3,700 mappings, and
+    `jax.clear_caches()` gives all of them back)."""
+    yield
+    import gc
+    import sys
+
+    jax = sys.modules.get("jax")
+    if jax is not None:
+        jax.clear_caches()
+        gc.collect()
+
+
 def _sweep_leaked_shm():
     """Chaos/kill tests SIGKILL workers, which cannot unlink their shm
     arena segments; sweep after every cluster so a leak in one test
