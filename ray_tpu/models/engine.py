@@ -128,14 +128,14 @@ where its last request ended would have that request's rows read tick
 after tick for nobody; parked, it costs one block, and its scatter lands
 in row 0, which the next `_splice_slot` overwrites (a ring's row 0
 likewise). And it costs NO state where the family's state step walks
-(`Family.state_walks`: `ops/mamba2.ssd_step`): the step is handed the
-device's liveness vector, the one `_set_rows` writes and `_chosen`
-reads, visits the slots it holds live and neither reads nor writes a
-dead slot's state, which the next `_splice_slot` writes whole (a slot
-whose budget ends with the tick ahead is still live on the chip for one
-step more, as its row is still decoded). A family whose step does not
-walk steps every slot's state, tick after tick, for nobody. A weight
-swap holds from the next LAUNCH: the tick in
+(`Family.state_walks`: `ops/mamba2.ssd_step`, `ops/kda.kda_step`): the
+step is handed the device's liveness vector, the one `_set_rows` writes
+and `_chosen` reads, visits the slots it holds live and neither reads
+nor writes a dead slot's state, which the next `_splice_slot` writes
+whole (a slot whose budget ends with the tick ahead is still live on the
+chip for one step more, as its row is still decoded). A family whose
+step does not walk steps every slot's state, tick after tick, for
+nobody. A weight swap holds from the next LAUNCH: the tick in
 flight finishes on the weights it was launched with. The depth is what
 the engine can see, not a knob: a pass that carries drafts needs the
 host's tokens to draft from, so a speculating engine reads the tick in

@@ -56,7 +56,7 @@ class Family:
     tick that reads every row, or rows the caller marks, does not.
     `state_walks`: the tick's state step visits the live slots alone:
     `decode` takes `live` [B], the tick's own liveness vector, beside its
-    other arguments (`ops/mamba2.ssd_step`)."""
+    other arguments (`ops/mamba2.ssd_step`, `ops/kda.kda_step`)."""
 
     config_type: type
     init: Callable

@@ -32,14 +32,16 @@ prefills a run of tokens FROM POSITION 0 (the chunked scan from the
 carried state, the expanded attention over the run alone) or appends one
 token at any position (the recurrence, the absorbed attention over the
 cache); it hands back the logits of the last position only. `decode` runs
-one step for every slot at its own position and reports what the expert
-layers' grouped products saw.
+one step for every slot at its own position, the KDA layers' state step
+for the slots the caller's `live` vector holds live alone
+(`Family.state_walks`), and reports what the expert layers' grouped
+products saw.
 """
 from __future__ import annotations
 
 import math
 from dataclasses import dataclass
-from typing import Any, Dict, List, Tuple
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -214,10 +216,11 @@ def kimi_linear_init(config: KimiLinearConfig, key: jax.Array) -> Params:
 
 # ------------------------------------------------------------ the mixers
 
-def _kda(h: jax.Array, p: Params, c: KimiLinearConfig, cache: Params
-         ) -> Tuple[jax.Array, Params]:
+def _kda(h: jax.Array, p: Params, c: KimiLinearConfig, cache: Params,
+         live: Optional[jax.Array] = None) -> Tuple[jax.Array, Params]:
     """h [B, T, D] on top of the state in `cache`: the chunked form for a
-    run of tokens, the recurrence itself for one token a row."""
+    run of tokens, the recurrence itself for one token a row, which with
+    `live` [B] visits the live rows' state alone (`ops/kda.kda_step`)."""
     b, t, _ = h.shape
     nh, dk, lo = c.kda_num_heads, c.kda_head_dim, c.kda_low_rank
     qkv, d_lo, g_lo, beta = jnp.split(
@@ -233,7 +236,7 @@ def _kda(h: jax.Array, p: Params, c: KimiLinearConfig, cache: Params
     beta = jax.nn.sigmoid(beta.astype(F32))
     if t == 1:
         o, state = kda_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0],
-                            cache["state"])
+                            cache["state"], live)
         o = o[:, None]
     else:
         o, state = kda_scan(q, k, v, g, beta, cache["state"], c.chunk_size)
@@ -437,9 +440,13 @@ def kimi_linear_forward_cached(params: Params, tokens: jax.Array,
 
 def kimi_linear_decode(params: Params, tokens: jax.Array,
                        config: KimiLinearConfig, cache: list,
-                       pos_vec: jax.Array):
+                       pos_vec: jax.Array,
+                       live: Optional[jax.Array] = None):
     """One step for a ragged batch: tokens [B], slot b at position
-    pos_vec[b]. Returns (logits [B, vocab] float32, the new cache, the
+    pos_vec[b]; `live` [B] (the tick's own liveness vector, 0 for a dead
+    slot) lets the KDA layers' state step visit the live slots alone: a
+    dead slot's state is then neither read nor written, and its logits
+    are nobody's. Returns (logits [B, vocab] float32, the new cache, the
     expert layers' counts for the engine's loop record, summed over the
     layers: `moe_pairs_held`, token-expert pairs that fell on held
     experts; `moe_experts_hit`, held experts that got a row; and
@@ -457,7 +464,7 @@ def kimi_linear_decode(params: Params, tokens: jax.Array,
         with jax.named_scope(_SCOPE[kind]):
             h = rms_norm(x, p["norm1"]["scale"], c.norm_eps)
             if kind == "K":
-                y, new_cache[at] = _kda(h, p["kda"], c, cache[at])
+                y, new_cache[at] = _kda(h, p["kda"], c, cache[at], live)
             else:
                 y, new_cache[at] = _mla_decode(h, p["mla"], c, cache[at],
                                                positions)
@@ -503,4 +510,4 @@ FAMILY = Family(
     partition_specs=kimi_linear_partition_specs,
     init_cache=kimi_linear_init_cache,
     forward_cached=kimi_linear_forward_cached, decode=kimi_linear_decode,
-    decode_walks=True)
+    decode_walks=True, state_walks=True)

@@ -210,6 +210,114 @@ def test_decode_counts_what_the_expert_layers_saw(params):
                               cache, jnp.zeros(4, jnp.int32))
 
 
+def test_ticks_by_liveness_are_the_ticks_without_on_the_live_rows(params):
+    """Four slots prefilled, then eight ticks: with `live` given (under
+    interpret mode the walk `kda_step_live`) the live slots' logits, state
+    and tails are those of the pass that steps every slot, and a dead
+    slot's state lies where the prefill left it, to the bit, float32."""
+    from ray_tpu.ops import dispatch
+
+    live = jnp.asarray([1, 0, 1, 1], jnp.int32)
+    lv = np.asarray(live) != 0
+    _, cache0 = kl.kimi_linear_forward_cached(
+        params, jnp.asarray(TOKENS[:64].reshape(4, 16)), CFG,
+        kl.kimi_linear_init_cache(CFG, 4), 0)
+    shape = (4, CFG.kda_num_heads, CFG.kda_head_dim, CFG.kda_head_dim)
+    with dispatch.pallas_interpret():
+        walk = jax.jit(lambda cache, tok, pos: kl.kimi_linear_decode(
+            params, tok, CFG, cache, pos, live)[:2])
+        plain = jax.jit(lambda cache, tok, pos: kl.kimi_linear_decode(
+            params, tok, CFG, cache, pos)[:2])
+        walked, every = cache0, cache0
+        for i in range(8):
+            tok = jnp.asarray(TOKENS[4 * i:4 * i + 4])
+            pos = jnp.full(4, 16 + i, jnp.int32)
+            want, every = plain(every, tok, pos)
+            if i == 0:
+                (took,) = [c for c in dispatch.kernel_choices("state_step")
+                           if c["shape"] == shape]
+                assert took["choice"] == "reference" and "liveness" in \
+                    took["reason"]
+            got, walked = walk(walked, tok, pos)
+            np.testing.assert_allclose(np.asarray(got)[lv],
+                                       np.asarray(want)[lv], atol=TOL,
+                                       rtol=0)
+            assert np.isfinite(np.asarray(got)).all()
+        (took,) = [c for c in dispatch.kernel_choices("state_step")
+                   if c["shape"] == shape]
+    assert took["choice"] == "pallas" and took["heads_block"] == 4
+    for at in range(1, 4):
+        assert walked[at]["state"].dtype == jnp.float32
+        for leaf in ("state", "conv"):
+            np.testing.assert_allclose(
+                np.asarray(walked[at][leaf])[lv],
+                np.asarray(every[at][leaf])[lv], atol=TOL, rtol=0)
+        np.testing.assert_array_equal(np.asarray(walked[at]["state"])[~lv],
+                                      np.asarray(cache0[at]["state"])[~lv])
+        assert np.abs(np.asarray(every[at]["state"])[~lv]
+                      - np.asarray(cache0[at]["state"])[~lv]).max() > 0
+
+
+@pytest.mark.parametrize("form", ["reference", "pallas", "cannot-walk"])
+def test_the_ring_says_how_many_slots_states_a_tick_stepped(
+        params, form, monkeypatch):
+    """The family's state step walks (`Family.state_walks`): a tick's
+    record carries the slots the chip held live, between `live` and
+    `max_batch`, with two streams of different budgets in four slots; on
+    the CPU the step is the plain one over every row, under interpret mode
+    the kernel. The same program without the flag steps every slot's
+    state and says so; the streams are the same in all three, and the
+    slab's state is float32."""
+    from contextlib import nullcontext
+
+    from ray_tpu.observability import requests as reqtrace
+    from ray_tpu.ops import dispatch
+
+    assert kl.FAMILY.state_walks
+    if form == "cannot-walk":
+        monkeypatch.setattr(kl, "FAMILY", dataclasses.replace(
+            kl.FAMILY, state_walks=False))
+    # a window of its own for each form: the tick's program is traced
+    # once a config, under whichever form the process then had
+    cfg = dataclasses.replace(CFG, max_seq_len=CFG.max_seq_len - 8 * (
+        1 + ["reference", "pallas", "cannot-walk"].index(form)))
+    reqtrace._reset_store_for_tests()
+    with dispatch.pallas_interpret() if form == "pallas" else nullcontext():
+        eng = ContinuousBatchingEngine(params, cfg, max_batch=4)
+        try:
+            long, short = eng.stream(TOKENS[:16], 24), \
+                eng.stream(TOKENS[20:39], 6)
+            out = [int(t) for t in short], [int(t) for t in long]
+            stats = eng.kv_stats()
+            dtypes = {e["state"].dtype for e in eng._cache if "state" in e}
+        finally:
+            eng.stop()
+    ticks = [r for r in reqtrace.store().loop_records()
+             if r["engine_id"] == eng.engine_id
+             and "state_slots_stepped" in r]
+    reqtrace._reset_store_for_tests()
+    assert dtypes == {jnp.dtype(jnp.float32)}
+    assert (len(out[0]), len(out[1])) == (6, 24)
+    seq = np.concatenate([TOKENS[:16], out[1][:-1]]).astype(np.int32)
+    lg = kl.kimi_linear_forward(params, seq[None], cfg)[0][15:]
+    assert out[1] == [int(t) for t in jnp.argmax(lg, -1)]
+    stepped = [r["state_slots_stepped"] for r in ticks]
+    assert ticks and stats["state_slots_stepped"] >= sum(stepped)
+    (choice,) = [c for c in stats["state_step"] if c["shape"] == (
+        4, cfg.kda_num_heads, cfg.kda_head_dim, cfg.kda_head_dim)]
+    if form == "cannot-walk":
+        assert set(stepped) == {4}
+        assert stats["state_slots_stepped"] == 4 * stats["ticks_launched"]
+        assert choice["choice"] == "reference" and "liveness" in \
+            choice["reason"]
+        return
+    assert all(r["live"] <= r["state_slots_stepped"] <= 2 for r in ticks)
+    # both streams live, then the long one alone: never all four slots
+    assert {1, 2} <= set(stepped)
+    assert stats["state_slots_stepped"] < 4 * stats["ticks_launched"]
+    assert choice["choice"] == form
+
+
 def test_a_run_of_tokens_must_start_at_position_zero(params):
     step, init_cache, _ = _model_fns(CFG)
     with pytest.raises(ValueError, match="prefill from position 0"):
