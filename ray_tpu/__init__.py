@@ -12,10 +12,13 @@ init :1214, get :2523, put :2655, wait :2720, kill :2901.
 """
 from __future__ import annotations
 
-import atexit
-import os
-import tempfile
 import time
+
+_IMPORT_T0 = time.time()  # the package's import, first line to last
+
+import atexit  # noqa: E402
+import os  # noqa: E402
+import tempfile  # noqa: E402
 from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
 
 from . import exceptions  # noqa: F401
@@ -317,3 +320,7 @@ __all__ = [
     "available_resources", "nodes", "get_runtime_context", "ObjectRef",
     "ActorClass", "ActorHandle", "exceptions", "__version__",
 ]
+
+from .util.compile_cache import _stamp_import  # noqa: E402
+
+_stamp_import(__name__, _IMPORT_T0, time.time())

@@ -15,6 +15,10 @@ and `keye_vl2.py` (grouped-query attention under one, keys and values in
 pairs beside an index key in the slab) are imported where they are used.
 `moe_transformer.py` trains and is not served.
 """
+import time
+
+_IMPORT_T0 = time.time()  # the package's import, first line to last
+
 from .gpt2 import (  # noqa: F401
     GPT2Config,
     gpt2_forward,
@@ -76,3 +80,6 @@ from .moe_transformer import (  # noqa: F401
     moe_loss,
     moe_partition_specs,
 )
+from ..util.compile_cache import _stamp_import  # noqa: E402
+
+_stamp_import(__name__, _IMPORT_T0, time.time())

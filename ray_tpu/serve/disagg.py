@@ -358,13 +358,30 @@ def device_record() -> Dict[str, Any]:
 def runtime_record() -> Dict[str, Any]:
     """What this replica's process has compiled and how much device
     memory it holds (peak where the backend reports it): set-up cost and
-    head-room a caller budgets with, read through stats()."""
+    head-room a caller budgets with, read through stats().
+
+    `compile_cache` is `compile_cache_counts()`, all numbers: `hits`,
+    `misses`, `compiles` and `compile_s` (the hand-overs to the backend,
+    fetches and compiles together), and of set-up's seconds `trace_s`
+    and `lower_s` (Python's tracing and lowering, which a warm cache
+    does not save), `fetch_s` (the hits' retrievals) and
+    `miss_compile_s` (the hand-overs that compiled). `slowest_program`
+    is the `name` and `seconds` (trace, lowering and hand-over) of the
+    costliest program of the newest `compile_cache.RING`, None before
+    the first."""
     import jax
 
-    from ray_tpu.util.compile_cache import compile_cache_counts
+    from ray_tpu.util.compile_cache import (compile_cache_counts,
+                                            compile_cache_programs)
 
     mem = jax.devices()[0].memory_stats() or {}
+    slowest = max(
+        ((r["trace_s"] + r["lower_s"] + r["backend_s"], r["name"])
+         for r in compile_cache_programs() if "backend_s" in r),
+        default=None)
     return {"compile_cache": compile_cache_counts(),
+            "slowest_program": slowest and {
+                "name": slowest[1], "seconds": round(slowest[0], 3)},
             "peak_bytes_in_use": mem.get("peak_bytes_in_use")}
 
 
