@@ -10,9 +10,12 @@ A family is ONE file that ends in `FAMILY = Family(...)`
 slab's layout and what each kind is refused). `generate` and
 `ContinuousBatchingEngine` find it from the config's class; no list
 here or anywhere names a family for them. The names below are
-re-exports; `dots3_note.py` (latent attention under a learned selection)
-and `keye_vl2.py` (grouped-query attention under one, keys and values in
-pairs beside an index key in the slab) are imported where they are used.
+re-exports; `dots3_note.py` (latent attention under a learned selection),
+`keye_vl2.py` (grouped-query attention under one, keys and values in
+pairs beside an index key in the slab) and `zaya.py` (attention in a
+compressed latent behind two carried convolutions, one expert a token
+under an MLP router that may choose none) are imported where they are
+used.
 `moe_transformer.py` trains and is not served.
 """
 import time

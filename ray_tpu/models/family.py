@@ -16,6 +16,16 @@ takes for granted of full-length keys and values in pairs does not hold
 for the other three, and is refused in words (`refuse`) rather than
 served silently wrong.
 
+A state need not be large, nor the cache's main part: a cache of
+full-length keys and values IN PAIRS, one stack of one width, with a
+state of KILOBYTES after them (`models/zaya.py`: the tails of two
+convolutions that make a token's query from the token before it, 5.4 KB
+a slot and layer beside 1 KB of keys and values a token) is of kind
+"state" all the same. Its pairs would fit the paged pool's block, and a
+prefix of them still cannot be resumed without the tails at that block,
+which the pool does not keep: what a recurrence of megabytes is refused,
+it is refused, in the same words.
+
 The kinds combine. A cache that is latent AND ring holds latent rows
 alone, some entries of the full length and some in rings: what either
 kind is refused, it is refused, in words of its own. And the entries

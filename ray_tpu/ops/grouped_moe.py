@@ -37,24 +37,35 @@ records the choice and the kernel's row tile in `ops/dispatch` under
 `grouped_product` (`STREAM_ROWS_AN_EXPERT` says what the number is held
 by, `ROW_TILE` why one tile serves every call).
 
-Four callers, four expert shapes: `models/nemotron_h.py` (experts in
+Five callers, five expert shapes: `models/nemotron_h.py` (experts in
 a latent space, `w1` [held, L, I] under relu squared),
 `models/kimi_linear.py` (SwiGLU experts at full hidden width: gate and up
 packed in ONE `w1` [held, D, 2 I], and an `activation` that maps the
 [rows, 2 I] product to `silu(gate) * up` [rows, I], which `w2` [held, I, D]
-takes) and `models/deepseek_v2.py` (the same packing at `w1` [40, 5120,
+takes), `models/deepseek_v2.py` (the same packing at `w1` [40, 5120,
 3072], `w2` [40, 1536, 5120], and over a prompt hundreds of rows an
 expert, in token blocks: the buffers here are sized for ALL T x k pairs,
-held or not). `held_experts` asks nothing of the activation but that it
-keeps the rows. Two routers over sigmoid scores share
+held or not), `models/smallthinker.py` and `models/keye_vl2.py` (every
+expert held, `w1` [64, 2560, 1536] under `relu(gate) * up` and [128, 2048,
+1536] under SwiGLU) and `models/zaya.py`: ONE expert a token of sixteen
+WIDE ones (`w1` [16, 2048, 4096], 16.8 MB each, in four tiles of
+columns; `w2` [16, 2048, 2048] in two): a buffer of T rows, 4 rows an
+expert a tick of 64 slots, 66 a prompt of 1,056 tokens, so every call is
+the streamed kernel's. `held_experts` asks nothing of the activation but
+that it keeps the rows. Five routers. Two over sigmoid scores share
 `sigmoid_topk_route` (the bias chooses); the third,
 `softmax_group_limited_route`, scores by softmax and lets only the best
 GROUPS of experts compete, as a deployment that keeps a group on a chip
 does; the fourth, `softmax_topk_route`, is the plain one: the k largest
 logits, weighed by their softmax over the chosen alone
-(`models/smallthinker.py`: every expert held, `w1` [64, 2560, 1536] under
-`relu(gate) * up`, and a router that reads the layer's input, so its
-choice and the sort below do not wait for the layer's attention).
+(`models/smallthinker.py`, whose router reads the layer's input, so its
+choice and the sort below do not wait for the layer's attention); the
+fifth, `mlp_top1_route`, is no single matrix: a down-projection, the LAST
+layer's router state added in, a norm and a three-matrix MLP, ONE choice
+a token among the experts and one choice more, which is NO expert. That
+choice needs nothing of `held_experts` that it did not have: an index no
+share holds (`first + held` or above) is a pair held elsewhere, sorted
+last and dropped, and the token's output is exactly 0.
 """
 from __future__ import annotations
 
@@ -164,6 +175,35 @@ def softmax_topk_route(h: jax.Array, w_router: jax.Array, k: int
                      precision=jax.lax.Precision.HIGHEST)
     top, chosen = jax.lax.top_k(logits, k)
     return chosen.astype(jnp.int32), jax.nn.softmax(top, axis=-1)
+
+
+def mlp_top1_route(h: jax.Array, carried: jax.Array, w_down: jax.Array,
+                   gamma: jax.Array, norm: jax.Array,
+                   mlp: Tuple[jax.Array, ...], bias: jax.Array, eps: float
+                   ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """The router that is an MLP with a state of its own (ZAYA1,
+    arXiv:2511.17127), all of it in float32: `r = h W_down + gamma *
+    carried` (`carried` the LAST layer's `r`: the layer loop hands it on
+    beside the residual stream), `p = softmax(MLP(RMSNorm(r)))` over the
+    experts AND one choice more, the MLP three matrices under GELU; ONE
+    choice, `argmax(p + bias)` (the bias chooses, it does not weigh), its
+    weight `p` of it. The last choice is NO expert: a caller hands
+    `held_experts` the index as it is, which no share holds. h [T, D],
+    carried [T, R], w_down [D, R], gamma [], norm [R], mlp ([R, R], [R,
+    R], [R, E + 1]), bias [E + 1] -> (choice [T, 1] int32 in 0 .. E,
+    weights [T, 1] float32, r [T, R] float32)."""
+    hi = jax.lax.Precision.HIGHEST
+    r = jnp.dot(h.astype(F32), w_down.astype(F32), precision=hi) \
+        + gamma.astype(F32) * carried.astype(F32)
+    z = r * jax.lax.rsqrt(jnp.mean(r * r, -1, keepdims=True) + eps) \
+        * norm.astype(F32)
+    for w in mlp[:-1]:
+        z = jax.nn.gelu(jnp.dot(z, w.astype(F32), precision=hi),
+                        approximate=False)
+    p = jax.nn.softmax(jnp.dot(z, mlp[-1].astype(F32), precision=hi), -1)
+    chosen = jnp.argmax(p + bias.astype(F32), axis=-1)[:, None]
+    return (chosen.astype(jnp.int32),
+            jnp.take_along_axis(p, chosen, axis=-1), r)
 
 
 # ------------------------------------------- the streamed grouped product

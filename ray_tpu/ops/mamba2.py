@@ -59,8 +59,10 @@ _STEP_VMEM_LIMIT = 64 << 20
 
 
 def causal_conv(xbc: jax.Array, tail: jax.Array, w: jax.Array,
-                b: jax.Array) -> Tuple[jax.Array, jax.Array]:
-    """Depthwise causal convolution, then SiLU. xbc [B, T, C] are the new
+                b: jax.Array, activation=jax.nn.silu
+                ) -> Tuple[jax.Array, jax.Array]:
+    """Depthwise causal convolution, then SiLU (or `activation`; None:
+    the taps' sum as it is, `ops/cca.py`). xbc [B, T, C] are the new
     inputs, tail [B, K-1, C] the K-1 inputs before them (zeros at the
     start of a sequence), w [K, C], b [C]. Returns (out [B, T, C] in
     xbc's dtype, the new tail [B, K-1, C] in the tail's dtype)."""
@@ -70,8 +72,9 @@ def causal_conv(xbc: jax.Array, tail: jax.Array, w: jax.Array,
     acc = b.astype(F32)
     for i in range(k):
         acc = acc + window[:, i:i + t].astype(F32) * w32[i]
-    return (jax.nn.silu(acc).astype(xbc.dtype),
-            window[:, t:].astype(tail.dtype))
+    if activation is not None:
+        acc = activation(acc)
+    return acc.astype(xbc.dtype), window[:, t:].astype(tail.dtype)
 
 
 def live_first(live: jax.Array) -> Tuple[jax.Array, jax.Array]:

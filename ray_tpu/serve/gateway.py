@@ -688,6 +688,7 @@ class GatewayServer:
 
         self._pool.submit(work)
         got: List[int] = []
+        held: Optional[tuple] = None    # an item taken behind a frame
         sent_text = ""
         first = True
         disconnected = False
@@ -701,13 +702,27 @@ class GatewayServer:
                 if self._client_gone(request):
                     disconnected = True
                     break
-                try:
-                    kind, payload = await asyncio.wait_for(
-                        q.get(), timeout=0.5)
-                except asyncio.TimeoutError:
-                    continue
+                if held is not None:
+                    (kind, payload), held = held, None
+                else:
+                    try:
+                        kind, payload = await asyncio.wait_for(
+                            q.get(), timeout=0.5)
+                    except asyncio.TimeoutError:
+                        continue
                 if kind == "tokens":
                     got.extend(payload)
+                    # a loop that lags its streams (64 of them at 80
+                    # tokens a second each outrun one frame a token)
+                    # sends what has queued meanwhile as ONE frame: the
+                    # cost of a pass is then a frame's, not a token's,
+                    # and the backlog cannot grow without bound
+                    while held is None and not q.empty():
+                        item = q.get_nowait()
+                        if item[0] == "tokens":
+                            got.extend(item[1])
+                        else:       # the stream's end: the next pass's
+                            held = item
                     text = self._codec.decode(got)
                     delta, sent_text = text[len(sent_text):], text
                     try:

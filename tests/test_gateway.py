@@ -545,3 +545,42 @@ def test_state_api_sees_gateway_telemetry(gateway_cluster, model):
     finally:
         gw.stop()
         engine.stop()
+
+
+class _BurstRouter:
+    """A router whose tokens arrive faster than one frame a token can be
+    written: every token a chunk of its own, handed over back to back."""
+
+    def generate(self, prompt, max_tokens, on_tokens=None, **_kw):
+        toks = [(7 * i) % 250 + 1 for i in range(max_tokens)]
+        for tok in toks:
+            on_tokens([tok])
+        return toks
+
+    def stats(self):
+        return {}
+
+
+def test_a_burst_goes_out_in_fewer_frames_and_the_same_text():
+    """What queues while the loop writes a frame is sent as ONE frame
+    (a loop that lags 64 long streams must not fall ever further behind
+    them): the deltas still concatenate to exactly the body."""
+    gw = GatewayServer(_BurstRouter(), model="tiny", vocab_size=256,
+                       max_tokens_cap=800)
+    host, port = gw.ready()
+    try:
+        n = 600
+        conn, resp = _post(host, port, "/v1/completions",
+                           body={"model": "tiny", "prompt": [1, 2, 3],
+                                 "max_tokens": n, "stream": True})
+        assert resp.status == 200
+        chunks, saw_done = _drain_sse(resp)
+        conn.close()
+    finally:
+        gw.stop()
+    assert saw_done
+    want = " ".join(str((7 * i) % 250 + 1) for i in range(n))
+    assert "".join(c["choices"][0]["text"] for c in chunks) == want
+    assert chunks[-1]["choices"][0]["finish_reason"] == "length"
+    # one frame a token would be n + 1
+    assert len(chunks) < n // 2

@@ -10,7 +10,8 @@ import numpy as np
 import pytest
 
 from ray_tpu.ops import dispatch, grouped_moe
-from ray_tpu.ops.grouped_moe import ROW_TILE, held_experts
+from ray_tpu.ops.grouped_moe import (ROW_TILE, held_experts,
+                                     mlp_top1_route)
 
 F32 = jnp.float32
 BF16 = jnp.bfloat16
@@ -181,6 +182,7 @@ _NEMOTRON = ((128, 1024, 2688), (128, 2688, 1024))
 _KIMI = ((64, 2304, 2048), (64, 1024, 2304))
 _DEEPSEEK = ((40, 5120, 3072), (40, 1536, 5120))
 _SMALLTHINKER = ((64, 2560, 1536), (64, 768, 2560))
+_ZAYA = ((16, 2048, 4096), (16, 2048, 2048))
 PROGRAMS = {
     "nemotron_h-tick": (96, 22, *_NEMOTRON, "pallas"),
     "kimi_linear-tick": (128, 8, *_KIMI, "pallas"),
@@ -195,6 +197,11 @@ PROGRAMS = {
     "deepseek_v2-prefill-block-2048": (2048, 6, *_DEEPSEEK, "reference"),
     "deepseek_v2-prefill-1024": (1024, 6, *_DEEPSEEK, "pallas"),
     "smallthinker-prefill-block-2048": (2048, 6, *_SMALLTHINKER, "pallas"),
+    # ONE expert a token: 4 rows an expert a tick of 64 slots, 66 a
+    # prompt of 1,056 tokens, 128 a block of 2,048
+    "zaya-tick": (64, 1, *_ZAYA, "pallas"),
+    "zaya-prefill-1056": (1056, 1, *_ZAYA, "pallas"),
+    "zaya-prefill-block-2048": (2048, 1, *_ZAYA, "pallas"),
 }
 
 
@@ -235,7 +242,7 @@ def test_shape_rule_names_no_model_and_reads_no_environment():
 
 
 @pytest.mark.parametrize("family", ["nemotron_h", "kimi_linear",
-                                    "deepseek_v2", "smallthinker"])
+                                    "deepseek_v2", "smallthinker", "zaya"])
 def test_a_family_forward_under_the_kernel(family):
     """Each caller's whole forward pass at its toy size with the streamed
     kernel (interpret mode) against the same pass on `ragged_dot`."""
@@ -255,3 +262,103 @@ def test_a_family_forward_under_the_kernel(family):
     assert choices and all(c["choice"] == "pallas" for c in choices)
     assert np.isfinite(got).all()
     np.testing.assert_allclose(got, want, atol=2e-2, rtol=0)
+
+
+# ------------------------------------------------- the fifth router
+
+def _router(seed=0, d=24, r=8, experts=4):
+    k = iter(jax.random.split(jax.random.PRNGKey(seed), 8))
+    n = lambda *s: jax.random.normal(next(k), s, F32)
+    return {"w_down": n(d, r) * d ** -0.5, "gamma": jnp.float32(0.5),
+            "norm": 1.0 + 0.1 * n(r),
+            "mlp": (n(r, r) * r ** -0.5, n(r, r) * r ** -0.5,
+                    2.0 * n(r, experts + 1) * r ** -0.5),
+            "bias": jnp.zeros((experts + 1,), F32)}
+
+
+def _route(p, h, carried, eps=1e-5):
+    return mlp_top1_route(h, carried, p["w_down"], p["gamma"], p["norm"],
+                          p["mlp"], p["bias"], eps)
+
+
+def test_mlp_top1_route_by_hand():
+    """`r = h W + gamma r'`, RMSNorm, two GELU layers (erf), softmax over
+    experts + 1, ONE choice, its probability the weight."""
+    from math import erf
+
+    p = _router()
+    h = jax.random.normal(jax.random.PRNGKey(1), (9, 24), F32)
+    carried = jax.random.normal(jax.random.PRNGKey(2), (9, 8), F32)
+    chosen, weights, state = _route(p, h, carried)
+    w = jax.tree.map(lambda x: np.asarray(x, np.float64), p)
+    r = np.asarray(h, np.float64) @ w["w_down"] \
+        + 0.5 * np.asarray(carried, np.float64)
+    z = r / np.sqrt((r * r).mean(-1, keepdims=True) + 1e-5) * w["norm"]
+    gelu = np.vectorize(lambda x: 0.5 * x * (1.0 + erf(x / 2 ** 0.5)))
+    z = gelu(gelu(z @ w["mlp"][0]) @ w["mlp"][1]) @ w["mlp"][2]
+    prob = np.exp(z - z.max(-1, keepdims=True))
+    prob /= prob.sum(-1, keepdims=True)
+    np.testing.assert_allclose(state, r, atol=1e-5)
+    assert state.dtype == F32 and weights.dtype == F32
+    assert chosen.shape == weights.shape == (9, 1)
+    assert chosen.dtype == jnp.int32
+    np.testing.assert_array_equal(chosen[:, 0], prob.argmax(-1))
+    np.testing.assert_allclose(weights[:, 0], prob.max(-1), atol=1e-6)
+    assert 0 <= int(chosen.min()) and int(chosen.max()) <= 4
+
+
+def test_mlp_top1_route_carries_its_state_and_its_bias_only_chooses():
+    p = _router(seed=3)
+    h = jax.random.normal(jax.random.PRNGKey(4), (64, 24), F32)
+    zero = jnp.zeros((64, 8), F32)
+    chosen, weights, state = _route(p, h, zero)
+    # the state handed on is the down-projection plus gamma times the last
+    _c, _w, again = _route(p, h, state)
+    np.testing.assert_allclose(again, 1.5 * state, atol=1e-5)
+    # the same direction at another length is the same choice (the norm)
+    np.testing.assert_array_equal(_c, chosen)
+    # another layer's state moves it
+    other = jax.random.normal(jax.random.PRNGKey(5), (64, 8), F32)
+    assert not np.array_equal(_route(p, h, 4.0 * other)[0], chosen)
+    # gamma 0: the last layer's state is not read
+    still = _route({**p, "gamma": jnp.float32(0.0)}, h, 7.0 + state)
+    np.testing.assert_array_equal(still[0], chosen)
+    # a bias that lifts ONE choice over every probability: it is chosen,
+    # and its weight is still its probability, without the bias
+    for lifted in (1, 4):       # an expert; the last choice, no expert
+        bias = jnp.zeros((5,), F32).at[lifted].set(2.0)
+        forced, weight, _s = _route({**p, "bias": bias}, h, zero)
+        assert (np.asarray(forced) == lifted).all()
+        assert float(weight.max()) < 1.0
+        kept = np.asarray(chosen[:, 0]) == lifted
+        np.testing.assert_allclose(weight[kept], weights[kept], atol=1e-7)
+
+
+@pytest.mark.parametrize("path", ["reference", "pallas"])
+def test_the_last_choice_takes_the_path_of_a_pair_held_elsewhere(path):
+    """`held_experts` under ONE choice a token of which the last is no
+    expert: those tokens' rows are sorted last and dropped, their output
+    is exactly 0, the others' is their one expert's at its weight."""
+    held, width, inner = 4, 32, 16
+    w1, w2 = _weights(held, width, 2 * inner, inner, seed=2)
+    u = jax.random.normal(jax.random.PRNGKey(7), (24, width), BF16)
+    chosen = jnp.asarray(np.arange(24) % (held + 1), jnp.int32)[:, None]
+    weights = jax.random.uniform(jax.random.PRNGKey(8), (24, 1), F32,
+                                 0.1, 0.9)
+    call = lambda: jax.jit(lambda *a: held_experts(*a, 0, _swiglu))(
+        u, chosen, weights, w1, w2)
+    if path == "pallas":
+        with dispatch.pallas_interpret():
+            out, counts = call()
+    else:
+        out, counts = call()
+    out, none = np.asarray(out), np.asarray(chosen[:, 0]) == held
+    assert (out[none] == 0.0).all() and none.sum() == 4
+    assert int(counts["pairs_held"]) == 20
+    np.testing.assert_array_equal(counts["sizes"], [5, 5, 5, 5])
+    for t in np.flatnonzero(~none)[:6]:
+        e = int(chosen[t, 0])
+        mid = _swiglu(jnp.dot(u[t], w1[e], preferred_element_type=F32))
+        want = float(weights[t, 0]) * jnp.dot(
+            mid.astype(BF16), w2[e], preferred_element_type=F32)
+        np.testing.assert_allclose(out[t], want, atol=2e-2, rtol=2e-2)
