@@ -43,6 +43,18 @@ surface stays bit-checkable against the engine oracle. ``prompt`` may
 also be a raw token-id list (the OpenAI array-of-tokens form), which
 is what the tests drive.
 
+A streamed delta is made from the tokens that are NEW since the last
+frame and ``_DELTA_CONTEXT`` tokens before them (:class:`_StreamText`:
+``decode(tokens[p:])`` less ``decode(tokens[p:r])``), never from the
+whole answer again, so a frame's cost does not grow with the answer;
+a delta that ends inside a character (U+FFFD from a byte codec) waits
+for the tokens that complete it. The deltas of a stream, joined, are
+exactly ``codec.decode(all its tokens)``, the non-streaming body, for
+any ``codec=`` whose pieces join locally. The request's ``sse_flush``
+phase carries ``writes``, ``tokens`` (framed) and ``decoded`` (handed
+to ``codec.decode``), ``stats()`` their totals ``sse_tokens`` and
+``sse_decoded_tokens``: the ratio is what a frame costs.
+
 Per repo convention the gateway gets the full surface treatment:
 ``util.state.gateway_status()``, ``ray_tpu gateway``, dashboard
 ``/api/gateway`` + tab, lazy Prometheus
@@ -112,6 +124,50 @@ def _sse_frame(payload: Any) -> bytes:
     return b"data: " + data + b"\n\n"
 
 
+# tokens of context a delta is decoded behind: a codec's pieces join
+# LOCALLY (ByteCodec's separating space needs one token, a byte
+# tokenizer's character at most three bytes before its last, a
+# sentence-piece's leading space one), so a few tokens behind the last
+# frame's end render the new tokens as the whole answer would
+_DELTA_CONTEXT = 3
+_INCOMPLETE = "\ufffd"     # what a byte codec renders half a character as
+
+
+class _StreamText:
+    """One stream's text, a frame at a time, at a frame's cost: the
+    delta of the tokens that are new is ``decode(tokens[p:])`` less
+    ``decode(tokens[p:r])``, ``r`` the last frame's end and ``p``
+    ``_DELTA_CONTEXT`` tokens behind it — never the whole answer
+    again. A delta that is empty or ends in an incomplete character is
+    held back (``""``; ``r`` stays) until the tokens that complete it
+    have come, and ``final=True`` sends what is held as it is. The
+    deltas, joined, are EXACTLY ``codec.decode(tokens)``, however the
+    tokens were grouped into frames."""
+
+    __slots__ = ("_decode", "tokens", "framed", "decoded")
+
+    def __init__(self, codec: Any):
+        self._decode = codec.decode
+        self.tokens: List[int] = []
+        self.framed = 0     # tokens whose text has gone out: r
+        self.decoded = 0    # tokens handed to decode, both calls
+
+    def delta(self, final: bool = False) -> str:
+        toks, r = self.tokens, self.framed
+        if r == len(toks):
+            return ""
+        p = max(0, r - _DELTA_CONTEXT)
+        text = self._decode(toks[p:])
+        self.decoded += len(toks) - p
+        if r > p:
+            text = text[len(self._decode(toks[p:r])):]
+            self.decoded += r - p
+        if not final and (not text or text.endswith(_INCOMPLETE)):
+            return ""
+        self.framed = len(toks)
+        return text
+
+
 class GatewayServer:
     """One gateway replica: an aiohttp server thread in front of one
     (or several, keyed by model name) DisaggRouter(s). Runs equally
@@ -169,6 +225,9 @@ class GatewayServer:
             "accepted": 0, "completed": 0, "streamed": 0,
             "disconnects": 0, "rate_limited": 0, "sheds": 0,
             "errors": 0, "preempt_dropped": 0, "tokens_out": 0,
+            # tokens the SSE bridge framed, and tokens it handed to
+            # codec.decode for them: their ratio is a frame's cost
+            "sse_tokens": 0, "sse_decoded_tokens": 0,
         }
         self._by_class: Dict[str, Dict[str, int]] = {
             c: {"accepted": 0, "completed": 0, "shed": 0,
@@ -615,8 +674,9 @@ class GatewayServer:
     async def _stream_response(self, request, ctx: Dict[str, Any]):
         """SSE bridge: generate runs on the executor; its on_tokens
         chunks land on an asyncio queue (call_soon_threadsafe) and are
-        re-framed as OpenAI stream chunks. Each delta is the decode of
-        all tokens so far minus what was already sent, so concatenated
+        re-framed as OpenAI stream chunks. Each delta is made from
+        the tokens that are new and a few before them (_StreamText: a
+        frame costs a frame, not the answer so far), and concatenated
         deltas are EXACTLY the non-streaming body. Disconnects —
         noticed by a failed write, by aiohttp cancelling the handler,
         or by transport polling while decode is quiet — set the cancel
@@ -632,12 +692,15 @@ class GatewayServer:
         cancel_event = threading.Event()
         q: asyncio.Queue = asyncio.Queue()
         t0 = time.perf_counter()
-        # sse_flush accounting: wall time spent inside resp.write —
-        # concurrent with decode (the executor keeps generating while
-        # the loop flushes), so the phase is marked concurrent and
-        # excluded from the phase-sum invariant
+        # sse_flush accounting: wall time spent on frames (the delta's
+        # decode, the payload and resp.write) — concurrent with decode
+        # (the executor keeps generating while the loop flushes), so
+        # the phase is marked concurrent and excluded from the
+        # phase-sum invariant
         flush_s = 0.0
         flush_n = 0
+        text = _StreamText(self._codec)
+        got = text.tokens
 
         def _put(item):
             try:
@@ -659,11 +722,15 @@ class GatewayServer:
                 _put(("error", e))
 
         def _finish(outcome, cause=None, **attrs):
+            with self._lock:
+                self._stats["sse_tokens"] += text.framed
+                self._stats["sse_decoded_tokens"] += text.decoded
             if tr is None:
                 return
             if flush_s > 0.0:
                 tr.add_phase("sse_flush", flush_s * 1e3,
-                             concurrent=True, writes=flush_n)
+                             concurrent=True, writes=flush_n,
+                             tokens=text.framed, decoded=text.decoded)
             tr.finish(outcome, cause=cause, **attrs)
 
         # the status line is written lazily at the FIRST frame: a
@@ -686,11 +753,21 @@ class GatewayServer:
                 await resp.prepare(request)
                 prepared = True
 
-        self._pool.submit(work)
-        got: List[int] = []
-        held: Optional[tuple] = None    # an item taken behind a frame
-        sent_text = ""
         first = True
+
+        async def _write_delta(delta):
+            nonlocal flush_n, first
+            await _prepare_once()
+            await resp.write(_sse_frame(self._completion_payload(
+                route, ctx["req_id"], ctx["created"], ctx["model"],
+                delta, None, 0, 0, chunk=True, first_chunk=first)))
+            flush_n += 1
+            if first:
+                first = False
+                self._first_byte(cls, (time.perf_counter() - t0) * 1e3)
+
+        self._pool.submit(work)
+        held: Optional[tuple] = None    # an item taken behind a frame
         disconnected = False
         failed: Optional[BaseException] = None
         try:
@@ -723,26 +800,20 @@ class GatewayServer:
                             got.extend(item[1])
                         else:       # the stream's end: the next pass's
                             held = item
-                    text = self._codec.decode(got)
-                    delta, sent_text = text[len(sent_text):], text
+                    t_w = time.perf_counter()
+                    n_new = len(got) - text.framed
                     try:
-                        t_w = time.perf_counter()
-                        await _prepare_once()
-                        await resp.write(_sse_frame(
-                            self._completion_payload(
-                                route, ctx["req_id"], ctx["created"],
-                                ctx["model"], delta, None, 0, 0,
-                                chunk=True, first_chunk=first)))
-                        flush_s += time.perf_counter() - t_w
-                        flush_n += 1
+                        delta = text.delta()
+                        if delta:
+                            await _write_delta(delta)
                     except _CLIENT_GONE:
                         disconnected = True
                         break
-                    if first:
-                        first = False
-                        self._first_byte(
-                            cls, (time.perf_counter() - t0) * 1e3)
-                    if self._consume_chaos("tokens", len(payload)):
+                    finally:
+                        flush_s += time.perf_counter() - t_w
+                    if not delta:   # held back: it rides a later frame
+                        continue
+                    if self._consume_chaos("tokens", n_new):
                         if request.transport is not None:
                             request.transport.abort()
                         disconnected = True
@@ -753,8 +824,11 @@ class GatewayServer:
                               and toks
                               and toks[-1] == int(self._eos_token)
                               else "length")
+                    t_w = time.perf_counter()
                     try:
-                        t_w = time.perf_counter()
+                        delta = text.delta(final=True)
+                        if delta:   # what was held goes out as it is
+                            await _write_delta(delta)
                         await _prepare_once()
                         await resp.write(_sse_frame(
                             self._completion_payload(
@@ -763,11 +837,12 @@ class GatewayServer:
                                 chunk=True)))
                         await resp.write(_sse_frame(b"[DONE]"))
                         await resp.write_eof()
-                        flush_s += time.perf_counter() - t_w
                         flush_n += 1
                     except _CLIENT_GONE:
                         disconnected = True
                         break
+                    finally:
+                        flush_s += time.perf_counter() - t_w
                     self._count_done(cls, len(toks), streamed=True)
                     self._count(route, cls, 200)
                     _finish("ok", tokens=len(toks), streamed=True)

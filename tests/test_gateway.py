@@ -28,6 +28,7 @@ from __future__ import annotations
 import dataclasses
 import http.client
 import json
+import random
 import socket
 import threading
 import time
@@ -40,7 +41,8 @@ import pytest
 from ray_tpu.models.engine import ContinuousBatchingEngine
 from ray_tpu.models.llama import LlamaConfig, llama_init
 from ray_tpu.serve.disagg import DisaggRouter
-from ray_tpu.serve.gateway import GatewayServer
+from ray_tpu.serve.gateway import (ByteCodec, GatewayServer,
+                                   _DELTA_CONTEXT, _StreamText)
 from ray_tpu.serve.handle import RequestShedError
 from ray_tpu.serve.qos import QosGate, TenantPolicy, TokenBucket
 
@@ -547,15 +549,21 @@ def test_state_api_sees_gateway_telemetry(gateway_cluster, model):
         engine.stop()
 
 
-class _BurstRouter:
-    """A router whose tokens arrive faster than one frame a token can be
-    written: every token a chunk of its own, handed over back to back."""
+class _GroupRouter:
+    """Hands a fixed answer over in the groups it was given."""
+
+    def __init__(self, groups, pause_s):
+        self.groups, self.pause_s = groups, pause_s
 
     def generate(self, prompt, max_tokens, on_tokens=None, **_kw):
-        toks = [(7 * i) % 250 + 1 for i in range(max_tokens)]
-        for tok in toks:
-            on_tokens([tok])
-        return toks
+        out = []
+        for g in self.groups:
+            out.extend(g)
+            if on_tokens is not None:
+                on_tokens(g)
+                if self.pause_s:
+                    time.sleep(self.pause_s)
+        return out
 
     def stats(self):
         return {}
@@ -565,11 +573,14 @@ def test_a_burst_goes_out_in_fewer_frames_and_the_same_text():
     """What queues while the loop writes a frame is sent as ONE frame
     (a loop that lags 64 long streams must not fall ever further behind
     them): the deltas still concatenate to exactly the body."""
-    gw = GatewayServer(_BurstRouter(), model="tiny", vocab_size=256,
+    n = 600
+    # every token a chunk of its own, handed over back to back: faster
+    # than one frame a token can be written
+    burst = _GroupRouter([[(7 * i) % 250 + 1] for i in range(n)], 0.0)
+    gw = GatewayServer(burst, model="tiny", vocab_size=256,
                        max_tokens_cap=800)
     host, port = gw.ready()
     try:
-        n = 600
         conn, resp = _post(host, port, "/v1/completions",
                            body={"model": "tiny", "prompt": [1, 2, 3],
                                  "max_tokens": n, "stream": True})
@@ -584,3 +595,120 @@ def test_a_burst_goes_out_in_fewer_frames_and_the_same_text():
     assert chunks[-1]["choices"][0]["finish_reason"] == "length"
     # one frame a token would be n + 1
     assert len(chunks) < n // 2
+
+
+# ------------------------------------------------ a frame costs a frame
+
+
+class _Utf8Codec:
+    """Tokens are UTF-8 bytes: a character of two to four bytes lies
+    across tokens, and half a character decodes to U+FFFD."""
+
+    def encode(self, text):
+        return list(text.encode("utf-8")) or [32]
+
+    def decode(self, tokens):
+        return bytes(int(t) for t in tokens).decode("utf-8",
+                                                    errors="replace")
+
+
+class _PieceCodec:
+    """Pieces joined with NO separator, some with a space of their own
+    in front (the sentence-piece shape)."""
+
+    PIECES = ["a", " the", "ing", " ", "qu", " Z", "-", "é", " 你", "x"]
+
+    def encode(self, text):
+        return [1 + (b % len(self.PIECES)) for b in text.encode()] or [1]
+
+    def decode(self, tokens):
+        return "".join(self.PIECES[int(t) % len(self.PIECES)]
+                       for t in tokens)
+
+
+_UTF8_TEXT = "naïve 你好, wörld 🌍! ½ of a 🧪 — ok. "
+
+_CODECS = {"bytecodec": ByteCodec(256), "utf8": _Utf8Codec(),
+           "pieces": _PieceCodec()}
+
+
+def _stream_tokens(codec_name, n):
+    if codec_name == "utf8":    # may end INSIDE a character
+        return list((_UTF8_TEXT * (1 + n // 20)).encode("utf-8"))[:n]
+    return [(7 * i) % 250 + 1 for i in range(n)]
+
+
+def _groups(tokens, grouping, seed):
+    if grouping == "one":
+        return [[t] for t in tokens]
+    if grouping == "all":
+        return [list(tokens)]
+    rng, out, i = random.Random(seed), [], 0
+    while i < len(tokens):
+        k = rng.randint(1, 5)
+        out.append(list(tokens[i:i + k]))
+        i += k
+    return out
+
+
+@pytest.mark.parametrize("grouping", ["one", "random", "all"])
+@pytest.mark.parametrize("n", [1, 2, 7, 600])
+@pytest.mark.parametrize("codec_name", sorted(_CODECS))
+def test_joined_deltas_are_the_body_at_a_frames_cost(codec_name, n,
+                                                      grouping):
+    """The bridge's contract, for any codec and however the tokens were
+    grouped into frames: the deltas of a stream, joined, are EXACTLY
+    codec.decode(all its tokens), which is the non-streaming body; a
+    delta is empty only while it is held back for the rest of its
+    character; and a frame costs a few tokens of decode, not the
+    answer so far (the old form handed n / 2 tokens a token to
+    decode: 300 at 600)."""
+    codec = _CODECS[codec_name]
+    tokens = _stream_tokens(codec_name, n)
+    groups = _groups(tokens, grouping, seed=1000 * n + len(codec_name))
+    want = codec.decode(tokens)
+    bound = 2 * _DELTA_CONTEXT + 5
+
+    # the delta's maker alone: the groups ARE the frames
+    text, sent = _StreamText(codec), ""
+    for g in groups:
+        text.tokens.extend(g)
+        delta = text.delta()
+        if not delta:   # held back, and with reason
+            rest = codec.decode(text.tokens)[len(sent):]
+            assert codec_name == "utf8" and rest.endswith("\ufffd"), (
+                g, rest)
+        sent += delta
+    sent += text.delta(final=True)
+    assert sent == want
+    assert text.framed == n and text.delta(final=True) == ""
+    if n == 600:
+        assert text.decoded / n < bound, text.decoded
+
+    # through the wire: frames merge as the loop finds them queued
+    gw = GatewayServer(_GroupRouter(groups, 2e-4 if n <= 7 else 0.0),
+                       model="tiny", codec=codec, max_tokens_cap=800)
+    host, port = gw.ready()
+    try:
+        body = {"model": "tiny", "prompt": [1, 2, 3], "max_tokens": n}
+        conn, resp = _post(host, port, "/v1/completions",
+                           body=dict(body, stream=True))
+        assert resp.status == 200
+        chunks, saw_done = _drain_sse(resp)
+        conn.close()
+        conn, resp = _post(host, port, "/v1/completions", body=body)
+        assert resp.status == 200
+        whole = json.loads(resp.read())
+        conn.close()
+        stats = gw.stats()
+    finally:
+        gw.stop()
+    assert saw_done
+    texts = [c["choices"][0]["text"] for c in chunks]
+    assert "".join(texts) == want == whole["choices"][0]["text"]
+    # no content frame is empty: a held-back delta rides a later frame
+    assert all(texts[:-1]) and texts[-1] == ""
+    assert chunks[-1]["choices"][0]["finish_reason"] == "length"
+    assert stats["sse_tokens"] == n
+    if n == 600:
+        assert stats["sse_decoded_tokens"] / n < bound, stats

@@ -446,6 +446,17 @@ def test_gateway_stream_records_sse_flush_and_tokens(gw_stack):
     flush = [p for p in kept["phases"] if p["phase"] == "sse_flush"]
     assert flush and flush[0]["concurrent"] is True
     assert flush[0]["writes"] >= 1
+    # what the frames cost: tokens framed, tokens handed to decode
+    assert flush[0]["tokens"] == 5
+    assert 5 <= flush[0]["decoded"] <= 7 * 5
+    # the phase-sum invariant still leaves the concurrent phase out
+    seq_ms = sum(p["dur_ms"] for p in kept["phases"]
+                 if not p.get("concurrent"))
+    assert seq_ms <= kept["total_ms"] + 5.0, kept
+    stats = gw_stack["gw"].stats()
+    assert stats["sse_tokens"] >= 5
+    assert stats["sse_tokens"] <= stats["sse_decoded_tokens"] \
+        <= 7 * stats["sse_tokens"]
 
 
 # --------------------------------------------------------- chaos e2e
