@@ -12,10 +12,11 @@ slab's layout and what each kind is refused). `generate` and
 here or anywhere names a family for them. The names below are
 re-exports; `dots3_note.py` (latent attention under a learned selection),
 `keye_vl2.py` (grouped-query attention under one, keys and values in
-pairs beside an index key in the slab) and `zaya.py` (attention in a
+pairs beside an index key in the slab), `zaya.py` (attention in a
 compressed latent behind two carried convolutions, one expert a token
-under an MLP router that may choose none) are imported where they are
-used.
+under an MLP router that may choose none) and `granite_hybrid.py` (a
+Mamba-2 or attention mixer AND ten of 72 experts AND a shared MLP in
+every layer, four multipliers) are imported where they are used.
 `moe_transformer.py` trains and is not served.
 """
 import time

@@ -37,7 +37,7 @@ records the choice and the kernel's row tile in `ops/dispatch` under
 `grouped_product` (`STREAM_ROWS_AN_EXPERT` says what the number is held
 by, `ROW_TILE` why one tile serves every call).
 
-Five callers, five expert shapes: `models/nemotron_h.py` (experts in
+Six callers, six expert shapes: `models/nemotron_h.py` (experts in
 a latent space, `w1` [held, L, I] under relu squared),
 `models/kimi_linear.py` (SwiGLU experts at full hidden width: gate and up
 packed in ONE `w1` [held, D, 2 I], and an `activation` that maps the
@@ -51,7 +51,14 @@ expert held, `w1` [64, 2560, 1536] under `relu(gate) * up` and [128, 2048,
 WIDE ones (`w1` [16, 2048, 4096], 16.8 MB each, in four tiles of
 columns; `w2` [16, 2048, 2048] in two): a buffer of T rows, 4 rows an
 expert a tick of 64 slots, 66 a prompt of 1,056 tokens, so every call is
-the streamed kernel's. `held_experts` asks nothing of the activation but
+the streamed kernel's; and `models/granite_hybrid.py`: TEN experts a
+token of 72 NARROW ones, 36 held (`w1` [36, 4096, 1536] in two tiles of
+columns, `w2` [36, 768, 4096] whole: 18.9 MB an expert), in EVERY layer
+beside a shared MLP: a tick of 8 slots is a buffer of 80 rows with half
+its pairs held elsewhere (streamed), a prompt block of 2,048 tokens one
+of 20,480 rows, 569 an expert held, over the cap (`ragged_dot`, handed
+the groups of the pairs held; the other half of the buffer belongs to
+no group). `held_experts` asks nothing of the activation but
 that it keeps the rows. Five routers. Two over sigmoid scores share
 `sigmoid_topk_route` (the bias chooses); the third,
 `softmax_group_limited_route`, scores by softmax and lets only the best
@@ -59,7 +66,8 @@ GROUPS of experts compete, as a deployment that keeps a group on a chip
 does; the fourth, `softmax_topk_route`, is the plain one: the k largest
 logits, weighed by their softmax over the chosen alone
 (`models/smallthinker.py`, whose router reads the layer's input, so its
-choice and the sort below do not wait for the layer's attention); the
+choice and the sort below do not wait for the layer's attention;
+`models/keye_vl2.py`; `models/granite_hybrid.py` at k = 10 of 72); the
 fifth, `mlp_top1_route`, is no single matrix: a down-projection, the LAST
 layer's router state added in, a norm and a three-matrix MLP, ONE choice
 a token among the experts and one choice more, which is NO expert. That

@@ -35,6 +35,16 @@ nor written, and its `y` is 0. The small operands (x dt, the decay, B
 and C: 48 KB a slot) stay resident for the whole call, transposed by XLA
 so that a head's column of them spreads along the lanes of its state.
 Which form a traced shape took: `dispatch.kernel_choices("state_step")`.
+
+Two callers, two shapes. `models/nemotron_h.py`: 8 groups of 16 heads, a
+chunk of 128, the whole run of a prompt in one call (192 to 1,024
+tokens). `models/granite_hybrid.py`: ONE group (all 128 heads read the
+same B and C: `rep = heads`, a visit of the step kernel takes one block
+of B and C a slot), a chunk of 256, a prompt of thousands of tokens
+walked in blocks of 2,048 with the state and the tail carried from call
+to call, so a call's last chunk is ragged wherever a prompt is no whole
+number of chunks. Both go through the same code: nothing here reads the
+number of groups but as a shape.
 """
 from __future__ import annotations
 

@@ -79,9 +79,11 @@ def test_prefill_then_decode_is_the_references_one_forward_pass(params):
                                  init_cache(CFG, 1), 0)
     np.testing.assert_allclose(logits[0, 0], want[20], atol=TOL, rtol=0)
     assert int(counts["attn_blocks"]) == 3 * 9     # 3 layers, 3 x 3 blocks
+    # one compiled step for the nine
+    one = jax.jit(lambda t, c, at: step(params, t, CFG, c, at))
     for pos in range(21, 30):
-        logits, cache, counts = step(params, TOKENS[None, pos:pos + 1],
-                                     CFG, cache, pos)
+        logits, cache, counts = one(TOKENS[None, pos:pos + 1], cache,
+                                    jnp.int32(pos))
         np.testing.assert_allclose(logits[0, 0], want[pos], atol=TOL,
                                    rtol=0)
         assert int(counts["attn_blocks"]) == 0

@@ -7,6 +7,7 @@ attention; the kernels against their `jax.numpy` forms; the shares of an
 expert layer add up; and what `models/family.py` says of a cache that is
 latent AND ring."""
 import dataclasses
+import functools
 import os
 import sys
 
@@ -31,7 +32,11 @@ TOKENS = np.random.default_rng(3).integers(1, 500, 64).astype(np.int32)
 PROMPT, TOTAL = 30, 50      # the window is 9, the top k 12, the ring 12
 
 
+@functools.lru_cache(maxsize=None)
 def _toy(dtype=jnp.float32, **changed):
+    """Made once a module for each set of arguments (the init and its 200
+    draws of noise are a quarter of a minute; nothing writes into what is
+    handed back)."""
     conf = configs.load_config("dots3-note-l5-e32")
     conf = {**conf, **configs.family(conf).toy}
     cfg = dataclasses.replace(configs.program_config(conf, 64), dtype=dtype,
@@ -51,20 +56,24 @@ def test_the_program_is_the_reference_over_a_prompt_and_through_the_cache():
     assert cfg.ring_rows == 12 and cfg.band_block == 8
     want = np.asarray(reference.logits(conf, params, TOKENS[:TOTAL]))
     tokens = jnp.asarray(TOKENS[:TOTAL])[None]
-    got = m.dots3_note_forward(params, tokens, cfg)
+    got = jax.jit(lambda t: m.dots3_note_forward(params, t, cfg))(tokens)
     np.testing.assert_allclose(np.asarray(got[0]), want, atol=2e-4)
     cache = m.dots3_note_init_cache(cfg, 1)
-    logits, cache, counts = m.dots3_note_forward_counted(
-        params, tokens[:, :PROMPT], cfg, cache, 0)
+    logits, cache, counts = jax.jit(
+        lambda t, c: m.dots3_note_forward_counted(params, t, cfg, c, 0))(
+            tokens[:, :PROMPT], cache)
     np.testing.assert_allclose(np.asarray(logits[0, 0]), want[PROMPT - 1],
                                atol=2e-4)
     assert int(counts["dsa_rows_visible"]) == PROMPT * (PROMPT + 1) // 2
     assert int(counts["dsa_rows_selected"]) == 12 * 13 // 2 + 18 * 12
     assert int(counts["ring_rows_read"]) == 9 * 10 // 2 + 21 * 9
-    # 20 steps through a ring of 12: it wraps, twice nearly
+    # 20 steps through a ring of 12: it wraps, twice nearly (one
+    # compiled step, as the engine's tick is)
+    step = jax.jit(lambda t, c, at: m.dots3_note_decode(params, t, cfg, c,
+                                                        at))
     for pos in range(PROMPT, TOTAL):
-        logits, cache, counts = m.dots3_note_decode(
-            params, tokens[:, pos], cfg, cache, jnp.asarray([pos]))
+        logits, cache, counts = step(tokens[:, pos], cache,
+                                     jnp.asarray([pos]))
         np.testing.assert_allclose(np.asarray(logits[0]), want[pos],
                                    atol=2e-4)
     assert int(counts["dsa_rows_selected"]) == 12
@@ -77,10 +86,12 @@ def test_the_program_is_the_reference_over_a_prompt_and_through_the_cache():
 def test_the_kernels_are_their_plain_forms(length):     # keys, a packed mask
     _conf, cfg, params = _toy()
     tokens = jnp.asarray(TOKENS[:length])[None]
-    plain = m.dots3_note_forward(params, tokens, cfg)
+    # each form traced as ONE program under the mode the process then has
+    plain = jax.jit(lambda t: m.dots3_note_forward(params, t, cfg))(tokens)
     dispatch.reset_kernel_choices()
     with dispatch.pallas_interpret():
-        kernels = m.dots3_note_forward(params, tokens, cfg)
+        kernels = jax.jit(
+            lambda t: m.dots3_note_forward(params, t, cfg))(tokens)
     took = {c["op"]: c["choice"] for c in dispatch.kernel_choices()}
     assert {took[op] for op in ("dsa_select", "mla_selected", "mla_band")
             } == {"pallas"}
@@ -158,7 +169,8 @@ class _nothing:
 def test_a_prompt_no_longer_than_top_k_is_dense_attention():
     conf, cfg, params = _toy(index_topk=64)
     tokens = jnp.asarray(TOKENS[:40])[None]
-    got = np.asarray(m.dots3_note_forward(params, tokens, cfg)[0])
+    got = np.asarray(jax.jit(
+        lambda t: m.dots3_note_forward(params, t, cfg))(tokens)[0])
     dense = np.asarray(reference.logits(
         {**conf, "reference_selection": "dense"}, params, TOKENS[:40]))
     np.testing.assert_allclose(got, dense, atol=2e-4)

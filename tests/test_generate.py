@@ -40,9 +40,11 @@ def test_incremental_decode_matches_full_forward(model):
     seq = jnp.asarray(rng.integers(0, CFG.vocab_size, (1, 14)), jnp.int32)
     cache = init_kv_cache(CFG, 1)
     _, cache = llama_forward_cached(model, seq[:, :8], CFG, cache, 0)
+    # one compiled step for the six, as the engine's tick is
+    step = jax.jit(lambda s, c, at: llama_forward_cached(model, s, CFG, c,
+                                                         at))
     for t in range(8, 14):
-        step_logits, cache = llama_forward_cached(
-            model, seq[:, t:t + 1], CFG, cache, t)
+        step_logits, cache = step(seq[:, t:t + 1], cache, jnp.int32(t))
         full = llama_forward(model, seq[:, :t + 1], CFG)
         np.testing.assert_allclose(
             np.asarray(step_logits[:, 0]), np.asarray(full[:, -1]),

@@ -242,10 +242,12 @@ def test_shape_rule_names_no_model_and_reads_no_environment():
 
 
 @pytest.mark.parametrize("family", ["nemotron_h", "kimi_linear",
-                                    "deepseek_v2", "smallthinker", "zaya"])
+                                    "deepseek_v2", "smallthinker", "zaya",
+                                    "granite_hybrid"])
 def test_a_family_forward_under_the_kernel(family):
     """Each caller's whole forward pass at its toy size with the streamed
-    kernel (interpret mode) against the same pass on `ragged_dot`."""
+    kernel (interpret mode) against the same pass on `ragged_dot`, each
+    traced as ONE program under the form the process then has."""
     import importlib
     mod = importlib.import_module(f"ray_tpu.models.{family}")
     config = next(v for k, v in vars(mod).items()
@@ -254,10 +256,12 @@ def test_a_family_forward_under_the_kernel(family):
     tokens = jax.random.randint(jax.random.PRNGKey(4), (2, 6), 0,
                                 config.vocab_size)
     forward = getattr(mod, f"{family}_forward")
-    want = np.asarray(forward(params, tokens, config))
+    want = np.asarray(jax.jit(
+        lambda p, t: forward(p, t, config))(params, tokens))
     dispatch.reset_kernel_choices()
     with dispatch.pallas_interpret():
-        got = np.asarray(forward(params, tokens, config))
+        got = np.asarray(jax.jit(
+            lambda p, t: forward(p, t, config))(params, tokens))
     choices = dispatch.kernel_choices("grouped_product")
     assert choices and all(c["choice"] == "pallas" for c in choices)
     assert np.isfinite(got).all()

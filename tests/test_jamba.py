@@ -79,9 +79,10 @@ def test_prefill_then_eight_tokens_through_the_cache(toy):
                          0)
     assert logits.shape == (1, 1, cfg.vocab_size)   # the LAST position's
     _close(logits[0, 0], want[29])
+    one_step = jax.jit(lambda t, c, at: decode(params, t, cfg, c, at))
     for pos in range(30, 38):
-        logits, cache = decode(params, TOKENS[pos:pos + 1], cfg, cache,
-                               jnp.asarray([pos], jnp.int32))
+        logits, cache = one_step(TOKENS[pos:pos + 1], cache,
+                                 jnp.asarray([pos], jnp.int32))
         _close(logits[0], want[pos])
     # a suffix through `forward_cached` (T > 1 at pos > 0) continues too
     logits, _ = step(params, TOKENS[None, 38:41], cfg, cache, 38)
@@ -109,9 +110,11 @@ def test_padding_moves_neither_the_state_nor_the_tail(toy):
     plain, c1 = _model_fns(one)[0](params, TOKENS[None, :13], one,
                                    init_cache(one, 1), 0)
     cs = init_cache(cfg, 1)
+    # one compiled step for the thirteen
+    one_step = jax.jit(lambda t, c, at: decode(params, t, cfg, c, at))
     for pos in range(13):
-        steps, cs = decode(params, TOKENS[pos:pos + 1], cfg, cs,
-                           jnp.asarray([pos], jnp.int32))
+        steps, cs = one_step(TOKENS[pos:pos + 1], cs,
+                             jnp.asarray([pos], jnp.int32))
     _close(padded, plain, 1e-5)
     _close(padded[0, 0], steps[0], 1e-5)
     for got, a, b in zip(cp, c1, cs):

@@ -7,6 +7,7 @@ never selected; the sectioned multimodal rotary against the plain one;
 the route against SmallThinker's; and what `models/family.py` says of a
 cache that is pairs AND index."""
 import dataclasses
+import functools
 import os
 import sys
 
@@ -35,7 +36,11 @@ TOKENS = np.random.default_rng(3).integers(1, 500, 64).astype(np.int32)
 PROMPT, TOTAL = 30, 50      # the top k is 12
 
 
+@functools.lru_cache(maxsize=None)
 def _toy(dtype=jnp.float32, **changed):
+    """Made once a module for each set of arguments (the init and its 200
+    draws of noise are a quarter of a minute; nothing writes into what is
+    handed back)."""
     conf = configs.load_config(CONFIG)
     conf = {**conf, **configs.family(conf).toy}
     cfg = dataclasses.replace(configs.program_config(conf, 64), dtype=dtype,
@@ -55,11 +60,12 @@ def test_the_program_is_the_reference_over_a_prompt_and_through_the_slab():
     assert (cfg.index_topk, cfg.attn_block, cfg.head_group) == (12, 8, 2)
     want = np.asarray(reference.logits(conf, params, TOKENS[:TOTAL]))
     tokens = jnp.asarray(TOKENS[:TOTAL])[None]
-    got = m.keye_vl2_forward(params, tokens, cfg)
+    got = jax.jit(lambda t: m.keye_vl2_forward(params, t, cfg))(tokens)
     np.testing.assert_allclose(np.asarray(got[0]), want, atol=2e-4)
     cache = m.keye_vl2_init_cache(cfg, 1)
-    logits, cache, counts = m.keye_vl2_forward_counted(
-        params, tokens[:, :PROMPT], cfg, cache, 0)
+    logits, cache, counts = jax.jit(
+        lambda t, c: m.keye_vl2_forward_counted(params, t, cfg, c, 0))(
+            tokens[:, :PROMPT], cache)
     np.testing.assert_allclose(np.asarray(logits[0, 0]), want[PROMPT - 1],
                                atol=2e-4)
     assert int(counts["dsa_rows_visible"]) == PROMPT * (PROMPT + 1) // 2
@@ -69,10 +75,13 @@ def test_the_program_is_the_reference_over_a_prompt_and_through_the_slab():
     assert int(counts["moe_pairs_held"]) == 3 * PROMPT * 3
     assert int(counts["moe_rows_mean"]) == 3 * PROMPT * 3 // (3 * 8)
     assert int(counts["moe_rows_max"]) >= int(counts["moe_rows_mean"])
-    # 20 steps, every one past the 12th row: the tick selects
+    # 20 steps, every one past the 12th row: the tick selects (one
+    # compiled step, as the engine's tick is)
+    step = jax.jit(lambda t, c, at: m.keye_vl2_decode(params, t, cfg, c,
+                                                      at))
     for pos in range(PROMPT, TOTAL):
-        logits, cache, counts = m.keye_vl2_decode(
-            params, tokens[:, pos], cfg, cache, jnp.asarray([pos]))
+        logits, cache, counts = step(tokens[:, pos], cache,
+                                     jnp.asarray([pos]))
         np.testing.assert_allclose(np.asarray(logits[0]), want[pos],
                                    atol=2e-4)
     assert int(counts["dsa_rows_selected"]) == 12
@@ -84,11 +93,11 @@ def test_the_program_is_the_reference_over_a_prompt_and_through_the_slab():
 def test_a_block_size_changes_nothing_beyond_rounding():
     _conf, cfg, params = _toy()
     tokens = jnp.asarray(TOKENS[:45])[None]
-    want = np.asarray(m.keye_vl2_forward(params, tokens, cfg))
+    forward = jax.jit(m.keye_vl2_forward, static_argnums=2)
+    want = np.asarray(forward(params, tokens, cfg))
     for changed in (dict(head_group=4), dict(ffn_block=64),
                     dict(index_block=8), dict(attn_block=16)):
-        got = m.keye_vl2_forward(
-            params, tokens, dataclasses.replace(cfg, **changed))
+        got = forward(params, tokens, dataclasses.replace(cfg, **changed))
         np.testing.assert_allclose(np.asarray(got), want, atol=2e-4)
     with pytest.raises(ValueError, match="whole heads of keys"):
         dataclasses.replace(cfg, head_group=1)
@@ -102,10 +111,12 @@ def test_a_block_size_changes_nothing_beyond_rounding():
 def test_the_kernels_are_their_plain_forms(length):     # keys, a packed mask
     conf, cfg, params = _toy()
     tokens = jnp.asarray(TOKENS[:length])[None]
-    plain = m.keye_vl2_forward(params, tokens, cfg)
+    # each form traced as ONE program under the mode the process then has
+    plain = jax.jit(lambda t: m.keye_vl2_forward(params, t, cfg))(tokens)
     dispatch.reset_kernel_choices()
     with dispatch.pallas_interpret():
-        kernels = m.keye_vl2_forward(params, tokens, cfg)
+        kernels = jax.jit(
+            lambda t: m.keye_vl2_forward(params, t, cfg))(tokens)
     took = {c["op"]: c["choice"] for c in dispatch.kernel_choices()}
     assert {took[op] for op in ("dsa_select", "gqa_selected")} == {"pallas"}
     assert dispatch.kernel_choices("gqa_selected")[0]["shape"] \

@@ -56,14 +56,18 @@ def params():
         next(keys), x.shape, x.dtype), p)
 
 
+# one compiled program a length (a pass op by op costs ten times that)
+_forward = jax.jit(lambda p, seq: kl.kimi_linear_forward(p, seq, CFG))
+
+
 def test_forward_agrees_with_the_reference(params):
-    got = kl.kimi_linear_forward(params, TOKENS[None, :40], CFG)[0]
+    got = _forward(params, TOKENS[None, :40])[0]
     want = reference.logits(CONF, params, TOKENS[:40])
     np.testing.assert_allclose(got, want, atol=TOL, rtol=0)
 
 
 def test_another_share_is_another_function(params):
-    got = kl.kimi_linear_forward(params, TOKENS[None, :24], CFG)[0]
+    got = _forward(params, TOKENS[None, :24])[0]
     other = reference.logits({**CONF, "expert_parallel_rank": 1}, params,
                              TOKENS[:24])
     assert float(jnp.max(jnp.abs(got - other))) > TOL
@@ -184,7 +188,7 @@ def test_prefill_then_ticks_through_the_slab_is_one_forward_pass(params):
         eng.stop()
     for prompt, out, stream in zip(prompts, emitted, streams):
         seq = np.concatenate([prompt, out[:-1]]).astype(np.int32)
-        lg = kl.kimi_linear_forward(params, seq[None], CFG)[0]
+        lg = _forward(params, seq[None])[0]
         lg = lg[len(prompt) - 1:]
         assert out == [int(t) for t in jnp.argmax(lg, -1)]
         lp = jax.nn.log_softmax(lg, -1)
@@ -196,9 +200,9 @@ def test_decode_counts_what_the_expert_layers_saw(params):
     cache = kl.kimi_linear_init_cache(CFG, 4)
     assert [sorted(e) for e in cache] == [["k"]] + [["conv", "state"]] * 3
     assert cache[0]["k"].shape == (4, 128, 128)
-    _, _, counts = kl.kimi_linear_decode(
-        params, jnp.asarray(TOKENS[:4]), CFG, cache,
-        jnp.zeros(4, jnp.int32))
+    _, _, counts = jax.jit(lambda t, c, at: kl.kimi_linear_decode(
+        params, t, CFG, c, at))(jnp.asarray(TOKENS[:4]), cache,
+                                jnp.zeros(4, jnp.int32))
     # 4 tokens x 3 experts in each of the 3 expert layers, a quarter of
     # the router's width held
     assert 0 < int(counts["moe_pairs_held"]) <= 36
@@ -219,9 +223,9 @@ def test_ticks_by_liveness_are_the_ticks_without_on_the_live_rows(params):
 
     live = jnp.asarray([1, 0, 1, 1], jnp.int32)
     lv = np.asarray(live) != 0
-    _, cache0 = kl.kimi_linear_forward_cached(
-        params, jnp.asarray(TOKENS[:64].reshape(4, 16)), CFG,
-        kl.kimi_linear_init_cache(CFG, 4), 0)
+    _, cache0 = jax.jit(lambda t, c: kl.kimi_linear_forward_cached(
+        params, t, CFG, c, 0))(jnp.asarray(TOKENS[:64].reshape(4, 16)),
+                               kl.kimi_linear_init_cache(CFG, 4))
     shape = (4, CFG.kda_num_heads, CFG.kda_head_dim, CFG.kda_head_dim)
     with dispatch.pallas_interpret():
         walk = jax.jit(lambda cache, tok, pos: kl.kimi_linear_decode(

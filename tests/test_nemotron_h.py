@@ -246,9 +246,10 @@ def test_the_prefill_hands_back_the_last_positions_logits_only(tiny):
     cfg, params = tiny
     fwd, init_cache, _ = _model_fns(cfg)
     toks = jnp.asarray([_prompts()[1]], jnp.int32)
-    logits, _ = fwd(params, toks, cfg, init_cache(cfg, 1), jnp.int32(0))
+    logits, _ = jax.jit(lambda t, c: fwd(params, t, cfg, c, jnp.int32(0)))(
+        toks, init_cache(cfg, 1))
     assert logits.shape == (1, 1, cfg.vocab_size)
-    full = nemotron_h_forward(params, toks, cfg)
+    full = jax.jit(lambda t: nemotron_h_forward(params, t, cfg))(toks)
     np.testing.assert_allclose(logits[0, 0], full[0, -1], atol=2e-2)
 
 
