@@ -5,11 +5,20 @@ at GPT-2 vocab the fp32 logits are ~200KB *per token row*, so a
 materialized [N, V] logits tensor plus log_softmax costs gigabytes of HBM
 traffic per step. This kernel never materializes logits: the vocab axis
 streams through VMEM in blocks while an online logsumexp (flash-attention
-style, log2 domain) and the target-logit pick run in registers. The
-backward recomputes P = exp(logits - lse) blockwise from the saved
-row-logsumexp — two kernels (dx over row blocks, dW over vocab blocks) —
-with the one-hot terms (wte gather / segment-sum scatter) left to XLA
-where they are cheap single passes.
+style, log2 domain) and the target-logit pick run in registers.
+
+The backward makes P = exp(logits - lse) once, from the saved
+row-logsumexp: `fused_ce_dx` (over row blocks) recomputes the logits,
+multiplies P by W and writes each tile of P, transposed, in the dtype
+that product reads it in; `fused_ce_dw` (over vocab blocks) reads those
+tiles and is one product, P^T (g x). The kernels so execute 8 N V d for
+the 6 N V d the loss needs. P is `local rows x padded vocab x itemsize`
+bytes: 3.30 GB at GPT-2's training shape (32,768 x 50,304 bfloat16).
+Rows whose P would pass `P_BUDGET_BYTES` are walked in super-blocks that
+each fit (dx then dW a super-block, dW carried from one to the next), so
+the buffer is bounded by the shape and by no knob. The one-hot terms (wte
+gather / segment-sum scatter) are left to XLA where they are cheap single
+passes.
 
 New capability vs the reference (no kernels of its own — SURVEY.md §5.7);
 the chunked-XLA fallback (`_ce_reference`) is the correctness oracle, and
@@ -39,6 +48,12 @@ _LN2 = 0.6931471805599453
 
 # token rows per program (env override for bench sweeps)
 DEFAULT_BLOCK_N = int(os.environ.get("RAY_TPU_CE_BLOCK_N", "1024"))
+
+# The most bytes of P the backward holds at once: a quarter of the 16 GB
+# of a v5e, the smallest chip trained on. GPT-2's step at 32 x 1,024 holds
+# 7.4 GB without P and 10.6 GB with its 3.30 GB whole; a shape whose P is
+# larger is walked in super-blocks of rows (`_super_rows`).
+P_BUDGET_BYTES = 4 << 30
 
 
 def _ce_reference(x: jax.Array, w: jax.Array, targets: jax.Array,
@@ -149,129 +164,163 @@ def _ce_fwd_pallas(x, w, targets, vocab_size: int, block_n: int,
 # -------------------------------------------------------------- backward
 
 
-def _ce_dx_kernel(x_ref, w_ref, lse_ref, dx_ref, acc_scr, *,
-                  block_n: int, block_v: int, n_v_blocks: int,
+def _ce_dx_kernel(x_ref, w_ref, lse_ref, dx_ref, pt_ref, *, block_v: int,
                   vocab_size: int, padded: bool):
-    """dx_unscaled = P @ W, streamed over vocab blocks. Grid
-    (row_block, vocab_block), vocab minor; acc in scratch."""
+    """dx_unscaled = P @ W, streamed over vocab blocks, and P itself: every
+    grid step writes its tile of P = exp2(s - lse), in the dtype the
+    product reads it in and transposed, as `_ce_dw_kernel`'s left operand
+    (the transpose hides under the products here; a tile `[block_n,
+    block_v]` is no legal block at a block_v of 320 or 448, and a product
+    over the rows of both operands there costs 1 ms of 15). Grid
+    (row_block, vocab_block), vocab minor; the f32 output block is the
+    accumulator (a scratch beside it would pass the 16 MB of scoped
+    VMEM)."""
     vi = pl.program_id(1)
 
     @pl.when(vi == 0)
     def _init():
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+        dx_ref[...] = jnp.zeros_like(dx_ref)
 
-    cd = x_ref.dtype
-    x = x_ref[...]
     w = w_ref[...]
     s = jax.lax.dot_general(
-        x, w, (((1,), (1,)), ((), ())),
+        x_ref[...], w, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * _LOG2E
-    if padded:
+    if padded:  # a padded column's P is exp2(-1e30 - lse) = 0
         col = vi * block_v + jax.lax.broadcasted_iota(
             jnp.int32, (s.shape[0], block_v), 1)
         s = jnp.where(col < vocab_size, s, _NEG_INF)
     lse2 = lse_ref[:, :1] * _LOG2E
-    p = jnp.exp2(s - lse2)
-    acc_scr[...] = acc_scr[...] + jax.lax.dot_general(
-        p.astype(cd), w, (((1,), (0,)), ((), ())),
+    p = jnp.exp2(s - lse2).astype(pt_ref.dtype)
+    pt_ref[...] = p.T
+    dx_ref[...] = dx_ref[...] + jax.lax.dot_general(
+        p, w, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
 
-    @pl.when(vi == n_v_blocks - 1)
-    def _finalize():
-        dx_ref[...] = acc_scr[...].astype(dx_ref.dtype)
 
+def _ce_dw_kernel(xg_ref, pt_ref, carry_ref, dw_ref):
+    """dW_unscaled[v_block] = carry + P^T @ (g*x) from the tiles of P^T
+    that `_ce_dx_kernel` wrote, streamed over row blocks: no logits, no
+    mask (a padded column's P is 0), no exp2. Grid (vocab_block,
+    row_block), rows minor; the f32 output block is the accumulator."""
 
-def _ce_dw_kernel(x_ref, w_ref, lse_ref, xg_ref, dw_ref, acc_scr, *,
-                  block_n: int, block_v: int, n_n_blocks: int,
-                  vocab_size: int, padded: bool):
-    """dW_unscaled[v_block] = P^T @ (g*x), streamed over row blocks. Grid
-    (vocab_block, row_block), rows minor."""
-    ni = pl.program_id(1)
-    vi = pl.program_id(0)
-
-    @pl.when(ni == 0)
+    @pl.when(pl.program_id(1) == 0)
     def _init():
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+        dw_ref[...] = carry_ref[...]
 
-    cd = x_ref.dtype
-    x = x_ref[...]
-    w = w_ref[...]
-    st = jax.lax.dot_general(  # [block_v, block_n] = W X^T, log2 domain
-        w, x, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * _LOG2E
-    if padded:
-        row = vi * block_v + jax.lax.broadcasted_iota(
-            jnp.int32, (block_v, st.shape[1]), 0)
-        st = jnp.where(row < vocab_size, st, _NEG_INF)
-    lse2 = lse_ref[:, :1] * _LOG2E  # [block_n, 1]
-    pt = jnp.exp2(st - lse2.T)      # [block_v, block_n]
-    acc_scr[...] = acc_scr[...] + jax.lax.dot_general(
-        pt.astype(cd), xg_ref[...], (((1,), (0,)), ((), ())),
+    dw_ref[...] = dw_ref[...] + jax.lax.dot_general(
+        pt_ref[...], xg_ref[...], (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
 
-    @pl.when(ni == n_n_blocks - 1)
-    def _finalize():
-        dw_ref[...] = acc_scr[...].astype(dw_ref.dtype)
 
-
-def _ce_bwd_pallas(x, w, targets, lse, g, vocab_size: int, block_n: int,
-                   block_v: int, interpret: bool):
-    """Returns (dx in x's dtype, dW in f32: the caller may still have to
-    sum it over row shards)."""
+def _ce_dx_pallas(x, w, lse_b, vocab_size: int, block_n: int, block_v: int,
+                  interpret: bool):
+    """(dx_unscaled f32 [n, d], P^T [v, n] in x's dtype) of one
+    super-block's rows."""
     n, d = x.shape
     v = w.shape[0]
-    block_n = min(block_n, n)
-    lse_b = jnp.broadcast_to(lse[:, None], (n, _LANES))
-
-    dx_kernel = functools.partial(
-        _ce_dx_kernel, block_n=block_n, block_v=block_v,
-        n_v_blocks=v // block_v, vocab_size=vocab_size,
-        padded=v > vocab_size)
-    dx_unscaled = pl.pallas_call(
-        dx_kernel,
-        grid=(pl.cdiv(n, block_n), v // block_v),
+    itemsize = x.dtype.itemsize
+    return pl.pallas_call(
+        functools.partial(
+            _ce_dx_kernel, block_v=block_v, vocab_size=vocab_size,
+            padded=v > vocab_size),
+        grid=(n // block_n, v // block_v),
         in_specs=[
             pl.BlockSpec((block_n, d), lambda i, j: (i, 0)),
             pl.BlockSpec((block_v, d), lambda i, j: (j, 0)),
             pl.BlockSpec((block_n, _LANES), lambda i, j: (i, 0)),
         ],
-        out_specs=pl.BlockSpec((block_n, d), lambda i, j: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n, d), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((block_n, d), jnp.float32)],
+        out_specs=[
+            pl.BlockSpec((block_n, d), lambda i, j: (i, 0)),
+            pl.BlockSpec((block_v, block_n), lambda i, j: (j, i)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((n, d), jnp.float32),
+            jax.ShapeDtypeStruct((v, n), x.dtype),
+        ],
         interpret=interpret,
         name="fused_ce_dx",
         cost_estimate=pl.CostEstimate(
-            flops=4 * n * v * d, bytes_accessed=2 * x.size,
+            flops=4 * n * v * d,
+            bytes_accessed=(n * d * (itemsize + 4) + n * v * itemsize
+                            + n // block_n * w.size * w.dtype.itemsize),
             transcendentals=n * v),
     )(x, w, lse_b)
-    # one-hot term and upstream scaling in XLA (cheap single passes)
-    dx = (dx_unscaled - w[targets].astype(jnp.float32)) * g[:, None]
 
-    xg = (x.astype(jnp.float32) * g[:, None]).astype(x.dtype)
-    dw_kernel = functools.partial(
-        _ce_dw_kernel, block_n=block_n, block_v=block_v,
-        n_n_blocks=pl.cdiv(n, block_n), vocab_size=vocab_size,
-        padded=v > vocab_size)
-    dw_unscaled = pl.pallas_call(
-        dw_kernel,
-        grid=(v // block_v, pl.cdiv(n, block_n)),
+
+def _ce_dw_pallas(xg, pt, dw, block_n: int, block_v: int, interpret: bool):
+    """dw + P^T @ xg, in dw's buffer (f32 [v, d]). The rows' operand
+    first: `chip_smoke.py` reads there that a shard got its own rows."""
+    v, n = pt.shape
+    d = xg.shape[1]
+    return pl.pallas_call(
+        _ce_dw_kernel,
+        grid=(v // block_v, n // block_n),
         in_specs=[
             pl.BlockSpec((block_n, d), lambda j, i: (i, 0)),
+            pl.BlockSpec((block_v, block_n), lambda j, i: (j, i)),
             pl.BlockSpec((block_v, d), lambda j, i: (j, 0)),
-            pl.BlockSpec((block_n, _LANES), lambda j, i: (i, 0)),
-            pl.BlockSpec((block_n, d), lambda j, i: (i, 0)),
         ],
         out_specs=pl.BlockSpec((block_v, d), lambda j, i: (j, 0)),
         out_shape=jax.ShapeDtypeStruct((v, d), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((block_v, d), jnp.float32)],
+        input_output_aliases={2: 0},
         interpret=interpret,
         name="fused_ce_dw",
         cost_estimate=pl.CostEstimate(
-            flops=4 * n * v * d,
-            bytes_accessed=2 * x.size + w.size, transcendentals=n * v),
-    )(x, w, lse_b, xg)
-    # scatter-add of the one-hot rows: dW[tgt] -= g*x
-    dw = dw_unscaled.at[targets].add(-xg.astype(jnp.float32))
+            flops=2 * n * v * d,
+            bytes_accessed=(pt.size * pt.dtype.itemsize + 2 * dw.size * 4
+                            + v // block_v * xg.size * xg.dtype.itemsize),
+            transcendentals=0),
+    )(xg, pt, dw)
+
+
+def _super_rows(n: int, v: int, itemsize: int, block_n: int,
+                budget: int) -> int:
+    """Rows of one super-block of the backward: the most whole row blocks
+    that cut `n` evenly and whose P `[rows, v]` is within `budget` bytes
+    (one row block where even that is over it)."""
+    blocks = n // block_n
+    for k in range(1, blocks + 1):
+        if blocks % k == 0 and (n // k) * v * itemsize <= budget:
+            return n // k
+    return block_n
+
+
+def _ce_bwd_pallas(x, w, targets, lse, g, vocab_size: int, block_n: int,
+                   block_v: int, interpret: bool,
+                   p_budget_bytes: int = P_BUDGET_BYTES):
+    """Returns (dx in x's dtype, dW in f32: the caller may still have to
+    sum it over row shards). P is made once, a super-block of rows at a
+    time (`_super_rows`): dx writes it, dW reads it."""
+    n, d = x.shape
+    v = w.shape[0]
+    block_n = min(block_n, n)
+    rows = _super_rows(n, v, x.dtype.itemsize, block_n, p_budget_bytes)
+    lse_b = jnp.broadcast_to(lse[:, None], (n, _LANES))
+    xg = (x.astype(jnp.float32) * g[:, None]).astype(x.dtype)
+
+    def super_block(dw, blk):
+        x_, lse_, xg_ = blk
+        dx_, pt = _ce_dx_pallas(x_, w, lse_, vocab_size, block_n, block_v,
+                                interpret)
+        # on the CPU, where interpret mode inlines a one-block grid, XLA
+        # folds dx's transpose into dW's product, a bf16 form its DotThunk
+        # lacks; on the chip the step is 2 ms shorter with it (PERF.md 6)
+        pt = jax.lax.optimization_barrier(pt)
+        return _ce_dw_pallas(xg_, pt, dw, block_n, block_v, interpret), dx_
+
+    dw = jnp.zeros((v, d), jnp.float32)
+    if rows == n:
+        dw, dx_unscaled = super_block(dw, (x, lse_b, xg))
+    else:  # one P at a time: the loop carries dW from one to the next
+        dw, dx_unscaled = jax.lax.scan(
+            super_block, dw, jax.tree.map(
+                lambda a: a.reshape(n // rows, rows, a.shape[1]),
+                (x, lse_b, xg)))
+        dx_unscaled = dx_unscaled.reshape(n, d)
+    # one-hot terms and upstream scaling in XLA (cheap single passes):
+    # dx -= g * W[tgt]; scatter-add dW[tgt] -= g * x
+    dx = (dx_unscaled - w[targets].astype(jnp.float32)) * g[:, None]
+    dw = dw.at[targets].add(-xg.astype(jnp.float32))
     return dx.astype(x.dtype), dw
 
 
@@ -358,7 +407,17 @@ def _lce_fwd(x, w, targets, vocab_size):
 def _lce_bwd(vocab_size, res, g):
     x, w, targets, lse, used_pallas = res
     if used_pallas:
-        rows = _row_axes(x.shape[0])
+        (n, d), v = x.shape, w.shape[0]
+        rows = _row_axes(n)
+        shards = dispatch.axes_size(rows or ())
+        local = n // shards
+        p_rows = _super_rows(local, v, x.dtype.itemsize,
+                             min(DEFAULT_BLOCK_N, local), P_BUDGET_BYTES)
+        # the forward's entry again, with what the backward holds a shard
+        dispatch.record_choice(
+            "linear_cross_entropy", (n, d, v), "pallas", shards=shards,
+            backward={"p_bytes": p_rows * v * x.dtype.itemsize,
+                      "super_blocks": local // p_rows})
 
         def bwd(x_, w_, t_, lse_, g_):
             dx, dw = _ce_bwd_pallas(

@@ -253,6 +253,82 @@ def test_fused_ce_padded_rows_masked_when_block_divides_vocab():
                                atol=1e-4, rtol=1e-4)
 
 
+def _ce_case(dtype, vocab):
+    """Four row blocks of 64 and two vocabulary blocks of 384."""
+    n, d, v = 256, 128, 768
+    key = jax.random.split(jax.random.PRNGKey(12), 4)
+    x = jax.random.normal(key[0], (n, d), jnp.float32).astype(dtype)
+    w = (jax.random.normal(key[1], (v, d), jnp.float32) * 0.1).astype(dtype)
+    t = jax.random.randint(key[2], (n,), 0, vocab)
+    g = jax.random.uniform(key[3], (n,), jnp.float32) / n
+    return x, w, t, g
+
+
+@pytest.mark.parametrize("dtype,vocab,super_blocks", [
+    (jnp.float32, 700, 1), (jnp.float32, 768, 1), (jnp.bfloat16, 700, 1),
+    (jnp.bfloat16, 768, 1), (jnp.float32, 700, 2), (jnp.bfloat16, 700, 4)],
+    ids=["f32-padded", "f32", "bf16-padded", "bf16", "f32-over-budget",
+         "bf16-far-over-budget"])
+def test_fused_ce_bwd_makes_p_once_and_matches_reference(dtype, vocab,
+                                                         super_blocks):
+    """dx and dW from ONE P, whole or a super-block of rows at a time
+    under a budget its bytes pass, against the reference's gradients."""
+    from ray_tpu.ops import fused_ce
+
+    x, w, t, g = _ce_case(dtype, vocab)
+    (n, _d), v = x.shape, w.shape[0]
+    budget = n // super_blocks * v * x.dtype.itemsize
+    assert fused_ce._super_rows(n, v, x.dtype.itemsize, 64,
+                                budget) == n // super_blocks
+    (_, lse), vjp = jax.vjp(
+        lambda a, b: fused_ce._ce_reference(a, b, t, vocab), x, w)
+    rx, rw = vjp((g, jnp.zeros_like(lse)))
+    dx, dw = fused_ce._ce_bwd_pallas(x, w, t, lse, g, vocab, 64, 384, True,
+                                     p_budget_bytes=budget)
+    assert dx.dtype == dtype and dw.dtype == jnp.float32
+    tol = dict(atol=5e-2 / n, rtol=5e-2) if dtype == jnp.bfloat16 \
+        else dict(atol=1e-5 / n, rtol=1e-3)
+    np.testing.assert_allclose(np.asarray(dx, np.float32),
+                               np.asarray(rx, np.float32), **tol)
+    np.testing.assert_allclose(np.asarray(dw), np.asarray(rw, np.float32),
+                               **tol)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_fused_ce_dx_writes_the_tile_of_p(dtype):
+    """The P^T that `fused_ce_dw` reads: nothing in the padded columns of
+    P (no mask is left in dW's kernel), a distribution in every row."""
+    from ray_tpu.ops import fused_ce
+
+    vocab = 700
+    x, w, t, _g = _ce_case(dtype, vocab)
+    _, lse = fused_ce._ce_reference(x, w, t, vocab)
+    lse_b = jnp.broadcast_to(lse[:, None], (x.shape[0], fused_ce._LANES))
+    _dx, pt = fused_ce._ce_dx_pallas(x, w, lse_b, vocab, 64, 384, True)
+    assert pt.shape == (w.shape[0], x.shape[0]) and pt.dtype == dtype
+    p = np.asarray(pt, np.float32).T
+    assert not p[:, vocab:].any() and (p[:, :vocab] > 0).all()
+    # each entry is rounded to the dtype once: 8 bits of mantissa in bf16
+    np.testing.assert_allclose(p.sum(axis=1), 1.0,
+                               atol=2 ** -8 if dtype == jnp.bfloat16
+                               else 1e-5)
+
+
+def test_fused_ce_backward_form_in_kernel_choices():
+    from ray_tpu.ops import dispatch, fused_ce
+
+    x, w, t, _g = _ce_case(jnp.bfloat16, 700)
+    dispatch.reset_kernel_choices()
+    loss = lambda a, b: jnp.mean(fused_ce.linear_cross_entropy(a, b, t, 700))
+    jax.eval_shape(loss, x, w)  # the forward alone: no backward to name
+    (fwd,) = dispatch.kernel_choices("linear_cross_entropy")
+    assert fwd["choice"] == "pallas" and "backward" not in fwd
+    jax.eval_shape(jax.grad(loss, argnums=(0, 1)), x, w)
+    (both,) = dispatch.kernel_choices("linear_cross_entropy")
+    assert {k: both[k] for k in fwd} == fwd
+    assert both["backward"] == {"p_bytes": 256 * 768 * 2, "super_blocks": 1}
+
+
 def test_flash_fused_bwd_matches_two_pass(monkeypatch):
     """The fused single-pass backward (dq revisiting-accumulator) must
     match the two-pass backward and the XLA reference gradient."""
