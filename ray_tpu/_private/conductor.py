@@ -36,6 +36,7 @@ from .. import exceptions as exc
 from . import serialization
 from .ids import ActorID, NodeID, PlacementGroupID, WorkerID
 from .rpc import ClientPool, RpcServer
+from .telemetry import TelemetryStore
 
 def _worker_start_timeout() -> float:
     from .config import config
@@ -262,95 +263,21 @@ class ConductorHandler:
         self._weights_pending: Dict[Tuple[str, int], Dict[str, Any]] = {}
         self._weight_events: List[Dict[str, Any]] = []
 
-        # Paged KV prefix cache (models/kvcache.py): serving engines
-        # push per-engine stat snapshots + prefix-hit/evict markers;
-        # the conductor only aggregates (no KV bytes ever land here).
-        self._kvcache_stats: Dict[str, Dict[str, Any]] = {}
-        self._kvcache_events: List[Dict[str, Any]] = []
+        # Telemetry (kvcache, online, disagg, ...): the newest snapshot
+        # a component and a ring of instant markers a subsystem, one
+        # row of _private/telemetry.py each, under the store's own lock.
+        self._telemetry = TelemetryStore()
 
-        # Online learning loop (ray_tpu.online): sampler actors, the
-        # rollout buffer, and the learner each push stat snapshots
-        # (keyed by component id) + rollout/publish/swap/ingest markers;
-        # the conductor only aggregates — rollout payloads never land
-        # here.
-        self._online_stats: Dict[str, Dict[str, Any]] = {}
-        self._online_events: List[Dict[str, Any]] = []
-
-        # Disaggregated serving (serve/disagg.py): prefill servers,
-        # decode servers, and routers push stat snapshots (keyed by
-        # component id) + kv_publish/kv_transfer/shed markers; the
-        # conductor only aggregates — KV payload never lands here.
-        self._disagg_stats: Dict[str, Dict[str, Any]] = {}
-        self._disagg_events: List[Dict[str, Any]] = []
-
-        # Global KV plane (serve/kvplane.py): replicas push tier-2
-        # arena / tier-3 adoption snapshots + spill/adopt/directory
-        # markers, and the PREFIX DIRECTORY lives here — (namespace,
-        # digest-chain) -> holder + chunk descriptor, metadata only
-        # (the weight-fabric registry pattern: atomic commit, TTL reap,
-        # keep-last-K GC). KV payload bytes never land here; they ride
-        # the chunk fabric between replicas.
-        self._kvplane_stats: Dict[str, Dict[str, Any]] = {}
-        self._kvplane_events: List[Dict[str, Any]] = []
+        # Global KV plane (serve/kvplane.py): the PREFIX DIRECTORY —
+        # (namespace, digest-chain) -> holder + chunk descriptor,
+        # metadata only (the weight-fabric registry pattern: atomic
+        # commit, TTL reap, keep-last-K GC). KV payload bytes never
+        # land here; they ride the chunk fabric between replicas.
         self._kvplane_dir: Dict[Tuple[str, str], Dict[str, Any]] = {}
         self._kvplane_dir_counters: Dict[str, int] = {
             k: 0 for k in ("publishes", "republishes", "lookups",
                            "directory_hits", "directory_misses",
                            "reaped", "gced", "unpublished")}
-
-        # Serving autoscaler (serve/autoscale.py): policy loops push
-        # status snapshots (targets, decisions, replica-seconds) +
-        # scale_up/scale_down/drain markers; the conductor only
-        # aggregates. util.state.autoscaler_status(), `ray_tpu
-        # autoscale`, and /api/autoscale all read the same aggregate.
-        self._autoscale_stats: Dict[str, Dict[str, Any]] = {}
-        self._autoscale_events: List[Dict[str, Any]] = []
-
-        # Serving-plane fault tolerance (serve/disagg.py failover +
-        # serve/autoscale.py self-healing): routers push failover/shed
-        # accounting, healers push death/replacement/breaker counters.
-        # The failover/replace/breaker_trip instant markers ride the
-        # RESILIENCE event log (they ARE recovery events); this roster
-        # feeds util.state.servefault_status(), `ray_tpu servefault`,
-        # and /api/servefault with one set of numbers.
-        self._servefault_stats: Dict[str, Dict[str, Any]] = {}
-
-        # Multi-tenant LoRA serving (serve/lora.py): adapter pools push
-        # paging snapshots (hits/misses/evictions/swaps, residents),
-        # routers push per-tenant request counters; page_in/evict/swap
-        # markers feed the merged timeline's `lora` lane. One aggregate
-        # feeds util.state.lora_status(), `ray_tpu lora`, /api/lora.
-        self._lora_stats: Dict[str, Dict[str, Any]] = {}
-        self._lora_events: List[Dict[str, Any]] = []
-
-        # HTTP front door (serve/gateway.py): gateway replicas push
-        # request/class/code counters + TTFT windows; QoS gates and
-        # routers push accept/first_byte/preempt/rate_limit/disconnect
-        # markers for the merged timeline's `gateway` lane. One
-        # aggregate feeds util.state.gateway_status(), `ray_tpu
-        # gateway`, and /api/gateway.
-        self._gateway_stats: Dict[str, Dict[str, Any]] = {}
-        self._gateway_events: List[Dict[str, Any]] = []
-
-        # Per-request flight recorder (observability/requests.py):
-        # stores push retention/outcome counters + compact latency
-        # summaries (p99 attribution population) and each KEPT trace
-        # rides the event log so `ray_tpu requests --trace <id>` and
-        # the merged timeline's `requests` lane can replay a request's
-        # phase spans. One aggregate feeds
-        # util.state.requesttrace_status(), `ray_tpu requests`, and
-        # /api/requesttrace.
-        self._requesttrace_stats: Dict[str, Dict[str, Any]] = {}
-        self._requesttrace_events: List[Dict[str, Any]] = []
-
-        # Step-time oracle (observability.roofline): predicted step-time
-        # breakdowns keyed by layout + predicted-vs-measured validation
-        # records (residuals, fitted calibration). One aggregate feeds
-        # util.state.oracle_status(), `ray_tpu oracle`, /api/oracle, and
-        # the merged timeline's predicted-step-time counter track.
-        self._oracle_predictions: Dict[str, Dict[str, Any]] = {}
-        self._oracle_validations: List[Dict[str, Any]] = []
-        self._oracle_events: List[Dict[str, Any]] = []
 
         # MPMD pipelines (ray_tpu.mpmd): stage registry (a pipeline
         # flips "formed" atomically when its LAST stage registers —
@@ -1674,400 +1601,90 @@ class ConductorHandler:
         with self._lock:
             return self._weight_events[-limit:]
 
-    # ------------------------------------------------- paged KV cache
-    # Serving engines (models/engine.py) push their prefix-cache stat
-    # snapshots and instant markers here; util.state.kv_cache_stats(),
-    # `ray_tpu kvcache`, and the dashboard /api/kvcache all read the
-    # same aggregate so every surface reports one set of numbers.
+    # ---------------------------------------------------------- telemetry
+    # Every subsystem that only carries numbers from a component to a
+    # reader is a row of _private/telemetry.py's SUBSYSTEMS, kept in one
+    # TelemetryStore under a lock of its own: components push through
+    # ray_tpu.util.telemetry.Pusher; util.state, `ray_tpu <x>`, the
+    # dashboard's /api/<x> and the merged timeline read these four
+    # methods, so every surface reports one set of numbers. No payload
+    # (KV bytes, rollouts, adapters) ever lands here.
 
-    _KVCACHE_EVENTS_KEPT = 10_000
-    _KVCACHE_TOTAL_KEYS = (
-        "lookups", "hits", "partial_hits", "misses", "reused_tokens",
-        "prefilled_tokens", "spliced_tokens", "inserted_blocks",
-        "evictions", "cow_copies", "invalidations", "admitted",
-        "prefill_admitted", "adopted", "prefill_calls",
-        "spec_proposed", "spec_accepted", "spec_verify_ticks",
-        "spec_emitted_tokens")
+    def report_stats(self, subsystem: str, worker_id: str,
+                     component_id: str, stats: Dict[str, Any]) -> None:
+        self._telemetry.report_stats(subsystem, worker_id, component_id,
+                                     stats)
 
-    def report_kvcache_stats(self, worker_id: str, engine_id: str,
-                             stats: Dict[str, Any]) -> None:
-        if not isinstance(stats, dict):
-            return
-        key = f"{str(worker_id)[:12]}:{engine_id}"
-        with self._lock:
-            self._kvcache_stats[key] = dict(
-                stats, worker_id=worker_id, engine_id=engine_id,
-                ts=time.time())
+    def report_event(self, subsystem: str, event: Dict[str, Any]) -> None:
+        self._telemetry.report_event(subsystem, event)
 
-    def get_kvcache_stats(self) -> Dict[str, Any]:
-        with self._lock:
-            engines = {k: dict(v) for k, v in self._kvcache_stats.items()}
-        totals: Dict[str, Any] = {k: 0 for k in self._KVCACHE_TOTAL_KEYS}
-        for st in engines.values():
-            for k in self._KVCACHE_TOTAL_KEYS:
-                v = st.get(k)
-                if isinstance(v, (int, float)):
-                    totals[k] += v
-        looked = totals["lookups"]
-        totals["hit_rate"] = ((totals["hits"] + totals["partial_hits"])
-                              / looked if looked else 0.0)
-        seen = totals["reused_tokens"] + totals["prefilled_tokens"]
-        totals["token_reuse_rate"] = (totals["reused_tokens"] / seen
-                                      if seen else 0.0)
-        return {"engines": engines, "totals": totals}
+    # servefault's markers (failover / replace / breaker_trip) are
+    # recovery events: they land in the resilience log, beside the
+    # preemption and restart markers, and its events are that slice
+    _SERVEFAULT_EVENT_KINDS = ("failover", "replace", "breaker_trip",
+                               "replica_death", "chaos", "serve_drain")
 
-    def get_speculation_stats(self) -> Dict[str, Any]:
-        """The speculative-decoding slice of the kvcache snapshots
-        (engines embed their spec counters in the same kv_stats push —
-        ONE report channel, so util.state.speculation_stats(),
-        `ray_tpu speculate`, /api/speculation, and Prometheus can never
-        disagree with the kvcache surface). Engines that never enabled
-        speculation are filtered out of `engines` but an all-zero
-        totals dict is still returned."""
-        with self._lock:
-            snaps = {k: dict(v) for k, v in self._kvcache_stats.items()}
-        engines = {k: {
-            "engine_id": v.get("engine_id"),
-            "speculate_k": v.get("speculate_k", 0),
-            "spec_proposed": v.get("spec_proposed", 0),
-            "spec_accepted": v.get("spec_accepted", 0),
-            "spec_verify_ticks": v.get("spec_verify_ticks", 0),
-            "spec_emitted_tokens": v.get("spec_emitted_tokens", 0),
-            "acceptance_rate": v.get("acceptance_rate", 0.0),
-            "tokens_per_verify": v.get("tokens_per_verify", 0.0),
-            "kv_int8": v.get("kv_int8", False),
-            "ts": v.get("ts"),
-        } for k, v in snaps.items() if v.get("speculate_k")}
-        from ray_tpu.util.state import speculation_totals
+    def get_events(self, subsystem: str,
+                   limit: int = 10_000) -> List[Dict[str, Any]]:
+        if subsystem == "servefault":
+            with self._lock:
+                events = list(self._resilience_events)
+            kinds = self._SERVEFAULT_EVENT_KINDS
+            return [e for e in events if e.get("kind") in kinds][-limit:]
+        return self._telemetry.events(subsystem, limit)
 
-        return {"engines": engines,
-                "totals": speculation_totals(engines)}
+    def get_status(self, subsystem: str) -> Dict[str, Any]:
+        out = self._telemetry.status(subsystem)
+        if subsystem == "kvplane":
+            out["directory"] = self._kvplane_directory_summary()
+        return out
 
-    def report_kvcache_event(self, event: Dict[str, Any]) -> None:
-        """Prefix-hit / evict / invalidate instant markers for the
-        merged timeline (observability.timeline)."""
-        if not isinstance(event, dict):
-            return
-        with self._lock:
-            event = dict(event)
-            event.setdefault("ts", time.time())
-            self._kvcache_events.append(event)
-            if len(self._kvcache_events) > self._KVCACHE_EVENTS_KEPT:
-                del self._kvcache_events[
-                    :len(self._kvcache_events)
-                    - self._KVCACHE_EVENTS_KEPT]
-
-    def get_kvcache_events(self, limit: int = 10_000
-                           ) -> List[Dict[str, Any]]:
-        with self._lock:
-            return self._kvcache_events[-limit:]
-
-    # --------------------------------------------- online learning loop
-    # Samplers / the rollout buffer / the learner (ray_tpu.online) push
-    # their stat snapshots and instant markers here; util.state
-    # .online_status(), `ray_tpu online`, and the dashboard /api/online
-    # all read the same aggregate so every surface reports one set of
-    # numbers.
-
-    _ONLINE_EVENTS_KEPT = 10_000
-    _ONLINE_STATS_KEPT = 256
-
-    def report_online_stats(self, worker_id: str, component_id: str,
-                            stats: Dict[str, Any]) -> None:
-        if not isinstance(stats, dict):
-            return
-        with self._lock:
-            self._online_stats[str(component_id)] = dict(
-                stats, worker_id=worker_id,
-                component_id=str(component_id), ts=time.time())
-            # learner snapshots are keyed by unique run ids: without an
-            # eviction bound, every finished run's final snapshot would
-            # accumulate forever. Oldest-first by last report time.
-            while len(self._online_stats) > self._ONLINE_STATS_KEPT:
-                oldest = min(self._online_stats,
-                             key=lambda k:
-                             self._online_stats[k].get("ts", 0.0))
-                del self._online_stats[oldest]
-
-    def get_online_status(self) -> Dict[str, Any]:
-        """One aggregate for every online-loop surface: components
-        grouped by role (sampler / buffer / learner) plus cluster
-        totals (rollouts, rollout tokens, buffer occupancy, learner
-        ingest, worst sampler staleness)."""
-        with self._lock:
-            comps = {k: dict(v) for k, v in self._online_stats.items()}
-        samplers = {k: v for k, v in comps.items()
-                    if v.get("role") == "sampler"}
-        buffers = {k: v for k, v in comps.items()
-                   if v.get("role") == "buffer"}
-        learners = {k: v for k, v in comps.items()
-                    if v.get("role") == "learner"}
-        totals: Dict[str, Any] = {
-            "samplers": len(samplers),
-            "rollouts": sum(int(s.get("rollouts", 0))
-                            for s in samplers.values()),
-            "rollout_tokens": sum(int(s.get("rollout_tokens", 0))
-                                  for s in samplers.values()),
-            "swaps": sum(int(s.get("swap_count", 0))
-                         for s in samplers.values()),
-            "buffer_occupancy": sum(int(b.get("occupancy", 0))
-                                    for b in buffers.values()),
-            "buffer_capacity": sum(int(b.get("capacity", 0))
-                                   for b in buffers.values()),
-            "buffer_rejected": sum(int(b.get("rejected", 0))
-                                   for b in buffers.values()),
-            "ingested_rollouts": sum(int(l.get("ingested_rollouts", 0))
-                                     for l in learners.values()),
-            "ingested_tokens": sum(int(l.get("ingested_tokens", 0))
-                                   for l in learners.values()),
-            "learner_steps": max((int(l.get("steps", 0))
-                                  for l in learners.values()),
-                                 default=0),
-            "published_versions": max((int(l.get("published_version", 0))
-                                       for l in learners.values()),
-                                      default=0),
-        }
-        stale = [s.get("staleness_versions") for s in samplers.values()
-                 if s.get("staleness_versions") is not None]
-        totals["staleness_versions"] = max(stale) if stale else None
-        high = [s.get("max_staleness_versions")
-                for s in samplers.values()
-                if s.get("max_staleness_versions") is not None]
-        totals["max_staleness_versions"] = max(high + stale) \
-            if (high or stale) else None
-        return {"samplers": samplers, "buffers": buffers,
-                "learners": learners, "totals": totals}
-
-    def report_online_event(self, event: Dict[str, Any]) -> None:
-        """Rollout / publish / swap / ingest instant markers for the
-        merged timeline's online lane."""
-        if not isinstance(event, dict):
-            return
-        with self._lock:
-            event = dict(event)
-            event.setdefault("ts", time.time())
-            self._online_events.append(event)
-            if len(self._online_events) > self._ONLINE_EVENTS_KEPT:
-                del self._online_events[
-                    :len(self._online_events)
-                    - self._ONLINE_EVENTS_KEPT]
-
-    def get_online_events(self, limit: int = 10_000
-                          ) -> List[Dict[str, Any]]:
-        with self._lock:
-            return self._online_events[-limit:]
-
-    # ---------------------------------------------- disaggregated serving
-    # Prefill/decode servers and routers (serve/disagg.py) push their
-    # stat snapshots and instant markers here; util.state.disagg_status(),
-    # `ray_tpu disagg`, and the dashboard /api/disagg all read the same
-    # aggregate so every surface reports one set of numbers.
-
-    _DISAGG_EVENTS_KEPT = 10_000
-    _DISAGG_STATS_KEPT = 256
-    # live gauges (router queue depth) only count snapshots at most this
-    # old — routers re-push on every dispatch/complete (0.5s throttle),
-    # so anything older is a dead component's frozen last word
-    _DISAGG_GAUGE_FRESH_S = 15.0
-
-    def report_disagg_stats(self, worker_id: str, component_id: str,
-                            stats: Dict[str, Any]) -> None:
-        if not isinstance(stats, dict):
-            return
-        with self._lock:
-            self._disagg_stats[str(component_id)] = dict(
-                stats, worker_id=worker_id,
-                component_id=str(component_id), ts=time.time())
-            while len(self._disagg_stats) > self._DISAGG_STATS_KEPT:
-                oldest = min(self._disagg_stats,
-                             key=lambda k:
-                             self._disagg_stats[k].get("ts", 0.0))
-                del self._disagg_stats[oldest]
-
-    def get_disagg_status(self) -> Dict[str, Any]:
-        """One aggregate for every disagg surface: components grouped
-        by role (prefill / decode / router) plus cluster totals
-        (transfers, KV bytes split shm/rpc, adoptions, sheds, live
-        queue depth)."""
-        with self._lock:
-            comps = {k: dict(v) for k, v in self._disagg_stats.items()}
-        now = time.time()
-        prefill = {k: v for k, v in comps.items()
-                   if v.get("role") == "prefill"}
-        decode = {k: v for k, v in comps.items()
-                  if v.get("role") == "decode"}
-        routers = {k: v for k, v in comps.items()
-                   if v.get("role") == "router"}
-        totals: Dict[str, Any] = {
-            "prefill_replicas": len(prefill),
-            "decode_replicas": len(decode),
-            "prefills": sum(int(p.get("prefills", 0))
-                            for p in prefill.values()),
-            "prefilled_tokens": sum(int(p.get("prefilled_tokens", 0))
-                                    for p in prefill.values()),
-            "reused_tokens": sum(int(p.get("reused_tokens", 0))
-                                 for p in prefill.values()),
-            "published_transfers": sum(
-                int(p.get("published_transfers", 0))
-                for p in prefill.values()),
-            "published_bytes": sum(int(p.get("published_bytes", 0))
-                                   for p in prefill.values()),
-            "transfers": sum(int(d.get("transfers", 0))
-                             for d in decode.values()),
-            "kv_fetched_bytes": sum(int(d.get("kv_fetched_bytes", 0))
-                                    for d in decode.values()),
-            "shm_bytes": sum(int(d.get("shm_bytes", 0))
-                             for d in decode.values()),
-            "rpc_bytes": sum(int(d.get("rpc_bytes", 0))
-                             for d in decode.values()),
-            "adopted": sum(int(d.get("adopted", 0))
-                           for d in decode.values()),
-            "decoded_tokens": sum(int(d.get("decoded_tokens", 0))
-                                  for d in decode.values()),
-            "dispatched": sum(int(r.get("dispatched", 0))
-                              for r in routers.values()),
-            "shed": sum(int(r.get("shed", 0))
-                        for r in routers.values()),
-            # live gauge, not a counter: a crashed router's final
-            # snapshot (which never expires from the roster) must not
-            # contribute phantom queue depth forever — only snapshots
-            # fresh enough to still describe a living component count.
-            # Monotonic counters above tolerate stale snapshots; this
-            # is the input signal for the planned SLO autoscaler.
-            "queue_depth": sum(
-                int(r.get("pending", 0)) for r in routers.values()
-                if now - float(r.get("ts", 0.0))
-                <= self._DISAGG_GAUGE_FRESH_S),
-            "max_queue_depth_seen": max(
-                (int(r.get("max_pending", 0))
-                 for r in routers.values()), default=0),
-        }
-        return {"prefill": prefill, "decode": decode,
-                "routers": routers, "totals": totals}
-
-    def report_disagg_event(self, event: Dict[str, Any]) -> None:
-        """kv_publish / kv_transfer / shed instant markers for the
-        merged timeline's disagg lane."""
-        if not isinstance(event, dict):
-            return
-        with self._lock:
-            event = dict(event)
-            event.setdefault("ts", time.time())
-            self._disagg_events.append(event)
-            if len(self._disagg_events) > self._DISAGG_EVENTS_KEPT:
-                del self._disagg_events[
-                    :len(self._disagg_events)
-                    - self._DISAGG_EVENTS_KEPT]
-
-    def get_disagg_events(self, limit: int = 10_000
-                          ) -> List[Dict[str, Any]]:
-        with self._lock:
-            return self._disagg_events[-limit:]
+    def get_request_trace(self, request_id: str
+                          ) -> Optional[Dict[str, Any]]:
+        """Replay one request's full trace: the newest kept
+        kind="trace" record under the id, with any kind="phase" child
+        records remote tiers pushed merged in (attempt-tagged, so
+        failover replays read as child spans under the same id)."""
+        rid = str(request_id)
+        events = self._telemetry.events("requesttrace")
+        trace = None
+        for ev in reversed(events):
+            if ev.get("kind") == "trace" \
+                    and str(ev.get("request_id")) == rid:
+                trace = dict(ev)
+                break
+        if trace is None:
+            return None
+        remote = [dict(ev) for ev in events
+                  if ev.get("kind") == "phase"
+                  and str(ev.get("request_id")) == rid]
+        if remote:
+            trace["remote_phases"] = remote
+        return trace
 
     # ------------------------------------------------- global KV plane
-    # Replicas (serve/kvplane.py HostArena owners, routers) push tier-2
-    # arena / tier-3 adoption snapshots and spill/adopt/directory
-    # markers here, and the cluster-wide PREFIX DIRECTORY lives here:
-    # (namespace, digest-chain) -> holder + chunk descriptor — metadata
-    # only, the weight-fabric registry pattern (atomic commit, TTL
-    # reap, keep-last-K GC). util.state.kvplane_status(), `ray_tpu
-    # kvplane`, and the dashboard /api/kvplane all read the same
-    # aggregate so every surface reports one set of numbers.
+    # The cluster-wide PREFIX DIRECTORY lives here: (namespace,
+    # digest-chain) -> holder + chunk descriptor — metadata only, the
+    # weight-fabric registry pattern (atomic commit, TTL reap,
+    # keep-last-K GC). Its commit / reap / GC markers ride the kvplane
+    # row's event ring beside the replicas' own.
 
-    _KVPLANE_EVENTS_KEPT = 10_000
-    _KVPLANE_STATS_KEPT = 256
     _KVPLANE_DIR_KEPT = 4096
-    _KVPLANE_GAUGE_FRESH_S = 15.0
-    _KVPLANE_TOTAL_KEYS = (
-        "spills", "spill_bytes", "tier2_hits", "tier2_probes",
-        "tier2_reused_tokens", "tier2_fetched_bytes",
-        "arena_evictions", "tier3_publishes", "tier3_adopts",
-        "tier3_adopted_blocks", "tier3_reused_tokens",
-        "tier3_fetched_bytes", "directory_hits", "directory_misses",
-        "directory_fallbacks")
 
-    def report_kvplane_stats(self, worker_id: str, component_id: str,
-                             stats: Dict[str, Any]) -> None:
-        if not isinstance(stats, dict):
-            return
-        with self._lock:
-            self._kvplane_stats[str(component_id)] = dict(
-                stats, worker_id=worker_id,
-                component_id=str(component_id), ts=time.time())
-            while len(self._kvplane_stats) > self._KVPLANE_STATS_KEPT:
-                oldest = min(self._kvplane_stats,
-                             key=lambda k:
-                             self._kvplane_stats[k].get("ts", 0.0))
-                del self._kvplane_stats[oldest]
-
-    def get_kvplane_stats(self) -> Dict[str, Any]:
-        with self._lock:
-            comps = {k: dict(v) for k, v in self._kvplane_stats.items()}
-        now = time.time()
-        totals: Dict[str, Any] = {k: 0 for k in self._KVPLANE_TOTAL_KEYS}
-        for st in comps.values():
-            for k in self._KVPLANE_TOTAL_KEYS:
-                v = st.get(k)
-                if isinstance(v, (int, float)):
-                    totals[k] += v
-        # live gauges: only snapshots fresh enough to describe a living
-        # replica count (the disagg queue-depth discipline)
-        totals["arena_entries"] = sum(
-            int(c.get("entries", 0)) for c in comps.values()
-            if now - float(c.get("ts", 0.0))
-            <= self._KVPLANE_GAUGE_FRESH_S)
-        totals["arena_bytes"] = sum(
-            int(c.get("bytes", 0)) for c in comps.values()
-            if now - float(c.get("ts", 0.0))
-            <= self._KVPLANE_GAUGE_FRESH_S)
-        probes = totals["tier2_probes"]
-        totals["tier2_hit_rate"] = (totals["tier2_hits"] / probes
-                                    if probes else 0.0)
-        looks = (totals["directory_hits"]
-                 + totals["directory_misses"])
-        totals["directory_hit_rate"] = (totals["directory_hits"] / looks
-                                        if looks else 0.0)
-        return {"components": comps, "totals": totals}
-
-    def get_kvplane_status(self) -> Dict[str, Any]:
-        """One aggregate for every kvplane surface: per-component
-        snapshots + cluster totals + the prefix directory's summary
-        (entries, bytes, per-namespace counts, commit/reap/GC
-        counters). Directory entry payloads stay out: descriptors are
+    def _kvplane_directory_summary(self) -> Dict[str, Any]:
+        """Entries, bytes, per-namespace counts, commit/reap/GC
+        counters. Directory entry payloads stay out: descriptors are
         metadata, but a status call is a human surface."""
-        out = self.get_kvplane_stats()
         with self._lock:
             per_ns: Dict[str, int] = {}
             total_bytes = 0
             for (ns, _d), e in self._kvplane_dir.items():
                 per_ns[ns] = per_ns.get(ns, 0) + 1
                 total_bytes += int(e.get("nbytes", 0))
-            out["directory"] = {
-                "entries": len(self._kvplane_dir),
-                "nbytes": total_bytes,
-                "namespaces": per_ns,
-                "counters": dict(self._kvplane_dir_counters)}
-        return out
-
-    def report_kvplane_event(self, event: Dict[str, Any]) -> None:
-        """spill / tier2_hit / tier3_publish / tier3_adopt /
-        directory_hit instant markers for the merged timeline's kvplane
-        lane."""
-        if not isinstance(event, dict):
-            return
-        with self._lock:
-            event = dict(event)
-            event.setdefault("ts", time.time())
-            self._kvplane_events.append(event)
-            if len(self._kvplane_events) > self._KVPLANE_EVENTS_KEPT:
-                del self._kvplane_events[
-                    :len(self._kvplane_events)
-                    - self._KVPLANE_EVENTS_KEPT]
-
-    def get_kvplane_events(self, limit: int = 10_000
-                           ) -> List[Dict[str, Any]]:
-        with self._lock:
-            return self._kvplane_events[-limit:]
+            return {"entries": len(self._kvplane_dir),
+                    "nbytes": total_bytes,
+                    "namespaces": per_ns,
+                    "counters": dict(self._kvplane_dir_counters)}
 
     # ---- prefix directory (the weight-fabric registry pattern) ----
 
@@ -2111,11 +1728,11 @@ class ConductorHandler:
                                    or self._kvplane_dir[k]["ts"]))
                 del self._kvplane_dir[oldest]
                 self._kvplane_dir_counters["gced"] += 1
-            ev = {"kind": "tier3_publish", "namespace": key[0],
-                  "digest": key[1][:16], "holder": meta.get("holder"),
-                  "tokens": meta.get("tokens"),
-                  "nbytes": meta.get("nbytes"), "ts": now}
-            self._kvplane_events.append(ev)
+        self.report_event("kvplane", {
+            "kind": "tier3_publish", "namespace": key[0],
+            "digest": key[1][:16], "holder": meta.get("holder"),
+            "tokens": meta.get("tokens"),
+            "nbytes": meta.get("nbytes"), "ts": now})
         self.publish("kvplane", {"event": "publish", "digest": key[1],
                                  "namespace": key[0],
                                  "holder": meta.get("holder")})
@@ -2173,10 +1790,9 @@ class ConductorHandler:
                     del self._kvplane_dir[key]
                     self._kvplane_dir_counters["reaped"] += 1
                     reaped.append(key)
-            if reaped:
-                self._kvplane_events.append(
-                    {"kind": "reap", "entries": len(reaped),
-                     "ts": time.time()})
+        if reaped:
+            self.report_event("kvplane",
+                              {"kind": "reap", "entries": len(reaped)})
         return len(reaped)
 
     def kvplane_gc(self, keep: int,
@@ -2199,537 +1815,10 @@ class ConductorHandler:
                     del self._kvplane_dir[k]
                     self._kvplane_dir_counters["gced"] += 1
                     dropped += 1
-            if dropped:
-                self._kvplane_events.append(
-                    {"kind": "gc", "entries": dropped,
-                     "ts": time.time()})
+        if dropped:
+            self.report_event("kvplane",
+                              {"kind": "gc", "entries": dropped})
         return dropped
-
-    # ------------------------------------------------ HTTP front door
-    # Gateway replicas (serve/gateway.py) push request counters by
-    # priority class and status code plus TTFT windows; the QoS gate
-    # and routers push instant markers (accept / first_byte / preempt /
-    # rate_limit / disconnect) for the merged timeline's gateway lane.
-    # util.state.gateway_status(), `ray_tpu gateway`, and the dashboard
-    # /api/gateway all read the same aggregate.
-
-    _GATEWAY_EVENTS_KEPT = 10_000
-    _GATEWAY_STATS_KEPT = 64
-
-    def report_gateway_stats(self, worker_id: str, component_id: str,
-                             stats: Dict[str, Any]) -> None:
-        if not isinstance(stats, dict):
-            return
-        with self._lock:
-            self._gateway_stats[str(component_id)] = dict(
-                stats, worker_id=worker_id,
-                component_id=str(component_id), ts=time.time())
-            while len(self._gateway_stats) > self._GATEWAY_STATS_KEPT:
-                oldest = min(self._gateway_stats,
-                             key=lambda k:
-                             self._gateway_stats[k].get("ts", 0.0))
-                del self._gateway_stats[oldest]
-
-    def get_gateway_status(self) -> Dict[str, Any]:
-        """One aggregate for every gateway surface: per-replica
-        snapshots plus cluster totals (requests by outcome, per-class
-        accept/complete/shed/disconnect split, status-code histogram,
-        preemptions)."""
-        with self._lock:
-            gateways = {k: dict(v)
-                        for k, v in self._gateway_stats.items()}
-        by_class: Dict[str, Dict[str, int]] = {}
-        by_code: Dict[str, int] = {}
-        for g in gateways.values():
-            for cls, row in (g.get("by_class") or {}).items():
-                agg = by_class.setdefault(cls, {})
-                for k, v in row.items():
-                    agg[k] = agg.get(k, 0) + int(v)
-            for code, n in (g.get("by_code") or {}).items():
-                by_code[code] = by_code.get(code, 0) + int(n)
-        totals: Dict[str, Any] = {
-            "gateways": len(gateways),
-            "by_class": by_class,
-            "by_code": by_code,
-        }
-        for key in ("accepted", "completed", "streamed", "tokens_out",
-                    "rate_limited", "sheds", "disconnects", "errors",
-                    "preemptions"):
-            totals[key] = sum(int(g.get(key, 0))
-                              for g in gateways.values())
-        return {"gateways": gateways, "totals": totals}
-
-    def report_gateway_event(self, event: Dict[str, Any]) -> None:
-        """accept / first_byte / preempt / rate_limit / disconnect
-        instant markers for the merged timeline's gateway lane."""
-        if not isinstance(event, dict):
-            return
-        with self._lock:
-            event = dict(event)
-            event.setdefault("ts", time.time())
-            self._gateway_events.append(event)
-            if len(self._gateway_events) > self._GATEWAY_EVENTS_KEPT:
-                del self._gateway_events[
-                    :len(self._gateway_events)
-                    - self._GATEWAY_EVENTS_KEPT]
-
-    def get_gateway_events(self, limit: int = 10_000
-                           ) -> List[Dict[str, Any]]:
-        with self._lock:
-            return self._gateway_events[-limit:]
-
-    # ------------------------------------------ per-request flight recorder
-    # RequestTraceStores (observability/requests.py) push retention /
-    # outcome counters plus a compact per-request summary window (the
-    # unbiased p99-attribution population); every KEPT full trace rides
-    # the event log as a kind="trace" record so `ray_tpu requests
-    # --trace <id>` and the merged timeline's `requests` lane replay
-    # its phase spans. Remote tier hops (actor-mode prefill/decode)
-    # push kind="phase" child records under the same request id.
-    # util.state.requesttrace_status(), `ray_tpu requests`, and
-    # /api/requesttrace all read the same aggregate.
-
-    _REQTRACE_EVENTS_KEPT = 10_000
-    _REQTRACE_STATS_KEPT = 64
-
-    def report_requesttrace_stats(self, worker_id: str,
-                                  component_id: str,
-                                  stats: Dict[str, Any]) -> None:
-        if not isinstance(stats, dict):
-            return
-        with self._lock:
-            self._requesttrace_stats[str(component_id)] = dict(
-                stats, worker_id=worker_id,
-                component_id=str(component_id), ts=time.time())
-            while len(self._requesttrace_stats) \
-                    > self._REQTRACE_STATS_KEPT:
-                oldest = min(self._requesttrace_stats,
-                             key=lambda k:
-                             self._requesttrace_stats[k].get("ts", 0.0))
-                del self._requesttrace_stats[oldest]
-
-    def get_requesttrace_status(self) -> Dict[str, Any]:
-        """One aggregate for every request-trace surface: per-store
-        snapshots, cluster totals (completed/kept/dropped, outcome
-        tally, replay + preempt counts), the cluster-wide slowest
-        list, and a p99-attribution report recomputed over the merged
-        per-component summary windows so the tail owner is named from
-        the whole population, not one process's slice."""
-        with self._lock:
-            stores = {k: dict(v)
-                      for k, v in self._requesttrace_stats.items()}
-        totals: Dict[str, Any] = {"stores": len(stores)}
-        for key in ("completed", "kept", "dropped", "replayed_requests",
-                    "preempted_requests"):
-            totals[key] = sum(int(s.get(key, 0))
-                              for s in stores.values())
-        outcomes: Dict[str, int] = {}
-        slowest: List[Dict[str, Any]] = []
-        merged_recent: List[Dict[str, Any]] = []
-        for s in stores.values():
-            for k, v in (s.get("outcomes") or {}).items():
-                outcomes[k] = outcomes.get(k, 0) + int(v)
-            slowest.extend(s.get("slowest") or [])
-            merged_recent.extend(s.get("recent") or [])
-        totals["outcomes"] = outcomes
-        totals["slowest_ms"] = max(
-            [float(s.get("slowest_ms", 0.0)) for s in stores.values()],
-            default=0.0)
-        slowest.sort(key=lambda r: float(r.get("total_ms") or 0.0),
-                     reverse=True)
-        from ray_tpu.observability.requests import p99_attribution
-
-        return {"stores": stores, "totals": totals,
-                "slowest": slowest[:32],
-                "attribution": p99_attribution(merged_recent)}
-
-    def report_requesttrace_event(self, event: Dict[str, Any]) -> None:
-        """kind="trace" kept-trace records (full phase breakdowns) and
-        kind="phase" remote child spans for the merged timeline's
-        requests lane."""
-        if not isinstance(event, dict):
-            return
-        with self._lock:
-            event = dict(event)
-            event.setdefault("ts", time.time())
-            self._requesttrace_events.append(event)
-            if len(self._requesttrace_events) \
-                    > self._REQTRACE_EVENTS_KEPT:
-                del self._requesttrace_events[
-                    :len(self._requesttrace_events)
-                    - self._REQTRACE_EVENTS_KEPT]
-
-    def get_requesttrace_events(self, limit: int = 10_000
-                                ) -> List[Dict[str, Any]]:
-        with self._lock:
-            return self._requesttrace_events[-limit:]
-
-    def get_request_trace(self, request_id: str
-                          ) -> Optional[Dict[str, Any]]:
-        """Replay one request's full trace: the newest kept
-        kind="trace" record under the id, with any kind="phase" child
-        records remote tiers pushed merged in (attempt-tagged, so
-        failover replays read as child spans under the same id)."""
-        rid = str(request_id)
-        with self._lock:
-            events = list(self._requesttrace_events)
-        trace = None
-        for ev in reversed(events):
-            if ev.get("kind") == "trace" \
-                    and str(ev.get("request_id")) == rid:
-                trace = dict(ev)
-                break
-        if trace is None:
-            return None
-        remote = [dict(ev) for ev in events
-                  if ev.get("kind") == "phase"
-                  and str(ev.get("request_id")) == rid]
-        if remote:
-            trace["remote_phases"] = remote
-        return trace
-
-    # ------------------------------------------ serving fault tolerance
-    # Disagg routers (failover/shed accounting) and self-healers
-    # (death/replacement/breaker counters) push snapshots here;
-    # util.state.servefault_status(), `ray_tpu servefault`, and the
-    # dashboard /api/servefault all read the same aggregate. The
-    # instant markers (failover / replace / breaker_trip) land in the
-    # resilience event log — recovery events belong in the resilience
-    # lane of the merged timeline.
-
-    _SERVEFAULT_STATS_KEPT = 128
-    _SERVEFAULT_EVENT_KINDS = ("failover", "replace", "breaker_trip",
-                               "replica_death", "chaos", "serve_drain")
-
-    def report_servefault_stats(self, worker_id: str, component_id: str,
-                                stats: Dict[str, Any]) -> None:
-        if not isinstance(stats, dict):
-            return
-        with self._lock:
-            self._servefault_stats[str(component_id)] = dict(
-                stats, worker_id=worker_id,
-                component_id=str(component_id), ts=time.time())
-            while len(self._servefault_stats) > \
-                    self._SERVEFAULT_STATS_KEPT:
-                oldest = min(self._servefault_stats,
-                             key=lambda k:
-                             self._servefault_stats[k].get("ts", 0.0))
-                del self._servefault_stats[oldest]
-
-    def get_servefault_status(self) -> Dict[str, Any]:
-        """One aggregate for every servefault surface: router snapshots
-        (failovers by phase, sheds by cause, corpses removed) + healer
-        snapshots (deaths, replacements, breaker) + cluster totals."""
-        with self._lock:
-            comps = {k: dict(v)
-                     for k, v in self._servefault_stats.items()}
-        routers = {k: v for k, v in comps.items()
-                   if v.get("role") == "router"}
-        healers = {k: v for k, v in comps.items()
-                   if v.get("role") == "healer"}
-        tiers = ("prefill", "decode")
-
-        def _sum_tiered(snaps, key):
-            return {t: sum(int((s.get(key) or {}).get(t, 0))
-                           for s in snaps.values()) for t in tiers}
-
-        sheds_by_cause: Dict[str, int] = {}
-        for r in routers.values():
-            for cause, n in (r.get("sheds_by_cause") or {}).items():
-                sheds_by_cause[cause] = \
-                    sheds_by_cause.get(cause, 0) + int(n)
-        totals: Dict[str, Any] = {
-            "routers": len(routers),
-            "healers": len(healers),
-            "failovers": _sum_tiered(routers, "failovers"),
-            "failovers_total": sum(
-                sum((r.get("failovers") or {}).values())
-                for r in routers.values()),
-            "failover_requests": sum(
-                int(r.get("failover_requests", 0))
-                for r in routers.values()),
-            "sheds_by_cause": sheds_by_cause,
-            "removed_dead": _sum_tiered(routers, "removed_dead"),
-            "deaths": _sum_tiered(healers, "deaths"),
-            "replacements": _sum_tiered(healers, "replacements"),
-            "replacements_total": sum(
-                sum((h.get("replacements") or {}).values())
-                for h in healers.values()),
-            "replacements_blocked": sum(
-                int(h.get("replacements_blocked", 0))
-                for h in healers.values()),
-            "breaker_trips": sum(int(h.get("breaker_trips", 0))
-                                 for h in healers.values()),
-            "drains_reaped": sum(int(h.get("drains_reaped", 0))
-                                 for h in healers.values()),
-        }
-        return {"routers": routers, "healers": healers,
-                "totals": totals}
-
-    def get_servefault_events(self, limit: int = 10_000
-                              ) -> List[Dict[str, Any]]:
-        """The servefault slice of the resilience event log (the
-        markers live there — one lane, one set of numbers)."""
-        with self._lock:
-            events = list(self._resilience_events)
-        kinds = self._SERVEFAULT_EVENT_KINDS
-        return [e for e in events if e.get("kind") in kinds][-limit:]
-
-    # -------------------------------------------- multi-tenant LoRA
-    # Adapter pools (serve/lora.py AdapterPool — one per prefill /
-    # decode replica or colocated engine) push paging snapshots,
-    # routers push per-tenant request counters;
-    # util.state.lora_status(), `ray_tpu lora`, and /api/lora all read
-    # the same aggregate so every surface reports one set of numbers.
-
-    _LORA_STATS_KEPT = 256
-    _LORA_EVENTS_KEPT = 10_000
-
-    def report_lora_stats(self, worker_id: str, component_id: str,
-                          stats: Dict[str, Any]) -> None:
-        if not isinstance(stats, dict):
-            return
-        with self._lock:
-            self._lora_stats[str(component_id)] = dict(
-                stats, worker_id=worker_id,
-                component_id=str(component_id), ts=time.time())
-            while len(self._lora_stats) > self._LORA_STATS_KEPT:
-                oldest = min(self._lora_stats,
-                             key=lambda k:
-                             self._lora_stats[k].get("ts", 0.0))
-                del self._lora_stats[oldest]
-
-    def get_lora_status(self) -> Dict[str, Any]:
-        """One aggregate for every lora surface: pool snapshots (pool
-        paging counters + residents), router tenant counters, plus
-        cluster totals (acquires/hits/misses/evictions/swaps/page-in
-        bytes, per-tenant request rollup)."""
-        with self._lock:
-            comps = {k: dict(v) for k, v in self._lora_stats.items()}
-        pools = {k: v for k, v in comps.items()
-                 if v.get("role") == "pool"}
-        routers = {k: v for k, v in comps.items()
-                   if v.get("role") == "router"}
-        tenants: Dict[str, Dict[str, Any]] = {}
-        for p in pools.values():
-            for t, ts in (p.get("tenants") or {}).items():
-                agg = tenants.setdefault(
-                    t, {"hits": 0, "misses": 0, "evictions": 0,
-                        "swaps": 0, "dispatched": 0, "completed": 0,
-                        "shed": 0, "slo_misses": 0})
-                for key in ("hits", "misses", "evictions", "swaps"):
-                    agg[key] += int(ts.get(key, 0))
-        for r in routers.values():
-            for t, ts in (r.get("tenants") or {}).items():
-                agg = tenants.setdefault(
-                    t, {"hits": 0, "misses": 0, "evictions": 0,
-                        "swaps": 0, "dispatched": 0, "completed": 0,
-                        "shed": 0, "slo_misses": 0})
-                for key in ("dispatched", "completed", "shed",
-                            "slo_misses"):
-                    agg[key] += int(ts.get(key, 0))
-        acquires = sum(int(p.get("acquires", 0))
-                       for p in pools.values())
-        hits = sum(int(p.get("hits", 0)) for p in pools.values())
-        totals: Dict[str, Any] = {
-            "pools": len(pools),
-            "routers": len(routers),
-            "slots": sum(int(p.get("slots", 0))
-                         for p in pools.values()),
-            "resident": sum(int(p.get("resident", 0))
-                            for p in pools.values()),
-            "pinned": sum(int(p.get("pinned", 0))
-                          for p in pools.values()),
-            "acquires": acquires,
-            "hits": hits,
-            "misses": sum(int(p.get("misses", 0))
-                          for p in pools.values()),
-            "evictions": sum(int(p.get("evictions", 0))
-                             for p in pools.values()),
-            "swaps": sum(int(p.get("swaps", 0))
-                         for p in pools.values()),
-            "page_in_bytes": sum(int(p.get("page_in_bytes", 0))
-                                 for p in pools.values()),
-            "hit_rate": hits / acquires if acquires else 0.0,
-            "tenants": len(tenants),
-        }
-        return {"pools": pools, "routers": routers,
-                "tenants": tenants, "totals": totals}
-
-    def report_lora_event(self, event: Dict[str, Any]) -> None:
-        """page_in / evict / swap instant markers for the merged
-        timeline's lora lane."""
-        if not isinstance(event, dict):
-            return
-        with self._lock:
-            event = dict(event)
-            event.setdefault("ts", time.time())
-            self._lora_events.append(event)
-            if len(self._lora_events) > self._LORA_EVENTS_KEPT:
-                del self._lora_events[
-                    :len(self._lora_events) - self._LORA_EVENTS_KEPT]
-
-    def get_lora_events(self, limit: int = 10_000
-                        ) -> List[Dict[str, Any]]:
-        with self._lock:
-            return self._lora_events[-limit:]
-
-    # ------------------------------------------------ serving autoscaler
-    # serve/autoscale.py policy loops push status snapshots and
-    # scale_up/scale_down/drain instant markers here;
-    # util.state.autoscaler_status(), `ray_tpu autoscale`, and the
-    # dashboard /api/autoscale all read the same aggregate so every
-    # surface reports one set of numbers.
-
-    _AUTOSCALE_STATS_KEPT = 64
-    _AUTOSCALE_EVENTS_KEPT = 10_000
-
-    def report_autoscale_stats(self, worker_id: str, autoscaler_id: str,
-                               stats: Dict[str, Any]) -> None:
-        if not isinstance(stats, dict):
-            return
-        with self._lock:
-            self._autoscale_stats[str(autoscaler_id)] = dict(
-                stats, worker_id=worker_id,
-                autoscaler_id=str(autoscaler_id), ts=time.time())
-            while len(self._autoscale_stats) > self._AUTOSCALE_STATS_KEPT:
-                oldest = min(self._autoscale_stats,
-                             key=lambda k:
-                             self._autoscale_stats[k].get("ts", 0.0))
-                del self._autoscale_stats[oldest]
-
-    def get_autoscale_status(self) -> Dict[str, Any]:
-        """One aggregate for every autoscale surface: per-loop status
-        snapshots plus cluster totals (decisions by direction, drains,
-        replica-seconds per tier, current targets)."""
-        with self._lock:
-            loops = {k: dict(v)
-                     for k, v in self._autoscale_stats.items()}
-        totals: Dict[str, Any] = {
-            "autoscalers": len(loops),
-            "scale_ups": sum(sum(s.get("scale_ups", {}).values())
-                             for s in loops.values()),
-            "scale_downs": sum(sum(s.get("scale_downs", {}).values())
-                               for s in loops.values()),
-            "drains_completed": sum(int(s.get("drains_completed", 0))
-                                    for s in loops.values()),
-            "drains_forced": sum(int(s.get("drains_forced", 0))
-                                 for s in loops.values()),
-            "replica_seconds": {
-                tier: round(sum(
-                    float(s.get("replica_seconds", {}).get(tier, 0.0))
-                    for s in loops.values()), 3)
-                for tier in ("prefill", "decode")},
-            "active_replicas": {
-                tier: sum(int(s.get(f"{tier}_active", 0))
-                          for s in loops.values())
-                for tier in ("prefill", "decode")},
-        }
-        return {"autoscalers": loops, "totals": totals}
-
-    def report_autoscale_event(self, event: Dict[str, Any]) -> None:
-        """scale_up / scale_down / drain instant markers for the merged
-        timeline's autoscale lane."""
-        if not isinstance(event, dict):
-            return
-        with self._lock:
-            event = dict(event)
-            event.setdefault("ts", time.time())
-            self._autoscale_events.append(event)
-            if len(self._autoscale_events) > self._AUTOSCALE_EVENTS_KEPT:
-                del self._autoscale_events[
-                    :len(self._autoscale_events)
-                    - self._AUTOSCALE_EVENTS_KEPT]
-
-    def get_autoscale_events(self, limit: int = 10_000
-                             ) -> List[Dict[str, Any]]:
-        with self._lock:
-            return self._autoscale_events[-limit:]
-
-    # ------------------------------------------------- step-time oracle
-    # observability.roofline pushes layout predictions and validation
-    # records here; util.state.oracle_status(), `ray_tpu oracle`, and
-    # the dashboard /api/oracle all read the same aggregate so every
-    # surface reports one set of numbers. Events feed the merged
-    # timeline's predicted-step-time counter track.
-
-    _ORACLE_PREDICTIONS_KEPT = 256
-    _ORACLE_VALIDATIONS_KEPT = 1024
-    _ORACLE_EVENTS_KEPT = 10_000
-
-    def _oracle_event_locked(self, event: Dict[str, Any]) -> None:
-        event.setdefault("ts", time.time())
-        self._oracle_events.append(event)
-        if len(self._oracle_events) > self._ORACLE_EVENTS_KEPT:
-            del self._oracle_events[
-                :len(self._oracle_events) - self._ORACLE_EVENTS_KEPT]
-
-    def report_oracle_prediction(self, worker_id: str, layout: str,
-                                 prediction: Dict[str, Any]) -> None:
-        if not isinstance(prediction, dict):
-            return
-        with self._lock:
-            rec = dict(prediction, layout=str(layout),
-                       worker_id=worker_id, ts=time.time())
-            self._oracle_predictions[str(layout)] = rec
-            while len(self._oracle_predictions) > \
-                    self._ORACLE_PREDICTIONS_KEPT:
-                oldest = min(self._oracle_predictions,
-                             key=lambda k:
-                             self._oracle_predictions[k].get("ts", 0.0))
-                del self._oracle_predictions[oldest]
-            self._oracle_event_locked(dict(
-                kind="prediction", layout=str(layout),
-                predicted_step_ms=prediction.get("predicted_step_ms"),
-                device_step_ms=prediction.get("device_step_ms"),
-                ici_wait_ms=prediction.get("ici_wait_ms"),
-                dcn_wait_ms=prediction.get("dcn_wait_ms")))
-
-    def report_oracle_validation(self, worker_id: str,
-                                 rec: Dict[str, Any]) -> None:
-        if not isinstance(rec, dict):
-            return
-        with self._lock:
-            rec = dict(rec, worker_id=worker_id, ts=time.time())
-            self._oracle_validations.append(rec)
-            if len(self._oracle_validations) > \
-                    self._ORACLE_VALIDATIONS_KEPT:
-                del self._oracle_validations[
-                    :len(self._oracle_validations)
-                    - self._ORACLE_VALIDATIONS_KEPT]
-            self._oracle_event_locked(dict(
-                kind="validation", layout=rec.get("layout"),
-                run_id=rec.get("run_id"),
-                calibration=rec.get("calibration"),
-                residuals=rec.get("residuals"),
-                n_steps=rec.get("n_steps")))
-
-    def get_oracle_status(self) -> Dict[str, Any]:
-        """One aggregate for every oracle surface: the latest prediction
-        per layout, the validation tail, and totals (counts + the last
-        fitted calibration and its worst phase residual)."""
-        with self._lock:
-            preds = {k: dict(v)
-                     for k, v in self._oracle_predictions.items()}
-            vals = [dict(v) for v in self._oracle_validations[-100:]]
-            n_validations = len(self._oracle_validations)
-        last = vals[-1] if vals else {}
-        residuals = last.get("residuals") or {}
-        totals: Dict[str, Any] = {
-            "layouts": len(preds),
-            "validations": n_validations,
-            "last_calibration": last.get("calibration"),
-            "worst_residual_ratio": max(
-                (float(r) for r in residuals.values()), default=None,
-                key=lambda r: abs(r - 1.0)),
-        }
-        return {"predictions": preds, "validations": vals,
-                "totals": totals}
-
-    def get_oracle_events(self, limit: int = 10_000
-                          ) -> List[Dict[str, Any]]:
-        with self._lock:
-            return self._oracle_events[-limit:]
 
     # ------------------------------------------------------ MPMD pipelines
     # ray_tpu.mpmd: stage registry, channel mailbox, per-stage stats and

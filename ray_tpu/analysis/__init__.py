@@ -18,7 +18,6 @@ catches:
   methods, and host syncs inside jitted functions (`astlint`);
 - cross-module invariants (`invariants`): lock-discipline races
   (a `self._*` attr mutated both under `with self._lock` and bare),
-  conductor↔CLI↔dashboard↔metrics↔timeline surface-parity drift,
   the env-knob registry (`RAY_TPU_*` reads — hot-path re-parses,
   inconsistent defaults, undocumented knobs), and jitted pool updaters
   missing `donate_argnums`.
@@ -48,13 +47,9 @@ from .findings import (  # noqa: F401
 )
 from .astlint import lint_file, lint_path, lint_source  # noqa: F401
 from .invariants import (  # noqa: F401
-    PARITY_WAIVERS,
-    SURFACE_ALIASES,
     analyze_invariants,
     check_env_knobs,
-    check_surface_parity,
     collect_env_reads,
-    discover_subsystems,
     format_knob_table,
     knob_table,
     scan_env_reads,

@@ -62,10 +62,6 @@ RULES: Dict[str, str] = {
         "in a lock-using class, a self._* attribute mutated both "
         "under `with self._lock` and outside it — a data race "
         "candidate, both sites cited",
-    "surface-parity":
-        "a conductor subsystem missing part of the full surface "
-        "treatment (state accessor == CLI == dashboard == Prometheus "
-        "== timeline lane)",
     "env-knob-inconsistent-default":
         "one RAY_TPU_* knob parsed with different literal defaults at "
         "different sites",

@@ -4,7 +4,7 @@ The per-file AST rules in `astlint` catch bugs a single screenful of
 code can prove. The invariants here are different: each one is a
 repo-wide convention whose violation is only visible when you look at
 SEVERAL sites (or several modules) at once — the way race detectors and
-aliasing analyses work in mature runtimes. Four rule families:
+aliasing analyses work in mature runtimes. Three rule families:
 
 - **lock-discipline** (warning, per class): in a class that guards
   state with ``with self._lock`` (or a Condition wrapping it), every
@@ -17,19 +17,6 @@ aliasing analyses work in mature runtimes. Four rule families:
   ``*_locked`` name suffix. Deliberate lock-free reads/writes (e.g.
   monotonic counters read for telemetry) suppress with
   ``# shardlint: ok=lock-free`` plus a one-line justification.
-
-- **surface-parity** (error, per subsystem): the ROADMAP convention —
-  "every new subsystem gets the full surface treatment" — as a lint.
-  Every conductor stats aggregation (``report_<X>_stats`` /
-  ``get_<X>_status`` pair) must come with the matching
-  ``util.state.<X>_status()`` accessor, ``ray_tpu <X>`` CLI
-  subcommand, dashboard ``/api/<X>`` route, ``ray_tpu_<X>_*``
-  Prometheus family, and merged-timeline lane
-  (``<X>_trace_events``). Names are matched fuzzily (``kvcache`` ↔
-  ``kv_cache_stats``, ``speculation`` ↔ ``speculate``) plus a small
-  documented alias table for surfaces that deliberately share
-  (``servefault`` recovery markers ride the ``resilience`` timeline
-  lane) or abbreviate (``ray_tpu_spec_*``).
 
 - **env-knob registry** (warnings): every ``RAY_TPU_*`` environment
   read in the package, cross-referenced. Three rules:
@@ -66,9 +53,9 @@ import ast
 import os
 import re
 from dataclasses import dataclass, field
-from typing import Dict, Iterable, List, Optional, Sequence, Set, Tuple
+from typing import Dict, List, Optional, Sequence, Set, Tuple
 
-from .findings import ERROR, Finding, WARNING
+from .findings import Finding, WARNING
 
 # ------------------------------------------------------- lock-discipline
 
@@ -519,200 +506,6 @@ def format_knob_table(rows: Sequence[Dict[str, object]],
     return "\n".join(out)
 
 
-# --------------------------------------------------------- surface-parity
-
-# Subsystems whose push/get channel predates the surface convention and
-# is deliberately CLI/dashboard-less — each waiver carries its reason.
-PARITY_WAIVERS: Dict[str, str] = {
-    "task": "core task-event channel; surfaced via the timeline/"
-            "summary endpoints, not a per-subsystem page",
-    "rpc": "control-plane dispatch diagnostics (get_rpc_stats) — an "
-           "internal latency probe, deliberately unexposed",
-}
-
-# (subsystem, surface) -> extra accepted stems, for surfaces that
-# deliberately abbreviate or share. Everything else matches fuzzily.
-SURFACE_ALIASES: Dict[Tuple[str, str], Tuple[str, ...]] = {
-    # engines push spec counters under ray_tpu_spec_* (the metric names
-    # predate the subsystem name)
-    ("speculation", "prometheus"): ("spec",),
-    # recovery markers share one lane whether they heal a training gang
-    # or a serving tier (see observability/timeline.py docstring)
-    ("servefault", "timeline"): ("resilience",),
-    # the flight recorder's metric family abbreviates to reqtrace
-    # (ray_tpu_reqtrace_phase_ms etc — observability/requests.py)
-    ("requesttrace", "prometheus"): ("reqtrace",),
-}
-
-_SURFACE_FILES = {
-    "state": os.path.join("util", "state.py"),
-    "cli": os.path.join("scripts", "cli.py"),
-    "dashboard": os.path.join("dashboard", "__init__.py"),
-    "timeline": os.path.join("observability", "timeline.py"),
-}
-
-_SURFACE_FIX = {
-    "state": "add a util.state.<x>_status() accessor reading the "
-             "conductor aggregate",
-    "cli": "add the `ray_tpu <x>` subcommand (scripts/cli.py) over the "
-           "state accessor",
-    "dashboard": "add the dashboard /api/<x> route over the same "
-                 "aggregate",
-    "prometheus": "emit a ray_tpu_<x>_* Prometheus family from the "
-                  "subsystem's metrics module",
-    "timeline": "add a <x>_trace_events lane to "
-                "observability/timeline.py and merge it in "
-                "merged_chrome_trace",
-}
-
-
-def _norm(name: str) -> str:
-    return re.sub(r"[^a-z0-9]", "", name.lower())
-
-
-def _stem_matches(stem: str, candidate: str) -> bool:
-    """Fuzzy subsystem-name match: normalized common prefix covers the
-    shorter name entirely (>= 4 chars), or all but a short suffix of
-    both (kvcache ~ kv_cache_stats, speculation ~ speculate)."""
-    a, b = _norm(stem), _norm(candidate)
-    if not a or not b:
-        return False
-    lcp = 0
-    for x, y in zip(a, b):
-        if x != y:
-            break
-        lcp += 1
-    if lcp == min(len(a), len(b)) and lcp >= 4:
-        return True
-    return lcp >= max(5, min(len(a), len(b)) - 3)
-
-
-def _match_any(stem: str, surface: str,
-               candidates: Iterable[str]) -> bool:
-    stems = (stem,) + SURFACE_ALIASES.get((stem, surface), ())
-    return any(_stem_matches(s, c) for s in stems for c in candidates)
-
-
-_REPORT_RE = re.compile(r"^report_(\w+?)_(stats|events?)$")
-_GET_RE = re.compile(r"^get_(\w+?)_(status|stats)$")
-
-
-def discover_subsystems(conductor_tree: ast.AST) -> Dict[str, int]:
-    """Subsystem stem -> defining line, discovered from the conductor's
-    report/get method names. A stem qualifies via a worker-push channel
-    (report_<X>_stats / report_<X>_event) or a status aggregate
-    (get_<X>_status / get_<X>_stats); waived stems are dropped."""
-    stems: Dict[str, int] = {}
-    for node in ast.walk(conductor_tree):
-        if not isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef)):
-            continue
-        m = _GET_RE.match(node.name) or _REPORT_RE.match(node.name)
-        if not m:
-            continue
-        stem = m.group(1)
-        if stem in PARITY_WAIVERS:
-            continue
-        if stem not in stems or node.lineno < stems[stem]:
-            stems[stem] = node.lineno
-    return stems
-
-
-def check_surface_parity(package_root: str) -> List[Finding]:
-    """Assert every conductor subsystem ships the full surface
-    treatment: state accessor, CLI subcommand, dashboard route,
-    Prometheus family, merged-timeline lane. One ERROR per missing
-    surface, anchored at the subsystem's conductor method so the
-    convention fails review as a lint, not folklore."""
-    conductor_path = os.path.join(package_root, "_private",
-                                  "conductor.py")
-    if not os.path.isfile(conductor_path):
-        return []
-    trees: Dict[str, Tuple[str, ast.AST]] = {}
-    for role, rel in _SURFACE_FILES.items():
-        full = os.path.join(package_root, rel)
-        if not os.path.isfile(full):
-            return []  # not a ray_tpu-shaped tree: rule is inert
-        with open(full, encoding="utf-8", errors="replace") as fh:
-            src = fh.read()
-        try:
-            trees[role] = (full, ast.parse(src))
-        except SyntaxError:
-            return []
-    with open(conductor_path, encoding="utf-8",
-              errors="replace") as fh:
-        try:
-            conductor_tree = ast.parse(fh.read())
-        except SyntaxError:
-            return []
-    stems = discover_subsystems(conductor_tree)
-    if not stems:
-        return []
-
-    # candidate names per surface
-    state_defs = [n.name for n in ast.walk(trees["state"][1])
-                  if isinstance(n, (ast.FunctionDef,
-                                    ast.AsyncFunctionDef))]
-    cli_cmds = []
-    for node in ast.walk(trees["cli"][1]):
-        if isinstance(node, ast.Call) and \
-                isinstance(node.func, ast.Attribute) and \
-                node.func.attr == "add_parser" and node.args and \
-                isinstance(node.args[0], ast.Constant):
-            cli_cmds.append(str(node.args[0].value))
-    api_routes = []
-    for node in ast.walk(trees["dashboard"][1]):
-        if isinstance(node, ast.Constant) and \
-                isinstance(node.value, str):
-            api_routes.extend(re.findall(r"/api/([\w-]+)", node.value))
-        elif isinstance(node, ast.JoinedStr):
-            for part in node.values:
-                if isinstance(part, ast.Constant) and \
-                        isinstance(part.value, str):
-                    api_routes.extend(
-                        re.findall(r"/api/([\w-]+)", part.value))
-    lanes = [m.group(1) for n in ast.walk(trees["timeline"][1])
-             if isinstance(n, ast.FunctionDef)
-             for m in [re.match(r"^(\w+)_trace_events$", n.name)] if m]
-    prom_families: Set[str] = set()
-    for dirpath, dirnames, filenames in os.walk(package_root):
-        dirnames[:] = [d for d in sorted(dirnames)
-                       if not d.startswith(".")
-                       and d not in ("__pycache__", "analysis")]
-        for name in sorted(filenames):
-            if not name.endswith(".py"):
-                continue
-            with open(os.path.join(dirpath, name), encoding="utf-8",
-                      errors="replace") as fh:
-                prom_families.update(
-                    re.findall(r"\"ray_tpu_([a-z0-9_]+)\"", fh.read()))
-
-    surface_candidates = {
-        "state": state_defs,
-        "cli": cli_cmds,
-        "dashboard": api_routes,
-        "prometheus": sorted(prom_families),
-        "timeline": lanes,
-    }
-    findings: List[Finding] = []
-    for stem in sorted(stems):
-        missing = [surface for surface, cands
-                   in surface_candidates.items()
-                   if not _match_any(stem, surface, cands)]
-        if not missing:
-            continue
-        hints = "; ".join(_SURFACE_FIX[s].replace("<x>", stem)
-                          for s in missing)
-        findings.append(Finding(
-            "surface-parity", ERROR,
-            f"{conductor_path}:{stems[stem]}",
-            f"subsystem '{stem}' is missing the full surface "
-            f"treatment: no {', no '.join(missing)} — the one-set-of-"
-            "numbers discipline (state == CLI == dashboard == "
-            "Prometheus == timeline) is broken",
-            hints))
-    return findings
-
-
 # ---------------------------------------------------------------- driver
 
 _SKIP_DIRS = frozenset({"__pycache__", "node_modules", "venv", "build",
@@ -757,11 +550,11 @@ def collect_env_reads(package_root: str) -> List[EnvRead]:
 def analyze_invariants(package_root: str,
                        readme_text: Optional[str] = None
                        ) -> List[Finding]:
-    """Run the cross-module families over a package tree: the env-knob
-    registry and the surface-parity checker. (The per-file families —
-    lock-discipline and the donation auditor — already run under
-    `lint_path`/`lint_source`; running them here too would double-
-    report.) Suppression comments on the cited lines are honored."""
+    """Run the cross-module family over a package tree: the env-knob
+    registry. (The per-file families — lock-discipline and the donation
+    auditor — already run under `lint_path`/`lint_source`; running
+    them here too would double-report.) Suppression comments on the
+    cited lines are honored."""
     from .astlint import _suppressions
 
     findings: List[Finding] = []
@@ -769,7 +562,6 @@ def analyze_invariants(package_root: str,
         else _find_readme(package_root)
     findings.extend(check_env_knobs(collect_env_reads(package_root),
                                     readme))
-    findings.extend(check_surface_parity(package_root))
     # honor per-line suppressions at each finding's cited site
     out: List[Finding] = []
     suppress_cache: Dict[str, Dict[int, Optional[Set[str]]]] = {}
@@ -795,9 +587,7 @@ def analyze_invariants(package_root: str,
 
 
 __all__ = [
-    "EnvRead", "PARITY_WAIVERS", "SURFACE_ALIASES",
-    "analyze_invariants", "check_env_knobs", "check_surface_parity",
-    "collect_env_reads", "discover_subsystems", "format_knob_table",
-    "knob_table", "lint_donation_audit", "lint_lock_discipline",
-    "scan_env_reads",
+    "EnvRead", "analyze_invariants", "check_env_knobs",
+    "collect_env_reads", "format_knob_table", "knob_table",
+    "lint_donation_audit", "lint_lock_discipline", "scan_env_reads",
 ]

@@ -85,6 +85,7 @@ Routes:
 from __future__ import annotations
 
 import asyncio
+import functools
 import os
 import threading
 import time
@@ -92,6 +93,7 @@ from collections import defaultdict
 from typing import Any, Dict, List, Optional, Tuple
 
 from ray_tpu._private.rpc import ClientPool, ReconnectingClient
+from ray_tpu._private.telemetry import SUBSYSTEMS
 
 DEFAULT_DASHBOARD_PORT = 8265
 
@@ -186,30 +188,22 @@ class _ClusterData:
             status["live_demand"] = []
         return status
 
-    def kvcache(self) -> Dict[str, Any]:
-        """Paged-KV prefix cache: engine stats + the recent event tail
+    def telemetry(self, subsystem: str) -> Dict[str, Any]:
+        """One telemetry subsystem's aggregate + its recent event tail
         (one payload so the SPA's panel needs a single fetch)."""
-        out = self.conductor.call("get_kvcache_stats", timeout=10.0)
+        out = self.conductor.call("get_status", subsystem, timeout=10.0)
         try:
-            out["events"] = self.conductor.call("get_kvcache_events",
+            out["events"] = self.conductor.call("get_events", subsystem,
                                                 100, timeout=5.0)
-        except Exception:  # noqa: BLE001 — older conductor
+        except Exception:  # noqa: BLE001 — the aggregate still shows
             out["events"] = []
         return out
 
-    def speculation(self) -> Dict[str, Any]:
-        """Speculative-decoding aggregate + the kvcache lane's
-        spec_accept/spec_reject marker slice (one payload so the SPA's
-        panel needs a single fetch)."""
-        out = self.conductor.call("get_speculation_stats", timeout=10.0)
-        try:
-            events = self.conductor.call("get_kvcache_events", 10_000,
-                                         timeout=5.0)
-            out["events"] = [e for e in events if str(
-                e.get("kind", "")).startswith("spec_")][-100:]
-        except Exception:  # noqa: BLE001 — older conductor
-            out["events"] = []
-        return out
+    def __getattr__(self, name: str):
+        # `data.lora()` is the payload /api/lora serves, for every row
+        if name in SUBSYSTEMS:
+            return functools.partial(self.telemetry, name)
+        raise AttributeError(name)
 
     def pipeline(self) -> Dict[str, Any]:
         """MPMD pipeline registry + the recent event tail (one payload
@@ -218,113 +212,6 @@ class _ClusterData:
         try:
             out["events"] = self.conductor.call("get_pipeline_events",
                                                 100, timeout=5.0)
-        except Exception:  # noqa: BLE001 — older conductor
-            out["events"] = []
-        return out
-
-    def online(self) -> Dict[str, Any]:
-        """Online-loop aggregate + the recent event tail (one payload
-        so the SPA's panel needs a single fetch)."""
-        out = self.conductor.call("get_online_status", timeout=10.0)
-        try:
-            out["events"] = self.conductor.call("get_online_events",
-                                                100, timeout=5.0)
-        except Exception:  # noqa: BLE001 — older conductor
-            out["events"] = []
-        return out
-
-    def disagg(self) -> Dict[str, Any]:
-        """Disaggregated-serving aggregate + the recent event tail (one
-        payload so the SPA's panel needs a single fetch)."""
-        out = self.conductor.call("get_disagg_status", timeout=10.0)
-        try:
-            out["events"] = self.conductor.call("get_disagg_events",
-                                                100, timeout=5.0)
-        except Exception:  # noqa: BLE001 — older conductor
-            out["events"] = []
-        return out
-
-    def kvplane(self) -> Dict[str, Any]:
-        """Global-KV-plane aggregate (arena tiers, prefix directory,
-        routing outcomes) + the recent spill/tier2_hit/tier3_publish/
-        tier3_adopt/directory_hit event tail (one payload so the SPA's
-        panel needs a single fetch)."""
-        out = self.conductor.call("get_kvplane_status", timeout=10.0)
-        try:
-            out["events"] = self.conductor.call("get_kvplane_events",
-                                                100, timeout=5.0)
-        except Exception:  # noqa: BLE001 — older conductor
-            out["events"] = []
-        return out
-
-    def autoscale(self) -> Dict[str, Any]:
-        """Serving-autoscaler aggregate + the recent event tail (one
-        payload so the SPA's panel needs a single fetch)."""
-        out = self.conductor.call("get_autoscale_status", timeout=10.0)
-        try:
-            out["events"] = self.conductor.call("get_autoscale_events",
-                                                100, timeout=5.0)
-        except Exception:  # noqa: BLE001 — older conductor
-            out["events"] = []
-        return out
-
-    def servefault(self) -> Dict[str, Any]:
-        """Serving-fault-tolerance aggregate + the resilience lane's
-        failover/replace/breaker_trip event slice (one payload so the
-        SPA's panel needs a single fetch)."""
-        out = self.conductor.call("get_servefault_status", timeout=10.0)
-        try:
-            out["events"] = self.conductor.call(
-                "get_servefault_events", 100, timeout=5.0)
-        except Exception:  # noqa: BLE001 — older conductor
-            out["events"] = []
-        return out
-
-    def lora(self) -> Dict[str, Any]:
-        """Multi-tenant LoRA aggregate + the recent page_in/evict/swap
-        event tail (one payload so the SPA's panel needs a single
-        fetch)."""
-        out = self.conductor.call("get_lora_status", timeout=10.0)
-        try:
-            out["events"] = self.conductor.call("get_lora_events",
-                                                100, timeout=5.0)
-        except Exception:  # noqa: BLE001 — older conductor
-            out["events"] = []
-        return out
-
-    def gateway(self) -> Dict[str, Any]:
-        """HTTP front-door aggregate + the recent accept/first_byte/
-        preempt/rate_limit/disconnect event tail (one payload so the
-        SPA's panel needs a single fetch)."""
-        out = self.conductor.call("get_gateway_status", timeout=10.0)
-        try:
-            out["events"] = self.conductor.call("get_gateway_events",
-                                                100, timeout=5.0)
-        except Exception:  # noqa: BLE001 — older conductor
-            out["events"] = []
-        return out
-
-    def oracle(self) -> Dict[str, Any]:
-        """Step-time-oracle aggregate + the recent event tail (one
-        payload so the SPA's panel needs a single fetch)."""
-        out = self.conductor.call("get_oracle_status", timeout=10.0)
-        try:
-            out["events"] = self.conductor.call("get_oracle_events",
-                                                100, timeout=5.0)
-        except Exception:  # noqa: BLE001 — older conductor
-            out["events"] = []
-        return out
-
-    def requesttrace(self) -> Dict[str, Any]:
-        """Per-request flight-recorder aggregate (totals, p99
-        attribution, slowest requests with phase breakdowns) + the
-        recent kept-trace event tail (one payload so the SPA's panel
-        needs a single fetch)."""
-        out = self.conductor.call("get_requesttrace_status",
-                                  timeout=10.0)
-        try:
-            out["events"] = self.conductor.call(
-                "get_requesttrace_events", 100, timeout=5.0)
         except Exception:  # noqa: BLE001 — older conductor
             out["events"] = []
         return out
@@ -438,22 +325,11 @@ class DashboardServer:
         app.router.add_get(
             "/api/weights",
             self._json_route(lambda: d.simple("get_weight_versions")))
-        app.router.add_get("/api/kvcache", self._json_route(d.kvcache))
-        app.router.add_get("/api/speculation",
-                           self._json_route(d.speculation))
         app.router.add_get("/api/pipeline", self._json_route(d.pipeline))
-        app.router.add_get("/api/online", self._json_route(d.online))
-        app.router.add_get("/api/disagg", self._json_route(d.disagg))
-        app.router.add_get("/api/kvplane", self._json_route(d.kvplane))
-        app.router.add_get("/api/autoscale",
-                           self._json_route(d.autoscale))
-        app.router.add_get("/api/servefault",
-                           self._json_route(d.servefault))
-        app.router.add_get("/api/lora", self._json_route(d.lora))
-        app.router.add_get("/api/gateway", self._json_route(d.gateway))
-        app.router.add_get("/api/oracle", self._json_route(d.oracle))
-        app.router.add_get("/api/requesttrace",
-                           self._json_route(d.requesttrace))
+        for name in SUBSYSTEMS:
+            app.router.add_get(
+                f"/api/{name}",
+                self._json_route(lambda s=name: d.telemetry(s)))
         app.router.add_get(
             "/api/rpc",
             self._json_route(lambda: d.simple("get_rpc_stats")))

@@ -318,6 +318,7 @@ from ray_tpu.observability import requests as reqtrace
 from ray_tpu.ops import dispatch
 from ray_tpu.ops.swa import decode_block, decode_rows_read
 from ray_tpu.util.profiling import name_thread
+from ray_tpu.util.telemetry import Pusher
 
 from .family import family_of, refuse, slab_spec, stacks
 from .generate import merge_lora_params
@@ -1108,7 +1109,7 @@ class ContinuousBatchingEngine:
         self.cancelled_by_reason: Dict[str, int] = {}
         self.max_prefills_admitted_per_tick = 0
         self.max_adoptions_admitted_per_tick = 0
-        self._last_stats_push = 0.0
+        self._pusher = Pusher("kvcache", self.engine_id)
         # the host's mirror of each slot's last token and position, as
         # of the newest tick READ; the tick's own copies stay on the
         # chip (`_dev`: tokens, positions, liveness, the outputs of the
@@ -1482,33 +1483,18 @@ class ContinuousBatchingEngine:
         """Best-effort push of kv_stats + pending timeline events to the
         conductor (no-op without a live cluster); throttled unless
         forced."""
-        now = time.monotonic()
-        if not force and now - self._last_stats_push < 0.5:
-            return
-        self._last_stats_push = now
-        from ray_tpu._private import worker as worker_mod
-
-        w = worker_mod.global_worker
-        if w is None:
-            if self.kv_cache is not None:
-                self.kv_cache.drain_events()  # keep the buffer bounded
-            self._drain_spec_events()
-            return
-        try:
-            w.conductor.notify("report_kvcache_stats", w.worker_id,
-                               self.engine_id, self.kv_stats())
-            if self.kv_cache is not None:
-                for ev in self.kv_cache.drain_events():
-                    ev.setdefault("engine", self.engine_id)
-                    w.conductor.notify("report_kvcache_event", ev)
+        def events():
+            kv = (self.kv_cache.drain_events()
+                  if self.kv_cache is not None else [])
+            for ev in kv:
+                ev.setdefault("engine", self.engine_id)
             # spec_accept/spec_reject markers ride the kvcache timeline
             # lane — the engine buffers them itself because a decode
             # replica (prefix cache disabled) has no kv_cache to carry
             # events through
-            for ev in self._drain_spec_events():
-                w.conductor.notify("report_kvcache_event", ev)
-        except Exception:  # noqa: BLE001 — cluster shutting down
-            pass
+            return kv + self._drain_spec_events()
+
+        self._pusher.push(self.kv_stats, events, force=force)
 
     # ------------------------------------------------------- admission
     def _admit(self, it: Optional[Dict[str, Any]] = None) -> None:

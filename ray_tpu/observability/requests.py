@@ -46,11 +46,11 @@ training StepTimer:
   p99 cohorts and names the phase that owns the tail.
 
 One set of numbers: the store pushes stats + kept traces to the
-conductor (``report_requesttrace_stats`` / ``report_requesttrace_
-event``), and ``util.state.requesttrace_status()``, ``ray_tpu
-requests``, ``/api/requesttrace``, the lazy ``ray_tpu_reqtrace_*``
-Prometheus family, and the merged timeline's ``requests`` lane all
-read the same aggregate.
+conductor (the ``requesttrace`` row of the telemetry channel), and
+``util.state.requesttrace_status()``, ``ray_tpu requests``,
+``/api/requesttrace``, the lazy ``ray_tpu_reqtrace_*`` Prometheus
+family, and the merged timeline's ``requests`` lane all read the same
+aggregate.
 
 Knobs (all live-retunable through util/envknobs.py):
 
@@ -76,6 +76,8 @@ import time
 import uuid
 from contextlib import contextmanager
 from typing import Any, Dict, Iterator, List, Optional
+
+from ray_tpu.util.telemetry import Pusher, emit
 
 # The canonical phase order (rendering + report ordering). ``sse_flush``
 # overlaps decode on the gateway's event loop, so it is excluded from
@@ -505,22 +507,6 @@ def reqtrace_metrics() -> Dict[str, Any]:
 
 # -------------------------------------------------------- conductor IO
 
-def _worker():
-    from ray_tpu._private import worker as worker_mod
-
-    return worker_mod.global_worker
-
-
-def _notify(method: str, *args: Any) -> None:
-    w = _worker()
-    if w is None:
-        return
-    try:
-        w.conductor.notify(method, *args)
-    except Exception:  # noqa: BLE001 — telemetry only
-        pass
-
-
 def push_remote_phase(request_id: str, phase_name: str,
                       dur_ms: float, *, attempt: int = 1,
                       **attrs: Any) -> None:
@@ -536,7 +522,7 @@ def push_remote_phase(request_id: str, phase_name: str,
                           "attempt": int(attempt)}
     if attrs:
         ev.update(attrs)
-    _notify("report_requesttrace_event", ev)
+    emit("requesttrace", ev)
 
 
 # -------------------------------------------------------------- store
@@ -569,7 +555,7 @@ class RequestTraceStore:
         self._replayed = 0
         self._preempted = 0
         self._slowest_ms = 0.0
-        self._last_push = 0.0
+        self._pusher = Pusher("requesttrace", self.component_id)
         self._rng = random.Random()
 
     # ------------------------------------------------------- knobs
@@ -646,7 +632,7 @@ class RequestTraceStore:
             m["kept"].inc(tags={"reason": reason})
             # kept traces ride the conductor event log: the timeline's
             # `requests` lane and get_request_trace read them back
-            _notify("report_requesttrace_event", dict(rec))
+            emit("requesttrace", rec)
         if new_champion:
             m["slowest_ms"].set(
                 total_ms,
@@ -748,19 +734,7 @@ class RequestTraceStore:
     # ------------------------------------------------------ publishing
 
     def publish_telemetry(self, force: bool = False) -> None:
-        now = time.monotonic()
-        with self._lock:
-            if not force and now - self._last_push < 0.5:
-                return
-            self._last_push = now
-        w = _worker()
-        if w is None:
-            return
-        try:
-            w.conductor.notify("report_requesttrace_stats", w.worker_id,
-                               self.component_id, self.stats())
-        except Exception:  # noqa: BLE001 — cluster shutting down
-            pass
+        self._pusher.push(self.stats, force=force)
 
 
 # ----------------------------------------------------- global store

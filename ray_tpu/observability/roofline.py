@@ -39,8 +39,13 @@ from __future__ import annotations
 
 import json
 import threading
+import time
 from dataclasses import dataclass
 from typing import Any, Dict, List, Optional, Sequence, Tuple
+
+from ray_tpu._private.telemetry import (ORACLE_PREDICTION,
+                                        ORACLE_VALIDATION)
+from ray_tpu.util.telemetry import Pusher
 
 from . import flops as _flops
 from .step_timer import summarize_records
@@ -339,28 +344,21 @@ def oracle_metrics() -> Dict[str, Any]:
     return _metrics
 
 
-def _worker():
-    from ray_tpu._private import worker as worker_mod
-
-    return worker_mod.global_worker
-
-
 def record_prediction(layout: str, prediction: Dict[str, Any]) -> None:
     """Publish one layout's prediction to every oracle surface: the
     Prometheus gauge, the conductor aggregate (state API / CLI /
     dashboard), and the merged timeline's predicted-step-time counter
     track. Best-effort without a cluster (the gauge still updates)."""
+    layout = str(layout)
     oracle_metrics()["predicted"].set(
         float(prediction.get("predicted_step_ms", 0.0)),
-        tags={"layout": str(layout)})
-    w = _worker()
-    if w is None:
-        return
-    try:
-        w.conductor.notify("report_oracle_prediction", w.worker_id,
-                           str(layout), dict(prediction))
-    except Exception:  # noqa: BLE001 — cluster shutting down
-        pass
+        tags={"layout": layout})
+    event = {"kind": "prediction", "layout": layout}
+    for key in ("predicted_step_ms", "device_step_ms", "ici_wait_ms",
+                "dcn_wait_ms"):
+        event[key] = prediction.get(key)
+    Pusher("oracle", ORACLE_PREDICTION + layout).push(
+        dict(prediction, layout=layout), (event,), force=True)
 
 
 def record_validation(rec: Dict[str, Any]) -> None:
@@ -369,14 +367,13 @@ def record_validation(rec: Dict[str, Any]) -> None:
     m = oracle_metrics()
     for phase, ratio in (rec.get("residuals") or {}).items():
         m["residual"].set(float(ratio), tags={"phase": str(phase)})
-    w = _worker()
-    if w is None:
-        return
-    try:
-        w.conductor.notify("report_oracle_validation", w.worker_id,
-                           dict(rec))
-    except Exception:  # noqa: BLE001 — cluster shutting down
-        pass
+    event = {"kind": "validation"}
+    for key in ("layout", "run_id", "calibration", "residuals",
+                "n_steps"):
+        event[key] = rec.get(key)
+    # validations are a log: each record is a component of its own
+    Pusher("oracle", f"{ORACLE_VALIDATION}{time.time_ns()}").push(
+        dict(rec), (event,), force=True)
 
 
 __all__ = ["LINK_CONSTANTS", "LinkConstants", "NOMINAL_LINK_CONSTANTS",

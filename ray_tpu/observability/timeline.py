@@ -101,7 +101,7 @@ merges and labels them:
 from __future__ import annotations
 
 import json
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
 
 def step_trace_events(records: List[Dict[str, Any]]) -> List[Dict[str, Any]]:
@@ -166,59 +166,74 @@ def resilience_trace_events(events: List[Dict[str, Any]]
     return out
 
 
-def weight_trace_events(events: List[Dict[str, Any]]
-                        ) -> List[Dict[str, Any]]:
-    """Instant markers for weight-fabric events (publish, fetch, swap,
-    gc, reap) — mirrors the resilience track under pid "weights"."""
+# lane -> (the key a marker's track is named by, or None for its kind;
+# the parts of its label after the kind: a format and the keys it may
+# take its value from, the first that carries one)
+LANES: Dict[str, Tuple[Optional[str], Tuple[Tuple[str, ...], ...]]] = {
+    "weights": (None, ((":{}", "name"), ("@v{}", "version"))),
+    "kvcache": (None, ((":{}", "outcome"), (" +{}tok", "reused_tokens"))),
+    "online": ("sampler", ((":{}", "sampler"),
+                           ("@v{}", "weights_version", "version"))),
+    "disagg": (None, ((":{}", "server", "router"), (" {}B", "bytes"))),
+    "lora": (None, ((":{}", "tenant"), ("@v{}", "version"),
+                    (" {}B", "bytes"))),
+    "kvplane": (None, ((":{}", "replica", "holder", "router"),
+                       (" {}blk", "blocks"), (" {}B", "nbytes"))),
+    "gateway": (None, ((":{}", "class"), ("@{}", "tenant"),
+                       (" {}ms", "ttft_ms"))),
+    "autoscale": (None, ((":{}", "tier"), ("->{}", "to"))),
+}
+
+
+def instant_lane(subsystem: str, events: List[Dict[str, Any]]
+                 ) -> List[Dict[str, Any]]:
+    """Instant markers of one subsystem under pid `subsystem`: one
+    global-scope "i" event per entry, named by its kind and the parts
+    of the lane's label (its row of LANES; without one, the bare kind)
+    whose keys the event carries, with the whole event in args."""
+    track, parts = LANES.get(subsystem, (None, ()))
     out: List[Dict[str, Any]] = []
     for ev in events:
         ts = ev.get("ts")
         if ts is None:
             continue
         kind = str(ev.get("kind", "event"))
-        name = ev.get("name")
-        ver = ev.get("version")
-        label = f"{kind}:{name}" if name else kind
-        if ver is not None:
-            label += f"@v{ver}"
+        name = kind
+        for fmt, *keys in parts:
+            value = next((ev[k] for k in keys
+                          if ev.get(k) not in (None, "")), None)
+            if value is not None:
+                name += fmt.format(value)
         out.append({
-            "name": label, "cat": "weights", "ph": "i", "s": "g",
-            "ts": ts * 1e6, "pid": "weights", "tid": kind,
+            "name": name, "cat": subsystem, "ph": "i", "s": "g",
+            "ts": ts * 1e6, "pid": subsystem,
+            "tid": str(ev.get(track) or kind) if track else kind,
             "args": {k: v for k, v in ev.items()
                      if k != "ts" and v is not None},
         })
     return out
 
 
-def kvcache_trace_events(events: List[Dict[str, Any]]
+def gateway_trace_events(events: List[Dict[str, Any]]
                          ) -> List[Dict[str, Any]]:
-    """Instant markers for paged-KV cache events (prefix_hit, evict,
-    invalidate) — mirrors the weights track under pid "kvcache"."""
-    out: List[Dict[str, Any]] = []
-    for ev in events:
-        ts = ev.get("ts")
-        kind = str(ev.get("kind", "event"))
-        if ts is None or kind.startswith("spec_"):
-            continue  # spec_* markers render on the speculation lane
-        label = kind
-        if ev.get("outcome"):
-            label += f":{ev['outcome']}"
-        if ev.get("reused_tokens") is not None:
-            label += f" +{ev['reused_tokens']}tok"
-        out.append({
-            "name": label, "cat": "kvcache", "ph": "i", "s": "g",
-            "ts": ts * 1e6, "pid": "kvcache", "tid": kind,
-            "args": {k: v for k, v in ev.items()
-                     if k != "ts" and v is not None},
-        })
-    return out
+    """The HTTP front door's lane (accept, first_byte, preempt,
+    rate_limit, disconnect)."""
+    return instant_lane("gateway", events)
+
+
+def _kvcache_lanes(events: List[Dict[str, Any]]) -> List[Dict[str, Any]]:
+    """The kvcache ring feeds two lanes: the spec_* markers render on
+    the speculation lane, the rest on the prefix cache's own."""
+    own = [ev for ev in events
+           if not str(ev.get("kind", "")).startswith("spec_")]
+    return instant_lane("kvcache", own) + speculation_trace_events(events)
 
 
 def speculation_trace_events(events: List[Dict[str, Any]]
                              ) -> List[Dict[str, Any]]:
     """Instant markers for speculative-decoding verify outcomes — the
     spec_* slice of the kvcache event channel (engines push spec_accept
-    / spec_reject through the same report_kvcache_event path), rendered
+    / spec_reject through the kvcache row's event ring), rendered
     under its own pid "speculation" so acceptance reads as a lane
     instead of noise in the prefix-cache track."""
     out: List[Dict[str, Any]] = []
@@ -260,174 +275,6 @@ def pipeline_trace_events(events: List[Dict[str, Any]]
             "name": label, "cat": "pipeline", "ph": "i", "s": "g",
             "ts": ts * 1e6, "pid": "pipeline",
             "tid": f"stage {stage}" if stage is not None else kind,
-            "args": {k: v for k, v in ev.items()
-                     if k != "ts" and v is not None},
-        })
-    return out
-
-
-def online_trace_events(events: List[Dict[str, Any]]
-                        ) -> List[Dict[str, Any]]:
-    """Instant markers for online-loop events (rollout, ingest,
-    publish, swap) — one lane per sampler (learner events lane under
-    their kind) beneath pid "online"."""
-    out: List[Dict[str, Any]] = []
-    for ev in events:
-        ts = ev.get("ts")
-        if ts is None:
-            continue
-        kind = str(ev.get("kind", "event"))
-        label = kind
-        if ev.get("sampler"):
-            label += f":{ev['sampler']}"
-        if ev.get("weights_version") is not None:
-            label += f"@v{ev['weights_version']}"
-        elif ev.get("version") is not None:
-            label += f"@v{ev['version']}"
-        out.append({
-            "name": label, "cat": "online", "ph": "i", "s": "g",
-            "ts": ts * 1e6, "pid": "online",
-            "tid": str(ev.get("sampler") or kind),
-            "args": {k: v for k, v in ev.items()
-                     if k != "ts" and v is not None},
-        })
-    return out
-
-
-def disagg_trace_events(events: List[Dict[str, Any]]
-                        ) -> List[Dict[str, Any]]:
-    """Instant markers for disaggregated-serving events (kv_publish,
-    kv_transfer, shed) — mirrors the kvcache track under pid
-    "disagg"."""
-    out: List[Dict[str, Any]] = []
-    for ev in events:
-        ts = ev.get("ts")
-        if ts is None:
-            continue
-        kind = str(ev.get("kind", "event"))
-        label = kind
-        where = ev.get("server") or ev.get("router")
-        if where:
-            label += f":{where}"
-        if ev.get("bytes") is not None:
-            label += f" {ev['bytes']}B"
-        out.append({
-            "name": label, "cat": "disagg", "ph": "i", "s": "g",
-            "ts": ts * 1e6, "pid": "disagg", "tid": kind,
-            "args": {k: v for k, v in ev.items()
-                     if k != "ts" and v is not None},
-        })
-    return out
-
-
-def lora_trace_events(events: List[Dict[str, Any]]
-                      ) -> List[Dict[str, Any]]:
-    """Instant markers for multi-tenant LoRA events (page_in, evict,
-    swap) — mirrors the kvcache track under pid "lora", so adapter
-    paging lines up against the disagg lane's request markers and the
-    weights lane's publish markers."""
-    out: List[Dict[str, Any]] = []
-    for ev in events:
-        ts = ev.get("ts")
-        if ts is None:
-            continue
-        kind = str(ev.get("kind", "event"))
-        label = kind
-        if ev.get("tenant"):
-            label += f":{ev['tenant']}"
-        if ev.get("version") is not None:
-            label += f"@v{ev['version']}"
-        if ev.get("bytes") is not None:
-            label += f" {ev['bytes']}B"
-        out.append({
-            "name": label, "cat": "lora", "ph": "i", "s": "g",
-            "ts": ts * 1e6, "pid": "lora", "tid": kind,
-            "args": {k: v for k, v in ev.items()
-                     if k != "ts" and v is not None},
-        })
-    return out
-
-
-def kvplane_trace_events(events: List[Dict[str, Any]]
-                         ) -> List[Dict[str, Any]]:
-    """Instant markers for global-KV-plane events (spill, tier2_hit,
-    tier3_publish, tier3_adopt, directory_hit, evict_storm, reap) —
-    mirrors the kvcache track under pid "kvplane", so tier demotions
-    and cross-replica adoptions read against the engines' block-level
-    reuse markers and the disagg lane's transfer markers."""
-    out: List[Dict[str, Any]] = []
-    for ev in events:
-        ts = ev.get("ts")
-        if ts is None:
-            continue
-        kind = str(ev.get("kind", "event"))
-        label = kind
-        where = ev.get("replica") or ev.get("holder") or \
-            ev.get("router")
-        if where:
-            label += f":{where}"
-        if ev.get("blocks") is not None:
-            label += f" {ev['blocks']}blk"
-        if ev.get("nbytes") is not None:
-            label += f" {ev['nbytes']}B"
-        out.append({
-            "name": label, "cat": "kvplane", "ph": "i", "s": "g",
-            "ts": ts * 1e6, "pid": "kvplane", "tid": kind,
-            "args": {k: v for k, v in ev.items()
-                     if k != "ts" and v is not None},
-        })
-    return out
-
-
-def gateway_trace_events(events: List[Dict[str, Any]]
-                         ) -> List[Dict[str, Any]]:
-    """Instant markers for HTTP front-door events (accept, first_byte,
-    preempt, rate_limit, disconnect) — mirrors the disagg track under
-    pid "gateway", so ingress pressure and preemptions read against
-    the router's shed/transfer markers and the lora lane's tenant
-    paging."""
-    out: List[Dict[str, Any]] = []
-    for ev in events:
-        ts = ev.get("ts")
-        if ts is None:
-            continue
-        kind = str(ev.get("kind", "event"))
-        label = kind
-        if ev.get("class"):
-            label += f":{ev['class']}"
-        if ev.get("tenant"):
-            label += f"@{ev['tenant']}"
-        if ev.get("ttft_ms") is not None:
-            label += f" {ev['ttft_ms']}ms"
-        out.append({
-            "name": label, "cat": "gateway", "ph": "i", "s": "g",
-            "ts": ts * 1e6, "pid": "gateway", "tid": kind,
-            "args": {k: v for k, v in ev.items()
-                     if k != "ts" and v is not None},
-        })
-    return out
-
-
-def autoscale_trace_events(events: List[Dict[str, Any]]
-                           ) -> List[Dict[str, Any]]:
-    """Instant markers for serving-autoscaler events (scale_up, drain,
-    scale_down) — mirrors the disagg track under pid "autoscale" so
-    replica-set changes read against the shed/transfer markers they
-    react to."""
-    out: List[Dict[str, Any]] = []
-    for ev in events:
-        ts = ev.get("ts")
-        if ts is None:
-            continue
-        kind = str(ev.get("kind", "event"))
-        label = kind
-        if ev.get("tier"):
-            label += f":{ev['tier']}"
-        if ev.get("to") is not None:
-            label += f"->{ev['to']}"
-        out.append({
-            "name": label, "cat": "autoscale", "ph": "i", "s": "g",
-            "ts": ts * 1e6, "pid": "autoscale", "tid": kind,
             "args": {k: v for k, v in ev.items()
                      if k != "ts" and v is not None},
         })
@@ -544,6 +391,15 @@ def task_trace_events(task_events: List[Dict[str, Any]]
     return out
 
 
+# telemetry rings whose lane has a structure of its own; the others are
+# instant markers
+_OWN_LANES = {
+    "kvcache": _kvcache_lanes,
+    "oracle": oracle_trace_events,
+    "requesttrace": requests_trace_events,
+}
+
+
 def merged_chrome_trace(task_events: List[Dict[str, Any]],
                         spans: List[Dict[str, Any]],
                         step_records: List[Dict[str, Any]],
@@ -551,28 +407,14 @@ def merged_chrome_trace(task_events: List[Dict[str, Any]],
                             List[Dict[str, Any]]] = None,
                         weight_events: Optional[
                             List[Dict[str, Any]]] = None,
-                        kvcache_events: Optional[
-                            List[Dict[str, Any]]] = None,
                         pipeline_events: Optional[
                             List[Dict[str, Any]]] = None,
-                        online_events: Optional[
-                            List[Dict[str, Any]]] = None,
-                        disagg_events: Optional[
-                            List[Dict[str, Any]]] = None,
-                        oracle_events: Optional[
-                            List[Dict[str, Any]]] = None,
-                        autoscale_events: Optional[
-                            List[Dict[str, Any]]] = None,
-                        lora_events: Optional[
-                            List[Dict[str, Any]]] = None,
-                        gateway_events: Optional[
-                            List[Dict[str, Any]]] = None,
-                        requesttrace_events: Optional[
-                            List[Dict[str, Any]]] = None,
-                        kvplane_events: Optional[
-                            List[Dict[str, Any]]] = None
+                        telemetry_events: Optional[
+                            Dict[str, List[Dict[str, Any]]]] = None
                         ) -> List[Dict[str, Any]]:
-    """Merge the sources into one sorted event list."""
+    """Merge the sources into one sorted event list.
+    `telemetry_events` holds the event ring of each telemetry subsystem
+    by its name."""
     from ray_tpu.util import tracing
 
     trace = task_trace_events(task_events)
@@ -581,28 +423,13 @@ def merged_chrome_trace(task_events: List[Dict[str, Any]],
     if resilience_events:
         trace.extend(resilience_trace_events(resilience_events))
     if weight_events:
-        trace.extend(weight_trace_events(weight_events))
-    if kvcache_events:
-        trace.extend(kvcache_trace_events(kvcache_events))
-        trace.extend(speculation_trace_events(kvcache_events))
+        trace.extend(instant_lane("weights", weight_events))
     if pipeline_events:
         trace.extend(pipeline_trace_events(pipeline_events))
-    if online_events:
-        trace.extend(online_trace_events(online_events))
-    if disagg_events:
-        trace.extend(disagg_trace_events(disagg_events))
-    if oracle_events:
-        trace.extend(oracle_trace_events(oracle_events))
-    if autoscale_events:
-        trace.extend(autoscale_trace_events(autoscale_events))
-    if lora_events:
-        trace.extend(lora_trace_events(lora_events))
-    if gateway_events:
-        trace.extend(gateway_trace_events(gateway_events))
-    if requesttrace_events:
-        trace.extend(requests_trace_events(requesttrace_events))
-    if kvplane_events:
-        trace.extend(kvplane_trace_events(kvplane_events))
+    for name, events in (telemetry_events or {}).items():
+        lane = _OWN_LANES.get(name)
+        trace.extend(lane(events) if lane
+                     else instant_lane(name, events))
     trace.sort(key=lambda e: e.get("ts", 0.0))
     return trace
 
@@ -613,6 +440,7 @@ def merged_timeline(filename: Optional[str] = None,
     ``timeline --merged`` backend). Flushes this process's pending task
     events and spans first so a short driver's trace is complete."""
     from ray_tpu._private import worker as worker_mod
+    from ray_tpu._private.telemetry import SUBSYSTEMS
 
     w = worker_mod.global_worker
     if w is None:
@@ -620,66 +448,22 @@ def merged_timeline(filename: Optional[str] = None,
     w._flush_task_events()  # spans ride the same flush (tracing.drain)
     events = w.conductor.call("get_task_events", limit, timeout=30.0)
     spans = w.conductor.call("get_spans", limit, timeout=30.0)
-    try:
-        steps = w.conductor.call("get_train_steps", limit, timeout=30.0)
-    except Exception:  # noqa: BLE001 — pre-flight-recorder conductor
-        steps = []
-    try:
-        resil = w.conductor.call("get_resilience_events", limit,
-                                 timeout=30.0)
-    except Exception:  # noqa: BLE001 — pre-resilience conductor
-        resil = []
-    try:
-        wev = w.conductor.call("get_weight_events", limit, timeout=30.0)
-    except Exception:  # noqa: BLE001 — pre-weights conductor
-        wev = []
-    try:
-        kvev = w.conductor.call("get_kvcache_events", limit, timeout=30.0)
-    except Exception:  # noqa: BLE001 — pre-kvcache conductor
-        kvev = []
-    try:
-        pev = w.conductor.call("get_pipeline_events", limit,
-                               timeout=30.0)
-    except Exception:  # noqa: BLE001 — pre-mpmd conductor
-        pev = []
-    try:
-        oev = w.conductor.call("get_online_events", limit, timeout=30.0)
-    except Exception:  # noqa: BLE001 — pre-online conductor
-        oev = []
-    try:
-        dev = w.conductor.call("get_disagg_events", limit, timeout=30.0)
-    except Exception:  # noqa: BLE001 — pre-disagg conductor
-        dev = []
-    try:
-        orev = w.conductor.call("get_oracle_events", limit, timeout=30.0)
-    except Exception:  # noqa: BLE001 — pre-oracle conductor
-        orev = []
-    try:
-        asev = w.conductor.call("get_autoscale_events", limit,
-                                timeout=30.0)
-    except Exception:  # noqa: BLE001 — pre-autoscale conductor
-        asev = []
-    try:
-        lev = w.conductor.call("get_lora_events", limit, timeout=30.0)
-    except Exception:  # noqa: BLE001 — pre-lora conductor
-        lev = []
-    try:
-        gev = w.conductor.call("get_gateway_events", limit, timeout=30.0)
-    except Exception:  # noqa: BLE001 — pre-gateway conductor
-        gev = []
-    try:
-        rtev = w.conductor.call("get_requesttrace_events", limit,
-                                timeout=30.0)
-    except Exception:  # noqa: BLE001 — pre-requesttrace conductor
-        rtev = []
-    try:
-        kpev = w.conductor.call("get_kvplane_events", limit,
-                                timeout=30.0)
-    except Exception:  # noqa: BLE001 — pre-kvplane conductor
-        kpev = []
-    trace = merged_chrome_trace(events, spans, steps, resil, wev, kvev,
-                                pev, oev, dev, orev, asev, lev, gev,
-                                rtev, kpev)
+
+    def tail(method: str, *args: Any) -> List[Dict[str, Any]]:
+        try:
+            return w.conductor.call(method, *args, limit, timeout=30.0)
+        except Exception:  # noqa: BLE001 — a lane less, not no trace
+            return []
+
+    # a row that keeps no ring of its own has its markers in another
+    # lane (speculation's in kvcache's, servefault's in resilience's)
+    telemetry = {name: tail("get_events", name)
+                 for name, row in SUBSYSTEMS.items()
+                 if row.view_of is None and row.events_kept}
+    trace = merged_chrome_trace(
+        events, spans, tail("get_train_steps"),
+        tail("get_resilience_events"), tail("get_weight_events"),
+        tail("get_pipeline_events"), telemetry)
     if filename:
         with open(filename, "w") as f:
             json.dump(trace, f)

@@ -32,6 +32,8 @@ import threading
 import time
 from typing import Any, Callable, Dict, List, Optional
 
+from ray_tpu.util.telemetry import Pusher
+
 from .metrics import online_metrics
 
 
@@ -53,7 +55,7 @@ class RolloutBuffer:
         self.total_in = 0
         self.total_out = 0
         self._versions: Dict[int, int] = {}  # weights_version -> queued
-        self._last_push = 0.0
+        self._pusher = Pusher("online", f"buffer/{self.name}")
 
     # ------------------------------------------------------------- queue
 
@@ -118,23 +120,13 @@ class RolloutBuffer:
             }
 
     def _publish_telemetry(self, force: bool = False) -> None:
-        now = time.monotonic()
-        if not force and now - self._last_push < 0.25:
-            return
-        self._last_push = now
-        st = self.stats()
-        online_metrics()["buffer_occupancy"].set(
-            st["occupancy"], tags={"buffer": self.name})
-        from ray_tpu._private import worker as worker_mod
+        def stats():
+            st = self.stats()
+            online_metrics()["buffer_occupancy"].set(
+                st["occupancy"], tags={"buffer": self.name})
+            return st
 
-        w = worker_mod.global_worker
-        if w is None:
-            return
-        try:
-            w.conductor.notify("report_online_stats", w.worker_id,
-                               f"buffer/{self.name}", st)
-        except Exception:  # noqa: BLE001 — cluster shutting down
-            pass
+        self._pusher.push(stats, force=force)
 
 
 # --------------------------------------------------- learner-side stream

@@ -25,6 +25,8 @@ from typing import Any, Callable, Dict, List, Optional
 
 import numpy as np
 
+from ray_tpu.util.telemetry import Pusher, emit
+
 from .buffer import RolloutBuffer, from_rollouts
 from .sampler import spawn_samplers
 
@@ -414,7 +416,7 @@ def _samplers_caught_up(last_version: int, weights_name: str,
     if w is None:
         return True
     try:
-        st = w.conductor.call("get_online_status", timeout=5.0)
+        st = w.conductor.call("get_status", "online", timeout=5.0)
     except Exception:  # noqa: BLE001 — conductor mid-restart
         return True
     now = time.time()
@@ -430,8 +432,6 @@ def _samplers_caught_up(last_version: int, weights_name: str,
 
 
 def _learner_stats(ctx, **stats) -> None:
-    from ray_tpu._private import worker as worker_mod
-
     from .metrics import online_metrics
 
     prev = getattr(ctx, "_online_ingested", 0)
@@ -440,26 +440,9 @@ def _learner_stats(ctx, **stats) -> None:
         online_metrics()["ingested_rollouts"].inc(
             cur - prev, tags={"run": ctx.run_id})
     ctx._online_ingested = cur
-    w = worker_mod.global_worker
-    if w is None:
-        return
-    try:
-        w.conductor.notify(
-            "report_online_stats", w.worker_id,
-            f"learner/{ctx.run_id}",
-            dict(stats, role="learner", run_id=ctx.run_id))
-    except Exception:  # noqa: BLE001 — telemetry only
-        pass
+    Pusher("online", f"learner/{ctx.run_id}").push(
+        dict(stats, role="learner", run_id=ctx.run_id), force=True)
 
 
 def _learner_telemetry(ctx, **event) -> None:
-    from ray_tpu._private import worker as worker_mod
-
-    w = worker_mod.global_worker
-    if w is None:
-        return
-    try:
-        w.conductor.notify("report_online_event",
-                           dict(event, run_id=ctx.run_id))
-    except Exception:  # noqa: BLE001 — telemetry only
-        pass
+    emit("online", dict(event, run_id=ctx.run_id))

@@ -33,6 +33,8 @@ from typing import Any, Callable, Dict, List, Optional
 
 import numpy as np
 
+from ray_tpu.util.telemetry import Pusher, emit
+
 from .metrics import online_metrics
 
 
@@ -105,7 +107,7 @@ class RolloutSampler:
         self.run_error: Optional[str] = None  # why the loop died, if it did
         self._stop = threading.Event()
         self._thread: Optional[threading.Thread] = None
-        self._last_push = 0.0
+        self._pusher = Pusher("online", f"sampler/{self.sampler_id}")
 
     # ---------------------------------------------------------- lifecycle
 
@@ -146,9 +148,12 @@ class RolloutSampler:
                         self._push_telemetry()
                         self._stop.wait(0.02)
                         continue
+                seen = self._seen_version
                 self._held.append(self._rollout_one())
                 self._flush()
-                self._push_telemetry()
+                # a hot swap is what the learner's publication gate
+                # waits to read: its snapshot goes out at once
+                self._push_telemetry(force=self._seen_version != seen)
             except Exception as e:  # noqa: BLE001 — a dead rollout
                 # thread must be VISIBLE: record the cause, push a
                 # final snapshot, and stop (a healthy-looking actor
@@ -172,15 +177,15 @@ class RolloutSampler:
         m["rollouts"].inc(1, tags={"sampler": self.sampler_id})
         m["rollout_tokens"].inc(len(completion),
                                 tags={"sampler": self.sampler_id})
-        self._event({"kind": "rollout", "sampler": self.sampler_id,
-                     "tokens": len(completion),
-                     "weights_version": version})
+        emit("online", {"kind": "rollout", "sampler": self.sampler_id,
+                        "tokens": len(completion),
+                        "weights_version": version})
         if version is not None and version != self._seen_version:
             # the sync thread swapped while we decoded: mark it in the
             # online lane (the weights lane has the fabric-side marker)
-            self._event({"kind": "swap", "sampler": self.sampler_id,
-                         "from_version": self._seen_version,
-                         "to_version": version})
+            emit("online", {"kind": "swap", "sampler": self.sampler_id,
+                            "from_version": self._seen_version,
+                            "to_version": version})
             self._seen_version = version
         return {"prompt": np.asarray(prompt, np.int32),
                 "completion": np.asarray(completion, np.int32),
@@ -232,32 +237,7 @@ class RolloutSampler:
         }
 
     def _push_telemetry(self, force: bool = False) -> None:
-        now = time.monotonic()
-        if not force and now - self._last_push < 0.25:
-            return
-        self._last_push = now
-        from ray_tpu._private import worker as worker_mod
-
-        w = worker_mod.global_worker
-        if w is None:
-            return
-        try:
-            w.conductor.notify("report_online_stats", w.worker_id,
-                               f"sampler/{self.sampler_id}",
-                               self.status())
-        except Exception:  # noqa: BLE001 — cluster shutting down
-            pass
-
-    def _event(self, event: Dict[str, Any]) -> None:
-        from ray_tpu._private import worker as worker_mod
-
-        w = worker_mod.global_worker
-        if w is None:
-            return
-        try:
-            w.conductor.notify("report_online_event", event)
-        except Exception:  # noqa: BLE001 — telemetry only
-            pass
+        self._pusher.push(self.status, force=force)
 
 
 def spawn_samplers(num_samplers: int, weights_name: str,

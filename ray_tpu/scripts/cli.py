@@ -321,7 +321,6 @@ def cmd_kvcache(args) -> None:
     cluster totals every other surface (state API, /api/kvcache,
     Prometheus, timeline markers) reports from the same snapshots."""
     _connect(args)
-    from ray_tpu._private import worker as worker_mod
     from ray_tpu.util import state
 
     st = state.kv_cache_stats(getattr(args, "engine", None))
@@ -362,10 +361,8 @@ def cmd_kvcache(args) -> None:
               f"cow={s.get('cow_copies', 0)} "
               f"invalidations={s.get('invalidations', 0)}")
     if args.events:
-        w = worker_mod.global_worker
-        events = w.conductor.call("get_kvcache_events", args.events,
-                                  timeout=10.0)
-        _print_event_tail(events, args.events)
+        _print_event_tail(state.events("kvcache", args.events),
+                          args.events)
 
 
 def cmd_speculate(args) -> None:
@@ -375,7 +372,6 @@ def cmd_speculate(args) -> None:
     (state API, /api/speculation, Prometheus, the kvcache timeline
     lane's spec markers) reports from the same snapshots."""
     _connect(args)
-    from ray_tpu._private import worker as worker_mod
     from ray_tpu.util import state
 
     st = state.speculation_stats(getattr(args, "engine", None))
@@ -401,12 +397,8 @@ def cmd_speculate(args) -> None:
               f"tokens/verify={s.get('tokens_per_verify', 0.0):.2f} "
               f"int8_kv={'on' if s.get('kv_int8') else 'off'}")
     if args.events:
-        w = worker_mod.global_worker
-        events = w.conductor.call("get_kvcache_events", 10_000,
-                                  timeout=10.0)
-        spec = [e for e in events
-                if str(e.get("kind", "")).startswith("spec_")]
-        _print_event_tail(spec[-args.events:], args.events)
+        _print_event_tail(state.events("speculation", args.events),
+                          args.events)
 
 
 def cmd_pipeline(args) -> None:
@@ -472,7 +464,6 @@ def cmd_online(args) -> None:
     every other surface (state API, /api/online, Prometheus, timeline
     markers) reports from the same snapshots."""
     _connect(args)
-    from ray_tpu._private import worker as worker_mod
     from ray_tpu.util import state
 
     st = state.online_status()
@@ -514,10 +505,8 @@ def cmd_online(args) -> None:
               f"last_loss={l.get('last_loss')} "
               f"published=v{l.get('published_version')}")
     if args.events:
-        w = worker_mod.global_worker
-        events = w.conductor.call("get_online_events", args.events,
-                                  timeout=10.0)
-        _print_event_tail(events, args.events)
+        _print_event_tail(state.events("online", args.events),
+                          args.events)
 
 
 def cmd_disagg(args) -> None:
@@ -528,7 +517,6 @@ def cmd_disagg(args) -> None:
     other surface (state API, /api/disagg, Prometheus, timeline
     markers) reports from the same snapshots."""
     _connect(args)
-    from ray_tpu._private import worker as worker_mod
     from ray_tpu.util import state
 
     st = state.disagg_status()
@@ -576,10 +564,8 @@ def cmd_disagg(args) -> None:
               f"(max {r.get('max_pending', 0)}, "
               f"depth_knob={r.get('max_queue_depth')})")
     if args.events:
-        w = worker_mod.global_worker
-        events = w.conductor.call("get_disagg_events", args.events,
-                                  timeout=10.0)
-        _print_event_tail(events, args.events)
+        _print_event_tail(state.events("disagg", args.events),
+                          args.events)
 
 
 def cmd_kvplane(args) -> None:
@@ -591,7 +577,6 @@ def cmd_kvplane(args) -> None:
     other surface (state API, /api/kvplane, Prometheus, timeline
     markers) reports from the same snapshots."""
     _connect(args)
-    from ray_tpu._private import worker as worker_mod
     from ray_tpu.util import state
 
     st = state.kvplane_status()
@@ -643,10 +628,8 @@ def cmd_kvplane(args) -> None:
                   f"t3_adopt={c.get('tier3_adopts', 0)} "
                   f"storms={c.get('evict_storms', 0)}")
     if args.events:
-        w = worker_mod.global_worker
-        events = w.conductor.call("get_kvplane_events", args.events,
-                                  timeout=10.0)
-        _print_event_tail(events, args.events)
+        _print_event_tail(state.events("kvplane", args.events),
+                          args.events)
 
 
 def cmd_servefault(args) -> None:
@@ -658,7 +641,6 @@ def cmd_servefault(args) -> None:
     Prometheus, resilience-lane timeline markers) reports from the
     same snapshots."""
     _connect(args)
-    from ray_tpu._private import worker as worker_mod
     from ray_tpu.util import state
 
     st = state.servefault_status()
@@ -706,10 +688,8 @@ def cmd_servefault(args) -> None:
               f"breaker_open={h.get('breaker_open') or []} "
               f"drains_reaped={h.get('drains_reaped', 0)}")
     if args.events:
-        w = worker_mod.global_worker
-        events = w.conductor.call("get_servefault_events", args.events,
-                                  timeout=10.0)
-        _print_event_tail(events, args.events)
+        _print_event_tail(state.events("servefault", args.events),
+                          args.events)
 
 
 def cmd_gateway(args) -> None:
@@ -720,7 +700,6 @@ def cmd_gateway(args) -> None:
     API, /api/gateway, Prometheus, `gateway` timeline lane) reports
     from the same snapshots."""
     _connect(args)
-    from ray_tpu._private import worker as worker_mod
     from ray_tpu.util import state
 
     st = state.gateway_status()
@@ -766,10 +745,8 @@ def cmd_gateway(args) -> None:
               f"preemptions={g.get('preemptions', 0)}"
               + (f" {ttft_txt}" if ttft_txt else ""))
     if args.events:
-        w = worker_mod.global_worker
-        events = w.conductor.call("get_gateway_events", args.events,
-                                  timeout=10.0)
-        _print_event_tail(events, args.events)
+        _print_event_tail(state.events("gateway", args.events),
+                          args.events)
 
 
 def cmd_requests(args) -> None:
@@ -781,7 +758,6 @@ def cmd_requests(args) -> None:
     /api/requesttrace, Prometheus, `requests` timeline lane) reads.
     `--trace <id>` replays one kept request's full span log."""
     _connect(args)
-    from ray_tpu._private import worker as worker_mod
     from ray_tpu.util import state
 
     if args.trace:
@@ -861,10 +837,8 @@ def cmd_requests(args) -> None:
               f"attempts={rec.get('attempts', 1)}"
               + (f"  [{ph_txt}]" if ph_txt else ""))
     if args.events:
-        w = worker_mod.global_worker
-        events = w.conductor.call("get_requesttrace_events",
-                                  args.events, timeout=10.0)
-        _print_event_tail(events, args.events)
+        _print_event_tail(state.events("requesttrace", args.events),
+                          args.events)
 
 
 def cmd_lora(args) -> None:
@@ -874,7 +848,6 @@ def cmd_lora(args) -> None:
     surface (state API, /api/lora, Prometheus, `lora` timeline lane)
     reports from the same snapshots."""
     _connect(args)
-    from ray_tpu._private import worker as worker_mod
     from ray_tpu.util import state
 
     st = state.lora_status()
@@ -915,10 +888,8 @@ def cmd_lora(args) -> None:
               f"misses={ts.get('misses', 0)} "
               f"swaps={ts.get('swaps', 0)}")
     if args.events:
-        w = worker_mod.global_worker
-        events = w.conductor.call("get_lora_events", args.events,
-                                  timeout=10.0)
-        _print_event_tail(events, args.events)
+        _print_event_tail(state.events("lora", args.events),
+                          args.events)
 
 
 def cmd_autoscale(args) -> None:
@@ -928,7 +899,6 @@ def cmd_autoscale(args) -> None:
     other surface (state API, /api/autoscale, Prometheus, timeline
     markers) reports from the same snapshots."""
     _connect(args)
-    from ray_tpu._private import worker as worker_mod
     from ray_tpu.util import state
 
     st = state.autoscaler_status()
@@ -963,10 +933,8 @@ def cmd_autoscale(args) -> None:
             for d in s["draining"]:
                 print(f"    DRAINING {d.get('tier')}:{d.get('rid')}")
     if args.events:
-        w = worker_mod.global_worker
-        events = w.conductor.call("get_autoscale_events", args.events,
-                                  timeout=10.0)
-        _print_event_tail(events, args.events)
+        _print_event_tail(state.events("autoscale", args.events),
+                          args.events)
 
 
 def cmd_oracle(args) -> None:
@@ -976,7 +944,6 @@ def cmd_oracle(args) -> None:
     totals every other surface (state API, /api/oracle, Prometheus,
     timeline counter track) reports from the same aggregate."""
     _connect(args)
-    from ray_tpu._private import worker as worker_mod
     from ray_tpu.util import state
 
     st = state.oracle_status()
@@ -1014,10 +981,8 @@ def cmd_oracle(args) -> None:
               f"layout={v.get('layout')} steps={v.get('n_steps')} "
               f"calibration={v.get('calibration', 1.0):.3f} {res}")
     if args.events:
-        w = worker_mod.global_worker
-        events = w.conductor.call("get_oracle_events", args.events,
-                                  timeout=10.0)
-        _print_event_tail(events, args.events)
+        _print_event_tail(state.events("oracle", args.events),
+                          args.events)
 
 
 def cmd_metrics(args) -> None:
@@ -1534,8 +1499,8 @@ def main(argv=None) -> None:
                          "built-in dryrun layout")
     sp.add_argument("--invariants", action="store_true",
                     help="also run the cross-module invariant engine "
-                         "(lock discipline, surface parity, env-knob "
-                         "registry, donation audit)")
+                         "(lock discipline, env-knob registry, "
+                         "donation audit)")
     sp.add_argument("--knob-table", action="store_true",
                     help="print the canonical RAY_TPU_* env-knob table "
                          "from the registry (markdown; rides the JSON "

@@ -82,6 +82,7 @@ import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 from ray_tpu.observability.step_timer import percentile
+from ray_tpu.util.telemetry import Pusher, emit
 
 _SEQ = itertools.count()
 
@@ -416,18 +417,6 @@ def _worker():
     return worker_mod.global_worker
 
 
-def _notify_event(event: Dict[str, Any]) -> None:
-    """Best-effort instant marker into the conductor's autoscale event
-    log (the merged timeline's `autoscale` lane)."""
-    w = _worker()
-    if w is None:
-        return
-    try:
-        w.conductor.notify("report_autoscale_event", dict(event))
-    except Exception:  # noqa: BLE001 — cluster shutting down
-        pass
-
-
 def _notify_resilience(event: Dict[str, Any]) -> None:
     """Drains ride the resilience grace flow — mirror them into its
     event log/counters too (the PR-4 lane preemptions already use)."""
@@ -540,8 +529,8 @@ class DisaggAutoscaler:
         self._managed: Dict[str, Tuple[str, Dict[str, Any]]] = {}
         self._heals: List[threading.Thread] = []
         self._last_tick: Optional[float] = None
-        self._last_push = 0.0
-        self._last_sf_push = 0.0
+        self._pusher = Pusher("autoscale", self.autoscaler_id)
+        self._sf_pusher = Pusher("servefault", self.autoscaler_id)
         self._teardowns: List[threading.Thread] = []
         self._stop = threading.Event()
         self._thread: Optional[threading.Thread] = None
@@ -696,7 +685,7 @@ class DisaggAutoscaler:
             ev = {"kind": "scale_up", "tier": tier, "replica": rid,
                   "to": target, "reason": reason,
                   "autoscaler": self.autoscaler_id}
-            _notify_event(ev)
+            emit("autoscale", ev)
             actions.append(ev)
         return actions
 
@@ -742,9 +731,9 @@ class DisaggAutoscaler:
                     self._stats["wakeups"][tier] += 1
                 autoscale_metrics()["decisions"].inc(
                     tags={"tier": tier, "direction": "up"})
-                _notify_event({"kind": "scale_from_zero", "tier": tier,
-                               "replica": rid,
-                               "autoscaler": self.autoscaler_id})
+                emit("autoscale", {"kind": "scale_from_zero", "tier": tier,
+                                   "replica": rid,
+                                   "autoscaler": self.autoscaler_id})
                 self.publish_telemetry(force=True)
             finally:
                 with self._lock:
@@ -781,7 +770,7 @@ class DisaggAutoscaler:
                   "to": target, "inflight": r["inflight"],
                   "grace_s": self.drain_grace_s, "reason": reason,
                   "autoscaler": self.autoscaler_id}
-            _notify_event(ev)
+            emit("autoscale", ev)
             _notify_resilience({"kind": "serve_drain", "name": r["rid"],
                                 "tier": tier,
                                 "grace_s": self.drain_grace_s})
@@ -831,7 +820,7 @@ class DisaggAutoscaler:
                   "replica": d.rid, "drained": bool(drained),
                   "waited_s": round(now - d.since, 3),
                   "autoscaler": self.autoscaler_id}
-            _notify_event(ev)
+            emit("autoscale", ev)
             actions.append(ev)
         finalized = [d for d in pending if d not in still]
         with self._lock:
@@ -968,13 +957,13 @@ class DisaggAutoscaler:
                     "replica": rid, "machine": machine,
                     "was_draining": was_draining,
                     "autoscaler": self.autoscaler_id}
-        _notify_event(death_ev)        # the autoscale lane
+        emit("autoscale", death_ev)        # the autoscale lane
         _notify_resilience(dict(death_ev))  # the servefault event slice
         if was_draining:
-            _notify_event({"kind": "scale_down", "tier": tier,
-                           "replica": rid, "drained": False,
-                           "reaped": True,
-                           "autoscaler": self.autoscaler_id})
+            emit("autoscale", {"kind": "scale_down", "tier": tier,
+                               "replica": rid, "drained": False,
+                               "reaped": True,
+                               "autoscaler": self.autoscaler_id})
         # breaker: decayed per-host death score through the existing
         # failure-domain tracker. The OPEN edge comes from the
         # tracker's own trip counter (incremented under ITS lock
@@ -1035,7 +1024,7 @@ class DisaggAutoscaler:
         servefault_metrics()["replacements"].inc(tags={"tier": tier})
         ev = {"kind": "replace", "tier": tier, "replica": rid,
               "for": dead_rid, "autoscaler": self.autoscaler_id}
-        _notify_event(ev)
+        emit("autoscale", ev)
         _notify_resilience(dict(ev))
         self.publish_servefault(force=True)
         self.publish_telemetry(force=True)
@@ -1059,13 +1048,7 @@ class DisaggAutoscaler:
         return sf
 
     def publish_servefault(self, force: bool = False) -> None:
-        from .disagg import _push_servefault
-
-        now = time.monotonic()
-        if not force and now - self._last_sf_push < 0.5:
-            return
-        self._last_sf_push = now
-        _push_servefault(self.autoscaler_id, self.servefault_stats())
+        self._sf_pusher.push(self.servefault_stats, force=force)
 
     # ------------------------------------------------------------ status
 
@@ -1107,18 +1090,7 @@ class DisaggAutoscaler:
         return s
 
     def publish_telemetry(self, force: bool = False) -> None:
-        now = time.monotonic()
-        if not force and now - self._last_push < 0.5:
-            return
-        self._last_push = now
-        w = _worker()
-        if w is None:
-            return
-        try:
-            w.conductor.notify("report_autoscale_stats", w.worker_id,
-                               self.autoscaler_id, self.status())
-        except Exception:  # noqa: BLE001 — cluster shutting down
-            pass
+        self._pusher.push(self.status, force=force)
 
     # -------------------------------------------------------------- loop
 

@@ -89,6 +89,7 @@ import numpy as np
 
 from ray_tpu.exceptions import ActorError, WorkerCrashedError
 from ray_tpu.observability import requests as reqtrace
+from ray_tpu.util.telemetry import Pusher, emit
 
 from .autoscale import SlidingWindow, default_target_p99_ms
 from .handle import RequestShedError, shed_counter
@@ -252,54 +253,6 @@ def _worker():
     from ray_tpu._private import worker as worker_mod
 
     return worker_mod.global_worker
-
-
-def _notify_event(event: Dict[str, Any]) -> None:
-    """Best-effort instant marker into the conductor's disagg event log
-    (the merged timeline's `disagg` lane). No-op without a cluster."""
-    w = _worker()
-    if w is None:
-        return
-    try:
-        w.conductor.notify("report_disagg_event", dict(event))
-    except Exception:  # noqa: BLE001 — cluster shutting down
-        pass
-
-
-def _notify_kvplane(event: Dict[str, Any]) -> None:
-    """Best-effort instant marker into the conductor's kvplane event
-    log (the merged timeline's `kvplane` lane)."""
-    w = _worker()
-    if w is None:
-        return
-    try:
-        w.conductor.notify("report_kvplane_event", dict(event))
-    except Exception:  # noqa: BLE001 — cluster shutting down
-        pass
-
-
-def _push_stats(component_id: str, stats: Dict[str, Any]) -> None:
-    w = _worker()
-    if w is None:
-        return
-    try:
-        w.conductor.notify("report_disagg_stats", w.worker_id,
-                           component_id, stats)
-    except Exception:  # noqa: BLE001 — cluster shutting down
-        pass
-
-
-def _push_servefault(component_id: str, stats: Dict[str, Any]) -> None:
-    """Servefault snapshot -> conductor aggregate (state API, CLI,
-    /api/servefault, and the one-set-of-numbers check read it)."""
-    w = _worker()
-    if w is None:
-        return
-    try:
-        w.conductor.notify("report_servefault_stats", w.worker_id,
-                           component_id, stats)
-    except Exception:  # noqa: BLE001 — cluster shutting down
-        pass
 
 
 def _notify_resilience(event: Dict[str, Any]) -> None:
@@ -505,7 +458,9 @@ class PrefillServer:
             "prefills", "prefilled_tokens", "reused_tokens",
             "published_transfers", "published_bytes", "acked",
             "reaped_unacked")}
-        self._last_push = 0.0
+        self._pusher = Pusher("disagg", self.server_id)
+        self._kv_pusher = Pusher("kvcache", self.server_id)
+        self._kvplane_pusher = Pusher("kvplane", self.server_id)
         disagg_metrics()  # lazy registration before the first event
 
     # ---------------------------------------------------------- data plane
@@ -540,7 +495,7 @@ class PrefillServer:
                 with self._lock:
                     self._kvp_stats["evict_storms"] += 1
                     self._kvp_stats["storm_evicted_blocks"] += evicted
-                _notify_kvplane({"kind": "evict_storm",
+                emit("kvplane", {"kind": "evict_storm",
                                  "replica": self.server_id,
                                  "blocks": evicted,
                                  "requested": storm})
@@ -639,10 +594,10 @@ class PrefillServer:
             self._stats["reused_tokens"] += int(reused)
             self._stats["published_transfers"] += 1
             self._stats["published_bytes"] += nbytes
-        _notify_event({"kind": "kv_publish", "server": self.server_id,
-                       "transfer_id": rec["transfer_id"],
-                       "bytes": nbytes, "plen": plen,
-                       "outcome": outcome})
+        emit("disagg", {"kind": "kv_publish", "server": self.server_id,
+                        "transfer_id": rec["transfer_id"],
+                        "bytes": nbytes, "plen": plen,
+                        "outcome": outcome})
         if w is not None and self.kvplane:
             self._maybe_publish_t3(prompt[0], namespace)
         self.publish_telemetry()
@@ -674,7 +629,7 @@ class PrefillServer:
                 self._kvp_stats["tier3_reused_tokens"] += reused
             self._kvp_stats["tier3_fetched_bytes"] += fetched
         if adopted:
-            _notify_kvplane({"kind": "tier3_adopt",
+            emit("kvplane", {"kind": "tier3_adopt",
                              "replica": self.server_id,
                              "blocks": int(adopted),
                              "tokens": reused, "nbytes": fetched,
@@ -742,7 +697,7 @@ class PrefillServer:
     def kvplane_stats(self) -> Dict[str, Any]:
         """This replica's kvplane snapshot (tier-2 arena + tier-3
         holder counters + per-caller fabric attribution) — one
-        component of the conductor's get_kvplane_stats aggregate."""
+        component of the `kvplane` telemetry row's aggregate."""
         from ray_tpu.util import chunks
 
         s: Dict[str, Any] = {"role": "prefill",
@@ -865,35 +820,22 @@ class PrefillServer:
         return s
 
     def publish_telemetry(self, force: bool = False) -> None:
-        now = time.monotonic()
-        if not force and now - self._last_push < 0.5:
+        if not self._pusher.push(self.stats, force=force):
             return
-        self._last_push = now
-        _push_stats(self.server_id, self.stats())
         if self.lora_pool is not None:
             self.lora_pool.publish_telemetry(force=force)
-        w = _worker()
-        if w is None:
-            if self.kv_cache is not None:
-                self.kv_cache.drain_events()
-            if self.arena is not None:
-                self.arena.drain_events()
-            return
-        try:
-            w.conductor.notify("report_kvcache_stats", w.worker_id,
-                               self.server_id, self.kv_stats())
-            if self.kv_cache is not None:
-                for ev in self.kv_cache.drain_events():
-                    ev.setdefault("engine", self.server_id)
-                    w.conductor.notify("report_kvcache_event", ev)
-            if self.kvplane:
-                w.conductor.notify("report_kvplane_stats", w.worker_id,
-                                   self.server_id,
-                                   self.kvplane_stats())
-                for ev in self.arena.drain_events():
-                    w.conductor.notify("report_kvplane_event", ev)
-        except Exception:  # noqa: BLE001 — cluster shutting down
-            pass
+
+        def kv_events():
+            kv = (self.kv_cache.drain_events()
+                  if self.kv_cache is not None else [])
+            for ev in kv:
+                ev.setdefault("engine", self.server_id)
+            return kv
+
+        self._kv_pusher.push(self.kv_stats, kv_events, force=True)
+        if self.arena is not None:
+            self._kvplane_pusher.push(self.kvplane_stats,
+                                      self.arena.drain_events, force=True)
 
 
 # ------------------------------------------------------------- decode tier
@@ -971,7 +913,7 @@ class DecodeServer:
         self._stats = {k: 0 for k in (
             "transfers", "kv_fetched_bytes", "shm_bytes", "rpc_bytes",
             "chunks_local", "decoded_tokens")}
-        self._last_push = 0.0
+        self._pusher = Pusher("disagg", self.server_id)
         disagg_metrics()
 
     _STREAM_REAP_S = 600.0
@@ -1023,12 +965,12 @@ class DecodeServer:
         m = disagg_metrics()
         m["transfers"].inc()
         m["kv_bytes"].inc(nbytes, tags={"direction": "recv"})
-        _notify_event({"kind": "kv_transfer", "server": self.server_id,
-                       "transfer_id": rec.get("transfer_id"),
-                       "bytes": nbytes, "plen": rec["plen"],
-                       "shm_bytes": acc["shm_bytes"],
-                       "rpc_bytes": acc["rpc_bytes"],
-                       "outcome": rec.get("outcome")})
+        emit("disagg", {"kind": "kv_transfer", "server": self.server_id,
+                        "transfer_id": rec.get("transfer_id"),
+                        "bytes": nbytes, "plen": rec["plen"],
+                        "shm_bytes": acc["shm_bytes"],
+                        "rpc_bytes": acc["rpc_bytes"],
+                        "outcome": rec.get("outcome")})
         # flight recorder: in-process routers have the request trace
         # active on THIS thread (the open kv_transfer span absorbs the
         # fetch breakdown); an actor-mode replica has no thread-local
@@ -1250,11 +1192,8 @@ class DecodeServer:
         return s
 
     def publish_telemetry(self, force: bool = False) -> None:
-        now = time.monotonic()
-        if not force and now - self._last_push < 0.5:
+        if not self._pusher.push(self.stats, force=force):
             return
-        self._last_push = now
-        _push_stats(self.server_id, self.stats())
         if self.lora_pool is not None:
             self.lora_pool.publish_telemetry(force=force)
         # the engine's own kvcache push carries the adoption counters
@@ -1464,8 +1403,10 @@ class DisaggRouter:
         # the resumed stream's re-prefill landing (the chaos benchmark
         # reports this window's summary as the recovery impact)
         self._failover_win = SlidingWindow()
-        self._last_push = 0.0
-        self._last_sf_push = 0.0
+        self._pusher = Pusher("disagg", self.router_id)
+        self._kvplane_pusher = Pusher("kvplane", self.router_id)
+        self._lora_pusher = Pusher("lora", self.router_id)
+        self._sf_pusher = Pusher("servefault", self.router_id)
         disagg_metrics()
         servefault_metrics()
 
@@ -1551,8 +1492,8 @@ class DisaggRouter:
         if woke:
             with self._lock:
                 self._stats["tier_wakeups"] += 1
-            _notify_event({"kind": "tier_wake",
-                           "router": self.router_id, "tier": tier})
+            emit("disagg", {"kind": "tier_wake",
+                            "router": self.router_id, "tier": tier})
         return woke
 
     def _lora_enabled(self) -> bool:
@@ -1702,9 +1643,9 @@ class DisaggRouter:
         shed_counter().inc(tags={"app": "disagg",
                                  "deployment": self.router_id})
         servefault_metrics()["sheds"].inc(tags={"cause": cause})
-        _notify_event({"kind": "shed", "router": self.router_id,
-                       "cause": cause,
-                       "retry_after_s": self.retry_after_s})
+        emit("disagg", {"kind": "shed", "router": self.router_id,
+                        "cause": cause,
+                        "retry_after_s": self.retry_after_s})
         self.publish_telemetry()
         self.publish_servefault()
         return RequestShedError(message, retry_after_s=self.retry_after_s,
@@ -1924,17 +1865,17 @@ class DisaggRouter:
             except Exception:  # noqa: BLE001 — victim mid-teardown
                 pass
         try:
-            from .qos import gateway_metrics, push_gateway_event
+            from .qos import gateway_metrics
 
             gateway_metrics()["preemptions"].inc()
-            push_gateway_event({"kind": "preempt",
-                                "router": self.router_id,
-                                "victim_tenant": victim.tenant,
-                                "tokens_done": victim.tokens})
+            emit("gateway", {"kind": "preempt",
+                             "router": self.router_id,
+                             "victim_tenant": victim.tenant,
+                             "tokens_done": victim.tokens})
         except Exception:  # noqa: BLE001 — telemetry only
             pass
-        _notify_event({"kind": "preempt", "router": self.router_id,
-                       "victim_tenant": victim.tenant})
+        emit("disagg", {"kind": "preempt", "router": self.router_id,
+                        "victim_tenant": victim.tenant})
         self.publish_telemetry()
 
     def _check_abort(self, deadline: Optional[float],
@@ -2076,7 +2017,7 @@ class DisaggRouter:
             kvplane_metrics()["directory"].inc(
                 tags={"outcome": outcome})
             if outcome == "hit":
-                _notify_kvplane({
+                emit("kvplane", {
                     "kind": "directory_hit", "router": self.router_id,
                     "replica": rep.rid,
                     "digest": dir_entry.get("digest"),
@@ -2894,39 +2835,26 @@ class DisaggRouter:
         return s
 
     def publish_telemetry(self, force: bool = False) -> None:
-        now = time.monotonic()
-        if not force and now - self._last_push < 0.5:
+        if not self._pusher.push(self.stats, force=force):
             return
-        self._last_push = now
-        _push_stats(self.router_id, self.stats())
         if self._disagg_mode and self._kvplane_dir:
-            w = _worker()
-            if w is not None:
-                try:
-                    w.conductor.notify("report_kvplane_stats",
-                                       w.worker_id, self.router_id,
-                                       self.kvplane_stats())
-                except Exception:  # noqa: BLE001 — shutting down
-                    pass
-        tenants = self.tenant_stats()
-        if tenants:
+            self._kvplane_pusher.push(self.kvplane_stats, force=True)
+
+        def tenant_counters():
             # the router's tenant counters ride the lora surface too,
             # beside the pools' paging stats (one aggregate, every
             # surface reads the same numbers)
-            w = _worker()
-            if w is not None:
-                try:
-                    w.conductor.notify(
-                        "report_lora_stats", w.worker_id,
-                        self.router_id,
-                        {"role": "router", "router_id": self.router_id,
-                         "tenant_affinity_hits":
-                             self._stats["tenant_affinity_hits"],
-                         "tenant_affinity_total":
-                             self._stats["tenant_affinity_total"],
-                         "tenants": tenants})
-                except Exception:  # noqa: BLE001 — shutting down
-                    pass
+            tenants = self.tenant_stats()
+            if not tenants:
+                return None
+            return {"role": "router", "router_id": self.router_id,
+                    "tenant_affinity_hits":
+                        self._stats["tenant_affinity_hits"],
+                    "tenant_affinity_total":
+                        self._stats["tenant_affinity_total"],
+                    "tenants": tenants}
+
+        self._lora_pusher.push(tenant_counters, force=True)
 
     def servefault_stats(self) -> Dict[str, Any]:
         """The fault-tolerance snapshot this router contributes to the
@@ -2945,11 +2873,7 @@ class DisaggRouter:
         return sf
 
     def publish_servefault(self, force: bool = False) -> None:
-        now = time.monotonic()
-        if not force and now - self._last_sf_push < 0.5:
-            return
-        self._last_sf_push = now
-        _push_servefault(self.router_id, self.servefault_stats())
+        self._sf_pusher.push(self.servefault_stats, force=force)
 
 
 __all__ = ["DecodeServer", "DisaggRouter", "PrefillServer",

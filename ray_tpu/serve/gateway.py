@@ -78,11 +78,12 @@ from concurrent.futures import ThreadPoolExecutor
 from typing import Any, Dict, List, Optional
 
 from ray_tpu.observability import requests as reqtrace
+from ray_tpu.util.telemetry import Pusher, emit
 
 from .autoscale import SlidingWindow
 from .handle import RequestShedError
 from .qos import (CLASSES, INTERACTIVE, QosGate, gateway_metrics,
-                  push_gateway_event, push_gateway_stats, shed_outcome)
+                  shed_outcome)
 
 _GW_SEQ = itertools.count()
 
@@ -235,7 +236,7 @@ class GatewayServer:
         self._by_code: Dict[str, int] = {}
         self._ttft_win: Dict[str, SlidingWindow] = {
             c: SlidingWindow() for c in CLASSES}
-        self._last_push = 0.0
+        self._pusher = Pusher("gateway", self.gateway_id)
         self._ready = threading.Event()
         self._bound_port: Optional[int] = None
         self._shutdown = threading.Event()
@@ -306,9 +307,9 @@ class GatewayServer:
             self._stats["accepted"] += 1
             if cls in self._by_class:
                 self._by_class[cls]["accepted"] += 1
-        push_gateway_event({"kind": "accept", "gateway": self.gateway_id,
-                            "route": route, "class": cls,
-                            "tenant": tenant})
+        emit("gateway", {"kind": "accept", "gateway": self.gateway_id,
+                         "route": route, "class": cls,
+                         "tenant": tenant})
         self.publish_telemetry()
 
     def _count_done(self, cls: str, n_tokens: int,
@@ -325,9 +326,9 @@ class GatewayServer:
         self._ttft_win.setdefault(cls, SlidingWindow()).add(ttft_ms)
         gateway_metrics()["ttft_ms"].observe(ttft_ms,
                                              tags={"class": cls})
-        push_gateway_event({"kind": "first_byte",
-                            "gateway": self.gateway_id, "class": cls,
-                            "ttft_ms": round(ttft_ms, 3)})
+        emit("gateway", {"kind": "first_byte",
+                         "gateway": self.gateway_id, "class": cls,
+                         "ttft_ms": round(ttft_ms, 3)})
 
     def stats(self) -> Dict[str, Any]:
         """This replica's snapshot — the shape the conductor
@@ -358,11 +359,7 @@ class GatewayServer:
         return s
 
     def publish_telemetry(self, force: bool = False) -> None:
-        now = time.monotonic()
-        if not force and now - self._last_push < 0.5:
-            return
-        self._last_push = now
-        push_gateway_stats(self.gateway_id, self.stats())
+        self._pusher.push(self.stats, force=force)
 
     # ------------------------------------------------------ http plumbing
 
@@ -629,9 +626,9 @@ class GatewayServer:
             # aiohttp cancelled the handler: the client went away
             cancel_event.set()
             self._count(route, cls, 499)
-            push_gateway_event({"kind": "disconnect",
-                                "gateway": self.gateway_id,
-                                "class": cls, "phase": "waiting"})
+            emit("gateway", {"kind": "disconnect",
+                             "gateway": self.gateway_id,
+                             "class": cls, "phase": "waiting"})
             if tr is not None:
                 tr.finish("disconnect", cause="client_gone")
             raise
@@ -853,19 +850,19 @@ class GatewayServer:
         except asyncio.CancelledError:
             cancel_event.set()
             self._count(route, cls, 499)
-            push_gateway_event({"kind": "disconnect",
-                                "gateway": self.gateway_id,
-                                "class": cls, "phase": "streaming"})
+            emit("gateway", {"kind": "disconnect",
+                             "gateway": self.gateway_id,
+                             "class": cls, "phase": "streaming"})
             _finish("disconnect", cause="client_gone",
                     tokens_sent=len(got))
             raise
         if disconnected:
             cancel_event.set()
             self._count(route, cls, 499)
-            push_gateway_event({"kind": "disconnect",
-                                "gateway": self.gateway_id,
-                                "class": cls, "phase": "streaming",
-                                "tokens_sent": len(got)})
+            emit("gateway", {"kind": "disconnect",
+                             "gateway": self.gateway_id,
+                             "class": cls, "phase": "streaming",
+                             "tokens_sent": len(got)})
             _finish("disconnect", cause="client_gone",
                     tokens_sent=len(got))
             return resp

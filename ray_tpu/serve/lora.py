@@ -69,6 +69,8 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from ray_tpu.util.telemetry import Pusher
+
 _POOL_SEQ = itertools.count()
 _EVENTS_KEPT = 512
 
@@ -439,7 +441,7 @@ class AdapterPool:
             "acquires", "hits", "misses", "evictions", "swaps",
             "page_in_bytes", "releases")}
         self._tenant_stats: Dict[str, Dict[str, int]] = {}
-        self._last_push = 0.0
+        self._pusher = Pusher("lora", self.pool_id)
         lora_metrics()  # lazy registration before the first event
 
     # ----------------------------------------------------------- helpers
@@ -809,21 +811,7 @@ class AdapterPool:
         the conductor (no-op without a live cluster); throttled unless
         forced — the one-set-of-numbers source for every lora
         surface."""
-        now = time.monotonic()
-        if not force and now - self._last_push < 0.5:
-            return
-        self._last_push = now
-        w = _worker()
-        if w is None:
-            self.drain_events()  # keep the buffer bounded
-            return
-        try:
-            w.conductor.notify("report_lora_stats", w.worker_id,
-                               self.pool_id, self.stats())
-            for ev in self.drain_events():
-                w.conductor.notify("report_lora_event", ev)
-        except Exception:  # noqa: BLE001 — cluster shutting down
-            pass
+        self._pusher.push(self.stats, self.drain_events, force=force)
 
 
 __all__ = ["AdapterPool", "FabricAdapterSource", "LocalAdapterSource",

@@ -38,6 +38,8 @@ import time
 from dataclasses import dataclass
 from typing import Any, Dict, Optional
 
+from ray_tpu.util.telemetry import emit
+
 from .handle import RequestShedError
 
 INTERACTIVE = "interactive"
@@ -101,38 +103,6 @@ def gateway_metrics() -> Dict[str, Any]:
             # half-built dict
             _metrics = m
     return _metrics
-
-
-def _worker():
-    from ray_tpu._private import worker as worker_mod
-
-    return worker_mod.global_worker
-
-
-def push_gateway_stats(component_id: str, stats: Dict[str, Any]) -> None:
-    """Best-effort snapshot push to the conductor's gateway roster
-    (feeds util.state.gateway_status(), `ray_tpu gateway`, and
-    /api/gateway with one set of numbers)."""
-    try:
-        w = _worker()
-        if w is None:
-            return
-        w.conductor.notify("report_gateway_stats", w.worker_id,
-                           str(component_id), stats)
-    except Exception:  # noqa: BLE001 — telemetry only
-        pass
-
-
-def push_gateway_event(event: Dict[str, Any]) -> None:
-    """Best-effort instant marker (accept / first_byte / preempt /
-    rate_limit / disconnect) for the merged timeline's gateway lane."""
-    try:
-        w = _worker()
-        if w is None:
-            return
-        w.conductor.notify("report_gateway_event", dict(event))
-    except Exception:  # noqa: BLE001 — telemetry only
-        pass
 
 
 # ------------------------------------------------------------ the gate
@@ -313,9 +283,9 @@ class QosGate:
         # rejection side effects OUTSIDE the lock — overload must not
         # serialize healthy admissions behind a socket write
         gateway_metrics()["rate_limited"].inc(tags={"tenant": key})
-        push_gateway_event({"kind": "rate_limit", "tenant": key,
-                            "cause": cause, "class": cls,
-                            "retry_after_s": round(retry_after, 3)})
+        emit("gateway", {"kind": "rate_limit", "tenant": key,
+                         "cause": cause, "class": cls,
+                         "retry_after_s": round(retry_after, 3)})
         raise RequestShedError(msg, retry_after_s=retry_after,
                                cause=cause)
 
@@ -342,5 +312,4 @@ class QosGate:
 
 
 __all__ = ["BATCH", "CLASSES", "INTERACTIVE", "QosGate", "TenantPolicy",
-           "TokenBucket", "gateway_metrics", "push_gateway_event",
-           "push_gateway_stats", "shed_outcome"]
+           "TokenBucket", "gateway_metrics", "shed_outcome"]

@@ -10,6 +10,8 @@ import time
 from collections import defaultdict
 from typing import Any, Dict, List, Optional
 
+from ray_tpu._private.telemetry import speculation_totals  # noqa: F401
+
 
 def _conductor():
     from ray_tpu._private import worker as worker_mod
@@ -129,40 +131,34 @@ def weight_versions(name: Optional[str] = None) -> Dict[str, Any]:
     return out
 
 
+def status(subsystem: str) -> Dict[str, Any]:
+    """One telemetry subsystem's aggregate (a row of
+    ray_tpu._private.telemetry.SUBSYSTEMS): the dict `ray_tpu
+    <subsystem>` prints and the dashboard serves at /api/<subsystem>."""
+    return _conductor().conductor.call("get_status", subsystem,
+                                       timeout=10.0)
+
+
+def events(subsystem: str, limit: int = 10_000) -> List[Dict[str, Any]]:
+    """The newest `limit` instant markers of one telemetry subsystem
+    (its lane of the merged timeline)."""
+    return _conductor().conductor.call("get_events", subsystem, limit,
+                                       timeout=10.0)
+
+
 def kv_cache_stats(engine: Optional[str] = None) -> Dict[str, Any]:
     """Paged-KV prefix-cache view (models/kvcache.py): per-engine stat
     snapshots (hits/misses/evictions, pool utilization, reused vs
     prefilled tokens) plus cluster totals with hit/token-reuse rates.
     The CLI analog is `python -m ray_tpu kvcache`; the dashboard serves
     it at /api/kvcache. `engine` filters to one engine id."""
-    out = _conductor().conductor.call("get_kvcache_stats", timeout=10.0)
+    out = status("kvcache")
     if engine is not None:
         out = {"engines": {k: v for k, v in out.get("engines",
                                                     {}).items()
                            if v.get("engine_id") == engine},
                "totals": out.get("totals", {})}
     return out
-
-
-def speculation_totals(engines: Dict[str, Dict[str, Any]]
-                       ) -> Dict[str, Any]:
-    """The ONE speculation rollup (counter sums + acceptance rate +
-    tokens-per-verify) — shared by the conductor's
-    get_speculation_stats and this module's engine filter so a new
-    counter can never make the filtered view disagree with the
-    cluster-wide one."""
-    totals: Dict[str, Any] = {
-        k: sum(int(e.get(k, 0)) for e in engines.values())
-        for k in ("spec_proposed", "spec_accepted",
-                  "spec_verify_ticks", "spec_emitted_tokens")}
-    totals["acceptance_rate"] = (
-        totals["spec_accepted"] / totals["spec_proposed"]
-        if totals["spec_proposed"] else 0.0)
-    totals["tokens_per_verify"] = (
-        totals["spec_emitted_tokens"] / totals["spec_verify_ticks"]
-        if totals["spec_verify_ticks"] else 0.0)
-    totals["engines"] = len(engines)
-    return totals
 
 
 def speculation_stats(engine: Optional[str] = None) -> Dict[str, Any]:
@@ -174,8 +170,7 @@ def speculation_stats(engine: Optional[str] = None) -> Dict[str, Any]:
     speculate`; the dashboard serves it at /api/speculation;
     spec_accept/spec_reject markers ride the merged timeline's kvcache
     lane. `engine` filters to one engine id."""
-    out = _conductor().conductor.call("get_speculation_stats",
-                                      timeout=10.0)
+    out = status("speculation")
     if engine is not None:
         engines = {k: v for k, v in out.get("engines", {}).items()
                    if v.get("engine_id") == engine}
@@ -210,8 +205,7 @@ def online_status() -> Dict[str, Any]:
     (steps, ingested rollouts/tokens, last published version) — plus
     cluster totals. The CLI analog is `python -m ray_tpu online`; the
     dashboard serves it at /api/online."""
-    return _conductor().conductor.call("get_online_status",
-                                       timeout=10.0)
+    return status("online")
 
 
 def disagg_status() -> Dict[str, Any]:
@@ -223,8 +217,7 @@ def disagg_status() -> Dict[str, Any]:
     high-water queue depth) — plus cluster totals. The CLI analog is
     `python -m ray_tpu disagg`; the dashboard serves it at
     /api/disagg."""
-    return _conductor().conductor.call("get_disagg_status",
-                                       timeout=10.0)
+    return status("disagg")
 
 
 def kvplane_status() -> Dict[str, Any]:
@@ -239,8 +232,7 @@ def kvplane_status() -> Dict[str, Any]:
     /api/kvplane; spill/tier2_hit/tier3_publish/tier3_adopt/
     directory_hit markers ride the merged timeline's `kvplane`
     lane."""
-    return _conductor().conductor.call("get_kvplane_status",
-                                       timeout=10.0)
+    return status("kvplane")
 
 
 def lora_status() -> Dict[str, Any]:
@@ -252,8 +244,7 @@ def lora_status() -> Dict[str, Any]:
     is `python -m ray_tpu lora`; the dashboard serves it at
     /api/lora; page_in/evict/swap markers ride the merged timeline's
     `lora` lane."""
-    return _conductor().conductor.call("get_lora_status",
-                                      timeout=10.0)
+    return status("lora")
 
 
 def gateway_status() -> Dict[str, Any]:
@@ -265,8 +256,7 @@ def gateway_status() -> Dict[str, Any]:
     ray_tpu gateway`; the dashboard serves it at /api/gateway; the
     accept/first_byte/preempt/rate_limit/disconnect markers ride the
     merged timeline's `gateway` lane."""
-    return _conductor().conductor.call("get_gateway_status",
-                                       timeout=10.0)
+    return status("gateway")
 
 
 def requesttrace_status() -> Dict[str, Any]:
@@ -279,8 +269,7 @@ def requesttrace_status() -> Dict[str, Any]:
     ray_tpu requests`; the dashboard serves it at /api/requesttrace;
     kept traces render as real spans in the merged timeline's
     `requests` lane."""
-    return _conductor().conductor.call("get_requesttrace_status",
-                                       timeout=10.0)
+    return status("requesttrace")
 
 
 def request_trace(request_id: str) -> Optional[Dict[str, Any]]:
@@ -303,8 +292,7 @@ def servefault_status() -> Dict[str, Any]:
     instant markers live in the merged timeline's RESILIENCE lane. The
     CLI analog is `python -m ray_tpu servefault`; the dashboard serves
     it at /api/servefault."""
-    return _conductor().conductor.call("get_servefault_status",
-                                       timeout=10.0)
+    return status("servefault")
 
 
 def autoscaler_status() -> Dict[str, Any]:
@@ -316,8 +304,7 @@ def autoscaler_status() -> Dict[str, Any]:
     it at /api/autoscale. (The NODE-level autoscaler —
     ray_tpu.autoscaler, which launches/terminates hosts — mirrors its
     status separately at /api/autoscaler.)"""
-    return _conductor().conductor.call("get_autoscale_status",
-                                       timeout=10.0)
+    return status("autoscale")
 
 
 def oracle_status() -> Dict[str, Any]:
@@ -327,8 +314,7 @@ def oracle_status() -> Dict[str, Any]:
     tail (per-phase residuals, fitted calibration), and totals. The CLI
     analog is `python -m ray_tpu oracle`; the dashboard serves it at
     /api/oracle."""
-    return _conductor().conductor.call("get_oracle_status",
-                                       timeout=10.0)
+    return status("oracle")
 
 
 def resilience_status() -> Dict[str, Any]:

@@ -615,65 +615,6 @@ def test_env_knob_suppression(tmp_path):
     assert fs == []
 
 
-def _write_surface_tree(root, timeline_src):
-    """A minimal ray_tpu-shaped tree with one conductor subsystem
-    ('widget') and every surface except whatever timeline_src omits."""
-    for rel, src in {
-        "_private/conductor.py":
-            "class Handler:\n"
-            "    def report_widget_stats(self, s):\n"
-            "        pass\n"
-            "    def get_widget_stats(self):\n"
-            "        return {}\n",
-        "util/state.py": "def widget_status():\n    return {}\n",
-        "scripts/cli.py":
-            "def build(sub):\n"
-            "    sp = sub.add_parser('widget')\n",
-        "dashboard/__init__.py": "ROUTE = '/api/widget'\n",
-        "observability/timeline.py": timeline_src,
-        "util/metrics.py": "FAMILY = \"ray_tpu_widget_requests\"\n",
-    }.items():
-        dest = root / rel
-        dest.parent.mkdir(parents=True, exist_ok=True)
-        dest.write_text(src)
-
-
-def test_surface_parity_fires_and_passes(tmp_path):
-    """Seeded violation: a conductor subsystem with every surface BUT
-    the timeline lane errors naming the missing surface; adding the
-    lane clears it."""
-    from ray_tpu.analysis import check_surface_parity
-
-    pkg = tmp_path / "pkg"
-    _write_surface_tree(pkg, "def unrelated():\n    return []\n")
-    fs = check_surface_parity(str(pkg))
-    assert len(fs) == 1 and fs[0].rule == "surface-parity"
-    assert fs[0].severity == "error"
-    assert "'widget'" in fs[0].message and "no timeline" in fs[0].message
-    assert "conductor.py:2" in fs[0].location
-
-    _write_surface_tree(
-        pkg, "def widget_trace_events(evs):\n    return []\n")
-    assert check_surface_parity(str(pkg)) == []
-
-
-def test_surface_parity_suppression(tmp_path):
-    """`# shardlint: disable=surface-parity` on the conductor method
-    waives one subsystem (the documented alternative to a
-    PARITY_WAIVERS entry)."""
-    from ray_tpu.analysis import analyze_invariants
-
-    pkg = tmp_path / "pkg"
-    _write_surface_tree(pkg, "def unrelated():\n    return []\n")
-    conductor = pkg / "_private" / "conductor.py"
-    conductor.write_text(
-        "class Handler:\n"
-        "    def report_widget_stats(self, s):"
-        "  # shardlint: disable=surface-parity\n"
-        "        pass\n")
-    assert analyze_invariants(str(pkg), readme_text="") == []
-
-
 def test_envknobs_accessor_caches_and_retunes(monkeypatch):
     """util/envknobs: the parse is memoized on the raw string — same
     raw returns the cached value, a changed env re-parses (live

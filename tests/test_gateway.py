@@ -535,7 +535,7 @@ def test_state_api_sees_gateway_telemetry(gateway_cluster, model):
         assert totals["by_code"].get("200", 0) >= 1
 
         w = gateway_cluster
-        events = w.conductor.call("get_gateway_events", limit=10_000)
+        events = w.conductor.call("get_events", "gateway", limit=10_000)
         kinds = {e.get("kind") for e in events}
         assert "accept" in kinds
 

@@ -466,7 +466,7 @@ def test_directory_ttl_reap_and_gc(kvplane_cluster, monkeypatch):
     for i in range(5):
         w.conductor.call("kvplane_publish", "gcns", f"b{i}" * 32, meta)
     assert w.conductor.call("kvplane_gc", 2, "gcns") == 3
-    st = w.conductor.call("get_kvplane_status")
+    st = w.conductor.call("get_status", "kvplane")
     assert st["directory"]["namespaces"].get("gcns") == 2
     ctr = st["directory"]["counters"]
     assert ctr["reaped"] >= 3 and ctr["gced"] >= 3

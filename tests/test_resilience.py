@@ -416,6 +416,22 @@ def test_resume_matches_uninterrupted_run(tmp_path, monkeypatch):
                                                    rel=1e-12)
 
 
+def _await_resilience_count(kind: str, n: int) -> None:
+    """Block (60 s at most) until the conductor has counted `n`
+    resilience events of `kind`. The ranks of a gang without collectives
+    run unsynchronised, and a loaded host starts one a second after
+    another: a scripted scenario waits on what it depends on, it does
+    not sleep."""
+    import time as _t
+
+    from ray_tpu.util import state
+
+    deadline = _t.monotonic() + 60.0
+    while state.resilience_status()["counters"].get(kind, 0) < n \
+            and _t.monotonic() < deadline:
+        _t.sleep(0.05)
+
+
 def _async_grace_train_fn(cfg):
     """_sgd_train_fn with the grace checkpoint taken through an
     AsyncCheckpointer whose artificial write delay far exceeds the test
@@ -429,8 +445,14 @@ def _async_grace_train_fn(cfg):
     from ray_tpu.train import (get_checkpoint, get_context,
                                preemption_requested, report)
     from ray_tpu.train import async_checkpoint as _ac
+    from ray_tpu.train.session import _report_resilience_event
 
     ctx = get_context()
+    # a rank is subscribed to the preemption notice before its train fn
+    # starts: rank 0's step 2 fires it only when every rank is here
+    _report_resilience_event({"kind": "rank_ready",
+                              "rank": ctx.get_world_rank()})
+    _await_resilience_count("rank_ready", ctx.get_world_size())
     ckpter = _ac.AsyncCheckpointer()
     ckpter._test_write_delay = float(cfg.get("write_delay", 0.0))
     step, w = 0, _np.full(4, 5.0)
@@ -450,6 +472,19 @@ def _async_grace_train_fn(cfg):
             ckpt = ckpter.save(d, {"step": _np.int64(step), "w": w})
         report({"step": step, "loss": loss,
                 "world": ctx.get_world_size()}, checkpoint=ckpt)
+        if step == 2:
+            # the notice rides pubsub: step 3 starts when it has landed
+            # here, not after a sleep a loaded host outlasts
+            deadline = _t.monotonic() + 60.0
+            while preemption_requested() is None \
+                    and _t.monotonic() < deadline:
+                _t.sleep(0.01)
+        if step == 5:
+            # and the step-6 kill lands when every rank's grace
+            # checkpoint is committed and acked, not while a loaded
+            # host's writer is still at it
+            _await_resilience_count("grace_checkpoint",
+                                    ctx.get_world_size())
         if cfg.get("step_sleep"):
             _t.sleep(float(cfg["step_sleep"]))
 
@@ -474,10 +509,10 @@ def test_async_grace_checkpoint_commits_within_window(tmp_path,
         "restart_backoff_max_s": 0.2,
     })
     try:
-        # generous step spacing: the preemption broadcast rides pubsub
-        # and must land on the workers BEFORE the kill step even on a
-        # loaded machine — too-tight spacing flakes into
-        # resume-from-scratch
+        # the train fn waits for every rank to have started, for the
+        # step-2 preemption notice to land and, before the kill step,
+        # for the grace checkpoints' acks: the newest one is step 3's on
+        # any machine
         monkeypatch.setenv("RAY_TPU_CHAOS_PLAN", json.dumps([
             {"action": "preempt", "node": "head", "grace_s": 15.0,
              "at_step": 2},
@@ -507,7 +542,7 @@ def test_async_grace_checkpoint_commits_within_window(tmp_path,
             assert m["loss"] == pytest.approx(expected[m["step"] - 1],
                                               rel=1e-12)
         first_resumed = result.metrics_history[0]["step"]
-        assert 3 < first_resumed <= 7, first_resumed
+        assert first_resumed == 4, first_resumed
         st = state.resilience_status()
         assert st["counters"].get("grace_checkpoint", 0) >= 1
     finally:
