@@ -64,6 +64,9 @@ CASES = {
     "smallthinker-ring": ((16, 1, 28, 4, 128, 4096), 16, 4095, 4096, 0),
     "jamba2-docqa": ((8, 1, 20, 1, 128, 33280), 8, 4000, 18500, 0),
     "nemotron-reason": ((96, 1, 32, 2, 128, 1536), 27, 300, 1100, 0),
+    # four heads of 64 packed to a row of 256 lanes: the entry lies head
+    # by head (PR 61; the slab form's scale is d's here, a time all the same)
+    "gpt2-chat": ((64, 1, 12, 3, 256, 1024), 3, 100, 640, 0),
 }
 TOY = {name: ((4, t, 2 * h // g, 2, 32, 96), 2, 40, 90, parked)
        for name, ((_, t, h, g, _, _), _, _, _, parked) in CASES.items()}

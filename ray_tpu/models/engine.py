@@ -207,7 +207,7 @@ at the positions the tick was launched with: whole blocks, a dead slot's
 one block, by the function the kernels' walks use,
 `ops/swa.decode_rows_read`, for keys and values and for latent rows
 alike; ``max_batch`` x rows for a family whose tick reads every row or
-the rows a selection marks, GPT-2 and `dots3_note`:
+the rows a selection marks, `dots3_note`:
 `Family.decode_walks`), for a family with state
 ``state_slots_stepped`` (the slot-states ONE layer's state step visits:
 the slots the chip held live at the launch, counted on the host from

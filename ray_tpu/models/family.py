@@ -60,10 +60,13 @@ class Family:
     every block's ["attn"] an adapter applies to, `((name, in, out),
     ...)`. `decode_walks`: the tick's attention walks
     each slot's rows up to its position, block by block
-    (`ops/swa.decode_attention` over keys and values,
-    `ops/mla.absorbed_attention` by positions over latent rows), by the
-    block `ops/swa.decode_block` gives for the slab's longest entry; a
-    tick that reads every row, or rows the caller marks, does not.
+    (`ops/swa.decode_attention` over keys and values [B, S, G, d] of any
+    G and whole 128-lane rows, several packed heads to a row among them:
+    `models/gpt2.py` hands each as a query head with the other heads'
+    lanes zeroed; `ops/mla.absorbed_attention` by positions over latent
+    rows [B, S, width]), by the block `ops/swa.decode_block` gives for
+    the slab's longest entry; a tick that reads every row, or rows the
+    caller marks, does not.
     `state_walks`: the tick's state step visits the live slots alone:
     `decode` takes `live` [B], the tick's own liveness vector, beside its
     other arguments (`ops/mamba2.ssd_step`, `ops/kda.kda_step`)."""

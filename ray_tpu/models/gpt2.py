@@ -232,16 +232,16 @@ def _qkv_rows(qkv: jax.Array, cache: Params):
 
 def _cache_attention(q: jax.Array, ck: jax.Array, cv: jax.Array,
                      positions: jax.Array, head_dim: int) -> jax.Array:
-    """Masked attention over the cache as it lies: q [B, t, g, W] and
-    ck/cv [B, S, g, W] hold p = W // head_dim heads to a row; query
-    (b, j) sees rows <= positions[b, j] (positions [B, t] or [1, t]).
-    Rows are contracted whole, the grouped form of
-    ops/swa.cache_attention with g rows and p queries a row: the query
-    of head r is its row with every lane outside its own head_dim set
-    to zero, so its scores are exactly its own (the other lanes add
-    0 * k), and its output is its own lanes of the row its
-    probabilities give. Nothing narrower than a row is ever formed.
-    Returns [B, t, g * W], heads in their order."""
+    """A PROMPT's and a SUFFIX's attention alone (`_attention` chooses; a
+    tick walks): masked float32 scores against ALL S rows of the cache as
+    it lies. q [B, t, g, W], ck/cv [B, S, g, W], p = W // head_dim heads
+    to a row; query (b, j) sees rows <= positions[b, j] ([B, t] or [1,
+    t]). Rows are contracted whole, ops/swa.slab_attention with g rows
+    and p queries a row: head r's query is its row with every lane
+    outside its own head_dim set to zero, so its scores are exactly its
+    own (the other lanes add 0 * k), and its output is its own lanes of
+    the row its probabilities give. Nothing narrower than a row is ever
+    formed. Returns [B, t, g * W], heads in their order."""
     b, t, g, w = q.shape
     p = w // head_dim
     own = jnp.arange(w)[None, :] // head_dim == jnp.arange(p)[:, None]
@@ -265,7 +265,7 @@ def _block_cached(x: jax.Array, p: Params, config: GPT2Config,
     """Cache-path block: tokens at [pos, pos+t) attend the full written
     prefix — the GPT-2 analog of llama_block_cached. The new rows go
     into the cache in its own row shape (`_qkv_rows`), and the
-    attention reads it where it lies (`_cache_attention`)."""
+    attention reads it where it lies (`_attention`)."""
     c = config
     t = x.shape[1]
     h = layer_norm(x, p["ln_1"]["scale"], p["ln_1"]["bias"])
@@ -276,7 +276,7 @@ def _block_cached(x: jax.Array, p: Params, config: GPT2Config,
     ck = jax.lax.dynamic_update_slice(cache["k"], k, (0, pos, 0, 0))
     cv = jax.lax.dynamic_update_slice(cache["v"], v, (0, pos, 0, 0))
     positions = pos + jnp.arange(t)[None, :]
-    a = _cache_attention(q, ck, cv, positions, c.head_dim)
+    a = _attention(q, ck, cv, positions, c.head_dim)
     return _mlp_res(_attn_proj_res(x, a, p, c), p, c), {"k": ck, "v": cv}
 
 
@@ -289,8 +289,8 @@ def _block_decode(x: jax.Array, p: Params, config: GPT2Config,
     one-token tick; t == k+1: the speculative verify pass — see
     llama_block_decode for the masking contract the oracle rests on).
     One row a slot and position is scattered into the cache in its own
-    row shape, and the attention is `_block_cached`'s
-    (`_cache_attention`), so a verify row is a sequential tick's math.
+    row shape, and the attention is `_block_cached`'s (`_attention`:
+    the decode walk), so a verify row is a sequential tick's math.
 
     `lora` (optional, serve/lora.py mixed-tenant decode): this layer's
     per-slot adapter selection for the fused qkv projection —
@@ -315,7 +315,7 @@ def _block_decode(x: jax.Array, p: Params, config: GPT2Config,
           jnp.arange(k.shape[2])[None, None, :])
     ck = cache["k"].at[at].set(k)
     cv = cache["v"].at[at].set(v)
-    a = _cache_attention(q, ck, cv, positions, c.head_dim)
+    a = _attention(q, ck, cv, positions, c.head_dim)
     return _mlp_res(_attn_proj_res(x, a, p, c), p, c), {"k": ck, "v": cv}
 
 
@@ -466,8 +466,38 @@ def gpt2_lora_targets(config: GPT2Config):
     return (("qkv", config.d_model, 3 * config.d_model),)
 
 
+# Down here, and imported here, because the training step's Mosaic
+# kernels are keyed in the compile cache by the lines of their call
+# sites above (`_block`, `gpt2_loss`; ROADMAP D21): those must not move.
+from ..ops.swa import DECODE_ROWS, decode_attention  # noqa: E402
+
+
+def _attention(q: jax.Array, ck: jax.Array, cv: jax.Array,
+               positions: jax.Array, head_dim: int) -> jax.Array:
+    """The cached paths' attention in the form the run's length asks
+    for, read from `q`'s shape alone as `ops/swa.cache_attention` reads
+    it. A run of at most `DECODE_ROWS` rows is a tick's (one token a
+    slot, or the speculative verify's k + 1) and takes the decode walk:
+    `_cache_attention`'s zeroed rows ride `ops/swa.decode_attention` as
+    g x p query heads over g key-value heads of W numbers, scaled by a
+    head's own width, so slot b's blocks up to its position are read and
+    no other. A longer one (a prompt, a suffix on a cached prefix,
+    `generate()`'s prefill) takes `_cache_attention` over the slab."""
+    b, t, g, w = q.shape
+    if t > DECODE_ROWS:
+        return _cache_attention(q, ck, cv, positions, head_dim)
+    p = w // head_dim
+    own = jnp.arange(w)[None, :] // head_dim == jnp.arange(p)[:, None]
+    qr = jnp.where(own, q[:, :, :, None, :], 0)          # [B, t, g, p, W]
+    a = decode_attention(qr.reshape(b, t, g * p, w), ck, cv,
+                         jnp.broadcast_to(positions, (b, t)),
+                         head_dim ** -0.5).reshape(b, t, g, p, w)
+    # a head's own lanes of the row its probabilities gave
+    return jnp.where(own, a, 0).sum(3).reshape(b, t, g * w)
+
+
 FAMILY = Family(
     config_type=GPT2Config, init=gpt2_init, forward=gpt2_forward,
     loss=gpt2_loss, partition_specs=gpt2_partition_specs,
     init_cache=gpt2_init_kv_cache, forward_cached=gpt2_forward_cached,
-    decode=gpt2_decode, lora_targets=gpt2_lora_targets)
+    decode=gpt2_decode, lora_targets=gpt2_lora_targets, decode_walks=True)

@@ -29,35 +29,35 @@ query heads stacked, and whose walk is a band.
             `jax.numpy`, which is also the kernel's reference.
   decode    a tick's run (`decode_attention`: one token a slot, or the
             speculative verify's k + 1) over the slab entry [B, S, G, d]
-            WHERE IT LIES: no transpose, no repeat, no copy; a block of
-            rows is all G heads, contiguous. Slot b's walk visits the
-            blocks 0 .. `positions[b, -1] // block` and no other
-            (`decode_blocks`), under a running softmax with float32
-            scores and accumulator, so a tick reads the rows its slots
-            hold and not `max_batch x max_seq_len`; a parked slot
+            WHERE IT LIES: no transpose, no repeat, no copy. Slot b's
+            walk visits the blocks 0 .. `positions[b, -1] // block` and
+            no other (`decode_blocks`), under a running softmax with
+            float32 scores and accumulator, so a tick reads the rows its
+            slots hold and not `max_batch x max_seq_len`; a parked slot
             (position 0: `models/engine.py` `_finish`) costs one block.
             Row j of a run masks by its own position, and a block it
             sees nothing of leaves its max, sum and accumulator untouched
             to the bit: a verify row is a sequential tick's. On a TPU it
             is the Pallas kernel `gqa_decode_t<t>`, ONE call a layer: the
             positions scalar-prefetched, the queries resident, the walk
-            the kernel's own loops (slots, then a slot's blocks: a
-            dynamic trip count) over blocks it copies from HBM itself,
-            two copies ahead of the products (the next slots' first
-            blocks behind a slot's last, so 29 parked slots stream like
-            one long one). The entry is read as [B, S G, d], which on
-            the chip is the same bytes (as [B, S, G d] it is not: XLA
-            then copies the slab), and a slot's `t x H` query rows are
-            the rows of ONE product against a block's `block x G` key
-            rows, padded to whole sublanes in VMEM, never in HBM; a score
-            of another key-value head's key is masked like a row past
-            the position, so the matrix unit takes each key tile once for
-            all heads. The block
-            follows from the entry's shape (`_decode_block`). Elsewhere
-            the same blocks in `jax.numpy`, the kernel's reference.
+            the kernel's own loops (slots, then a slot's blocks) over
+            blocks it copies from HBM itself, two copies ahead of the
+            products. Any G and any d of whole 128-lane tiles go, under
+            the axes the chip lays the entry down in (`_lies_by_group`):
+            G a power of two as [B, S G, d], a block all G heads'
+            rows, contiguous; any other G as [B, G, S, d], a block G
+            runs of rows, one strided copy (as [B, S, G d] XLA copies
+            the slab). A slot's `t x H` query rows are the rows of ONE
+            product against a block's `block x G` key rows; a score of
+            another key-value head's key is masked like a row past the
+            position. Heads narrower than a row ride it PACKED
+            (`models/gpt2.py`: p heads to a row of W lanes): each is a
+            query head whose row has the other heads' lanes zeroed, so
+            H = G x p, d = W, and `scale` is the head's own width's. The
+            block follows from the entry's shape (`_decode_block`).
+            Elsewhere the same blocks in `jax.numpy`, the reference.
             `dispatch.kernel_choices("gqa_decode")` lists the shapes (B,
-            t, H, G, d, S) and which ran; `decode_rows_read` is the
-            host's count of the rows a walk reads.
+            t, H, G, d, S); `decode_rows_read` is the host's count.
 
 The window counts the query's own position: query i sees keys j with
 `i - window < j <= i`, so a ring of `window` rows holds exactly what a
@@ -428,7 +428,8 @@ def _block_seen(q_at, k_at, j, block: int):
     return (k_at <= q_at) & (k_at >= j * block)
 
 
-def _decode_walk(q, ck, cv, positions, block: int):
+def _decode_walk(q, ck, cv, positions, block: int,
+                 scale: Optional[float] = None):
     """The decode form's running softmax in `jax.numpy`, block by block
     as the kernel walks: q [B, t, H, d], ck, cv [B, S, G, d], positions
     [B, t] -> (max, sum, accumulator) [B, G, rep, t, .], float32. Every
@@ -439,7 +440,7 @@ def _decode_walk(q, ck, cv, positions, block: int):
     qg = q.reshape(b, t, g, h // g, d)
     q_at = positions[:, None, None, :, None]
     last = decode_blocks(positions[:, -1], block, s_rows)
-    scale = d ** -0.5
+    scale = d ** -0.5 if scale is None else scale
 
     def step(j, carry):
         m, l, acc = carry
@@ -467,25 +468,29 @@ def _decode_walk(q, ck, cv, positions, block: int):
     return jax.lax.fori_loop(0, jnp.max(last), step, init)
 
 
-def _decode_blocked(q, ck, cv, positions, block: int) -> jax.Array:
+def _decode_blocked(q, ck, cv, positions, block: int,
+                    scale: Optional[float] = None) -> jax.Array:
     b, t, h, d = q.shape
-    _, l, acc = _decode_walk(q, ck, cv, positions, block)
+    _, l, acc = _decode_walk(q, ck, cv, positions, block, scale)
     return (acc / l).astype(q.dtype).transpose(0, 3, 1, 2, 4).reshape(
         b, t, h * d)
 
 
 def _decode_kernel(pos_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem,
                    q_s, m_s, l_s, acc_s, *, block: int, chunk: int,
-                   groups: int, t: int):
+                   groups: int, t: int, scale: float):
     """The whole tick's walk, one slot after another. Refs: pos [B, t] in
     SMEM; q [B, t x H, d], every slot's query heads as the model has
-    them, resident (a tick's queries are a few hundred KB); k, v [B, S x
-    G, d], the slab entry as it lies in HBM with rows and key-value heads
-    read as ONE axis (on the chip [B, S, G, d] and [B, S G, d] are the
-    same bytes; [B, S, G d] is not, and costs a copy of the slab); o as
-    q. Scratch: `kbuf.shape[0]` blocks of keys and as many of values with
-    their DMA semaphores, one slot's queries scaled and padded to whole
-    sublanes, their running max, sum and accumulator.
+    them, resident (a tick's queries are a few hundred KB); k, v the
+    slab entry as it lies in HBM (`_lies_by_group`): [B, S x G, d], rows
+    and key-value heads read as ONE axis (for G a power of two [B, S, G,
+    d] and [B, S G, d] are the same bytes on the chip; [B, S, G d] is
+    not, and costs a copy of the slab), or [B, G, S, d], a head's rows
+    one after another, which is where any other G lies; o as q. Scratch:
+    `kbuf.shape[0]` blocks of keys and as many of values (as the entry
+    lies: [block x G, d] or [G, block, d]) with their DMA semaphores, one
+    slot's queries scaled and padded to whole sublanes, their running
+    max, sum and accumulator.
 
     ALL heads of a slot are the rows of one product against a block of
     `block x G` key rows, and a score whose key row belongs to another
@@ -498,23 +503,36 @@ def _decode_kernel(pos_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem,
     one sequence of copies that runs `buffers - 1` ahead of the
     products, over a slot's end into the next slot's first blocks, so
     that a slab of parked slots streams like one long slot. The scale
-    and log2(e) are folded into the queries; products take the cache's
-    dtype (or the queries', the wider) and accumulate in float32."""
+    (`scale`: d ** -0.5 for whole heads, a packed head's own for rows of
+    several) and log2(e) are folded into the queries; products take the
+    cache's dtype (or the queries', the wider) and accumulate in
+    float32."""
     slots, rows, d = q_ref.shape
     buffers = kbuf.shape[0]
     padded = q_s.shape[0]
-    s_rows = k_hbm.shape[1] // groups
+    by_group = len(k_hbm.shape) == 4
+    s_rows = k_hbm.shape[2] if by_group else k_hbm.shape[1] // groups
     cd = jnp.promote_types(q_ref.dtype, kbuf.dtype)
     heads = rows // t
     rep = heads // groups
 
     def copies(slot, j, buf):
-        at = pl.ds(pl.multiple_of(
-            _block_start(j, block, s_rows) * groups, 8), block * groups)
-        return (pltpu.make_async_copy(k_hbm.at[slot, at, :], kbuf.at[buf],
+        at = _block_start(j, block, s_rows)
+        if by_group:    # G runs of `block` rows, one copy
+            src = (slot, slice(None), pl.ds(pl.multiple_of(at, 16), block))
+        else:
+            src = (slot, pl.ds(pl.multiple_of(at * groups, 8),
+                               block * groups))
+        return (pltpu.make_async_copy(k_hbm.at[src], kbuf.at[buf],
                                       sem.at[0, buf]),
-                pltpu.make_async_copy(v_hbm.at[slot, at, :], vbuf.at[buf],
+                pltpu.make_async_copy(v_hbm.at[src], vbuf.at[buf],
                                       sem.at[1, buf]))
+
+    def tile(ref, buf, c0):
+        """Rows [c0, c0 + chunk) of a block, all heads: [chunk x G, d]."""
+        if by_group:
+            return ref[buf, :, c0:c0 + chunk, :].reshape(groups * chunk, d)
+        return ref[buf, c0 * groups:(c0 + chunk) * groups, :]
 
     def start(slot, j, buf):
         @pl.when(slot < slots)
@@ -543,14 +561,19 @@ def _decode_kernel(pos_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem,
     head = row - query * heads
     g_row = sum((head >= g * rep).astype(jnp.int32)
                 for g in range(1, groups))
-    # column c of a chunk is key-value head c mod G of key row c // G
+    # column c of a chunk is key-value head c mod G of key row c // G;
+    # where the entry lies by head, key row c mod chunk of head c // chunk
     col = jax.lax.broadcasted_iota(jnp.int32, (padded, chunk * groups), 1)
-    mine = (col % groups) == g_row
-    k_in = col // groups
+    if by_group:
+        g_col = sum((col >= g * chunk).astype(jnp.int32)
+                    for g in range(1, groups))
+        mine, k_in = g_col == g_row, col - g_col * chunk
+    else:
+        mine, k_in = (col % groups) == g_row, col // groups
     q_s[...] = jnp.zeros(q_s.shape, q_s.dtype)
 
     def slot_walk(b, carry):
-        q_s[:rows, :] = q_ref[b].astype(F32) * (d ** -0.5 * _LOG2E)
+        q_s[:rows, :] = q_ref[b].astype(F32) * (scale * _LOG2E)
         m_s[...] = jnp.full(m_s.shape, _NEG, F32)
         l_s[...] = jnp.zeros(l_s.shape, F32)
         acc_s[...] = jnp.zeros(acc_s.shape, F32)
@@ -567,10 +590,9 @@ def _decode_kernel(pos_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem,
                 c.wait()
             at = _block_start(j, block, s_rows)
             for c0 in range(0, block, chunk):
-                at_c = (buf, slice(c0 * groups, (c0 + chunk) * groups))
                 s = jax.lax.dot_general(
-                    q, kbuf[at_c].astype(cd), (((1,), (1,)), ((), ())),
-                    preferred_element_type=F32)
+                    q, tile(kbuf, buf, c0).astype(cd),
+                    (((1,), (1,)), ((), ())), preferred_element_type=F32)
                 s = jnp.where(
                     mine & _block_seen(q_at, at + c0 + k_in, j, block),
                     s, _NEG)
@@ -581,7 +603,7 @@ def _decode_kernel(pos_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem,
                 m_s[...] = m_new
                 l_s[...] = alpha * l_s[...] + jnp.sum(p, -1, keepdims=True)
                 acc_s[...] = acc_s[...] * alpha + jax.lax.dot_general(
-                    p.astype(cd), vbuf[at_c].astype(cd),
+                    p.astype(cd), tile(vbuf, buf, c0).astype(cd),
                     (((1,), (0,)), ((), ())), preferred_element_type=F32)
             return (done + 1,) + after(a_slot, a_j)
 
@@ -592,33 +614,52 @@ def _decode_kernel(pos_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem,
     jax.lax.fori_loop(0, slots, slot_walk, (jnp.int32(0),) + ahead)
 
 
-@functools.partial(jax.jit, static_argnums=(4, 5))
-def _decode_pallas(q, ck, cv, positions, block: int, interpret: bool
-                   ) -> jax.Array:
+def _lies_by_group(groups: int) -> bool:
+    """How the chip lays an entry [B, S, G, d] down (XLA's default for
+    the shape, read from compiled ticks: PERF.md section 6, PR 61): with
+    G a power of two row by row, G heads to a sublane tile of their own
+    size, so [B, S G, d] is the same bytes; with any other G (GPT-2
+    small's 3 rows of 256 lanes) a tile of G would be padded, and S is
+    the second-minor axis: the bytes are [B, G, S, d]'s."""
+    return bool(groups & (groups - 1))
+
+
+@functools.partial(jax.jit, static_argnums=(4, 5, 6))
+def _decode_pallas(q, ck, cv, positions, block: int, interpret: bool,
+                   scale: Optional[float] = None) -> jax.Array:
     """Jitted on its own so that the layers of a tick share one lowering
     (`_prefill_pallas`). q [B, t, H, d], ck, cv [B, S, G, d] as the slab
-    holds them, positions [B, t] int32 -> [B, t, H d]."""
+    holds them, positions [B, t] int32 -> [B, t, H d]. The kernel's
+    operand is the entry's bytes under the axes they lie in: a reshape
+    or a transpose that the chip compiles to a bitcast."""
     b, t, heads, d = q.shape
     s_rows, groups = ck.shape[1], ck.shape[2]
     rows = t * heads
-    chunk = max(128, _DECODE_CHUNK // groups)
+    # the power of two that holds `_DECODE_CHUNK` columns of scores
+    chunk = max(128, 1 << (_DECODE_CHUNK // groups).bit_length() - 1)
     if block % chunk:       # a short entry is one block, and one chunk
         chunk = block
     padded = -(-rows // 8) * 8
-    merged = lambda x: x.reshape(b, s_rows * groups, d)
+    if _lies_by_group(groups):
+        kv_block = (groups, block, d)
+        lying = lambda x: x.transpose(0, 2, 1, 3)
+    else:
+        kv_block = (block * groups, d)
+        lying = lambda x: x.reshape(b, s_rows * groups, d)
     resident = pl.BlockSpec((b, rows, d), lambda i, pos: (0, 0, 0))
     where_it_lies = pl.BlockSpec(memory_space=pl.ANY)
     visited = b * -(-s_rows // block)
     out = pl.pallas_call(
         functools.partial(_decode_kernel, block=block, chunk=chunk,
-                          groups=groups, t=t),
+                          groups=groups, t=t,
+                          scale=d ** -0.5 if scale is None else scale),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=(1,),
             in_specs=[resident, where_it_lies, where_it_lies],
             out_specs=resident,
             scratch_shapes=[
-                pltpu.VMEM((_DECODE_BUFFERS, block * groups, d), ck.dtype),
-                pltpu.VMEM((_DECODE_BUFFERS, block * groups, d), cv.dtype),
+                pltpu.VMEM((_DECODE_BUFFERS,) + kv_block, ck.dtype),
+                pltpu.VMEM((_DECODE_BUFFERS,) + kv_block, cv.dtype),
                 pltpu.SemaphoreType.DMA((2, _DECODE_BUFFERS)),
                 pltpu.VMEM((padded, d), F32),
                 pltpu.VMEM((padded, 1), F32),
@@ -637,19 +678,23 @@ def _decode_pallas(q, ck, cv, positions, block: int, interpret: bool
             bytes_accessed=2 * q.size * q.dtype.itemsize
             + 2 * visited * block * groups * d * ck.dtype.itemsize,
             transcendentals=visited * rows * block * groups),
-    )(positions, q.reshape(b, rows, d), merged(ck), merged(cv))
+    )(positions, q.reshape(b, rows, d), lying(ck), lying(cv))
     return out.reshape(b, t, heads * d)
 
 
 def decode_attention(q: jax.Array, ck: jax.Array, cv: jax.Array,
-                     positions: jax.Array) -> jax.Array:
+                     positions: jax.Array, scale: Optional[float] = None
+                     ) -> jax.Array:
     """A tick's attention over the slab where it lies: q [B, t, H, d] (t
     = 1, or the speculative verify's k + 1), ck, cv [B, S, G, d] in their
     own dtype, query (b, j) seeing rows `<= positions[b, j]` of slot b
     (positions [B, t] int32, ascending along t). Returns [B, t, H d] in
     q's dtype: what the masked softmax over all S rows gives, from a walk
     that ends at each slot's last block (module docstring). The block
-    follows from the entry's shape (`decode_block`)."""
+    follows from the entry's shape (`decode_block`). Any G goes; `scale`
+    (d ** -0.5 where none is given) is what a row of several packed
+    heads passes: its queries are rows with the other heads' lanes
+    zeroed, and a head's own width scales them (`models/gpt2.py`)."""
     b, t, h, d = q.shape
     s_rows, groups = ck.shape[1], ck.shape[2]
     block = decode_block(ck.shape, ck.dtype)
@@ -657,17 +702,15 @@ def decode_attention(q: jax.Array, ck: jax.Array, cv: jax.Array,
     positions = positions.astype(jnp.int32)
     interpret = dispatch.interpret_forced()
     reason = dispatch.backend_reason()
-    if not reason and groups & (groups - 1):
-        reason = f"{groups} key-value heads are no power of two"
     if not reason and not interpret and (d % 128 or s_rows % 16):
         reason = (f"rows of {d} numbers or an entry of {s_rows} rows do "
                   "not fill the kernel's tiles")
     if reason:
         dispatch.record_choice("gqa_decode", shape, "reference", reason,
                                block=block)
-        return _decode_blocked(q, ck, cv, positions, block)
+        return _decode_blocked(q, ck, cv, positions, block, scale)
     dispatch.record_choice("gqa_decode", shape, "pallas", block=block)
-    return _decode_pallas(q, ck, cv, positions, block, interpret)
+    return _decode_pallas(q, ck, cv, positions, block, interpret, scale)
 
 
 def cache_attention(q: jax.Array, ck: jax.Array, cv: jax.Array,

@@ -25,7 +25,7 @@ from ray_tpu.ops import dispatch, mla, swa
 # bytes a token, [(rows, layers, bytes a slot)], entries, walks)
 TINY = {
     "GPT2Config": ("gpt2", None, None, False, False, 0, 1024,
-                   [(128, 2, 131072)], 2, False),
+                   [(128, 2, 131072)], 2, True),
     "LlamaConfig": ("llama", None, None, False, False, 0, 512,
                     [(128, 2, 65536)], 2, True),
     "NemotronHConfig": ("nemotron_h", "state", None, False, True, 18688,

@@ -284,6 +284,7 @@ def test_one_latent_array_a_step_doubles_the_blocks_rows(shape, block):
     (4096, 4, 128, 256),        # and its rings
     (33280, 1, 128, 1024),      # jamba: one key-value head
     (1536, 2, 128, 512),        # nemotron
+    (1024, 3, 256, 128),        # gpt2-chat: four heads of 64 to a row
     (96, 2, 16, 96)])           # never over the entry
 def test_the_decode_block_follows_from_the_entrys_shape(rows, groups, d,
                                                         block):
