@@ -45,7 +45,7 @@ import time
 
 HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 LOOP_KEYS = ("dispatch_ms", "readback_ms", "emit_ms", "admit_ms",
-             "total_ms", "live")
+             "total_ms", "live", "handed", "handovers")
 
 
 def thread_cpu() -> dict:
@@ -167,6 +167,7 @@ class Clocks:
         def stop_and_write(engine, *a, **k):
             # the harness stops the engine on its way out, whatever
             # happened: the last point this process is sure to reach
+            clocks.out["handover"] = engine.kv_stats().get("handover")
             clocks.finish()
             return stop(engine, *a, **k)
         eng.ContinuousBatchingEngine.stop = stop_and_write
@@ -181,14 +182,35 @@ class Clocks:
             full = [r for r in recs if r["live"] >= 0.85 * r["max_batch"]]
             steady = [r for r in full if not r["admissions"]]
 
+            # a pass that decoded and admitted nothing, full or not (a
+            # cell whose ticks never fill: `gpt2-chat`)
+            decoding = [r for r in recs
+                        if r["live"] >= 1 and not r["admissions"]]
+
             def means(rs):
-                return {k: sum(r[k] for r in rs) / len(rs)
+                # a parent without the hand-over has no such counters
+                return {k: sum(r.get(k, 0) for r in rs) / len(rs)
                         for k in LOOP_KEYS} if rs else {}
 
+            def share(rs, cond):
+                return sum(map(cond, rs)) / len(rs) if rs else None
+
+            reads = sorted(r["readback_ms"] for r in decoding)
             self.out["loop"] = {
                 "passes": len(recs), "full": len(full),
                 "steady_full": len(steady), "mean_all": means(recs),
-                "mean_full": means(full), "mean_steady_full": means(steady)}
+                "mean_full": means(full), "mean_steady_full": means(steady),
+                "steady": len(decoding), "mean_steady": means(decoding),
+                # the hand-over as the ring shows it: ONE sink call a
+                # steady pass, for every token the tick made
+                "steady_one_handover": share(
+                    decoding, lambda r: r.get("handovers") == 1),
+                "steady_all_handed": share(
+                    decoding, lambda r: r.get("handed")
+                    == r["live"] - r["discarded"]),
+                "steady_readback_ms": {
+                    q: reads[int(q * (len(reads) - 1))]
+                    for q in (0.5, 0.95)} if reads else {}}
             phases = [p for t in reqtrace.store().slowest(10 ** 6)
                       for p in (t.get("phases") or [])
                       if p["phase"] == "sse_flush"]

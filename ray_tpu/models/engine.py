@@ -190,9 +190,15 @@ NO uploads in a steady pass; 0 in a pass that launches nothing because
 no budget outlives the tick it reads; the speculative tick uploads its
 tokens as ever, its drafting is bookkeeping); ``readback_ms`` (the wait
 for the tokens and log-probabilities of the tick launched a pass
-earlier: BLOCKED on the device, so not host work, and most of a tick
-now that the host's pass runs under it); ``emit_ms`` (the walk over
-that tick's slots: emit, finish, queue puts, discards); ``inflight``
+earlier, whose copy to the host that launch began: BLOCKED on the
+device, so not host work; most of a tick where the chip bounds the pass,
+next to nothing where the host does, since the tick had ended and its
+tokens were on the host before they were asked for); ``emit_ms`` (the
+walk over that tick's slots, emit, finish, queue puts, discards, and the
+pass's hand-over: the sink calls); ``handed`` and ``handovers`` (tokens
+that left by a sink in the pass, and the sink calls that took them: 1
+where every stream is one consumer's; "A tick lands once" below);
+``inflight``
 (ticks queued on the chip while the pass was blocked on its read-back:
 1 in a steady pass, 0 in a pass with drafts, with nothing left to
 decode for, or that admits a long prompt) and ``discarded`` (rows of
@@ -236,6 +242,35 @@ each carries ``pass``, the number its pass's record takes in the ring
 request: the spans are the ring's picture in the profiler, the ring is
 what is read.
 With the recorder off the loop reads no clock and builds no record.
+
+A tick lands once. Between a tick's end on the chip and its tokens in
+the hands of the thread that frames them lie a copy and a crossing of
+threads, and the loop pays each once a pass. The COPY of what `_land`
+reads (the tokens, their log-probabilities and, with the recorder on,
+the family's counters) starts at the launch, right behind the tick
+(`_launch`: ``copy_to_host_async``; no program changes): a host-bound
+pass finds the data on the host a pass later, a device-bound one saves
+the round trip after the tick. The tokens are the next tick's input too
+and are not donated, so the copy and that tick both read them. An
+admission's prefill is read in the breath it is launched in and is left
+as it was. The CROSSING: a consumer may hand ``stream()`` a `StreamSink`.
+EVERY token of such a stream, the prefill's first too, then leaves by
+the pass's hand-over and none by the queue, so that one thread alone
+orders them: `_emit` appends to the pass's batch, and after the walk
+(`_land`, `_spec_emit`'s; at once after an admission's first `_emit`
+and a cancel's `_finish`) `_hand_over` calls each DISTINCT sink ONCE
+with ``[(stream, [tokens...], ended), ...]`` in the order the walk met
+the slots, a stream's end in the call that holds its last token (alone,
+with no token, where a cancel ended it). No token is held back to fill
+a batch. The stream's OWNER sleeps on the sink's ``wake``, which the
+loop sets once the first token and once the end have been handed over,
+and reads ``TokenStream.produced``, ``ended`` and at the end
+``tokens()``, the engine's own history (`ctx`). A request without a sink
+keeps its queue, token by token: the plain iterator is untouched. Two
+paths that share the walk and differ in where a token is put, chosen by
+what the code can see (a sink was registered), not by a knob.
+``kv_stats()["handover"]`` counts ``handed``, ``handovers`` and
+``queued`` (tokens that went by a queue).
 
 The loop keeps a ledger of the gaps it makes (`_GapLedger`, PR 35). A
 tick's tokens go out when its read-back returns (a LANDING); the time
@@ -293,7 +328,8 @@ above a few hundredths, the host's chain at admissions; the rest is the
 host's work under a busy chip. All zero while the recorder is off.
 
 Per-request token queues make it the natural producer for Serve's
-streaming path; `ContinuousBatchingEngine` is thread-safe for
+streaming path (the gateway's streams take the hand-over above
+instead); `ContinuousBatchingEngine` is thread-safe for
 concurrent submit/iterate from replica request threads. The streamed
 iterator exposes ``cache_outcome`` (hit|partial|miss) so the replica's
 TTFT histogram can label prefix-cache wins.
@@ -329,6 +365,12 @@ _ENGINE_SEQ = itertools.count()
 _SPEC_EVENTS_KEPT = 512
 # the loop's one clock; tests swap it to count the reads
 _now = time.perf_counter
+
+
+def _start_copy(x: jax.Array) -> None:
+    """Begin `x`'s copy to the host and return; tests swap it to count
+    the copies a launch starts."""
+    x.copy_to_host_async()
 
 
 def _clock(rec: Optional[dict]) -> float:
@@ -916,6 +958,41 @@ class _Request:
         # the ledger's number of the last landing this request took a
         # token from (recorder on)
         self.landed = -1
+        # where the emitted tokens begin in `ctx` (the prompt's length,
+        # 0 for an adoption that carried no prompt)
+        self.ctx_base = 0
+        # the hand-over (module docstring): the consumer's `StreamSink`
+        # (None: the queue, token by token), the tokens of the pass
+        # under way that have not been handed over yet (None: the
+        # request is not in `_batch`), and whether its END has left, by
+        # the sink or by the queue
+        self.sink: Optional["StreamSink"] = None
+        self.batch: Optional[List[int]] = None
+        self.closed = False
+        self.stream: Optional["TokenStream"] = None
+
+
+class StreamSink:
+    """What a consumer registers with ONE stream (``stream(sink=)``) to
+    take its tokens by hand-over (module docstring, "A tick lands
+    once"). `call` is the consumer's one callable, shared by all its
+    streams (a gateway has one a server): the loop calls each DISTINCT
+    `call` once a pass with ``[(stream, [tokens...], ended), ...]``, on
+    the loop's thread, so it must not block. `tag` is the consumer's own
+    handle on this stream (``TokenStream.tag``; the gateway's: the
+    stream's asyncio queue). `wake` is the event the stream's OWNER
+    sleeps on: the loop sets it twice a stream, once its first token and
+    once its end have been handed over, and anyone who wants the owner
+    to look up sets it too (the gateway at a disconnect). One sink may
+    serve a request's streams in turn (a replay after a preemption)."""
+
+    __slots__ = ("call", "tag", "wake")
+
+    def __init__(self, call: Callable[[List[tuple]], None],
+                 tag: Any = None):
+        self.call = call
+        self.tag = tag
+        self.wake = threading.Event()
 
 
 class TokenStream:
@@ -926,7 +1003,9 @@ class TokenStream:
     ``queue_ms`` / ``prefill_ms`` split the first token's wait on the
     engine's own clock, set before the first token arrives too (None
     with the flight recorder off); ``prefills_waited`` counts the other
-    requests' prefills that ran in ``queue_ms``."""
+    requests' prefills that ran in ``queue_ms``. A stream that was given
+    a `StreamSink` is no iterator: its tokens leave by the sink, and its
+    owner reads ``produced``, ``ended`` and, at the end, ``tokens()``."""
 
     def __init__(self, req: _Request, timeout_s: float):
         self._req = req
@@ -936,10 +1015,35 @@ class TokenStream:
         return self
 
     def __next__(self) -> int:
+        if self._req.sink is not None:
+            raise TypeError("this stream's tokens leave by its sink")
         tok = self._req.out.get(timeout=self._timeout_s)
         if tok is _DONE:
             raise StopIteration
         return int(tok)
+
+    @property
+    def tag(self) -> Any:
+        """The consumer's handle, as its `StreamSink` carries it."""
+        sink = self._req.sink
+        return None if sink is None else sink.tag
+
+    @property
+    def produced(self) -> int:
+        """Tokens emitted so far."""
+        return self._req.produced
+
+    @property
+    def ended(self) -> bool:
+        """The stream's end has LEFT the engine: handed to the sink
+        (after its last token, in the same call) or put on the queue."""
+        return self._req.closed
+
+    def tokens(self) -> List[int]:
+        """Every token emitted so far, in order: the engine's own
+        history (`ctx`), no second copy kept. Whole once ``ended``."""
+        r = self._req
+        return r.ctx[r.ctx_base:]
 
     @property
     def cache_outcome(self) -> Optional[str]:
@@ -1128,6 +1232,14 @@ class ContinuousBatchingEngine:
         self.lookahead_ticks = 0      # launched behind a tick in flight
         self.lookahead_discarded = 0  # their rows a finished slot left
         self._gaps = _GapLedger()     # module docstring
+        # the hand-over: the requests that hold tokens (or an end) of
+        # the pass under way for their sinks, in the order the walk met
+        # them; tokens that left by a sink, the sink calls that took
+        # them, and tokens that went by a queue
+        self._batch: List[_Request] = []
+        self.handed = 0
+        self.handovers = 0
+        self.queued = 0
         self._slot_req: List[Optional[_Request]] = [None] * max_batch
         self._free = list(range(max_batch))
         # multi-tenant LoRA (serve/lora.py AdapterPool, duck-typed so
@@ -1159,12 +1271,16 @@ class ContinuousBatchingEngine:
     # ------------------------------------------------------------- API
     def submit(self, prompt_tokens, max_new_tokens: int,
                eos_token: Optional[int] = None,
-               adapter_id: Optional[str] = None) -> "_Request":
+               adapter_id: Optional[str] = None,
+               sink: Optional[StreamSink] = None,
+               timeout_s: float = 120.0) -> "_Request":
         """`adapter_id` (multi-tenant LoRA): decode this request under
         that tenant's adapter. The pool pin — and a cold adapter's
         page-in — happens HERE on the caller's thread, so paging never
         blocks the decode loop; the pin is released when the slot
-        frees (finish or cancel)."""
+        frees (finish or cancel). `sink`: EVERY token of the request,
+        the prefill's first too, leaves by the hand-over and none by the
+        queue (module docstring); its `TokenStream` is `.stream`."""
         prompt = np.asarray(prompt_tokens, np.int32).reshape(1, -1)
         if prompt.shape[1] + max_new_tokens > self.config.max_seq_len:
             raise ValueError("prompt + max_new_tokens exceeds max_seq_len")
@@ -1180,7 +1296,10 @@ class ContinuousBatchingEngine:
             self._next_rid += 1
         req = _Request(rid, prompt, max_new_tokens, eos_token)
         req.ctx = [int(t) for t in prompt[0]]
+        req.ctx_base = len(req.ctx)
         req.ctx_has_prompt = True
+        req.sink = sink
+        req.stream = TokenStream(req, timeout_s)
         req.adapter_id = adapter_id
         req.lora_slot = lora_slot
         req.prefills_before = self.prefill_admitted
@@ -1192,13 +1311,15 @@ class ContinuousBatchingEngine:
     def stream(self, prompt_tokens, max_new_tokens: int,
                eos_token: Optional[int] = None,
                timeout_s: float = 120.0,
-               adapter_id: Optional[str] = None) -> Iterator[int]:
+               adapter_id: Optional[str] = None,
+               sink: Optional[StreamSink] = None) -> Iterator[int]:
         """Submit and yield tokens as the shared loop produces them.
         Returns a TokenStream whose ``cache_outcome`` labels the
-        admission's prefix-cache result."""
-        req = self.submit(prompt_tokens, max_new_tokens, eos_token,
-                          adapter_id=adapter_id)
-        return TokenStream(req, timeout_s)
+        admission's prefix-cache result. With a `sink` the stream yields
+        nothing: the loop hands its tokens to the sink, once a pass."""
+        return self.submit(prompt_tokens, max_new_tokens, eos_token,
+                           adapter_id=adapter_id, sink=sink,
+                           timeout_s=timeout_s).stream
 
     def generate(self, prompt_tokens, max_new_tokens: int,
                  eos_token: Optional[int] = None,
@@ -1276,7 +1397,9 @@ class ContinuousBatchingEngine:
                        max_new_tokens, eos_token)
         if prompt_tokens is not None:
             req.ctx = [int(t) for t in prompt_tokens]
+            req.ctx_base = len(req.ctx)
             req.ctx_has_prompt = True
+        req.stream = TokenStream(req, timeout_s)
         req.cache_outcome = cache_outcome
         req.reused_tokens = int(reused_tokens)
         req.adapter_id = adapter_id
@@ -1286,7 +1409,7 @@ class ContinuousBatchingEngine:
             req.t_submit = _now()
         self._pending_adopt.put(_Adoption(req, plen, ck, cv,
                                           first_token, score))
-        return TokenStream(req, timeout_s)
+        return req.stream
 
     def update_params(self, params: Any,
                       version: Optional[int] = None) -> threading.Event:
@@ -1357,7 +1480,7 @@ class ContinuousBatchingEngine:
             self._cancels += 1
         return True
 
-    def _apply_cancels(self) -> None:
+    def _apply_cancels(self, it: Optional[Dict[str, Any]] = None) -> None:
         """Decode-loop only, between ticks: free cancelled ACTIVE slots
         (queued cancelled requests are dropped at admission instead)."""
         with self._lock:
@@ -1367,6 +1490,7 @@ class ContinuousBatchingEngine:
             if req is not None and req.cancelled and not req.finished:
                 self._count_cancel(req)
                 self._finish(req)
+        self._hand_over(it)
         self.publish_kv_telemetry()
 
     def _count_cancel(self, req: "_Request") -> None:
@@ -1437,6 +1561,12 @@ class ContinuousBatchingEngine:
             # for by the host's step (module docstring: what the
             # benchmark's gap readers read)
             gaps=self._gaps.totals(),
+            # tokens that left by a sink, the sink calls that took them
+            # (one a pass where every stream is one consumer's), and
+            # tokens that went by a stream's queue
+            handover={"handed": self.handed,
+                      "handovers": self.handovers,
+                      "queued": self.queued},
             # which traced shapes of the expert layers' grouped product
             # took the streamed kernel (the process's, not this engine's)
             grouped_product=dispatch.kernel_choices("grouped_product"),
@@ -1510,6 +1640,7 @@ class ContinuousBatchingEngine:
                 break
             if self._adopt_one(adoption, it):
                 adopted += 1
+            self._hand_over(it)
         admitted = 0
         while self._free and admitted < self.max_prefills_per_tick:
             try:
@@ -1518,6 +1649,9 @@ class ContinuousBatchingEngine:
                 break
             if self._admit_one(req, it):
                 admitted += 1
+            # the first token goes at once, a batch of one: the next
+            # admission's prefill does not hold it
+            self._hand_over(it)
         if adopted:
             self.max_adoptions_admitted_per_tick = max(
                 self.max_adoptions_admitted_per_tick, adopted)
@@ -1678,7 +1812,12 @@ class ContinuousBatchingEngine:
                 self._output_memory.move_to_end(key)
                 while len(self._output_memory) > self._output_memory_cap:
                     self._output_memory.popitem(last=False)
-        req.out.put(_DONE)
+        if req.sink is None:
+            req.out.put(_DONE)
+            req.closed = True
+        else:
+            # the end rides the pass's hand-over, behind the last token
+            self._batched(req)
         slot = req.slot
         if slot is not None:
             self._slot_req[slot] = None
@@ -1705,11 +1844,60 @@ class ContinuousBatchingEngine:
         req.scores.append(score)
         if req.t_first is None and req.t_admit is not None:
             req.t_first = _now()
-        req.out.put(tok)
+        if req.sink is None:
+            req.out.put(tok)
+            self.queued += 1
+        else:
+            self._batched(req).append(tok)
         req.produced += 1
         if (req.eos_token is not None and tok == req.eos_token) \
                 or req.produced >= req.max_new:
             self._finish(req)
+
+    def _batched(self, req: _Request) -> List[int]:
+        """`req`'s tokens in the hand-over under way, which it joins."""
+        if req.batch is None:
+            req.batch = []
+            self._batch.append(req)
+        return req.batch
+
+    def _hand_over(self, it: Optional[Dict[str, Any]] = None) -> None:
+        """Decode-loop only: what the walk just made (`_land`, an
+        admission's first `_emit`, a cancel's `_finish`) crosses to the
+        consumers, each DISTINCT sink called ONCE with its streams'
+        tokens in the order the walk met them, a stream's end in the
+        call that holds its last token. Nothing is held back to fill a
+        batch. Then, and only then, the owners that have something to do
+        are woken: at a stream's first token and at its end, so that an
+        owner who sees ``ended`` knows the consumer has every token."""
+        reqs = self._batch
+        if not reqs:
+            return
+        self._batch = []
+        by_sink: Dict[Any, List[tuple]] = {}
+        wake: List[_Request] = []
+        handed = 0
+        for req in reqs:
+            toks, req.batch = req.batch, None
+            handed += len(toks)
+            by_sink.setdefault(req.sink.call, []).append(
+                (req.stream, toks, req.finished))
+            # its end, or every token it has made so far: its first
+            if req.finished or req.produced == len(toks):
+                wake.append(req)
+        for call, batch in by_sink.items():
+            try:
+                call(batch)
+            except Exception:  # noqa: BLE001 - the consumer's, not the loop's
+                pass
+        for req in wake:
+            req.closed = req.finished
+            req.sink.wake.set()
+        self.handed += handed
+        self.handovers += len(by_sink)
+        if it is not None:
+            it["handed"] += handed
+            it["handovers"] += len(by_sink)
 
     # ------------------------------------------------------- speculation
 
@@ -1862,6 +2050,16 @@ class ContinuousBatchingEngine:
                 cache, nxt, lp, counts, pos_next = _tick(
                     self.params, self.config, self._cache, tok, pos, mask)
             gaps.launched(work=True)
+            # what `_land` will read starts for the host NOW, behind the
+            # tick: a host-bound pass finds it there a pass later, a
+            # device-bound one saves the round trip after the tick.
+            # `nxt` is the next tick's input too and is not donated, so
+            # the copy and that tick both read it
+            _start_copy(nxt)
+            _start_copy(lp)
+            if it is not None and counts:
+                for v in counts.values():
+                    _start_copy(v)
             self._cache = cache
             if not drafts:
                 self._dev = (nxt, pos_next, mask)
@@ -1930,6 +2128,7 @@ class ContinuousBatchingEngine:
                     tok = int(nxt_np[slot])
                     self._tokens[slot] = tok
                     self._emit(req, tok, float(lp_np[slot]))
+            self._hand_over(it)
         self.lookahead_discarded += discarded
         if it is not None:
             gaps.land(t1, streams, it)
@@ -2023,7 +2222,7 @@ class ContinuousBatchingEngine:
             # a swap holds from the next LAUNCH: the tick in flight
             # finishes on the weights it was launched with
             self._apply_pending_swap()
-            self._apply_cancels()
+            self._apply_cancels(it)
             # a long prompt's prefill is not queued behind the tick in
             # flight (`_long_prompt_waits`): that tick is read and its
             # tokens go out first, and the pass ends with the next tick
@@ -2088,6 +2287,7 @@ class ContinuousBatchingEngine:
                 "admit_ms": 0.0, "admissions": [],
                 "dispatch_ms": 0.0, "readback_ms": 0.0,
                 "emit_ms": 0.0, "total_ms": 0.0,
+                "handed": 0, "handovers": 0,
                 "inflight": 0, "discarded": 0}, _now()
 
     def _record_pass(self, it: Dict[str, Any], t_top: float) -> None:

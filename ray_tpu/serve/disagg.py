@@ -1259,7 +1259,7 @@ class _PreemptSlot:
     free."""
 
     __slots__ = ("key", "tenant", "rep", "tokens", "preempted",
-                 "cancel_fn")
+                 "cancel_fn", "live")
 
     def __init__(self, key: int, tenant: Optional[str] = None):
         self.key = key
@@ -1268,6 +1268,14 @@ class _PreemptSlot:
         self.tokens = 0
         self.preempted = False
         self.cancel_fn: Optional[Callable[[], None]] = None
+        # a live stream whose tokens leave by a sink: its owner sleeps
+        # and counts them into `tokens` at the stream's end
+        self.live: Any = None
+
+    def delivered(self) -> int:
+        """Tokens delivered so far, a sleeping owner's stream's too."""
+        live = self.live
+        return self.tokens + (live.produced if live is not None else 0)
 
 
 class DisaggRouter:
@@ -1847,7 +1855,7 @@ class DisaggRouter:
                  and not s.preempted]
         if not cands:
             return None
-        victim = min(cands, key=lambda s: s.tokens)
+        victim = min(cands, key=_PreemptSlot.delivered)
         victim.preempted = True
         return victim
 
@@ -1871,7 +1879,7 @@ class DisaggRouter:
             emit("gateway", {"kind": "preempt",
                              "router": self.router_id,
                              "victim_tenant": victim.tenant,
-                             "tokens_done": victim.tokens})
+                             "tokens_done": victim.delivered()})
         except Exception:  # noqa: BLE001 — telemetry only
             pass
         emit("disagg", {"kind": "preempt", "router": self.router_id,
@@ -2166,7 +2174,8 @@ class DisaggRouter:
                  tenant: Optional[str] = None,
                  priority: Optional[str] = None,
                  on_tokens=None,
-                 cancel_event: Any = None) -> List[int]:
+                 cancel_event: Any = None,
+                 sink: Any = None) -> List[int]:
         """One request end-to-end. `on_first_token()` (optional) fires
         the moment the first token exists — at prefill completion under
         disaggregation — which is what the harness's TTFT measures.
@@ -2199,8 +2208,15 @@ class DisaggRouter:
         caller as it lands (the gateway's SSE bridge). `cancel_event`
         (a threading.Event) aborts the request with shed cause
         ``disconnect`` when set — the gateway sets it when the HTTP
-        client goes away. All three default to None: in-process
-        callers are byte-for-byte unaffected."""
+        client goes away. `sink` (a ``models.engine.StreamSink``, the
+        gateway's for a streamed request) takes the tokens by HAND-OVER
+        where the engine is colocated and no `token_sleep_s` is set:
+        the engine's loop hands the sink every token, once a pass, and
+        `on_tokens` is not called; this thread sleeps on ``sink.wake``
+        from the first token to the stream's end (`_generate_colocated`).
+        Everywhere else the sink is ignored and `on_tokens` streams as
+        ever. All four default to None: in-process callers are
+        byte-for-byte unaffected."""
         if priority is not None and priority not in ("interactive",
                                                      "batch"):
             raise ValueError(
@@ -2248,7 +2264,8 @@ class DisaggRouter:
                             prompt, max_new_tokens, eos_token,
                             timeout_s, deadline, on_first_token,
                             token_sleep_s, t_admit, tenant, pslot,
-                            on_tokens, cancel_event, rep_box)
+                            on_tokens, cancel_event, rep_box,
+                            sink if token_sleep_s <= 0 else None)
                     else:
                         out = self._generate_disagg(
                             rep_box, prompt, max_new_tokens, eos_token,
@@ -2277,6 +2294,19 @@ class DisaggRouter:
                 tr.finish("error", cause=type(e).__name__)
             raise
 
+    def stream_sink(self, call: Callable[[List[tuple]], None],
+                    tag: Any = None) -> Any:
+        """A ``StreamSink`` for ONE streamed request of a consumer whose
+        one callable is `call` (``generate(sink=)``), where this
+        router's engine hands over: the colocated engine. None where it
+        does not (the disaggregated tiers pull chunks from a replica):
+        the consumer's `on_tokens` streams there."""
+        if self._disagg_mode:
+            return None
+        from ray_tpu.models.engine import StreamSink
+
+        return StreamSink(call, tag)
+
     def _record_tenant_ttft(self, tenant: Optional[str],
                             ttft_ms: float) -> None:
         if tenant is None:
@@ -2292,12 +2322,24 @@ class DisaggRouter:
                             token_sleep_s, t_admit, tenant=None,
                             pslot=None, on_tokens=None,
                             cancel_event=None,
-                            rep_box=None) -> List[int]:
+                            rep_box=None, sink=None) -> List[int]:
         """Single-engine path — now a replay LOOP mirroring
         _generate_disagg: a preempted batch stream ends early at the
         engine's tick boundary (cancelled slots drain through _DONE)
         and resumes here from prompt+history for the remaining budget,
-        bit-identical under greedy decode."""
+        bit-identical under greedy decode.
+
+        With a `sink` (``generate``) this thread does not iterate: the
+        engine's loop hands the sink EVERY token of the stream, the
+        first too, so one thread alone orders them. This thread wakes
+        ONCE at the first token (a signal that carries none: the TTFT
+        window, the ``decode_first_token`` phase, `on_first_token`),
+        then sleeps until the stream's end, the deadline or the
+        `cancel_event`, whichever is first: a TIMED wait on
+        ``sink.wake`` that ends at the deadline and that whoever sets
+        the `cancel_event` sets too, so a shed is no later than a
+        token's time, never a poll's. At the end it takes the history
+        from the stream (``TokenStream.tokens()``)."""
         history: List[int] = []
         first_emitted = False
         had_preempt = False
@@ -2313,10 +2355,10 @@ class DisaggRouter:
                 [prompt, np.asarray(history, np.int32)])
                 if history else prompt)
             try:
-                stream = self._colocated.stream(replay, remaining,
-                                                eos_token,
-                                                timeout_s=timeout_s,
-                                                adapter_id=tenant)
+                stream = self._colocated.stream(
+                    replay, remaining, eos_token, timeout_s=timeout_s,
+                    adapter_id=tenant,
+                    **({} if sink is None else {"sink": sink}))
             except Exception as e:  # noqa: BLE001 — submit-time failure
                 if _is_pool_exhausted(e):
                     raise self._shed_pool_exhausted("colocated", tenant,
@@ -2330,40 +2372,76 @@ class DisaggRouter:
                     pslot.cancel_fn = (
                         lambda s=stream: self._colocated.cancel_slot(
                             s, "preempt"))
+                    if sink is not None:
+                        pslot.live = stream
             t_dec = time.perf_counter()
             t_first_tok: Optional[float] = None
             n_attempt_toks = 0
+
+            def first_token() -> None:
+                """The attempt's first token exists."""
+                nonlocal t_first_tok, first_emitted
+                t_first_tok = time.perf_counter()
+                if tr is not None:
+                    # the engine's own split of this wait rides the
+                    # phase as its parts
+                    tr.add_phase(
+                        "decode_first_token",
+                        (t_first_tok - t_dec) * 1e3,
+                        parts=_first_token_parts(stream),
+                        **_first_token_waited(stream))
+                if not first_emitted:
+                    first_emitted = True
+                    ttft = (time.perf_counter() - t_admit) * 1e3
+                    self._ttft_win.add(ttft)
+                    self._record_tenant_ttft(tenant, ttft)
+                    if on_first_token is not None:
+                        on_first_token()
+
             try:
-                for tok in stream:
-                    if t_first_tok is None:
-                        t_first_tok = time.perf_counter()
-                        if tr is not None:
-                            # the engine's own split of this wait
-                            # rides the phase as its parts
-                            tr.add_phase(
-                                "decode_first_token",
-                                (t_first_tok - t_dec) * 1e3,
-                                parts=_first_token_parts(stream),
-                                **_first_token_waited(stream))
-                    n_attempt_toks += 1
-                    if not first_emitted:
-                        first_emitted = True
-                        ttft = (time.perf_counter() - t_admit) * 1e3
-                        self._ttft_win.add(ttft)
-                        self._record_tenant_ttft(tenant, ttft)
-                        if on_first_token is not None:
-                            on_first_token()
-                    history.append(tok)
+                if sink is not None:
+                    while True:
+                        # to a hair past the deadline, where there is
+                        # one nearer than the engine's own timeout
+                        wait = timeout_s if deadline is None else min(
+                            timeout_s, max(0.0, deadline + 1e-3
+                                           - time.perf_counter()))
+                        woke = sink.wake.wait(wait)
+                        # cleared BEFORE the stream is read: what is
+                        # set after this is seen by the next wait
+                        sink.wake.clear()
+                        ended, before = stream.ended, n_attempt_toks
+                        n_attempt_toks = stream.produced
+                        if t_first_tok is None and n_attempt_toks:
+                            first_token()
+                        if ended:
+                            break
+                        self._check_abort(deadline, tenant, cancel_event)
+                        if not woke and wait >= timeout_s \
+                                and n_attempt_toks == before:
+                            # the engine stalled: what the iterator's
+                            # get raises after `timeout_s` and no token
+                            raise queue.Empty
+                    # the engine's own history of the stream, whole now
+                    history.extend(stream.tokens())
                     if pslot is not None:
-                        pslot.tokens = len(history)
-                    if on_tokens is not None:
-                        try:
-                            on_tokens([tok])
-                        except Exception:  # noqa: BLE001 — caller's
-                            pass
-                    if token_sleep_s > 0:
-                        time.sleep(token_sleep_s)
-                    self._check_abort(deadline, tenant, cancel_event)
+                        pslot.tokens, pslot.live = len(history), None
+                else:
+                    for tok in stream:
+                        if t_first_tok is None:
+                            first_token()
+                        n_attempt_toks += 1
+                        history.append(tok)
+                        if pslot is not None:
+                            pslot.tokens = len(history)
+                        if on_tokens is not None:
+                            try:
+                                on_tokens([tok])
+                            except Exception:  # noqa: BLE001 — caller's
+                                pass
+                        if token_sleep_s > 0:
+                            time.sleep(token_sleep_s)
+                        self._check_abort(deadline, tenant, cancel_event)
             except RequestShedError as e:
                 # deadline/disconnect shed mid-stream: cancel the
                 # engine slot so the abandoned request stops burning
@@ -2384,6 +2462,7 @@ class DisaggRouter:
                 if pslot is not None:
                     with self._lock:
                         pslot.cancel_fn = None
+                        pslot.live = None
             if tr is not None and t_first_tok is not None:
                 tr.add_phase("decode_steady",
                              (time.perf_counter() - t_first_tok) * 1e3,

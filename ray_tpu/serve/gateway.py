@@ -668,10 +668,34 @@ class GatewayServer:
             route, ctx["req_id"], ctx["created"], ctx["model"], text,
             finish, len(ctx["prompt_tokens"]), len(toks)))
 
+    def _hand_over(self, batch: List[tuple]) -> None:
+        """This server's ONE sink (``models.engine.StreamSink.call``),
+        called on an engine's loop thread once a pass with every
+        stream's new tokens: ONE `call_soon_threadsafe` for them all,
+        whose callback (`_deliver`, on the asyncio thread) puts each
+        stream's on that stream's queue, which its `tag` holds."""
+        try:
+            self._loop.call_soon_threadsafe(self._deliver, batch)
+        except RuntimeError:  # loop shut down: nobody reads any more
+            for stream, _toks, _ended in batch:
+                stream.tag[1]()
+
+    @staticmethod
+    def _deliver(batch: List[tuple]) -> None:
+        for stream, toks, _ended in batch:
+            if toks:
+                stream.tag[0].put_nowait(("tokens", toks))
+
     async def _stream_response(self, request, ctx: Dict[str, Any]):
-        """SSE bridge: generate runs on the executor; its on_tokens
-        chunks land on an asyncio queue (call_soon_threadsafe) and are
-        re-framed as OpenAI stream chunks. Each delta is made from
+        """SSE bridge: generate runs on the executor and what it makes
+        lands on this request's asyncio queue, to be re-framed as OpenAI
+        stream chunks. Where the router's engine hands over (a colocated
+        engine: `DisaggRouter.stream_sink`), the TOKENS cross from the
+        engine's loop thread, every stream's in one
+        `call_soon_threadsafe` a pass (`_hand_over`), and the executor's
+        thread sleeps from the first token to the end, when it sends
+        ``done`` behind them; elsewhere its `on_tokens` chunks cross one
+        `call_soon_threadsafe` each. Each delta is made from
         the tokens that are new and a few before them (_StreamText: a
         frame costs a frame, not the answer so far), and concatenated
         deltas are EXACTLY the non-streaming body. Disconnects —
@@ -699,15 +723,31 @@ class GatewayServer:
         text = _StreamText(self._codec)
         got = text.tokens
 
+        # the stream's tokens by hand-over, where the router has an
+        # engine that hands over; its worker then sleeps on `sink.wake`
+        make_sink = getattr(router, "stream_sink", None)
+        sink = None
+
+        def _cancel():
+            cancel_event.set()
+            if sink is not None:
+                sink.wake.set()     # a sleeping worker sheds NOW
+
         def _put(item):
             try:
                 loop.call_soon_threadsafe(q.put_nowait, item)
             except RuntimeError:  # loop shut down mid-request
-                cancel_event.set()
+                _cancel()
 
         kwargs = self._generate_kwargs(ctx)
         kwargs["cancel_event"] = cancel_event
         kwargs["on_tokens"] = lambda toks: _put(("tokens", list(toks)))
+        if callable(make_sink):
+            # its tag: where `_deliver` puts the tokens, and what
+            # `_hand_over` calls where nobody reads any more
+            sink = make_sink(self._hand_over, (q, _cancel))
+        if sink is not None:
+            kwargs["sink"] = sink
 
         def work():
             try:
@@ -848,7 +888,7 @@ class GatewayServer:
                     failed = payload
                     break
         except asyncio.CancelledError:
-            cancel_event.set()
+            _cancel()
             self._count(route, cls, 499)
             emit("gateway", {"kind": "disconnect",
                              "gateway": self.gateway_id,
@@ -857,7 +897,7 @@ class GatewayServer:
                     tokens_sent=len(got))
             raise
         if disconnected:
-            cancel_event.set()
+            _cancel()
             self._count(route, cls, 499)
             emit("gateway", {"kind": "disconnect",
                              "gateway": self.gateway_id,

@@ -34,7 +34,9 @@ PROMPT = list(range(1, 20))  # 19 tokens: four blocks of 4 and a tail of 3
 # `gap_ms` where a stream felt the gap
 FIELDS = {"engine_id", "pass", "ts", "live", "live_rows", "max_batch",
           "pending", "admit_ms", "admissions", "dispatch_ms",
-          "readback_ms", "emit_ms", "total_ms", "inflight", "discarded"}
+          "readback_ms", "emit_ms", "total_ms", "inflight", "discarded",
+          # tokens that left by a sink in the pass, and the sink calls
+          "handed", "handovers"}
 GAP_FIELDS = {"gap_streams", "gap_admissions", "gap_blocked_ms",
               "gap_empty_ms", "gap_empty_by"}
 # and `slab_rows_read`, what the tick's walk read of the slab (PR 41)
